@@ -31,20 +31,15 @@ class TpuAccelerator(Accelerator):
         return "tpu"
 
     def is_available(self) -> bool:
-        try:
-            import jax
+        """A backend that fails to initialize raises; it does not read as
+        "no TPU" (and so as a quiet fall to the CPU accelerator)."""
+        import jax
 
-            return any(d.platform == "tpu" for d in jax.devices())
-        except Exception:
-            return False
+        return jax.default_backend() == "tpu"
 
     def memory_stats(self, index: int = 0) -> dict:
-        try:
-            dev = self.local_devices()[index]
-            stats = dev.memory_stats() or {}
-            return dict(stats)
-        except Exception:
-            return {}
+        # PJRT reports None where a backend keeps no stats (the CPU's)
+        return dict(self.local_devices()[index].memory_stats() or {})
 
     def supported_dtypes(self) -> list:
         import jax.numpy as jnp

@@ -53,8 +53,6 @@ def main(argv=None):
         os.environ[k] = str(v)
 
     import jax
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import numpy as np
 
     import deepspeed_tpu

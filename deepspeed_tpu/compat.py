@@ -1,77 +1,10 @@
-"""Version compatibility shims for the installed JAX.
+"""The one place this codebase names JAX's SPMD primitives.
 
-``shard_map`` is the one API this codebase uses that has moved between
-JAX releases: new versions export :func:`jax.shard_map` (with a
-``check_vma`` kwarg); 0.4.x only has
-``jax.experimental.shard_map.shard_map`` (same call style, but the
-replication check is spelled ``check_rep``). Every module in this repo
-imports ``shard_map`` from here instead of from ``jax`` directly —
-``tests/test_marker_audit.py`` enforces that.
+Every module imports ``shard_map`` (and ``axis_size`` / ``pcast``) from
+here instead of from ``jax`` directly — ``tests/test_marker_audit.py``
+enforces that — so a future JAX move of these names is a one-file change.
+They are plain re-exports of the installed JAX's (0.9.0) own.
 """
 
-from __future__ import annotations
-
-import inspect
-
-try:                                    # jax >= 0.5: top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-# True when shard_map supports partially-automatic axes usably (the new
-# ``axis_names`` API). 0.4.x's experimental version takes ``auto`` but has
-# no eager impl (`if auto: raise NotImplementedError`) and emits
-# PartitionId ops XLA:CPU SPMD rejects — tests for partial-auto paths
-# (SPMD pipeline, dropless expert parallelism) skip on it.
-PARTIAL_AUTO_SHARD_MAP = "axis_names" in _PARAMS
-
-
-def axis_size(axis_name):
-    """Static size of a named mesh axis from inside ``shard_map``/``pmap``.
-
-    ``jax.lax.axis_size`` only exists in newer JAX; on 0.4.x the axis
-    frame (which old ``axis_frame`` returns as a bare int) carries it.
-    The result is a Python int — usable in static shapes (``jnp.split``).
-    """
-    try:
-        from jax.lax import axis_size as _axis_size
-        return _axis_size(axis_name)
-    except ImportError:
-        from jax import core
-        frame = core.axis_frame(axis_name)
-        return frame if isinstance(frame, int) else frame.size
-
-
-try:                                    # new JAX: varying/manual type casts
-    from jax.lax import pcast
-except ImportError:
-    def pcast(x, axis_name=None, to=None):
-        """No-op on 0.4.x: the varying/invariant distinction ``pcast``
-        manages does not exist there, so values already behave as if cast."""
-        return x
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-              check_rep=None, axis_names=None, **kwargs):
-    """:func:`jax.shard_map` with version-variant kwargs normalized.
-
-    - ``check_vma`` (new spelling) / ``check_rep`` (0.4.x spelling):
-      whichever the installed JAX understands is used.
-    - ``axis_names`` (new: the axes that are *manual*): translated to the
-      0.4.x ``auto`` complement (the axes left automatic) when needed.
-    """
-    flag = check_vma if check_vma is not None else check_rep
-    if flag is not None:
-        if "check_vma" in _PARAMS:
-            kwargs["check_vma"] = flag
-        elif "check_rep" in _PARAMS:
-            kwargs["check_rep"] = flag
-    if axis_names is not None:
-        if "axis_names" in _PARAMS:
-            kwargs["axis_names"] = axis_names
-        elif "auto" in _PARAMS:
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+from jax import shard_map                                     # noqa: F401
+from jax.lax import axis_size, pcast                          # noqa: F401

@@ -46,7 +46,7 @@ from typing import Dict
 
 import jax.numpy as jnp
 
-from ...ops.quantizer import _HAS_FP8, FP8_MAX
+from ...ops.quantizer import FP8_MAX
 
 # Symmetric int8: values in [-127, 127] (−128 unused, keeps the code
 # symmetric around zero) with scale = amax / 127.
@@ -90,9 +90,6 @@ def validate_kv_quant(dtype: str, scale_granularity: str) -> None:
     if dtype not in SUPPORTED_DTYPES:
         raise ValueError(f"kv_quant.dtype {dtype!r} not supported "
                          f"(implemented: {SUPPORTED_DTYPES})")
-    if dtype == "fp8_e4m3" and not _HAS_FP8:
-        raise ValueError("kv_quant.dtype 'fp8_e4m3' needs a JAX build "
-                         "with float8_e4m3fn")
     if scale_granularity not in SUPPORTED_GRANULARITIES:
         raise ValueError(
             f"kv_quant.scale_granularity {scale_granularity!r} not "

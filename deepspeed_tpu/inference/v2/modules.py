@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional
 
-from ...ops.pallas_utils import HAS_PALLAS, on_tpu
+from ...ops.pallas_utils import on_tpu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,8 +125,7 @@ def _flash_pallas_supports(seq_len=0, head_dim=0, block_q=512, block_kv=512,
                            force_interpret=False, **_):
     from ...ops import flash_attention as fa
 
-    return (HAS_PALLAS
-            and fa._pallas_ok(seq_len, seq_len, head_dim, block_q, block_kv)
+    return (fa._pallas_ok(seq_len, seq_len, head_dim, block_q, block_kv)
             and (on_tpu() or force_interpret or fa._FORCE_INTERPRET))
 
 

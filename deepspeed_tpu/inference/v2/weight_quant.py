@@ -46,7 +46,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ...ops.quantizer import _HAS_FP8, choose_block, quantize_blockwise
+from ...ops.quantizer import choose_block, quantize_blockwise
 
 #: weight representations this module encodes (the config surface
 #: rejects anything else up front)
@@ -70,9 +70,6 @@ def validate_weight_quant(dtype: str, block: int) -> None:
     if dtype not in WEIGHT_SUPPORTED_DTYPES:
         raise ValueError(f"weight_quant.dtype {dtype!r} not supported "
                          f"(implemented: {WEIGHT_SUPPORTED_DTYPES})")
-    if dtype == "fp8_e4m3" and not _HAS_FP8:
-        raise ValueError("weight_quant.dtype 'fp8_e4m3' needs a JAX "
-                         "build with float8_e4m3fn")
     if int(block) < 1:
         raise ValueError(f"weight_quant.block must be >= 1, got {block}")
 

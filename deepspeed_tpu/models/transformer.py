@@ -426,17 +426,55 @@ def _local_attention(q, k, v, cfg: TransformerConfig, causal=True, window=0):
         return out.transpose(0, 2, 1, 3)
     if cfg.use_flash_attention and cfg.attention_impl != "reference" \
             and q.shape[1] == k.shape[1]:
-        try:
-            from ..ops.flash_attention import flash_attention
+        from ..ops.flash_attention import flash_attention, pallas_enabled
 
-            return flash_attention(q, k, v, causal=causal,
-                                   block_q=cfg.flash_block_q,
-                                   block_kv=cfg.flash_block_kv,
-                                   window=window, sm_scale=cfg.attn_scale)
-        except Exception:
-            pass
+        flash = partial(flash_attention, causal=causal,
+                        block_q=cfg.flash_block_q,
+                        block_kv=cfg.flash_block_kv,
+                        window=window, sm_scale=cfg.attn_scale)
+        # kernel or XLA formulation is decided by shape and platform before
+        # the call; a kernel the compiler refuses raises, it does not give
+        # way to the O(T²) reference behind the caller's back
+        if pallas_enabled(q, k, cfg.flash_block_q, cfg.flash_block_kv):
+            return _per_device(flash, q, k, v)
+        return flash(q, k, v)
     return attention_reference(q, k, v, causal=causal, window=window,
                                scale=cfg.attn_scale)
+
+
+def _per_device(attn, q, k, v):
+    """Run ``attn(q, k, v)`` on each device's shard of the topology mesh:
+    batch over the data/fsdp axes, heads over ``tensor``. GSPMD cannot
+    partition a Mosaic kernel ("wrap the call in a shard_map"), so under a
+    mesh of more than one device the kernel call is made manual here —
+    over whichever axes an enclosing shard_map (Ulysses, the pipeline, the
+    1-bit step) has not already made manual."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..compat import shard_map
+    from ..parallel import topology as topo
+
+    if not topo.has_topology():
+        return attn(q, k, v)
+    mesh = topo.get_topology().mesh
+    manual = frozenset(jax.sharding.get_abstract_mesh().manual_axes)
+    free = {a: n for a, n in mesh.shape.items() if n > 1 and a not in manual}
+    if not free:
+        return attn(q, k, v)
+    batch = tuple(a for a in topo.BATCH_AXES if a in free)
+    heads = topo.TENSOR_AXIS if topo.TENSOR_AXIS in free else None
+    nb, nh = math.prod(free[a] for a in batch), free.get(heads, 1)
+    if q.shape[0] % nb or q.shape[2] % nh or k.shape[2] % nh:
+        raise ValueError(
+            f"flash attention over mesh {dict(mesh.shape)}: q {q.shape} / "
+            f"kv {k.shape} need batch divisible by {nb} ({batch}) and "
+            f"heads by {nh} ({heads})")
+    spec_ = P(batch or None, None, heads, None)
+    # inside an enclosing shard_map the context mesh is the only legal one
+    return shard_map(attn, mesh=None if manual else mesh,
+                     in_specs=(spec_, spec_, spec_), out_specs=spec_,
+                     axis_names=frozenset(mesh.axis_names) - manual,
+                     check_vma=False)(q, k, v)
 
 
 def _seq_parallel_size() -> int:

@@ -41,11 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas_utils import HAS_PALLAS as _HAS_PALLAS
 from .pallas_utils import on_tpu as _on_tpu
-if _HAS_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from .pallas_utils import pl, pltpu
 
 NEG_INF = -1e30
 LANES = 128        # scratch lane width for row statistics (VPU register shape)
@@ -248,7 +245,7 @@ def _pallas_ok(T, S, D, block_q, block_kv) -> bool:
     bq, bkv = _block_sizes(T, S, block_q, block_kv)
     # bq/bkv are sublane/lane-facing block dims → multiples of 128; D blocks
     # always cover the whole head dim, so any multiple of 8 is tileable.
-    return (_HAS_PALLAS and T % bq == 0 and S % bkv == 0
+    return (T % bq == 0 and S % bkv == 0
             and D % 8 == 0 and bq % 128 == 0 and bkv % 128 == 0)
 
 
@@ -455,7 +452,10 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
     return out
 
 
-def _pallas_enabled(q, k, block_q, block_kv):
+def pallas_enabled(q, k, block_q, block_kv) -> bool:
+    """Will ``flash_attention`` run the Pallas kernels for these operands
+    (tileable shape, on TPU) or the XLA formulation? Depends on T, S and D
+    only, so a batch/head shard answers as the whole does."""
     B, T, H, D = q.shape
     S = k.shape[1]
     if not _pallas_ok(T, S, D, block_q, block_kv):
@@ -466,7 +466,7 @@ def _pallas_enabled(q, k, block_q, block_kv):
 def _flash_fwd(q, k, v, causal, block_q, block_kv, window=0, sm_scale=None):
     if window and not causal:
         raise ValueError("sliding window requires causal attention")
-    if _pallas_enabled(q, k, block_q, block_kv):
+    if pallas_enabled(q, k, block_q, block_kv):
         o_hm, lse = _fwd_pallas(q, k, v, causal, block_q, block_kv, window,
                                 sm_scale, interpret=_use_interpret())
         return o_hm.transpose(0, 2, 1, 3), (q, k, v, o_hm, lse)
@@ -476,7 +476,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_kv, window=0, sm_scale=None):
 
 def _flash_bwd(causal, block_q, block_kv, window, sm_scale, res, g):
     q, k, v, o_hm, lse = res
-    if o_hm is not None and _pallas_enabled(q, k, block_q, block_kv):
+    if o_hm is not None and pallas_enabled(q, k, block_q, block_kv):
         return _bwd_pallas(q, k, v, o_hm, lse, g, causal, block_q, block_kv,
                            window, sm_scale, interpret=_use_interpret())
     _, vjp = jax.vjp(

@@ -40,11 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas_utils import HAS_PALLAS as _HAS_PALLAS
 from .pallas_utils import on_tpu as _on_tpu
-if _HAS_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from .pallas_utils import pl, pltpu
 
 NEG_INF = -1e30
 LANES = 128
@@ -66,10 +63,10 @@ def _paged_kernel(tables_ref, startp_ref, ntok_ref, slopes_ref, q_ref,
                   quant: bool):
     """One (n, kh, b) grid step: fold table block b of sequence n into the
     online softmax of its [G·C, D] query group. With ``quant`` the KV
-    pools are int8 and two extra (1, 1) SMEM operands carry this block's
-    per-(block, kv-head) dequantization scales (docs/SERVING.md "KV
-    quantization") — the block is dequantized in VMEM right after its DMA,
-    so HBM only ever holds int8."""
+    pools are int8/fp8 and two extra SMEM operands carry sequence n's
+    per-(table slot, kv-head) dequantization scales, flat [MB·KH]
+    (docs/SERVING.md "KV quantization") — the block is dequantized in
+    VMEM right after its DMA, so HBM only ever holds the 1-byte payload."""
     if quant:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
@@ -99,8 +96,9 @@ def _paged_kernel(tables_ref, startp_ref, ntok_ref, slopes_ref, q_ref,
         k = k_ref[0, 0].astype(jnp.float32)                   # [bs, D]
         v = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+            si = b * pl.num_programs(1) + kh
+            k = k * ks_ref[0, 0, si]
+            v = v * vs_ref[0, 0, si]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [G*C, bs]
         # causal + context mask: q row r is chunk pos r % C at global
@@ -182,35 +180,35 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
     kernel = functools.partial(_paged_kernel, block_size=bs, chunk=C,
                                groups=G, sm_scale=sm_scale, alibi=alibi,
                                window=window, quant=quant)
+    # index maps see every scalar-prefetch ref; only the table is used
     in_specs = [
-        pl.BlockSpec((1, 1, G * C, D),
-                     lambda n, kh, b, tbl, sp, nt, sl: (n, kh, 0, 0)),
+        pl.BlockSpec((1, 1, G * C, D), lambda n, kh, b, *_: (n, kh, 0, 0)),
         pl.BlockSpec((1, 1, bs, D),
-                     lambda n, kh, b, tbl, sp, nt, sl:
-                     (tbl[n, b], kh, 0, 0)),
+                     lambda n, kh, b, tbl, *_: (tbl[n, b], kh, 0, 0)),
         pl.BlockSpec((1, 1, bs, D),
-                     lambda n, kh, b, tbl, sp, nt, sl:
-                     (tbl[n, b], kh, 0, 0)),
+                     lambda n, kh, b, tbl, *_: (tbl[n, b], kh, 0, 0)),
     ]
     operands = [qh, k_pool, v_pool]
     if quant:
-        # per-(block, kv-head) dequant scales: one (1, 1) SMEM scalar per
-        # grid step, the index map walking the block table exactly like
-        # the KV slabs (guide: scalars are 2-D blocks in SMEM)
-        scale_spec = pl.BlockSpec((1, 1),
-                                  lambda n, kh, b, tbl, sp, nt, sl:
-                                  (tbl[n, b], kh),
-                                  memory_space=pltpu.TPUMemorySpace.SMEM)
+        # per-(block, kv-head) dequant scales, gathered through the
+        # (clamped) block table to [N, 1, MB·KH]: one SMEM row per
+        # sequence, fetched when n changes and read as a scalar at
+        # b·KH + kh. A (1, 1) block of the [NB, KH] plane breaks the TPU
+        # (8, 128)-or-whole-array block rule, and whole planes outgrow
+        # the 1 MB of SMEM with the pool; a row's size follows the table.
+        scale_spec = pl.BlockSpec((1, 1, MB * KH),
+                                  lambda n, kh, b, *_: (n, 0, 0),
+                                  memory_space=pltpu.SMEM)
         in_specs += [scale_spec, scale_spec]
-        operands += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+        operands += [
+            jnp.asarray(s, jnp.float32)[tables].reshape(N, 1, MB * KH)
+            for s in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(N, KH, MB),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G * C, D),
-                               lambda n, kh, b, tbl, sp, nt, sl:
-                               (n, kh, 0, 0)),
+                               lambda n, kh, b, *_: (n, kh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G * C, D), jnp.float32),
             pltpu.VMEM((G * C, LANES), jnp.float32),
@@ -287,7 +285,7 @@ def pallas_supported(num_heads: int, kv_heads: int, head_dim: int,
     """Static eligibility of the Pallas kernel for a head geometry — the
     single source of truth shared by the runtime dispatch below and the
     v2 module registry's heuristics (inference/v2/modules.py)."""
-    return (_HAS_PALLAS and kv_heads > 0 and num_heads % kv_heads == 0
+    return (kv_heads > 0 and num_heads % kv_heads == 0
             and head_dim % 8 == 0
             and (_on_tpu() or force_interpret or _FORCE_INTERPRET))
 

@@ -1,31 +1,23 @@
-"""Shared Pallas availability / platform probing for the kernel modules.
+"""Shared Pallas imports and platform probe for the kernel modules.
 
 Each kernel module (flash_attention, paged_attention, quantizer) keeps its
-own ``_FORCE_INTERPRET`` test hook (tests monkeypatch per module), but the
-import guard and platform probe live here so a detection fix lands once.
+own ``_FORCE_INTERPRET`` test hook (tests monkeypatch per module); the
+platform probe lives here so a detection fix lands once. Pallas ships with
+the installed JAX — a failed import is an error, not "no kernels".
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
-
-try:
-    from jax.experimental import pallas as pl                    # noqa: F401
-    from jax.experimental.pallas import tpu as pltpu             # noqa: F401
-    HAS_PALLAS = True
-    # jax < 0.5 names the TPU compiler-params dataclass TPUCompilerParams;
-    # newer jax renamed it CompilerParams. Alias the modern name so the
-    # kernels write current-jax code and still run on the floor version.
-    if not hasattr(pltpu, "CompilerParams") \
-            and hasattr(pltpu, "TPUCompilerParams"):
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
-except Exception:  # pragma: no cover
-    pl = pltpu = None
-    HAS_PALLAS = False
+from jax.experimental import pallas as pl                    # noqa: F401
+from jax.experimental.pallas import tpu as pltpu             # noqa: F401
 
 
+@functools.cache
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """Is the default backend a TPU? Decided once; a backend that fails to
+    initialize raises here instead of reading as "not on TPU" (which would
+    flip every kernel to interpret mode or its XLA formulation)."""
+    return jax.default_backend() == "tpu"

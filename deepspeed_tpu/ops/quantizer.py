@@ -28,11 +28,13 @@ group onto e4m3's dynamic range. Weight serving
 (``inference/v2/kv_quant.py``) both ride this entry point.
 
 A Pallas kernel handles the (quantize, dequantize) hot pair on TPU
-(tested in interpret mode off-TPU); the XLA formulation is the fallback
-and reference. :func:`quantized_matmul` is the serving hot op: matmul
-straight from the quantized representation — the weight tile is
-dequantized in VMEM right after its DMA on the Pallas path, and the XLA
-fallback fuses the dequant multiply into the dot's operand read; both
+(interpret mode in the CPU tests, Mosaic-compiled for a described v5e in
+``tests/test_tpu_compile.py``); the XLA formulation runs off TPU and for
+shapes with no TPU-tileable split — a rule on the shape, read before the
+call — and is the reference. :func:`quantized_matmul` is the serving hot
+op: matmul straight from the quantized representation — the Pallas path
+streams the 1-byte payload and applies the group scales in VMEM, the XLA
+formulation fuses the dequant multiply into the dot's operand read; both
 accumulate in fp32.
 """
 
@@ -45,27 +47,20 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas_utils import HAS_PALLAS as _HAS_PALLAS
 from .pallas_utils import on_tpu as _on_tpu
-if _HAS_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from .pallas_utils import pl, pltpu
 
 _FORCE_INTERPRET = False    # test hook (same pattern as flash_attention.py)
+
+
+def _use_interpret() -> bool:
+    return _FORCE_INTERPRET or not _on_tpu()
+
 
 #: max finite magnitude of float8_e4m3fn — the fp8 counterpart of
 #: ``qmax(8)``; group scale = amax / FP8_MAX maps each quant group onto
 #: the format's full dynamic range.
 FP8_MAX = 448.0
-_HAS_FP8 = hasattr(jnp, "float8_e4m3fn")
-
-
-def fp8_dtype():
-    """``jnp.float8_e4m3fn`` (raises on JAX builds without fp8 — callers
-    validate via the config surface first, so this is a backstop)."""
-    if not _HAS_FP8:
-        raise RuntimeError("this JAX build has no float8_e4m3fn dtype")
-    return jnp.float8_e4m3fn
 
 
 def qmax(bits: int) -> int:
@@ -105,7 +100,7 @@ def _quantize_xla(x, bits: int, block: int, dtype: str = "int8"):
     if dtype == "fp8_e4m3":
         scale = amax / FP8_MAX
         inv = jnp.where(scale > 0, 1.0 / scale, 0.0)
-        q = jnp.clip(xb * inv, -FP8_MAX, FP8_MAX).astype(fp8_dtype())
+        q = jnp.clip(xb * inv, -FP8_MAX, FP8_MAX).astype(jnp.float8_e4m3fn)
     else:
         scale = amax / qmax(bits)
         inv = jnp.where(scale > 0, 1.0 / scale, 0.0)
@@ -146,16 +141,39 @@ def _dequant_kernel(q_ref, s_ref, o_ref, *, block: int):
     o_ref[...] = xb.reshape(rows, n).astype(o_ref.dtype)
 
 
+#: bytes a kernel's tiles may claim of the 16 MB scoped VMEM a Mosaic
+#: kernel gets on v5e — the rest is the compiler's for fp32 temporaries
+_VMEM_TILE_BUDGET = 6 * 1024 * 1024
+
+
+def _tile(dim: int, cap: int, unit: int) -> int:
+    """Largest multiple of ``unit`` that divides ``dim`` and is <= ``cap``;
+    0 when none does."""
+    t = min(cap, dim) // unit * unit
+    while t >= unit and dim % t != 0:
+        t -= unit
+    return t
+
+
+def _row_tile(rows: int, n: int) -> int:
+    """Row tile of the (quantize, dequantize) pair, sized from ``n``: an
+    fp32 and a 1-byte [tile, n] block, each double-buffered, must fit the
+    tile budget (a fixed 256 rows asked 20 MB of the 16 MB at n = 8192).
+    Multiples of 32 — the int8 sublane tile — are preferred; 0 when no
+    multiple of 8 fits."""
+    cap = min(_VMEM_TILE_BUDGET // (10 * n), 256)
+    return _tile(rows, cap, 32) or _tile(rows, cap, 8)
+
+
 def _pallas_2d_ok(rows: int, n: int, block: int) -> bool:
-    return (_HAS_PALLAS and (_on_tpu() or _FORCE_INTERPRET)
-            and n % block == 0 and n % 128 == 0 and rows % 8 == 0)
+    return ((_on_tpu() or _FORCE_INTERPRET)
+            and n % block == 0 and n % 128 == 0 and rows % 8 == 0
+            and _row_tile(rows, n) > 0)
 
 
 def _quantize_pallas(x2, bits: int, block: int):
     rows, n = x2.shape
-    tile_r = min(rows, 256)
-    while rows % tile_r != 0:
-        tile_r -= 8
+    tile_r = _row_tile(rows, n)
     kern = functools.partial(_quant_kernel, bits=bits, block=block)
     return pl.pallas_call(
         kern,
@@ -165,15 +183,13 @@ def _quantize_pallas(x2, bits: int, block: int):
                    pl.BlockSpec((tile_r, n // block), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows, n), jnp.int8),
                    jax.ShapeDtypeStruct((rows, n // block), jnp.float32)],
-        interpret=_FORCE_INTERPRET or not _on_tpu(),
+        interpret=_use_interpret(),
     )(x2)
 
 
 def _dequantize_pallas(q2, s2, block: int, dtype):
     rows, n = q2.shape
-    tile_r = min(rows, 256)
-    while rows % tile_r != 0:
-        tile_r -= 8
+    tile_r = _row_tile(rows, n)
     kern = functools.partial(_dequant_kernel, block=block)
     return pl.pallas_call(
         kern,
@@ -182,7 +198,7 @@ def _dequantize_pallas(q2, s2, block: int, dtype):
                   pl.BlockSpec((tile_r, n // block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile_r, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, n), dtype),
-        interpret=_FORCE_INTERPRET or not _on_tpu(),
+        interpret=_use_interpret(),
     )(q2, s2)
 
 
@@ -254,49 +270,72 @@ def dequantize_blockwise(q, scales, block: Optional[int] = None,
 
 # ------------------------------------------------- quantized matmul (serving)
 
-def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, *, block: int):
-    """One (i, j) grid step: ``x`` tile [bm, K] × weight tile [K, bn].
-    The quantized weight tile is dequantized in VMEM right after its DMA
-    (q · broadcast scale) and the dot accumulates in fp32 — HBM only
-    ever holds the 1-byte payload + the f32 scale plane."""
-    x = x_ref[...].astype(jnp.float32)                       # [bm, K]
-    qw = q_ref[...].astype(jnp.float32)                      # [K, bn]
-    s = s_ref[...]                                           # [K, bn/B]
-    k, bn = qw.shape
-    w = (qw.reshape(k, bn // block, block)
-         * s[:, :, None]).reshape(k, bn)
-    o_ref[...] = lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, block: int):
+    """One (i, j, kk) grid step: fold ``x`` tile [bm, bk] × weight tile
+    [bk, bn] into the fp32 accumulator. The scale of weight row k in
+    column group g is applied to *x's column k* — ``(x · s_g) @ q_g`` is
+    the same sum as ``x @ (q_g · s_g)``, and a [1, bk] scale row
+    broadcasts over x's sublanes where a [bk, 1] column would need a
+    (bk, 1) block the TPU lowering refuses. HBM only ever holds the
+    1-byte payload + the f32 scale plane."""
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    x = x_ref[...].astype(jnp.float32)                       # [bm, bk]
+    for g in range(q_ref.shape[1] // block):
+        cols = slice(g * block, (g + 1) * block)
+        acc_ref[:, cols] += lax.dot_general(
+            x * s_ref[g], q_ref[:, cols].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _flush():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _qmm_tiles(m: int, k: int, n: int, block: int):
+    """(bm, bk, bn) for the Pallas matmul, or None when the shape has no
+    TPU-tileable split: bm | m in sublane multiples; bn | n in whole
+    lane-aligned scale groups; bk | k in lane multiples (x's minor dim),
+    or all of a short k."""
+    if block % 128 or n % block or m % 8 or k % 8:
+        return None
+    bm = _tile(m, 256, 8)
+    bn = _tile(n, 512, block)
+    bk = _tile(k, 1024, 128) or (k if k <= 1024 else 0)
+    return (bm, bk, bn) if bm and bn and bk else None
 
 
 def _qmm_pallas_ok(m: int, k: int, n: int, block: int) -> bool:
-    return (_HAS_PALLAS and (_on_tpu() or _FORCE_INTERPRET)
-            and n % block == 0 and n % 128 == 0 and k % 8 == 0
-            and m % 8 == 0)
+    return ((_on_tpu() or _FORCE_INTERPRET)
+            and _qmm_tiles(m, k, n, block) is not None)
 
 
 def _qmm_pallas(x2, q, s, block: int, out_dtype):
     m, k = x2.shape
     n = q.shape[-1]
-    bm = min(m, 256)
-    while m % bm != 0:
-        bm -= 8
-    bn = 128
-    while bn % block != 0:          # scale groups must tile the N tile
-        bn += 128
-    bn = min(bn, n)
+    bm, bk, bn = _qmm_tiles(m, k, n, block)
+    # scale plane [K, n/B] -> [n/B, 1, K]: a tile's groups are whole
+    # leading rows and each row lies along lanes, like x's columns
+    st = s.T.reshape(n // block, 1, k)
     kern = functools.partial(_qmm_kernel, block=block)
     return pl.pallas_call(
         kern,
-        grid=(m // bm, n // bn),
-        in_specs=[pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-                  pl.BlockSpec((k, bn), lambda i, j: (0, j)),
-                  pl.BlockSpec((k, bn // block), lambda i, j: (0, j))],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        grid=(m // bm, n // bn, k // bk),
+        in_specs=[pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+                  pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
+                  pl.BlockSpec((bn // block, 1, bk),
+                               lambda i, j, kk: (j, 0, kk))],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        interpret=_FORCE_INTERPRET or not _on_tpu(),
-    )(x2, q, s)
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_use_interpret(),
+    )(x2, q, st)
 
 
 def quantized_matmul(x, q, scales, block: Optional[int] = None,
@@ -305,12 +344,13 @@ def quantized_matmul(x, q, scales, block: Optional[int] = None,
     accumulation — the weight-serving hot op (int8/fp8 weights,
     ``inference/v2/weight_quant.py``).
 
-    Pallas path: tiled matmul whose weight tile dequantizes in VMEM
-    (HBM traffic is the 1-byte payload — the point of weight
-    quantization on memory-bound decode). XLA fallback: dequantize-
-    then-dot, where the dequant multiply fuses into the dot's operand
-    read. Both paths produce identical values (dequantization is exact
-    and both accumulate in fp32).
+    Pallas path (TPU, shapes ``_qmm_tiles`` accepts): tiled matmul that
+    reads the 1-byte payload straight from HBM — the point of weight
+    quantization on memory-bound decode — and applies the group scales
+    in VMEM. XLA formulation (off TPU, or shapes with no tileable
+    split): dequantize-then-dot, where the dequant multiply fuses into
+    the dot's operand read. Both accumulate in fp32 and agree to fp32
+    rounding.
     """
     out_dtype = out_dtype or x.dtype
     kdim, n = q.shape
@@ -319,7 +359,7 @@ def quantized_matmul(x, q, scales, block: Optional[int] = None,
     m = 1
     for d in lead:
         m *= d
-    if m > 0 and n % block == 0 and _qmm_pallas_ok(m, kdim, n, block):
+    if m > 0 and _qmm_pallas_ok(m, kdim, n, block):
         out2 = _qmm_pallas(x.reshape(m, kdim), q,
                            scales.astype(jnp.float32), block, out_dtype)
         return out2.reshape(*lead, n)

@@ -723,6 +723,7 @@ class DeepSpeedTpuEngine:
         self._update_raw = update
         self._finalize_raw = finalize_offload if offload_plan is not None else None
         self._layouts_tuned = False
+        self._state_formats = None      # the formats the autotune pinned
         self._micro_fn = jax.jit(
             micro,
             out_shardings=(state_shardings, plan.replicated()),
@@ -761,58 +762,56 @@ class DeepSpeedTpuEngine:
         role (it has no direct equivalent — CUDA torch controls layouts
         explicitly)."""
         self._layouts_tuned = True
-        try:
-            from jax.experimental.layout import Format, Layout
-        except Exception:
+        if jax.default_backend() != "tpu":
             return
-        if jax.devices()[0].platform != "tpu":
-            return
-        try:
-            ss = self._state_shardings
-            is_shard = lambda x: isinstance(x, jax.sharding.Sharding)
-            auto_state = jax.tree.map(lambda s: Format(Layout.AUTO, s), ss,
-                                      is_leaf=is_shard)
-            rep = self.plan.replicated()
-            micro_auto = jax.jit(
-                self._micro_raw,
-                in_shardings=(auto_state, None, None),
-                out_shardings=(auto_state, rep),
+        from jax.experimental.layout import Format, Layout
+
+        # a failure here raises: training on at default layouts would cost
+        # ~3x step time (above) without anything saying so
+        ss = self._state_shardings
+        is_shard = lambda x: isinstance(x, jax.sharding.Sharding)
+        auto_state = jax.tree.map(lambda s: Format(Layout.AUTO, s), ss,
+                                  is_leaf=is_shard)
+        rep = self.plan.replicated()
+        micro_auto = jax.jit(
+            self._micro_raw,
+            in_shardings=(auto_state, None, None),
+            out_shardings=(auto_state, rep),
+            donate_argnums=(0,))
+        # AUTO layouts require abstract (ShapeDtypeStruct) args to lower.
+        avals = jax.eval_shape(lambda s, b, r: (s, b, r),
+                               self.state, batch, rng)
+        compiled = micro_auto.lower(*avals).compile()
+        out_state_fmt = compiled.output_formats[0]
+        # Move the live state into the preferred layouts (one-time cost)
+        # and pin every step program to them.
+        self.state = jax.device_put(self.state, out_state_fmt)
+        self._micro_fn = jax.jit(
+            self._micro_raw,
+            in_shardings=(out_state_fmt, None, None),
+            out_shardings=(out_state_fmt, rep),
+            donate_argnums=(0,))
+        if self._finalize_raw is not None:
+            self._finalize_fn = jax.jit(
+                self._finalize_raw,
+                in_shardings=(out_state_fmt,),
+                out_shardings=(out_state_fmt, None, None),
                 donate_argnums=(0,))
-            # AUTO layouts require abstract (ShapeDtypeStruct) args to lower.
-            avals = jax.eval_shape(lambda s, b, r: (s, b, r),
-                                   self.state, batch, rng)
-            compiled = micro_auto.lower(*avals).compile()
-            out_state_fmt = compiled.output_formats[0]
-            # Move the live state into the preferred layouts (one-time cost)
-            # and pin every step program to them.
-            self.state = jax.device_put(self.state, out_state_fmt)
-            self._micro_fn = jax.jit(
-                self._micro_raw,
-                in_shardings=(out_state_fmt, None, None),
-                out_shardings=(out_state_fmt, rep),
+        else:
+            self._update_fn = jax.jit(
+                self._update_raw,
+                in_shardings=(out_state_fmt,),
+                out_shardings=(out_state_fmt, None),
                 donate_argnums=(0,))
-            if self._finalize_raw is not None:
-                self._finalize_fn = jax.jit(
-                    self._finalize_raw,
-                    in_shardings=(out_state_fmt,),
-                    out_shardings=(out_state_fmt, None, None),
-                    donate_argnums=(0,))
-            else:
-                self._update_fn = jax.jit(
-                    self._update_raw,
+            if getattr(self, "_onebit", False):
+                self._update_warm_fn = jax.jit(
+                    self._update_warm_raw,
                     in_shardings=(out_state_fmt,),
                     out_shardings=(out_state_fmt, None),
                     donate_argnums=(0,))
-                if getattr(self, "_onebit", False):
-                    self._update_warm_fn = jax.jit(
-                        self._update_warm_raw,
-                        in_shardings=(out_state_fmt,),
-                        out_shardings=(out_state_fmt, None),
-                        donate_argnums=(0,))
-            log_dist("layout autotune: state pinned to XLA-preferred formats",
-                     ranks=[0])
-        except Exception as exc:  # pragma: no cover - depends on backend
-            logger.warning(f"layout autotune skipped: {exc}")
+        self._state_formats = out_state_fmt
+        log_dist("layout autotune: state pinned to XLA-preferred formats",
+                 ranks=[0])
 
     # ------------------------------------------------------------- data plumbing
     def deepspeed_io(self, dataset, batch_size=None, collate_fn=None,

@@ -23,6 +23,12 @@ exercises the cross-frontend failover path on every peer.
                       {"enabled": true, "peers": [...]}} ...}
     }
 
+One process per chip: this process builds its local engines on the
+accelerator it finds, and a chip belongs to one process. Replica servers
+it fronts on the same host (``scripts/serve_replica.py``) each need a
+chip of their own — one started on the chip this process holds exits
+with an error instead of serving.
+
 Seeded init keeps byte-parity testable across frontends: every frontend
 (and every replica server) built from the same spec holds identical
 weights, so greedy streams must match to the token no matter which
@@ -47,7 +53,11 @@ sys.path.insert(0, _REPO)
 
 
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap = argparse.ArgumentParser(
+        description=__doc__.splitlines()[0],
+        epilog="One process per chip: this process's local engines hold "
+               "the accelerator it finds; every serve_replica.py process "
+               "it fronts on the same host needs a chip of its own.")
     ap.add_argument("--spec", required=True, help="spec JSON path")
     args = ap.parse_args(argv)
 

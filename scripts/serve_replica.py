@@ -7,6 +7,12 @@ builds can be a TP-sharded mesh slice spanning that host's chips — and
 serves the fabric RPC protocol (deepspeed_tpu/serving/fabric/server.py)
 for a frontend to adopt as a :class:`RemoteHandle` replica.
 
+One process per chip: a chip belongs to the first process that touches
+JAX on it, so each replica process needs a chip (or a host's worth of
+chips) of its own, not one that a frontend with local engines or another
+replica already holds. A replica that finds its chip taken exits with
+code 3 and says so; it does not wait for the chip.
+
     python scripts/serve_replica.py --spec spec.json \
         [--listen 127.0.0.1:0] [--replica-id 0] [--heartbeat-s 1.0]
 
@@ -83,6 +89,19 @@ def main(argv=None) -> int:
     from deepspeed_tpu.serving.config import ServingConfig
     from deepspeed_tpu.serving.fabric.server import ReplicaServer
     from deepspeed_tpu.serving.fabric.transport import advertised_address
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    try:
+        jax.devices()
+    except RuntimeError as e:
+        # libtpu refuses a second process on a held chip at once ("Unable
+        # to initialize backend 'tpu': ... libtpu multi-process lockfile")
+        print("serve_replica: no accelerator for this process — a chip "
+              "belongs to one process, and each replica server (and a "
+              "frontend that builds local engines) needs one of its own: "
+              f"{e}", file=sys.stderr)
+        return 3
 
     mesh = None
     if spec.get("mesh"):

@@ -179,3 +179,98 @@ def test_sliding_window_cross_length():
     ref = fa._attention_xla(q, k, v, True, 100)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------------- the model's flash call
+
+def _attn_cfg(**kw):
+    import dataclasses
+
+    from deepspeed_tpu.models.transformer import TINY_TEST
+
+    return dataclasses.replace(TINY_TEST, flash_block_q=128,
+                               flash_block_kv=128, **kw)
+
+
+def test_local_attention_propagates_kernel_failure(monkeypatch):
+    """A kernel that fails must fail the step — not hand the call to the
+    O(T²) reference behind the caller's back (the old try/except)."""
+    from deepspeed_tpu.models import transformer as tr
+
+    def boom(*a, **kw):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(fa, "flash_attention", boom)
+    q = _rand((2, 128, 4, 16), 0)
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        tr._local_attention(q, q, q, _attn_cfg())
+
+
+def _mesh_qkv(mesh_axes, B=4, T=128, H=4, KH=2, D=16):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.parallel import topology as topo
+
+    t = topo.MeshTopology.build(**mesh_axes)
+    topo.set_topology(t)
+    sh = NamedSharding(t.mesh, P(topo.BATCH_AXES, None, "tensor", None))
+    q = jax.device_put(_rand((B, T, H, D), 0), sh)
+    k = jax.device_put(_rand((B, T, KH, D), 1), sh)
+    v = jax.device_put(_rand((B, T, KH, D), 2), sh)
+    return t, q, k, v
+
+
+@pytest.mark.parametrize("mesh_axes", [
+    {"data": 2, "fsdp": 2, "tensor": 2}, {"data": 1, "fsdp": 8},
+    {"data": 4, "tensor": 2}])
+def test_flash_call_is_per_device_under_a_mesh(mesh_axes, devices8):
+    """GSPMD cannot partition a Mosaic kernel, so under a mesh of more
+    than one device the model's flash call is shard_mapped: batch over
+    data/fsdp, heads over tensor — forward and grads match the reference
+    and the output keeps the operands' sharding."""
+    from deepspeed_tpu.models import transformer as tr
+
+    _, q, k, v = _mesh_qkv(mesh_axes, B=8)
+    cfg = _attn_cfg()
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+    flash = lambda q, k, v: tr._local_attention(q, k, v, cfg)   # noqa: E731
+    ref = lambda q, k, v: tr.attention_reference(q, k, v)       # noqa: E731
+    out = jax.jit(flash)(q, k, v)
+    assert out.sharding.is_equivalent_to(q.sharding, out.ndim)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    gp = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_flash_call_inside_a_manual_axis(devices8):
+    """Inside an enclosing shard_map (the pipeline's manual ``pipe`` axis)
+    the flash call maps the axes still automatic, on the context mesh."""
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.compat import shard_map
+    from deepspeed_tpu.models import transformer as tr
+
+    t, q, k, v = _mesh_qkv({"pipe": 2, "data": 2, "tensor": 2})
+    cfg = _attn_cfg()
+    staged = shard_map(lambda q, k, v: tr._local_attention(q, k, v, cfg),
+                       mesh=t.mesh, in_specs=P(), out_specs=P(),
+                       axis_names={"pipe"}, check_vma=False)
+    out = jax.jit(staged)(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(tr.attention_reference(q, k, v)),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_flash_call_names_an_indivisible_shape(devices8):
+    from deepspeed_tpu.models import transformer as tr
+
+    _, q, k, v = _mesh_qkv({"data": 1, "fsdp": 8}, B=8)
+    with pytest.raises(ValueError, match=r"batch divisible by 8"):
+        tr._local_attention(q[:4], k[:4], v[:4], _attn_cfg())

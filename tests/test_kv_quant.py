@@ -824,14 +824,28 @@ def test_phase_runner_skip_and_budget(tmp_path, monkeypatch):
     runner2 = bench.PhaseRunner()
     cached = runner2.run("quick", lambda: {"x": 2})
     assert cached["x"] == 1 and cached["phase_cached"]
-    # backend loss short-circuits later phases with an explicit stamp
+    # a phase that raises is stamped AND listed as failed (main exits
+    # non-zero on it); it does not stop the phases after it
     monkeypatch.delenv("BENCH_RESUME", raising=False)
     runner3 = bench.PhaseRunner()
 
     def die():
-        raise RuntimeError("UNAVAILABLE: tunnel gone")
+        raise RuntimeError("kernel refused")
 
     out = runner3.run("dead", die)
-    assert out["phase_skipped"].startswith("tpu_backend_lost")
-    out2 = runner3.run("after", lambda: {"x": 3})
-    assert out2["phase_skipped"].startswith("tpu_backend_lost")
+    assert "RuntimeError: kernel refused" in out["phase_skipped"]
+    assert runner3.run("after", lambda: {"x": 3})["x"] == 3
+    assert runner3.failed == ["dead"] and runner.failed == ["wedge"]
+    assert bench._verdict({"failed_phases": runner3.failed}) == 1
+    assert bench._verdict({"failed_phases": []}) == 0
+    # a phase that spawns replica processes is skipped by name where this
+    # process holds the chip — stated in the output, not a failure
+    own = runner3.run("fabric", die, needs_own_chip=True)
+    assert "a chip belongs to one process" in own["phase_skipped"]
+    assert runner3.failed == ["dead"]
+    # an unknown device kind is an error, not an assumed peak
+    class _Dev:
+        device_kind = "Quantum v9"
+    monkeypatch.setattr(bench.jax, "devices", lambda: [_Dev()])
+    with pytest.raises(ValueError, match="quantum v9"):
+        bench.detect_peak()

@@ -8,18 +8,11 @@ import jax
 import jax.numpy as jnp
 
 import deepspeed_tpu
-from deepspeed_tpu.compat import PARTIAL_AUTO_SHARD_MAP
 from deepspeed_tpu.moe import MoE, TopKGate, top1gating, top2gating
 from deepspeed_tpu.moe.sharded_moe import moe_dispatch_combine, _capacity
 from deepspeed_tpu.models import build_model
 from deepspeed_tpu.models.transformer import TINY_TEST, CausalLM
 import dataclasses
-
-
-_partial_auto = pytest.mark.skipif(
-    not PARTIAL_AUTO_SHARD_MAP,
-    reason="installed jax lacks usable partial-auto shard_map "
-           "(no eager impl / PartitionId under CPU SPMD)")
 
 
 def test_capacity():
@@ -199,7 +192,6 @@ def test_dropless_causal_lm_trains(devices8):
     assert losses[-1] < losses[0], losses
 
 
-@_partial_auto
 def test_dropless_ep_matches_single_shard(devices8):
     """Expert-parallel dropless (gather → per-shard ragged_dot →
     psum_scatter under the partial-manual expert shard_map) reproduces the
@@ -231,7 +223,6 @@ def test_dropless_ep_matches_single_shard(devices8):
     np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-5)
 
 
-@_partial_auto
 def test_dropless_ep_no_gate_and_imbalance(devices8):
     """EP dropless without SwiGLU, all tokens on one expert shard: no
     token dropped, other shard contributes exact zeros."""
@@ -259,7 +250,6 @@ def test_dropless_ep_no_gate_and_imbalance(devices8):
     assert (np.abs(np.asarray(out)).sum(axis=-1) > 0).all()
 
 
-@_partial_auto
 def test_dropless_ep_causal_lm_matches_capacity_loss(devices8):
     """A dropless-EP CausalLM on an expert=2 mesh trains, and its loss
     matches the capacity path at a capacity factor high enough that no

@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 
 import deepspeed_tpu
-from deepspeed_tpu.compat import PARTIAL_AUTO_SHARD_MAP
 from deepspeed_tpu.models.transformer import CausalLM, TINY_TEST
 from deepspeed_tpu.parallel import topology as topo
 from deepspeed_tpu.parallel.pipeline import pipelined_layer_apply
@@ -22,12 +21,6 @@ from deepspeed_tpu.runtime.pipe.module import partition_balanced
 
 
 # ---------------------------------------------------------------- topology
-_partial_auto = pytest.mark.skipif(
-    not PARTIAL_AUTO_SHARD_MAP,
-    reason="installed jax lacks usable partial-auto shard_map "
-           "(no eager impl / PartitionId under CPU SPMD)")
-
-
 def test_process_topology_rank_mapping():
     t = ProcessTopology(axes=["pipe", "data"], dims=[2, 4])
     assert t.world_size() == 8
@@ -110,7 +103,6 @@ def test_pipeline_module_stage_assignment():
 
 
 # ---------------------------------------------------------- SPMD execution
-@_partial_auto
 def test_spmd_pipeline_matches_sequential():
     """Pipelined layer apply must equal the plain scan."""
     t = topo.MeshTopology.build(pipe=4, data=-1)
@@ -134,7 +126,6 @@ def test_spmd_pipeline_matches_sequential():
                                rtol=2e-5, atol=2e-5)
 
 
-@_partial_auto
 def test_spmd_pipeline_grads_match():
     t = topo.MeshTopology.build(pipe=2, data=-1)
     topo.set_topology(t)
@@ -162,7 +153,6 @@ def test_spmd_pipeline_grads_match():
                                rtol=2e-4, atol=2e-5)
 
 
-@_partial_auto
 def test_engine_trains_with_pipeline_parallel():
     cfg = dataclasses.replace(TINY_TEST, num_kv_heads=4)
     model = CausalLM(cfg)
@@ -193,7 +183,6 @@ def test_engine_trains_with_pipeline_parallel():
     assert losses[-1] < losses[0]
 
 
-@_partial_auto
 def test_pipeline_matches_unpipelined_loss():
     cfg = dataclasses.replace(TINY_TEST, num_kv_heads=4, pipeline_microbatches=2)
     model = CausalLM(cfg)
@@ -212,7 +201,6 @@ def test_pipeline_matches_unpipelined_loss():
     np.testing.assert_allclose(loss_pp, loss_dense, rtol=1e-4)
 
 
-@_partial_auto
 def test_pipeline_moe_aux_loss_nonzero():
     """MoE aux loss must flow out of the pipelined path (not silently zero)."""
     cfg = dataclasses.replace(TINY_TEST, num_kv_heads=4, moe_num_experts=4,

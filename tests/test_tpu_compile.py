@@ -1,0 +1,193 @@
+"""Mosaic compiles of every Pallas entry point for a *described* TPU v5e.
+
+Interpret mode (the rest of the kernel tests) cannot see what the chip's
+compiler refuses: a block that breaks the (8, 128)-or-whole-array rule,
+a tile set over the 16 MB of scoped VMEM, a kernel GSPMD is asked to
+partition. libtpu is installed here and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``), so each kernel
+is lowered with ``interpret=False`` at Pythia-1.4B shapes (hidden 2048,
+ffn 8192, 16 heads × 128, vocab 50304) and compiled — nothing runs, so
+these say nothing about results or times. ``chip_smoke.py`` checks the
+numbers on the chip.
+
+Skipped only where the topology cannot be described. The persistent
+compile cache is off around them: an entry written for an unattached chip
+cannot be read back and warns on every later run.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import NamedSharding, SingleDeviceSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
+
+from deepspeed_tpu.ops import flash_attention as fa  # noqa: E402
+from deepspeed_tpu.ops import paged_attention as pa  # noqa: E402
+from deepspeed_tpu.ops import pallas_utils  # noqa: E402
+from deepspeed_tpu.ops import quantizer as qz  # noqa: E402
+
+FP8 = jnp.float8_e4m3fn
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2").devices
+    except Exception as e:  # no libtpu / unknown topology on this host
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes, sharding):
+    """Compile ``fn`` for the described chip; returns the optimized HLO."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "the Pallas kernel is not in the program"
+    return text
+
+
+# ------------------------------------------------------------------- flash
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("block", [512, 1024])
+def test_flash_fwd_bwd(v5e, dtype, block):
+    qkv = ((8, 2048, 16, 128), dtype)
+
+    def loss(q, k, v):
+        o_hm, lse = fa._fwd_pallas(q, k, v, True, block, block, 0,
+                                   interpret=False)
+        g = o_hm.transpose(0, 2, 1, 3)
+        return fa._bwd_pallas(q, k, v, o_hm, lse, g, True, block, block, 0,
+                              interpret=False)
+
+    text = _compile(loss, qkv, qkv, qkv, sharding=SingleDeviceSharding(v5e[0]))
+    assert text.count("tpu_custom_call") >= 3        # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("mesh_axes", [{"fsdp": 4}, {"tensor": 4},
+                                       {"data": 2, "tensor": 2}])
+def test_flash_call_on_a_four_device_mesh(v5e, mesh_axes, monkeypatch):
+    """The model's own flash call (``_local_attention``), operands sharded
+    over a four-chip mesh, forward and grad — under plain jit this is
+    "Mosaic kernels cannot be automatically partitioned"."""
+    from deepspeed_tpu.models import transformer as tr
+    from deepspeed_tpu.parallel import topology as topo
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    t = topo.MeshTopology.build(devices=v5e, **{"data": 1, **mesh_axes})
+    topo.set_topology(t)
+    sh = NamedSharding(t.mesh, P(topo.BATCH_AXES, None, "tensor", None))
+    qkv = ((8, 2048, 16, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        o = tr._local_attention(q, k, v, tr.PYTHIA_1B4)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv,
+                    sharding=sh)
+    # each device runs the kernel on its own batch/head shard: no operand
+    # is gathered to get there
+    assert "all-gather" not in text and "all-to-all" not in text
+
+
+# ------------------------------------------------------------------- paged
+
+@pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.float32, jnp.int8, FP8],
+                         ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("chunk", [1, 8, 256])
+def test_paged_attention(v5e, pool, chunk):
+    N, H, KH, D, bs, MB, NB = 8, 16, 16, 128, 64, 32, 512
+    quant = pool in (jnp.int8, FP8)
+    qdt = jnp.float32 if pool == jnp.float32 else jnp.bfloat16
+    shapes = [((N, chunk, H, D), qdt), ((NB, KH, bs, D), pool),
+              ((NB, KH, bs, D), pool), ((N, MB), jnp.int32),
+              ((N,), jnp.int32), ((N,), jnp.int32)]
+    if quant:
+        shapes += [((NB, KH), jnp.float32)] * 2      # the scale planes
+
+    def attend(q, k, v, tbl, sp, nt, *scales):
+        kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+        return pa._paged_pallas(q, k, v, tbl, sp, nt, interpret=False, **kw)
+
+    _compile(attend, *shapes, sharding=SingleDeviceSharding(v5e[0]))
+
+
+# --------------------------------------------------------------- quantizer
+
+_PROJ = [(2048, 8192), (8192, 2048), (2048, 2048), (2048, 50304)]
+
+
+@pytest.fixture
+def qz_on_tpu(monkeypatch):
+    # the quantizer's kernels take interpret from on_tpu(); the CPU backend
+    # answers no, so the test steers it — not an option of the program
+    monkeypatch.setattr(qz, "_on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("payload", [jnp.int8, FP8],
+                         ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("k,n", _PROJ)
+@pytest.mark.parametrize("m", [8, 256, 2048])
+def test_quantized_matmul(v5e, qz_on_tpu, m, k, n, payload):
+    _compile(lambda x, q, s: qz._qmm_pallas(x, q, s, 128, jnp.bfloat16),
+             ((m, k), jnp.bfloat16), ((k, n), payload),
+             ((k, n // 128), jnp.float32),
+             sharding=SingleDeviceSharding(v5e[0]))
+
+
+# stacked [L·in, out] is what the weight-quant build and ZeRO++ really pass
+@pytest.mark.parametrize("rows,n", _PROJ + [(24 * 2048, 8192)])
+def test_block_quantize_dequantize(v5e, qz_on_tpu, rows, n):
+    one = SingleDeviceSharding(v5e[0])
+    _compile(lambda x: qz._quantize_pallas(x, 8, 128),
+             ((rows, n), jnp.float32), sharding=one)
+    _compile(lambda q, s: qz._dequantize_pallas(q, s, 128, jnp.bfloat16),
+             ((rows, n), jnp.int8), ((rows, n // 128), jnp.float32),
+             sharding=one)
+
+
+def test_untileable_shapes_are_decided_before_the_call(qz_on_tpu):
+    """Which formulation runs is a rule on the shape, read before the
+    call — never a compile that failed and was caught: a row too long for
+    any VMEM tile, or scale groups that are not lane-aligned."""
+    assert qz._pallas_2d_ok(2048, 8192, 128)
+    assert not qz._pallas_2d_ok(8, 1 << 21, 128)
+    assert qz._qmm_pallas_ok(8, 2048, 8192, 128)
+    assert not qz._qmm_pallas_ok(8, 2048, 8192, 64)
+
+
+# ---------------------------------------------------------------- platform
+
+def test_on_tpu_propagates_a_backend_error(monkeypatch):
+    """A backend that cannot initialize is an error, not "not on TPU" —
+    that answer flips every kernel to interpret mode or the XLA path."""
+    def dead():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "default_backend", dead)
+    pallas_utils.on_tpu.cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            pallas_utils.on_tpu()
+    finally:
+        monkeypatch.undo()
+        pallas_utils.on_tpu.cache_clear()
+    assert pallas_utils.on_tpu() is (jax.default_backend() == "tpu")
