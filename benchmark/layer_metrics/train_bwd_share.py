@@ -1,0 +1,7 @@
+"""Backward pass (gradient accumulation included), share of device busy time in percent."""
+
+from benchmark import scopes
+
+
+def reduce(ctx):
+    return scopes.train_share(ctx, "bwd")

@@ -178,6 +178,17 @@ class InferenceEngineV2:
         self.batch = RaggedBatchWrapper(self.config.max_ragged_sequence_count,
                                         self.config.max_chunk_tokens,
                                         max_blocks_per_seq)
+        # what the last put staged, counted where the work happens (plain
+        # ints; the scheduler copies them into its span attrs when traced):
+        # the bucket [S, C] the forward ran at, its real rows and valid
+        # tokens, the keys those rows' queries may see and the query-key
+        # pairs (the paged kernel's bytes and FLOPs follow from them), and
+        # the pool's available blocks after allocation
+        self.last_put: Dict[str, int] = {}
+        # the same, cumulative since the engine was built (pad ratio over
+        # any interval = delta positions_computed / delta tokens_valid)
+        self.put_totals: Dict[str, int] = {
+            "forwards": 0, "positions_computed": 0, "tokens_valid": 0}
 
     def _build_state_manager(self) -> DSStateManager:
         """Fresh sequence registry + KV pools from the current config —
@@ -263,14 +274,29 @@ class InferenceEngineV2:
 
         self.batch.clear()
         staged = []
+        valid = kv_read = qk_pairs = 0
         for uid, toks in zip(uids, tokens_list):
             seq = self.state_manager.get_or_create_sequence(uid)
             self.state_manager.maybe_allocate_kv(seq, len(toks))
             self.batch.insert_sequence(uid, list(toks), seq.seen_tokens,
                                        seq.kv_blocks)
             staged.append((seq, toks))
+            n, seen = len(toks), seq.seen_tokens
+            valid += n
+            kv_read += seen + n
+            qk_pairs += n * seen + n * (n + 1) // 2
 
         arrays = self.batch.finalize()
+        bucket_seqs, bucket_chunk = arrays["tokens"].shape
+        self.last_put = {
+            "bucket_seqs": bucket_seqs, "bucket_chunk": bucket_chunk,
+            "rows": len(staged), "valid_tokens": valid,
+            "kv_read_tokens": kv_read, "qk_pairs": qk_pairs,
+            "free_blocks": self.state_manager.available_blocks}
+        totals = self.put_totals
+        totals["forwards"] += 1
+        totals["positions_computed"] += bucket_seqs * bucket_chunk
+        totals["tokens_valid"] += valid
         args = (self.params, self.state_manager.kv_cache,
                 jnp.asarray(arrays["tokens"]),
                 jnp.asarray(arrays["start_pos"]),

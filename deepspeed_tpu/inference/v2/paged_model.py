@@ -150,38 +150,35 @@ class PagedCausalLM:
         NB = kv_cache["k"].shape[1]
         MB = block_tables.shape[1]
         dt = cfg.dtype
+        # Program scopes (docs/OBSERVABILITY.md "XLA alignment"): every
+        # operation carries in its HLO op_name the part of the program
+        # that caused it — embed, layers{attn_norm, qkv, kv_write, attend,
+        # attn_out, mlp}, final_norm, logits — the vocabulary
+        # models/transformer.py shares. What runs under ``layers`` but
+        # under none of the block's scopes is the scan's own plumbing:
+        # slices of the stacked weights and pools, the pools written back.
+        scope = jax.named_scope
 
-        x = params["embed"]["wte"][tokens].astype(dt)          # [N, C, H]
-        if cfg.embedding_layernorm:
-            x = _norm(x, params["embed"]["ln_w"],
-                      params["embed"].get("ln_b"), cfg.norm, cfg.norm_eps)
-        positions = start_pos[:, None] + jnp.arange(C)[None, :]  # [N, C]
-        slopes = None
-        if cfg.position == "rope":
-            cos_full, sin_full = rope_table(cfg.max_seq_len, cfg.rot_dim,
-                                            cfg.rope_theta)
-            cos = cos_full[positions]                           # [N, C, R/2]
-            sin = sin_full[positions]
-        elif cfg.position == "alibi":
-            # bias applied inside the paged kernel (slope · kv_position)
-            slopes = alibi_slopes(cfg.num_heads)
-            cos = sin = None
-        else:
-            x = x + params["embed"]["wpe"][positions].astype(dt)
-            cos = sin = None
-
-        valid = jnp.arange(C)[None, :] < n_tokens[:, None]      # [N, C]
-
-        # scatter coordinates for KV writes: (pool block, slot-in-block)
-        blk_idx = positions // bs                               # [N, C]
-        blk_off = positions % bs
-        blk_ids = jnp.take_along_axis(
-            block_tables, jnp.clip(blk_idx, 0, MB - 1), axis=1)  # [N, C]
-        # invalid tokens → sentinel NB: a *positive* out-of-range id, which
-        # mode="drop" really drops (-1 would wrap to pool block NB-1 — JAX
-        # normalizes negative scatter indices before the bounds check)
-        write_blk = jnp.where(valid & (blk_ids >= 0), blk_ids, NB).reshape(-1)
-        write_off = blk_off.reshape(-1)
+        with scope("embed"):
+            x = params["embed"]["wte"][tokens].astype(dt)      # [N, C, H]
+            if cfg.embedding_layernorm:
+                x = _norm(x, params["embed"]["ln_w"],
+                          params["embed"].get("ln_b"), cfg.norm,
+                          cfg.norm_eps)
+            positions = start_pos[:, None] + jnp.arange(C)[None, :]  # [N, C]
+            slopes = None
+            if cfg.position == "rope":
+                cos_full, sin_full = rope_table(cfg.max_seq_len, cfg.rot_dim,
+                                                cfg.rope_theta)
+                cos = cos_full[positions]                       # [N, C, R/2]
+                sin = sin_full[positions]
+            elif cfg.position == "alibi":
+                # bias applied inside the paged kernel (slope · kv_position)
+                slopes = alibi_slopes(cfg.num_heads)
+                cos = sin = None
+            else:
+                x = x + params["embed"]["wpe"][positions].astype(dt)
+                cos = sin = None
 
         # int8 KV quantization (kv_quant.py, docs/SERVING.md "KV
         # quantization"): detected from the cache pytree so the disabled
@@ -189,11 +186,26 @@ class PagedCausalLM:
         # block plan is layer-invariant — computed once, closed over by
         # every scanned layer body.
         quant = "k_scale" in kv_cache
-        if quant:
-            from .kv_quant import quantized_block_write, touched_block_plan
+        with scope("kv_write"):
+            # scatter coordinates for KV writes: (pool block, slot-in-block)
+            valid = jnp.arange(C)[None, :] < n_tokens[:, None]  # [N, C]
+            blk_idx = positions // bs                           # [N, C]
+            blk_off = positions % bs
+            blk_ids = jnp.take_along_axis(
+                block_tables, jnp.clip(blk_idx, 0, MB - 1), axis=1)  # [N, C]
+            # invalid tokens → sentinel NB: a *positive* out-of-range id,
+            # which mode="drop" really drops (-1 would wrap to pool block
+            # NB-1 — JAX normalizes negative scatter indices before the
+            # bounds check)
+            write_blk = jnp.where(valid & (blk_ids >= 0), blk_ids,
+                                  NB).reshape(-1)
+            write_off = blk_off.reshape(-1)
+            if quant:
+                from .kv_quant import (quantized_block_write,
+                                       touched_block_plan)
 
-            kv_plan = touched_block_plan(block_tables, start_pos, n_tokens,
-                                         C, bs, NB)
+                kv_plan = touched_block_plan(block_tables, start_pos,
+                                             n_tokens, C, bs, NB)
 
         def rope_q(q):
             if cfg.position != "rope":
@@ -209,73 +221,84 @@ class PagedCausalLM:
                 else:
                     lp, kc, vc = xs           # kc/vc [NB, KH, bs, D]
                     ks = vs = None
-                h1 = _norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"),
-                           cfg.norm, cfg.norm_eps)
+                with scope("attn_norm"):
+                    h1 = _norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"),
+                               cfg.norm, cfg.norm_eps)
                 nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-                q = rope_q(_linear(h1, lp["wq"], lp.get("wq_b"),
-                                   dt).reshape(N, C, nh, hd))
-                k = rope_q(_linear(h1, lp["wk"], lp.get("wk_b"),
-                                   dt).reshape(N, C, kvh, hd))
-                v = _linear(h1, lp["wv"], lp.get("wv_b"),
-                            dt).reshape(N, C, kvh, hd)
+                with scope("qkv"):
+                    q = rope_q(_linear(h1, lp["wq"], lp.get("wq_b"),
+                                       dt).reshape(N, C, nh, hd))
+                    k = rope_q(_linear(h1, lp["wk"], lp.get("wk_b"),
+                                       dt).reshape(N, C, kvh, hd))
+                    v = _linear(h1, lp["wv"], lp.get("wv_b"),
+                                dt).reshape(N, C, kvh, hd)
 
-                if quant:
-                    # quantized paged KV write: read-modify-write of only
-                    # the touched blocks — dequantize, merge the new
-                    # tokens, re-quantize at the monotone per-block scale
-                    kc, ks = quantized_block_write(kc, ks,
-                                                   k.reshape(-1, kvh, hd),
-                                                   kv_plan)
-                    vc, vs = quantized_block_write(vc, vs,
-                                                   v.reshape(-1, kvh, hd),
-                                                   kv_plan)
-                else:
-                    # paged KV write (reference linear_blocked_kv_rotary
-                    # kernel): token t lands at kc[block(t), :, slot(t), :]
-                    kc = kc.at[write_blk, :, write_off, :].set(
-                        k.reshape(-1, kvh, hd), mode="drop")
-                    vc = vc.at[write_blk, :, write_off, :].set(
-                        v.reshape(-1, kvh, hd), mode="drop")
+                with scope("kv_write"):
+                    if quant:
+                        # quantized paged KV write: read-modify-write of
+                        # only the touched blocks — dequantize, merge the
+                        # new tokens, re-quantize at the monotone
+                        # per-block scale
+                        kc, ks = quantized_block_write(
+                            kc, ks, k.reshape(-1, kvh, hd), kv_plan)
+                        vc, vs = quantized_block_write(
+                            vc, vs, v.reshape(-1, kvh, hd), kv_plan)
+                    else:
+                        # paged KV write (reference
+                        # linear_blocked_kv_rotary kernel): token t lands
+                        # at kc[block(t), :, slot(t), :]
+                        kc = kc.at[write_blk, :, write_off, :].set(
+                            k.reshape(-1, kvh, hd), mode="drop")
+                        vc = vc.at[write_blk, :, write_off, :].set(
+                            v.reshape(-1, kvh, hd), mode="drop")
 
                 # paged read: Pallas block-table walk (reference
                 # blocked_flash; Mistral sliding window clamps the walk to
                 # the last W positions; TP shard_maps the walk over the
                 # tensor axis; int8 pools dequantize in-kernel via the
                 # scale operands)
-                attn = self._attend(q, kc, vc, block_tables, start_pos,
-                                    n_tokens, slopes, window=window,
-                                    k_scale=ks, v_scale=vs)
-                attn_out = _linear(attn.reshape(N, C, nh * hd), lp["wo"],
-                                   lp.get("wo_b"), dt)
-                x = self.model._attn_mlp_merge(x, attn_out, lp, h1)
+                with scope("attend"):
+                    attn = self._attend(q, kc, vc, block_tables, start_pos,
+                                        n_tokens, slopes, window=window,
+                                        k_scale=ks, v_scale=vs)
+                with scope("attn_out"):
+                    attn_out = _linear(attn.reshape(N, C, nh * hd),
+                                       lp["wo"], lp.get("wo_b"), dt)
+                with scope("mlp"):      # norm, MLP and the residual adds
+                    x = self.model._attn_mlp_merge(x, attn_out, lp, h1)
                 return x, ((kc, vc, ks, vs) if quant else (kc, vc))
             return block
 
-        if quant:
-            x, (new_k, new_v, new_ks, new_vs) = self.model._scan_layers(
-                block_for, x, (params["layers"], kv_cache["k"],
-                               kv_cache["v"], kv_cache["k_scale"],
-                               kv_cache["v_scale"]))
-            new_cache = {"k": new_k, "v": new_v,
-                         "k_scale": new_ks, "v_scale": new_vs}
-        else:
-            x, (new_k, new_v) = self.model._scan_layers(
-                block_for, x, (params["layers"], kv_cache["k"],
-                               kv_cache["v"]))
-            new_cache = {"k": new_k, "v": new_v}
-        x = _norm(x, params["final_norm"]["w"], params["final_norm"].get("b"),
-                  cfg.norm, cfg.norm_eps)
-        if verify_width:
-            # right-aligned trailing-positions gather: row i, slot j reads
-            # chunk position n_tokens[i] - W + j (clipped) — slot W-1 is
-            # exactly the default path's last-token gather
-            W = verify_width
-            idx = jnp.clip(n_tokens[:, None] - W + jnp.arange(W)[None, :],
-                           0, C - 1)                              # [N, W]
-            x_v = jnp.take_along_axis(x, idx[:, :, None], axis=1)  # [N,W,H]
-            return self.model._unembed(params, x_v), new_cache
-        # logits_gather: only the last valid token per sequence
-        last_idx = jnp.clip(n_tokens - 1, 0, C - 1)
-        x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-        logits = self.model._unembed(params, x_last[:, None, :])[:, 0]
-        return logits, new_cache
+        with scope("layers"):
+            if quant:
+                x, (new_k, new_v, new_ks, new_vs) = self.model._scan_layers(
+                    block_for, x, (params["layers"], kv_cache["k"],
+                                   kv_cache["v"], kv_cache["k_scale"],
+                                   kv_cache["v_scale"]))
+                new_cache = {"k": new_k, "v": new_v,
+                             "k_scale": new_ks, "v_scale": new_vs}
+            else:
+                x, (new_k, new_v) = self.model._scan_layers(
+                    block_for, x, (params["layers"], kv_cache["k"],
+                                   kv_cache["v"]))
+                new_cache = {"k": new_k, "v": new_v}
+        with scope("final_norm"):
+            x = _norm(x, params["final_norm"]["w"],
+                      params["final_norm"].get("b"), cfg.norm, cfg.norm_eps)
+        with scope("logits"):
+            if verify_width:
+                # right-aligned trailing-positions gather: row i, slot j
+                # reads chunk position n_tokens[i] - W + j (clipped) — slot
+                # W-1 is exactly the default path's last-token gather
+                W = verify_width
+                idx = jnp.clip(n_tokens[:, None] - W + jnp.arange(W)[None, :],
+                               0, C - 1)                          # [N, W]
+                x_v = jnp.take_along_axis(x, idx[:, :, None],
+                                          axis=1)                 # [N,W,H]
+                return self.model._unembed(params, x_v), new_cache
+            # logits_gather: only the last valid token per sequence
+            last_idx = jnp.clip(n_tokens - 1, 0, C - 1)
+            x_last = jnp.take_along_axis(x, last_idx[:, None, None],
+                                         axis=1)[:, 0]
+            logits = self.model._unembed(params, x_last[:, None, :])[:, 0]
+            return logits, new_cache

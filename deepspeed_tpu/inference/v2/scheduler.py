@@ -404,6 +404,7 @@ class ContinuousBatchingScheduler:
             reserve = max(0, self.decode_reserve_tokens - decode_used)
             reserve = min(reserve, max(0, budget - 1))
         prompt_budget = budget - reserve
+        deferred: List[Request] = []
         for req in candidates + new_candidates:
             scheduled = False
             if prompt_budget > 0 and len(uids) < self._max_seqs:
@@ -415,7 +416,11 @@ class ContinuousBatchingScheduler:
                     prompt_budget -= take
                     scheduled = True
             if not scheduled and req.uid not in self.running:
-                self.pending.appendleft(req)   # new request deferred
+                deferred.append(req)           # new request deferred
+        # back to the head of the queue in arrival order (one appendleft
+        # each would put them back reversed, and which request is
+        # prefilled next would depend on who else happened to be waiting)
+        self.pending.extendleft(reversed(deferred))
         return uids, chunks, plan
 
     def _match_prefix_for(self, req: Request) -> None:
@@ -769,49 +774,83 @@ class ContinuousBatchingScheduler:
         req.spans = None
 
     def step(self) -> List[int]:
-        """One engine forward; returns uids of requests finished this step."""
-        uids, chunks, plan = self._pack()
-        if not uids:
-            return []
-        # verification width: the widest speculative decode chunk this
-        # step, bucketed (pow2) to bound compiled-program variants. Steps
-        # with no drafts in flight — pure prefill, draft-less decode —
-        # take the exact historical path.
-        spec_w = max((len(c) for _, c, d in plan if d and len(c) > 1),
-                     default=0)
-        # per-forward telemetry span (replica-level trace): brackets the
-        # device call including host materialization of the logits
-        traced = self.tracer.enabled
-        fspan = self.tracer.begin(
-            "forward", trace_id=self.trace_label,
-            attrs={"n_seqs": len(uids),
-                   "n_tokens": int(sum(len(c) for c in chunks))}) \
-            if traced else None
-        if self.proposer is None or spec_w == 0:
-            logits = np.asarray(self.engine.put(uids, chunks))
-            vspan = None
-        else:
-            W = self.engine.batch._bucket(spec_w, self._chunk)
-            if traced:
-                fspan.set("verify_width", W)
+        """One engine forward; returns uids of requests finished this step.
+
+        Traced (docs/OBSERVABILITY.md "Trace model"), a step is a ``step``
+        span on the scheduler's trace with one child per phase, each
+        mirrored into an open profiler session as ``ds:<name>``:
+
+        - ``pack``: :meth:`_pack` — admission, prefix matching, the
+          chunks, and **sampling**: each decode row's next token is drawn
+          from its last logits here (``sample_fn``), drafts included;
+        - ``stage``: the host part of ``engine.put`` — scheduling check,
+          KV allocation, ``batch.finalize``, uploads and the dispatch;
+          attrs are the engine's record of the put (``last_put``);
+        - ``fetch``: ``np.asarray`` of the logits — the wait for the
+          device plus the copy back;
+        - ``commit``: the per-row loop — commit, finish, flush and the
+          ``on_token`` / ``on_finish`` callbacks (inside ``spec_verify``
+          on a speculative step).
+
+        The ``forward`` span (``stage`` + ``fetch`` in one interval) is
+        kept for its readers. One call site each, traced or not: a
+        disabled tracer hands out the shared no-op span."""
+        tracer = self.tracer
+        traced = tracer.enabled
+        with tracer.span("step", trace_id=self.trace_label):
+            with tracer.span("pack"):
+                uids, chunks, plan = self._pack()
+            if not uids:
+                return []
+            # verification width: the widest speculative decode chunk this
+            # step, bucketed (pow2) to bound compiled-program variants.
+            # Steps with no drafts in flight — pure prefill, draft-less
+            # decode — take the exact historical path.
+            spec_w = max((len(c) for _, c, d in plan if d and len(c) > 1),
+                         default=0)
+            speculative = self.proposer is not None and spec_w > 0
             # speculative step: right-aligned trailing-position logits for
             # verification; the prefix-cache hash chain is committed
             # per-row below, once rejected drafts have been trimmed (the
             # index must never see tokens a trim can roll back)
-            logits = np.asarray(self.engine.put(uids, chunks,
-                                                verify_width=W,
-                                                defer_commit=True))
-            # host-side verify/trim/commit of this step, as its own span
-            vspan = self.tracer.begin("spec_verify",
-                                      trace_id=self.trace_label,
-                                      attrs={"verify_width": W}) \
-                if traced else None
-        if traced:
-            fspan.end()
+            W = self.engine.batch._bucket(spec_w, self._chunk) \
+                if speculative else 0
+            put_kw = {"verify_width": W, "defer_commit": True} \
+                if speculative else {}
+            fspan = vspan = None
+            if traced:
+                fspan = tracer.begin(
+                    "forward", trace_id=self.trace_label,
+                    attrs={"n_seqs": len(uids),
+                           "n_tokens": int(sum(len(c) for c in chunks))})
+                if speculative:
+                    fspan.set("verify_width", W)
+            with tracer.span("stage") as sspan:
+                logits = self.engine.put(uids, chunks, **put_kw)
+                if traced:
+                    sspan.attrs.update(self.engine.last_put)
+                    fspan.attrs.update(self.engine.last_put)
+            with tracer.span("fetch"):
+                logits = np.asarray(logits)
+            if traced:
+                fspan.end()
+                if speculative:
+                    # host-side verify/trim/commit of this step, as its
+                    # own span
+                    vspan = tracer.begin("spec_verify",
+                                         trace_id=self.trace_label,
+                                         attrs={"verify_width": W})
+            with tracer.span("commit"):
+                done_now = self._commit(plan, logits, speculative)
+            if vspan is not None:
+                vspan.end()
+            return done_now
+
+    def _commit(self, plan, logits, speculative: bool) -> List[int]:
+        """Commit one step's rows — only after the forward succeeded."""
         done_now = []
-        # commit state only after the forward succeeded
         for i, (req, chunk, is_decode) in enumerate(plan):
-            if self.proposer is None or spec_w == 0:
+            if not speculative:
                 req.last_logits = logits[i]
                 if is_decode:
                     if not req.generated:
@@ -868,8 +907,6 @@ class ContinuousBatchingScheduler:
                 done_now.append(req.uid)
                 if req.on_finish is not None:
                     req.on_finish(req, req.finish_reason)
-        if vspan is not None:
-            vspan.end()
         return done_now
 
     def _apply_verified(self, req: Request, chunk: List[int],

@@ -735,31 +735,38 @@ class CausalLM:
         cfg = self.cfg
         B, T, H = x.shape
 
-        # attention (projections shared with the KV-cache/paged paths)
-        h1 = _norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"), cfg.norm, cfg.norm_eps)
-        q, k, v = self._qkv(h1, lp, cos, sin, B, T)
-        attn = _attention(q, k, v, cfg, causal=True, window=window)
-        attn = _linear(attn.reshape(B, T, -1), lp["wo"], lp.get("wo_b"),
-                       cfg.dtype)
-        if cfg.dropout > 0 and not deterministic:
-            rng, sub = jax.random.split(rng)
-            attn = attn * jax.random.bernoulli(sub, 1 - cfg.dropout, attn.shape) / (1 - cfg.dropout)
+        # attention (projections shared with the KV-cache/paged paths).
+        # The scopes are the paged forward's (inference/v2/paged_model.py),
+        # so a training trace and a serving trace read alike.
+        with jax.named_scope("attn_norm"):
+            h1 = _norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"), cfg.norm, cfg.norm_eps)
+        with jax.named_scope("qkv"):
+            q, k, v = self._qkv(h1, lp, cos, sin, B, T)
+        with jax.named_scope("attend"):
+            attn = _attention(q, k, v, cfg, causal=True, window=window)
+        with jax.named_scope("attn_out"):
+            attn = _linear(attn.reshape(B, T, -1), lp["wo"], lp.get("wo_b"),
+                           cfg.dtype)
+            if cfg.dropout > 0 and not deterministic:
+                rng, sub = jax.random.split(rng)
+                attn = attn * jax.random.bernoulli(sub, 1 - cfg.dropout, attn.shape) / (1 - cfg.dropout)
 
         # mlp (dense or MoE; body shared with the inference paths).
         # parallel_residual (NeoX/Falcon): both branches read the SAME
         # input x; shared_layernorm (GPT-J): the mlp reads h1 itself;
         # sequential (default): mlp reads the post-attention x.
-        if cfg.shared_layernorm:
-            h2 = h1
-        else:
-            mlp_in = x if cfg.parallel_residual else x + attn
-            h2 = _norm(mlp_in, lp["mlp_norm_w"], lp.get("mlp_norm_b"),
-                       cfg.norm, cfg.norm_eps)
-        y, l_aux = self._mlp_body(h2, lp, rng, deterministic)
-        if cfg.dropout > 0 and not deterministic:
-            rng, sub = jax.random.split(rng)
-            y = y * jax.random.bernoulli(sub, 1 - cfg.dropout, y.shape) / (1 - cfg.dropout)
-        return x + attn + y, l_aux
+        with jax.named_scope("mlp"):    # norm, MLP and the residual adds
+            if cfg.shared_layernorm:
+                h2 = h1
+            else:
+                mlp_in = x if cfg.parallel_residual else x + attn
+                h2 = _norm(mlp_in, lp["mlp_norm_w"], lp.get("mlp_norm_b"),
+                           cfg.norm, cfg.norm_eps)
+            y, l_aux = self._mlp_body(h2, lp, rng, deterministic)
+            if cfg.dropout > 0 and not deterministic:
+                rng, sub = jax.random.split(rng)
+                y = y * jax.random.bernoulli(sub, 1 - cfg.dropout, y.shape) / (1 - cfg.dropout)
+            return x + attn + y, l_aux
 
     def _mlp_body(self, h2, lp, rng, deterministic: bool):
         """Dense or MoE FFN on normed input; returns (y, aux_loss)."""
@@ -853,22 +860,23 @@ class CausalLM:
             for grp in ("embed", "final_norm", "lm_head"):
                 if grp in params:
                     params[grp] = {k: flat[f"{grp}.{k}"] for k in params[grp]}
-        x = params["embed"]["wte"][tokens].astype(cfg.dtype)
-        if cfg.embedding_layernorm:
-            x = _norm(x, params["embed"]["ln_w"], params["embed"].get("ln_b"),
-                      cfg.norm, cfg.norm_eps)
-        if cfg.position == "rope":
-            cos_full, sin_full = rope_table(cfg.max_seq_len, cfg.rot_dim,
-                                            cfg.rope_theta)
-            if positions is not None:
-                cos, sin = cos_full[positions], sin_full[positions]
+        with jax.named_scope("embed"):
+            x = params["embed"]["wte"][tokens].astype(cfg.dtype)
+            if cfg.embedding_layernorm:
+                x = _norm(x, params["embed"]["ln_w"],
+                          params["embed"].get("ln_b"), cfg.norm, cfg.norm_eps)
+            if cfg.position == "rope":
+                cos_full, sin_full = rope_table(cfg.max_seq_len, cfg.rot_dim,
+                                                cfg.rope_theta)
+                if positions is not None:
+                    cos, sin = cos_full[positions], sin_full[positions]
+                else:
+                    cos, sin = cos_full[:T], sin_full[:T]
             else:
-                cos, sin = cos_full[:T], sin_full[:T]
-        else:
-            if cfg.position == "learned":
-                pos = positions if positions is not None else jnp.arange(T)
-                x = x + params["embed"]["wpe"][pos].astype(cfg.dtype)
-            cos = sin = jnp.zeros((T, 1), jnp.float32)
+                if cfg.position == "learned":
+                    pos = positions if positions is not None else jnp.arange(T)
+                    x = x + params["embed"]["wpe"][pos].astype(cfg.dtype)
+                cos = sin = jnp.zeros((T, 1), jnp.float32)
         if rng is None:
             rng = jax.random.PRNGKey(0)
 
@@ -918,9 +926,10 @@ class CausalLM:
                 return block(carry, lp, cos, sin, key, deterministic, win)
 
             num_micro = cfg.pipeline_microbatches or pp
-            x, aux_sum = pipelined_layer_apply(
-                layer_fn, (params["layers"], layer_keys), x, num_micro,
-                mesh=topo.get_topology().mesh)
+            with jax.named_scope("layers"):
+                x, aux_sum = pipelined_layer_apply(
+                    layer_fn, (params["layers"], layer_keys), x, num_micro,
+                    mesh=topo.get_topology().mesh)
             aux_losses = aux_sum[None]
         else:
             def scan_for(win):
@@ -933,11 +942,14 @@ class CausalLM:
                     return x, aux
                 return scan_fn
 
-            x, aux_losses = self._scan_layers(
-                scan_for, x, (params["layers"], layer_keys))
-        x = _norm(x, params["final_norm"]["w"], params["final_norm"].get("b"),
-                  cfg.norm, cfg.norm_eps)
-        logits = self._unembed(params, x)
+            with jax.named_scope("layers"):
+                x, aux_losses = self._scan_layers(
+                    scan_for, x, (params["layers"], layer_keys))
+        with jax.named_scope("final_norm"):
+            x = _norm(x, params["final_norm"]["w"],
+                      params["final_norm"].get("b"), cfg.norm, cfg.norm_eps)
+        with jax.named_scope("logits"):
+            logits = self._unembed(params, x)
         if return_aux:
             return logits, jnp.sum(aux_losses)
         return logits
@@ -1257,17 +1269,19 @@ class CausalLM:
         mask = batch.get("loss_mask")
         logits, aux = self.apply(params, tokens, rng=rng,
                                  deterministic=rng is None, return_aux=True)
-        logits = logits.astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        nll = logz - gold
-        if mask is not None:
-            loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
-        else:
-            loss = jnp.mean(nll)
-        if self.cfg.moe_num_experts > 0:
-            loss = loss + self.cfg.moe_aux_loss_coef * aux
-        return loss
+        with jax.named_scope("loss"):
+            logits = logits.astype(jnp.float32)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, labels[..., None],
+                                       axis=-1)[..., 0]
+            nll = logz - gold
+            if mask is not None:
+                loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
+            else:
+                loss = jnp.mean(nll)
+            if self.cfg.moe_num_experts > 0:
+                loss = loss + self.cfg.moe_aux_loss_coef * aux
+            return loss
 
     # convenience
     def num_params(self) -> int:

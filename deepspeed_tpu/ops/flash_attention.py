@@ -294,6 +294,7 @@ def _fwd_pallas(q, k, v, causal, block_q, block_kv, window, sm_scale=None,
         block_kv=bkv, q_len=T, kv_len=S, window=window)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -347,6 +348,7 @@ def _bwd_pallas(q, k, v, o_hm, lse, g, causal, block_q, block_kv, window,
         block_kv=bkv, q_len=T, kv_len=S, window=window)
     dqh = pl.pallas_call(
         dq_kernel,
+        name="flash_attention_dq",
         grid=(B, H, nqb, S // bkv),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, stat_spec],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -389,6 +391,7 @@ def _bwd_pallas(q, k, v, o_hm, lse, g, causal, block_q, block_kv, window,
         block_kv=bkv, q_len=T, kv_len=S, num_q_blocks=nqb, window=window)
     dkh, dvh = pl.pallas_call(
         dkv_kernel,
+        name="flash_attention_dkv",
         grid=(B, KH, S // bkv, group * nqb),
         in_specs=[qg_spec, kvg_spec, kvg_spec, qg_spec, qg_spec, statg_spec],
         out_specs=[

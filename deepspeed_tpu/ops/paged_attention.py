@@ -218,6 +218,7 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
     out_dt = q.dtype
     o = pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, KH, G * C, D), out_dt),
         compiler_params=pltpu.CompilerParams(

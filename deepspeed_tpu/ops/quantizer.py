@@ -177,6 +177,7 @@ def _quantize_pallas(x2, bits: int, block: int):
     kern = functools.partial(_quant_kernel, bits=bits, block=block)
     return pl.pallas_call(
         kern,
+        name="quantize_blockwise",
         grid=(rows // tile_r,),
         in_specs=[pl.BlockSpec((tile_r, n), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((tile_r, n), lambda i: (i, 0)),
@@ -193,6 +194,7 @@ def _dequantize_pallas(q2, s2, block: int, dtype):
     kern = functools.partial(_dequant_kernel, block=block)
     return pl.pallas_call(
         kern,
+        name="dequantize_blockwise",
         grid=(rows // tile_r,),
         in_specs=[pl.BlockSpec((tile_r, n), lambda i: (i, 0)),
                   pl.BlockSpec((tile_r, n // block), lambda i: (i, 0))],
@@ -324,6 +326,7 @@ def _qmm_pallas(x2, q, s, block: int, out_dtype):
     kern = functools.partial(_qmm_kernel, block=block)
     return pl.pallas_call(
         kern,
+        name="quantized_matmul",
         grid=(m // bm, n // bn, k // bk),
         in_specs=[pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
                   pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),

@@ -245,14 +245,15 @@ class DeepSpeedTpuEngine:
             monitor_memory=self.config.memory_breakdown)
         self.monitor = self._build_monitor()
         # step profiling (docs/OBSERVABILITY.md "Step profiling"):
-        # wall_clock_breakdown (reference engine.py flag) or an enabled
-        # telemetry block brackets fwd+bwd and the optimizer step with
-        # synchronized timers — a block_until_ready per bracket, so real
-        # device time is measured, at a small throughput cost — and
-        # records matching spans on the tracer ("train" trace).
+        # wall_clock_breakdown (reference engine.py flag) brackets fwd+bwd
+        # and the optimizer step with synchronized timers — a
+        # block_until_ready per bracket, so real device time is measured,
+        # at a throughput cost. An enabled telemetry block alone records
+        # the fwd_bwd / optimizer_step spans ("train" trace) round the
+        # dispatches and synchronizes nothing: tracing must not change
+        # what it traces.
         self.tracer = self.config.telemetry.build_tracer()
-        self._profile_steps = bool(self.config.wall_clock_breakdown
-                                   or self.config.telemetry.enabled)
+        self._profile_steps = bool(self.config.wall_clock_breakdown)
 
         log_dist(
             f"DeepSpeedTpuEngine ready: mesh={dict(self.mesh.shape)} "
@@ -514,22 +515,30 @@ class DeepSpeedTpuEngine:
                 loss = module.loss(params, batch, rng)
                 return (loss * scale / (dp_size if predivide else 1.0)).astype(jnp.float32), loss
 
-            grads, loss = jax.grad(loss_fn, has_aux=True)(state.params)
-            grad_acc = jax.tree.map(jnp.add, state.grad_acc, grads)
+            # program scopes (docs/OBSERVABILITY.md "XLA alignment"):
+            # inside loss_and_grad JAX's own op_name prefixes tell the
+            # passes apart — jvp(…) forward, transpose(jvp(…)) backward,
+            # and the checkpoint marker on the recomputed forward
+            with jax.named_scope("loss_and_grad"):
+                grads, loss = jax.grad(loss_fn, has_aux=True)(state.params)
+            with jax.named_scope("grad_accumulate"):
+                grad_acc = jax.tree.map(jnp.add, state.grad_acc, grads)
             return state._replace(grad_acc=grad_acc), loss
 
         def unscale_and_clip(state: TrainState):
-            scale = state.scale_state.scale
-            denom = scale * gas / (dp_size if predivide else 1.0)
-            grads = jax.tree.map(lambda g: g / denom, state.grad_acc)
-            flat = jax.tree.leaves(grads)
-            sumsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in flat)
-            gnorm = jnp.sqrt(sumsq)
-            overflow = ~jnp.isfinite(gnorm)
-            if clip > 0:
-                coeff = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                grads = jax.tree.map(lambda g: g * coeff, grads)
-            return grads, gnorm, overflow
+            with jax.named_scope("grad_norm_clip"):
+                scale = state.scale_state.scale
+                denom = scale * gas / (dp_size if predivide else 1.0)
+                grads = jax.tree.map(lambda g: g / denom, state.grad_acc)
+                flat = jax.tree.leaves(grads)
+                sumsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                            for g in flat)
+                gnorm = jnp.sqrt(sumsq)
+                overflow = ~jnp.isfinite(gnorm)
+                if clip > 0:
+                    coeff = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                    grads = jax.tree.map(lambda g: g * coeff, grads)
+                return grads, gnorm, overflow
 
         def next_scale_state(ss: ScaleState, overflow):
             """Dynamic loss scale automaton (reference loss_scaler.py:136)."""
@@ -576,7 +585,8 @@ class DeepSpeedTpuEngine:
             def skip(_):
                 return state.params, state.opt_state
 
-            new_params, new_opt = lax.cond(overflow, skip, do_step, None)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = lax.cond(overflow, skip, do_step, None)
             new_state = book_keeping(state, new_params, new_opt, overflow)
             metrics = {"grad_norm": gnorm, "lr": lr, "overflow": overflow,
                        "loss_scale": state.scale_state.scale}
@@ -869,13 +879,16 @@ class DeepSpeedTpuEngine:
             # from the host; docs/OBSERVABILITY.md)
             fwd_timer = self.timers(FORWARD_MICRO_TIMER)
             fwd_timer.start()
-            span = self.tracer.begin("fwd_bwd", trace_id="train",
-                                     attrs={"micro_step": self.micro_steps})
+        # one call site, profiled, traced or neither (the flash kernels'
+        # compile-cache keys carry it); the span is the dispatch unless
+        # the timer above makes it the whole micro step
+        with self.tracer.span(
+                "fwd_bwd", trace_id="train",
+                attrs={"micro_step": self.micro_steps}
+                if self.tracer.enabled else None):
             self.state, loss = self._micro_fn(self.state, batch, step_rng)
-            fwd_timer.stop(record=True)
-            span.end()
-        else:
-            self.state, loss = self._micro_fn(self.state, batch, step_rng)
+            if self._profile_steps:
+                fwd_timer.stop(record=True)
         self._pending_loss = loss
         if self.config.check_numerics and not self.fp16_enabled \
                 and not np.isfinite(float(loss)):
@@ -948,22 +961,24 @@ class DeepSpeedTpuEngine:
         if self._profile_steps:
             step_timer = self.timers(STEP_GLOBAL_TIMER)
             step_timer.start()
-            opt_span = self.tracer.begin(
+        with self.tracer.span(
                 "optimizer_step", trace_id="train",
-                attrs={"global_step": self.global_steps})
-        if self._offload_plan is not None:
-            metrics = self._offload_step()
-        elif self._onebit and self.global_steps < self.opt.freeze_step:
-            # Warmup phase: full-precision momentum/variance build-up
-            # (host-dispatched — see _build_step_fns onebit path).
-            self.state, metrics = self._update_warm_fn(self.state)
-        else:
-            self.state, metrics = self._update_fn(self.state)
-        if self._profile_steps:
-            step_timer.stop(record=True)   # synced: real update duration
-            opt_span.set("skipped",
-                         bool(np.asarray(metrics.get("overflow", False)))) \
-                    .end()
+                attrs={"global_step": self.global_steps}
+                if self.tracer.enabled else None) as opt_span:
+            if self._offload_plan is not None:
+                metrics = self._offload_step()
+            elif self._onebit and self.global_steps < self.opt.freeze_step:
+                # Warmup phase: full-precision momentum/variance build-up
+                # (host-dispatched — see _build_step_fns onebit path).
+                self.state, metrics = self._update_warm_fn(self.state)
+            else:
+                self.state, metrics = self._update_fn(self.state)
+            if self._profile_steps:
+                step_timer.stop(record=True)   # synced: real update duration
+                # reading the flag waits for the update: only under the
+                # synchronizing profile
+                opt_span.set("skipped", bool(np.asarray(
+                    metrics.get("overflow", False))))
         if pre_scan is not None \
                 and not np.isfinite(float(metrics.get("grad_norm", 0.0))):
             # under fp16 the dynamic-loss-scale automaton owns overflow

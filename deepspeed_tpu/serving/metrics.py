@@ -383,6 +383,10 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # tokens-per-forward = emitted/decode_forwards
               "spec_tokens_proposed", "spec_tokens_accepted",
               "spec_tokens_emitted", "spec_decode_forwards",
+              # what the engine's forwards computed (engine.put_totals,
+              # delta-published per Replica): pad ratio over an interval =
+              # delta positions_computed / delta tokens_valid
+              "forwards", "positions_computed", "tokens_valid",
               # fault tolerance (docs/SERVING.md "Fault tolerance"):
               # failover = a dead replica's request re-enqueued (stream
               # resumed elsewhere); restarts = supervisor replaced a DEAD

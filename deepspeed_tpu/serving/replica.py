@@ -178,6 +178,7 @@ class Replica:
         # delta-publishing the monotonic registry counters (summable
         # across replicas)
         self._prefix_last: Dict[str, int] = {}
+        self._put_last: Dict[str, int] = {}
         self._spec_last: Dict[str, int] = {}
         self._tier_last: Dict[str, int] = {}
         self._preempt_last: Dict[str, int] = {}
@@ -583,6 +584,7 @@ class Replica:
     _TIER_COUNTERS = (("spilled", "kv_tier_blocks_spilled"),
                       ("restored", "kv_tier_blocks_restored"),
                       ("dropped", "kv_tier_blocks_dropped"))
+    _PUT_COUNTERS = ("forwards", "positions_computed", "tokens_valid")
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),
                          ("resumed", "sequences_resumed"))
 
@@ -602,6 +604,15 @@ class Replica:
                 if delta:
                     self.metrics.counter(name).inc(delta)
             self._prefix_last = stats
+        # what the forwards computed against what was asked of them: pad
+        # ratio over any interval = delta positions / delta valid tokens
+        totals = getattr(self.engine, "put_totals", None)
+        if totals is not None:
+            for name in self._PUT_COUNTERS:
+                delta = totals[name] - self._put_last.get(name, 0)
+                if delta:
+                    self.metrics.counter(name).inc(delta)
+            self._put_last = dict(totals)
         # published with or without a proposer: plain decode rows count
         # one forward / one emitted token, so emitted/decode_forwards
         # reads 1.0 for a spec-off replica (and fleet-wide ratios keep an
@@ -673,7 +684,13 @@ class Replica:
     def _loop(self) -> None:
         while not self._stop.is_set() and self.state != ReplicaState.DEAD:
             try:
-                self._admit_inbox()
+                # spans only where something happens, so an idle replica
+                # records nothing: device idle time between steps is then
+                # named by what the worker was doing
+                if not self._inbox.empty():
+                    with self.tracer.span("admit_inbox",
+                                          trace_id=self.scheduler.trace_label):
+                        self._admit_inbox()
                 self._enforce_slo()
                 if self._evacuate_cb is not None:
                     self._do_evacuate()
@@ -687,7 +704,9 @@ class Replica:
                                              self._steps_done)
                     self.scheduler.step()
                     self._steps_done += 1
-                    self._publish_prefix_stats()
+                    with self.tracer.span("publish_stats",
+                                          trace_id=self.scheduler.trace_label):
+                        self._publish_prefix_stats()
                     # routine-failure uids (cancel/deadline) can emit no
                     # further scheduler callbacks once the step that
                     # detached them completed — prune so the set doesn't
@@ -703,7 +722,18 @@ class Replica:
                     self._busy_since = None
                     if self.state == ReplicaState.DRAINING:
                         break
-                    self._stop.wait(self.idle_wait_s)
+                    # one span for the whole idle period, not one per
+                    # wait (an idle replica must not fill the span ring):
+                    # keep waiting while there is nothing this loop would
+                    # look at
+                    with self.tracer.span("idle_wait",
+                                          trace_id=self.scheduler.trace_label):
+                        while not self._stop.wait(self.idle_wait_s) \
+                                and self._inbox.empty() \
+                                and not self._active \
+                                and self._evacuate_cb is None \
+                                and self.state == ReplicaState.HEALTHY:
+                            pass
                 self.last_progress_t = time.monotonic()
             except Exception as e:  # engine/scheduler fault → DEAD replica
                 logger.error(f"serving replica {self.replica_id} died: {e!r}")

@@ -28,9 +28,6 @@ class TelemetryConfig(DSConfigModel):
     error_dump_window_s: float = 3600.0
     # where dumps land (None = <tmpdir>/deepspeed_tpu_telemetry)
     dump_dir: Optional[str] = None
-    # mirror context-manager spans into jax.profiler.TraceAnnotation so
-    # host spans line up with XLA traces in the same Perfetto view
-    xla_annotations: bool = False
 
     def build_tracer(self):
         """The configured tracer — the shared NOOP singleton when
@@ -39,8 +36,7 @@ class TelemetryConfig(DSConfigModel):
 
         if not self.enabled:
             return NOOP_TRACER
-        return Tracer(enabled=True, max_spans=self.max_spans,
-                      xla_annotations=self.xla_annotations)
+        return Tracer(enabled=True, max_spans=self.max_spans)
 
     def build_recorder(self, tracer, metrics=None, role="frontend"):
         """Flight recorder over ``tracer``; ``metrics`` (an object with

@@ -60,6 +60,27 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+#: what every annotation the program writes into a profiler trace starts
+#: with, so a trace reader can tell the program's spans from anyone else's
+ANNOTATION_PREFIX = "ds:"
+
+_UNSET = object()
+_trace_annotation = _UNSET
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation``, imported once when the first
+    enabled tracer is built; None — recorded, not retried per span — where
+    JAX is not installed (spans are then recorded without a mirror)."""
+    global _trace_annotation
+    if _trace_annotation is _UNSET:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = None
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation
+
 
 class Span:
     """One timed interval: ``[t_start, t_end]`` on the monotonic clock,
@@ -95,26 +116,26 @@ class Span:
         self.tracer._record(self)
 
     # -- context-manager form: auto-parents off the thread-local stack and
-    # (optionally) mirrors into jax.profiler.TraceAnnotation so host spans
-    # line up with XLA device traces in the same Perfetto view.
+    # mirrors into jax.profiler.TraceAnnotation as ``ds:<name>``, so host
+    # spans sit on the device trace's clock in the same Perfetto view; the
+    # attrs the span holds when it closes become the annotation's stats.
+    # The annotation is a no-op while no profiler session is open. begin()
+    # spans are not mirrored: an annotation belongs to the thread that
+    # opened it.
     def __enter__(self) -> "Span":
         self.tracer._push(self)
-        if self.tracer.xla_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._xla_ctx = TraceAnnotation(self.name)
-                self._xla_ctx.__enter__()
-            except Exception:
-                self._xla_ctx = None
+        annotation = self.tracer._annotation
+        if annotation is not None:
+            self._xla_ctx = annotation(ANNOTATION_PREFIX + self.name)
+            self._xla_ctx.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         if self._xla_ctx is not None:
-            try:
-                self._xla_ctx.__exit__(*exc)
-            finally:
-                self._xla_ctx = None
+            if self.attrs:
+                self._xla_ctx.set_metadata(**self.attrs)
+            self._xla_ctx.__exit__(*exc)
+            self._xla_ctx = None
         self.tracer._pop(self)
         self.end()
         return False
@@ -141,10 +162,10 @@ class Tracer:
                    "_completed_total": "_lock"}
 
     def __init__(self, enabled: bool = True, max_spans: int = 8192,
-                 clock=time.monotonic, xla_annotations: bool = False):
+                 clock=time.monotonic):
         self.enabled = bool(enabled)
         self.clock = clock
-        self.xla_annotations = bool(xla_annotations)
+        self._annotation = _annotation_class() if self.enabled else None
         self.max_spans = int(max_spans)
         self._spans: "deque[Span]" = deque(maxlen=self.max_spans)
         # open (started, un-ended) spans, so crash dumps show in-flight

@@ -195,6 +195,39 @@ def test_continuous_batching_end_to_end(model_and_params):
             f"uid {uid}: {finished[uid].generated} vs {ref.tolist()}"
 
 
+def test_deferred_requests_keep_arrival_order(model_and_params):
+    """New requests a step has no token budget for go back to the head of
+    the queue in arrival order: requests reach their first chunk in the
+    order they came, whoever else was waiting at the time (put back one
+    ``appendleft`` at a time they were reversed every step, so a burst
+    split over two steps by a timing accident was served in another
+    order than the same burst admitted whole)."""
+    model, params = model_and_params
+
+    def first_chunk_order(split):
+        engine = _v2_engine(model, params, max_ragged_batch_size=16)
+        sched = ContinuousBatchingScheduler(engine)
+        order = []
+        put = engine.put
+
+        def recording_put(uids, chunks, **kw):
+            order.extend(u for u in uids if u not in order)
+            return put(uids, chunks, **kw)
+
+        engine.put = recording_put
+        for uid in range(1, split + 1):
+            sched.submit(uid, [uid] * 16, max_new_tokens=1)
+        sched.step()
+        assert [r.uid for r in sched.pending] == list(range(2, split + 1))
+        for uid in range(split + 1, 5):
+            sched.submit(uid, [uid] * 16, max_new_tokens=1)
+        sched.run_to_completion(max_steps=50)
+        return order
+
+    assert first_chunk_order(4) == [1, 2, 3, 4]
+    assert first_chunk_order(3) == [1, 2, 3, 4]
+
+
 def test_generate_ragged_prompts(model_and_params):
     """v1 generate accepts ragged prompts (list-of-lists) and each
     sequence's greedy continuation matches generating it alone — the r3

@@ -131,6 +131,100 @@ def test_disabled_hot_path_allocation_free():
     assert tr.export() == []
 
 
+# ------------------------------------------------- profiler annotations
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records what a span
+    does with it (no profiler session is needed to see that)."""
+    log = []
+
+    def __init__(self, name, **kwargs):
+        self.name = name
+        self.log.append(("init", name, kwargs))
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def set_metadata(self, **kwargs):
+        self.log.append(("metadata", self.name, kwargs))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture()
+def fake_annotation(monkeypatch):
+    from deepspeed_tpu.telemetry import tracer as tracer_mod
+
+    monkeypatch.setattr(tracer_mod, "_trace_annotation", _FakeAnnotation)
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    return _FakeAnnotation.log
+
+
+def test_context_span_is_mirrored_as_ds_annotation(fake_annotation):
+    """Every context-manager span of an enabled tracer enters and leaves a
+    TraceAnnotation named ds:<name>; the attrs it holds when it closes
+    (set at creation or later) become the annotation's stats. begin()
+    spans belong to no thread and are not mirrored."""
+    tr = Tracer()
+    with tr.span("step", trace_id="replica-0"):
+        with tr.span("stage", attrs={"n": 1}) as sp:
+            sp.set("bucket_seqs", 16)
+    tr.begin("forward", trace_id="replica-0").end()
+    assert fake_annotation == [
+        ("init", "ds:step", {}), ("enter", "ds:step"),
+        ("init", "ds:stage", {}), ("enter", "ds:stage"),
+        ("metadata", "ds:stage", {"n": 1, "bucket_seqs": 16}),
+        ("exit", "ds:stage"), ("exit", "ds:step")]
+    assert [s["name"] for s in tr.export()] == ["stage", "step", "forward"]
+
+
+def test_annotation_failure_is_not_swallowed(fake_annotation, monkeypatch):
+    def boom(self):
+        raise RuntimeError("profiler broke")
+
+    monkeypatch.setattr(_FakeAnnotation, "__enter__", boom)
+    with pytest.raises(RuntimeError, match="profiler broke"):
+        with Tracer().span("x"):
+            pass
+
+
+def test_disabled_tracer_constructs_no_annotation(fake_annotation):
+    tr = Tracer(enabled=False)
+    with tr.span("a"):
+        pass
+    with NOOP_TRACER.span("b"):
+        pass
+    assert fake_annotation == [] and tr._annotation is None
+
+
+def test_tracer_works_without_jax_profiler(monkeypatch):
+    """Where jax.profiler cannot be imported the absence is recorded once
+    (not retried and swallowed per span) and spans are still recorded."""
+    import builtins
+
+    from deepspeed_tpu.telemetry import tracer as tracer_mod
+
+    real_import, attempts = builtins.__import__, []
+
+    def no_profiler(name, *args, **kwargs):
+        if name == "jax.profiler":
+            attempts.append(name)
+            raise ImportError("no jax here")
+        return real_import(name, *args, **kwargs)
+
+    monkeypatch.setattr(tracer_mod, "_trace_annotation", tracer_mod._UNSET)
+    monkeypatch.setattr(builtins, "__import__", no_profiler)
+    tr = Tracer()
+    for _ in range(3):
+        with tr.span("a"):
+            pass
+    Tracer()
+    assert tr._annotation is None and len(attempts) == 1
+    assert [s["name"] for s in tr.export()] == ["a"] * 3
+
+
 # ------------------------------------------------------------ chrome trace
 def test_chrome_trace_schema_valid():
     tr = Tracer()
@@ -217,9 +311,17 @@ def test_flight_recorder_on_error_rate_limited(tmp_path):
 def test_telemetry_config_builders():
     tc = TelemetryConfig()
     assert tc.build_tracer() is NOOP_TRACER
-    tc_on = TelemetryConfig(enabled=True, max_spans=16, xla_annotations=True)
+    tc_on = TelemetryConfig(enabled=True, max_spans=16)
     tr = tc_on.build_tracer()
-    assert tr.enabled and tr.max_spans == 16 and tr.xla_annotations
+    assert tr.enabled and tr.max_spans == 16
+    # mirroring into the profiler is what an enabled tracer does, not a
+    # knob: the config has no field for it and the tracer takes none
+    from jax.profiler import TraceAnnotation
+
+    assert tr._annotation is TraceAnnotation
+    assert set(TelemetryConfig.model_fields) == {
+        "enabled", "max_spans", "max_metric_snapshots", "dump_on_error",
+        "max_error_dumps", "error_dump_window_s", "dump_dir"}
     rec = tc_on.build_recorder(tr)
     assert isinstance(rec, FlightRecorder)
 
@@ -378,22 +480,33 @@ def test_engine_step_profiling(via):
     rng = np.random.default_rng(0)
     gb = 2 * engine.topology.get_data_parallel_world_size()
     data = {"input_ids": rng.integers(0, 64, size=(gb, 33), dtype=np.int64)}
+    syncs = []
+    real_sync = engine.timers._sync_fn
+    engine.timers._sync_fn = lambda: (syncs.append(1), real_sync())
     engine.train_batch(iter([data, data]))
     # flops_per_sample auto-populated from the flops profiler (satellite)
     from deepspeed_tpu.profiling import train_step_flops
 
     assert engine.tput_timer.flops_per_sample \
         == pytest.approx(train_step_flops(cfg, 1, 32))
-    # synchronized timers recorded both phases
-    assert engine.timers.has(FORWARD_MICRO_TIMER)
-    assert engine.timers.has(STEP_GLOBAL_TIMER)
-    assert engine.timers(FORWARD_MICRO_TIMER).mean() > 0
     if via == "telemetry":
-        names = [s["name"] for s in engine.tracer.export()]
+        # tracing alone records the spans round the dispatches and
+        # synchronizes nothing: it must not change what it traces
+        assert not engine._profile_steps and not syncs
+        assert not engine.timers.timers
+        spans = engine.tracer.export()
+        names = [s["name"] for s in spans]
         assert names.count("fwd_bwd") == 2       # gas=2 micro steps
         assert names.count("optimizer_step") == 1
-        assert all(s["trace_id"] == "train" for s in engine.tracer.export())
+        assert all(s["trace_id"] == "train" for s in spans)
+        assert [s["attrs"] for s in spans if s["name"] == "fwd_bwd"] == \
+            [{"micro_step": 0}, {"micro_step": 1}]
     else:
+        # synchronized timers recorded both phases
+        assert engine._profile_steps and syncs
+        assert engine.timers.has(FORWARD_MICRO_TIMER)
+        assert engine.timers.has(STEP_GLOBAL_TIMER)
+        assert engine.timers(FORWARD_MICRO_TIMER).mean() > 0
         assert not engine.tracer.enabled
 
 
@@ -524,6 +637,111 @@ def test_greedy_parity_telemetry_on_vs_off():
     ref = run(None)
     traced = run(Tracer())
     assert_greedy_parity(ref, traced, label="telemetry")
+
+
+def test_scheduler_step_phase_spans():
+    """One step is a ``step`` span whose children are pack, stage, fetch
+    and commit in that order; ``stage`` (and the kept ``forward``) carry
+    the engine's own record of the put."""
+    from deepspeed_tpu.inference.v2.scheduler import (
+        ContinuousBatchingScheduler)
+
+    eng = tiny_engine()
+    tr = Tracer()
+    sched = ContinuousBatchingScheduler(eng, tracer=tr,
+                                        trace_label="replica-7")
+    sched.submit(1, list(range(1, 12)), max_new_tokens=4)
+    sched.submit(2, list(range(20, 25)), max_new_tokens=4)
+    sched.step()
+    record = dict(eng.last_put)
+    assert record == {"bucket_seqs": 2, "bucket_chunk": 16, "rows": 2,
+                      "valid_tokens": 16, "kv_read_tokens": 16,
+                      "qk_pairs": 11 * 12 // 2 + 5 * 6 // 2,
+                      "free_blocks": eng.state_manager.available_blocks}
+    spans = {s["name"]: s for s in tr.export()}
+    assert set(spans) == {"step", "pack", "stage", "fetch", "commit",
+                          "forward"}
+    step = spans["step"]
+    assert step["parent_id"] is None and step["trace_id"] == "replica-7"
+    phases = [spans[n] for n in ("pack", "stage", "fetch", "commit")]
+    for a, b in zip(phases, phases[1:]):
+        assert a["t_end"] <= b["t_start"]
+    for ph in phases:
+        assert ph["parent_id"] == step["span_id"]
+        assert ph["trace_id"] == "replica-7"
+        assert step["t_start"] <= ph["t_start"] and ph["t_end"] <= step["t_end"]
+    assert spans["stage"]["attrs"] == record
+    assert spans["forward"]["attrs"] == dict(record, n_seqs=2, n_tokens=16)
+    # a decode step: one position a row, every key seen so far read
+    sched.step()
+    assert eng.last_put["bucket_chunk"] == 1
+    assert eng.last_put["kv_read_tokens"] == 12 + 6
+    assert eng.last_put["qk_pairs"] == 12 + 6
+    assert eng.put_totals == {"forwards": 2, "tokens_valid": 18,
+                              "positions_computed": 2 * 16 + 2}
+    # a step with nothing to run is a step with a pack and no more
+    idle = ContinuousBatchingScheduler(tiny_engine(), tracer=Tracer())
+    assert idle.step() == []
+    assert sorted(s["name"] for s in idle.tracer.export()) == \
+        ["pack", "step"]
+
+
+def test_tokens_and_finish_order_identical_traced_or_not():
+    """Plain decoding (no speculation), requests of different lengths:
+    the streamed tokens and the order requests finish in do not depend on
+    the tracer."""
+    from deepspeed_tpu.inference.v2.scheduler import (
+        ContinuousBatchingScheduler)
+
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, VOCAB, size=n).tolist() for n in (9, 40, 3)]
+
+    def run(tracer):
+        sched = ContinuousBatchingScheduler(tiny_engine(), tracer=tracer)
+        events = []
+        for uid, (p, n) in enumerate(zip(prompts, (7, 3, 5))):
+            sched.submit(uid, p, max_new_tokens=n,
+                         on_token=lambda u, t: events.append(("tok", u, t)),
+                         on_finish=lambda r, why: events.append(
+                             ("fin", r.uid, why)))
+        sched.run_to_completion()
+        return json.dumps(events).encode()
+
+    assert run(None) == run(Tracer())
+
+
+def test_replica_loop_spans_and_put_counters():
+    """The worker names what it does between steps (admit_inbox when
+    there was something to admit, one idle_wait per idle period) and
+    publishes the engine's cumulative put counters."""
+    from deepspeed_tpu.serving import ServingConfig, ServingFrontend
+
+    eng = tiny_engine()
+    fe = ServingFrontend([eng], ServingConfig(
+        max_queue_depth=8, telemetry={"enabled": True}))
+    try:
+        h = fe.submit(list(range(1, 10)), max_new_tokens=3)
+        assert fe.wait_all([h], timeout=120)
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline and not any(
+                s["name"] == "idle_wait" and s["attrs"].get("open")
+                for s in fe.tracer.export()):
+            time.sleep(0.002)
+        spans = [s for s in fe.tracer.export()
+                 if s["trace_id"] == "replica-0"]
+        names = [s["name"] for s in spans]
+        assert names.count("admit_inbox") == 1
+        assert names.count("step") == names.count("publish_stats") >= 3
+        # idle before the request and after it: two periods, not one span
+        # per 5 ms wait
+        assert names.count("idle_wait") == 2
+        snap = fe.metrics_snapshot()
+        assert snap["forwards"] == eng.put_totals["forwards"] >= 3
+        assert snap["tokens_valid"] == eng.put_totals["tokens_valid"] == 9 + 3
+        assert snap["positions_computed"] == \
+            eng.put_totals["positions_computed"] >= 16 + 2
+    finally:
+        fe.shutdown(drain=False, timeout=5)
 
 
 def test_frontend_debug_dump_and_prometheus(tmp_path):

@@ -16,6 +16,7 @@ cannot be read back and warns on every later run.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -128,6 +129,38 @@ def test_paged_attention(v5e, pool, chunk):
         return pa._paged_pallas(q, k, v, tbl, sp, nt, interpret=False, **kw)
 
     _compile(attend, *shapes, sharding=SingleDeviceSharding(v5e[0]))
+
+
+def test_the_kernels_carry_their_names(v5e):
+    """``name=`` on each pallas_call: the custom call's HLO instruction and
+    its op_name take it, so a device trace tells the kernels apart by name
+    (``%paged_attention.1``, not ``%closed_call.1``)."""
+    one = SingleDeviceSharding(v5e[0])
+    N, H, D, bs, MB, NB = 8, 16, 128, 64, 32, 512
+    kv = ((NB, H, bs, D), jnp.bfloat16)
+
+    def attend(q, k, v, tbl, sp, nt):
+        with jax.named_scope("attend"):
+            return pa._paged_pallas(q, k, v, tbl, sp, nt, interpret=False)
+
+    text = _compile(attend, ((N, 1, H, D), jnp.bfloat16), kv, kv,
+                    ((N, MB), jnp.int32), ((N,), jnp.int32),
+                    ((N,), jnp.int32), sharding=one)
+    assert re.search(r"%paged_attention(\.\d+)? = .* custom-call\(", text)
+    assert 'op_name="jit(attend)/attend/paged_attention/' in text
+
+    qkv = ((2, 1024, 16, 128), jnp.bfloat16)
+
+    def flash(q, k, v):
+        o_hm, lse = fa._fwd_pallas(q, k, v, True, 512, 512, 0,
+                                   interpret=False)
+        return fa._bwd_pallas(q, k, v, o_hm, lse, o_hm.transpose(0, 2, 1, 3),
+                              True, 512, 512, 0, interpret=False)
+
+    text = _compile(flash, qkv, qkv, qkv, sharding=one)
+    for name in ("flash_attention_fwd", "flash_attention_dq",
+                 "flash_attention_dkv"):
+        assert re.search(rf"%{name}(\.\d+)? = .* custom-call\(", text), name
 
 
 # --------------------------------------------------------------- quantizer
