@@ -598,7 +598,9 @@ def tensor_serve_phase(cfg, seed=0, engine_cfg=None, lens=(256, 100, 200)):
         np.zeros((4,), np.int32),
         np.zeros((4, engine.batch.max_blocks_per_seq), np.int32))
     calls = [ln for ln in exe.as_text().splitlines() if KERNEL_CALL in ln]
-    operand = "[{},{},{},{}]".format(*local[1:])
+    # the kernel reads the stacked pool where it lies: its operand is the
+    # device's whole [L, NB, KH/n, bs, D] shard, not a layer's slab of it
+    operand = "[{},{},{},{},{}]".format(*local)
     check(all(operand in ln for ln in calls),
           f"paged kernel does not run on a {operand} pool shard")
     return {"mesh": {"tensor": n}, "logits_max_rel_err": round(max(errs), 5),

@@ -297,23 +297,36 @@ class InferenceEngineV2:
         totals["forwards"] += 1
         totals["positions_computed"] += bucket_seqs * bucket_chunk
         totals["tokens_valid"] += valid
-        args = (self.params, self.state_manager.kv_cache,
+        kv_cache = self.state_manager.kv_cache
+        args = (self.params, kv_cache,
                 jnp.asarray(arrays["tokens"]),
                 jnp.asarray(arrays["start_pos"]),
                 jnp.asarray(arrays["n_tokens"]),
                 jnp.asarray(arrays["block_tables"]))
-        if verify_width:
-            logits, new_cache = self.paged.forward_verify(
-                *args, verify_width=int(verify_width))
-        else:
-            logits, new_cache = self.paged.forward(*args)
+        # the forward consumes ``kv_cache`` (donated, written in place) and
+        # hands the same memory back as ``new_cache``
+        try:
+            if verify_width:
+                logits, new_cache = self.paged.forward_verify(
+                    *args, verify_width=int(verify_width))
+            else:
+                logits, new_cache = self.paged.forward(*args)
+        except Exception as e:
+            if any(leaf.is_deleted() for leaf in kv_cache.values()):
+                raise RuntimeError(
+                    "the forward failed after it had consumed the donated "
+                    "KV pool: every cached sequence is lost with it and "
+                    "this engine cannot be retried — rebuild it "
+                    "(serving marks the replica DEAD)") from e
+            raise
         # commit sequence state only after the forward was dispatched: a
-        # failed forward leaves seen_tokens unchanged (the step can be
-        # retried) and — critically — never registers blocks whose KV was
-        # never written in the prefix-cache index. Allocation above is safe
-        # either way: the blocks belong to the sequence and return to the
-        # pool at flush. (Assumes each uid appears at most once per batch,
-        # which the scheduler guarantees.)
+        # forward that fails before dispatch leaves the pool and
+        # seen_tokens unchanged (the step can be retried) and — critically
+        # — never registers blocks whose KV was never written in the
+        # prefix-cache index. Allocation above is safe either way: the
+        # blocks belong to the sequence and return to the pool at flush.
+        # (Assumes each uid appears at most once per batch, which the
+        # scheduler guarantees.)
         self.state_manager.kv_cache = new_cache
         for seq, toks in staged:
             seq.seen_tokens += len(toks)

@@ -11,8 +11,9 @@ HBM byte budget buys ~2x the blocks → ~2x the concurrent sequences
 a prefix-cache-shared block therefore shares its scale for free.
 
 Write path (``paged_model.py``): a ragged chunk's KV lands in at most
-``TB = ceil((C-1)/bs) + 2`` pool blocks per sequence, a *static* bound —
-so the quantized write is a read-modify-write of only the touched blocks:
+``TB = (C + 2·bs - 2)//bs`` pool blocks per sequence, a *static* bound —
+so the quantized write is the read-modify-write of only the touched
+blocks that every pool gets (``kv_write.py``), with the code in between:
 
 1. gather the touched int8 blocks and their scales, dequantize;
 2. zero stale slots (positions >= the sequence's context length — content
@@ -34,19 +35,19 @@ speculation under kv_quant is bounded-divergent rather than byte-lossless
 (docs/SERVING.md "KV quantization" interaction matrix).
 
 Read path: the scale planes ride into ``ops/paged_attention.py`` as extra
-operands (``k_scale``/``v_scale`` ``[NB, KH]`` per layer); the Pallas
-kernel dequantizes each streamed block in VMEM with its scalar scale, the
-XLA fallback multiplies the gathered context. TP serving shards the
-planes over the kv-head axis exactly like the pools.
+operands (``k_scale``/``v_scale`` ``[L, NB, KH]``, read at the layer
+index like the pools); the Pallas kernel dequantizes each streamed block
+in VMEM with its scalar scale, the XLA fallback multiplies the gathered
+context. TP serving shards the planes over the kv-head axis exactly like
+the pools.
 """
 
 from __future__ import annotations
 
-from typing import Dict
-
 import jax.numpy as jnp
 
 from ...ops.quantizer import FP8_MAX
+from .kv_write import place_rows
 
 # Symmetric int8: values in [-127, 127] (−128 unused, keeps the code
 # symmetric around zero) with scale = amax / 127.
@@ -119,68 +120,18 @@ def blocks_for_budget(budget_bytes: int, model_cfg, block_size: int,
                // kv_bytes_per_block(model_cfg, block_size, quant, dtype))
 
 
-def touched_block_plan(block_tables, start_pos, n_tokens, chunk: int,
-                       block_size: int, num_blocks: int) -> Dict[str, object]:
-    """Static-shape plan of the pool blocks this step's KV writes touch.
+def quantized_block_write(pool, scale, new_vals, plan, layer):
+    """Merge new K or V rows into layer ``layer`` of a quantized pool (the
+    quantized counterpart of the reference ``linear_blocked_kv_rotary``
+    scatter).
 
-    A row writing ``n_tokens`` new tokens from ``start_pos`` lands in the
-    logical blocks ``start_pos//bs .. (start_pos+n_tokens-1)//bs`` — at
-    most ``TB = (C-1)//bs + 2`` of them for a chunk width C, regardless of
-    alignment. The plan is layer-invariant (same coordinates for every
-    layer's pool), so ``paged_model`` computes it once per forward and
-    closes over it in the scanned layer body.
-
-    Ownership invariant (why the full-block scatter back is safe): the
-    touched window starts at ``start_pos//bs``, and every block at or past
-    that index belongs exclusively to the writing sequence — prefix-cache
-    sharing only ever covers *full* blocks strictly below the matched
-    length (block-aligned), trims into indexed blocks are refused, and
-    padding rows (``n_tokens == 0``) produce an empty window.
-    """
-    N, MB = block_tables.shape
-    bs = block_size
-    TB = (chunk - 1) // bs + 2
-    ctx_len = start_pos + n_tokens                                   # [N]
-    first_blk = start_pos // bs                                      # [N]
-    tidx = first_blk[:, None] + jnp.arange(TB)[None, :]              # [N, TB]
-    ids = jnp.take_along_axis(block_tables,
-                              jnp.clip(tidx, 0, MB - 1), axis=1)     # [N, TB]
-    touched = (tidx * bs < ctx_len[:, None]) & (tidx < MB) & (ids >= 0)
-    # gather side clamps (garbage rows are masked below); scatter side
-    # uses the positive out-of-range sentinel NB, which mode="drop"
-    # really drops (-1 would wrap — same trick as the unquantized write)
-    gather_ids = jnp.where(touched, jnp.clip(ids, 0, num_blocks - 1), 0)
-    scatter_ids = jnp.where(touched, ids, num_blocks)
-    # live KV slots of each touched block: global position < ctx_len.
-    # Slots past that hold stale content (freed tenant / trimmed drafts)
-    # and are zeroed so they can neither inflate the scale nor survive
-    # the re-quantized write-back.
-    slot_pos = tidx[:, :, None] * bs + jnp.arange(bs)[None, None, :]
-    live_slots = (slot_pos < ctx_len[:, None, None]) & touched[:, :, None]
-    # per-token scatter coordinates into the gathered [N, TB, ...] view
-    positions = start_pos[:, None] + jnp.arange(chunk)[None, :]      # [N, C]
-    valid = jnp.arange(chunk)[None, :] < n_tokens[:, None]
-    t_tok = positions // bs - first_blk[:, None]                     # [N, C]
-    n_flat = jnp.repeat(jnp.arange(N), chunk)
-    t_flat = jnp.where(valid, t_tok, TB).reshape(-1)                 # TB drops
-    slot_flat = (positions % bs).reshape(-1)
-    # blocks already holding this sequence's quantized tokens keep a
-    # monotone scale; a freshly allocated block ignores the stale plane
-    # entry of its previous tenant (the "scale invalidation on free")
-    has_prior = (tidx * bs < start_pos[:, None]) & touched
-    return {"gather_ids": gather_ids, "scatter_ids": scatter_ids,
-            "live_slots": live_slots, "has_prior": has_prior,
-            "n_flat": n_flat, "t_flat": t_flat, "slot_flat": slot_flat}
-
-
-def quantized_block_write(pool, scale, new_vals, plan):
-    """Merge new K or V rows into a quantized pool (the quantized
-    counterpart of the reference ``linear_blocked_kv_rotary`` scatter).
-
-    ``pool`` [NB, KH, bs, D] int8 or float8_e4m3fn — the representation
-    is derived from ``pool.dtype``, so the paged forward needs no extra
-    plumbing; ``scale`` [NB, KH] f32; ``new_vals`` [N*C, KH, D] (row
-    order matches ``plan``'s flattened token coordinates). Returns the
+    ``pool`` [L, NB, KH, bs, D] int8 or float8_e4m3fn — the whole stacked
+    cache, of which only the touched blocks of the one layer are gathered
+    and scattered back, so a caller that owns the buffer (the paged
+    forward's scan carry) has it updated in place; the representation is
+    derived from ``pool.dtype``, so the paged forward needs no extra
+    plumbing; ``scale`` [L, NB, KH] f32; ``new_vals`` [N*C, KH, D] (the
+    chunk's rows, sequence-major, as ``plan`` was built). Returns the
     updated (pool, scale). The monotone-scale rule keeps steady-state
     decode exact for both representations: while the scale is unchanged,
     dequantize→requantize round-trips the stored code bit-for-bit
@@ -188,14 +139,13 @@ def quantized_block_write(pool, scale, new_vals, plan):
     ``q·s/s`` is ``q``).
     """
     qmax = qmax_of(pool.dtype)
-    deq = (pool[plan["gather_ids"]].astype(jnp.float32)
-           * scale[plan["gather_ids"]][:, :, :, None, None])
+    old_scale = scale[layer, plan["gather_ids"]]                 # [N, TB, KH]
+    deq = (pool[layer, plan["gather_ids"]].astype(jnp.float32)
+           * old_scale[:, :, :, None, None])
     deq = jnp.where(plan["live_slots"][:, :, None, :, None], deq, 0.0)
-    deq = deq.at[plan["n_flat"], plan["t_flat"], :, plan["slot_flat"], :].set(
-        new_vals.astype(jnp.float32), mode="drop")
+    deq = place_rows(deq, new_vals, plan)
     amax = jnp.max(jnp.abs(deq), axis=(3, 4))                    # [N, TB, KH]
-    prior = jnp.where(plan["has_prior"][:, :, None],
-                      scale[plan["gather_ids"]], 0.0)
+    prior = jnp.where(plan["has_prior"][:, :, None], old_scale, 0.0)
     new_scale = jnp.maximum(jnp.maximum(amax / qmax, prior), SCALE_EPS)
     scaled = deq / new_scale[:, :, :, None, None]
     if pool.dtype == jnp.int8:
@@ -204,6 +154,6 @@ def quantized_block_write(pool, scale, new_vals, plan):
         # float8: the cast rounds to nearest representable — no integer
         # rounding step, and the clip keeps inf out of the pool
         q = jnp.clip(scaled, -qmax, qmax).astype(pool.dtype)
-    pool = pool.at[plan["scatter_ids"]].set(q, mode="drop")
-    scale = scale.at[plan["scatter_ids"]].set(new_scale, mode="drop")
+    pool = pool.at[layer, plan["scatter_ids"]].set(q, mode="drop")
+    scale = scale.at[layer, plan["scatter_ids"]].set(new_scale, mode="drop")
     return pool, scale

@@ -98,12 +98,13 @@ def _attn_pallas_factory(force_interpret=False, **_):
         # mode off-TPU instead of letting the runtime dispatch silently
         # fall back to the XLA gather
         def fn(q, kc, vc, tables, start_pos, n_tokens, alibi_slopes=None,
-               window=0, sm_scale=None, k_scale=None, v_scale=None):
+               window=0, sm_scale=None, k_scale=None, v_scale=None,
+               layer=None):
             return pa._paged_pallas(q, kc, vc, tables, start_pos, n_tokens,
                                     alibi_slopes=alibi_slopes,
                                     window=window, sm_scale=sm_scale,
                                     k_scale=k_scale, v_scale=v_scale,
-                                    interpret=True)
+                                    layer=layer, interpret=True)
 
         fn.__name__ = "paged_attention_interpret"
         return fn

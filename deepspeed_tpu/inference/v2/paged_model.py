@@ -9,12 +9,18 @@ function processes a mixed prefill/decode ragged batch with static shapes:
 - tokens [N, C] padded chunks, per-seq ``start_pos`` (tokens already
   cached) and ``n_tokens`` (valid width) — Dynamic SplitFuse feeds both
   prompt chunks and single decode tokens through this same path;
-- paged KV cache [L, NB, KH, bs, D] with per-seq block tables; writes are
-  a drop-mode scatter at (block, slot), reads go through the Pallas
-  paged-attention kernel (``ops/paged_attention.py``) which walks each
-  sequence's block table directly — no dense [N, max_ctx, KH, D] gather,
-  no GQA ``jnp.repeat`` (the XLA gather formulation remains as the
-  off-TPU fallback inside ``paged_attention``);
+- paged KV cache [L, NB, KH, bs, D] with per-seq block tables. **The pool
+  stays where it is**: the jit donates it, the layer scan carries it
+  whole, writes are a read-modify-write of the touched blocks of that
+  one buffer at (layer, block) (``kv_write.py``), and reads go through
+  the Pallas paged-attention kernel (``ops/paged_attention.py``), which
+  takes the stacked pool plus the layer index and walks each sequence's
+  block table directly — no per-layer slab is sliced out or written
+  back, no dense [N, max_ctx, KH, D] gather, no GQA ``jnp.repeat`` (the
+  XLA gather formulation remains as the off-TPU fallback inside
+  ``paged_attention``). The caller's ``kv_cache`` is consumed: the
+  returned cache is the same memory (docs/SERVING.md "The pool
+  contract");
 - returns logits only at each sequence's last valid token (logits_gather);
 - weight serving (``weight_quant.py``): when the param tree holds
   blockwise-quantized ``{"qw", "qs"}`` nodes, every projection/MLP/unembed
@@ -36,6 +42,8 @@ from jax import lax
 
 from ...models.transformer import (CausalLM, _linear, _norm, alibi_slopes,
                                    apply_rope, rope_table)
+from .kv_quant import quantized_block_write
+from .kv_write import block_write, touched_block_plan
 
 
 class PagedCausalLM:
@@ -69,7 +77,9 @@ class PagedCausalLM:
         from .modules import instantiate_attn
 
         self._attn_raw = instantiate_attn(self.cfg, name=attn_impl)
-        self.forward = jax.jit(self._forward)
+        # the KV pool (argument 1) is donated: the returned cache is the
+        # caller's buffer, written in place
+        self.forward = jax.jit(self._forward, donate_argnums=(1,))
         # trailing-positions logits variant for speculative verification
         # (spec/): same forward, but the unembed runs over each row's LAST
         # ``verify_width`` positions (right-aligned) so the target's
@@ -77,44 +87,47 @@ class PagedCausalLM:
         # materializing [N, C, vocab] when only K+1 << C positions matter.
         # A separate compiled program per width bucket — the default path
         # stays byte-identical.
-        self.forward_verify = jax.jit(self._forward,
+        self.forward_verify = jax.jit(self._forward, donate_argnums=(1,),
                                       static_argnames=("verify_width",))
 
-    def _attend(self, q, kc, vc, block_tables, start_pos, n_tokens, slopes,
-                window=0, k_scale=None, v_scale=None):
-        """Paged attention, shard_mapped over the tensor axis when TP>1.
-        ``k_scale``/``v_scale`` [NB, KH]: per-(block, kv-head) dequant
-        scales for int8 pools (kv_quant.py) — sharded over the kv-head
-        axis exactly like the pools, so TP serving is preserved."""
+    def _attend(self, q, pools, layer, block_tables, start_pos, n_tokens,
+                slopes, window=0):
+        """Paged attention over layer ``layer`` of the stacked pools,
+        shard_mapped over the tensor axis when TP>1. ``pools``: the cache
+        tree — ``k``/``v`` [L, NB, KH, bs, D], plus ``k_scale``/``v_scale``
+        [L, NB, KH] per-(block, kv-head) dequant scales for int8 pools
+        (kv_quant.py), sharded over the kv-head axis exactly like the
+        pools, so TP serving is preserved."""
         sm_scale = self.cfg.attn_scale
-        quant_kw = ({} if k_scale is None
-                    else {"k_scale": k_scale, "v_scale": v_scale})
+        quant_kw = ({"k_scale": pools["k_scale"], "v_scale": pools["v_scale"]}
+                    if "k_scale" in pools else {})
         if self.tp == 1:
-            return self._attn_raw(q, kc, vc, block_tables, start_pos,
-                                  n_tokens, alibi_slopes=slopes,
+            return self._attn_raw(q, pools["k"], pools["v"], block_tables,
+                                  start_pos, n_tokens, alibi_slopes=slopes,
                                   window=window, sm_scale=sm_scale,
-                                  **quant_kw)
+                                  layer=layer, **quant_kw)
         from jax.sharding import PartitionSpec as P
         from ...compat import shard_map
 
         q_spec = P(None, None, "tensor", None)        # [N, C, H, D]
-        kv_spec = P(None, "tensor", None, None)       # [NB, KH, bs, D]
+        kv_spec = P(None, None, "tensor", None, None)  # [L, NB, KH, bs, D]
         rep = P()
 
-        operands = [q, kc, vc, block_tables, start_pos, n_tokens]
-        in_specs = [q_spec, kv_spec, kv_spec, rep, rep, rep]
+        operands = [q, pools["k"], pools["v"], layer, block_tables,
+                    start_pos, n_tokens]
+        in_specs = [q_spec, kv_spec, kv_spec, rep, rep, rep, rep]
         if slopes is not None:
             operands.append(slopes)
             in_specs.append(P("tensor"))
-        if k_scale is not None:
-            operands += [k_scale, v_scale]
-            in_specs += [P(None, "tensor"), P(None, "tensor")]  # [NB, KH]
+        if quant_kw:
+            operands += [pools["k_scale"], pools["v_scale"]]
+            in_specs += [P(None, None, "tensor")] * 2   # [L, NB, KH]
 
         attn = self._attn_raw
         has_slopes = slopes is not None
-        has_scales = k_scale is not None
+        has_scales = bool(quant_kw)
 
-        def local(q, kc, vc, tbl, sp, nt, *rest):
+        def local(q, kc, vc, lyr, tbl, sp, nt, *rest):
             i = 0
             sl = None
             if has_slopes:
@@ -122,7 +135,7 @@ class PagedCausalLM:
             kw = ({"k_scale": rest[i], "v_scale": rest[i + 1]}
                   if has_scales else {})
             return attn(q, kc, vc, tbl, sp, nt, alibi_slopes=sl,
-                        window=window, sm_scale=sm_scale, **kw)
+                        window=window, sm_scale=sm_scale, layer=lyr, **kw)
 
         return shard_map(
             local, mesh=self.mesh, in_specs=tuple(in_specs),
@@ -135,7 +148,8 @@ class PagedCausalLM:
         kv_cache {k,v}: [L, NB, KH, bs, D] — plus {k_scale,v_scale}
         [L, NB, KH] when the pools are int8-quantized (kv_quant.py); the
         pytree structure selects the compiled program, so the
-        unquantized trace is untouched.
+        unquantized trace is untouched. The jitted entry points donate
+        ``kv_cache``: the returned cache is its memory, updated in place.
 
         Returns (last_logits [N, V], new_kv_cache) — or, with static
         ``verify_width`` W > 0, (logits [N, W, V], new_kv_cache) holding
@@ -148,7 +162,6 @@ class PagedCausalLM:
         N, C = tokens.shape
         bs = self.block_size
         NB = kv_cache["k"].shape[1]
-        MB = block_tables.shape[1]
         dt = cfg.dtype
         # Program scopes (docs/OBSERVABILITY.md "XLA alignment"): every
         # operation carries in its HLO op_name the part of the program
@@ -156,7 +169,8 @@ class PagedCausalLM:
         # attn_out, mlp}, final_norm, logits — the vocabulary
         # models/transformer.py shares. What runs under ``layers`` but
         # under none of the block's scopes is the scan's own plumbing:
-        # slices of the stacked weights and pools, the pools written back.
+        # slices of the stacked weights. (The pools are not in it: they
+        # ride in the carry and are neither sliced nor written back.)
         scope = jax.named_scope
 
         with scope("embed"):
@@ -180,32 +194,16 @@ class PagedCausalLM:
                 x = x + params["embed"]["wpe"][positions].astype(dt)
                 cos = sin = None
 
-        # int8 KV quantization (kv_quant.py, docs/SERVING.md "KV
-        # quantization"): detected from the cache pytree so the disabled
-        # path below is byte-for-byte the historical program. The touched-
-        # block plan is layer-invariant — computed once, closed over by
-        # every scanned layer body.
+        # The KV write plan (kv_write.py): which pool blocks this step's
+        # rows touch and where in them each row lands. Layer-invariant —
+        # computed once, closed over by every scanned layer body. int8/fp8
+        # pools (kv_quant.py, docs/SERVING.md "KV quantization") are
+        # detected from the cache pytree, so the unquantized program holds
+        # none of their code.
         quant = "k_scale" in kv_cache
         with scope("kv_write"):
-            # scatter coordinates for KV writes: (pool block, slot-in-block)
-            valid = jnp.arange(C)[None, :] < n_tokens[:, None]  # [N, C]
-            blk_idx = positions // bs                           # [N, C]
-            blk_off = positions % bs
-            blk_ids = jnp.take_along_axis(
-                block_tables, jnp.clip(blk_idx, 0, MB - 1), axis=1)  # [N, C]
-            # invalid tokens → sentinel NB: a *positive* out-of-range id,
-            # which mode="drop" really drops (-1 would wrap to pool block
-            # NB-1 — JAX normalizes negative scatter indices before the
-            # bounds check)
-            write_blk = jnp.where(valid & (blk_ids >= 0), blk_ids,
-                                  NB).reshape(-1)
-            write_off = blk_off.reshape(-1)
-            if quant:
-                from .kv_quant import (quantized_block_write,
-                                       touched_block_plan)
-
-                kv_plan = touched_block_plan(block_tables, start_pos,
-                                             n_tokens, C, bs, NB)
+            kv_plan = touched_block_plan(block_tables, start_pos, n_tokens,
+                                         C, bs, NB)
 
         def rope_q(q):
             if cfg.position != "rope":
@@ -215,12 +213,12 @@ class PagedCausalLM:
             return apply_rope(q, cos, sin, cfg.rope_interleaved)
 
         def block_for(window):
-            def block(x, xs):
-                if quant:
-                    lp, kc, vc, ks, vs = xs   # + scale planes [NB, KH]
-                else:
-                    lp, kc, vc = xs           # kc/vc [NB, KH, bs, D]
-                    ks = vs = None
+            def block(carry, xs):
+                # the whole cache tree rides in the carry next to x, so
+                # the loop updates the one (donated) buffer in place;
+                # ``layer`` says where in it this iteration works
+                x, pools = carry
+                lp, layer = xs
                 with scope("attn_norm"):
                     h1 = _norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"),
                                cfg.norm, cfg.norm_eps)
@@ -233,55 +231,46 @@ class PagedCausalLM:
                     v = _linear(h1, lp["wv"], lp.get("wv_b"),
                                 dt).reshape(N, C, kvh, hd)
 
+                # paged KV write (reference linear_blocked_kv_rotary
+                # kernel): token t lands at pool[layer, block(t), :,
+                # slot(t), :] — a read-modify-write of only the touched
+                # blocks, in place; quantized pools dequantize, merge and
+                # re-quantize them at the monotone per-block scale
                 with scope("kv_write"):
-                    if quant:
-                        # quantized paged KV write: read-modify-write of
-                        # only the touched blocks — dequantize, merge the
-                        # new tokens, re-quantize at the monotone
-                        # per-block scale
-                        kc, ks = quantized_block_write(
-                            kc, ks, k.reshape(-1, kvh, hd), kv_plan)
-                        vc, vs = quantized_block_write(
-                            vc, vs, v.reshape(-1, kvh, hd), kv_plan)
-                    else:
-                        # paged KV write (reference
-                        # linear_blocked_kv_rotary kernel): token t lands
-                        # at kc[block(t), :, slot(t), :]
-                        kc = kc.at[write_blk, :, write_off, :].set(
-                            k.reshape(-1, kvh, hd), mode="drop")
-                        vc = vc.at[write_blk, :, write_off, :].set(
-                            v.reshape(-1, kvh, hd), mode="drop")
+                    pools = dict(pools)
+                    for name, rows in (("k", k), ("v", v)):
+                        rows = rows.reshape(-1, kvh, hd)
+                        if quant:
+                            sname = name + "_scale"
+                            pools[name], pools[sname] = quantized_block_write(
+                                pools[name], pools[sname], rows, kv_plan,
+                                layer)
+                        else:
+                            pools[name] = block_write(pools[name], rows,
+                                                      kv_plan, layer)
 
-                # paged read: Pallas block-table walk (reference
-                # blocked_flash; Mistral sliding window clamps the walk to
-                # the last W positions; TP shard_maps the walk over the
-                # tensor axis; int8 pools dequantize in-kernel via the
-                # scale operands)
+                # paged read: Pallas block-table walk over this layer of
+                # the stacked pools (reference blocked_flash; Mistral
+                # sliding window clamps the walk to the last W positions;
+                # TP shard_maps the walk over the tensor axis; int8 pools
+                # dequantize in-kernel via the scale operands)
                 with scope("attend"):
-                    attn = self._attend(q, kc, vc, block_tables, start_pos,
-                                        n_tokens, slopes, window=window,
-                                        k_scale=ks, v_scale=vs)
+                    attn = self._attend(q, pools, layer, block_tables,
+                                        start_pos, n_tokens, slopes,
+                                        window=window)
                 with scope("attn_out"):
                     attn_out = _linear(attn.reshape(N, C, nh * hd),
                                        lp["wo"], lp.get("wo_b"), dt)
                 with scope("mlp"):      # norm, MLP and the residual adds
                     x = self.model._attn_mlp_merge(x, attn_out, lp, h1)
-                return x, ((kc, vc, ks, vs) if quant else (kc, vc))
+                return (x, pools), None
             return block
 
         with scope("layers"):
-            if quant:
-                x, (new_k, new_v, new_ks, new_vs) = self.model._scan_layers(
-                    block_for, x, (params["layers"], kv_cache["k"],
-                                   kv_cache["v"], kv_cache["k_scale"],
-                                   kv_cache["v_scale"]))
-                new_cache = {"k": new_k, "v": new_v,
-                             "k_scale": new_ks, "v_scale": new_vs}
-            else:
-                x, (new_k, new_v) = self.model._scan_layers(
-                    block_for, x, (params["layers"], kv_cache["k"],
-                                   kv_cache["v"]))
-                new_cache = {"k": new_k, "v": new_v}
+            (x, new_cache), _ = self.model._scan_layers(
+                block_for, (x, dict(kv_cache)),
+                (params["layers"],
+                 jnp.arange(cfg.num_layers, dtype=jnp.int32)))
         with scope("final_norm"):
             x = _norm(x, params["final_norm"]["w"],
                       params["final_norm"].get("b"), cfg.norm, cfg.norm_eps)

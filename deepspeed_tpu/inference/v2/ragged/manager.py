@@ -146,17 +146,20 @@ class DSStateManager:
             return jax.jit(lambda: jnp.zeros(shp, adt),
                            out_shardings=shard)()
 
-        zeros = _alloc(shape, pool_dt, sharding)
-        self.kv_cache = {"k": zeros, "v": zeros}
+        # one buffer per leaf: the forward donates the cache and writes it
+        # in place (paged_model.py), and one buffer cannot be donated twice
+        self.kv_cache = {"k": _alloc(shape, pool_dt, sharding),
+                         "v": _alloc(shape, pool_dt, sharding)}
         if self.kv_quant:
             # symmetric per-(layer, block, kv-head) scales, indexed by
             # pool block id — a prefix-shared block shares its scale for
             # free; freed blocks' stale entries are ignored (not reset) by
             # the fresh-block write rule in kv_quant.quantized_block_write
             sshape = shape[:3]
-            szeros = _alloc(sshape, jnp.float32, scale_sharding)
-            self.kv_cache["k_scale"] = szeros
-            self.kv_cache["v_scale"] = szeros
+            self.kv_cache["k_scale"] = _alloc(sshape, jnp.float32,
+                                              scale_sharding)
+            self.kv_cache["v_scale"] = _alloc(sshape, jnp.float32,
+                                              scale_sharding)
 
     # -- sequence registry -------------------------------------------------
     def get_or_create_sequence(self, uid: int) -> DSSequenceDescriptor:

@@ -11,10 +11,13 @@ softmax.
 
 - **q** [N, C, H, D]: per-sequence chunk of new tokens (C = 1 for pure
   decode; Dynamic SplitFuse feeds prompt chunks through the same path).
-- **KV pool** [NB, KH, bs, D]: the paged cache. The pool's per-(block,
-  kv-head) slab is the trailing [bs, D] — exactly one tileable VMEM block,
-  DMA'd directly by a BlockSpec index map that *dereferences the block
-  table* (scalar-prefetched, so indices are known before the body runs).
+- **KV pool** [L, NB, KH, bs, D] plus a ``layer`` scalar: the engine's
+  whole stacked cache, read where it lies — no layer's slab is sliced out
+  of it. The pool's per-(layer, block, kv-head) slab is the trailing
+  [bs, D] — exactly one tileable VMEM block, DMA'd directly by a BlockSpec
+  index map that *dereferences the layer and the block table* (both
+  scalar-prefetched, so indices are known before the body runs). A
+  [NB, KH, bs, D] pool with no ``layer`` is the one-layer case.
   No [N, max_ctx, H, D] gather is ever materialized in HBM and GQA needs
   no ``jnp.repeat`` — each grid step matmuls the [G·C, D] query group
   against the shared [bs, D] KV block.
@@ -57,8 +60,8 @@ def _use_interpret() -> bool:
 
 # ------------------------------------------------------------------- kernel
 
-def _paged_kernel(tables_ref, startp_ref, ntok_ref, slopes_ref, q_ref,
-                  k_ref, v_ref, *refs, block_size: int, chunk: int,
+def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
+                  q_ref, k_ref, v_ref, *refs, block_size: int, chunk: int,
                   groups: int, sm_scale: float, alibi: bool, window: int,
                   quant: bool):
     """One (n, kh, b) grid step: fold table block b of sequence n into the
@@ -93,8 +96,8 @@ def _paged_kernel(tables_ref, startp_ref, ntok_ref, slopes_ref, q_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * sm_scale        # [G*C, D]
-        k = k_ref[0, 0].astype(jnp.float32)                   # [bs, D]
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0].astype(jnp.float32)                # [bs, D]
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         if quant:
             si = b * pl.num_programs(1) + kh
             k = k * ks_ref[0, 0, si]
@@ -155,11 +158,31 @@ def _clamp_tables(block_tables, ctx_len, block_size, start_pos=None,
     return jnp.maximum(tbl, 0).astype(jnp.int32)
 
 
+def _stacked(k_pool, v_pool, k_scale, v_scale, layer):
+    """The one calling convention underneath: stacked [L, NB, KH, bs, D]
+    pools (and [L, NB, KH] scale planes) read at a ``layer`` scalar. A
+    per-layer [NB, KH, bs, D] pool is layer 0 of a stack of one — a
+    reshape, not a copy."""
+    if k_pool.ndim == 4:
+        if layer is not None:
+            raise ValueError("a layer index needs the stacked "
+                             "[L, NB, KH, bs, D] pool")
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
+    elif layer is None:
+        raise ValueError("a stacked [L, NB, KH, bs, D] pool needs its layer")
+    return k_pool, v_pool, k_scale, v_scale, jnp.asarray(layer, jnp.int32)
+
+
 def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
                   alibi_slopes=None, window: int = 0, sm_scale=None,
-                  k_scale=None, v_scale=None, interpret: bool):
+                  k_scale=None, v_scale=None, layer=None, interpret: bool):
+    k_pool, v_pool, k_scale, v_scale, layer = _stacked(
+        k_pool, v_pool, k_scale, v_scale, layer)
     N, C, H, D = q.shape
-    NB, KH, bs, _ = k_pool.shape
+    _, NB, KH, bs, _ = k_pool.shape
     G = H // KH
     MB = block_tables.shape[1]
     quant = k_scale is not None
@@ -180,13 +203,14 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
     kernel = functools.partial(_paged_kernel, block_size=bs, chunk=C,
                                groups=G, sm_scale=sm_scale, alibi=alibi,
                                window=window, quant=quant)
-    # index maps see every scalar-prefetch ref; only the table is used
+    # index maps see every scalar-prefetch ref; only the layer and the
+    # table are used
+    kv_spec = pl.BlockSpec(
+        (1, 1, 1, bs, D),
+        lambda n, kh, b, lyr, tbl, *_: (lyr[0], tbl[n, b], kh, 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, G * C, D), lambda n, kh, b, *_: (n, kh, 0, 0)),
-        pl.BlockSpec((1, 1, bs, D),
-                     lambda n, kh, b, tbl, *_: (tbl[n, b], kh, 0, 0)),
-        pl.BlockSpec((1, 1, bs, D),
-                     lambda n, kh, b, tbl, *_: (tbl[n, b], kh, 0, 0)),
+        kv_spec, kv_spec,
     ]
     operands = [qh, k_pool, v_pool]
     if quant:
@@ -201,10 +225,10 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
                                   memory_space=pltpu.SMEM)
         in_specs += [scale_spec, scale_spec]
         operands += [
-            jnp.asarray(s, jnp.float32)[tables].reshape(N, 1, MB * KH)
+            jnp.asarray(s, jnp.float32)[layer, tables].reshape(N, 1, MB * KH)
             for s in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(N, KH, MB),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G * C, D),
@@ -224,7 +248,7 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(tables, startp, ntok, slopes, *operands)
+    )(layer.reshape(1), tables, startp, ntok, slopes, *operands)
     # [N, KH, G*C, D] -> [N, C, H, D]
     return (o.reshape(N, KH, G, C, D).transpose(0, 3, 1, 2, 4)
             .reshape(N, C, H, D))
@@ -234,14 +258,19 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
 
 def paged_attention_xla(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
                         alibi_slopes=None, window: int = 0, sm_scale=None,
-                        k_scale=None, v_scale=None):
+                        k_scale=None, v_scale=None, layer=None):
     """Dense-gather formulation (the pre-Pallas path): gather the table into
-    [N, MB*bs, KH, D] and mask. Numerically the kernel's reference.
-    ``k_scale``/``v_scale`` [NB, KH]: per-(block, kv-head) dequantization
-    scales for int8 pools (docs/SERVING.md "KV quantization") — gathered
-    through the same block table and applied to the gathered context."""
+    [N, MB*bs, KH, D] and mask. Numerically the kernel's reference, with
+    the kernel's arguments: stacked pools read at ``layer`` (only the
+    table's blocks of that layer are gathered), or one layer's pool.
+    ``k_scale``/``v_scale`` [L, NB, KH]: per-(block, kv-head)
+    dequantization scales for int8 pools (docs/SERVING.md "KV
+    quantization") — gathered through the same block table and applied to
+    the gathered context."""
+    k_pool, v_pool, k_scale, v_scale, layer = _stacked(
+        k_pool, v_pool, k_scale, v_scale, layer)
     N, C, H, D = q.shape
-    NB, KH, bs, _ = k_pool.shape
+    _, NB, KH, bs, _ = k_pool.shape
     G = H // KH
     MB = block_tables.shape[1]
     sm_scale = 1.0 / math.sqrt(D) if sm_scale is None else float(sm_scale)
@@ -249,13 +278,13 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
     ctx_positions = jnp.arange(MB * bs)
     tbl = jnp.maximum(block_tables, 0)
     # pool [NB, KH, bs, D] -> per-seq [N, MB, KH, bs, D] -> [N, KH, MB*bs, D]
-    k_ctx = k_pool[tbl]
-    v_ctx = v_pool[tbl]
+    k_ctx = k_pool[layer, tbl]
+    v_ctx = v_pool[layer, tbl]
     if k_scale is not None:
         k_ctx = (k_ctx.astype(jnp.float32)
-                 * k_scale[tbl][:, :, :, None, None]).astype(q.dtype)
+                 * k_scale[layer, tbl][:, :, :, None, None]).astype(q.dtype)
         v_ctx = (v_ctx.astype(jnp.float32)
-                 * v_scale[tbl][:, :, :, None, None]).astype(q.dtype)
+                 * v_scale[layer, tbl][:, :, :, None, None]).astype(q.dtype)
     k_ctx = k_ctx.transpose(0, 2, 1, 3, 4).reshape(N, KH, MB * bs, D)
     v_ctx = v_ctx.transpose(0, 2, 1, 3, 4).reshape(N, KH, MB * bs, D)
 
@@ -293,16 +322,19 @@ def pallas_supported(num_heads: int, kv_heads: int, head_dim: int,
 
 def _pallas_ok(q, k_pool) -> bool:
     N, C, H, D = q.shape
-    KH = k_pool.shape[1]
+    KH = k_pool.shape[-3]
     return pallas_supported(H, KH, D)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
                     alibi_slopes=None, window: int = 0, sm_scale=None,
-                    k_scale=None, v_scale=None):
+                    k_scale=None, v_scale=None, layer=None):
     """Block-table paged attention.
 
-    q [N, C, H, D]; k/v pool [NB, KH, bs, D]; block_tables [N, MB]
+    q [N, C, H, D]; k/v pool [L, NB, KH, bs, D] read at the scalar
+    ``layer`` (traced or static) — the serving forward hands over its
+    whole cache and no slab of it is materialised — or one layer's
+    [NB, KH, bs, D] with ``layer`` left out; block_tables [N, MB]
     (entries < 0 = unallocated); start_pos/n_tokens [N]. The pool must
     already contain this chunk's K/V (write-then-attend, like the
     reference's blocked_kv_rotary-then-blocked_flash sequence).
@@ -311,19 +343,20 @@ def paged_attention(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
     ``window`` > 0: sliding-window attention (Mistral serving — reference
     inference/v2/model_implementations/mistral/model.py:202); KV blocks
     wholly before the window are skipped for compute and DMA.
-    ``k_scale``/``v_scale`` [NB, KH]: per-(block, kv-head) dequantization
-    scales for int8 KV pools (docs/SERVING.md "KV quantization") —
-    dequantization happens inside the kernel (VMEM) / after the gather
-    (XLA path), so HBM only ever holds the int8 pool.
+    ``k_scale``/``v_scale`` [L, NB, KH] (or [NB, KH] beside a one-layer
+    pool): per-(block, kv-head) dequantization scales for int8 KV pools
+    (docs/SERVING.md "KV quantization") — dequantization happens inside
+    the kernel (VMEM) / after the gather (XLA path), so HBM only ever
+    holds the int8 pool.
     Rows beyond n_tokens are garbage (masked out downstream).
     """
     if _pallas_ok(q, k_pool):
         return _paged_pallas(q, k_pool, v_pool, block_tables, start_pos,
                              n_tokens, alibi_slopes=alibi_slopes,
                              window=window, sm_scale=sm_scale,
-                             k_scale=k_scale, v_scale=v_scale,
+                             k_scale=k_scale, v_scale=v_scale, layer=layer,
                              interpret=_use_interpret())
     return paged_attention_xla(q, k_pool, v_pool, block_tables, start_pos,
                                n_tokens, alibi_slopes=alibi_slopes,
                                window=window, sm_scale=sm_scale,
-                               k_scale=k_scale, v_scale=v_scale)
+                               k_scale=k_scale, v_scale=v_scale, layer=layer)

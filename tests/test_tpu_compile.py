@@ -15,6 +15,7 @@ compile cache is off around them: an entry written for an unattached chip
 cannot be read back and warns on every later run.
 """
 
+import math
 import os
 import re
 
@@ -115,20 +116,82 @@ def test_flash_call_on_a_four_device_mesh(v5e, mesh_axes, monkeypatch):
                          ids=lambda d: jnp.dtype(d).name)
 @pytest.mark.parametrize("chunk", [1, 8, 256])
 def test_paged_attention(v5e, pool, chunk):
-    N, H, KH, D, bs, MB, NB = 8, 16, 16, 128, 64, 32, 512
+    """The kernel as the serving forward calls it: the whole stacked pool
+    [L, NB, KH, bs, D] and a traced layer scalar."""
+    L, N, H, KH, D, bs, MB, NB = 4, 8, 16, 16, 128, 64, 32, 512
     quant = pool in (jnp.int8, FP8)
     qdt = jnp.float32 if pool == jnp.float32 else jnp.bfloat16
-    shapes = [((N, chunk, H, D), qdt), ((NB, KH, bs, D), pool),
-              ((NB, KH, bs, D), pool), ((N, MB), jnp.int32),
-              ((N,), jnp.int32), ((N,), jnp.int32)]
+    shapes = [((N, chunk, H, D), qdt), ((L, NB, KH, bs, D), pool),
+              ((L, NB, KH, bs, D), pool), ((), jnp.int32),
+              ((N, MB), jnp.int32), ((N,), jnp.int32), ((N,), jnp.int32)]
     if quant:
-        shapes += [((NB, KH), jnp.float32)] * 2      # the scale planes
+        shapes += [((L, NB, KH), jnp.float32)] * 2   # the scale planes
 
-    def attend(q, k, v, tbl, sp, nt, *scales):
+    def attend(q, k, v, layer, tbl, sp, nt, *scales):
         kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
-        return pa._paged_pallas(q, k, v, tbl, sp, nt, interpret=False, **kw)
+        return pa._paged_pallas(q, k, v, tbl, sp, nt, layer=layer,
+                                interpret=False, **kw)
 
     _compile(attend, *shapes, sharding=SingleDeviceSharding(v5e[0]))
+
+
+@pytest.mark.parametrize("bucket", [(1, 1), (16, 1), (8, 256)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.int8],
+                         ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("entry", ["forward", "forward_verify"])
+def test_paged_forward_keeps_the_pool_in_place(v5e, entry, pool, bucket,
+                                               monkeypatch):
+    """The serving forward at Pythia-1.4B widths (6 of its 24 layers, the
+    benchmark's 336 blocks of 64 tokens): the chip's compiler aliases every
+    pool leaf to the output and keeps its temporaries under ONE layer's
+    slab. This is the reading that decides how the write is formulated:
+    XLA ran a per-token scatter ``pool.at[layer, blk, :, slot, :]`` behind
+    two transposing copies of the whole pool (temporaries of one pool leaf
+    at [16, 1]), which the CPU backend's compile does not show; the
+    whole-block scatter of ``kv_write.py`` is the pool's own layout."""
+    import dataclasses
+
+    from deepspeed_tpu.inference.v2 import modules
+    from deepspeed_tpu.inference.v2.paged_model import PagedCausalLM
+    from deepspeed_tpu.models import transformer as tr
+
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(modules, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(tr.PYTHIA_1B4, num_layers=6,
+                              dtype=jnp.bfloat16)
+    model = tr.CausalLM(cfg)
+    bs, NB, MB = 64, 336, 32
+    paged = PagedCausalLM(model, bs, MB)
+    one = SingleDeviceSharding(v5e[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree.map(
+        lambda a: spec(a.shape, jnp.bfloat16),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    shape = (cfg.num_layers, NB, cfg.kv_heads, bs, cfg.head_dim)
+    cache = {"k": spec(shape, pool), "v": spec(shape, pool)}
+    if pool == jnp.int8:
+        cache["k_scale"] = spec(shape[:3], jnp.float32)
+        cache["v_scale"] = spec(shape[:3], jnp.float32)
+    N, C = bucket
+    kw = {"verify_width": 4} if entry == "forward_verify" else {}
+    compiled = getattr(paged, entry).lower(
+        params, cache, spec((N, C), jnp.int32), spec((N,), jnp.int32),
+        spec((N,), jnp.int32), spec((N, MB), jnp.int32), **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+    def nbytes(s):
+        return math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(nbytes(s) for s in cache.values())
+    slab = nbytes(cache["k"]) // cfg.num_layers
+    assert mem.temp_size_in_bytes < slab, (
+        f"{mem.temp_size_in_bytes} B of temporaries against a layer's slab "
+        f"of {slab} B: the pool is being copied")
 
 
 def test_the_kernels_carry_their_names(v5e):
@@ -137,7 +200,7 @@ def test_the_kernels_carry_their_names(v5e):
     (``%paged_attention.1``, not ``%closed_call.1``)."""
     one = SingleDeviceSharding(v5e[0])
     N, H, D, bs, MB, NB = 8, 16, 128, 64, 32, 512
-    kv = ((NB, H, bs, D), jnp.bfloat16)
+    kv = ((NB, H, bs, D), jnp.bfloat16)      # a one-layer pool
 
     def attend(q, k, v, tbl, sp, nt):
         with jax.named_scope("attend"):
