@@ -5,9 +5,10 @@
 Everything a number rests on lives here, where a PR that claims a gain
 cannot change it: traffic generation (``traffic.py``), metric arithmetic
 (``arithmetic.py``), the peaks table and FLOP/byte functions
-(``peaks.py``), the plain references (``reference.py``), the trace reducer
-(``trace.py``) and the comparison that decides ``correct``. From the
-program it takes only the system under test and its spans and counters.
-See ``README.md`` for how a later PR adds a configuration, a cell, a
-traffic mix or a metric as files.
+(``peaks.py``), each block type's plain reference and parameter count
+(``blocks/<block>.py``, found by the name a configuration's file gives),
+the trace reducer (``trace.py``) and the comparison that decides
+``correct``. From the program it takes only the system under test and its
+spans and counters. See ``README.md`` for how a later PR adds a
+configuration, a block type, a cell, a traffic mix or a metric as files.
 """

@@ -1,5 +1,7 @@
 """Metric arithmetic, kept with the benchmark so every PR computes a number
-the same way. Pure functions of plain lists; tested against hand counts."""
+the same way. Pure functions of plain lists, tested against hand counts;
+at the end the two measures of disagreement between the program's output
+and a block's reference, which every block's check shares."""
 
 from __future__ import annotations
 
@@ -86,3 +88,24 @@ def longest_silence(times: Iterable[float], t0: float, t1: float) -> float:
     inside = sorted(t for t in times if t0 <= t < t1)
     edges = [t0] + inside + [t1]
     return max(b - a for a, b in zip(edges, edges[1:]))
+
+
+def rms_rel_err(got, want) -> float:
+    """Root-mean-square disagreement over the reference's RMS: averages
+    over the whole vocabulary, so it moves with the precision of the
+    arithmetic and not with one unlucky logit."""
+    import numpy as np
+
+    got = np.asarray(got, np.float64)
+    want = np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2))
+                 / (np.sqrt(np.mean(want ** 2)) + 1e-12))
+
+
+def max_rel_err(got, want) -> float:
+    """Largest disagreement relative to the reference's range."""
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-12))

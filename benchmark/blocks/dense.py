@@ -1,7 +1,12 @@
-"""Plain references: the two block types' forward pass and loss in
-straightforward float32 ``jax.numpy`` — no kernel, no cache, no batching
-tricks, independent of ``deepspeed_tpu/models/transformer.py``. Written
-from the published descriptions:
+"""The ``dense`` block: a decoder layer of attention and one MLP that
+every token passes through — its plain reference (forward pass and loss)
+and its arithmetic, found by the name a configuration's file gives
+(``"block": "dense"``; ``manifest.resolve``).
+
+The reference is straightforward float32 ``jax.numpy`` — no kernel, no
+cache, no batching tricks, independent of
+``deepspeed_tpu/models/transformer.py``. Written from the published
+descriptions:
 
 - GPT-NeoX (Pythia): LayerNorm with bias, rotary on the first
   ``rotary_pct·head_dim`` dims (rotate-half pairing), exact GELU, biases
@@ -18,6 +23,10 @@ The only thing shared with the program is the parameter tree's naming
 the layer dim, ``final_norm``, ``lm_head.w``). On a TPU a float32 matmul
 runs in lower precision unless told otherwise, so everything here runs
 under ``jax.default_matmul_precision("highest")``.
+
+A block whose layer differs from this one only in what stands in the
+MLP's place (sparse experts) is a file of its own beside this one that
+passes its own ``mlp(h, lp, arch)`` to ``logits`` and ``loss`` here.
 """
 
 from __future__ import annotations
@@ -74,37 +83,43 @@ def _attention(q, k, v, window, q_block):
     return jnp.concatenate(out, axis=0).reshape(T, H * D)
 
 
-def _layer(x, lp, arch, q_block):
+def _lin(y, lp, name):
+    y = y @ lp[name]
+    return y + lp[name + "_b"] if name + "_b" in lp else y
+
+
+def mlp(h, lp, arch):
+    """The layer's MLP on its normed input [T, hidden]."""
+    if arch["activation"] == "silu":
+        y = jax.nn.silu(_lin(h, lp, "w_gate")) * _lin(h, lp, "w_in")
+    elif arch["activation"] == "gelu_exact":
+        y = jax.nn.gelu(_lin(h, lp, "w_in"), approximate=False)
+    else:
+        raise ValueError(f"no reference for activation "
+                         f"{arch['activation']!r}")
+    return _lin(y, lp, "w_out")
+
+
+def _layer(x, lp, arch, q_block, mlp):
     nh = arch["num_heads"]
     kvh = arch.get("num_kv_heads") or nh
     hd = arch["hidden_size"] // nh
     T = x.shape[0]
     kind, eps = arch["norm"], arch["norm_eps"]
     rot = int(hd * arch.get("rope_pct", 1.0)) // 2 * 2
-
-    def lin(y, name):
-        y = y @ lp[name]
-        return y + lp[name + "_b"] if name + "_b" in lp else y
-
     h1 = _norm(x, lp["attn_norm_w"], lp.get("attn_norm_b"), kind, eps)
-    q = _rotary(lin(h1, "wq").reshape(T, nh, hd), rot, arch["rope_theta"])
-    k = _rotary(lin(h1, "wk").reshape(T, kvh, hd), rot, arch["rope_theta"])
-    v = lin(h1, "wv").reshape(T, kvh, hd)
-    attn = lin(_attention(q, k, v, arch.get("sliding_window") or 0, q_block),
-               "wo")
+    theta = arch["rope_theta"]
+    q = _rotary(_lin(h1, lp, "wq").reshape(T, nh, hd), rot, theta)
+    k = _rotary(_lin(h1, lp, "wk").reshape(T, kvh, hd), rot, theta)
+    v = _lin(h1, lp, "wv").reshape(T, kvh, hd)
+    attn = _lin(_attention(q, k, v, arch.get("sliding_window") or 0,
+                           q_block), lp, "wo")
     mlp_in = x if arch.get("parallel_residual") else x + attn
     h2 = _norm(mlp_in, lp["mlp_norm_w"], lp.get("mlp_norm_b"), kind, eps)
-    if arch["activation"] == "silu":
-        y = jax.nn.silu(lin(h2, "w_gate")) * lin(h2, "w_in")
-    elif arch["activation"] == "gelu_exact":
-        y = jax.nn.gelu(lin(h2, "w_in"), approximate=False)
-    else:
-        raise ValueError(f"no reference for activation "
-                         f"{arch['activation']!r}")
-    return x + attn + lin(y, "w_out")
+    return x + attn + mlp(h2, lp, arch)
 
 
-def _logits_one(params, tokens, arch, q_block):
+def _logits_one(params, tokens, arch, q_block, mlp):
     """tokens [T] → float32 logits [T, vocab]."""
     if arch.get("position") != "rope":
         raise ValueError("the reference covers rotary models only")
@@ -112,7 +127,7 @@ def _logits_one(params, tokens, arch, q_block):
     x = params["embed"]["wte"][tokens].astype(jnp.float32)
 
     def body(x, lp):
-        return _layer(x, f32(lp), arch, q_block), None
+        return _layer(x, f32(lp), arch, q_block, mlp), None
 
     x, _ = jax.lax.scan(body, x, params["layers"])
     fn = f32(params["final_norm"])
@@ -122,18 +137,18 @@ def _logits_one(params, tokens, arch, q_block):
     return x @ params["lm_head"]["w"].astype(jnp.float32)
 
 
-def logits(params, tokens, arch, q_block=1024):
+def logits(params, tokens, arch, q_block=1024, mlp=mlp):
     """Reference logits for one sequence, at the highest matmul precision."""
     with jax.default_matmul_precision("highest"):
-        return _logits_one(params, tokens, arch, q_block)
+        return _logits_one(params, tokens, arch, q_block, mlp)
 
 
-def loss(params, input_ids, arch, q_block=1024):
+def loss(params, input_ids, arch, q_block=1024, mlp=mlp):
     """Mean next-token negative log-likelihood over ``input_ids``
     [B, T+1] (inputs are [:, :-1], labels [:, 1:])."""
     with jax.default_matmul_precision("highest"):
         def one(ids):
-            lg = _logits_one(params, ids[:-1], arch, q_block)
+            lg = _logits_one(params, ids[:-1], arch, q_block, mlp)
             logz = jax.nn.logsumexp(lg, axis=-1)
             gold = jnp.take_along_axis(lg, ids[1:, None], axis=-1)[:, 0]
             return jnp.mean(logz - gold)
@@ -141,22 +156,19 @@ def loss(params, input_ids, arch, q_block=1024):
         return jnp.mean(jax.lax.map(one, input_ids))
 
 
-def rms_rel_err(got, want) -> float:
-    """Root-mean-square disagreement over the reference's RMS: averages
-    over the whole vocabulary, so it moves with the precision of the
-    arithmetic and not with one unlucky logit."""
-    import numpy as np
-
-    got = np.asarray(got, np.float64)
-    want = np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / (np.sqrt(np.mean(want ** 2)) + 1e-12))
+def attention_matmul_params(arch: dict) -> int:
+    """One layer's q, k, v and o weights."""
+    h, nh = arch["hidden_size"], arch["num_heads"]
+    kvh = arch.get("num_kv_heads") or nh
+    hd = h // nh
+    return h * nh * hd + 2 * h * kvh * hd + nh * hd * h
 
 
-def max_rel_err(got, want) -> float:
-    """Largest disagreement relative to the reference's range."""
-    import numpy as np
-
-    got = np.asarray(got, np.float32)
-    want = np.asarray(want, np.float32)
-    return float(np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-12))
+def matmul_params(arch: dict) -> int:
+    """Weights that a token is multiplied with once in a forward pass:
+    q, k, v, o, the MLP (three matrices when gated) and the output head.
+    The embedding is a lookup, norms and biases are not matmuls."""
+    h, m = arch["hidden_size"], arch["intermediate_size"]
+    per_layer = attention_matmul_params(arch) \
+        + (3 if arch["activation"] == "silu" else 2) * h * m
+    return arch["num_layers"] * per_layer + h * arch["vocab_size"]

@@ -1,10 +1,13 @@
 """``BENCHMARK.json`` and the files its names point at.
 
 The harness is driven by data: a cell names a configuration and a traffic
-mix; each is a file found by that name, and each metric is a reader found
-by its name. ``validate`` holds the manifest to the contract's limits (the
-ones a file can be checked for without a run), so a later PR that adds an
-entry learns of a slip from tier-1 and not from a refused chip run.
+mix; each is a file found by that name, each metric is a reader found by
+its name, and what the harness knows about a configuration's block type
+(its plain reference, its arithmetic, the scope names it adds) is a module
+under ``blocks/`` found by the name the configuration's file gives.
+``validate`` holds the manifest to the contract's limits (the ones a file
+can be checked for without a run), so a later PR that adds an entry learns
+of a slip from tier-1 and not from a refused chip run.
 """
 
 from __future__ import annotations
@@ -23,6 +26,9 @@ PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
+#: what a block module must define (``blocks/dense.py`` says what each is);
+#: ``SCOPES`` and ``PUBLISHED_TO_FIELD`` are its to add
+BLOCK_EXPORTS = ("logits", "loss", "matmul_params")
 
 
 class ManifestError(ValueError):
@@ -49,14 +55,17 @@ def cell(manifest: dict, name: str) -> dict:
 
 def resolve(manifest: dict, name: str, root: str = CHECKOUT) -> dict:
     """Everything one cell runs on, found by name: the manifest entry, the
-    configuration's file, the traffic mix's file, the cell's own file."""
+    configuration's file and the block module it names, the traffic mix's
+    file, the cell's own file."""
     w = cell(manifest, name)
     cfg = next(c for c in manifest["configs"] if c["name"] == w["config"])
     bench_dir = os.path.join(root, manifest["paths"][0])
+    config = _read_json(os.path.join(root, cfg["file"]))
     return {
         "cell": w,
         "config_entry": cfg,
-        "config": _read_json(os.path.join(root, cfg["file"])),
+        "config": config,
+        "block": find_block(bench_dir, config, cfg["file"]),
         "traffic": _read_json(os.path.join(bench_dir, "traffic",
                                            w["traffic"] + ".json")),
         "workload": _read_json(os.path.join(bench_dir, "workloads",
@@ -67,7 +76,8 @@ def resolve(manifest: dict, name: str, root: str = CHECKOUT) -> dict:
 
 def find_module(bench_dir: str, group_dir: str, name: str):
     """The module ``<bench_dir>/<group_dir>/<name>.py``: how a metric's
-    reader and a traffic generator are found by the name the data gives."""
+    reader, a traffic generator and a configuration's block are found by
+    the name the data gives."""
     path = os.path.join(bench_dir, group_dir, name + ".py")
     if not os.path.isfile(path):
         raise ManifestError(f"no {group_dir}/{name}.py under {bench_dir}")
@@ -76,6 +86,22 @@ def find_module(bench_dir: str, group_dir: str, name: str):
         path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
+    return module
+
+
+def find_block(bench_dir: str, config: dict, where: str):
+    """The module ``<bench_dir>/blocks/<block>.py`` that the configuration
+    names under ``block``, held to what the harness calls on it."""
+    name = config.get("block")
+    if not isinstance(name, str) or not NAME.match(name):
+        raise ManifestError(f"{where}: names no block (\"block\": the name "
+                            f"of a file under blocks/)")
+    module = find_module(bench_dir, "blocks", name)
+    missing = [f for f in BLOCK_EXPORTS
+               if not callable(getattr(module, f, None))]
+    if missing:
+        raise ManifestError(f"blocks/{name}.py defines no "
+                            f"{', '.join(missing)}")
     return module
 
 
@@ -94,8 +120,9 @@ def _one_line(s, what):
 
 def validate(manifest: dict, root: str = CHECKOUT) -> None:
     """Raise ManifestError on the first breach of the contract's static
-    limits (keys, names, units, counts, files found by name, the share of
-    four-chip cells, what a run's length lets a full check cost)."""
+    limits (keys, names, units, counts, files and block modules found by
+    name, the share of four-chip cells, what a run's length lets a full
+    check cost)."""
     if set(manifest) != TOP_KEYS:
         raise ManifestError(f"top-level keys {sorted(manifest)} != "
                             f"{sorted(TOP_KEYS)}")
@@ -173,7 +200,8 @@ def validate(manifest: dict, root: str = CHECKOUT) -> None:
         if w["chips"] not in (1, 4):
             raise ManifestError(f"workload {w['name']}: chips 1 or 4")
         _one_line(w["why"], "workload why")
-        info = resolve(manifest, w["name"], root)   # every file, by name
+        # every file by name, the configuration's block module among them
+        info = resolve(manifest, w["name"], root)
         gen = os.path.join(info["bench_dir"], "traffic",
                            str(info["traffic"].get("generator")) + ".py")
         if not os.path.isfile(gen):

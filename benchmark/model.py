@@ -2,8 +2,10 @@
 
 The file holds the published ``config.json`` keys (what the contract
 compares) and, under ``transformer_config``, the same architecture as
-``TransformerConfig`` fields — data, so a new dense model needs no code.
-``check_consistent`` holds the two views together."""
+``TransformerConfig`` fields — data, so a new model of a block that is
+there needs no code. ``check_consistent`` holds the two views together;
+a block module adds the keys only its kind of model publishes
+(``PUBLISHED_TO_FIELD`` in ``blocks/<block>.py``)."""
 
 from __future__ import annotations
 
@@ -26,9 +28,11 @@ PUBLISHED_TO_FIELD = {
 }
 
 
-def check_consistent(config: dict) -> None:
+def check_consistent(config: dict, block=None) -> None:
     arch = config["transformer_config"]
-    for key, field in PUBLISHED_TO_FIELD.items():
+    pairs = dict(PUBLISHED_TO_FIELD,
+                 **getattr(block, "PUBLISHED_TO_FIELD", {}))
+    for key, field in pairs.items():
         if key in config and field in arch and config[key] != arch[field]:
             raise ValueError(f"{key}={config[key]!r} in the file, but "
                              f"transformer_config.{field}={arch[field]!r}")
@@ -36,13 +40,15 @@ def check_consistent(config: dict) -> None:
         raise ValueError("positions run exceed the published positions")
 
 
-def transformer_config(config: dict, **overrides):
-    """The program's ``TransformerConfig`` for this file."""
+def transformer_config(info: dict, **overrides):
+    """The program's ``TransformerConfig`` for the cell's configuration
+    (``info`` is ``manifest.resolve``'s)."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.models.transformer import TransformerConfig
 
-    check_consistent(config)
+    config = info["config"]
+    check_consistent(config, info["block"])
     fields = dict(config["transformer_config"])
     fields["dtype"] = jnp.dtype(fields["dtype"])
     fields.update(overrides)

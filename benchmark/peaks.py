@@ -1,7 +1,9 @@
 """Published peaks by ``device_kind``, and the operations and bytes an
 algorithm needs, computed from shapes. A device that is not in the table
 is an error, not a default. ``arch`` below is the ``transformer_config``
-group of a configuration's file (plain data, not a program object)."""
+group of a configuration's file (plain data, not a program object) and
+``block`` the module its ``block`` names (``blocks/<block>.py``): how many
+weights a token meets is the block's to say, not this file's."""
 
 from __future__ import annotations
 
@@ -30,32 +32,23 @@ def _heads(arch):
     return nh, kvh, arch["hidden_size"] // nh
 
 
-def matmul_params(arch: dict) -> int:
-    """Weights that a token is multiplied with once in a forward pass:
-    q, k, v, o, the MLP (three matrices when gated) and the output head.
-    The embedding is a lookup, norms and biases are not matmuls."""
-    h, m, L = arch["hidden_size"], arch["intermediate_size"], arch["num_layers"]
-    nh, kvh, hd = _heads(arch)
-    attn = h * nh * hd + 2 * h * kvh * hd + nh * hd * h
-    mlp = (3 if arch["activation"] == "silu" else 2) * h * m
-    return L * (attn + mlp) + h * arch["vocab_size"]
-
-
-def forward_flops(arch: dict, new_tokens: int, context_tokens: int) -> float:
+def forward_flops(block, arch: dict, new_tokens: int,
+                  context_tokens: int) -> float:
     """FLOPs of a forward over ``new_tokens`` query positions that attend
     to ``context_tokens`` key positions in total (the sum, over the query
-    positions, of the keys each may see): 2 per weight per token, plus
+    positions, of the keys each may see): 2 per weight the block says a
+    token is multiplied with (``block.matmul_params``) per token, plus
     QKᵀ and PV (2·2·head_dim per head per query-key pair)."""
     nh, _, hd = _heads(arch)
-    return (2.0 * matmul_params(arch) * new_tokens
+    return (2.0 * block.matmul_params(arch) * new_tokens
             + 4.0 * arch["num_layers"] * nh * hd * context_tokens)
 
 
-def train_flops_per_token(arch: dict, seq: int) -> float:
+def train_flops_per_token(block, arch: dict, seq: int) -> float:
     """Model FLOPs a training step needs per token at sequence length
     ``seq``: forward + backward = 3 × forward, causal attention sees
     seq/2 keys on average. Recomputation (remat) is NOT counted."""
-    return 3.0 * forward_flops(arch, 1, seq / 2.0)
+    return 3.0 * forward_flops(block, arch, 1, seq / 2.0)
 
 
 def paged_attention_cost(arch: dict, query_tokens: int, kv_read_tokens: int,

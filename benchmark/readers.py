@@ -13,6 +13,11 @@ from collections import Counter, defaultdict
 from . import arithmetic as ar
 from . import peaks, trace
 
+#: the flash-attention forward and its two backward kernels, as named in
+#: ``ops/flash_attention.py``
+FLASH_KERNELS = ("kernel:flash_attention_fwd", "kernel:flash_attention_dq",
+                 "kernel:flash_attention_dkv")
+
 
 def due_in_window(ctx):
     w0, w1 = ctx.result["window"]
@@ -145,14 +150,44 @@ def forward_device_ms(ctx, mixed: bool):
     return ar.median(secs) * 1e3 if secs else None
 
 
+def kernel_roofline(ctx, names, least_seconds, per_module=None):
+    """Kernels' share (%) of their roofline: ``least_seconds``, the least
+    time the chip could take for the calls made (from their shapes), over
+    the device time of the events of the custom calls ``names``
+    (``kernel:<name>``, as ``breakdown.device_ops`` prints a kernel's
+    ``name=``) — their own and no other kernel's, so a second kernel in
+    the same program moves nothing here. Without ``per_module`` both are
+    of the traced window. With it, ``least_seconds`` is that of one
+    execution of the program whose name holds ``per_module``, the time is
+    that of the named kernels' events inside each whole execution in the
+    window, and the median over the executions is given."""
+    if ctx.trace is None or not least_seconds:
+        return None
+    if per_module is None:
+        spent = sum(ctx.trace["kernel_seconds"].get(n, 0.0) for n in names)
+        return 100.0 * least_seconds / spent if spent else None
+    t1 = ctx.trace["window"][1]
+    mine = [k for k in ctx.trace["kernels"] if k["family"] in names]
+    shares = []
+    for m in ctx.trace["modules"]:
+        a, b = m["start"], m["start"] + m["dur"]
+        if per_module not in m["name"] or b > t1:
+            continue
+        spent = sum(k["dur"] for k in mine
+                    if k["device"] == m["device"] and a <= k["start"] < b)
+        if spent:
+            shares.append(100.0 * least_seconds / spent)
+    return ar.median(shares) if shares else None
+
+
 def paged_attention_roofline(ctx):
     """The paged-attention kernel's share (%) of its roofline over the
     traced window: the least time the chip could take for the calls the
     window made (from their shapes: the larger of FLOPs over the bf16
     peak and bytes over the HBM peak, per layer) over the device time of
-    the kernel's events (the forward's only custom call)."""
+    the kernel's events."""
     marks = ctx.result.get("trace_marks")
-    if ctx.trace is None or not marks or not ctx.trace["kernel_s"]:
+    if ctx.trace is None or not marks:
         return None
     arch, kind = ctx.result["arch"], ctx.device["kind"]
     least = 0.0
@@ -160,7 +195,7 @@ def paged_attention_roofline(ctx):
         cost = peaks.paged_attention_cost(arch, a["valid_tokens"],
                                           a["kv_read_tokens"], a["qk_pairs"])
         least += arch["num_layers"] * peaks.roofline_seconds(cost, kind)
-    return 100.0 * least / ctx.trace["kernel_s"] if least else None
+    return kernel_roofline(ctx, ("kernel:paged_attention",), least)
 
 
 # ------------------------------------------------------------------ train
@@ -176,7 +211,7 @@ def mfu_percent(ctx):
     rate = train_tokens_per_s_chip(ctx)
     if rate is None or ctx.device["platform"] == "cpu":
         return None             # a rehearsal has no chip to relate to
-    flops = peaks.train_flops_per_token(ctx.result["arch"],
+    flops = peaks.train_flops_per_token(ctx.info["block"], ctx.result["arch"],
                                         ctx.result["sequence_tokens"])
     return 100.0 * flops * rate / peaks.peaks(ctx.device["kind"])["flops_bf16"]
 
@@ -191,11 +226,10 @@ def flash_attention_roofline(ctx):
     the least time for one layer's causal attention forward and backward
     at the step's shapes (FLOP-bound; the remat pass's second forward is
     not needed work and is not counted) × layers, over the device time of
-    the custom calls inside each whole micro-step program of the traced
-    window."""
+    the three flash kernels inside each whole micro-step program of the
+    traced window."""
     if ctx.trace is None:
         return None
-    t0, t1 = ctx.trace["window"]
     arch = ctx.result["arch"]
     per_chip = ctx.result["tokens_per_step"] // ctx.result["chips"] \
         // ctx.result["sequence_tokens"]
@@ -203,16 +237,7 @@ def flash_attention_roofline(ctx):
         peaks.flash_attention_cost(arch, per_chip,
                                    ctx.result["sequence_tokens"]),
         ctx.device["kind"])
-    shares = []
-    for m in ctx.trace["modules"]:
-        a, b = m["start"], m["start"] + m["dur"]
-        if "micro" not in m["name"] or b > t1:
-            continue
-        spent = sum(k["dur"] for k in ctx.trace["kernels"]
-                    if k["device"] == m["device"] and a <= k["start"] < b)
-        if spent:
-            shares.append(100.0 * least / spent)
-    return ar.median(shares) if shares else None
+    return kernel_roofline(ctx, FLASH_KERNELS, least, per_module="micro")
 
 
 def collective_share(ctx, exposed: bool):

@@ -14,7 +14,14 @@ and every host event that is not ``bench:*``, so this module decodes the
   given to the innermost ``ds:*`` span open at the time (``trace._host_at``'s
   rule).
 
-``python3 -m benchmark.scopes <xplane.pb> [chips]`` prints the whole table.
+The vocabulary of scope names is the program's shared one (below) plus
+what a configuration's block adds under ``layers`` (``SCOPES`` in
+``blocks/<block>.py``: a sparse block's ``router`` and ``experts`` inside
+``mlp``); every function that reads an op_name takes those as
+``block_scopes``, and the readers take them from ``ctx.info["block"]``.
+
+``python3 -m benchmark.scopes <xplane.pb> [chips [scope ...]]`` prints the
+whole table (further words: a block's scopes).
 Every reader returns None off the chip (``ctx.trace is None``) and where the
 program carries no such name (a checkout from before the names existed).
 """
@@ -35,7 +42,8 @@ from . import trace
 OP_NAME_STATS = ("tf_op", "hlo_op_name", "op_name")
 ANNOTATION = "ds:"
 
-#: the program's scope vocabulary (docs/OBSERVABILITY.md "XLA alignment")
+#: the scope vocabulary every block shares (docs/OBSERVABILITY.md "XLA
+#: alignment"); a block module's ``SCOPES`` extends it
 BLOCK = ("attn_norm", "qkv", "kv_write", "attend", "attn_out", "mlp")
 MODEL = ("embed", "layers", "final_norm", "logits", "loss")
 STEP = ("loss_and_grad", "grad_accumulate", "grad_norm_clip", "optimizer")
@@ -196,23 +204,26 @@ def load_recorded(path: str) -> List[dict]:
 
 # ------------------------------------------------------------------- scopes
 
-def scope_path(op_name: str) -> tuple:
+def scope_path(op_name: str, block_scopes=()) -> tuple:
     """The program scopes an operation sits under, outermost first:
     ``jit(micro)/loss_and_grad/transpose(jvp(layers))/while/body/
     checkpoint/mlp/dot_general`` → (loss_and_grad, layers, mlp). JAX wraps
     a scope in the transformation it was traced under (``jvp(layers)``),
-    so the words are looked for inside each component."""
+    so the words are looked for inside each component. ``block_scopes``
+    are the names the configuration's block adds to the vocabulary."""
     return tuple(w for part in op_name.split("/")
-                 for w in _WORD.findall(part) if w in VOCABULARY)
+                 for w in _WORD.findall(part)
+                 if w in VOCABULARY or w in block_scopes)
 
 
-def scope_of(op_name: str) -> str:
-    """One row of the table per operation: its innermost program scope;
-    ``layers`` alone (under the scan, under none of the block's scopes) is
-    the scan's own plumbing — slices of the stacked weights and pools, the
-    pools written back, carry copies; no word of the vocabulary at all is
-    what the names do not yet explain."""
-    path = scope_path(op_name)
+def scope_of(op_name: str, block_scopes=()) -> str:
+    """One row of the table per operation: its innermost program scope
+    (``…/mlp/experts/dot_general`` is ``experts`` for a block that lists
+    it and ``mlp`` for one that does not); ``layers`` alone (under the
+    scan, under none of the block's scopes) is the scan's own plumbing —
+    slices of the stacked weights, carry copies; no word of the vocabulary
+    at all is what the names do not yet explain."""
+    path = scope_path(op_name, block_scopes)
     if not path:
         return UNSCOPED
     return SCAN_OVERHEAD if path[-1] == "layers" else path[-1]
@@ -267,14 +278,15 @@ def _module_at(modules, starts, t):
     return ""
 
 
-def summarize(events: List[dict], chips: int = 1) -> dict:
-    """Device self seconds by program scope (``by_scope``; the XLA
-    operation families under each in ``ops_by_scope``, all of those that
-    carry no scope in ``unscoped_ops``) and by training pass (``by_pass``);
-    idle seconds of the window by ``ds:*`` phase (``idle_by_phase``). Averaged over the ``chips`` lowest-numbered device
-    planes that ran anything. ``scoped`` says whether any operation
-    carried a scope of the program at all, ``spanned`` whether the trace
-    holds any ``ds:*`` annotation."""
+def summarize(events: List[dict], chips: int = 1, block_scopes=()) -> dict:
+    """Device self seconds by program scope (``by_scope``, over the shared
+    vocabulary and ``block_scopes``; the XLA operation families under
+    each in ``ops_by_scope``, all of those that carry no scope in
+    ``unscoped_ops``) and by training pass (``by_pass``); idle seconds of
+    the window by ``ds:*`` phase (``idle_by_phase``). Averaged over the
+    ``chips`` lowest-numbered device planes that ran anything. ``scoped``
+    says whether any operation carried a scope of the program at all,
+    ``spanned`` whether the trace holds any ``ds:*`` annotation."""
     w0, w1 = _window(events)
     planes = _planes(events, chips)
     if not planes:
@@ -296,7 +308,7 @@ def summarize(events: List[dict], chips: int = 1) -> dict:
         in_window = [e for e in ops if w0 <= e["start"] < w1]
         for e, own, _ in trace.exclusive(in_window):
             op_name = e.get("op_name", "")
-            scope = scope_of(op_name)
+            scope = scope_of(op_name, block_scopes)
             by_scope[scope] += own / n
             by_pass[train_pass(op_name, _module_at(
                 modules, starts, e["start"]))] += own / n
@@ -326,8 +338,9 @@ def _summary(ctx) -> Optional[dict]:
     if ctx.trace is None:
         return None
     if getattr(ctx, "_scopes", None) is None:
-        ctx._scopes = summarize(load(ctx.result["xplane"]),
-                                chips=ctx.result["chips"])
+        ctx._scopes = summarize(
+            load(ctx.result["xplane"]), chips=ctx.result["chips"],
+            block_scopes=getattr(ctx.info.get("block"), "SCOPES", ()))
     return ctx._scopes
 
 
@@ -379,4 +392,5 @@ if __name__ == "__main__":
     import sys
 
     chips = int(sys.argv[2]) if len(sys.argv) > 2 else 1
-    print(json.dumps(summarize(load(sys.argv[1]), chips), indent=1))
+    print(json.dumps(summarize(load(sys.argv[1]), chips, sys.argv[3:]),
+                     indent=1))

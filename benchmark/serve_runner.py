@@ -18,7 +18,7 @@ import time
 import numpy as np
 
 from . import arithmetic as ar
-from . import reference, traffic
+from . import traffic
 from .device import TraceWindow, memory_peak_bytes
 from .model import seeded_params, transformer_config
 from .probe import Probe
@@ -38,7 +38,7 @@ def build(info: dict, seed: int, arch_overrides=None):
         InferenceEngineV2, RaggedInferenceEngineConfig)
     from deepspeed_tpu.models.transformer import CausalLM
 
-    cfg = transformer_config(info["config"], **(arch_overrides or {}))
+    cfg = transformer_config(info, **(arch_overrides or {}))
     model = CausalLM(cfg)
     params = seeded_params(model, seed, cfg.dtype)
     sizing = {k: v for k, v in info["config"]["engine"].items()
@@ -165,19 +165,19 @@ def drain(fe, records, drain_s: float):
 
 # ------------------------------------------------------------ correctness
 
-def check_logits(engine, params, arch, sample, decode_steps: int,
+def check_logits(engine, params, info, sample, decode_steps: int,
                  tolerance: float, rms_tolerance: float) -> dict:
     """A seeded sample of requests, prefill in chunks and then decode
-    through the cache, the engine's logits at every step against the
-    reference's full forward over the same tokens: the largest
-    disagreement relative to the range, and the RMS disagreement relative
-    to the RMS (the tighter of the two: it averages over the vocabulary,
-    so a lower compute precision shows in it first)."""
+    through the cache, the engine's logits at every step against the full
+    forward of the configuration's block reference (``info["block"]``)
+    over the same tokens: the largest disagreement relative to the range,
+    and the RMS relative to the RMS (a lower precision shows there first)."""
     import jax
 
+    block, arch = info["block"], info["config"]["transformer_config"]
     chunk = engine.config.max_chunk_tokens
     width = -(-max(len(p) + decode_steps for p in sample) // 256) * 256
-    ref_fn = jax.jit(lambda p, t: reference.logits(p, t, arch))
+    ref_fn = jax.jit(lambda p, t: block.logits(p, t, arch))
     worst = worst_rms = 0.0
     for i, prompt in enumerate(sample):
         uid = _OWN_UID + (1 << 20) + i
@@ -197,8 +197,8 @@ def check_logits(engine, params, arch, sample, decode_steps: int,
             w = want[len(prompt) - 1 + step]
             if not np.isfinite(g).all():
                 return {"ok": False, "why": f"sample {i}: logits not finite"}
-            worst = max(worst, reference.max_rel_err(g, w))
-            worst_rms = max(worst_rms, reference.rms_rel_err(g, w))
+            worst = max(worst, ar.max_rel_err(g, w))
+            worst_rms = max(worst_rms, ar.rms_rel_err(g, w))
     ok = worst <= tolerance and worst_rms <= rms_tolerance
     return {"ok": ok, "max_rel_err": worst, "tolerance": tolerance,
             "rms_rel_err": worst_rms, "rms_tolerance": rms_tolerance,
@@ -274,7 +274,7 @@ def run(info: dict, args, watch, process_t0: float) -> dict:
     picks = rng.choice(len(ok_records),
                        size=min(check["requests"], len(ok_records)),
                        replace=False) if ok_records else []
-    logits = check_logits(engine, params, info["config"]["transformer_config"],
+    logits = check_logits(engine, params, info,
                           [ok_records[i].req.prompt for i in picks],
                           check["decode_steps"], check["tolerance"],
                           check["rms_tolerance"]) \

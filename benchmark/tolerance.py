@@ -5,7 +5,8 @@ from, measured and not asserted:
 
 runs the cell's own correctness check (``serve_runner.check_logits``: the
 engine through ``engine.put``, prefill then decode through the cache,
-against ``reference.py``) on one seeded prompt, three times:
+against the reference of the block the configuration names,
+``blocks/<block>.py``) on one seeded prompt, three times:
 
 ``float32``       engine and weights in float32. What is left is what the
                   two implementations do differently — it must be tiny,
@@ -47,11 +48,10 @@ def variants(info: dict, seed: int, prompt, kv_blocks: int):
     info = dict(info, config=dict(
         info["config"],
         engine=dict(info["config"]["engine"], kv_blocks=kv_blocks)))
-    check, arch = info["config"]["check"], \
-        info["config"]["transformer_config"]
+    check = info["config"]["check"]
 
     def measure(engine, params):
-        return sr.check_logits(engine, params, arch, [prompt],
+        return sr.check_logits(engine, params, info, [prompt],
                                check["decode_steps"], check["tolerance"],
                                check["rms_tolerance"])
 

@@ -58,8 +58,8 @@ KERNEL = " custom-call("      # a Pallas (Mosaic) kernel, in HLO text
 def op_family(name: str) -> str:
     """``%fusion.123 = …`` → ``fusion``: the operation without its
     instance number, so that the layers of a scan add up. A custom call
-    (a Pallas kernel; no kernel of the program has a ``name=`` yet) is
-    marked as one."""
+    is marked as one: a Pallas kernel's instruction takes the kernel's
+    ``name=`` (``%paged_attention.8`` → ``kernel:paged_attention``)."""
     head = name.split(" = ")[0].lstrip("%")
     family = re.sub(r"\.\d+(\.\d+)*$", "", head)
     return f"kernel:{family}" if KERNEL in name else family
@@ -123,7 +123,7 @@ def summarize(events: List[dict], chips: int) -> dict:
     op_seconds: Dict[str, float] = defaultdict(float)
     gap_seconds: Dict[str, float] = defaultdict(float)
     modules, kernels = [], []
-    kernel_s = 0.0
+    kernel_seconds: Dict[str, float] = defaultdict(float)
     for p in planes:
         ops = [e for e in by_plane[p] if e["line"] == OPS_LINE
                or "Async" in e["line"]]
@@ -144,10 +144,11 @@ def summarize(events: List[dict], chips: int) -> dict:
         coll.append(union_seconds(coll_iv))
         exposed.append(subtract_seconds(coll_iv, compute_iv))
         for e, own, _ in nested:
-            op_seconds[op_family(e["name"])] += own / len(planes)
+            family = op_family(e["name"])
+            op_seconds[family] += own / len(planes)
             if KERNEL in e["name"]:
-                kernel_s += e["dur"] / len(planes)
-                kernels.append(dict(e, device=p))
+                kernel_seconds[family] += e["dur"] / len(planes)
+                kernels.append(dict(e, device=p, family=family))
         for a, b in _gaps(core_iv, w0, w1):
             # cut the gap where an annotation begins or ends, and give
             # each piece to what the host was doing in it
@@ -167,7 +168,10 @@ def summarize(events: List[dict], chips: int) -> dict:
         "busy_s": sum(busy) / n,
         "collective_s": sum(coll) / n,
         "collective_exposed_s": sum(exposed) / n,
-        "kernel_s": kernel_s, "kernels": kernels,
+        # custom calls: device seconds by name (``kernel:<name>``), their
+        # total, and the events themselves
+        "kernel_seconds": dict(kernel_seconds),
+        "kernel_s": sum(kernel_seconds.values()), "kernels": kernels,
         "device_ops": top(op_seconds), "idle_gaps": top(gap_seconds),
         "modules": modules, "host": host,
     }

@@ -9,7 +9,7 @@ import time
 
 import numpy as np
 
-from . import reference, traffic
+from . import traffic
 from .device import TraceWindow, compile_cache_off, memory_peak_bytes
 from .model import transformer_config
 from .probe import Probe
@@ -21,7 +21,7 @@ def build(info: dict, seed: int, chips: int):
     from deepspeed_tpu.parallel import topology as topo
 
     wl, mix = info["workload"], info["traffic"]
-    cfg = transformer_config(info["config"], **wl.get("arch_overrides", {}))
+    cfg = transformer_config(info, **wl.get("arch_overrides", {}))
     if mix["sequence_tokens"] > cfg.max_seq_len:
         raise ValueError("sequences longer than the positions run")
     config = dict(wl["train_config"])
@@ -52,16 +52,18 @@ def one_step(engine, batch) -> float:
     return value
 
 
-def check_loss(engine, batch, arch, tolerance: float) -> dict:
+def check_loss(engine, batch, info, tolerance: float) -> dict:
     """The engine's loss on (its current parameters, this batch) against
-    the plain reference's on the same two. The reference reads the fp32
+    that of the configuration's block reference (``info["block"]``) on
+    the same two. The reference reads the fp32
     masters where they lie; under the mesh XLA gathers each layer's
     weights as the scan reaches it, so no second copy of the model is
     held. Then one more engine step on that batch gives the engine's
     loss for those same parameters."""
     import jax
 
-    want = float(jax.jit(lambda p, t: reference.loss(p, t, arch, q_block=512))(
+    block, arch = info["block"], info["config"]["transformer_config"]
+    want = float(jax.jit(lambda p, t: block.loss(p, t, arch, q_block=512))(
         engine.state.params, np.asarray(batch, np.int32)))
     got = one_step(engine, {"input_ids": batch})
     err = abs(got - want) / abs(want)
@@ -114,8 +116,7 @@ def run(info: dict, args, watch, process_t0: float) -> dict:
     peak = memory_peak_bytes(chips)
     xplane = trace.finish() if trace is not None else None
 
-    check = check_loss(engine, batches[i % len(batches)],
-                       info["config"]["transformer_config"],
+    check = check_loss(engine, batches[i % len(batches)], info,
                        info["config"]["check"]["loss_tolerance"])
     n = len(batches)
     fell = float(np.mean(losses[-n:])) < float(np.mean(losses[:n])) \

@@ -4,6 +4,7 @@ configurations are tiny (they live here, not in ``benchmark/configs/``) —
 and the command's refusals. A rehearsal proves control flow and counts;
 its seconds mean nothing and no device metric is read from it."""
 
+import hashlib
 import json
 import os
 import shutil
@@ -18,6 +19,7 @@ from benchmark import run as bench_run
 REPO = mf.CHECKOUT
 
 TINY_NEOX = {
+    "block": "dense",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "vocab_size": 256,
     "max_position_embeddings": 256, "rotary_pct": 0.25,
@@ -36,6 +38,7 @@ TINY_NEOX = {
               "tolerance": 1e-4, "rms_tolerance": 1e-4},
 }
 TINY_MISTRAL = {
+    "block": "dense",
     "hidden_size": 64, "intermediate_size": 160, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 256,
     "max_position_embeddings": 256, "sliding_window": 64,
@@ -69,7 +72,7 @@ def checkout(tmp_path, monkeypatch):
     and tiny configurations and traffic mixes under the real names."""
     root = str(tmp_path)
     bench = os.path.join(root, "benchmark")
-    for sub in ("end_to_end", "layer_metrics", "workloads"):
+    for sub in ("blocks", "end_to_end", "layer_metrics", "workloads"):
         shutil.copytree(os.path.join(REPO, "benchmark", sub),
                         os.path.join(bench, sub))
     shutil.copytree(os.path.join(REPO, "benchmark", "traffic"),
@@ -221,6 +224,116 @@ def test_a_generator_added_as_a_file_is_found_by_name(checkout, capsys):
            {"generator": "absent", "loop": "open"})
     with pytest.raises(mf.ManifestError):
         mf.validate(mf.load(checkout), checkout)
+
+
+#: a later PR's block file: the plain reference of a layer whose MLP is
+#: a top-1 choice among expert MLPs, every token served by its expert
+#: (``moe_dropless``) — a block the program runs and ``dense`` cannot check
+TOP1_BLOCK = '''
+"""Attention as in ``dense``; in the MLP's place ``moe_num_experts`` expert
+MLPs, of which each token takes the one its router scores highest, scaled
+by that score's softmax probability."""
+import jax
+import jax.numpy as jnp
+
+from benchmark.blocks import dense
+
+SCOPES = ("router", "experts")
+PUBLISHED_TO_FIELD = {"num_experts": "moe_num_experts",
+                      "num_experts_per_tok": "moe_top_k"}
+
+
+def _experts(h, lp, arch):
+    probs = jax.nn.softmax(h @ lp["router_wg"], axis=-1)
+    pick = jnp.argmax(probs, axis=-1)
+    gate = jnp.take_along_axis(probs, pick[:, None], axis=-1)
+    up = jnp.einsum("th,thm->tm", h, lp["w_in"][pick])
+    y = jax.nn.silu(jnp.einsum("th,thm->tm", h, lp["w_gate"][pick])) * up
+    return gate * jnp.einsum("tm,tmh->th", y, lp["w_out"][pick])
+
+
+def logits(params, tokens, arch, q_block=1024):
+    return dense.logits(params, tokens, arch, q_block, mlp=_experts)
+
+
+def loss(params, input_ids, arch, q_block=1024):
+    return dense.loss(params, input_ids, arch, q_block, mlp=_experts)
+
+
+def matmul_params(arch):
+    h, m = arch["hidden_size"], arch["intermediate_size"]
+    per_layer = dense.attention_matmul_params(arch) \\
+        + h * arch["moe_num_experts"] + arch["moe_top_k"] * 3 * h * m
+    return arch["num_layers"] * per_layer + h * arch["vocab_size"]
+'''
+
+
+def _harness_hashes(root):
+    out = {}
+    for top, _, files in os.walk(os.path.join(root, "benchmark")):
+        for name in files:
+            path = os.path.join(top, name)
+            if "__pycache__" not in path:
+                with open(path, "rb") as f:
+                    out[path] = hashlib.sha256(f.read()).hexdigest()
+    return out
+
+
+def test_a_block_added_as_files_is_found_by_name(checkout, capsys):
+    """A later PR's configuration whose block the ``dense`` reference does
+    not cover: a block file, the configuration's file that names it, a
+    mix, a cell and the entries — no file that was there is edited."""
+    from benchmark.model import check_consistent
+
+    before = _harness_hashes(checkout)
+    with open(os.path.join(checkout, "benchmark/blocks/top1.py"), "w") as f:
+        f.write(TOP1_BLOCK)
+    arch = dict(TINY_MISTRAL["transformer_config"], moe_num_experts=4,
+                moe_top_k=1, moe_dropless=True)
+    config = dict(TINY_MISTRAL, block="top1", num_experts=4,
+                  num_experts_per_tok=1, transformer_config=arch)
+    _write(os.path.join(checkout, "benchmark/configs/tiny-top1.json"), config)
+    _write(os.path.join(checkout, "benchmark/traffic/few.json"),
+           dict(TRAFFIC["batch"], schedule_seed=2))
+    _write(os.path.join(checkout,
+                        "benchmark/workloads/tiny-top1.few.json"),
+           {"runner": "serve"})
+    manifest = mf.load(checkout)
+    manifest["configs"].append(
+        {"name": "tiny-top1", "source": "a later PR's",
+         "file": "benchmark/configs/tiny-top1.json", "reduced": [],
+         "why": "top-1 dropless experts"})
+    manifest["workloads"].append(
+        {"name": "tiny-top1.few", "config": "tiny-top1", "traffic": "few",
+         "chips": 1, "why": "a later PR's cell"})
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        if "mistral-7b.batch" in m.get("workloads", []):
+            m["workloads"].append("tiny-top1.few")
+    _write(os.path.join(checkout, "BENCHMARK.json"), manifest)
+    mf.validate(manifest, checkout)
+
+    line, extra = rehearse(checkout, capsys, "tiny-top1.few", 0)
+    assert line["correct"], extra["why_not"]
+    assert line["failed"] == 0
+    assert set(line["metrics"]) == {"serve_tok_s", "setup_s"}
+    check = extra["counters"]["logits_check"]
+    assert 0 < check["max_rel_err"] < 1e-4 and check["rms_rel_err"] < 1e-4
+    # what the block adds is read where the harness reads it
+    info = mf.resolve(manifest, "tiny-top1.few", checkout)
+    assert info["block"].SCOPES == ("router", "experts")
+    dense = mf.resolve(manifest, "mistral-7b.batch", checkout)["block"]
+    assert info["block"].matmul_params(arch) == dense.matmul_params(arch) \
+        + 2 * 64 * 4        # one expert of the four, and a router a layer
+    with pytest.raises(ValueError, match="num_experts_per_tok"):
+        check_consistent(dict(config, num_experts_per_tok=2), info["block"])
+    assert _harness_hashes(checkout).items() >= before.items()
+    # the lookup is live: under the dense block the same configuration is
+    # held to equations that are not its own, and is refused (the stacked
+    # expert weights do not fit the dense MLP's shapes)
+    _write(os.path.join(checkout, "benchmark/configs/tiny-top1.json"),
+           dict(config, block="dense"))
+    with pytest.raises(TypeError, match="carry"):
+        rehearse(checkout, capsys, "tiny-top1.few", 0, seed="6")
 
 
 def test_the_tolerances_are_measured_not_asserted(checkout, capsys):

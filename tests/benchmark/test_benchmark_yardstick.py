@@ -1,22 +1,24 @@
 """The yardstick's own arithmetic, checked on the CPU in seconds: traffic is
 a pure function of the seed, percentiles and FLOP counts agree with hand
 counts, the manifest keeps the contract's static limits, the trace reducer
-reads a recorded trace, and the plain references agree with the program's
-model at a tiny size."""
+reads a recorded trace, and the plain references, found by the name a
+configuration gives its block, agree with the program's model at a tiny
+size."""
 
-import copy
 import itertools
 import json
 import math
 import os
+import shutil
 
 import numpy as np
 import pytest
 
 from benchmark import arithmetic as ar
 from benchmark import manifest as mf
-from benchmark import peaks, reference, trace, traffic
+from benchmark import peaks, readers, scopes, trace, traffic
 from benchmark.model import check_consistent
+from benchmark.run import Context
 
 BENCH = os.path.dirname(os.path.abspath(traffic.__file__))
 
@@ -140,21 +142,34 @@ def arch_of(name):
         return json.load(f)
 
 
+def block_of(config):
+    """The block module a configuration's file names, as a run finds it."""
+    return mf.find_block(BENCH, config, "a test")
+
+
 def test_model_flops_against_hand_counts():
-    mistral = arch_of("mistral-7b")["transformer_config"]
+    config = arch_of("mistral-7b")
+    mistral, block = config["transformer_config"], block_of(config)
     # a layer: q, o 4096², k, v 4096·1024, three 4096·14336 MLP matrices
     layer = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336
     n = mistral["num_layers"] * layer + 4096 * 32000
-    assert peaks.matmul_params(mistral) == n
+    assert block.matmul_params(mistral) == n
     per_token = 6 * n + 6 * mistral["num_layers"] * 4096 * 4096
-    assert peaks.train_flops_per_token(mistral, 4096) == \
+    assert peaks.train_flops_per_token(block, mistral, 4096) == \
         pytest.approx(per_token)
-    pythia = arch_of("pythia-1.4b")["transformer_config"]
-    assert peaks.matmul_params(pythia) == \
+    config = arch_of("pythia-1.4b")
+    pythia, block = config["transformer_config"], block_of(config)
+    assert block.matmul_params(pythia) == \
         24 * (4 * 2048 * 2048 + 2 * 2048 * 8192) + 2048 * 50304
     # one decode token at context 1000: 2 per weight + 4·H·D·1000 a layer
-    assert peaks.forward_flops(pythia, 1, 1000) == pytest.approx(
-        2 * peaks.matmul_params(pythia) + 4 * 24 * 2048 * 1000)
+    assert peaks.forward_flops(block, pythia, 1, 1000) == pytest.approx(
+        2 * block.matmul_params(pythia) + 4 * 24 * 2048 * 1000)
+
+    class Sparse:       # the count is the block's: an eighth of it active
+        matmul_params = staticmethod(lambda arch: n // 8)
+
+    assert peaks.train_flops_per_token(Sparse, mistral, 4096) == \
+        pytest.approx(6 * (n // 8) + 6 * mistral["num_layers"] * 4096 ** 2)
 
 
 def test_kernel_costs_and_roofline():
@@ -191,29 +206,51 @@ def test_the_manifest_keeps_the_contract():
     assert len(json.dumps(manifest)) < 64 * 1024
 
 
-def _break(manifest, how):
-    m = copy.deepcopy(manifest)
-    how(m)
-    return m
+def _name_block(root, name, body=None):
+    """Make the first configuration's file (in a copy of the checkout)
+    name the block ``name``, whose module holds ``body``."""
+    path = os.path.join(root, mf.load(root)["configs"][0]["file"])
+    config = dict(arch_of("pythia-1.4b"), block=name)
+    if name is None:
+        del config["block"]
+    with open(path, "w") as f:
+        json.dump(config, f)
+    if body is not None:
+        with open(os.path.join(root, "benchmark", "blocks", name + ".py"),
+                  "w") as f:
+            f.write(body)
 
 
 @pytest.mark.parametrize("how", [
-    lambda m: m["workloads"][0].update(name="has space"),
-    lambda m: m["end_to_end"][0].update(unit="tokens per second"),
-    lambda m: m["end_to_end"][0].update(bound=0.2),
-    lambda m: m["workloads"][0].update(chips=4),           # two of four
-    lambda m: m["workloads"][0].update(traffic="missing"),  # no such file
-    lambda m: m["per_layer"][0].update(moves="nothing"),
-    lambda m: m["per_layer"][0].update(why="a key too many"),
-    lambda m: m["configs"][1].update(reduced=["hidden_size"]),
-    lambda m: m.update(run_seconds=52),
-    lambda m: m["end_to_end"].pop(),                        # setup_s gone
-    lambda m: m["workloads"].append(dict(m["workloads"][0])),
+    lambda m, root: m["workloads"][0].update(name="has space"),
+    lambda m, root: m["end_to_end"][0].update(unit="tokens per second"),
+    lambda m, root: m["end_to_end"][0].update(bound=0.2),
+    lambda m, root: m["workloads"][0].update(chips=4),      # two of four
+    lambda m, root: m["workloads"][0].update(traffic="missing"),  # no file
+    lambda m, root: m["per_layer"][0].update(moves="nothing"),
+    lambda m, root: m["per_layer"][0].update(why="a key too many"),
+    lambda m, root: m["configs"][1].update(reduced=["hidden_size"]),
+    lambda m, root: m.update(run_seconds=52),
+    lambda m, root: m["end_to_end"].pop(),                  # setup_s gone
+    lambda m, root: m["workloads"].append(dict(m["workloads"][0])),
+    lambda m, root: _name_block(root, None),
+    lambda m, root: _name_block(root, "absent"),
+    lambda m, root: _name_block(
+        root, "lossless", "logits = matmul_params = lambda *a: 0\n"),
 ], ids=["name", "unit", "bound", "four-chip-share", "file-by-name", "moves",
-        "extra-key", "width-reduced", "run-seconds", "setup_s", "twice"])
-def test_a_broken_manifest_is_refused(how):
+        "extra-key", "width-reduced", "run-seconds", "setup_s", "twice",
+        "block-unnamed", "block-names-no-file", "block-lacks-loss"])
+def test_a_broken_manifest_is_refused(how, tmp_path):
+    root = str(tmp_path)
+    shutil.copy(os.path.join(mf.CHECKOUT, "BENCHMARK.json"), root)
+    shutil.copytree(BENCH, os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__", "testdata"))
+    os.makedirs(os.path.join(root, "tests", "benchmark"))
+    manifest = mf.load(root)
+    mf.validate(manifest, root)             # the copy is whole
+    how(manifest, root)
     with pytest.raises((mf.ManifestError, KeyError, FileNotFoundError)):
-        mf.validate(_break(mf.load(), how))
+        mf.validate(manifest, root)
 
 
 def test_metrics_are_found_per_cell():
@@ -306,6 +343,128 @@ def test_op_family():
         "kernel:closed_call"
 
 
+def _kernel_context(events, forward_attrs, arch):
+    """A traced run's context over recorded events: one ``forward`` span
+    of the benchmark's probe, with the put's counts, inside the window."""
+    from benchmark.probe import Probe
+
+    probe = Probe()
+    probe.spans.append(("forward", 1.0, 1.1, forward_attrs))
+    ctx = Context({"xplane": "recorded", "chips": 1, "arch": arch,
+                   "probe": probe, "trace_marks": (0.0, 2.0)}, {},
+                  {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    ctx._trace = trace.summarize(events, chips=1)
+    return ctx
+
+
+def test_a_kernels_roofline_is_over_its_own_events():
+    """``paged_attn_roofline`` on the recorded [16, 1] decode step whose
+    kernel carries its name (PR 26's): the least time of the step's 24
+    calls over the device time of ``kernel:paged_attention`` — what the
+    sum over every custom call gave, and still that after a second
+    kernel enters the forward."""
+    pythia = arch_of("pythia-1.4b")["transformer_config"]
+    events = scopes.load_recorded(os.path.join(
+        BENCH, "testdata", "chat_one_step_scoped.json"))
+    # the record runs on into the next step's first layer: keep one step
+    step = min((e for e in events if e["name"].startswith("jit__forward")),
+               key=lambda e: e["start"])
+    events = [e for e in events if not trace.DEVICE_PLANE.match(e["plane"])
+              or e["start"] < step["start"] + step["dur"]]
+    stage = min((e for e in events if e["name"] == "ds:stage"),
+                key=lambda e: e["start"])["stats"]
+    attrs = {k: stage[k] for k in ("valid_tokens", "kv_read_tokens",
+                                   "qk_pairs")}
+    ctx = _kernel_context(events, attrs, pythia)
+    own = [e for e in events if e["name"].startswith("%paged_attention.")]
+    assert len(own) == 24                   # one call a layer
+    least = 24 * peaks.roofline_seconds(
+        peaks.paged_attention_cost(pythia, 15, 5611, 5611), "TPU v5 lite")
+    want = 100.0 * least / sum(e["dur"] for e in own)
+    got = readers.paged_attention_roofline(ctx)
+    assert got == pytest.approx(want, rel=1e-12)
+    assert got == pytest.approx(5.25635, rel=1e-5)
+    # the parent divided by every custom call of the trace: the same to
+    # eight digits (the pool's allocation markers, ~1 ns each, were in)
+    assert ctx.trace["kernel_s"] > ctx.trace["kernel_seconds"][
+        "kernel:paged_attention"]
+    assert got == pytest.approx(100.0 * least / ctx.trace["kernel_s"],
+                                rel=1e-7)
+    # a second, differently named kernel in every layer of the forward
+    other = [dict(e, name=e["name"].replace("%paged_attention.",
+                                            "%grouped_matmul."),
+                  start=e["start"] + e["dur"] / 4, dur=e["dur"] / 2)
+             for e in own]
+    crowded = _kernel_context(events + other, attrs, pythia)
+    assert crowded.trace["kernel_s"] == pytest.approx(
+        1.5 * ctx.trace["kernel_s"], rel=1e-6)
+    assert readers.paged_attention_roofline(crowded) == got
+    assert dict(crowded.trace["device_ops"])["kernel:grouped_matmul"] > 0
+    # a kernel that did not run has nothing to read, and neither has a
+    # window that made no call
+    assert readers.kernel_roofline(ctx, ("kernel:absent",), least) is None
+    assert readers.kernel_roofline(ctx, ("kernel:paged_attention",),
+                                   0.0) is None
+
+
+def test_kernels_are_told_apart_inside_a_program():
+    """``per_module``: two executions of a micro step, each holding a
+    flash kernel (2 s, then 4 s) and another kernel; the least time of one
+    execution over the flash kernel's own time, the median of the two."""
+    call = (" custom-call(bf16[8]{0} %q), "
+            "custom_call_target=\"tpu_custom_call\"")
+    d = "/device:TPU:0"
+    events = [ev("/host:CPU", "main", "bench:window", 0.0, 30.0)]
+    for start, flash in ((1.0, 2.0), (11.0, 4.0)):
+        events += [
+            ev(d, "XLA Modules", "jit_micro(1)", start, 8.0),
+            ev(d, "XLA Ops", "%flash_attention_fwd.3 = bf16[8]{0}" + call,
+               start, flash),
+            ev(d, "XLA Ops", "%grouped_matmul.5 = bf16[8]{0}" + call,
+               start + flash, 1.0)]
+    ctx = Context({"xplane": "made by hand", "chips": 1}, {},
+                  {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    ctx._trace = trace.summarize(events, chips=1)
+    assert ctx.trace["kernel_seconds"] == pytest.approx(
+        {"kernel:flash_attention_fwd": 6.0, "kernel:grouped_matmul": 2.0})
+    assert readers.kernel_roofline(ctx, readers.FLASH_KERNELS, 1.5,
+                                   per_module="micro") == \
+        pytest.approx((75.0 + 37.5) / 2)
+    assert readers.kernel_roofline(ctx, readers.FLASH_KERNELS, 1.5) == \
+        pytest.approx(25.0)
+
+
+@pytest.mark.parametrize("block_scopes,want", [
+    ((), "mlp"), (("router", "experts"), "experts")],
+    ids=["shared-vocabulary", "with-the-blocks"])
+def test_a_blocks_scopes_extend_the_vocabulary(block_scopes, want):
+    op = "jit(_forward)/layers/while/body/closed_call/mlp/experts/dot_general:"
+    assert scopes.scope_of(op, block_scopes) == want
+    assert scopes.scope_path(op, block_scopes)[:2] == ("layers", "mlp")
+    # the scan's plumbing keeps its meaning: under layers, under none of
+    # the block's scopes, whichever block
+    plumbing = "jit(_forward)/layers/while/body/dynamic_slice:"
+    assert scopes.scope_of(plumbing, block_scopes) == scopes.SCAN_OVERHEAD
+    events = [
+        ev("/host:CPU", "python3", trace.WINDOW, 0.0, 10.0),
+        dict(ev("/device:TPU:0", trace.OPS_LINE, "%fusion.1 = f32[8] "
+                "fusion(%a)", 1.0, 3.0), op_name=op),
+        dict(ev("/device:TPU:0", trace.OPS_LINE, "%copy.2 = f32[8] "
+                "copy(%b)", 4.0, 1.0), op_name=plumbing)]
+
+    class Block:
+        SCOPES = block_scopes
+
+    ctx = Context({"xplane": "made by hand", "chips": 1},
+                  {"block": Block}, {"platform": "tpu", "kind": "x",
+                                     "count": 1})
+    ctx._trace = {"made": "by hand"}
+    ctx._scopes = scopes.summarize(events, 1, Block.SCOPES)
+    assert scopes.device_share(ctx, want) == pytest.approx(75.0)
+    assert scopes.device_share(ctx, scopes.SCAN_OVERHEAD) == \
+        pytest.approx(25.0)
+
+
 # -------------------------------------------------------------- reference
 
 TINY = {
@@ -325,15 +484,17 @@ TINY = {
 
 @pytest.mark.parametrize("block", ["neox", "mistral"])
 def test_reference_agrees_with_the_programs_model(block):
-    """Both block types, float32, perturbed gains and biases, a window
-    shorter than the sequence: logits and loss against ``CausalLM``."""
+    """Both models of the ``dense`` block, found as a run finds it (by
+    the name in the configuration's file), float32, perturbed gains and
+    biases, a window shorter than the sequence: logits and loss against
+    ``CausalLM``."""
     import jax
     import jax.numpy as jnp
 
     from benchmark.model import seeded_params
     from deepspeed_tpu.models.transformer import CausalLM, TransformerConfig
 
-    arch = TINY[block]
+    arch, reference = TINY[block], block_of({"block": "dense"})
     model = CausalLM(TransformerConfig(dtype=jnp.float32,
                                        attention_impl="reference", **arch))
     params = seeded_params(model, 11, jnp.float32)
@@ -343,11 +504,11 @@ def test_reference_agrees_with_the_programs_model(block):
     for row in range(2):
         got = reference.logits(params, jnp.asarray(ids[row, :-1]), arch,
                                q_block=16)
-        assert reference.max_rel_err(got, want[row]) < 2e-5
+        assert ar.max_rel_err(got, want[row]) < 2e-5
     loss = reference.loss(params, jnp.asarray(ids), arch, q_block=16)
     assert float(loss) == pytest.approx(
         float(model.loss(params, {"input_ids": jnp.asarray(ids)})), rel=1e-5)
     # tight enough to see a lower precision: bf16 weights move the logits
     low = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
     got = reference.logits(low, jnp.asarray(ids[0, :-1]), arch)
-    assert reference.max_rel_err(got, want[0]) > 1e-3
+    assert ar.max_rel_err(got, want[0]) > 1e-3
