@@ -9,6 +9,7 @@ sequence's KV blocks). The serving loop on top (Dynamic SplitFuse) lives in
 
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...models.transformer import CausalLM
+from ...ops import gated_delta
 from ...utils.logging import logger
 from .paged_model import PagedCausalLM
 from .ragged import BlockedAllocator, DSStateManager, RaggedBatchWrapper
@@ -45,7 +47,8 @@ class RaggedInferenceEngineConfig:
                  admission_oversubscription_factor: float = 1.0,
                  admission_preemption_enabled: bool = False,
                  admission_victim_policy: str = "lowest_class",
-                 admission_max_preemptions_per_seq: int = 2):
+                 admission_max_preemptions_per_seq: int = 2,
+                 compile_ahead: int = 0):
         self.max_ragged_batch_size = max_ragged_batch_size
         self.max_ragged_sequence_count = max_ragged_sequence_count
         self.max_chunk_tokens = max_chunk_tokens
@@ -95,6 +98,40 @@ class RaggedInferenceEngineConfig:
         self.admission_victim_policy = admission_victim_policy
         self.admission_max_preemptions_per_seq = \
             admission_max_preemptions_per_seq
+        # compile every forward a put can ask for when the engine is
+        # built, on this many threads at once, instead of one after the
+        # other at each shape's first put (docs/SERVING.md "Compiling
+        # ahead"). 0 (the default): compile at first use
+        self.compile_ahead = int(compile_ahead)
+
+
+class _RowLogits:
+    """The logits of a put that ran as several forwards: the parts stay
+    on the device, and are glued on the host, in the put's row order, when
+    they are asked for as one array (``np.asarray``, the scheduler's
+    ``fetch``). Gluing them on the device would be one more small program
+    for every (one-token rows, chunk rows) count a step can have."""
+
+    def __init__(self, parts, order):
+        self.parts = parts
+        self.order = np.argsort(order)
+        self.shape = (len(order),) + tuple(parts[0].shape[1:])
+        self.dtype = parts[0].dtype
+        self._whole = None
+
+    def __array__(self, dtype=None, copy=None):
+        if self._whole is None:
+            self._whole = np.concatenate(
+                [np.asarray(p) for p in self.parts])[self.order]
+            self.parts = None
+        whole = self._whole
+        return whole if dtype is None else whole.astype(dtype)
+
+    def __len__(self):
+        return self.shape[0]
+
+    def __getitem__(self, index):
+        return np.asarray(self)[index]
 
 
 class InferenceEngineV2:
@@ -134,6 +171,13 @@ class InferenceEngineV2:
             if tp <= 1:
                 jmesh = None
                 tp = 1
+            elif model.cfg.is_hybrid:
+                from ...models.hybrid import RecurrentStateUnsupported
+
+                raise RecurrentStateUnsupported(
+                    "TP serving splits the KV pool and the attention by "
+                    "head; a hybrid model's recurrent state and expert "
+                    "share have no such split built yet")
         # int8/fp8 weight serving (docs/SERVING.md "Weight
         # quantization"): quantize the param tree ONCE, before TP
         # placement — so the scale planes are computed from the full
@@ -173,11 +217,16 @@ class InferenceEngineV2:
         self._cache_sharding = cache_sharding
         self._scale_sharding = scale_sharding
         self.state_manager = self._build_state_manager()
-        self.paged = PagedCausalLM(model, self.config.kv_block_size,
-                                   max_blocks_per_seq, mesh=jmesh)
-        self.batch = RaggedBatchWrapper(self.config.max_ragged_sequence_count,
-                                        self.config.max_chunk_tokens,
-                                        max_blocks_per_seq)
+        self.paged = PagedCausalLM(
+            model, self.config.kv_block_size, max_blocks_per_seq, mesh=jmesh,
+            max_batch_tokens=self.config.max_ragged_batch_size)
+        # a hybrid model's chunks are padded to the delta rule's tile at
+        # least: narrower ones would be programs of their own that do the
+        # same tile's work
+        self.batch = RaggedBatchWrapper(
+            self.config.max_ragged_sequence_count,
+            self.config.max_chunk_tokens, max_blocks_per_seq,
+            min_chunk=gated_delta.TILE if cfg.is_hybrid else 1)
         # what the last put staged, counted where the work happens (plain
         # ints; the scheduler copies them into its span attrs when traced):
         # the bucket [S, C] the forward ran at, its real rows and valid
@@ -189,6 +238,51 @@ class InferenceEngineV2:
         # any interval = delta positions_computed / delta tokens_valid)
         self.put_totals: Dict[str, int] = {
             "forwards": 0, "positions_computed": 0, "tokens_valid": 0}
+        if cfg.is_hybrid:       # its sparse FFNs' rows (_count_routing)
+            self.put_totals.update(moe_rows_routed=0, moe_rows_held=0)
+        self._forward_jit = self.paged.forward
+        self._compile_ahead()
+
+    def forward_shapes(self) -> List[Tuple[int, int]]:
+        """Every ``[S, C]`` a put's forward can be: the batch's buckets; of
+        a hybrid model's only ``[1, C]`` and ``[S, 1]``
+        (``_forward_groups``)."""
+        seqs, chunks = self.batch.buckets()
+        return [(s, c) for s in seqs for c in chunks
+                if not self.model.cfg.is_hybrid or s == 1 or c == 1]
+
+    def _compile_ahead(self) -> None:
+        """Lower the forward at every shape of ``forward_shapes``, for the
+        parameters and the cache as they are now, and compile what is
+        lowered on ``config.compile_ahead`` threads meanwhile;
+        ``paged.forward`` then runs the executable of the batch's shape.
+        The lowering stays on this thread, one shape after the other:
+        it is Python and would take turns anyway, and which program
+        traces a shared inner function first is written into every
+        program's source locations — lowered on threads, the persistent
+        cache's keys differ from run to run (measured: 4 of 11 missed)."""
+        self.paged.forward = jitted = self._forward_jit
+        if not self.config.compile_ahead:
+            return
+        sm = self.state_manager
+        width = self.batch.max_blocks_per_seq
+
+        def lowered(shape):
+            s = shape[0]
+            ints = [shape, (s,), (s,), (s, width)] + \
+                ([(s,)] if sm.recurrent else [])
+            return jitted.lower(self.params, sm.forward_cache, *(
+                jax.ShapeDtypeStruct(i, jnp.int32) for i in ints))
+
+        with ThreadPoolExecutor(self.config.compile_ahead) as pool:
+            compiling = {shape: pool.submit(lowered(shape).compile)
+                         for shape in self.forward_shapes()}
+        programs = {shape: c.result() for shape, c in compiling.items()}
+
+        def forward(params, cache, tokens, *rest):
+            return programs[tokens.shape](params, cache, tokens, *rest)
+
+        self.paged.forward = forward
 
     def _build_state_manager(self) -> DSStateManager:
         """Fresh sequence registry + KV pools from the current config —
@@ -210,7 +304,10 @@ class InferenceEngineV2:
             kv_tier_enabled=self.config.kv_tier_enabled,
             kv_tier_host_bytes=self.config.kv_tier_host_bytes,
             kv_tier_disk_path=self.config.kv_tier_disk_path,
-            kv_tier_disk_bytes=self.config.kv_tier_disk_bytes)
+            kv_tier_disk_bytes=self.config.kv_tier_disk_bytes,
+            # a hybrid model: one recurrent-state slot a sequence the
+            # scheduler can have running
+            state_slots=self.config.max_ragged_sequence_count)
 
     # ----------------------------------------------------------- admission
     def can_schedule(self, uids: Sequence[int],
@@ -220,11 +317,12 @@ class InferenceEngineV2:
             return SchedulingResult.BatchSequenceLimitExceeded
         if sum(lengths) > self.config.max_ragged_batch_size:
             return SchedulingResult.BatchTokenLimitExceeded
-        blocks_needed = 0
+        blocks_needed = slots_needed = 0
         for uid, n in zip(uids, lengths):
             if n > self.config.max_chunk_tokens:
                 return SchedulingResult.SequenceTokenLimitExceeded
             seq = self.state_manager.get_sequence(uid)
+            slots_needed += seq is None
             total = (seq.seen_tokens if seq else 0) + n
             if total > self.model.cfg.max_seq_len:
                 return SchedulingResult.SequenceTokenLimitExceeded
@@ -234,6 +332,9 @@ class InferenceEngineV2:
         # available = free + LRU-evictable cached blocks (identical to the
         # free count when the prefix cache is disabled)
         if blocks_needed > self.state_manager.available_blocks:
+            return SchedulingResult.KVCacheLimitExceeded
+        if self.state_manager.recurrent and \
+                slots_needed > self.state_manager.free_state_slots:
             return SchedulingResult.KVCacheLimitExceeded
         return SchedulingResult.Success
 
@@ -271,7 +372,47 @@ class InferenceEngineV2:
         status = self.can_schedule(uids, [len(t) for t in tokens_list])
         if status != SchedulingResult.Success:
             raise SchedulingError(status)
+        groups = self._forward_groups(tokens_list)
+        if len(groups) == 1:
+            return self._forward_rows(uids, tokens_list, verify_width,
+                                      defer_commit)
+        outs, records = [], []
+        for rows in groups:
+            outs.append(self._forward_rows(
+                [uids[i] for i in rows], [tokens_list[i] for i in rows],
+                verify_width, defer_commit))
+            records.append(self.last_put)
+        # the put's record: its last (widest) forward's bucket, the sums
+        # of what its forwards counted, and how many they were
+        summed = ("rows", "valid_tokens", "kv_read_tokens", "qk_pairs",
+                  "moe_rows_routed", "moe_rows_held")
+        self.last_put = dict(records[-1], forwards=len(records), **{
+            k: sum(r[k] for r in records) for k in summed
+            if k in records[-1]})
+        return _RowLogits(outs, [i for rows in groups for i in rows])
 
+    def _forward_groups(self, tokens_list) -> List[List[int]]:
+        """Which rows of a put run together in one forward. The forward
+        pads its batch to an ``[S, C]`` bucket, so a chunk row beside
+        S - 1 one-token rows costs S times its own work in every mixer.
+        For a hybrid model that is most of a step (measured on the chip at
+        Qwen3-Next's widths, 8k of context: ``[8, 1024]`` 116 ms against
+        ``[1, 1024]`` 45 ms + ``[8, 1]`` 4 ms), so its rows wider than
+        one token run each as a forward of its own and the one-token rows
+        together. Every other model keeps the one forward a put (the
+        same split for them is ROADMAP S3's to measure)."""
+        everyone = [list(range(len(tokens_list)))]
+        if not self.model.cfg.is_hybrid:
+            return everyone
+        wide = [i for i, t in enumerate(tokens_list) if len(t) > 1]
+        ones = [i for i, t in enumerate(tokens_list) if len(t) == 1]
+        if not wide or (len(wide) == 1 and not ones):
+            return everyone
+        return ([ones] if ones else []) + [[i] for i in wide]
+
+    def _forward_rows(self, uids, tokens_list, verify_width: int,
+                      defer_commit: bool) -> jnp.ndarray:
+        """One forward over ``uids``' rows (``put``'s body)."""
         self.batch.clear()
         staged = []
         valid = kv_read = qk_pairs = 0
@@ -288,21 +429,33 @@ class InferenceEngineV2:
 
         arrays = self.batch.finalize()
         bucket_seqs, bucket_chunk = arrays["tokens"].shape
+        sm = self.state_manager
         self.last_put = {
             "bucket_seqs": bucket_seqs, "bucket_chunk": bucket_chunk,
             "rows": len(staged), "valid_tokens": valid,
             "kv_read_tokens": kv_read, "qk_pairs": qk_pairs,
-            "free_blocks": self.state_manager.available_blocks}
+            "free_blocks": sm.available_blocks}
         totals = self.put_totals
         totals["forwards"] += 1
         totals["positions_computed"] += bucket_seqs * bucket_chunk
         totals["tokens_valid"] += valid
-        kv_cache = self.state_manager.kv_cache
+        kv_cache = sm.forward_cache
         args = (self.params, kv_cache,
                 jnp.asarray(arrays["tokens"]),
                 jnp.asarray(arrays["start_pos"]),
                 jnp.asarray(arrays["n_tokens"]),
                 jnp.asarray(arrays["block_tables"]))
+        if sm.recurrent:
+            # each row's slot in the state tree; a padded row's is the
+            # scratch slot behind the last
+            slots = np.full((bucket_seqs,), sm.state_slots, np.int32)
+            slots[:len(staged)] = [seq.state_slot for seq, _ in staged]
+            args += (jnp.asarray(slots),)
+            # a hybrid model's put says two things more: its slots in use
+            # and its sparse FFNs' rows
+            self.last_put["state_slots_used"] = \
+                sm.state_slots - sm.free_state_slots
+            self._count_routing(valid)
         # the forward consumes ``kv_cache`` (donated, written in place) and
         # hands the same memory back as ``new_cache``
         try:
@@ -327,12 +480,29 @@ class InferenceEngineV2:
         # blocks belong to the sequence and return to the pool at flush.
         # (Assumes each uid appears at most once per batch, which the
         # scheduler guarantees.)
-        self.state_manager.kv_cache = new_cache
+        sm.forward_cache = new_cache
         for seq, toks in staged:
             seq.seen_tokens += len(toks)
             if not defer_commit:
                 self.state_manager.record_tokens(seq, toks)
         return logits[:len(uids)]
+
+    def _count_routing(self, valid_tokens: int) -> None:
+        """``moe_rows_routed`` / ``moe_rows_held`` of a hybrid model's
+        sparse FFNs: the (token, choice) pairs this forward routes — every
+        valid token, top-k choices, each layer — and, of those, the pairs
+        whose expert this model holds. The second is the *expectation*
+        under even routing (routed x held / experts): the real count
+        lives on the device and is not fetched."""
+        cfg = self.model.cfg
+        routed = valid_tokens * cfg.moe_top_k * cfg.num_layers
+        held = cfg.moe_held_experts[1] if cfg.moe_held_experts \
+            else cfg.moe_num_experts
+        counts = {"moe_rows_routed": routed,
+                  "moe_rows_held": routed * held // cfg.moe_num_experts}
+        self.last_put.update(counts)
+        for name, n in counts.items():
+            self.put_totals[name] += n
 
     def flush(self, uid: int) -> None:
         self.state_manager.flush_sequence(uid)
@@ -475,9 +645,11 @@ class InferenceEngineV2:
         already mid-flight are excluded from hashing by the chain-state
         consistency guard in ``record_tokens``). Disabling drops the whole
         index so retained blocks cannot strand outside the free pool."""
+        sm = self.state_manager
+        if enabled and sm.recurrent:
+            sm.refuse_recurrent("the prefix cache")
         self.config.enable_prefix_cache = bool(enabled)
         self.config.prefix_cache_max_blocks = max_blocks
-        sm = self.state_manager
         if enabled:
             sm.prefix_cache_enabled = True
             sm.prefix_cache_max_blocks = max_blocks or 0
@@ -564,6 +736,7 @@ class InferenceEngineV2:
         self.config.kv_quant_dtype = dtype
         self.config.kv_quant_scale_granularity = scale_granularity
         self.state_manager = self._build_state_manager()
+        self._compile_ahead()
 
     # ------------------------------------------------------- weight serving
     def configure_weight_quant(self, enabled: bool, dtype: str = "int8",
@@ -610,6 +783,7 @@ class InferenceEngineV2:
         self.config.weight_quant_dtype = dtype
         self.config.weight_quant_block = int(block)
         self.config.weight_quant_skip = skip_list
+        self._compile_ahead()
 
     def param_stats(self) -> Dict[str, object]:
         """Resident param-byte accounting (total + quantized share) — the

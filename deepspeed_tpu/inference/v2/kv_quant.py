@@ -99,15 +99,16 @@ def validate_kv_quant(dtype: str, scale_granularity: str) -> None:
 
 def kv_bytes_per_block(model_cfg, block_size: int, quant: bool,
                        dtype=None) -> int:
-    """HBM bytes one KV pool block costs across all layers: K and V slabs
-    ``[L, KH, bs, D]`` at the pool dtype, plus (quantized) two f32 scale
-    entries per (layer, kv-head). The unit of the fixed-byte-budget
-    comparison: at equal ``num_blocks * kv_bytes_per_block`` an int8 pool
-    holds ~2x the bf16 blocks."""
-    slab = (model_cfg.num_layers * model_cfg.kv_heads * block_size
-            * model_cfg.head_dim)
+    """HBM bytes one KV pool block costs across the layers that keep
+    per-token K/V (all of them, but for a hybrid block's recurrent
+    layers): K and V slabs ``[L, KH, bs, D]`` at the pool dtype, plus
+    (quantized) two f32 scale entries per (layer, kv-head). The unit of
+    the fixed-byte-budget comparison: at equal ``num_blocks *
+    kv_bytes_per_block`` an int8 pool holds ~2x the bf16 blocks."""
+    layers = getattr(model_cfg, "num_attn_layers", model_cfg.num_layers)
+    slab = layers * model_cfg.kv_heads * block_size * model_cfg.head_dim
     if quant:
-        return 2 * slab * 1 + 2 * model_cfg.num_layers * model_cfg.kv_heads * 4
+        return 2 * slab * 1 + 2 * layers * model_cfg.kv_heads * 4
     itemsize = jnp.dtype(dtype or model_cfg.dtype).itemsize
     return 2 * slab * itemsize
 

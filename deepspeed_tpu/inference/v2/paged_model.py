@@ -59,8 +59,12 @@ class PagedCausalLM:
 
     def __init__(self, model: CausalLM, block_size: int,
                  max_blocks_per_seq: int, mesh=None,
-                 attn_impl: str = None):
+                 attn_impl: str = None, max_batch_tokens: int = 0):
         self.model = model
+        # the most valid tokens one forward is given (the engine's
+        # max_ragged_batch_size; 0: unknown): a hybrid block's sparse FFN
+        # runs over that many rows, not over the padded [N, C] bucket
+        self.max_batch_tokens = int(max_batch_tokens)
         self.cfg = model.cfg
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
@@ -143,8 +147,11 @@ class PagedCausalLM:
 
     # ------------------------------------------------------------------
     def _forward(self, params, kv_cache, tokens, start_pos, n_tokens,
-                 block_tables, verify_width: int = 0):
+                 block_tables, state_slots=None, verify_width: int = 0):
         """tokens [N, C]; start_pos/n_tokens [N]; block_tables [N, MB];
+        ``state_slots`` [N]: a hybrid model's rows' slots in the recurrent
+        state tree, which then rides in ``kv_cache`` beside the pool
+        (``_forward_hybrid``); None otherwise.
         kv_cache {k,v}: [L, NB, KH, bs, D] — plus {k_scale,v_scale}
         [L, NB, KH] when the pools are int8-quantized (kv_quant.py); the
         pytree structure selects the compiled program, so the
@@ -159,6 +166,10 @@ class PagedCausalLM:
         duplicate their first position in the left padding.
         """
         cfg = self.cfg
+        if cfg.is_hybrid:
+            return self._forward_hybrid(params, kv_cache, tokens, start_pos,
+                                        n_tokens, block_tables, state_slots,
+                                        verify_width)
         N, C = tokens.shape
         bs = self.block_size
         NB = kv_cache["k"].shape[1]
@@ -286,6 +297,119 @@ class PagedCausalLM:
                                           axis=1)                 # [N,W,H]
                 return self.model._unembed(params, x_v), new_cache
             # logits_gather: only the last valid token per sequence
+            last_idx = jnp.clip(n_tokens - 1, 0, C - 1)
+            x_last = jnp.take_along_axis(x, last_idx[:, None, None],
+                                         axis=1)[:, 0]
+            logits = self.model._unembed(params, x_last[:, None, :])[:, 0]
+            return logits, new_cache
+
+    # ------------------------------------------------------------------
+    def _forward_hybrid(self, params, cache, tokens, start_pos, n_tokens,
+                        block_tables, state_slots, verify_width: int = 0):
+        """The forward of a hybrid block (``cfg.layer_pattern``,
+        models/hybrid.py): one scan over the periods, the period's layers
+        in order inside the body. ``cache`` holds two kinds of state and
+        both ride in the carry, donated and written in place:
+
+        - ``k``/``v`` [L_attn, NB, KH, bs, D]: the paged pool of the
+          attention layers only; ``layer`` counts those.
+        - ``ssm`` [L_lin, slots + 1, HV, DK, DV] float32 and ``conv``
+          [L_lin, slots + 1, K-1, CH]: the recurrent layers' state, one
+          slot a sequence (``state_slots`` [N]; padded rows point at the
+          scratch slot behind the last). A row with ``start_pos`` 0 starts
+          from zero whatever its slot holds; any other resumes from its
+          slot. Positions at or beyond ``n_tokens`` change no state.
+
+        Returns (last_logits [N, V], new cache)."""
+        from ...models import hybrid
+
+        if verify_width:
+            raise hybrid.RecurrentStateUnsupported(
+                "speculative verification rolls rejected tokens back; a "
+                "recurrent state cannot be cut at a token")
+        cfg = self.cfg
+        N, C = tokens.shape
+        bs = self.block_size
+        NB = cache["k"].shape[1]
+        dt = cfg.dtype
+        scope = jax.named_scope
+        pattern = cfg.layer_pattern
+        n_full, n_lin = pattern.count("full"), pattern.count("linear")
+        kvh, hd = cfg.kv_heads, cfg.head_dim
+
+        with scope("embed"):
+            x = params["embed"]["wte"][tokens].astype(dt)      # [N, C, H]
+            positions = start_pos[:, None] + jnp.arange(C)[None, :]
+            cos_full, sin_full = rope_table(cfg.max_seq_len, cfg.rot_dim,
+                                            cfg.rope_theta)
+            cos, sin = cos_full[positions], sin_full[positions]
+        quant = "k_scale" in cache
+        with scope("kv_write"):
+            kv_plan = touched_block_plan(block_tables, start_pos, n_tokens,
+                                         C, bs, NB)
+
+        def rope(t):
+            return apply_rope(t, cos, sin, cfg.rope_interleaved)
+
+        valid = jnp.arange(C)[None, :] < n_tokens[:, None]      # [N, C]
+        max_rows = min(N * C, self.max_batch_tokens or N * C)
+        fresh = start_pos == 0
+
+        def period(carry, xs):
+            x, pools = carry
+            slots, p = xs
+            pools = dict(pools)         # the mixers below write into it
+
+            def full_mixer(h1, lp, i):
+                layer = p * n_full + i
+                with scope("qkv"):
+                    q, k, v, gate = hybrid.full_qkv(cfg, h1, lp, rope)
+                with scope("kv_write"):
+                    for name, rows in (("k", k), ("v", v)):
+                        rows = rows.reshape(-1, kvh, hd)
+                        if quant:
+                            sname = name + "_scale"
+                            pools[name], pools[sname] = \
+                                quantized_block_write(
+                                    pools[name], pools[sname], rows,
+                                    kv_plan, layer)
+                        else:
+                            pools[name] = block_write(pools[name], rows,
+                                                      kv_plan, layer)
+                with scope("attend"):
+                    attn = self._attend(q, pools, layer, block_tables,
+                                        start_pos, n_tokens, None)
+                with scope("attn_out"):
+                    return hybrid.full_out(cfg, attn, gate, lp)
+
+            def linear_mixer(h1, lp, i):
+                layer = p * n_lin + i
+                with scope("linear_attn"):
+                    tail = pools["conv"][layer, state_slots]
+                    state = pools["ssm"][layer, state_slots]
+                    tail = jnp.where(fresh[:, None, None], 0, tail)
+                    state = jnp.where(fresh[:, None, None, None], 0, state)
+                    y, tail, state = hybrid.gdn_mixer(cfg, h1, lp, tail,
+                                                      state, n_tokens)
+                    pools["conv"] = pools["conv"].at[
+                        layer, state_slots].set(tail)
+                    pools["ssm"] = pools["ssm"].at[
+                        layer, state_slots].set(state)
+                    return y
+
+            x, _ = hybrid.run_period(cfg, x, slots, full_mixer, linear_mixer,
+                                     valid=valid, max_rows=max_rows)
+            return (x, pools), None
+
+        slots = tuple(params["layers"][f"slot{i}"]
+                      for i in range(len(pattern)))
+        with scope("layers"):
+            (x, new_cache), _ = lax.scan(
+                period, (x, dict(cache)),
+                (slots, jnp.arange(cfg.num_periods, dtype=jnp.int32)))
+        with scope("final_norm"):
+            x = hybrid.block_norm(cfg, x, params["final_norm"]["w"])
+        with scope("logits"):
             last_idx = jnp.clip(n_tokens - 1, 0, C - 1)
             x_last = jnp.take_along_axis(x, last_idx[:, None, None],
                                          axis=1)[:, 0]

@@ -49,6 +49,9 @@ class DSSequenceDescriptor:
     chain_hash: int = 0
     hashed_blocks: int = 0
     pending_tokens: List[int] = field(default_factory=list)
+    # a hybrid model's recurrent layers: this sequence's slot in the
+    # state tree (-1: the model has none)
+    state_slot: int = -1
 
     @property
     def cur_allocated_blocks(self) -> int:
@@ -68,10 +71,22 @@ class DSStateManager:
                  kv_tier_enabled: bool = False,
                  kv_tier_host_bytes: int = 64 * 1024 * 1024,
                  kv_tier_disk_path: Optional[str] = None,
-                 kv_tier_disk_bytes: int = 0):
+                 kv_tier_disk_bytes: int = 0,
+                 state_slots: int = 0):
         from ..kv_quant import kv_bytes_per_block
 
         self.cfg = model_cfg
+        # A hybrid model's recurrent layers keep a fixed-size state a
+        # sequence (models/hybrid.state_shapes) beside the paged K/V of
+        # its attention layers: ``state_slots`` slots, one a tracked
+        # sequence, taken with the sequence and given back by flush.
+        self.recurrent = getattr(model_cfg, "num_linear_layers", 0) > 0
+        self.state_slots = int(state_slots) if self.recurrent else 0
+        if self.recurrent and self.state_slots <= 0:
+            raise ValueError("a model with recurrent layers needs "
+                             "state_slots > 0")
+        if self.recurrent and enable_prefix_cache:
+            self.refuse_recurrent("the prefix cache")
         self.block_size = block_size
         self.num_blocks = num_blocks
         self.max_tracked_sequences = max_tracked_sequences
@@ -132,8 +147,9 @@ class DSStateManager:
         # paged-attention index maps (ops/paged_attention.py).
         # ``sharding``: optional NamedSharding placing KH over the tensor
         # axis (TP serving — reference v2 sharding/qkv.py:166 head split).
-        shape = (model_cfg.num_layers, num_blocks, model_cfg.kv_heads,
-                 block_size, model_cfg.head_dim)
+        shape = (getattr(model_cfg, "num_attn_layers",
+                         model_cfg.num_layers), num_blocks,
+                 model_cfg.kv_heads, block_size, model_cfg.head_dim)
         from ..kv_quant import pool_dtype as _pool_dtype
 
         pool_dt = _pool_dtype(self.kv_quant_dtype) if self.kv_quant else dt
@@ -160,13 +176,59 @@ class DSStateManager:
                                               scale_sharding)
             self.kv_cache["v_scale"] = _alloc(sshape, jnp.float32,
                                               scale_sharding)
+        # the recurrent state tree: [L_linear, slots + 1, ...] a leaf —
+        # the last slot is scratch, where a padded batch row's (unchanged)
+        # state is written. Donated to the forward with the pool. A slot
+        # is not cleared when it is given back: a row at start_pos 0
+        # starts from zero whatever its slot holds (paged_model.py).
+        self.state_cache: Dict[str, jax.Array] = {}
+        self._free_slots: List[int] = list(range(self.state_slots))[::-1]
+        if self.recurrent:
+            from ....models.hybrid import state_shapes
+
+            self.state_cache = {
+                name: jnp.zeros(shp, adt) for name, (shp, adt)
+                in state_shapes(model_cfg, self.state_slots + 1).items()}
+
+    def refuse_recurrent(self, what: str) -> None:
+        """The typed refusal of a feature that assumes per-token KV."""
+        from ....models.hybrid import RecurrentStateUnsupported
+
+        raise RecurrentStateUnsupported(
+            f"{what} needs per-token KV in every layer; this model keeps "
+            f"a recurrent state in {self.cfg.num_linear_layers} of its "
+            f"{self.cfg.num_layers} layers, which cannot be cut at a "
+            "token or shared by prefix (snapshots of state are not "
+            "built yet)")
+
+    # -- the forward's cache ------------------------------------------------
+    @property
+    def forward_cache(self) -> Dict[str, jax.Array]:
+        """What the paged forward is given and donated: the K/V pool and,
+        for a hybrid model, the recurrent state tree beside it."""
+        return {**self.kv_cache, **self.state_cache}
+
+    @forward_cache.setter
+    def forward_cache(self, cache: Dict[str, jax.Array]) -> None:
+        self.state_cache = {k: cache[k] for k in self.state_cache}
+        self.kv_cache = {k: v for k, v in cache.items()
+                         if k not in self.state_cache}
+
+    @property
+    def free_state_slots(self) -> int:
+        return len(self._free_slots)
 
     # -- sequence registry -------------------------------------------------
     def get_or_create_sequence(self, uid: int) -> DSSequenceDescriptor:
         if uid not in self._seqs:
             if len(self._seqs) >= self.max_tracked_sequences:
                 raise RuntimeError("max tracked sequences exceeded")
-            self._seqs[uid] = DSSequenceDescriptor(uid=uid)
+            seq = DSSequenceDescriptor(uid=uid)
+            if self.recurrent:
+                if not self._free_slots:
+                    raise RuntimeError("no free recurrent-state slot")
+                seq.state_slot = self._free_slots.pop()
+            self._seqs[uid] = seq
         return self._seqs[uid]
 
     def get_sequence(self, uid: int) -> Optional[DSSequenceDescriptor]:
@@ -181,6 +243,8 @@ class DSStateManager:
         self._reserved.pop(uid, None)     # reservation dies with the state
         if seq is not None and seq.kv_blocks:
             self._release_blocks(seq.kv_blocks)
+        if seq is not None and seq.state_slot >= 0:
+            self._free_slots.append(seq.state_slot)
 
     def _release_blocks(self, blocks: List[int]) -> None:
         """Drop one reference per block and keep the incremental
@@ -221,6 +285,8 @@ class DSStateManager:
         seq = self._seqs.get(uid)
         if seq is None or n_tokens <= 0:
             return 0
+        if self.recurrent:
+            self.refuse_recurrent("trim_sequence (speculative rollback)")
         if n_tokens > seq.seen_tokens:
             raise ValueError(
                 f"cannot trim {n_tokens} tokens from sequence {uid} "
@@ -290,6 +356,10 @@ class DSStateManager:
                 "kv_quant": self.kv_quant,
                 "kv_quant_dtype": self.kv_quant_dtype,
                 "n_blocks": len(seq.kv_blocks)}
+        if self.recurrent:
+            # the recurrent layers' state goes with the blocks, whole
+            meta["state"] = {name: np.asarray(leaf[:, seq.state_slot])
+                             for name, leaf in self.state_cache.items()}
         if chunk_blocks and chunk_blocks > 0:
             device_chunks = []
             for s in range(0, len(seq.kv_blocks), int(chunk_blocks)):
@@ -373,6 +443,13 @@ class DSStateManager:
         if set(slabs) != set(self.kv_cache):
             raise ValueError(f"KV import slab keys {sorted(slabs)} != "
                              f"pool keys {sorted(self.kv_cache)}")
+        state = payload.get("state") or {}
+        if {k: np.shape(v) for k, v in state.items()} != {
+                k: v.shape[:1] + v.shape[2:]
+                for k, v in self.state_cache.items()}:
+            raise ValueError("KV import recurrent-state mismatch: the "
+                             "payload and this model do not keep the same "
+                             "state a sequence")
         seen = int(payload["seen_tokens"])
         if len(tokens) != seen:
             raise ValueError(f"KV import needs the {seen} tokens the KV "
@@ -399,6 +476,11 @@ class DSStateManager:
         seq = self.get_or_create_sequence(uid)
         blocks = self.allocator.allocate(n)
         try:
+            if self.recurrent:
+                self.state_cache = {
+                    name: leaf.at[:, seq.state_slot].set(
+                        jnp.asarray(state[name], dtype=leaf.dtype))
+                    for name, leaf in self.state_cache.items()}
             if chunks is not None:
                 # streamed form: glue the chunks per slab and scatter
                 # ONCE per pool tensor — a per-chunk `.at[].set` would
@@ -425,6 +507,8 @@ class DSStateManager:
         except Exception:
             self._seqs.pop(uid, None)
             self.allocator.release(blocks)
+            if seq.state_slot >= 0:
+                self._free_slots.append(seq.state_slot)
             raise
 
     @property
@@ -538,6 +622,8 @@ class DSStateManager:
         # representation dtype axis (int8/fp8_e4m3) — absent only in
         # pre-dtype payloads, which were int8 by construction
         meta["kv_quant_dtype"] = payload.get("kv_quant_dtype", "int8")
+        if "state" in payload:
+            meta["state"] = payload["state"]
         if self._tier is not None:
             # not a prefix-cache spill: keep the per-block tier counters
             # honest (sequences_preempted counts these instead)
@@ -612,6 +698,11 @@ class DSStateManager:
         occ["kv_bytes_host_tier"] = tier["host_bytes"]
         occ["kv_blocks_disk_tier"] = tier["disk_blocks"]
         occ["kv_bytes_disk_tier"] = tier["disk_bytes"]
+        # a hybrid model's recurrent-state slots (zeros without one)
+        occ["state_slots"] = self.state_slots
+        occ["state_slots_used"] = self.state_slots - len(self._free_slots)
+        occ["state_bytes"] = sum(int(leaf.nbytes)
+                                 for leaf in self.state_cache.values())
         return occ
 
     def prefix_stats(self) -> Dict[str, int]:
@@ -630,6 +721,8 @@ class DSStateManager:
         """
         if not self.prefix_cache_enabled:
             return 0
+        if self.recurrent:      # enabled on a built engine: refuse here
+            self.refuse_recurrent("the prefix cache (match_prefix)")
         seq = self.get_or_create_sequence(uid)
         if seq.seen_tokens > 0 or seq.kv_blocks:
             return seq.seen_tokens
@@ -759,6 +852,8 @@ class DSStateManager:
         self._restore_times.clear()
         if not enabled:
             return
+        if self.recurrent:
+            self.refuse_recurrent("the KV tier")
         if not self.prefix_cache_enabled:
             raise ValueError(
                 "kv_tier requires the prefix cache: spill/restore happen "

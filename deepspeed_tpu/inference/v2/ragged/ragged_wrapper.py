@@ -16,9 +16,14 @@ import numpy as np
 
 
 class RaggedBatchWrapper:
-    def __init__(self, max_seqs: int, max_chunk: int, max_blocks_per_seq: int):
+    def __init__(self, max_seqs: int, max_chunk: int, max_blocks_per_seq: int,
+                 min_chunk: int = 1):
         self.max_seqs = max_seqs
         self.max_chunk = max_chunk
+        # the narrowest bucket a chunk wider than one token is padded to
+        # (a model whose mixer works in tiles gains nothing from a
+        # narrower program of its own); a one-token step stays [S, 1]
+        self.min_chunk = min(max(int(min_chunk), 1), max_chunk)
         self.max_blocks_per_seq = max_blocks_per_seq
         self.clear()
 
@@ -56,6 +61,17 @@ class RaggedBatchWrapper:
         self.uids.append(uid)
         return i
 
+    def buckets(self) -> Tuple[List[int], List[int]]:
+        """Every sequence count and every chunk width ``finalize`` can
+        hand out (what an engine that compiles ahead has to cover)."""
+        def pow2(cap):
+            return sorted({self._bucket(1 << i, cap)
+                           for i in range(cap.bit_length() + 1)})
+
+        chunks = {c if c == 1 else max(c, self.min_chunk)
+                  for c in pow2(self.max_chunk)}
+        return pow2(self.max_seqs), sorted(chunks)
+
     @staticmethod
     def _bucket(n: int, cap: int) -> int:
         """Smallest power of two >= n, capped. Bounds the number of compiled
@@ -81,6 +97,8 @@ class RaggedBatchWrapper:
             }
         S = self._bucket(max(len(self.uids), 1), self.max_seqs)
         C = self._bucket(max(int(self.n_tokens.max()), 1), self.max_chunk)
+        if C > 1:
+            C = max(C, self.min_chunk)
         return {
             "tokens": self.tokens[:S, :C],
             "start_pos": self.start_pos[:S],

@@ -1,0 +1,295 @@
+"""The parts of a hybrid block: layers of more than one kind in one model
+(``TransformerConfig.layer_pattern``: one period of mixer kinds, scanned
+one period an iteration), each followed by the same sparse FFN.
+
+- ``"full"``: gated softmax attention — a stated head size, per-head
+  q/k RMSNorm, an output gate read off a ``wq`` twice as wide
+  (``[q | g]`` within each head), partial rotary.
+- ``"linear"``: Gated DeltaNet (``ops/gated_delta.py``) — one projection
+  to ``[q | k | v | z]`` and one to ``[b | a]``, a depthwise causal conv
+  over ``[q | k | v]``, the gated delta rule over a float32 state, a
+  gated RMSNorm and the output projection. Its cache is the state and the
+  conv's last inputs, not per-token K/V.
+- the FFN: dropless top-k experts over a held range plus a shared expert
+  under a sigmoid gate (``moe/grouped.py``).
+
+``CausalLM`` (training, the reference path) and ``PagedCausalLM``
+(serving) both call these; only where the mixer's cache lives differs.
+Scopes follow ``docs/OBSERVABILITY.md``: ``linear_attn`` ⊃ ``gdn_proj``,
+``gdn_conv``, ``gdn_scan``, ``gdn_out``; ``router``, ``experts``,
+``shared_expert`` inside ``mlp``.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..ops import gated_delta as gd
+from ..parallel.sharding import spec
+
+KINDS = ("full", "linear")
+
+
+class RecurrentStateUnsupported(NotImplementedError):
+    """Raised where a feature that assumes per-token KV (rollback, prefix
+    sharing, the KV tier, head-split TP) meets a model with recurrent
+    layers: their state cannot be cut at a token or shared by prefix."""
+
+
+def rms(x, w, eps, zero_centered):
+    """RMSNorm over the last dim in float32; ``zero_centered``: the gain
+    is ``1 + w``."""
+    dt = x.dtype
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
+    w32 = w.astype(jnp.float32)
+    return (y * (1.0 + w32 if zero_centered else w32)).astype(dt)
+
+
+def block_norm(cfg, x, w):
+    return rms(x, w, cfg.norm_eps, cfg.norm_zero_centered)
+
+
+# ------------------------------------------------------------------ sizes
+
+def gdn_dims(cfg):
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    return hk, hv, dk, dv, 2 * hk * dk + hv * dv     # conv channels
+
+
+def state_shapes(cfg, slots: int):
+    """The recurrent cache for ``slots`` sequences: the delta rule's
+    state (float32 whatever the served type) and the conv's tail."""
+    hk, hv, dk, dv, ch = gdn_dims(cfg)
+    L = cfg.num_linear_layers
+    return {"ssm": ((L, slots, hv, dk, dv), jnp.float32),
+            "conv": ((L, slots, cfg.linear_conv_kernel - 1, ch), cfg.dtype)}
+
+
+# ------------------------------------------------------------------- init
+
+def init_slot(cfg, kind: str, key, periods: int):
+    """One position of the period: its mixer's and its FFN's weights,
+    stacked over the periods."""
+    h, hd, nh, kvh = (cfg.hidden_size, cfg.head_dim, cfg.num_heads,
+                      cfg.kv_heads)
+    P, std = periods, 0.02
+    out_std = std / math.sqrt(2 * cfg.num_layers)
+    ks = iter(jax.random.split(key, 16))
+
+    def w(shape, scale=std):
+        return (scale * jax.random.normal(next(ks), (P,) + shape)
+                ).astype(jnp.float32)
+
+    gain = (jnp.zeros if cfg.norm_zero_centered else jnp.ones)
+    lp = {"attn_norm_w": gain((P, h), jnp.float32),
+          "mlp_norm_w": gain((P, h), jnp.float32)}
+    if kind == "full":
+        q_out = nh * hd * (2 if cfg.attn_output_gate else 1)
+        lp.update(wq=w((h, q_out)), wk=w((h, kvh * hd)), wv=w((h, kvh * hd)),
+                  wo=w((nh * hd, h), out_std))
+        if cfg.qk_norm:
+            lp["q_norm_w"] = gain((P, hd), jnp.float32)
+            lp["k_norm_w"] = gain((P, hd), jnp.float32)
+    else:
+        hk, hv, dk, dv, ch = gdn_dims(cfg)
+        lp.update(
+            w_qkvz=w((h, ch + hv * dv)), w_ba=w((h, 2 * hv)),
+            conv_w=w((cfg.linear_conv_kernel, ch), 0.5),
+            # A = U(0, 16), dt_bias = 1: the source's modelling code
+            A_log=jnp.log(jax.random.uniform(next(ks), (P, hv), jnp.float32,
+                                             1e-3, 16.0)),
+            dt_bias=jnp.ones((P, hv), jnp.float32),
+            gdn_norm_w=jnp.ones((P, dv), jnp.float32),
+            w_gdn_out=w((hv * dv, h), out_std))
+    n_held = cfg.moe_held_experts[1] if cfg.moe_held_experts \
+        else cfg.moe_num_experts
+    m = cfg.moe_intermediate_size or cfg.intermediate_size
+    lp.update(router_wg=w((h, cfg.moe_num_experts), 1.0 / math.sqrt(h)),
+              w_in=w((n_held, h, m)), w_gate=w((n_held, h, m)),
+              w_out=w((n_held, m, h), out_std))
+    ms = cfg.moe_shared_intermediate_size
+    if ms:
+        lp.update(shared_w_in=w((h, ms)), shared_w_gate=w((h, ms)),
+                  shared_w_out=w((ms, h), out_std),
+                  shared_gate_w=w((h, 1), 1.0 / math.sqrt(h)))
+    return lp
+
+
+def slot_specs(cfg, kind: str):
+    """Logical sharding axes of ``init_slot``'s tree."""
+    lp = {"attn_norm_w": spec("layers", "embed"),
+          "mlp_norm_w": spec("layers", "embed")}
+    if kind == "full":
+        lp.update(wq=spec("layers", "embed", "heads"),
+                  wk=spec("layers", "embed", "kv_heads"),
+                  wv=spec("layers", "embed", "kv_heads"),
+                  wo=spec("layers", "heads", "embed"))
+        if cfg.qk_norm:
+            lp["q_norm_w"] = spec("layers", None)
+            lp["k_norm_w"] = spec("layers", None)
+    else:
+        lp.update(w_qkvz=spec("layers", "embed", None),
+                  w_ba=spec("layers", "embed", None),
+                  conv_w=spec("layers", None, None),
+                  A_log=spec("layers", None), dt_bias=spec("layers", None),
+                  gdn_norm_w=spec("layers", None),
+                  w_gdn_out=spec("layers", None, "embed"))
+    lp.update(router_wg=spec("layers", "embed", None),
+              w_in=spec("layers", "expert", "embed", "mlp"),
+              w_gate=spec("layers", "expert", "embed", "mlp"),
+              w_out=spec("layers", "expert", "mlp", "embed"))
+    if cfg.moe_shared_intermediate_size:
+        lp.update(shared_w_in=spec("layers", "embed", "mlp"),
+                  shared_w_gate=spec("layers", "embed", "mlp"),
+                  shared_w_out=spec("layers", "mlp", "embed"),
+                  shared_gate_w=spec("layers", "embed", None))
+    return lp
+
+
+# ----------------------------------------------------------------- mixers
+
+def full_qkv(cfg, h1, lp, rope):
+    """The gated attention layer's projections on its normed input
+    [B, T, H]: (q, k, v, gate) with q/k normed per head and rotated
+    (``rope``: q or k [B, T, heads, D] -> the same, rotated)."""
+    from .transformer import _linear
+
+    B, T, _ = h1.shape
+    nh, kvh, hd, dt = cfg.num_heads, cfg.kv_heads, cfg.head_dim, cfg.dtype
+    q = _linear(h1, lp["wq"], None, dt)
+    gate = None
+    if cfg.attn_output_gate:
+        q, gate = jnp.split(q.reshape(B, T, nh, 2 * hd), 2, axis=-1)
+    q = q.reshape(B, T, nh, hd)
+    k = _linear(h1, lp["wk"], None, dt).reshape(B, T, kvh, hd)
+    v = _linear(h1, lp["wv"], None, dt).reshape(B, T, kvh, hd)
+    if cfg.qk_norm:
+        q = block_norm(cfg, q, lp["q_norm_w"])
+        k = block_norm(cfg, k, lp["k_norm_w"])
+    return rope(q), rope(k), v, gate
+
+
+def full_out(cfg, attn, gate, lp):
+    """[B, T, heads, D] attention output -> the layer's output: under the
+    sigmoid of its gate, through ``wo``."""
+    from .transformer import _linear
+
+    B, T = attn.shape[:2]
+    if gate is not None:
+        attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)
+                                     ).astype(attn.dtype)
+    return _linear(attn.reshape(B, T, -1), lp["wo"], None, cfg.dtype)
+
+
+def gdn_mixer(cfg, h1, lp, tail, state, n_tokens):
+    """The Gated DeltaNet layer on its normed input [B, T, H], resumed
+    from ``tail`` [B, K-1, CH] and ``state`` [B, HV, DK, DV] (float32).
+    Positions at or beyond a row's ``n_tokens`` change neither. Returns
+    (y [B, T, H], new tail, new state)."""
+    from .transformer import _linear
+
+    B, T, _ = h1.shape
+    hk, hv, dk, dv, ch = gdn_dims(cfg)
+    dt, f32 = cfg.dtype, jnp.float32
+    with jax.named_scope("gdn_proj"):
+        qkvz = _linear(h1, lp["w_qkvz"], None, dt)
+        qkv, z = qkvz[..., :ch], qkvz[..., ch:]
+        ba = _linear(h1, lp["w_ba"], None, dt).astype(f32)
+        keep = (jnp.arange(T)[None, :] < n_tokens[:, None])[..., None]
+        beta = jnp.where(keep, jax.nn.sigmoid(ba[..., :hv]), 0.0)
+        g = jnp.where(keep, -jnp.exp(lp["A_log"].astype(f32))
+                      * jax.nn.softplus(ba[..., hv:]
+                                        + lp["dt_bias"].astype(f32)), 0.0)
+    with jax.named_scope("gdn_conv"):
+        qkv, tail = gd.causal_conv(qkv, tail, lp["conv_w"], n_tokens)
+        qkv = jax.nn.silu(qkv)
+        q = qkv[..., :hk * dk].reshape(B, T, hk, dk).astype(f32)
+        k = qkv[..., hk * dk:2 * hk * dk].reshape(B, T, hk, dk).astype(f32)
+        v = qkv[..., 2 * hk * dk:].reshape(B, T, hv, dv)
+        l2 = lambda x: x * lax.rsqrt(                           # noqa: E731
+            jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+        q, k = l2(q) * dk ** -0.5, l2(k)
+    with jax.named_scope("gdn_scan"):
+        if T == 1:
+            o, state = gd.gated_delta_step(q[:, 0], k[:, 0], v[:, 0],
+                                           g[:, 0], beta[:, 0], state)
+            o = o[:, None]
+        else:
+            o, state = gd.gated_delta_chunked(q, k, v, g, beta, state)
+    with jax.named_scope("gdn_out"):
+        o = rms(o, lp["gdn_norm_w"], cfg.norm_eps, False)
+        o = o * jax.nn.silu(z.reshape(B, T, hv, dv).astype(f32))
+        y = _linear(o.astype(dt).reshape(B, T, hv * dv), lp["w_gdn_out"],
+                    None, dt)
+    return y, tail, state
+
+
+# ----------------------------------------------------------------- period
+
+def run_period(cfg, x, slots, full_mixer, linear_mixer, valid=None,
+               max_rows=None, transform=None):
+    """One period of layers on x [B, T, H]: each position's norm, its
+    mixer, the sparse FFN and the residual adds. ``slots``: the period's
+    weights, one tree a position. ``full_mixer(h1, lp, i)`` /
+    ``linear_mixer(h1, lp, i)`` -> the mixer's output for the i-th layer
+    of its kind in the period: where the cache lives is the caller's
+    (training keeps none, serving a paged pool and state slots).
+    Returns (x, summed aux loss)."""
+    scope = jax.named_scope
+    aux = jnp.zeros((), jnp.float32)
+    seen = {kind: 0 for kind in KINDS}
+    for kind, lp in zip(cfg.layer_pattern, slots):
+        if transform is not None:
+            lp = transform(lp)
+        with scope("attn_norm"):
+            h1 = block_norm(cfg, x, lp["attn_norm_w"])
+        mixer = full_mixer if kind == "full" else linear_mixer
+        y = mixer(h1, lp, seen[kind])
+        seen[kind] += 1
+        with scope("mlp"):      # norm, FFN and the residual adds
+            x = x + y
+            h2 = block_norm(cfg, x, lp["mlp_norm_w"])
+            f, a = moe_ffn(cfg, h2, lp, valid=valid, max_rows=max_rows)
+            x = x + f
+        aux = aux + a
+    return x, aux
+
+
+# -------------------------------------------------------------------- FFN
+
+def moe_ffn(cfg, h2, lp, valid=None, max_rows=None):
+    """The sparse FFN on its normed input [B, T, H]: the held experts'
+    part of the top-k sum plus the shared expert. ``valid`` [B, T]:
+    padding reaches no expert; ``max_rows`` bounds the valid rows.
+    Returns (y, aux_loss)."""
+    from ..moe.grouped import dropless_moe_mlp
+    from .transformer import _linear
+
+    B, T, H = h2.shape
+    dt = cfg.dtype
+    rows = h2.reshape(B * T, H)
+    flat_valid = None if valid is None else valid.reshape(B * T)
+    with jax.named_scope("router"):
+        logits = rows.astype(jnp.float32) \
+            @ lp["router_wg"].astype(jnp.float32)
+    with jax.named_scope("experts"):
+        y, l_aux = dropless_moe_mlp(
+            rows, logits, lp["w_in"], lp["w_out"], lp["w_gate"],
+            activation="silu", dtype=dt, top_k=cfg.moe_top_k,
+            renormalize=cfg.moe_norm_topk, held=cfg.moe_held_experts,
+            valid=flat_valid, max_rows=max_rows)
+    if cfg.moe_shared_intermediate_size:
+        with jax.named_scope("shared_expert"):
+            s = jax.nn.silu(_linear(rows, lp["shared_w_gate"], None, dt)) \
+                * _linear(rows, lp["shared_w_in"], None, dt)
+            s = _linear(s, lp["shared_w_out"], None, dt)
+            gate = jax.nn.sigmoid(_linear(rows, lp["shared_gate_w"], None,
+                                          dt).astype(jnp.float32))
+            y = y + (s * gate.astype(dt))
+    return y.reshape(B, T, H), l_aux

@@ -102,10 +102,73 @@ class TransformerConfig:
     moe_min_capacity: int = 4
     moe_aux_loss_coef: float = 0.01
     moe_dropless: bool = False   # ragged_dot grouped GEMM (moe/grouped.py)
+    # Hybrid blocks (models/hybrid.py): layers of more than one kind.
+    # ``layer_pattern`` is one period of mixer kinds ("full" | "linear"),
+    # repeated num_layers / len(pattern) times and scanned one period an
+    # iteration; None is the uniform attention block above. The fields
+    # below are read by the hybrid block only.
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    head_size: Optional[int] = None      # stated head size; None → hidden/heads
+    attn_output_gate: bool = False       # wq twice as wide: [q | gate] a head
+    qk_norm: bool = False                # RMSNorm over each head of q and k
+    norm_zero_centered: bool = False     # RMSNorm gain is 1 + w
+    linear_num_key_heads: int = 0        # Gated DeltaNet layer sizes
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    moe_norm_topk: bool = False          # top-k weights divided by their sum
+    moe_held_experts: Optional[Tuple[int, int]] = None  # (lo, n): the share
+    #   of the moe_num_experts routed over whose weights this model holds
+    moe_intermediate_size: Optional[int] = None   # None → intermediate_size
+    moe_shared_intermediate_size: int = 0         # shared expert; 0 = none
+
+    def __post_init__(self):
+        # a configuration read from JSON brings lists
+        for name in ("layer_pattern", "moe_held_experts"):
+            value = getattr(self, name)
+            if isinstance(value, list):
+                object.__setattr__(self, name, tuple(value))
+        if self.layer_pattern is not None:
+            from .hybrid import KINDS
+
+            pattern = self.layer_pattern
+            if not pattern or any(k not in KINDS for k in pattern) \
+                    or self.num_layers % len(pattern):
+                raise ValueError(
+                    f"layer_pattern {pattern!r}: a period of {KINDS} that "
+                    f"divides num_layers ({self.num_layers})")
+            if self.sliding_window or self.moe_num_experts <= 0 \
+                    or self.norm != "rmsnorm" or self.position != "rope":
+                raise ValueError(
+                    "a hybrid block is RMSNorm, rotary, full-context "
+                    "attention and a sparse FFN (moe_num_experts > 0)")
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.head_size or self.hidden_size // self.num_heads
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.layer_pattern is not None
+
+    @property
+    def num_periods(self) -> int:
+        return self.num_layers // len(self.layer_pattern)
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers that keep per-token K/V (all of them, unless hybrid)."""
+        if self.layer_pattern is None:
+            return self.num_layers
+        return self.num_periods * self.layer_pattern.count("full")
+
+    @property
+    def num_linear_layers(self) -> int:
+        """Layers that keep a recurrent state instead."""
+        if self.layer_pattern is None:
+            return 0
+        return self.num_periods * self.layer_pattern.count("linear")
 
     @property
     def kv_heads(self) -> int:
@@ -601,6 +664,8 @@ class CausalLM:
     # -- init ---------------------------------------------------------------
     def init(self, rng) -> Dict[str, Any]:
         cfg = self.cfg
+        if cfg.is_hybrid:
+            return self._init_hybrid(rng)
         h, m, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
         hd, nh, kvh, L = cfg.head_dim, cfg.num_heads, cfg.kv_heads, cfg.num_layers
         keys = jax.random.split(rng, 11)
@@ -670,11 +735,44 @@ class CausalLM:
                 params["lm_head"]["b"] = jnp.zeros((v,), jnp.float32)
         return params
 
+    def _init_hybrid(self, rng) -> Dict[str, Any]:
+        """``layers`` holds one tree per position of the period
+        (``slot0`` …), each stacked over the periods: the scan takes one
+        period an iteration."""
+        from . import hybrid
+
+        cfg = self.cfg
+        h, v = cfg.hidden_size, cfg.vocab_size
+        keys = jax.random.split(rng, len(cfg.layer_pattern) + 2)
+        gain = jnp.zeros if cfg.norm_zero_centered else jnp.ones
+        params = {
+            "embed": {"wte": (0.02 * jax.random.normal(keys[-1], (v, h))
+                              ).astype(jnp.float32)},
+            "layers": {f"slot{i}": hybrid.init_slot(cfg, kind, keys[i],
+                                                    cfg.num_periods)
+                       for i, kind in enumerate(cfg.layer_pattern)},
+            "final_norm": {"w": gain((h,), jnp.float32)},
+        }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = {"w": (0.02 * jax.random.normal(
+                keys[-2], (h, v))).astype(jnp.float32)}
+        return params
+
     # -- sharding specs -----------------------------------------------------
     def param_specs(self) -> Dict[str, Any]:
         """Logical-axis spec tree mirroring ``init``'s param tree
         (consumed by parallel/sharding.py)."""
         cfg = self.cfg
+        if cfg.is_hybrid:
+            from . import hybrid
+
+            specs = {"embed": {"wte": spec("vocab", "embed")},
+                     "layers": {f"slot{i}": hybrid.slot_specs(cfg, kind)
+                                for i, kind in enumerate(cfg.layer_pattern)},
+                     "final_norm": {"w": spec("embed")}}
+            if not cfg.tie_embeddings:
+                specs["lm_head"] = {"w": spec("embed", "vocab")}
+            return specs
         layers = {
             "attn_norm_w": spec("layers", "embed"),
             "wq": spec("layers", "embed", "heads"),
@@ -849,6 +947,8 @@ class CausalLM:
         """tokens [B, T] int32 → logits [B, T, V] (in compute dtype).
         With ``return_aux``, returns (logits, moe_aux_loss)."""
         cfg = self.cfg
+        if cfg.is_hybrid:
+            return self._apply_hybrid(params, tokens, positions, return_aux)
         B, T = tokens.shape
         if self.global_transform is not None:
             # gather the non-stacked weights once per step (ZeRO++ qwZ);
@@ -954,6 +1054,72 @@ class CausalLM:
             return logits, jnp.sum(aux_losses)
         return logits
 
+    def _apply_hybrid(self, params, tokens, positions=None,
+                      return_aux: bool = False):
+        """The forward of a hybrid block (``cfg.layer_pattern``): one
+        ``lax.scan`` over the periods, whose body runs the period's
+        layers in order — compile time is O(1) in depth, whatever the
+        mix. Recurrent layers start from a zero state (no cache here:
+        training and the reference path)."""
+        from . import hybrid
+
+        cfg = self.cfg
+        B, T = tokens.shape
+        scope = jax.named_scope
+        with scope("embed"):
+            x = params["embed"]["wte"][tokens].astype(cfg.dtype)
+            cos, sin = rope_table(cfg.max_seq_len, cfg.rot_dim,
+                                  cfg.rope_theta)
+            cos, sin = ((cos[positions], sin[positions])
+                        if positions is not None else (cos[:T], sin[:T]))
+
+        def rope(t):
+            return apply_rope(t, cos, sin, cfg.rope_interleaved)
+
+        n_tokens = jnp.full((B,), T, jnp.int32)
+        state0 = {name: jnp.zeros((B,) + shape[2:], dt) for name, (shape, dt)
+                  in hybrid.state_shapes(cfg, B).items()} \
+            if cfg.num_linear_layers else None
+
+        def full_mixer(h1, lp, _):
+            with scope("qkv"):
+                q, k, v, gate = hybrid.full_qkv(cfg, h1, lp, rope)
+            with scope("attend"):
+                attn = _attention(q, k, v, cfg, causal=True)
+            with scope("attn_out"):
+                return hybrid.full_out(cfg, attn, gate, lp)
+
+        def linear_mixer(h1, lp, _):
+            with scope("linear_attn"):
+                return hybrid.gdn_mixer(cfg, h1, lp, state0["conv"],
+                                        state0["ssm"], n_tokens)[0]
+
+        def period(x, slots):
+            return hybrid.run_period(cfg, x, slots, full_mixer, linear_mixer,
+                                     transform=self.layer_transform)
+
+        if cfg.remat:
+            period = jax.checkpoint(period)
+        slots = tuple(params["layers"][f"slot{i}"]
+                      for i in range(len(cfg.layer_pattern)))
+        with scope("layers"):
+            x, aux = lax.scan(period, x, slots)
+        with scope("final_norm"):
+            x = hybrid.block_norm(cfg, x, params["final_norm"]["w"])
+        with scope("logits"):
+            logits = self._unembed(params, x)
+        if return_aux:
+            return logits, jnp.sum(aux)
+        return logits
+
+    def _no_contiguous_cache(self):
+        """The v1 cache paths below keep per-token K/V for every layer;
+        a hybrid block is served through inference/v2 only."""
+        if self.cfg.is_hybrid:
+            raise NotImplementedError(
+                "a hybrid block (layer_pattern) has no contiguous-cache "
+                "path: serve it through InferenceEngineV2")
+
     def _scan_layers(self, body_for_window, carry, xs):
         """``lax.scan`` over the stacked layer dim, split by the config's
         window schedule. ``body_for_window(w)`` returns a scan body with
@@ -999,6 +1165,7 @@ class CausalLM:
     # -- KV-cache inference (reference inference v1: model_implementations/
     # transformers/ds_transformer.py decode path) ---------------------------
     def init_cache(self, batch_size: int, max_len: int):
+        self._no_contiguous_cache()
         cfg = self.cfg
         shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, cfg.head_dim)
         return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
@@ -1008,6 +1175,7 @@ class CausalLM:
         hands its K/V to ``write_kv(kc, vc, k, v) -> (kc, vc)``) → final
         norm → logits. The contiguous and paged caches differ only in the
         write."""
+        self._no_contiguous_cache()
         cfg = self.cfg
         B, T = tokens.shape
         x = params["embed"]["wte"][tokens].astype(cfg.dtype)
@@ -1046,6 +1214,7 @@ class CausalLM:
     def decode_step(self, params, cache, tokens, pos):
         """One decode step: tokens [B] at position ``pos`` (scalar int32).
         Returns (logits [B, V], cache)."""
+        self._no_contiguous_cache()
         cfg = self.cfg
         B = tokens.shape[0]
         S = cache["k"].shape[2]
@@ -1083,6 +1252,7 @@ class CausalLM:
         Unlike ``init_cache``'s [B, S, ...] layout, the pool layout feeds
         ``ops/paged_attention.py`` directly — decode never materializes a
         [*, S] mask or attends past each sequence's live length."""
+        self._no_contiguous_cache()
         cfg = self.cfg
         nb = -(-max_len // block_size)
         shape = (cfg.num_layers, batch_size * nb, cfg.kv_heads, block_size,
@@ -1098,6 +1268,7 @@ class CausalLM:
         garbage K/V but are overwritten by decode before any query can
         attend them — the per-seq context mask in the paged kernel keeps
         them dead). Returns (logits [B, T, V], cache)."""
+        self._no_contiguous_cache()
         cfg = self.cfg
         B, T = tokens.shape
         bs = cache["k"].shape[3]
@@ -1123,6 +1294,7 @@ class CausalLM:
         gather fallback off-TPU) — per-token cost scales with each
         sequence's live context, not the cache capacity. Returns
         (logits [B, V], cache)."""
+        self._no_contiguous_cache()
         cfg = self.cfg
         B = tokens.shape[0]
         bs = cache["k"].shape[3]

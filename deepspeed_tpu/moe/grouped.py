@@ -11,8 +11,10 @@ over the true group sizes: no padding FLOPs, no dropped tokens.
 
 Two formulations:
 
-- ``dropless_moe_mlp`` — single-shard (no expert mesh axis): one sort +
-  three ``ragged_dot`` calls.
+- ``dropless_moe_mlp`` — single-shard (no expert mesh axis): top-k over
+  all experts, one sort of the (row, choice) pairs + three ``ragged_dot``
+  calls over the experts this layer holds (a share ``[lo, lo + n)`` of
+  them, or all).
 - ``dropless_moe_mlp_ep`` — expert-parallel (round 5): a *partial-manual*
   ``shard_map`` over just the ``expert`` axis (every other mesh axis stays
   under GSPMD). Activations are replicated over the expert axis, so each
@@ -45,52 +47,131 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
                      w_in: jax.Array, w_out: jax.Array,
                      w_gate: Optional[jax.Array] = None,
                      activation: str = "gelu",
-                     dtype=None) -> Tuple[jax.Array, jax.Array]:
-    """Top-1 dropless MoE FFN.
+                     dtype=None, *, top_k: int = 1,
+                     renormalize: bool = False,
+                     held: Optional[Tuple[int, int]] = None,
+                     valid: Optional[jax.Array] = None,
+                     max_rows: Optional[int] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Top-k dropless MoE FFN over the experts this layer holds.
 
-    tokens [N, H]; router_logits [N, E] (fp32); w_in [E, H, M];
-    w_out [E, M, H]; w_gate [E, H, M] for SwiGLU. Returns
-    (out [N, H], aux_loss) — aux is the GShard load-balancing loss
-    (E · Σ_e fraction_tokens_e · fraction_probs_e), same as top1gating.
+    tokens [N, H]; router_logits [N, E] (fp32) over ALL E experts;
+    w_in [n, H, M]; w_out [n, M, H]; w_gate [n, H, M] for SwiGLU, where
+    the n experts held are ``[lo, lo + n)`` (``held = (lo, n)``; None:
+    all of them). A token's ``top_k`` experts are chosen over all E and
+    weighted by their softmax probabilities (``renormalize``: divided by
+    their sum); only the pairs whose expert is held are computed here —
+    what the others would add is another holder's part of the sum.
+    ``valid`` [N] bool: rows that are padding; they reach no expert.
+    ``max_rows``: a bound the caller knows on the number of valid rows —
+    the grouped GEMMs then run over that many rows and not over N.
+
+    Returns (out [N, H], aux_loss) — aux is the GShard load-balancing
+    loss (E · Σ_e fraction_tokens_e · fraction_probs_e), same as
+    top1gating at ``top_k`` 1.
     """
     N, H = tokens.shape
     E = router_logits.shape[-1]
+    n_held = w_in.shape[0]
+    lo = 0 if held is None else int(held[0])
+    if held is not None and int(held[1]) != n_held:
+        raise ValueError(f"held {held} but {n_held} experts' weights")
     dtype = dtype or tokens.dtype
     probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    expert = jnp.argmax(router_logits, axis=-1)          # [N]
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=-1)[:, 0]
+    gate, expert = lax.top_k(probs, top_k)                # [N, k]
+    if renormalize:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
 
     # load-balance aux (reference sharded_moe.py top1gating l_aux)
     me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(jax.nn.one_hot(expert, E, dtype=jnp.float32), axis=0)
+    ce = jnp.mean(jnp.sum(jax.nn.one_hot(expert, E, dtype=jnp.float32),
+                          axis=1), axis=0) / top_k
     l_aux = jnp.sum(me * ce) * E
 
-    # sort tokens by expert; group sizes are the per-expert counts
-    order = jnp.argsort(expert)                          # stable
-    sorted_tokens = tokens[order].astype(dtype)
-    group_sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1)
+    rows = jnp.arange(N)
+    if max_rows is not None and max_rows < N:
+        # the valid rows first (stable), cut to the bound
+        rows = jnp.argsort(~valid)[:max_rows]
+        tokens, gate, expert = tokens[rows], gate[rows], expert[rows]
+        valid = valid[rows]
+    R = tokens.shape[0]
+    routed = (expert >= lo) & (expert < lo + n_held)      # [R, k]
+    if valid is not None:
+        routed &= valid[:, None]
+    # sort the (row, choice) pairs by held expert; pairs routed nowhere
+    # (padding, an absent expert) sort behind the last group, where
+    # ragged_dot computes nothing
+    key = jnp.where(routed, expert - lo, n_held).reshape(-1)
+    order = jnp.argsort(key)                              # stable
+    src = order // top_k                                  # pair -> row
+    group_sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:-1]
+    out_sorted = _ragged_expert_ffn(tokens[src].astype(dtype), group_sizes,
+                                    w_in, w_out, w_gate, activation, dtype,
+                                    matmul=grouped_matmul)
+    # back to (row, choice) order by a gather (the inverse of the sort),
+    # then the weighted sum over a row's choices; what ran behind the
+    # last group (pairs routed nowhere) is dropped, whatever it holds
+    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(R * top_k))
+    pairs = out_sorted[inverse].reshape(R, top_k, H).astype(jnp.float32)
+    out = jnp.sum(jnp.where(routed[..., None], pairs * gate[..., None], 0.0),
+                  axis=1)
+    if R < N:
+        out = jnp.zeros((N, H), jnp.float32).at[rows].set(out)
+    return out.astype(dtype), l_aux
 
-    out_sorted = _ragged_expert_ffn(sorted_tokens, group_sizes, w_in,
-                                    w_out, w_gate, activation, dtype)
 
-    # unsort + gate scale
-    out = jnp.zeros_like(out_sorted).at[order].set(out_sorted)
-    return out * gate[:, None].astype(dtype), l_aux
+#: rows of one grid step of the grouped-matmul kernel
+GMM_ROWS = 128
 
 
-def _ragged_expert_ffn(st, gs, w_in, w_out, w_gate, activation, dtype):
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs[rows of group i] @ rhs[i]`` for rows sorted by group; rows
+    behind the last group are not computed (what they hold is undefined).
+    lhs [m, k]; rhs [g, k, n]; group_sizes [g].
+
+    On a TPU this is the Pallas grouped matmul that JAX ships
+    (``jax.experimental.pallas.ops.tpu.megablox``; its custom call is
+    named ``gmm`` and keeps the caller's scope in its ``op_name``) and
+    not ``lax.ragged_dot``: XLA:TPU lowers that to custom calls of its
+    own (``ragged-dot-*``) that carry no ``op_name``, so a fifth to a half
+    of a step's device time would sit under no scope of the program. One
+    tile of 128 rows a step and a group's whole ``[k, n]`` weight (up to
+    2048 a side): a group of a few rows costs one pass over its weights,
+    and groups of no rows cost nothing. Off the TPU (tests):
+    ``lax.ragged_dot``."""
+    from ..ops.pallas_utils import on_tpu
+
+    if not on_tpu():
+        return lax.ragged_dot(lhs, rhs, group_sizes)
+    import importlib
+
+    gmm = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm").gmm
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    pad = -m % GMM_ROWS
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = gmm(lhs, rhs, group_sizes.astype(jnp.int32),
+              preferred_element_type=lhs.dtype,
+              tiling=(GMM_ROWS, min(k, 2048), min(n, 2048)))
+    return out[:m] if pad else out
+
+
+def _ragged_expert_ffn(st, gs, w_in, w_out, w_gate, activation, dtype,
+                       matmul=lax.ragged_dot):
     """Grouped FFN over expert-sorted tokens ``st`` with group sizes
     ``gs`` (one trailing dummy group allowed when the weights carry an
     extra zero expert)."""
-    h = lax.ragged_dot(st, w_in.astype(dtype), gs)
+    h = matmul(st, w_in.astype(dtype), gs)
     if w_gate is not None and activation == "silu":
-        g = lax.ragged_dot(st, w_gate.astype(dtype), gs)
+        g = matmul(st, w_gate.astype(dtype), gs)
         h = jax.nn.silu(g) * h
     elif activation == "relu":
         h = jax.nn.relu(h)
     else:
         h = jax.nn.gelu(h, approximate=activation != "gelu_exact")
-    return lax.ragged_dot(h, w_out.astype(dtype), gs)
+    return matmul(h, w_out.astype(dtype), gs)
 
 
 def dropless_moe_mlp_ep(tokens: jax.Array, router_logits: jax.Array,

@@ -48,6 +48,9 @@ from .pallas_utils import pl, pltpu
 
 NEG_INF = -1e30
 LANES = 128
+#: the most query rows (G·C) one grid step's VMEM block may hold; every
+#: shape the accepted configurations run (G·C <= 1024) is under it
+MAX_QUERY_ROWS = 2048
 
 # Test hook: force the Pallas path in interpreter mode off-TPU (same pattern
 # as ops/flash_attention.py).
@@ -351,11 +354,31 @@ def paged_attention(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
     Rows beyond n_tokens are garbage (masked out downstream).
     """
     if _pallas_ok(q, k_pool):
-        return _paged_pallas(q, k_pool, v_pool, block_tables, start_pos,
-                             n_tokens, alibi_slopes=alibi_slopes,
-                             window=window, sm_scale=sm_scale,
-                             k_scale=k_scale, v_scale=v_scale, layer=layer,
-                             interpret=_use_interpret())
+        def kernel(q, start_pos, n_tokens):
+            return _paged_pallas(
+                q, k_pool, v_pool, block_tables, start_pos, n_tokens,
+                alibi_slopes=alibi_slopes, window=window, sm_scale=sm_scale,
+                k_scale=k_scale, v_scale=v_scale, layer=layer,
+                interpret=_use_interpret())
+
+        N, C, H, _ = q.shape
+        tile = max(1, MAX_QUERY_ROWS // (H // k_pool.shape[-3]))
+        if C <= tile:
+            return kernel(q, start_pos, n_tokens)
+        # A query group of G·C rows is one VMEM block (with its float32
+        # accumulator and softmax statistics): a long chunk of a wide
+        # group is cut along C and each piece walks the table on its own.
+        # The pool already holds the whole chunk's K/V and the mask goes
+        # by position, so a piece is the same call at a later start. A
+        # piece past a row's valid tokens is given a context of 0: every
+        # block of its walk is dead.
+        outs = []
+        for c0 in range(0, C, tile):
+            n_sub = jnp.clip(n_tokens - c0, 0, tile)
+            outs.append(kernel(
+                q[:, c0:c0 + tile],
+                jnp.where(n_sub > 0, start_pos + c0, 0), n_sub))
+        return jnp.concatenate(outs, axis=1)
     return paged_attention_xla(q, k_pool, v_pool, block_tables, start_pos,
                                n_tokens, alibi_slopes=alibi_slopes,
                                window=window, sm_scale=sm_scale,

@@ -387,6 +387,10 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # delta-published per Replica): pad ratio over an interval =
               # delta positions_computed / delta tokens_valid
               "forwards", "positions_computed", "tokens_valid",
+              # a hybrid model's sparse FFNs: (token, choice) pairs routed
+              # and, of those, the pairs whose expert this replica holds
+              # (the expectation under even routing: engine._count_routing)
+              "moe_rows_routed", "moe_rows_held",
               # fault tolerance (docs/SERVING.md "Fault tolerance"):
               # failover = a dead replica's request re-enqueued (stream
               # resumed elsewhere); restarts = supervisor replaced a DEAD

@@ -584,7 +584,8 @@ class Replica:
     _TIER_COUNTERS = (("spilled", "kv_tier_blocks_spilled"),
                       ("restored", "kv_tier_blocks_restored"),
                       ("dropped", "kv_tier_blocks_dropped"))
-    _PUT_COUNTERS = ("forwards", "positions_computed", "tokens_valid")
+    _PUT_COUNTERS = ("forwards", "positions_computed", "tokens_valid",
+                     "moe_rows_routed", "moe_rows_held")
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),
                          ("resumed", "sequences_resumed"))
 
@@ -609,7 +610,7 @@ class Replica:
         totals = getattr(self.engine, "put_totals", None)
         if totals is not None:
             for name in self._PUT_COUNTERS:
-                delta = totals[name] - self._put_last.get(name, 0)
+                delta = totals.get(name, 0) - self._put_last.get(name, 0)
                 if delta:
                     self.metrics.counter(name).inc(delta)
             self._put_last = dict(totals)

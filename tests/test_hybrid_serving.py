@@ -1,0 +1,339 @@
+"""A hybrid model (Gated DeltaNet layers beside gated attention, a sparse
+FFN with a held share of the experts) through ``InferenceEngineV2``: the
+recurrent state lives in slots beside the paged K/V pool. A tiny
+configuration of the published shape — two periods of linear x 3 + full,
+a head size that is not hidden/heads, 8 experts top-2 of which 4 are
+held, a shared expert — in float32 on the CPU."""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.v2.engine_v2 import (
+    InferenceEngineV2, RaggedInferenceEngineConfig)
+from deepspeed_tpu.inference.v2.scheduling_utils import SchedulingResult
+from deepspeed_tpu.models.hybrid import RecurrentStateUnsupported
+from deepspeed_tpu.models.transformer import CausalLM, TransformerConfig
+
+CFG = TransformerConfig(
+    vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=8,
+    num_heads=4, num_kv_heads=2, head_size=16, max_seq_len=256,
+    norm="rmsnorm", norm_eps=1e-6, norm_zero_centered=True,
+    activation="silu", position="rope", rope_pct=0.25, rope_theta=1e7,
+    tie_embeddings=False, dtype=jnp.float32,
+    layer_pattern=("linear", "linear", "linear", "full"),
+    attn_output_gate=True, qk_norm=True,
+    linear_num_key_heads=2, linear_num_value_heads=4,
+    linear_key_head_dim=8, linear_value_head_dim=8, linear_conv_kernel=4,
+    moe_num_experts=8, moe_top_k=2, moe_dropless=True, moe_norm_topk=True,
+    moe_held_experts=(2, 4), moe_intermediate_size=16,
+    moe_shared_intermediate_size=16)
+SIZING = dict(max_ragged_batch_size=64, max_ragged_sequence_count=4,
+              max_chunk_tokens=16, kv_blocks=64, kv_block_size=8)
+
+
+@pytest.fixture(scope="module")
+def model_and_params():
+    model = CausalLM(CFG)
+    params = model.init(jax.random.PRNGKey(0))
+    # gains off their initial values, so a dropped gain would show
+    flat, tree = jax.tree_util.tree_flatten_with_path(params)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(flat))
+    flat = [leaf + 0.05 * jax.random.normal(k, leaf.shape)
+            if "norm" in jax.tree_util.keystr(path) else leaf
+            for (path, leaf), k in zip(flat, keys)]
+    return model, jax.tree_util.tree_unflatten(tree, flat)
+
+
+def engine(model_and_params, **sizing):
+    model, params = model_and_params
+    return InferenceEngineV2(model, params=params,
+                             config=RaggedInferenceEngineConfig(
+                                 **dict(SIZING, **sizing)))
+
+
+def prompt(seed, n):
+    return np.random.default_rng(seed).integers(0, 128, size=n).tolist()
+
+
+def feed(eng, uid, tokens, chunk=16):
+    """Prefill in chunks; the logits after the last token."""
+    for at in range(0, len(tokens), chunk):
+        out = eng.put([uid], [tokens[at:at + chunk]])
+    return np.asarray(out[0])
+
+
+def decode(eng, uid, tokens, steps, chunk=16):
+    """Prefill then greedy decode: (logits of every step, all tokens)."""
+    got, tokens = [feed(eng, uid, tokens, chunk)], list(tokens)
+    for _ in range(steps):
+        tokens.append(int(np.argmax(got[-1])))
+        got.append(np.asarray(eng.put([uid], [[tokens[-1]]])[0]))
+    return got, tokens
+
+
+def reference_block():
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark import manifest as mf
+
+    return mf.find_module(mf.HERE, "blocks", "qwen3_next")
+
+
+@pytest.mark.parametrize("n,chunk", [(45, 16), (16, 16), (37, 8), (5, 16),
+                                     (70, 16)])
+def test_chunks_then_decode_agree_with_apply_and_the_reference(
+        model_and_params, n, chunk):
+    model, params = model_and_params
+    eng = engine(model_and_params)
+    got, tokens = decode(eng, 7, prompt(n, n), steps=3, chunk=chunk)
+    ids = jnp.asarray(tokens)
+    want = np.asarray(model.apply(params, ids[None]))[0]
+    arch = {f.name: getattr(CFG, f.name) for f in dataclasses.fields(CFG)}
+    ref = np.asarray(reference_block().logits(params, ids, arch, q_block=32))
+    for step, g in enumerate(got):
+        for other in (want, ref):
+            w = other[n - 1 + step]
+            assert np.abs(g - w).max() < 1e-4 * np.abs(w).max()
+
+
+def test_a_sequence_does_not_depend_on_its_neighbours_or_on_padding(
+        model_and_params):
+    """The same sequence alone ([1, C] buckets) and beside three others of
+    other lengths ([4, C] buckets, its rows padded): the same logits, so
+    no state leaks between slots and padded positions change none."""
+    mine = prompt(1, 29)
+    alone, _ = decode(engine(model_and_params), 1, mine, steps=2)
+    eng = engine(model_and_params)
+    others = {2: prompt(2, 40), 3: prompt(3, 7), 4: prompt(4, 16)}
+    fed = {u: 0 for u in (1, 2, 3, 4)}
+    everyone = {1: mine, **others}
+    last = None
+    while any(fed[u] < len(everyone[u]) for u in everyone):
+        uids = [u for u in everyone if fed[u] < len(everyone[u])]
+        chunks = [everyone[u][fed[u]:fed[u] + 16] for u in uids]
+        out = eng.put(uids, chunks)
+        for i, u in enumerate(uids):
+            fed[u] += len(chunks[i])
+            if u == 1 and fed[1] == len(mine):
+                last = np.asarray(out[i])
+    together = [last]
+    toks = list(mine)
+    for _ in range(2):
+        toks.append(int(np.argmax(together[-1])))
+        out = eng.put([4, 1, 2], [[5], [toks[-1]], [9]])
+        together.append(np.asarray(out[1]))
+    for a, b in zip(alone, together):
+        np.testing.assert_allclose(b, a, atol=2e-6)
+
+
+def test_flush_returns_slot_and_blocks_and_a_reused_slot_starts_from_zero(
+        model_and_params):
+    eng = engine(model_and_params)
+    sm = eng.state_manager
+    first = feed(eng, 10, prompt(10, 33))
+    occ = eng.occupancy()
+    assert occ["state_slots"] == 4 and occ["state_slots_used"] == 1
+    assert occ["state_bytes"] == sum(int(x.nbytes)
+                                     for x in sm.state_cache.values())
+    assert eng.last_put["state_slots_used"] == 1
+    slot = sm.get_sequence(10).state_slot
+    eng.flush(10)
+    assert eng.occupancy()["state_slots_used"] == 0
+    assert sm.available_blocks == SIZING["kv_blocks"]
+    # the slot is not cleared on the device: the next sequence to take it
+    # starts from zero all the same
+    assert float(jnp.abs(sm.state_cache["ssm"][:, slot]).max()) > 0
+    again = feed(eng, 11, prompt(10, 33))
+    assert sm.get_sequence(11).state_slot == slot
+    np.testing.assert_allclose(again, first, atol=1e-6)
+    eng.flush(11)
+    assert sm.free_state_slots == 4
+
+
+def test_the_pool_holds_the_attention_layers_only(model_and_params):
+    eng = engine(model_and_params)
+    sm = eng.state_manager
+    assert CFG.num_attn_layers == 2 and CFG.num_linear_layers == 6
+    assert sm.kv_cache["k"].shape == (2, 64, 2, 8, 16)
+    assert sm.state_cache["ssm"].shape == (6, 5, 4, 8, 8)
+    assert sm.state_cache["ssm"].dtype == jnp.float32
+    assert sm.state_cache["conv"].shape == (6, 5, 3, 2 * 2 * 8 + 4 * 8)
+    # 2 (k, v) x 2 layers x 2 heads x 8 slots x 16 x 4 B
+    assert eng.occupancy()["bytes_per_block"] == 2 * 2 * 2 * 8 * 16 * 4
+    assert set(sm.forward_cache) == {"k", "v", "ssm", "conv"}
+
+
+def test_no_slot_no_admission(model_and_params):
+    eng = engine(model_and_params)
+    for uid in range(4):
+        eng.put([uid], [prompt(uid, 3)])
+    assert eng.can_schedule([0, 1], [1, 1]) == SchedulingResult.Success
+    assert eng.can_schedule([9], [4]) == SchedulingResult.KVCacheLimitExceeded
+    eng.flush(2)
+    assert eng.can_schedule([9], [4]) == SchedulingResult.Success
+
+
+@pytest.mark.parametrize("chunked", [0, 2])
+def test_export_then_import_reproduces_the_next_logits(model_and_params,
+                                                       chunked):
+    src = engine(model_and_params)
+    tokens = prompt(20, 41)
+    got, all_tokens = decode(src, 5, tokens, steps=2)
+    payload = src.export_sequence(5, chunk_blocks=chunked)
+    assert set(payload["state"]) == {"ssm", "conv"}
+    nxt = int(np.argmax(got[-1]))
+    want = np.asarray(src.put([5], [[nxt]])[0])
+    dst = engine(model_and_params)
+    dst.put([77], [prompt(3, 9)])           # its slot 0 is taken
+    dst.import_sequence(6, payload, all_tokens)
+    np.testing.assert_allclose(np.asarray(dst.put([6], [[nxt]])[0]), want,
+                               atol=1e-6)
+    # a payload without the state, or of another model, is refused whole
+    bare = {k: v for k, v in payload.items() if k != "state"}
+    with pytest.raises(ValueError, match="recurrent-state"):
+        dst.import_sequence(8, bare, all_tokens)
+    assert dst.state_manager.get_sequence(8) is None
+    assert dst.occupancy()["state_slots_used"] == 2
+
+
+def test_the_preemption_stash_carries_the_state(model_and_params):
+    eng = engine(model_and_params)
+    tokens = prompt(30, 26)
+    got, all_tokens = decode(eng, 5, tokens, steps=1)
+    nxt = int(np.argmax(got[-1]))
+    eng.preempt_stash(5, eng.export_sequence(5))
+    eng.flush(5)
+    assert eng.occupancy()["state_slots_used"] == 0
+    feed(eng, 50, prompt(31, 30))           # someone else takes the slot
+    eng.import_sequence(5, eng.preempt_restore_payload(5), all_tokens)
+    resumed = np.asarray(eng.put([5], [[nxt]])[0])
+    fresh = engine(model_and_params)
+    feed(fresh, 1, all_tokens)
+    np.testing.assert_allclose(
+        resumed, np.asarray(fresh.put([1], [[nxt]])[0]), atol=2e-6)
+
+
+def test_features_that_assume_per_token_kv_raise_the_typed_error(
+        model_and_params, devices8):
+    model, params = model_and_params
+    eng = engine(model_and_params)
+    feed(eng, 1, prompt(1, 20))
+    with pytest.raises(RecurrentStateUnsupported, match="trim_sequence"):
+        eng.trim_sequence(1, 2)
+    assert eng.trim_sequence(1, 0) == 0     # nothing to roll back: no-op
+    with pytest.raises(RecurrentStateUnsupported, match="prefix cache"):
+        eng.configure_prefix_cache(True)
+    assert eng.match_prefix(2, prompt(1, 20)) == 0      # off: untouched
+    with pytest.raises(RecurrentStateUnsupported, match="prefix cache"):
+        engine(model_and_params, enable_prefix_cache=True)
+    with pytest.raises(RecurrentStateUnsupported, match="KV tier"):
+        eng.configure_kv_tier(True)
+    with pytest.raises(RecurrentStateUnsupported, match="verif"):
+        eng.put([1], [[3, 4]], verify_width=2)
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(devices8[:2]), ("tensor",))
+    with pytest.raises(RecurrentStateUnsupported, match="TP serving"):
+        InferenceEngineV2(model, params=params, mesh=mesh,
+                          config=RaggedInferenceEngineConfig(**SIZING))
+    assert issubclass(RecurrentStateUnsupported, NotImplementedError)
+
+
+@pytest.mark.parametrize("call", ["init_cache", "prefill", "decode_step",
+                                  "init_paged_cache", "prefill_paged",
+                                  "decode_step_paged"])
+def test_the_contiguous_cache_paths_raise_for_a_hybrid_model(
+        model_and_params, call):
+    model, params = model_and_params
+    tok = jnp.zeros((1, 4), jnp.int32)
+    cache = {"k": jnp.zeros((8, 1, 8, 2, 16)), "v": jnp.zeros((8, 1, 8, 2, 16))}
+    args = {"init_cache": (1, 8), "prefill": (params, tok, cache),
+            "decode_step": (params, cache, tok[:, 0], 0),
+            "init_paged_cache": (1, 8),
+            "prefill_paged": (params, tok, jnp.array([4]), cache, None),
+            "decode_step_paged": (params, cache, None, tok[:, 0],
+                                  jnp.array([0]))}[call]
+    with pytest.raises(NotImplementedError, match="InferenceEngineV2"):
+        getattr(model, call)(*args)
+
+
+def test_the_puts_counters(model_and_params):
+    eng = engine(model_and_params)
+    eng.put([1, 2], [prompt(1, 10), prompt(2, 3)])
+    # 13 valid tokens x top-2 x 8 layers routed; half the experts held
+    assert eng.last_put["moe_rows_routed"] == 13 * 2 * 8
+    assert eng.last_put["moe_rows_held"] == 13 * 8
+    eng.put([1], [[5]])
+    assert eng.put_totals["moe_rows_routed"] == 14 * 2 * 8
+    assert eng.put_totals["moe_rows_held"] == 14 * 8
+    assert eng.last_put["state_slots_used"] == 2
+
+
+def test_a_dense_model_is_given_no_slots(model_and_params):
+    from deepspeed_tpu.models.transformer import TINY_TEST
+
+    model = CausalLM(TINY_TEST)
+    eng = InferenceEngineV2(model, config=RaggedInferenceEngineConfig(
+        **SIZING))
+    eng.put([1], [[1, 2, 3]])
+    assert eng.state_manager.state_cache == {}
+    assert set(eng.state_manager.forward_cache) == {"k", "v"}
+    assert "state_slots_used" not in eng.last_put
+    assert set(eng.put_totals) == {"forwards", "positions_computed",
+                                   "tokens_valid"}
+    occ = eng.occupancy()
+    assert occ["state_slots"] == occ["state_slots_used"] == 0
+    assert eng.state_manager.get_sequence(1).state_slot == -1
+
+
+# ------------------------------------------------- buckets, compiling ahead
+
+def test_a_chunk_is_padded_to_the_delta_rules_tile_and_no_narrower(
+        model_and_params):
+    """The forward's shapes: ``[1, C]`` from the tile (here the chunk cap,
+    which is under it) up, and ``[S, 1]``; a dense model keeps every power
+    of two."""
+    from deepspeed_tpu.inference.v2.ragged import RaggedBatchWrapper
+    from deepspeed_tpu.ops import gated_delta
+
+    eng = engine(model_and_params)
+    assert eng.forward_shapes() == [(1, 1), (1, 16), (2, 1), (4, 1)]
+    eng.put([1], [prompt(0, 3)])
+    assert eng.last_put["bucket_chunk"] == 16
+    eng.put([1], [[5]])
+    assert eng.last_put["bucket_chunk"] == 1
+    wide = RaggedBatchWrapper(32, 1024, 8, min_chunk=gated_delta.TILE)
+    assert wide.buckets() == ([1, 2, 4, 8, 16, 32],
+                              [1, 64, 128, 256, 512, 1024])
+    assert RaggedBatchWrapper(4, 16, 8).buckets() == ([1, 2, 4],
+                                                      [1, 2, 4, 8, 16])
+
+
+def test_compiled_ahead_the_engine_gives_the_same_logits_and_compiles_no_more(
+        model_and_params):
+    """``compile_ahead``: every shape's executable is there when the
+    engine is built, a put runs it (nothing is compiled at first use), and
+    the logits are those of the engine that compiles at first use."""
+    lazy, ahead = engine(model_and_params), engine(model_and_params,
+                                                   compile_ahead=2)
+    p, q = prompt(3, 21), prompt(4, 5)
+    puts = [([1], [p[:16]]), ([1, 2], [p[16:], q]), ([1, 2], [[7], [9]])]
+    for uids, tokens in puts:
+        np.testing.assert_allclose(np.asarray(ahead.put(uids, tokens)),
+                                   np.asarray(lazy.put(uids, tokens)),
+                                   rtol=1e-5, atol=1e-5)
+    # [1, 16] (each chunk row is a forward of its own) and [2, 1]: traced
+    # at first use by the one, never by the other
+    assert lazy.paged.forward._cache_size() == 2
+    assert ahead._forward_jit._cache_size() == 0
+    for eng in (lazy, ahead):
+        for uid in (1, 2):
+            eng.flush(uid)
+        assert eng.occupancy()["state_slots_used"] == 0
