@@ -231,8 +231,11 @@ class InferenceEngineV2:
         # ints; the scheduler copies them into its span attrs when traced):
         # the bucket [S, C] the forward ran at, its real rows and valid
         # tokens, the keys those rows' queries may see and the query-key
-        # pairs (the paged kernel's bytes and FLOPs follow from them), and
-        # the pool's available blocks after allocation
+        # pairs (the paged kernel's bytes and FLOPs follow from them), the
+        # table blocks those keys fill beside the slots of the bucket's
+        # tables (what the kernel's walk covers, of what a walk of the
+        # whole table would), and the pool's available blocks after
+        # allocation
         self.last_put: Dict[str, int] = {}
         # the same, cumulative since the engine was built (pad ratio over
         # any interval = delta positions_computed / delta tokens_valid)
@@ -385,6 +388,7 @@ class InferenceEngineV2:
         # the put's record: its last (widest) forward's bucket, the sums
         # of what its forwards counted, and how many they were
         summed = ("rows", "valid_tokens", "kv_read_tokens", "qk_pairs",
+                  "kv_blocks_live", "kv_table_slots",
                   "moe_rows_routed", "moe_rows_held")
         self.last_put = dict(records[-1], forwards=len(records), **{
             k: sum(r[k] for r in records) for k in summed
@@ -415,7 +419,8 @@ class InferenceEngineV2:
         """One forward over ``uids``' rows (``put``'s body)."""
         self.batch.clear()
         staged = []
-        valid = kv_read = qk_pairs = 0
+        valid = kv_read = qk_pairs = blocks_live = 0
+        block_size = self.config.kv_block_size
         for uid, toks in zip(uids, tokens_list):
             seq = self.state_manager.get_or_create_sequence(uid)
             self.state_manager.maybe_allocate_kv(seq, len(toks))
@@ -426,6 +431,7 @@ class InferenceEngineV2:
             valid += n
             kv_read += seen + n
             qk_pairs += n * seen + n * (n + 1) // 2
+            blocks_live += -(-(seen + n) // block_size)
 
         arrays = self.batch.finalize()
         bucket_seqs, bucket_chunk = arrays["tokens"].shape
@@ -434,6 +440,8 @@ class InferenceEngineV2:
             "bucket_seqs": bucket_seqs, "bucket_chunk": bucket_chunk,
             "rows": len(staged), "valid_tokens": valid,
             "kv_read_tokens": kv_read, "qk_pairs": qk_pairs,
+            "kv_blocks_live": blocks_live,
+            "kv_table_slots": bucket_seqs * arrays["block_tables"].shape[1],
             "free_blocks": sm.available_blocks}
         totals = self.put_totals
         totals["forwards"] += 1

@@ -4,28 +4,42 @@ Counterpart of the reference's ragged kernel suite
 (``inference/v2/kernels/ragged_ops/blocked_flash/blocked_flash.cpp`` — the
 blocked flash attention over "atoms" — plus ``atom_builder/atom_builder.cpp``
 which splits the ragged batch into fixed-size attention atoms). The TPU-first
-design needs no atom decomposition: the grid *is* the atom walk —
-``(seqs, kv_heads, table_blocks)`` with the table dimension innermost, each
-step streaming one KV block from the paged pool through VMEM into an online
-softmax.
+design needs no atom decomposition: the grid is ``(seqs, kv_heads / KHt)``
+and a loop inside each step walks the sequence's **live** table blocks,
+``T`` at a time, from HBM through VMEM into an online softmax. The cost
+follows the live KV bytes and the query-key pairs, not the table's width.
 
 - **q** [N, C, H, D]: per-sequence chunk of new tokens (C = 1 for pure
   decode; Dynamic SplitFuse feeds prompt chunks through the same path).
 - **KV pool** [L, NB, KH, bs, D] plus a ``layer`` scalar: the engine's
-  whole stacked cache, read where it lies — no layer's slab is sliced out
-  of it. The pool's per-(layer, block, kv-head) slab is the trailing
-  [bs, D] — exactly one tileable VMEM block, DMA'd directly by a BlockSpec
-  index map that *dereferences the layer and the block table* (both
-  scalar-prefetched, so indices are known before the body runs). A
-  [NB, KH, bs, D] pool with no ``layer`` is the one-layer case.
-  No [N, max_ctx, H, D] gather is ever materialized in HBM and GQA needs
-  no ``jnp.repeat`` — each grid step matmuls the [G·C, D] query group
-  against the shared [bs, D] KV block.
-- **Dead blocks** (past a sequence's context length) are skipped by
-  ``pl.when`` for compute and — because the index map clamps them to the
-  sequence's last live block, and Pallas only issues a DMA when the mapped
-  index changes — cost no HBM traffic either (same mechanism as the causal
-  clamp in flash_attention.py).
+  whole stacked cache, read where it lies (``memory_space=pl.ANY``) — no
+  layer's slab is sliced out of it. A block's [KHt, bs, D] slab (all of
+  [KH, bs, D] when the step holds every head: contiguous) is copied by
+  ``make_async_copy`` at ``[layer, table[n, b], heads]`` — layer, table and
+  lengths are scalar-prefetched — into one of two buffer slots, while the
+  other slot's T blocks are folded. A [NB, KH, bs, D] pool with no
+  ``layer`` is the one-layer case. No [N, max_ctx, H, D] gather is ever
+  materialized in HBM and GQA needs no ``jnp.repeat`` — each turn's two
+  dots are batched over the heads: q [KHt, G·C, D] · k [KHt, T·bs, D].
+- **The walk** runs from the sliding window's first live block to the
+  context's last, ``ceil(live / T)`` turns read from ``start_pos +
+  n_tokens``: a slot past the context (or of a padded row with no tokens)
+  costs no grid step, no copy and no arithmetic, and its table entry is
+  never dereferenced. Inside a turn, a block place that is not live is
+  not copied and its scores are masked; the V buffer starts each grid step
+  as zeros, so such a place holds zeros or an earlier turn's rows, never
+  a NaN for 0 · NaN to carry into the sum.
+- **Tiles**: ``KHt`` and ``T`` come from the shapes the call sees (G·C, D,
+  the local KH, bs, the dtypes) against ``VMEM_BUDGET`` (``_tiles``):
+  blocks first, up to ``KEY_TILE`` keys a turn, then every head that
+  fits — all heads for a one-token step, one for a 1,024-row group.
+- **Precision**: q, k and v enter both dots as the pool stores them
+  (bf16 as served) with float32 accumulation; ``sm_scale`` multiplies the
+  scores, so q is not rounded again; the softmax statistics and the
+  accumulator are float32; p is cast to the pool's dtype for p · v (what
+  ``paged_attention_xla`` does). An int8/fp8 payload is converted to the
+  query's dtype — exactly — and its per-(block, kv-head) scales multiply
+  the scores (K) and the probabilities (V) instead of the tile.
 - Masking: query row r (= g·C + ci) has global position start_pos + ci;
   KV slot s in table block b has position b·bs + s; attend iff
   kv_pos <= q_pos (causal over the shared pool) and kv_pos < ctx_len.
@@ -48,9 +62,18 @@ from .pallas_utils import pl, pltpu
 
 NEG_INF = -1e30
 LANES = 128
-#: the most query rows (G·C) one grid step's VMEM block may hold; every
-#: shape the accepted configurations run (G·C <= 1024) is under it
+#: the most query rows (G·C) one grid step may hold; every shape the
+#: accepted configurations run (G·C <= 1024) is under it
 MAX_QUERY_ROWS = 2048
+#: bytes of VMEM one grid step may claim — its query and output blocks,
+#: the float32 accumulator and softmax statistics, both slots of the K and
+#: V buffers and the score tiles (``_step_bytes``). Under the 16 MiB of
+#: scoped VMEM a v5e core grants a kernel by default, with room for what
+#: the compiler adds.
+VMEM_BUDGET = 12 * 2 ** 20
+#: the most keys one loop turn's dots span; the heads a step holds are
+#: whatever the budget leaves beside them
+KEY_TILE = 512
 
 # Test hook: force the Pallas path in interpreter mode off-TPU (same pattern
 # as ops/flash_attention.py).
@@ -61,104 +84,181 @@ def _use_interpret() -> bool:
     return _FORCE_INTERPRET or not _on_tpu()
 
 
+# -------------------------------------------------------------------- tiles
+
+def _step_bytes(kh_t: int, blocks: int, rows: int, head_dim: int,
+                block_size: int, q_dtype, pool_dtype) -> int:
+    """VMEM one grid step claims with ``kh_t`` KV heads and ``blocks``
+    table blocks a loop turn, from the shapes alone."""
+    q_b, pool_b = jnp.dtype(q_dtype).itemsize, jnp.dtype(pool_dtype).itemsize
+    rows = -(-rows // 16) * 16                    # a bf16 sublane tile
+    keys = blocks * block_size
+    per_head = (
+        2 * 2 * rows * head_dim * q_b             # q and o blocks, pipelined
+        + rows * head_dim * 4                     # accumulator
+        + 2 * rows * LANES * 4                    # running max and sum
+        + 2 * 2 * keys * head_dim * pool_b        # K and V, two slots each
+        + 3 * rows * keys * 4)                    # scores, p, p as stored
+    if pool_b == 1:
+        per_head += 2 * keys * head_dim * q_b     # the payload, converted
+    return kh_t * per_head
+
+
+def _tiles(rows: int, head_dim: int, kv_heads: int, block_size: int,
+           table_blocks: int, q_dtype, pool_dtype):
+    """``(KHt, T)``: the KV heads a grid step holds and the table blocks a
+    loop turn folds, from what the call sees. Blocks first, up to
+    ``KEY_TILE`` keys (a dot over one 64-key block fills half the MXU's
+    columns and re-scales the accumulator eight times as often); then
+    every head the budget leaves room for — all of them for a one-token
+    step, one for a long chunk of a wide group."""
+    def fits(kh_t, blocks):
+        return _step_bytes(kh_t, blocks, rows, head_dim, block_size,
+                           q_dtype, pool_dtype) <= VMEM_BUDGET
+
+    blocks = max(1, min(KEY_TILE // block_size, table_blocks))
+    while blocks > 1 and not fits(1, blocks):
+        blocks //= 2
+    kh_t = max((d for d in range(1, kv_heads + 1)
+                if kv_heads % d == 0 and fits(d, blocks)), default=1)
+    return kh_t, blocks
+
+
 # ------------------------------------------------------------------- kernel
 
 def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
-                  q_ref, k_ref, v_ref, *refs, block_size: int, chunk: int,
-                  groups: int, sm_scale: float, alibi: bool, window: int,
+                  q_ref, k_hbm, v_hbm, *refs, chunk: int, groups: int,
+                  kv_heads: int, sm_scale: float, alibi: bool, window: int,
                   quant: bool):
-    """One (n, kh, b) grid step: fold table block b of sequence n into the
-    online softmax of its [G·C, D] query group. With ``quant`` the KV
-    pools are int8/fp8 and two extra SMEM operands carry sequence n's
-    per-(table slot, kv-head) dequantization scales, flat [MB·KH]
-    (docs/SERVING.md "KV quantization") — the block is dequantized in
-    VMEM right after its DMA, so HBM only ever holds the 1-byte payload."""
+    """One (n, head group) grid step: sequence n's [KHt, G·C, D] query
+    rows against its live table blocks, ``T`` blocks a loop turn. The
+    pools stay in HBM; a turn's blocks are copied through the table into
+    one slot of the [2, KHt, T, bs, D] buffers while the other slot's are
+    folded into the online softmax, and the loop runs from the window's
+    first live block to the context's last — a table slot past it costs
+    nothing. With ``quant`` the pools are int8/fp8 and two SMEM operands
+    carry sequence n's per-(table slot, kv-head) dequantization scales,
+    flat [MB·KH] (docs/SERVING.md "KV quantization"): the payload goes
+    into the dots as it is (converted to the query's dtype, which holds it
+    exactly) and the scales multiply the scores and the probabilities."""
     if quant:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        o_ref, acc_ref, m_ref, l_ref = refs
+        ks_ref, vs_ref, *refs = refs
+    o_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref = refs
+    _, kh_t, T, bs, D = k_buf.shape
+    rows, keys = q_ref.shape[2], T * bs
+    last_slot = tables_ref.shape[1] - 1
     n = pl.program_id(0)
-    kh = pl.program_id(1)
-    b = pl.program_id(2)
-    nb = pl.num_programs(2)
+    kh0 = pl.program_id(1) * kh_t
+    layer = layer_ref[0]
+    startp = startp_ref[n]
+    ctx_len = startp + ntok_ref[n]
+    # live blocks [first, last): up to the context's end and, with a
+    # sliding window, from the earliest position any query row of this
+    # chunk attends, startp − window + 1
+    last = jnp.minimum(pl.cdiv(ctx_len, bs), last_slot + 1)
+    first = jnp.maximum(startp - window + 1, 0) // bs if window else 0
 
-    @pl.when(b == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def copies(b, slot):
+        """Table block b's K and V copies into their place in ``slot``."""
+        return [pltpu.make_async_copy(
+            hbm.at[layer, tables_ref[n, b], pl.ds(kh0, kh_t)],
+            buf.at[slot, :, b % T], sem.at[i, slot])
+            for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
 
-    ctx_len = startp_ref[n] + ntok_ref[n]
-    live = b * block_size < ctx_len
-    if window:
-        # sliding window: the earliest position any query row of this chunk
-        # attends is startp − window + 1 — blocks wholly before it are dead
-        live = live & (b * block_size + block_size - 1
-                       >= startp_ref[n] - window + 1)
+    def live_span(turn):
+        """The live blocks of a turn, [b0, b1): none past the last turn."""
+        return (jnp.maximum(first, turn * T),
+                jnp.minimum(last, turn * T + T))
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale        # [G*C, D]
-        k = k_ref[0, 0, 0].astype(jnp.float32)                # [bs, D]
-        v = v_ref[0, 0, 0].astype(jnp.float32)
+    # a loop over a turn's live blocks, not unrolled and with no branch a
+    # block: the kernel is traced once for every forward program (54 a
+    # dense engine), and each cond or loop it holds is host time at set-up
+    def each_live(turn, slot, act):
+        def one(b, _):
+            for dma in copies(b, slot):
+                act(dma)
+
+        lax.fori_loop(*live_span(turn), one, None)
+
+    def start(turn, slot):
+        each_live(turn, slot, lambda dma: dma.start())
+
+    def wait(turn, slot):
+        each_live(turn, slot, lambda dma: dma.wait())
+
+    # a place of a turn that no block is copied into keeps what it held:
+    # an earlier turn's rows, or these zeros — never the NaN that would
+    # reach the sum through 0 · NaN (its scores are masked)
+    v_buf[...] = jnp.zeros_like(v_buf)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    lo, hi = first // T, pl.cdiv(last, T)
+    start(lo, lo % 2)
+
+    # q row r = g·C + ci sits at global position startp + ci; ALiBi's
+    # slope belongs to head (kh0 + k)·G + g. Neither moves with the turn.
+    row = lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
+    qpos = startp + (row % chunk if groups > 1 else row)
+    if alibi:
+        # slopes live in SMEM; the static unroll keeps the reads scalar
+        slope = jnp.stack([
+            functools.reduce(
+                lambda acc, g: jnp.where(row[0] // chunk == g,
+                                         slopes_ref[kh0 + k, g], acc),
+                range(groups), jnp.zeros((rows, 1), jnp.float32))
+            for k in range(kh_t)])                            # [KHt, G·C, 1]
+
+    key_blk = lax.broadcasted_iota(jnp.int32, (1, keys), 1) // bs
+
+    def key_scales(ref, turn):
+        """[KHt, 1, T·bs]: each key's block scale, from SMEM scalars."""
+        return jnp.stack([
+            functools.reduce(
+                lambda acc, t: jnp.where(
+                    key_blk == t,
+                    ref[0, 0, jnp.minimum(turn * T + t, last_slot)
+                        * kv_heads + kh0 + k], acc),
+                range(T), jnp.zeros((1, keys), jnp.float32))
+            for k in range(kh_t)])
+
+    def fold(turn, _):
+        slot = turn % 2
+        start(turn + 1, 1 - slot)
+        wait(turn, slot)
+        q = q_ref[0]                                          # [KHt, G·C, D]
+        k = k_buf[slot].reshape(kh_t, keys, D)
+        v = v_buf[slot].reshape(kh_t, keys, D)
         if quant:
-            si = b * pl.num_programs(1) + kh
-            k = k * ks_ref[0, 0, si]
-            v = v * vs_ref[0, 0, si]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [G*C, bs]
-        # causal + context mask: q row r is chunk pos r % C at global
-        # position startp + r % C; KV slot col is position b*bs + col.
-        ci = lax.broadcasted_iota(jnp.int32, s.shape, 0) % chunk
-        qpos = startp_ref[n] + ci
-        kvpos = b * block_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            k, v = k.astype(q.dtype), v.astype(q.dtype)
+        s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32) * sm_scale
+        if quant:
+            s = s * key_scales(ks_ref, turn)
+        kvpos = turn * keys + lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2)
         if alibi:
-            # ALiBi logit bias: slope[head] · kv_position (row r of this
-            # kv-head group belongs to head kh·G + r//C). Slopes live in
-            # SMEM; the static G-unroll keeps reads scalar.
-            gi = lax.broadcasted_iota(jnp.int32, s.shape, 0) // chunk
-            slope = jnp.zeros_like(s[:, :1])
-            for g in range(groups):
-                slope = jnp.where(gi[:, :1] == g, slopes_ref[kh, g], slope)
             s = s + slope * kvpos.astype(jnp.float32)
+        # causal over the shared pool, and inside the context
         keep = (kvpos <= qpos) & (kvpos < ctx_len)
         if window:
-            keep = keep & (qpos - kvpos < window)
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]               # [G*C, 128]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+            keep = keep & (kvpos > qpos - window)
+        s = jnp.where(keep, s, NEG_INF)                       # [KHt, G·C, T·bs]
+        m_prev, l_prev = m_ref[...], l_ref[...]               # [KHt, G·C, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
+        p = jnp.exp(s - m_new[..., :1])
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        if quant:
+            p = p * key_scales(vs_ref, turn)
+        acc_ref[...] = acc_ref[...] * alpha[..., :1] + lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(b == nb - 1)
-    def _flush():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
-
-
-def _clamp_tables(block_tables, ctx_len, block_size, start_pos=None,
-                  window=0):
-    """Replace dead/unallocated table entries with the sequence's nearest
-    live block id so the kernel's index map repeats it (no DMA is issued when
-    the mapped block doesn't change between grid steps). Dead entries are
-    those past the context length and — with a sliding window — those wholly
-    before ``start_pos − window + 1``."""
-    N, MB = block_tables.shape
-    live_blocks = jnp.maximum(-(-ctx_len // block_size), 1)        # [N] >= 1
-    cols = jnp.arange(MB)[None, :]
-    last_live = jnp.clip(live_blocks - 1, 0, MB - 1)[:, None]
-    idx = jnp.minimum(cols, last_live)
-    if window and start_pos is not None:
-        first_live = jnp.clip((start_pos - window + 1) // block_size,
-                              0, MB - 1)[:, None]
-        idx = jnp.maximum(idx, first_live)
-    tbl = jnp.take_along_axis(block_tables, idx, axis=1)
-    return jnp.maximum(tbl, 0).astype(jnp.int32)
+    lax.fori_loop(lo, hi, fold, None)
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / l[..., :1]).astype(o_ref.dtype)
 
 
 def _stacked(k_pool, v_pool, k_scale, v_scale, layer):
@@ -190,41 +290,34 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
     MB = block_tables.shape[1]
     quant = k_scale is not None
     sm_scale = 1.0 / math.sqrt(D) if sm_scale is None else float(sm_scale)
+    kh_t, T = _tiles(G * C, D, KH, bs, MB, q.dtype, k_pool.dtype)
 
     # [N, C, H, D] -> [N, KH, G*C, D]: row r = g*C + ci
     qh = q.transpose(0, 2, 1, 3).reshape(N, KH, G * C, D)
-
-    ctx_len = start_pos + n_tokens
-    tables = _clamp_tables(block_tables, ctx_len, bs, start_pos, window)
-    startp = start_pos.astype(jnp.int32)
-    ntok = n_tokens.astype(jnp.int32)
+    if not quant:
+        qh = qh.astype(k_pool.dtype)      # the MXU gets what the pool holds
+    # an unallocated slot (< 0) is never live; 0 keeps its entry a block id
+    tables = jnp.maximum(block_tables, 0).astype(jnp.int32)
     alibi = alibi_slopes is not None
-    # slopes regrouped [KH, G] so the kernel reads its kv-head's row
+    # slopes regrouped [KH, G] so the kernel reads its kv-heads' rows
     slopes = (jnp.asarray(alibi_slopes, jnp.float32).reshape(KH, G)
               if alibi else jnp.zeros((KH, G), jnp.float32))
 
-    kernel = functools.partial(_paged_kernel, block_size=bs, chunk=C,
-                               groups=G, sm_scale=sm_scale, alibi=alibi,
-                               window=window, quant=quant)
-    # index maps see every scalar-prefetch ref; only the layer and the
-    # table are used
-    kv_spec = pl.BlockSpec(
-        (1, 1, 1, bs, D),
-        lambda n, kh, b, lyr, tbl, *_: (lyr[0], tbl[n, b], kh, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, G * C, D), lambda n, kh, b, *_: (n, kh, 0, 0)),
-        kv_spec, kv_spec,
-    ]
+    kernel = functools.partial(_paged_kernel, chunk=C, groups=G, kv_heads=KH,
+                               sm_scale=sm_scale, alibi=alibi, window=window,
+                               quant=quant)
+    q_spec = pl.BlockSpec((1, kh_t, G * C, D), lambda n, h, *_: (n, h, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, pool_spec, pool_spec]
     operands = [qh, k_pool, v_pool]
     if quant:
-        # per-(block, kv-head) dequant scales, gathered through the
-        # (clamped) block table to [N, 1, MB·KH]: one SMEM row per
-        # sequence, fetched when n changes and read as a scalar at
-        # b·KH + kh. A (1, 1) block of the [NB, KH] plane breaks the TPU
-        # (8, 128)-or-whole-array block rule, and whole planes outgrow
-        # the 1 MB of SMEM with the pool; a row's size follows the table.
-        scale_spec = pl.BlockSpec((1, 1, MB * KH),
-                                  lambda n, kh, b, *_: (n, 0, 0),
+        # per-(block, kv-head) dequant scales, gathered through the block
+        # table to [N, 1, MB·KH]: one SMEM row per sequence, fetched when
+        # n changes and read as a scalar at b·KH + kh. A (1, 1) block of
+        # the [NB, KH] plane breaks the TPU (8, 128)-or-whole-array block
+        # rule, and whole planes outgrow the 1 MB of SMEM with the pool;
+        # a row's size follows the table.
+        scale_spec = pl.BlockSpec((1, 1, MB * KH), lambda n, h, *_: (n, 0, 0),
                                   memory_space=pltpu.SMEM)
         in_specs += [scale_spec, scale_spec]
         operands += [
@@ -232,26 +325,28 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
             for s in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(N, KH, MB),
+        grid=(N, KH // kh_t),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G * C, D),
-                               lambda n, kh, b, *_: (n, kh, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((G * C, D), jnp.float32),
-            pltpu.VMEM((G * C, LANES), jnp.float32),
-            pltpu.VMEM((G * C, LANES), jnp.float32),
+            pltpu.VMEM((2, kh_t, T, bs, D), k_pool.dtype),
+            pltpu.VMEM((2, kh_t, T, bs, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),                 # [K/V, slot]
+            pltpu.VMEM((kh_t, G * C, D), jnp.float32),
+            pltpu.VMEM((kh_t, G * C, LANES), jnp.float32),
+            pltpu.VMEM((kh_t, G * C, LANES), jnp.float32),
         ],
     )
-    out_dt = q.dtype
     o = pl.pallas_call(
         kernel,
         name="paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, KH, G * C, D), out_dt),
+        out_shape=jax.ShapeDtypeStruct((N, KH, G * C, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(layer.reshape(1), tables, startp, ntok, slopes, *operands)
+    )(layer.reshape(1), tables, start_pos.astype(jnp.int32),
+      n_tokens.astype(jnp.int32), slopes, *operands)
     # [N, KH, G*C, D] -> [N, C, H, D]
     return (o.reshape(N, KH, G, C, D).transpose(0, 3, 1, 2, 4)
             .reshape(N, C, H, D))
@@ -344,13 +439,13 @@ def paged_attention(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
     ``alibi_slopes`` [H]: optional ALiBi bias slopes (BLOOM-family
     serving) — bias slope·kv_position is added to the logits in-kernel.
     ``window`` > 0: sliding-window attention (Mistral serving — reference
-    inference/v2/model_implementations/mistral/model.py:202); KV blocks
-    wholly before the window are skipped for compute and DMA.
+    inference/v2/model_implementations/mistral/model.py:202); the walk
+    starts at the window's first live block.
     ``k_scale``/``v_scale`` [L, NB, KH] (or [NB, KH] beside a one-layer
     pool): per-(block, kv-head) dequantization scales for int8 KV pools
-    (docs/SERVING.md "KV quantization") — dequantization happens inside
-    the kernel (VMEM) / after the gather (XLA path), so HBM only ever
-    holds the int8 pool.
+    (docs/SERVING.md "KV quantization") — the kernel applies them to the
+    scores and the probabilities, the XLA path to the gathered context,
+    so HBM only ever holds the int8 pool.
     Rows beyond n_tokens are garbage (masked out downstream).
     """
     if _pallas_ok(q, k_pool):

@@ -239,3 +239,180 @@ def test_sliding_window_drops_old_context(monkeypatch):
                               sp, nt, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2),
                                atol=1e-6, rtol=1e-6)
+
+
+# ------------------------------------------------- the walk over live blocks
+
+def _walk_case(rng, N, C, H, KH, D, bs, MB, NB, ctx_lens, n_tokens=None,
+               dtype=jnp.float32, quant=None):
+    """As ``_build_case`` with the rows' valid tokens given (0 = a padded
+    row of the bucket: no context, no table) and the pool's dtype: a float
+    one, or ``quant`` (int8 / fp8) with its [NB, KH] scale planes."""
+    n_tokens = [min(C, c) for c in ctx_lens] if n_tokens is None else n_tokens
+    q = jnp.asarray(rng.standard_normal((N, C, H, D)), dtype)
+    scales = {}
+    if quant is None:
+        kp, vp = (jnp.asarray(rng.standard_normal((NB, KH, bs, D)), dtype)
+                  for _ in range(2))
+    else:
+        kp, vp = (jnp.asarray(rng.integers(-120, 121, (NB, KH, bs, D)),
+                              jnp.float32).astype(quant) for _ in range(2))
+        scales = {f"{n}_scale": jnp.asarray(
+            rng.uniform(0.005, 0.02, (NB, KH)), jnp.float32) for n in "kv"}
+    # block 0 belongs to nobody: it is where a slot of −1 would land
+    perm = 1 + rng.permutation(NB - 1)
+    tables = np.full((N, MB), -1, np.int64)
+    pos = 0
+    for i, ctx in enumerate(ctx_lens):
+        nblk = -(-ctx // bs)
+        tables[i, :nblk] = perm[pos:pos + nblk]
+        pos += nblk
+    sp = [c - n for c, n in zip(ctx_lens, n_tokens)]
+    return ((q, kp, vp, jnp.asarray(tables, jnp.int32),
+             jnp.asarray(sp, jnp.int32), jnp.asarray(n_tokens, jnp.int32)),
+            scales)
+
+
+# 64-token blocks in a table of 20: ``_tiles`` folds 8 blocks a turn, so the
+# contexts are 0 live blocks (a padded row), 1, 8 (one whole turn), 11 (not
+# a multiple) and 20 (the table's width).
+_CTX = [0, 30, 512, 700, 1280]
+_GEOM = dict(N=5, bs=64, MB=20, NB=48, ctx_lens=_CTX)
+WALK_CASES = {
+    # all heads a step: MHA and GQA decode, one token a row
+    "mha_decode": dict(C=1, H=4, KH=4, D=64, n_tokens=[0, 1, 1, 1, 1]),
+    "gqa_32_8_decode": dict(C=1, H=32, KH=8, D=64, n_tokens=[0, 1, 1, 1, 1]),
+    "gqa_16_2_d256_decode": dict(C=1, H=16, KH=2, D=256,
+                                 n_tokens=[0, 1, 1, 1, 1]),
+    # chunk rows, some short of C (their tail rows are unspecified)
+    "gqa_chunk": dict(C=8, H=8, KH=2, D=64, n_tokens=[0, 8, 3, 8, 5]),
+    # G·C over MAX_QUERY_ROWS (patched to 16): cut along C into 3 pieces,
+    # a piece past a row's tokens walks nothing
+    "query_rows_cut": dict(C=24, H=4, KH=2, D=64, n_tokens=[0, 24, 5, 9, 17],
+                           max_rows=16),
+    "window_200": dict(C=4, H=4, KH=2, D=64, n_tokens=[0, 4, 4, 4, 4],
+                       kw=dict(window=200)),
+    "window_700_decode": dict(C=1, H=4, KH=4, D=64, n_tokens=[0, 1, 1, 1, 1],
+                              kw=dict(window=700)),
+    "alibi_mha": dict(C=1, H=4, KH=4, D=64, n_tokens=[0, 1, 1, 1, 1],
+                      alibi=True),
+    "alibi_gqa_chunk": dict(C=4, H=8, KH=2, D=64, n_tokens=[0, 4, 2, 4, 4],
+                            alibi=True),
+    "int8_pool": dict(C=1, H=8, KH=2, D=64, n_tokens=[0, 1, 1, 1, 1],
+                      quant=jnp.int8, atol=2e-4),
+    "int8_pool_chunk": dict(C=4, H=4, KH=4, D=64, n_tokens=[0, 4, 4, 2, 4],
+                            quant=jnp.int8, atol=2e-4),
+    "fp8_pool": dict(C=1, H=8, KH=2, D=64, n_tokens=[0, 1, 1, 1, 1],
+                     quant=jnp.float8_e4m3fn, atol=2e-4),
+    # bf16 as served, against the float32 formulation of the same values:
+    # what is left is p's and the output's rounding to bf16
+    "bf16_pool_decode": dict(C=1, H=8, KH=2, D=128, dtype=jnp.bfloat16,
+                             n_tokens=[0, 1, 1, 1, 1], atol=1e-2),
+    "bf16_pool_chunk": dict(C=8, H=4, KH=4, D=128, dtype=jnp.bfloat16,
+                            n_tokens=[0, 8, 8, 3, 8], atol=1e-2),
+}
+
+
+@pytest.mark.parametrize("name", WALK_CASES)
+def test_walk_over_live_blocks_matches_xla(name, monkeypatch):
+    """The kernel's tiling — every KV head a step can hold, several table
+    blocks a loop turn, a walk that ends at the sequence's last block —
+    against the XLA gather, in interpreter mode."""
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    case = dict(_GEOM, **WALK_CASES[name])
+    atol = case.pop("atol", 2e-5)
+    kw = dict(case.pop("kw", {}))
+    if case.pop("alibi", False):
+        from deepspeed_tpu.models.transformer import alibi_slopes
+
+        kw["alibi_slopes"] = alibi_slopes(case["H"])
+    if "max_rows" in case:
+        monkeypatch.setattr(pa, "MAX_QUERY_ROWS", case.pop("max_rows"))
+    args, scales = _walk_case(np.random.default_rng(5), **case)
+    kh_t, T = pa._tiles(
+        min(case["C"], pa.MAX_QUERY_ROWS // (case["H"] // case["KH"]))
+        * (case["H"] // case["KH"]), case["D"], case["KH"], case["bs"],
+        case["MB"], args[0].dtype, args[1].dtype)
+    assert T == 8 and (kh_t == case["KH"] or case["C"] > 1)
+    out = pa.paged_attention(*args, **kw, **scales)
+    f32 = [a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a
+           for a in args]
+    ref = pa.paged_attention_xla(*f32, **kw, **scales)
+    assert out.dtype == args[0].dtype
+    for i, n in enumerate(np.asarray(args[5])):
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32)[i, :n], np.asarray(ref)[i, :n],
+            atol=atol, rtol=atol, err_msg=f"row {i} of {name}")
+    # a padded row (no tokens, no context) reads as zeros, not garbage
+    assert not np.asarray(out, np.float32)[0].any()
+
+
+def test_unallocated_slots_are_never_dereferenced(monkeypatch):
+    """A table wider than the blocks in use: its −1 slots would land on
+    pool block 0, which here holds NaN and belongs to nobody."""
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    args, _ = _walk_case(np.random.default_rng(6), C=2, H=4, KH=2, D=64,
+                         n_tokens=[0, 2, 2, 2, 2], **_GEOM)
+    q, kp, vp, tbl, sp, nt = args
+    ref = pa.paged_attention_xla(q, kp.at[0].set(0.0), vp.at[0].set(0.0),
+                                 tbl, sp, nt)
+    out = pa.paged_attention(q, kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan),
+                             tbl, sp, nt)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out)[1:], np.asarray(ref)[1:],
+                               atol=2e-5, rtol=2e-5)
+
+
+# the shapes the benchmark's cells run: (G·C rows, D, local KH, bs, MB)
+CELL_GEOMETRIES = {
+    "pythia_decode": (1, 128, 16, 64, 32),
+    "pythia_chunk_256": (256, 128, 16, 64, 32),
+    "mistral_decode": (4, 128, 8, 64, 64),
+    "mistral_chunk_256": (1024, 128, 8, 64, 64),
+    "mistral_decode_tp4": (4, 128, 2, 64, 64),
+    "qwen3_next_decode": (8, 256, 2, 64, 512),
+    "qwen3_next_2048_row_piece": (2048, 256, 2, 64, 512),
+}
+
+
+@pytest.mark.parametrize("name", CELL_GEOMETRIES)
+@pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.int8],
+                         ids=lambda d: jnp.dtype(d).name)
+def test_tile_choice_stays_under_the_budget(name, pool):
+    """Shapes only: the heads and blocks a step takes, and the VMEM they
+    claim, at the geometries the cells run."""
+    rows, D, KH, bs, MB = CELL_GEOMETRIES[name]
+    kh_t, T = pa._tiles(rows, D, KH, bs, MB, jnp.bfloat16, pool)
+    assert KH % kh_t == 0 and 1 <= T <= MB and T * bs <= pa.KEY_TILE
+    assert pa._step_bytes(kh_t, T, rows, D, bs, jnp.bfloat16, pool) \
+        <= pa.VMEM_BUDGET < 16 * 2 ** 20
+    assert rows <= pa.MAX_QUERY_ROWS
+    if name.endswith("decode") or "decode_" in name:
+        # a one-token step takes every local head and several blocks
+        assert kh_t == KH and T > 1
+    if rows >= 1024:
+        assert kh_t == 1
+
+
+def test_put_record_counts_the_walk():
+    """``engine.last_put``: the table blocks the rows' contexts fill,
+    beside the slots of the bucket's tables."""
+    from deepspeed_tpu.inference.v2.engine_v2 import (
+        InferenceEngineV2, RaggedInferenceEngineConfig)
+    from deepspeed_tpu.models.transformer import CausalLM, TINY_TEST
+
+    vcfg = RaggedInferenceEngineConfig(
+        max_ragged_batch_size=128, max_ragged_sequence_count=4,
+        max_chunk_tokens=32, kv_blocks=32, kv_block_size=8,
+        max_tracked_sequences=8)
+    engine = InferenceEngineV2(CausalLM(TINY_TEST), config=vcfg)
+    slots = -(-TINY_TEST.max_seq_len // 8)
+    engine.put([1, 2], [list(range(1, 21)), list(range(1, 10))])
+    put = engine.last_put
+    assert put["kv_blocks_live"] == 3 + 2            # 20 and 9 tokens
+    assert put["kv_table_slots"] == put["bucket_seqs"] * slots
+    engine.put([1, 2, 3], [[5], [6], list(range(1, 9))])
+    put = engine.last_put
+    assert put["kv_blocks_live"] == 3 + 2 + 1        # 21, 10 and 8 tokens
+    assert put["kv_table_slots"] == put["bucket_seqs"] * slots
+    assert put["kv_blocks_live"] * 8 >= put["kv_read_tokens"]

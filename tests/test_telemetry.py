@@ -657,6 +657,9 @@ def test_scheduler_step_phase_spans():
     assert record == {"bucket_seqs": 2, "bucket_chunk": 16, "rows": 2,
                       "valid_tokens": 16, "kv_read_tokens": 16,
                       "qk_pairs": 11 * 12 // 2 + 5 * 6 // 2,
+                      # 11 and 5 tokens in 8-token blocks, of 2 tables of
+                      # 128 / 8 slots
+                      "kv_blocks_live": 2 + 1, "kv_table_slots": 2 * 16,
                       "free_blocks": eng.state_manager.available_blocks}
     spans = {s["name"]: s for s in tr.export()}
     assert set(spans) == {"step", "pack", "stage", "fetch", "commit",
@@ -670,13 +673,18 @@ def test_scheduler_step_phase_spans():
         assert ph["parent_id"] == step["span_id"]
         assert ph["trace_id"] == "replica-7"
         assert step["t_start"] <= ph["t_start"] and ph["t_end"] <= step["t_end"]
-    assert spans["stage"]["attrs"] == record
+    # ``stage`` carries the record without the walk's two counts (the
+    # benchmark's agreement test pins its keys); ``forward`` carries all
+    walk = {"kv_blocks_live", "kv_table_slots"}
+    assert spans["stage"]["attrs"] == {k: v for k, v in record.items()
+                                       if k not in walk}
     assert spans["forward"]["attrs"] == dict(record, n_seqs=2, n_tokens=16)
     # a decode step: one position a row, every key seen so far read
     sched.step()
     assert eng.last_put["bucket_chunk"] == 1
     assert eng.last_put["kv_read_tokens"] == 12 + 6
     assert eng.last_put["qk_pairs"] == 12 + 6
+    assert eng.last_put["kv_blocks_live"] == 2 + 1
     assert eng.put_totals == {"forwards": 2, "tokens_valid": 18,
                               "positions_computed": 2 * 16 + 2}
     # a step with nothing to run is a step with a pack and no more

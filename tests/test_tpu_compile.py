@@ -135,6 +135,33 @@ def test_paged_attention(v5e, pool, chunk):
     _compile(attend, *shapes, sharding=SingleDeviceSharding(v5e[0]))
 
 
+@pytest.mark.parametrize("geometry", [
+    # N, C, H, KH, D, MB, NB, window: the cells' other shapes — Mistral's
+    # GQA decode and chunk steps, Qwen3-Next's D = 256 / KH = 2 pool under
+    # a 512-slot table with its 2,048-row pieces, a TP shard's two heads
+    (32, 1, 32, 8, 128, 64, 1152, 4096), (32, 256, 32, 8, 128, 64, 1152, 4096),
+    (8, 1, 16, 2, 256, 512, 4096, 0), (1, 256, 16, 2, 256, 512, 4096, 0),
+    (32, 1, 8, 2, 128, 64, 1152, 4096),
+], ids=["mistral_decode", "mistral_chunk", "qwen3_next_decode",
+        "qwen3_next_piece", "mistral_decode_tp4"])
+def test_paged_attention_at_the_cells_geometries(v5e, geometry):
+    """The tiles ``_tiles`` picks for each (heads a step, blocks a turn)
+    fit the chip's scoped VMEM: the estimate in ``_step_bytes`` is held to
+    what the compiler allocates."""
+    N, C, H, KH, D, MB, NB, window = geometry
+    assert (H // KH) * C <= pa.MAX_QUERY_ROWS
+    bf16 = jnp.bfloat16
+    shapes = [((N, C, H, D), bf16), ((2, NB, KH, 64, D), bf16),
+              ((2, NB, KH, 64, D), bf16), ((), jnp.int32),
+              ((N, MB), jnp.int32), ((N,), jnp.int32), ((N,), jnp.int32)]
+
+    def attend(q, k, v, layer, tbl, sp, nt):
+        return pa._paged_pallas(q, k, v, tbl, sp, nt, layer=layer,
+                                window=window, interpret=False)
+
+    _compile(attend, *shapes, sharding=SingleDeviceSharding(v5e[0]))
+
+
 @pytest.mark.parametrize("bucket", [(1, 1), (16, 1), (8, 256)],
                          ids=lambda b: f"{b[0]}x{b[1]}")
 @pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.int8],
