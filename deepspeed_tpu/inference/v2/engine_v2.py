@@ -713,7 +713,7 @@ class InferenceEngineV2:
     def occupancy(self) -> Dict[str, int]:
         """KV-pool occupancy snapshot (blocks + bytes + evictable/
         available) — the single source the serving gauges
-        (``kv_blocks_in_use``/``kv_bytes_in_use``) and bench phase stamps
+        (``kv_blocks_in_use``/``kv_bytes_in_use``) and the autoscaler
         read; see :meth:`DSStateManager.occupancy`."""
         return self.state_manager.occupancy()
 
@@ -796,7 +796,7 @@ class InferenceEngineV2:
     def param_stats(self) -> Dict[str, object]:
         """Resident param-byte accounting (total + quantized share) — the
         single source the ``param_bytes_total``/``param_bytes_quantized``
-        serving gauges and the bench phase stamps read; cheap (pure
+        serving gauges and the fabric status frames read; cheap (pure
         shape/dtype metadata, computed lazily once per param tree)."""
         if self._weight_quant_stats is None:
             from .weight_quant import param_stats

@@ -113,14 +113,6 @@ def kv_bytes_per_block(model_cfg, block_size: int, quant: bool,
     return 2 * slab * itemsize
 
 
-def blocks_for_budget(budget_bytes: int, model_cfg, block_size: int,
-                      quant: bool, dtype=None) -> int:
-    """How many pool blocks a KV byte budget buys at this representation
-    (bench's concurrency-at-fixed-HBM comparison; at least 1)."""
-    return max(1, int(budget_bytes)
-               // kv_bytes_per_block(model_cfg, block_size, quant, dtype))
-
-
 def quantized_block_write(pool, scale, new_vals, plan, layer):
     """Merge new K or V rows into layer ``layer`` of a quantized pool (the
     quantized counterpart of the reference ``linear_blocked_kv_rotary``

@@ -62,14 +62,14 @@ _STORE_IDS = itertools.count()
 TIER_STAT_KEYS = ("spilled", "restored", "dropped", "demoted",
                   "hits", "misses", "corrupt")
 #: occupancy keys (also surfaced through ``DSStateManager.occupancy()``
-#: as ``kv_blocks_host_tier`` etc. — the bench phase stamps and the
-#: serving gauges read those)
+#: as ``kv_blocks_host_tier`` etc. — the serving gauges read
+#: those)
 TIER_OCC_KEYS = ("host_blocks", "host_bytes", "disk_blocks", "disk_bytes")
 
 
 def empty_tier_stats() -> Dict[str, int]:
     """The all-zero stats+occupancy dict a tier-less manager reports —
-    one shape for consumers (replica delta publish, bench stamps)
+    one shape for consumers (the replica's delta publish)
     whether or not a tier exists."""
     out = {k: 0 for k in TIER_STAT_KEYS}
     out.update({k: 0 for k in TIER_OCC_KEYS})

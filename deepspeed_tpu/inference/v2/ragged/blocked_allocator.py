@@ -42,9 +42,9 @@ class BlockedAllocator:
 
     def occupancy(self) -> Dict[str, int]:
         """One consistent snapshot of pool occupancy — the single home
-        for the counts admission control, the prefix cache, serving
-        metrics (``kv_blocks_in_use``/``kv_bytes_in_use`` gauges) and the
-        bench phases previously derived ad hoc."""
+        for the counts admission control, the prefix cache and the
+        serving metrics (``kv_blocks_in_use``/``kv_bytes_in_use`` gauges)
+        read."""
         in_use = self._num_blocks - len(self._free)
         bpb = self.bytes_per_block
         return {"total_blocks": self._num_blocks,

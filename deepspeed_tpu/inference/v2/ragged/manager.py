@@ -684,13 +684,13 @@ class DSStateManager:
         counts plus the prefix-cache view (evictable = reclaimable cached
         blocks, available = what an allocate can actually obtain). The
         serving layer publishes this as ``kv_blocks_in_use`` /
-        ``kv_bytes_in_use`` gauges and every bench phase stamps it."""
+        ``kv_bytes_in_use`` gauges."""
         occ = self.allocator.occupancy()
         occ["evictable_blocks"] = self.evictable_blocks
         occ["available_blocks"] = occ["free_blocks"] + occ["evictable_blocks"]
         # per-tier residency (docs/SERVING.md "KV tiering"): zeros when
-        # no tier is configured, so the serving gauges and bench stamps
-        # have one schema either way
+        # no tier is configured, so the serving gauges have one schema
+        # either way
         tier = (self._tier.occupancy() if self._tier is not None
                 else {"host_blocks": 0, "host_bytes": 0,
                       "disk_blocks": 0, "disk_bytes": 0})
@@ -975,7 +975,7 @@ class DSStateManager:
     def tier_stats(self) -> Dict[str, int]:
         """Monotonic spill/restore/drop counters plus current host/disk
         occupancy — all zeros (same shape) without a tier, so consumers
-        (replica delta publish, bench stamps) need no feature check."""
+        (the replica's delta publish) need no feature check."""
         from ..kv_tier import empty_tier_stats
 
         if self._tier is None:

@@ -120,7 +120,7 @@ class ContinuousBatchingScheduler:
         self._proposer_warned = False
         # admission overhaul (docs/SERVING.md "Admission and
         # preemption"), read from the ENGINE config so bare schedulers
-        # (bench, tests) and the serving stack share one wiring point
+        # (benchmark/, tests) and the serving stack share one wiring point
         # (``ServingFrontend`` stamps ``ServingConfig.admission`` onto
         # each replica engine via ``engine.configure_admission`` before
         # building the replica's scheduler). All-default = the

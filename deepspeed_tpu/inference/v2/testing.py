@@ -1,8 +1,8 @@
 """Shared correctness helpers for the v2 ragged engine.
 
 One home for the greedy-token-parity machinery used by
-``tests/test_prefix_cache.py``, ``tests/test_spec_decode.py``, and
-``bench.py``'s shared-prefix and speculative phases: every engine-level
+``tests/test_prefix_cache.py``, ``tests/test_spec_decode.py`` and the
+other feature suites under ``tests/``: every engine-level
 optimization here (prefix caching, speculative decoding) carries the hard
 guarantee that greedy token streams are byte-identical with the feature on
 and off — this module is the single definition of "run these prompts

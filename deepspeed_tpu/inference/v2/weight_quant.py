@@ -96,7 +96,7 @@ def quantize_weights(model_cfg, params, dtype: str = "int8",
     Returns ``(new_params, stats)`` where quantized leaves are
     ``{"qw", "qs"}`` nodes and everything else is the original array
     (same objects — no copy). ``stats`` carries the byte accounting the
-    serving gauges and bench phase publish."""
+    serving gauges publish."""
     validate_weight_quant(dtype, block)
     skip = set(skip) | set(DEFAULT_SKIP)
     moe = getattr(model_cfg, "moe_num_experts", 0) > 0
@@ -134,8 +134,8 @@ def param_stats(params, dtype: str = "", block: int = 0) -> Dict[str, int]:
     ``param_bytes_total`` = resident bytes of every leaf (scale planes
     included), ``param_bytes_quantized`` = bytes of the quantized nodes
     (payload + scales), ``params_quantized`` = node count. The shape the
-    ``param_bytes_total``/``param_bytes_quantized`` serving gauges and
-    the bench phase stamps read."""
+    ``param_bytes_total``/``param_bytes_quantized`` serving gauges
+    read."""
     total = quantized = nodes = 0
     for leaf in jax.tree.leaves(params, is_leaf=is_quantized):
         if is_quantized(leaf):

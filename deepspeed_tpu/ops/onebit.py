@@ -378,12 +378,17 @@ class OneBitLamb(OneBitOptimizer):
         t = state.step + 1
         dp = self.dp_size
         c2f = self._frozen_c2()
+        # the frozen ratio r was recorded against the bias-corrected update
+        # (warmup_step_local): applied to an uncorrected m it would leave
+        # the layer's step (1 - b1^t) short of lr·‖p‖ until the bias decays
+        c1 = 1.0 - b1 ** t.astype(jnp.float32)
 
         def upd(p, g, m, v, r, e, e2):
             c = b1 * m + (1 - b1) * g + e[0]
             m2, err, e2n = self._compress(c, e2, dp)
             # frozen v carries its frozen bias correction (see OneBitAdam)
-            u = m2 / (jnp.sqrt(v / c2f) + self.eps) + self.weight_decay * p
+            u = (m2 / c1) / (jnp.sqrt(v / c2f) + self.eps) \
+                + self.weight_decay * p
             return p - lr * r * u, m2, v, r, err[None], e2n[None]
 
         out = _tmap(upd, params, grads, state.moments["m"],

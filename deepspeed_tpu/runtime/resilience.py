@@ -17,8 +17,7 @@ TPU fleets, reusing the supervisor/backoff/chaos idioms proven out in
   the ``fold_in`` fold points), and the data-iterator position
   (``DeepSpeedTpuDataLoader.state_dict``) are all restored, so an
   interrupted+resumed run reproduces the uninterrupted loss curve
-  byte-for-byte (asserted in tests/test_train_resilience.py and the
-  bench ``train_chaos`` phase).
+  byte-for-byte (asserted in tests/test_train_resilience.py).
 - :class:`StepWatchdog`: a host-side thread with a rolling-median
   step-time baseline. A wedged step (stuck device call) is detected, the
   flight recorder is dumped, and the supervisor restarts from ``latest``
@@ -31,7 +30,7 @@ TPU fleets, reusing the supervisor/backoff/chaos idioms proven out in
 - :class:`TrainFaultInjector`: seeded, scripted training faults
   (``crash``/``sigterm``/``nan_grads``/``slow_step`` at exact step
   indices) in the style of ``serving/faults.py``, driving the chaos
-  suite and bench phase. Disabled = zero hooks anywhere.
+  suite. Disabled = zero hooks anywhere.
 
 Everything defaults off: with no ``resilience:`` block (and no supervisor
 constructed) training behavior is byte-for-byte historical.
@@ -68,9 +67,8 @@ class TrainFaultsConfig(DSConfigModel):
     """``resilience.faults: {...}`` TEST-ONLY deterministic training fault
     injection (docs/CONFIG.md): a seeded schedule of crashes, preemption
     signals, NaN gradient storms, and wedged-step latency, driving the
-    chaos suite (tests/test_train_resilience.py) and ``bench.py``'s
-    ``train_chaos`` phase. Disabled = no hooks — byte-for-byte the
-    uninstrumented training loop."""
+    chaos suite (tests/test_train_resilience.py). Disabled = no hooks —
+    byte-for-byte the uninstrumented training loop."""
 
     enabled: bool = False
     seed: int = 0

@@ -65,7 +65,7 @@ class AffinityState:
     # lock discipline (docs/CONCURRENCY.md): the digest table is
     # REPLACED (publication) by the router tick / status consumers and
     # read by the pick path; the share window and hit/miss tallies are
-    # mutated per pick from the dispatch thread and read by tests/bench.
+    # mutated per pick from the dispatch thread and read by tests.
     _GUARDED_BY = {"_digests": "_lock:writes", "_recent": "_lock",
                    "_stats": "_lock"}
 
@@ -187,8 +187,8 @@ class AffinityState:
             return dict(self._stats)
 
     def share_counts(self) -> Dict[int, int]:
-        """Per-replica counts over the current share window (bench/test
-        surface for the cap assertion)."""
+        """Per-replica counts over the current share window (the cap
+        assertion of tests/test_affinity.py reads it)."""
         with self._lock:
             out: Dict[int, int] = {}
             for rid in self._recent:

@@ -41,8 +41,8 @@ the ops journal (``scale_up`` / ``scale_down`` / ``replica_reroled`` /
 dashboard's record of what the controller *wants* vs what
 ``replicas_healthy`` says it has. The controller also keeps the
 ``replica_seconds`` ledger (fleet-size integral over time) — the
-chip-seconds-per-SLO-attained cost metric the bench ``autoscale`` phase
-reports against a static fleet (PAPERS.md: arxiv 2605.25645).
+chip-seconds-per-SLO-attained cost metric to hold against a static
+fleet's ``replicas * wall`` (PAPERS.md: arxiv 2605.25645).
 
 Disabled (``autoscaler.enabled: false``, the default) no controller is
 built anywhere — the static-fleet stack byte for byte.
@@ -194,8 +194,8 @@ class FleetController:
     # ---------------------------------------------------------------- stats
     def replica_seconds(self) -> float:
         """Fleet-size integral over time (parked corpses excluded) —
-        the replica-seconds cost ledger the bench ``autoscale`` phase
-        compares against ``static_replicas * wall``."""
+        the replica-seconds cost ledger, to compare against a static
+        fleet's ``static_replicas * wall``."""
         with self._lock:
             return self._replica_seconds
 

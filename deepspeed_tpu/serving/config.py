@@ -695,9 +695,9 @@ class FaultsConfig(DSConfigModel):
     """``faults: {...}`` TEST-ONLY deterministic fault injection
     (docs/CONFIG.md, serving/faults.py): a seeded schedule of replica
     crashes, wedges, ``engine.put`` errors, and slow-forward latency,
-    driving the chaos suite (tests/test_fault_tolerance.py) and
-    ``bench.py``'s chaos phase. Disabled = no engine proxying, no hooks
-    — byte-for-byte the uninstrumented serving stack."""
+    driving the chaos suite (tests/test_fault_tolerance.py). Disabled =
+    no engine proxying, no hooks — byte-for-byte the uninstrumented
+    serving stack."""
 
     enabled: bool = False
     seed: int = 0
@@ -723,8 +723,8 @@ class ChaosConfig(DSConfigModel):
     of ``faults:``: a seeded schedule of per-link latency, bandwidth
     throttle, connection drops, blackholes, partitions, duplicate/
     reordered deliveries and frame bit-corruption, interposed between
-    the fabric transport and its socket. Drives the net_chaos bench
-    phase and the transport edge-case suite. Disabled = the injector is
+    the fabric transport and its socket. Drives tests/test_net_chaos.py
+    and the transport edge-case suite. Disabled = the injector is
     never installed: zero interposition, byte-for-byte the
     uninstrumented transport (asserted in tests)."""
 
@@ -979,10 +979,10 @@ class ServingConfig(DSConfigModel):
     # disabled = no listener, byte-for-byte the endpoint-less stack
     observability: ObservabilityConfig = Field(
         default_factory=ObservabilityConfig)
-    # test-only deterministic fault injection (chaos suite / bench chaos
-    # phase); disabled = no injection hooks anywhere on the hot path
+    # test-only deterministic fault injection (the chaos suite);
+    # disabled = no injection hooks anywhere on the hot path
     faults: FaultsConfig = Field(default_factory=FaultsConfig)
-    # test-only deterministic NETWORK fault injection (net_chaos bench
-    # phase / transport edge-case suite); disabled = the injector is
+    # test-only deterministic NETWORK fault injection (the net_chaos and
+    # transport edge-case suites); disabled = the injector is
     # never installed — zero transport interposition
     chaos: ChaosConfig = Field(default_factory=ChaosConfig)

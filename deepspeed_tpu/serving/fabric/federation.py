@@ -63,7 +63,7 @@ PEERING_MARKERS = ("self_peering:", "stale_epoch:", "export_unknown:",
                    "federation_role:")
 
 #: per-process frontend-instance counter: two frontends in ONE process
-#: (the in-process test/bench topology) must still derive distinct
+#: (the in-process test topology) must still derive distinct
 #: identities, or they would refuse each other as self-peering
 _INSTANCE_SEQ = itertools.count(1)
 

@@ -5,8 +5,8 @@ untested fault tolerance. This module makes failure *schedulable*: a
 seeded :class:`FaultInjector` fires scripted faults at exact points in a
 replica's life — crash at scheduler-step k, wedge (block the worker loop)
 for t seconds, ``engine.put`` raising, slow-forward latency — so the
-chaos suite (tests/test_fault_tolerance.py) and ``bench.py``'s chaos
-phase replay the same failure story every run.
+chaos suite (tests/test_fault_tolerance.py) replays the same failure
+story every run.
 
 Wiring is test-only and zero-cost when off: the ``faults:`` config block
 (docs/CONFIG.md) builds the injector; :class:`Replica` consults
@@ -97,7 +97,7 @@ class FaultInjector:
             self.events.append(ev)
         self._lock = RankedLock("serving.faults")
         # (kind, replica, index, monotonic t) per firing — what the chaos
-        # tests and the bench chaos phase assert against / report
+        # tests assert against
         self.fired_log: List[tuple] = []
 
     # ----------------------------------------------------------- matching

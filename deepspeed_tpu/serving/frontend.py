@@ -11,8 +11,8 @@ Composes the whole serving stack::
 
 All telemetry lands in one :class:`MetricsRegistry` (TTFT/TPOT/queue
 histograms, shed/cancel/complete counters) that fans out through the
-``monitor/`` backends via :meth:`publish_metrics` and feeds ``bench.py``'s
-serving phase.
+``monitor/`` backends via :meth:`publish_metrics` and is served by the
+observability endpoint.
 """
 
 from __future__ import annotations

@@ -2,8 +2,8 @@
 
 The serving layer's telemetry lives in one thread-safe registry so the
 router/replica/queue code records blindly and every consumer — the
-``monitor/`` backends (TensorBoard / W&B / CSV), ``bench.py``'s serving
-phase, tests — reads the same numbers. Histograms use fixed upper-bound
+``monitor/`` backends (TensorBoard / W&B / CSV), the observability
+endpoint, tests — reads the same numbers. Histograms use fixed upper-bound
 buckets (Prometheus-style) so percentile estimates are mergeable and
 allocation-free on the hot path; ``percentile`` interpolates linearly
 within the winning bucket.
@@ -358,7 +358,7 @@ STOCK_CLASSES = ("interactive", "batch")
 def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
                     tenants: Sequence[str] = ()) -> MetricsRegistry:
     """Registry pre-declaring the serving layer's metric names, so
-    dashboards and ``bench.py`` see zeros (not absences) before traffic.
+    dashboards see zeros (not absences) before traffic.
     ``classes`` extends the per-class series (``ttft_s_class_<cls>``,
     ``requests_shed_class_<cls>``, …) beyond the stock
     interactive/batch pair — ``ServingFrontend`` passes the configured
@@ -534,9 +534,8 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # (docs/SERVING.md "Admission and preemption")
               "preempt_spill_s", "preempt_resume_s",
               # serving fabric: per-RPC wall time (hello/assign/
-              # evacuate), the transport-overhead signal the bench
-              # fabric phase stamps (docs/SERVING.md "Multi-host
-              # serving")
+              # evacuate), the transport-overhead signal
+              # (docs/SERVING.md "Multi-host serving")
               "rpc_call_s",
               # grow-path prefix-cache warm-up wall time, one sample per
               # grown replica (docs/SERVING.md "Fleet KV locality")

@@ -1,8 +1,8 @@
 """One serving replica: a worker thread driving an InferenceEngineV2.
 
-Thread-per-replica mirrors how ``bench.py``'s serving phase drives the
-engine: each replica owns a :class:`ContinuousBatchingScheduler` (Dynamic
-SplitFuse) over its engine and a lock-free inbox the router assigns into.
+One thread a replica and one engine a thread, so that no two threads ever
+drive one engine: each replica owns a :class:`ContinuousBatchingScheduler`
+(Dynamic SplitFuse) over its engine and a lock-free inbox the router assigns into.
 The loop per iteration: drain the inbox into the scheduler, enforce
 cancellations and deadlines (both free KV blocks *immediately* via
 ``scheduler.cancel`` → ``engine.flush``), then run one scheduler step,

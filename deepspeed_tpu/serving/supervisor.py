@@ -87,8 +87,8 @@ class ReplicaSupervisor:
             for r in router.replicas}
         self._lock = RankedLock("serving.supervisor")
         # per-restart records: {"replica", "t_dead", "t_restarted",
-        # "backoff_s", "attempt"} — the bench chaos phase's
-        # recovery_time_s = t_restarted - t_dead
+        # "backoff_s", "attempt"}; recovery time is
+        # t_restarted - t_dead
         self.restart_log: List[dict] = []
         self._stop = threading.Event()
         self.thread = threading.Thread(target=self._loop, daemon=True,

@@ -70,7 +70,7 @@ class FlightRecorder:
     @staticmethod
     def _sweep_stale_dumps(dump_dir: str) -> int:
         """Delete dump files whose owning pid (the trailing filename
-        token) is dead — a bench/test fleet's previous run must not leave
+        token) is dead — a test fleet's previous run must not leave
         its obituaries to be mistaken for this run's. Files of LIVE
         processes (including this one) and unparseable names are never
         touched; any OS error ends the sweep silently (telemetry must

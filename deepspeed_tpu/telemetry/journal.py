@@ -169,8 +169,8 @@ def validate_event(event: dict) -> List[str]:
 def validate_events(events: Sequence[dict]) -> List[str]:
     """Schema + ordering problems across a whole event list (empty =
     valid): per-event schema, strictly-increasing seq, non-decreasing
-    monotonic timestamps. The chaos suite and the bench ``slo`` phase
-    run this over live journals."""
+    monotonic timestamps. The chaos suites and
+    tests/test_slo_observability.py run this over live journals."""
     problems = []
     prev_seq, prev_t = None, None
     for ev in events:

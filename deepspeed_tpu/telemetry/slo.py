@@ -81,8 +81,8 @@ class SLOConfig(DSConfigModel):
     # construction. Tenants with no entry are unmonitored.
     tenants: Dict[str, SLOClassTarget] = Field(default_factory=dict)
     # burn-rate windows: fire on fast AND slow breach, resolve when the
-    # fast window clears. Production-shaped defaults; the CPU bench and
-    # the chaos suite shrink them to seconds.
+    # fast window clears. Production-shaped defaults; the tests
+    # (tests/test_slo_observability.py) shrink them to seconds.
     fast_window_s: float = 60.0
     slow_window_s: float = 300.0
     # burn-rate threshold in error-budget multiples (1.0 = spending the

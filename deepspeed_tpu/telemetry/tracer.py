@@ -361,8 +361,8 @@ def chrome_trace(spans: Sequence[Dict[str, Any]],
 def validate_chrome_trace(obj: Any) -> List[str]:
     """Structural check of a Chrome-trace object (or its JSON string):
     returns a list of problems, empty when the trace is loadable. Used by
-    ``bench.py``'s telemetry phase and tests so saved artifacts are
-    verified, not assumed."""
+    tests/test_telemetry.py and tests/test_fleet_obs.py so saved
+    artifacts are verified, not assumed."""
     problems: List[str] = []
     if isinstance(obj, (str, bytes)):
         try:
@@ -401,9 +401,9 @@ def validate_chrome_trace(obj: Any) -> List[str]:
 def trace_coverage(spans: Iterable[Dict[str, Any]], t0: float,
                    t1: float) -> float:
     """Fraction of the window ``[t0, t1]`` covered by the union of the
-    given spans' intervals (open spans count up to ``t1``). The bench
-    telemetry phase uses this to enforce that a request's span chain
-    accounts for ≥95% of its measured TTFT — coverage, not vibes."""
+    given spans' intervals (open spans count up to ``t1``).
+    tests/test_telemetry.py uses this to enforce that a request's span
+    chain accounts for ≥95% of its measured TTFT."""
     if t1 <= t0:
         return 1.0
     ivals: List[Tuple[float, float]] = []

@@ -24,8 +24,8 @@ counts.
 
 Ticks come from the serving router's ~1/s loop (the same place the
 flight recorder snapshots metrics); anything may also call
-:meth:`tick` directly (tests, the bench ``slo`` phase). The whole layer
-is passive — nothing here mutates the registry.
+:meth:`tick` directly (tests/test_slo_observability.py does). The whole
+layer is passive — nothing here mutates the registry.
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ reads. The cache's directory is part of its key, so it must not move:
 - unset: ``<checkout>/.jax_cache`` — a fixed path under the repo, listed in
   ``.gitignore``; never ``tempfile``, a pid or a time.
 
-Call before the first compile (``chip_smoke.py``, ``bench.py`` and
+Call before the first compile (``chip_smoke.py`` and
 ``scripts/serve_replica.py`` do).
 """
 

@@ -25,6 +25,7 @@ import pytest
 from deepspeed_tpu.inference.v2.engine_v2 import (
     InferenceEngineV2, RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.scheduler import ContinuousBatchingScheduler
+from deepspeed_tpu.inference.v2.testing import greedy_generate
 from deepspeed_tpu.models.transformer import CausalLM, TransformerConfig
 from deepspeed_tpu.serving import (AutoscalerConfig, ServingConfig,
                                    ServingFrontend, serving_metrics)
@@ -614,8 +615,10 @@ class TestElasticEndToEnd:
             [tiny_engine(0, max_seqs=2)],
             scfg, engine_factory=lambda i: tiny_engine(i, max_seqs=2))
         try:
-            hs = [fe.submit(p, max_new_tokens=24)
-                  for p in prompts(24, 17)]
+            ps = prompts(24, 17)
+            ref = greedy_generate(tiny_engine(90), ps, uid_base=500,
+                                  max_new_tokens=24)
+            hs = [fe.submit(p, max_new_tokens=24) for p in ps]
             assert fe.wait_all(hs, timeout=600)
             deadline = time.monotonic() + 60
             while time.monotonic() < deadline:
@@ -639,6 +642,9 @@ class TestElasticEndToEnd:
             assert validate_events(fe.journal.events()) == []
             snap = fe.metrics_snapshot()
             assert snap["requests_completed"] == 24
+            # every elastic stream (evacuated-and-resumed ones included)
+            # is the uncontended greedy stream of its prompt
+            assert [[ev.token for ev in h.drain()] for h in hs] == ref
             # the actuation surface reaches the health report too
             rep = fe.health_report()
             assert rep["autoscaler"]["scale_ups"] >= 1
@@ -646,3 +652,22 @@ class TestElasticEndToEnd:
             assert "autoscaler: target=1" in fe.health_report_text()
         finally:
             fe.shutdown(drain=False, timeout=5)
+
+    def test_disabled_block_is_the_static_stack(self):
+        """``autoscaler: {enabled: false}`` is byte-for-byte a config
+        that never heard of the block: no controller, the same greedy
+        streams."""
+        ps = prompts(6, 23)
+
+        def gens(extra):
+            fe = ServingFrontend([tiny_engine(0)],
+                                 ServingConfig(max_queue_depth=64, **extra))
+            try:
+                assert fe.autoscaler is None
+                hs = [fe.submit(p, max_new_tokens=8) for p in ps]
+                assert fe.wait_all(hs, timeout=300)
+                return [[ev.token for ev in h.drain()] for h in hs]
+            finally:
+                fe.shutdown(drain=False, timeout=5)
+
+        assert gens({"autoscaler": {"enabled": False}}) == gens({})

@@ -37,8 +37,8 @@ from deepspeed_tpu.telemetry.flight_recorder import FlightRecorder
 from deepspeed_tpu.telemetry.journal import OpsJournal
 from deepspeed_tpu.telemetry.tracer import Tracer
 
-from test_fabric import (VOCAB, _Servers, fabric_cfg, prompts, run_fleet,
-                         tiny_engine)
+from test_fabric import (VOCAB, _Servers, fabric_cfg, local_reference,
+                         prompts, run_fleet, tiny_engine)
 
 
 def _wait(pred, timeout=30.0, interval=0.05):
@@ -245,6 +245,46 @@ class TestObsEndpoint:
         # shutdown closed the listener
         with pytest.raises(OSError):
             self._get(addr, "/metrics")
+
+    def test_fleetctl_status_against_live_endpoint(self, capsys):
+        """``scripts/fleetctl.py status`` renders the live endpoint's
+        ``/health`` and exits 0; against a closed endpoint it exits 1 (the
+        liveness-probe contract of its docstring)."""
+        import importlib.util
+
+        spec = importlib.util.spec_from_file_location(
+            "fleetctl", os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "scripts", "fleetctl.py"))
+        fleetctl = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(fleetctl)
+        fe = ServingFrontend([tiny_engine()], ServingConfig(
+            max_queue_depth=64,
+            observability={"enabled": True, "listen": "127.0.0.1:0"}))
+        try:
+            addr = fe.observability_address
+            run_fleet(fe, prompts(2, 5), 4)
+            fleetctl.main(["--addr", addr, "status"])
+            out = capsys.readouterr().out
+            assert "replicas: 1 healthy=1" in out and "queue: depth=" in out
+        finally:
+            fe.shutdown(drain=False, timeout=5)
+        with pytest.raises(SystemExit) as ei:
+            fleetctl.main(["--addr", addr, "--timeout", "5", "status"])
+        assert ei.value.code == 1
+
+    def test_observability_on_off_absent_greedy_parity(self):
+        """Telemetry and the endpoint change what is recorded, never a
+        token: enabled, ``enabled: false`` and no block at all serve the
+        same greedy streams."""
+        ps = prompts(4, 9)
+        absent = local_reference(ps, 6)
+        assert local_reference(
+            ps, 6, telemetry={"enabled": False},
+            observability={"enabled": False}) == absent
+        assert local_reference(
+            ps, 6, telemetry={"enabled": True},
+            observability={"enabled": True,
+                           "listen": "127.0.0.1:0"}) == absent
 
     def test_disabled_is_absent(self):
         fe = ServingFrontend([tiny_engine()],

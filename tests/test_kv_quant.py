@@ -14,8 +14,7 @@ import pytest
 
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   RaggedInferenceEngineConfig)
-from deepspeed_tpu.inference.v2.kv_quant import (blocks_for_budget,
-                                                 kv_bytes_per_block,
+from deepspeed_tpu.inference.v2.kv_quant import (kv_bytes_per_block,
                                                  validate_kv_quant)
 from deepspeed_tpu.inference.v2.ragged import BlockedAllocator, DSStateManager
 from deepspeed_tpu.inference.v2.scheduler import ContinuousBatchingScheduler
@@ -86,11 +85,50 @@ def test_bytes_per_block_and_budget(model_and_params):
     assert i8 < base
     # the headline claim: a fixed byte budget buys >= 1.5x the blocks
     budget = 32 * base
-    assert blocks_for_budget(budget, cfg, BS, quant=True) >= 48
+    assert budget // i8 >= 48
     eng = make_engine(model, params, quant=True)
     occ = eng.occupancy()
     assert occ["bytes_per_block"] == i8
     assert occ["bytes_total"] == 64 * i8
+
+
+@pytest.mark.parametrize("qdtype", KV_DTYPES)
+def test_fixed_byte_budget_serves_more_sequences(model_and_params, qdtype):
+    """The headline claim in counts, not bytes: at one KV byte budget,
+    with the pool as the only bound, the scheduler keeps more sequences
+    decoding at once on quantized blocks than on full-precision ones, and
+    every request of the same burst completes both ways."""
+    model, params = model_and_params
+    plen, gen, base_blocks = 24, 8, 8
+    budget = base_blocks * kv_bytes_per_block(model.cfg, BS, quant=False)
+    q_blocks = budget // kv_bytes_per_block(model.cfg, BS, quant=True)
+    per_seq = -(-(plen + gen) // BS)
+    n_req = q_blocks // per_seq + 2         # past the quantized capacity
+    rng = np.random.default_rng(21)
+    reqs = [rand_prompt(rng, plen) for _ in range(n_req)]
+
+    def peak_running(quant, kv_blocks):
+        eng = make_engine(model, params, quant=quant, qdtype=qdtype,
+                          kv_blocks=int(kv_blocks), max_seqs=n_req + 1,
+                          admission_reservation=True)
+        sched = ContinuousBatchingScheduler(eng)
+        for i, p in enumerate(reqs):
+            sched.submit(100 + i, p, max_new_tokens=gen)
+        peak = steps = 0
+        while sched.has_work and steps < 5000:
+            sched.step()
+            steps += 1
+            peak = max(peak, len(sched.running))
+        done = sum(r.finish_reason == "length"
+                   for r in sched.finished.values())
+        assert eng.free_blocks == kv_blocks
+        return peak, done
+
+    peak_base, done_base = peak_running(False, base_blocks)
+    peak_q, done_q = peak_running(True, q_blocks)
+    assert done_base == done_q == n_req
+    assert peak_base <= base_blocks // per_seq + 1
+    assert peak_q >= 1.5 * peak_base, (peak_q, peak_base)
 
 
 def test_validate_kv_quant_rejects_unknown():
@@ -178,8 +216,8 @@ def test_bounded_divergence_and_logit_error(model_and_params, qdtype):
 @pytest.mark.parametrize("qdtype", KV_DTYPES)
 def test_perplexity_delta_gate(model_and_params, qdtype):
     """Teacher-forced perplexity of the quantized engine within 5% of
-    the unquantized engine (the bench kv_quant phase's gate, in
-    miniature) — both the int8 and fp8_e4m3 representations."""
+    the unquantized engine, for both the int8 and the fp8_e4m3
+    representation (the quality gate of docs/SERVING.md "KV quantization")."""
     model, params = model_and_params
     rng = np.random.default_rng(3)
     toks = rand_prompt(rng, 64)
@@ -469,383 +507,3 @@ def test_pallas_kernel_dequant_matches_xla(monkeypatch):
     vf = vq.astype(jnp.float32) * vs[:, :, None, None]
     dense = pa.paged_attention_xla(q, kf, vf, tbl, sp, nt)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(dense))
-
-
-# ------------------------------------------------------ bench schema check
-def test_bench_schema_validator():
-    import importlib
-    import os
-    import sys
-
-    os.environ.setdefault("BENCH_TIMEOUT_S", "0")   # no watchdog in tests
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    bench = importlib.import_module("bench")
-    occ = {k: 1 for k in bench._OCCUPANCY_KEYS}
-    good = {"kv_quant": {"max_concurrent_base": 8, "max_concurrent_int8": 16,
-                         "max_concurrent_fp8": 16,
-                         "concurrency_ratio": 2.0, "budget_bytes": 1024,
-                         "ppl_base": 1.0, "ppl_int8": 1.0, "ppl_fp8": 1.0,
-                         "ppl_ratio": 1.0, "ppl_ratio_fp8": 1.0,
-                         "ppl_gate_ok": True, "ppl_gate_ok_fp8": True,
-                         "greedy_parity": True,
-                         "mean_matched_prefix_frac": 1.0,
-                         "mean_matched_prefix_frac_fp8": 1.0,
-                         "disabled_parity": True, "kv_occupancy": occ}}
-    good["weight_quant"] = {
-        "param_bytes_fp32": 4096, "param_bytes_int8": 1024,
-        "weight_compression_x": 4.0, "bytes_gate_ok": True,
-        "host_byte_budget": 1 << 20,
-        "replicas_at_budget_base": 2, "replicas_at_budget_int8": 8,
-        "prefill_ttft_base_ms": 9.0, "prefill_ttft_int8_ms": 8.0,
-        "decode_tpot_base_ms": 2.0, "decode_tpot_int8_ms": 1.8,
-        "ppl_base": 1.0, "ppl_int8": 1.0, "ppl_ratio": 1.0,
-        "ppl_gate_ok": True, "mean_matched_prefix_frac": 1.0,
-        "greedy_parity": True, "disabled_parity": True,
-        "kv_occupancy": dict(occ)}
-    for name in bench._STAMPED_PHASES:
-        if name in ("kv_quant", "weight_quant", "train_chaos", "disagg",
-                    "slo", "kv_tier", "overload", "autoscale", "fabric"):
-            continue            # typed phases built explicitly
-        good[name] = {"kv_occupancy": dict(occ)}
-    good["kv_tier"] = {"tier_on_p50_ttft_ms": 10.7,
-                       "tier_off_p50_ttft_ms": 14.1,
-                       "ttft_improved": True, "blocks_spilled": 64,
-                       "blocks_restored": 64, "blocks_dropped": 0,
-                       "prefix_hit_rate_on": 0.89,
-                       "prefix_hit_rate_off": 0.0,
-                       "greedy_parity": True, "disabled_parity": True,
-                       "kv_occupancy": dict(occ)}
-    good["slo"] = {"alert_fired": True, "alert_resolved": True,
-                   "fire_to_resolve_s": 4.9, "alerts_firing_peak": 1,
-                   "alerts_firing_final": 0, "window_p95_ttft_ms": 12.5,
-                   "cum_p95_ttft_ms": 12.5, "window_agrees": True,
-                   "noise_floor_pct": 1.0, "overhead_slo_pct": 0.3,
-                   "overhead_ok": True, "journal_events": 2,
-                   "journal_schema_ok": True, "disabled_parity": True,
-                   "kv_occupancy": dict(occ)}
-    good["train_chaos"] = {"recovery_time_s": 0.12, "steps_lost": 1,
-                           "resume_parity": True,
-                           "sigterm_resume_parity": True,
-                           "injectors_off_parity": True, "restarts": 1,
-                           "n_steps": 8, "crash_at_step": 5,
-                           "urgent_save_s": 0.01,
-                           "kv_occupancy": dict(occ)}
-    good["disagg"] = {"handoffs_completed": 13, "handoff_fallbacks": 0,
-                      "tpot_improved": True, "handoff_parity": True,
-                      "disabled_parity": True, "replicas": 4,
-                      "decode_reserve_tokens": 8,
-                      "kv_occupancy": dict(occ)}
-    good["overload"] = {"n_requests": 24, "kv_blocks": 8,
-                        "overload_ratio": 10.25,
-                        "oversubscription_factor": 2.5,
-                        "zero_wedges": True, "completed_on": 24,
-                        "completed_off": 0,
-                        "completed_per_sec_on": 9.6,
-                        "completed_per_sec_off": 0.0,
-                        "sequences_preempted": 12,
-                        "sequences_resumed": 12,
-                        "p95_interactive_ttft_ms": 2500.0,
-                        "p99_interactive_ttft_ms": 2500.0,
-                        "p95_interactive_tpot_ms": 2.4,
-                        "p99_interactive_tpot_ms": 2.5,
-                        "preempt_parity": True, "disabled_parity": True,
-                        "kv_occupancy": dict(occ)}
-    good["autoscale"] = {"n_requests": 30, "min_replicas": 1,
-                         "max_replicas": 3, "static_replicas": 3,
-                         "slo_attainment_elastic": 1.0,
-                         "slo_attainment_static": 1.0,
-                         "attainment_ok": True,
-                         "replica_seconds_elastic": 16.2,
-                         "replica_seconds_static": 21.9,
-                         "elastic_beats_static_cost": True,
-                         "scale_ups": 2, "scale_downs": 2, "reroles": 0,
-                         "peak_replicas": 3, "final_replicas": 1,
-                         "requests_evacuated": 0,
-                         "greedy_parity": True, "disabled_parity": True,
-                         "kv_occupancy": dict(occ)}
-    good["fabric"] = {"replicas": 2, "n_requests": 8, "prompt_len": 24,
-                      "max_new": 8, "chunk_blocks": 1,
-                      "local_p50_ttft_ms": 1287.3,
-                      "local_p95_ttft_ms": 1287.4,
-                      "local_p50_tpot_ms": 2.3, "local_p95_tpot_ms": 3.5,
-                      "fabric_p50_ttft_ms": 1967.6,
-                      "fabric_p95_ttft_ms": 1989.7,
-                      "fabric_p50_tpot_ms": 3.4,
-                      "fabric_p95_tpot_ms": 169.7,
-                      "rpc_calls": 22, "rpc_p50_ms": 0.8,
-                      "rpc_p95_ms": 175.0,
-                      "rpc_overhead_p50_ttft_ms": 680.3,
-                      "handoffs_completed_local": 10,
-                      "handoffs_completed_fabric": 10,
-                      "handoff_fallbacks_fabric": 0,
-                      "handle_disconnects": 0,
-                      "parity": True, "disabled_parity": True,
-                      "zero_wedges": True, "kv_occupancy": dict(occ)}
-    good["multitenant"] = {"n_flood": 12, "n_interactive": 5,
-                           "flood_max_new": 10, "interactive_max_new": 6,
-                           "solo_p95_ttft_ms": 1635.7,
-                           "fair_on_p95_ttft_ms": 1921.0,
-                           "fair_off_p95_ttft_ms": 2158.6,
-                           "isolation_ratio_on": 1.174,
-                           "starvation_ratio_off": 1.32,
-                           "isolation_ok": True,
-                           "flood_tokens_on": 120,
-                           "flood_progress_ok": True,
-                           "fair_beats_off": True,
-                           "tenant_b_submitted": 5, "tenant_b_shed": 0,
-                           "zero_wedges": True,
-                           "greedy_parity": True, "disabled_parity": True,
-                           "kv_occupancy": dict(occ)}
-    good["affinity"] = {"n_requests": 72, "n_replicas": 3,
-                        "n_families": 9, "shared_prefix_tokens": 112,
-                        "max_new": 3,
-                        "affinity_on_p50_ttft_ms": 44.3,
-                        "affinity_on_p95_ttft_ms": 1591.1,
-                        "affinity_off_p50_ttft_ms": 91.5,
-                        "affinity_off_p95_ttft_ms": 1869.8,
-                        "ttft_improved": True,
-                        "prefix_tokens_saved_on": 5600,
-                        "prefix_tokens_saved_off": 2352,
-                        "tokens_saved_improved": True,
-                        "affinity_hits": 50, "affinity_misses": 22,
-                        "share_cap_ok": True,
-                        "warmup_blocks": 32, "warmup_s": 0.49,
-                        "warmup_first_hit_ok": True,
-                        "predictive_first_grow_tick": 5,
-                        "watermark_first_grow_tick": 8,
-                        "predictive_earlier": True,
-                        "predictive_peak_queue": 28.0,
-                        "watermark_peak_queue": 35.5,
-                        "predictive_no_flap": True,
-                        "greedy_parity": True, "disabled_parity": True,
-                        "kv_occupancy": dict(occ)}
-    good["federation"] = {"frontends": 2, "n_requests": 8,
-                          "prompt_len": 24, "max_new": 8,
-                          "exported_replicas": 1,
-                          "requests_federated": 4,
-                          "standalone_p50_ttft_ms": 3379.3,
-                          "standalone_p95_ttft_ms": 3647.8,
-                          "federated_p50_ttft_ms": 3271.0,
-                          "federated_p95_ttft_ms": 3568.3,
-                          "peer_rpc_calls": 5, "peer_rpc_p50_ms": 0.6,
-                          "peer_rpc_p95_ms": 1.0,
-                          "kill_n_requests": 4, "kill_max_new": 96,
-                          "requests_failed_over": 2,
-                          "failover_recovery_s": 0.268,
-                          "parity": True, "kill_parity": True,
-                          "disabled_parity": True, "zero_wedges": True,
-                          "kv_occupancy": dict(occ)}
-    good["fleet_obs"] = {"replicas": 2, "n_requests": 8,
-                         "prompt_len": 24, "max_new": 6,
-                         "wall_off_s": 0.272, "wall_off_rerun_s": 0.302,
-                         "wall_on_s": 0.282, "noise_floor_pct": 11.4,
-                         "overhead_enabled_pct": 3.9,
-                         "spans_total": 192, "server_spans": 16,
-                         "spans_forwarded": 68,
-                         "min_ttft_coverage": 0.999,
-                         "ttft_coverage_ok": True,
-                         "chains_complete": True,
-                         "trace_path": "/tmp/trace_fleet_1.json",
-                         "trace_valid": True, "journal_sources": 2,
-                         "journal_events_forwarded": 6,
-                         "journal_events_dropped": 0,
-                         "journal_exactly_once": True,
-                         "clock_offset_ms": 0.08,
-                         "http_metrics_ok": True, "http_health_ok": True,
-                         "fleetctl_ok": True, "parity": True,
-                         "disabled_parity": True, "zero_wedges": True,
-                         "kv_occupancy": dict(occ)}
-    good["net_chaos"] = {"replicas": 3, "n_requests": 9,
-                         "prompt_len": 24, "max_new": 6,
-                         "completed_under_chaos": 1.0,
-                         "recovery_time_s": 1.666,
-                         "quarantines_journaled": 1,
-                         "readmits_journaled": 1,
-                         "frames_corrupt": 3,
-                         "frames_corrupt_fatal": 0,
-                         "faults_injected": 40,
-                         "parity": True, "disabled_parity": True,
-                         "kv_occupancy": dict(occ)}
-    assert bench.validate_serving_schema(good) == []
-    # multitenant typed checks: bool-for-int rejected, missing named
-    bad_mt = dict(good)
-    bad_mt["multitenant"] = {"n_flood": True, "isolation_ok": 1}
-    problems_mt = bench.validate_serving_schema(bad_mt)
-    assert any("multitenant.n_flood" in p for p in problems_mt)
-    assert any("multitenant.isolation_ok" in p for p in problems_mt)
-    assert any("multitenant.fair_beats_off: missing" in p
-               for p in problems_mt)
-    # affinity typed checks: bool-for-int rejected, missing named
-    bad_af = dict(good)
-    bad_af["affinity"] = {"affinity_hits": True, "share_cap_ok": 1}
-    problems_af = bench.validate_serving_schema(bad_af)
-    assert any("affinity.affinity_hits" in p for p in problems_af)
-    assert any("affinity.share_cap_ok" in p for p in problems_af)
-    assert any("affinity.warmup_first_hit_ok: missing" in p
-               for p in problems_af)
-    # federation typed checks: bool-for-int rejected, missing named
-    bad_fd = dict(good)
-    bad_fd["federation"] = {"requests_federated": True, "kill_parity": 1}
-    problems_fd = bench.validate_serving_schema(bad_fd)
-    assert any("federation.requests_federated" in p for p in problems_fd)
-    assert any("federation.kill_parity" in p for p in problems_fd)
-    assert any("federation.failover_recovery_s: missing" in p
-               for p in problems_fd)
-    # fleet_obs typed checks: bool-for-int rejected, missing named
-    bad_fo = dict(good)
-    bad_fo["fleet_obs"] = {"journal_sources": True, "fleetctl_ok": 1}
-    problems_fo = bench.validate_serving_schema(bad_fo)
-    assert any("fleet_obs.journal_sources" in p for p in problems_fo)
-    assert any("fleet_obs.fleetctl_ok" in p for p in problems_fo)
-    assert any("fleet_obs.min_ttft_coverage: missing" in p
-               for p in problems_fo)
-    # fabric typed checks: bool-for-int rejected, missing fields named
-    bad_fb = dict(good)
-    bad_fb["fabric"] = {"rpc_calls": True, "parity": 1}
-    problems_fb = bench.validate_serving_schema(bad_fb)
-    assert any("fabric.rpc_calls" in p for p in problems_fb)
-    assert any("fabric.parity" in p for p in problems_fb)
-    assert any("fabric.zero_wedges: missing" in p for p in problems_fb)
-    # autoscale typed checks: bool-for-int rejected, missing named
-    bad_as = dict(good)
-    bad_as["autoscale"] = {"scale_ups": True, "attainment_ok": 1}
-    problems_as = bench.validate_serving_schema(bad_as)
-    assert any("autoscale.scale_ups" in p for p in problems_as)
-    assert any("autoscale.attainment_ok" in p for p in problems_as)
-    assert any("autoscale.greedy_parity: missing" in p
-               for p in problems_as)
-    # overload typed checks: bool-for-int rejected, missing fields named
-    bad_ov = dict(good)
-    bad_ov["overload"] = {"completed_on": True, "zero_wedges": 1}
-    problems_ov = bench.validate_serving_schema(bad_ov)
-    assert any("overload.completed_on" in p for p in problems_ov)
-    assert any("overload.zero_wedges" in p for p in problems_ov)
-    assert any("overload.preempt_parity: missing" in p
-               for p in problems_ov)
-    # disagg typed checks: missing and mistyped fields are named
-    bad_dg = dict(good)
-    bad_dg["disagg"] = {"handoffs_completed": True, "handoff_parity": 1}
-    problems_dg = bench.validate_serving_schema(bad_dg)
-    assert any("disagg.handoffs_completed" in p for p in problems_dg)
-    assert any("disagg.handoff_parity" in p for p in problems_dg)
-    assert any("disagg.disabled_parity: missing" in p for p in problems_dg)
-    # kv_tier typed checks: missing and mistyped (bool-for-int) named
-    bad_kt = dict(good)
-    bad_kt["kv_tier"] = {"blocks_restored": True, "greedy_parity": 1}
-    problems_kt = bench.validate_serving_schema(bad_kt)
-    assert any("kv_tier.blocks_restored" in p for p in problems_kt)
-    assert any("kv_tier.greedy_parity" in p for p in problems_kt)
-    assert any("kv_tier.disabled_parity: missing" in p
-               for p in problems_kt)
-    # skipped phases are exempt from field checks
-    skipped = dict(good)
-    skipped["chaos"] = {"phase_skipped": "phase budget 240s exceeded"}
-    assert bench.validate_serving_schema(skipped) == []
-    # missing/garbled fields are named
-    bad = dict(good)
-    bad["kv_quant"] = {"max_concurrent_base": "eight"}
-    problems = bench.validate_serving_schema(bad)
-    assert any("max_concurrent_base" in p for p in problems)
-    assert any("concurrency_ratio: missing" in p for p in problems)
-    bad2 = dict(good)
-    bad2["prefix"] = {"n_requests": 1}
-    assert any("prefix.kv_occupancy" in p
-               for p in bench.validate_serving_schema(bad2))
-    # train_chaos typed checks: wrong types and missing fields are named,
-    # a bool where an int is expected is rejected, a skip stamp is exempt
-    bad3 = dict(good)
-    bad3["train_chaos"] = {"recovery_time_s": "fast", "steps_lost": True,
-                           "kv_occupancy": dict(occ)}
-    problems3 = bench.validate_serving_schema(bad3)
-    assert any("train_chaos.recovery_time_s" in p for p in problems3)
-    assert any("train_chaos.steps_lost" in p for p in problems3)
-    assert any("train_chaos.resume_parity: missing" in p for p in problems3)
-    skipped2 = dict(good)
-    skipped2["train_chaos"] = {"phase_skipped": "not selected"}
-    assert bench.validate_serving_schema(skipped2) == []
-    # the shared typed-phase checker applies the bool guard to kv_quant
-    # too: a bool where an int is expected is named, not silently passed
-    bad4 = dict(good)
-    bad4["kv_quant"] = dict(good["kv_quant"], max_concurrent_base=True)
-    assert any("kv_quant.max_concurrent_base" in p
-               for p in bench.validate_serving_schema(bad4))
-    # weight_quant typed checks: bool-for-int rejected, missing named
-    bad_wq = dict(good)
-    bad_wq["weight_quant"] = {"param_bytes_fp32": True, "bytes_gate_ok": 1}
-    problems_wq = bench.validate_serving_schema(bad_wq)
-    assert any("weight_quant.param_bytes_fp32" in p for p in problems_wq)
-    assert any("weight_quant.bytes_gate_ok" in p for p in problems_wq)
-    assert any("weight_quant.disabled_parity: missing" in p
-               for p in problems_wq)
-    # slo typed checks: missing/mistyped fields named; a journal that
-    # failed validate_events is a schema problem in its own right
-    bad5 = dict(good)
-    bad5["slo"] = {"alert_fired": 1, "kv_occupancy": dict(occ)}
-    problems5 = bench.validate_serving_schema(bad5)
-    assert any("slo.alert_fired" in p for p in problems5)
-    assert any("slo.journal_schema_ok: missing" in p for p in problems5)
-    bad6 = dict(good)
-    bad6["slo"] = dict(good["slo"], journal_schema_ok=False)
-    assert any("journal events failed schema" in p
-               for p in bench.validate_serving_schema(bad6))
-    skipped3 = dict(good)
-    skipped3["slo"] = {"phase_skipped": "not selected"}
-    assert bench.validate_serving_schema(skipped3) == []
-
-
-def test_phase_runner_skip_and_budget(tmp_path, monkeypatch):
-    import importlib
-    import sys
-
-    monkeypatch.setenv("BENCH_TIMEOUT_S", "0")
-    sys.path.insert(0, str(tmp_path.parent))  # no-op, keeps sys.path sane
-    bench = importlib.import_module("bench")
-    monkeypatch.setenv("BENCH_PHASE_DIR", str(tmp_path))
-    monkeypatch.setenv("BENCH_PHASE_TIMEOUT_S", "1")
-    monkeypatch.delenv("BENCH_PHASES", raising=False)
-    monkeypatch.delenv("BENCH_RESUME", raising=False)
-    runner = bench.PhaseRunner(stamp=lambda: {"total_blocks": 1})
-    # a phase that exceeds its budget degrades to a stamp, and later
-    # phases in the SAME process skip too (the abandoned worker may
-    # still be mutating shared engine state — racing it would corrupt
-    # their numbers); skip stamps are never cached as artifacts
-    import time as _t
-    out = runner.run("wedge", lambda: _t.sleep(10))
-    assert "budget" in out["phase_skipped"]
-    assert out["kv_occupancy"] == {"total_blocks": 1}
-    after_wedge = runner.run("after-wedge", lambda: {"x": 9})
-    assert "prior phase wedged" in after_wedge["phase_skipped"]
-    assert not (tmp_path / "phase_wedge.json").exists()
-    # a completing phase writes its artifact; resume loads it
-    out = bench.PhaseRunner().run("quick", lambda: {"x": 1})
-    assert out["x"] == 1 and (tmp_path / "phase_quick.json").exists()
-    monkeypatch.setenv("BENCH_RESUME", "1")
-    runner2 = bench.PhaseRunner()
-    cached = runner2.run("quick", lambda: {"x": 2})
-    assert cached["x"] == 1 and cached["phase_cached"]
-    # a phase that raises is stamped AND listed as failed (main exits
-    # non-zero on it); it does not stop the phases after it
-    monkeypatch.delenv("BENCH_RESUME", raising=False)
-    runner3 = bench.PhaseRunner()
-
-    def die():
-        raise RuntimeError("kernel refused")
-
-    out = runner3.run("dead", die)
-    assert "RuntimeError: kernel refused" in out["phase_skipped"]
-    assert runner3.run("after", lambda: {"x": 3})["x"] == 3
-    assert runner3.failed == ["dead"] and runner.failed == ["wedge"]
-    assert bench._verdict({"failed_phases": runner3.failed}) == 1
-    assert bench._verdict({"failed_phases": []}) == 0
-    # a phase that spawns replica processes is skipped by name where this
-    # process holds the chip — stated in the output, not a failure
-    own = runner3.run("fabric", die, needs_own_chip=True)
-    assert "a chip belongs to one process" in own["phase_skipped"]
-    assert runner3.failed == ["dead"]
-    # an unknown device kind is an error, not an assumed peak
-    class _Dev:
-        device_kind = "Quantum v9"
-    monkeypatch.setattr(bench.jax, "devices", lambda: [_Dev()])
-    with pytest.raises(ValueError, match="quantum v9"):
-        bench.detect_peak()

@@ -669,6 +669,31 @@ def test_frontend_applies_tier_and_publishes_metrics(model_and_params):
         fe.shutdown(drain=False, timeout=5)
 
 
+def test_frontend_disabled_block_is_byte_identical(model_and_params):
+    """``kv_tier: {enabled: false}`` through the frontend's config is the
+    tier-less stack: no tier on the engine, the same greedy streams as a
+    config that has no ``kv_tier`` block."""
+    from deepspeed_tpu.serving import ServingConfig, ServingFrontend
+
+    model, params = model_and_params
+    reqs = shared_prefix_reqs(np.random.default_rng(16))
+
+    def gens(extra):
+        eng = make_engine(model, params, tier=False, prefix=False)
+        fe = ServingFrontend([eng], ServingConfig(
+            max_queue_depth=64, prefix_cache={"enabled": True},
+            admission={"reservation": True}, **extra))
+        try:
+            assert not eng.state_manager.kv_tier_enabled
+            handles = [fe.submit(p, max_new_tokens=4) for p in reqs]
+            assert fe.wait_all(handles, timeout=120)
+            return [[ev.token for ev in h.drain()] for h in handles]
+        finally:
+            fe.shutdown(drain=False, timeout=5)
+
+    assert gens({"kv_tier": {"enabled": False}}) == gens({})
+
+
 def test_restore_races_cancel_and_deadline(model_and_params):
     """Cancels and deadline expiries racing tier restores must settle
     terminally with the KV pool fully reclaimed — a restored block whose

@@ -103,14 +103,25 @@ def test_warmup_matches_plain_adam(devices8):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6)
 
 
+# LAMB moves each tensor by lr·‖p‖ a step (its trust ratio cancels the
+# update's own norm), so a learning rate sized for Adam (1e-3 moves an
+# element by 1e-3, some 5% of a 0.02-std weight) moves a LAMB layer by 0.1%
+# of its norm: over eight steps plain ``Lamb`` shows the same flat losses
+# as ``OneBitLamb`` did. The Lamb cases run at a learning rate of LAMB's
+# scale, the Adam cases at the Adam one.
+LAMB_LR = 2e-2
+
+
 @pytest.mark.parametrize("opt_type", ["OneBitAdam", "ZeroOneAdam",
                                       "OneBitLamb"])
 def test_compressed_phase_trains(opt_type, devices8):
     """Short warmup then compressed steps: loss keeps decreasing and the
     compiled compressed update moves packed sign bits (u8) through the
     two-phase all_to_all + all_gather wire."""
+    extra = {"lr": LAMB_LR} if opt_type == "OneBitLamb" else {}
     engine, _, _, _ = deepspeed_tpu.initialize(
-        model=build_model("tiny"), config=make_config(opt_type, freeze_step=2))
+        model=build_model("tiny"),
+        config=make_config(opt_type, freeze_step=2, **extra))
     losses = run_steps(engine, tiny_data(), steps=8)
     assert engine._onebit
     assert np.isfinite(losses).all()
@@ -127,6 +138,28 @@ def test_compressed_phase_trains(opt_type, devices8):
         jax.eval_shape(lambda s: s, engine.state)).as_text()
     # warmup phase all-reduces full-precision f32 gradients instead
     assert "i8" not in warm and "all_to_all" not in warm
+
+
+def test_onebit_lamb_follows_plain_lamb(devices8):
+    """The compressed phase applies the trust ratio frozen at the last
+    warm-up step to the bias-corrected compressed momentum, as warm-up
+    did: eight steps of ``OneBitLamb`` (two of them warm-up) end where
+    plain ``Lamb`` does on the same data. Without the momentum's bias
+    correction each layer's step is (1 - b1^t) short and the run ends
+    0.05 above plain Lamb."""
+    from deepspeed_tpu.parallel import topology as topo
+
+    finals = {}
+    for opt_type in ("OneBitLamb", "Lamb"):
+        topo.reset_topology()
+        cfg = make_config(opt_type, freeze_step=2, lr=LAMB_LR)
+        if opt_type == "Lamb":
+            del cfg["optimizer"]["params"]["freeze_step"]
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=build_model("tiny"), config=cfg)
+        finals[opt_type] = run_steps(engine, tiny_data(), steps=8)[-1]
+    assert finals["Lamb"] < 5.5                   # plain Lamb trains here
+    assert finals["OneBitLamb"] < finals["Lamb"] + 0.02, finals
 
 
 def test_packed_wire_bytes_beat_int8(devices8):

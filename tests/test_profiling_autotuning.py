@@ -93,8 +93,9 @@ def test_engine_profile_report(capsys):
 
 
 def test_report_mfu_consistency():
-    """Profiler's achieved TFLOPS must equal step_flops/step_time — the
-    same formula bench.py's MFU uses (agreement by construction)."""
+    """Profiler's achieved TFLOPS must equal step_flops/step_time (the
+    recomputed forward of a rematerialised step is counted: it is not the
+    benchmark's ``mfu``, which counts forward and backward only)."""
     model = build_model("tiny")
     prof = FlopsProfiler(model=model)
     report = prof.profile_report(batch_size=4, seq_len=32, step_time=0.1,

@@ -1,8 +1,8 @@
 """Deterministic serving load test on the CPU mesh (ISSUE 1 acceptance):
 mixed priorities/deadlines through the full stack, an over-capacity burst
 that sheds with Rejected (bounded queue), cancellation that returns KV
-blocks, replica fault degradation, and registry-sourced telemetry — the
-same numbers bench.py's serving phase reports."""
+blocks, replica fault degradation, and registry-sourced telemetry (the
+numbers ``ServingFrontend.metrics_snapshot`` hands an operator)."""
 
 import time
 
@@ -194,8 +194,10 @@ def test_shutdown_drain_completes_inflight():
     assert ei.value.reason == "draining"
 
 
-def test_bench_frontend_metrics_shape():
-    """bench.py's serving phase consumes exactly these registry keys."""
+def test_frontend_metrics_snapshot_keys():
+    """The registry keys an operator's dashboard reads are in the
+    snapshot after a burst that sheds, with the TTFT histogram's
+    percentiles beside them."""
     fe = ServingFrontend([tiny_engine()], ServingConfig(max_queue_depth=4))
     try:
         rng = np.random.default_rng(9)

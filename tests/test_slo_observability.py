@@ -640,7 +640,7 @@ class TestServingE2E:
             fe.shutdown(drain=False, timeout=5)
 
     def test_latency_fault_fires_and_resolves_alert(self):
-        """The bench slo phase's core story as a tier-1 test: a
+        """The alerting story end to end: a
         slow_forward fault inflates interactive TTFT past the target,
         the burn-rate alert fires (gauge + journal), and once the fault
         clears and fresh traffic repopulates the fast window it
@@ -684,6 +684,26 @@ class TestServingE2E:
             assert rep["slo"]["slo_ttft_interactive"]["fire_count"] == 1
         finally:
             fe.shutdown(drain=False, timeout=5)
+
+    def test_disabled_slo_block_is_byte_identical(self):
+        """``slo: {enabled: false}`` builds no alert engine and serves the
+        greedy streams of a config with no ``slo:`` block at all."""
+        from deepspeed_tpu.serving import ServingConfig, ServingFrontend
+
+        ps = prompts(6, 11)
+
+        def gens(extra):
+            fe = ServingFrontend([tiny_engine()],
+                                 ServingConfig(max_queue_depth=16, **extra))
+            try:
+                assert fe.alerts is None
+                hs = [fe.submit(p, max_new_tokens=6) for p in ps]
+                assert fe.wait_all(hs, timeout=120)
+                return [[ev.token for ev in h.drain()] for h in hs]
+            finally:
+                fe.shutdown(drain=False, timeout=5)
+
+        assert gens({"slo": {"enabled": False}}) == gens({})
 
     def test_windowed_ring_fed_by_router_tick(self):
         from deepspeed_tpu.serving import ServingConfig, ServingFrontend

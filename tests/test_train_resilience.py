@@ -6,7 +6,8 @@ fault injector kills/wedges/poisons a supervised train run at scripted
 steps and the suite asserts recovery — including the hard contract that
 an interrupted+resumed run reproduces the uninterrupted loss curve
 byte-for-byte and lands on identical final params.
-`TIER1_CHAOS_TRAIN=1 scripts/tier1.sh` smokes exactly this file.
+`TIER1_ARGS=tests/test_train_resilience.py bash scripts/tier1.sh` runs
+exactly this file.
 """
 
 import os

@@ -169,8 +169,9 @@ def test_bounded_divergence_and_logit_error(model_and_params, wdtype):
 
 def test_perplexity_delta_gate(model_and_params):
     """Teacher-forced perplexity of the int8-weight engine within 1% of
-    the full-precision engine (the bench weight_quant phase's gate, in
-    miniature) — and the verify_width path rides the quantized tree."""
+    the full-precision engine (the quality gate of docs/SERVING.md
+    "Weight quantization"), and the verify_width path rides the
+    quantized tree."""
     model, params = model_and_params
     rng = np.random.default_rng(3)
     toks = rand_prompt(rng, 64)
