@@ -105,6 +105,17 @@ class RaggedInferenceEngineConfig:
         self.compile_ahead = int(compile_ahead)
 
 
+#: The most positions ``S * C`` of a dense put's bucket that still run as
+#: one forward. A dense forward streams every weight once, whatever its
+#: shape, and multiplies each with every position: on a v5e (197 TFLOP/s
+#: over 819 GB/s of bf16) the stream is the cost up to about 240 positions
+#: -- one *weight pass* -- and the positions are beyond it. A chunk row
+#: beside one-token rows costs two passes as two forwards and its
+#: ``S * C`` positions as one padded forward: the two meet at two passes'
+#: worth (the chip's readings: ``_forward_groups``).
+_JOINT_POSITIONS = 512
+
+
 class _RowLogits:
     """The logits of a put that ran as several forwards: the parts stay
     on the device, and are glued on the host, in the put's row order, when
@@ -227,6 +238,9 @@ class InferenceEngineV2:
             self.config.max_ragged_sequence_count,
             self.config.max_chunk_tokens, max_blocks_per_seq,
             min_chunk=gated_delta.TILE if cfg.is_hybrid else 1)
+        # the most bucket positions a put runs as one forward
+        # (``_forward_groups``); a hybrid model's chunk rows never share one
+        self._joint_positions = 0 if cfg.is_hybrid else _JOINT_POSITIONS
         # what the last put staged, counted where the work happens (plain
         # ints; the scheduler copies them into its span attrs when traced):
         # the bucket [S, C] the forward ran at, its real rows and valid
@@ -240,19 +254,20 @@ class InferenceEngineV2:
         # the same, cumulative since the engine was built (pad ratio over
         # any interval = delta positions_computed / delta tokens_valid)
         self.put_totals: Dict[str, int] = {
-            "forwards": 0, "positions_computed": 0, "tokens_valid": 0}
+            "forwards": 0, "positions_computed": 0, "tokens_valid": 0,
+            "puts_split": 0}        # puts that ran as several forwards
         if cfg.is_hybrid:       # its sparse FFNs' rows (_count_routing)
             self.put_totals.update(moe_rows_routed=0, moe_rows_held=0)
         self._forward_jit = self.paged.forward
         self._compile_ahead()
 
     def forward_shapes(self) -> List[Tuple[int, int]]:
-        """Every ``[S, C]`` a put's forward can be: the batch's buckets; of
-        a hybrid model's only ``[1, C]`` and ``[S, 1]``
-        (``_forward_groups``)."""
+        """Every ``[S, C]`` a put's forward can be: ``[1, C]`` and
+        ``[S, 1]``, and the joint buckets ``_forward_groups`` leaves whole
+        (a hybrid model has none)."""
         seqs, chunks = self.batch.buckets()
         return [(s, c) for s in seqs for c in chunks
-                if not self.model.cfg.is_hybrid or s == 1 or c == 1]
+                if s == 1 or c == 1 or s * c <= self._joint_positions]
 
     def _compile_ahead(self) -> None:
         """Lower the forward at every shape of ``forward_shapes``, for the
@@ -372,10 +387,11 @@ class InferenceEngineV2:
           content that a later ``trim_sequence`` rolls back. The caller
           commits the accepted prefix afterwards via :meth:`commit_tokens`.
         """
-        status = self.can_schedule(uids, [len(t) for t in tokens_list])
+        widths = [len(t) for t in tokens_list]
+        status = self.can_schedule(uids, widths)
         if status != SchedulingResult.Success:
             raise SchedulingError(status)
-        groups = self._forward_groups(tokens_list)
+        groups = self._forward_groups(widths, verify_width)
         if len(groups) == 1:
             return self._forward_rows(uids, tokens_list, verify_width,
                                       defer_commit)
@@ -393,24 +409,44 @@ class InferenceEngineV2:
         self.last_put = dict(records[-1], forwards=len(records), **{
             k: sum(r[k] for r in records) for k in summed
             if k in records[-1]})
+        self.put_totals["puts_split"] += 1
         return _RowLogits(outs, [i for rows in groups for i in rows])
 
-    def _forward_groups(self, tokens_list) -> List[List[int]]:
-        """Which rows of a put run together in one forward. The forward
-        pads its batch to an ``[S, C]`` bucket, so a chunk row beside
-        S - 1 one-token rows costs S times its own work in every mixer.
-        For a hybrid model that is most of a step (measured on the chip at
+    def _forward_groups(self, widths: Sequence[int],
+                        verify_width: int = 0) -> List[List[int]]:
+        """Which rows of a put run together in one forward, from their
+        token counts. The forward pads its batch to an ``[S, C]`` bucket,
+        so a chunk row beside S - 1 one-token rows costs S times its own
+        work in every mixer and every matmul. Past a limit of ``S * C``
+        positions the rows wider than one token therefore run each as a
+        forward of its own (``[1, C]``) and the one-token rows together
+        (``[S, 1]``, first); the parts' logits meet at the scheduler's one
+        fetch (``_RowLogits``). The rule reads the bucket alone, not which
+        rows fill it: whoever has put every ``[S, C]`` once with one wide
+        row has run every program a later put can reach
+        (``forward_shapes``).
+
+        A hybrid model's limit is zero (measured on the chip at
         Qwen3-Next's widths, 8k of context: ``[8, 1024]`` 116 ms against
-        ``[1, 1024]`` 45 ms + ``[8, 1]`` 4 ms), so its rows wider than
-        one token run each as a forward of its own and the one-token rows
-        together. Every other model keeps the one forward a put (the
-        same split for them is ROADMAP S3's to measure)."""
-        everyone = [list(range(len(tokens_list)))]
-        if not self.model.cfg.is_hybrid:
-            return everyone
-        wide = [i for i, t in enumerate(tokens_list) if len(t) > 1]
-        ones = [i for i, t in enumerate(tokens_list) if len(t) == 1]
-        if not wide or (len(wide) == 1 and not ones):
+        ``[1, 1024]`` 45 ms + ``[8, 1]`` 4 ms). A dense model's is
+        ``_JOINT_POSITIONS`` (measured on the chip, PR 33: one chunk row
+        beside S - 1 one-token rows at 600 / 1,000 tokens of context, ms
+        as one forward / apart -- Mistral-7B's 11 layers ``[32, 256]``
+        257 / 19.9, ``[4, 256]`` 32.4 / 17.7, ``[32, 32]`` 34.0 / 18.1,
+        ``[2, 256]`` 17.4 / 16.7, ``[32, 16]`` 19.4 / 18.0, ``[4, 64]``
+        10.1 / 16.0; Pythia-1.4B ``[4, 256]`` 25.9 / 14.6, ``[2, 256]``
+        13.6 / 12.5, ``[2, 128]`` 7.7 / 10.3): at the limit the two are
+        within a tenth on the device and the one forward spares the host
+        a stage; at half of it one forward wins by a third, at twice it
+        the parts win by half. A put that verifies drafts stays whole (a
+        one-token group has no ``verify_width`` positions), and so does
+        one wide row alone."""
+        everyone = [list(range(len(widths)))]
+        wide = [i for i, n in enumerate(widths) if n > 1]
+        ones = [i for i, n in enumerate(widths) if n == 1]
+        seqs, chunk = self.batch.bucket(len(widths), max(widths))
+        if not wide or (len(wide) == 1 and not ones) or verify_width \
+                or seqs * chunk <= self._joint_positions:
             return everyone
         return ([ones] if ones else []) + [[i] for i in wide]
 

@@ -82,6 +82,13 @@ class RaggedBatchWrapper:
             b *= 2
         return min(b, cap)
 
+    def bucket(self, n_seqs: int, widest: int) -> Tuple[int, int]:
+        """The ``[S, C]`` that ``finalize`` trims ``n_seqs`` rows to, the
+        widest of them ``widest`` tokens."""
+        S = self._bucket(max(n_seqs, 1), self.max_seqs)
+        C = self._bucket(max(widest, 1), self.max_chunk)
+        return S, (max(C, self.min_chunk) if C > 1 else C)
+
     def finalize(self, bucketed: bool = True) -> Dict[str, np.ndarray]:
         """Device-ready arrays (the reference's pinned-buffer upload).
 
@@ -95,10 +102,7 @@ class RaggedBatchWrapper:
                 "n_tokens": self.n_tokens,
                 "block_tables": self.block_tables,
             }
-        S = self._bucket(max(len(self.uids), 1), self.max_seqs)
-        C = self._bucket(max(int(self.n_tokens.max()), 1), self.max_chunk)
-        if C > 1:
-            C = max(C, self.min_chunk)
+        S, C = self.bucket(len(self.uids), int(self.n_tokens.max()))
         return {
             "tokens": self.tokens[:S, :C],
             "start_pos": self.start_pos[:S],

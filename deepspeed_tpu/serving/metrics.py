@@ -385,8 +385,11 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               "spec_tokens_emitted", "spec_decode_forwards",
               # what the engine's forwards computed (engine.put_totals,
               # delta-published per Replica): pad ratio over an interval =
-              # delta positions_computed / delta tokens_valid
+              # delta positions_computed / delta tokens_valid; puts_split
+              # = the puts whose chunk rows and one-token rows ran as
+              # forwards of their own (engine._forward_groups)
               "forwards", "positions_computed", "tokens_valid",
+              "puts_split",
               # a hybrid model's sparse FFNs: (token, choice) pairs routed
               # and, of those, the pairs whose expert this replica holds
               # (the expectation under even routing: engine._count_routing)

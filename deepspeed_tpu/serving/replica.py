@@ -585,7 +585,7 @@ class Replica:
                       ("restored", "kv_tier_blocks_restored"),
                       ("dropped", "kv_tier_blocks_dropped"))
     _PUT_COUNTERS = ("forwards", "positions_computed", "tokens_valid",
-                     "moe_rows_routed", "moe_rows_held")
+                     "puts_split", "moe_rows_routed", "moe_rows_held")
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),
                          ("resumed", "sequences_resumed"))
 
@@ -606,7 +606,8 @@ class Replica:
                     self.metrics.counter(name).inc(delta)
             self._prefix_last = stats
         # what the forwards computed against what was asked of them: pad
-        # ratio over any interval = delta positions / delta valid tokens
+        # ratio over any interval = delta positions / delta valid tokens;
+        # puts_split = the puts that ran as more than one forward
         totals = getattr(self.engine, "put_totals", None)
         if totals is not None:
             for name in self._PUT_COUNTERS:

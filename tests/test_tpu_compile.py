@@ -142,8 +142,11 @@ def test_paged_attention(v5e, pool, chunk):
     (32, 1, 32, 8, 128, 64, 1152, 4096), (32, 256, 32, 8, 128, 64, 1152, 4096),
     (8, 1, 16, 2, 256, 512, 4096, 0), (1, 256, 16, 2, 256, 512, 4096, 0),
     (32, 1, 8, 2, 128, 64, 1152, 4096),
+    # a chunk row as a forward of its own (what the batch cell's prefill
+    # runs at); ``forward_verify`` still reaches [32, 256]
+    (1, 256, 32, 8, 128, 64, 1152, 4096),
 ], ids=["mistral_decode", "mistral_chunk", "qwen3_next_decode",
-        "qwen3_next_piece", "mistral_decode_tp4"])
+        "qwen3_next_piece", "mistral_decode_tp4", "mistral_chunk_alone"])
 def test_paged_attention_at_the_cells_geometries(v5e, geometry):
     """The tiles ``_tiles`` picks for each (heads a step, blocks a turn)
     fit the chip's scoped VMEM: the estimate in ``_step_bytes`` is held to
