@@ -116,6 +116,24 @@ class RaggedInferenceEngineConfig:
 _JOINT_POSITIONS = 512
 
 
+#: keys of ``engine.last_put`` that ride on the scheduler's ``forward``
+#: span only (by prefix): ``stage`` keeps the keys the benchmark's wrapper has
+FORWARD_ONLY = ("kv_blocks_live", "kv_table_slots", "kv_blocks_released",
+                "kv_bytes_", "kv_g")
+
+
+def _keys_and_pairs(window: int, seen: int, n: int) -> Tuple[int, int]:
+    """The keys a chunk of ``n`` tokens from position ``seen`` reads and
+    the query-key pairs it multiplies, under ``window`` (0: the whole
+    context): query i sees ``min(seen + i + 1, window)`` keys, and the
+    chunk reads from the first key its first query sees."""
+    if not window:
+        return seen + n, n * seen + n * (n + 1) // 2
+    short = max(0, min(n, window - seen))       # queries short of a window
+    return (seen + n - max(seen - window + 1, 0),
+            short * seen + short * (short + 1) // 2 + (n - short) * window)
+
+
 class _RowLogits:
     """The logits of a put that ran as several forwards: the parts stay
     on the device, and are glued on the host, in the put's row order, when
@@ -237,7 +255,8 @@ class InferenceEngineV2:
         self.batch = RaggedBatchWrapper(
             self.config.max_ragged_sequence_count,
             self.config.max_chunk_tokens, max_blocks_per_seq,
-            min_chunk=gated_delta.TILE if cfg.is_hybrid else 1)
+            min_chunk=gated_delta.TILE if cfg.is_hybrid else 1,
+            groups=len(self.state_manager.groups))
         # the most bucket positions a put runs as one forward
         # (``_forward_groups``); a hybrid model's chunk rows never share one
         self._joint_positions = 0 if cfg.is_hybrid else _JOINT_POSITIONS
@@ -258,6 +277,9 @@ class InferenceEngineV2:
             "puts_split": 0}        # puts that ran as several forwards
         if cfg.is_hybrid:       # its sparse FFNs' rows (_count_routing)
             self.put_totals.update(moe_rows_routed=0, moe_rows_held=0)
+        if any(g.window for g in self.state_manager.groups):
+            # blocks handed back behind a window while their sequence lived
+            self.put_totals["kv_blocks_released"] = 0
         self._forward_jit = self.paged.forward
         self._compile_ahead()
 
@@ -285,9 +307,12 @@ class InferenceEngineV2:
         sm = self.state_manager
         width = self.batch.max_blocks_per_seq
 
+        groups = len(sm.groups)
+
         def lowered(shape):
             s = shape[0]
-            ints = [shape, (s,), (s,), (s, width)] + \
+            ints = [shape, (s,), (s,),
+                    (s, width) if groups == 1 else (groups, s, width)] + \
                 ([(s,)] if sm.recurrent else [])
             return jitted.lower(self.params, sm.forward_cache, *(
                 jax.ShapeDtypeStruct(i, jnp.int32) for i in ints))
@@ -325,7 +350,25 @@ class InferenceEngineV2:
             kv_tier_disk_bytes=self.config.kv_tier_disk_bytes,
             # a hybrid model: one recurrent-state slot a sequence the
             # scheduler can have running
-            state_slots=self.config.max_ragged_sequence_count)
+            state_slots=self.config.max_ragged_sequence_count,
+            group_blocks=[self.window_pool_blocks(window) for window, _
+                          in self.model.cfg.kv_groups()[1:]])
+
+    def window_pool_blocks(self, window: int) -> int:
+        """The pool of a further layer group (``kv_blocks`` sizes the
+        first, whose K/V lives longest): the most blocks the group's
+        sequences can hold at once — between puts a sequence keeps the
+        blocks of its last ``window - 1`` positions (``window / bs + 1``
+        at most), a put adds a block for every ``bs`` tokens of its
+        budget and one a row, and ``max_ragged_sequence_count`` sequences
+        run — so that the group is never the one that runs out. Capped
+        at ``kv_blocks``: no group outgrows the first."""
+        c = self.config
+        if not window:
+            return c.kv_blocks
+        bs, seqs = c.kv_block_size, c.max_ragged_sequence_count
+        return min(c.kv_blocks, seqs * (-(-window // bs) + 2)
+                   + -(-c.max_ragged_batch_size // bs))
 
     # ----------------------------------------------------------- admission
     def can_schedule(self, uids: Sequence[int],
@@ -349,7 +392,8 @@ class InferenceEngineV2:
             blocks_needed += max(0, need - have)
         # available = free + LRU-evictable cached blocks (identical to the
         # free count when the prefix cache is disabled)
-        if blocks_needed > self.state_manager.available_blocks:
+        if blocks_needed > self.state_manager.available_blocks \
+                or self.state_manager.groups_short(blocks_needed):
             return SchedulingResult.KVCacheLimitExceeded
         if self.state_manager.recurrent and \
                 slots_needed > self.state_manager.free_state_slots:
@@ -405,7 +449,9 @@ class InferenceEngineV2:
         # of what its forwards counted, and how many they were
         summed = ("rows", "valid_tokens", "kv_read_tokens", "qk_pairs",
                   "kv_blocks_live", "kv_table_slots",
-                  "moe_rows_routed", "moe_rows_held")
+                  "moe_rows_routed", "moe_rows_held", "kv_blocks_released") \
+            + tuple(k for k in records[-1] if k.endswith(("_read_tokens",
+                                                          "_qk_pairs")))
         self.last_put = dict(records[-1], forwards=len(records), **{
             k: sum(r[k] for r in records) for k in summed
             if k in records[-1]})
@@ -455,50 +501,68 @@ class InferenceEngineV2:
         """One forward over ``uids``' rows (``put``'s body)."""
         self.batch.clear()
         staged = []
+        sm = self.state_manager
+        groups = sm.groups
         valid = kv_read = qk_pairs = blocks_live = 0
+        # by layer group, under its window: the keys the rows' queries may
+        # see and the query-key pairs (what its layers' kernel calls read
+        # and multiply), counted where a window can bound them
+        group_read, group_pairs = [0] * len(groups), [0] * len(groups)
         block_size = self.config.kv_block_size
         for uid, toks in zip(uids, tokens_list):
-            seq = self.state_manager.get_or_create_sequence(uid)
-            self.state_manager.maybe_allocate_kv(seq, len(toks))
-            self.batch.insert_sequence(uid, list(toks), seq.seen_tokens,
-                                       seq.kv_blocks)
+            seq = sm.get_or_create_sequence(uid)
+            sm.maybe_allocate_kv(seq, len(toks))
+            rows = sm.table_rows(seq)
+            self.batch.insert_sequence(
+                uid, toks, seq.seen_tokens,
+                rows[0] if len(groups) == 1 else rows)
             staged.append((seq, toks))
             n, seen = len(toks), seq.seen_tokens
             valid += n
-            kv_read += seen + n
-            qk_pairs += n * seen + n * (n + 1) // 2
-            blocks_live += -(-(seen + n) // block_size)
+            read, pairs = _keys_and_pairs(0, seen, n)
+            kv_read += read
+            qk_pairs += pairs
+            last = -(-(seen + n) // block_size)
+            for g, group in enumerate(groups):
+                # the blocks the kernel's walk covers: from the window's
+                # first live block to the context's last
+                blocks_live += last - sm.first_live_block(group.window, seen)
+                read, pairs = _keys_and_pairs(group.window, seen, n)
+                group_read[g] += read
+                group_pairs[g] += pairs
 
         arrays = self.batch.finalize()
         bucket_seqs, bucket_chunk = arrays["tokens"].shape
-        sm = self.state_manager
         self.last_put = {
             "bucket_seqs": bucket_seqs, "bucket_chunk": bucket_chunk,
             "rows": len(staged), "valid_tokens": valid,
             "kv_read_tokens": kv_read, "qk_pairs": qk_pairs,
             "kv_blocks_live": blocks_live,
-            "kv_table_slots": bucket_seqs * arrays["block_tables"].shape[1],
+            "kv_table_slots": bucket_seqs * len(groups)
+            * arrays["block_tables"].shape[-1],
             "free_blocks": sm.available_blocks}
         totals = self.put_totals
         totals["forwards"] += 1
         totals["positions_computed"] += bucket_seqs * bucket_chunk
         totals["tokens_valid"] += valid
         kv_cache = sm.forward_cache
-        args = (self.params, kv_cache,
-                jnp.asarray(arrays["tokens"]),
-                jnp.asarray(arrays["start_pos"]),
-                jnp.asarray(arrays["n_tokens"]),
-                jnp.asarray(arrays["block_tables"]))
+        # the host's arrays go to the jitted call as they are: its own
+        # argument path moves them in a fifth of the time four
+        # ``jnp.asarray`` calls take (the wrapper makes new ones a forward,
+        # so none is written again behind the transfer)
+        args = (self.params, kv_cache, arrays["tokens"],
+                arrays["start_pos"], arrays["n_tokens"],
+                arrays["block_tables"])
         if sm.recurrent:
             # each row's slot in the state tree; a padded row's is the
             # scratch slot behind the last
             slots = np.full((bucket_seqs,), sm.state_slots, np.int32)
             slots[:len(staged)] = [seq.state_slot for seq, _ in staged]
-            args += (jnp.asarray(slots),)
-            # a hybrid model's put says two things more: its slots in use
-            # and its sparse FFNs' rows
+            args += (slots,)
+            # a hybrid model's put says its slots in use
             self.last_put["state_slots_used"] = \
                 sm.state_slots - sm.free_state_slots
+        if self.model.cfg.is_hybrid:    # and its sparse FFNs' rows
             self._count_routing(valid)
         # the forward consumes ``kv_cache`` (donated, written in place) and
         # hands the same memory back as ``new_cache``
@@ -520,16 +584,56 @@ class InferenceEngineV2:
         # forward that fails before dispatch leaves the pool and
         # seen_tokens unchanged (the step can be retried) and — critically
         # — never registers blocks whose KV was never written in the
-        # prefix-cache index. Allocation above is safe either way: the
-        # blocks belong to the sequence and return to the pool at flush.
-        # (Assumes each uid appears at most once per batch, which the
-        # scheduler guarantees.)
+        # prefix-cache index, and hands no block back. Allocation above is
+        # safe either way: the blocks belong to the sequence and return to
+        # the pool at flush. (Assumes each uid appears at most once per
+        # batch, which the scheduler guarantees.)
         sm.forward_cache = new_cache
+        released = 0
         for seq, toks in staged:
             seq.seen_tokens += len(toks)
             if not defer_commit:
-                self.state_manager.record_tokens(seq, toks)
-        return logits[:len(uids)]
+                sm.record_tokens(seq, toks)
+                # the blocks now wholly behind a window belong to no later
+                # query (a put that verifies drafts may yet be trimmed: its
+                # release waits for ``commit_tokens``)
+                released += sm.release_behind(seq)
+        self._record_groups(released, group_read, group_pairs)
+        rows = logits[:len(uids)]
+        # the copy back is asked for now and follows the forward on the
+        # device's own queue: the scheduler's fetch then waits once, for
+        # the bytes, and not first for the program and then for the copy
+        start_copy = getattr(rows, "copy_to_host_async", None)
+        if start_copy is not None:
+            start_copy()
+        return rows
+
+    def _record_groups(self, released: int, group_read, group_pairs) -> None:
+        """The put's record by layer group, for a model that keeps more
+        than one and for any model once a block has been handed back (a
+        put inside its window keeps the record it had, key for key):
+        blocks handed back by this put, each group's pool blocks in use
+        of its total, the K/V bytes resident beside what the same
+        sequences would hold unreleased, and each group's window-bounded
+        keys and pairs."""
+        sm = self.state_manager
+        self._count_released(released)
+        if len(sm.groups) == 1 and not sm.blocks_released:
+            return
+        record = {"kv_blocks_released": released, **sm.resident_bytes()}
+        for g, group in enumerate(sm.groups):
+            alloc = group.allocator
+            record.update({f"kv_g{g}_window": group.window,
+                           f"kv_g{g}_in_use": alloc.total_blocks
+                           - alloc.free_blocks,
+                           f"kv_g{g}_total": alloc.total_blocks,
+                           f"kv_g{g}_read_tokens": group_read[g],
+                           f"kv_g{g}_qk_pairs": group_pairs[g]})
+        self.last_put.update(record)
+
+    def _count_released(self, released: int) -> None:
+        if "kv_blocks_released" in self.put_totals:
+            self.put_totals["kv_blocks_released"] += released
 
     def _count_routing(self, valid_tokens: int) -> None:
         """``moe_rows_routed`` / ``moe_rows_held`` of a hybrid model's
@@ -539,7 +643,7 @@ class InferenceEngineV2:
         under even routing (routed x held / experts): the real count
         lives on the device and is not fetched."""
         cfg = self.model.cfg
-        routed = valid_tokens * cfg.moe_top_k * cfg.num_layers
+        routed = valid_tokens * cfg.moe_top_k * cfg.num_sparse_layers
         held = cfg.moe_held_experts[1] if cfg.moe_held_experts \
             else cfg.moe_num_experts
         counts = {"moe_rows_routed": routed,
@@ -567,6 +671,8 @@ class InferenceEngineV2:
         seq = self.state_manager.get_sequence(uid)
         if seq is not None:
             self.state_manager.record_tokens(seq, tokens)
+            # the release a put that deferred its commit left undone
+            self._count_released(self.state_manager.release_behind(seq))
 
     # ----------------------------------------------------------- KV handoff
     def export_sequence(self, uid: int,
@@ -692,6 +798,8 @@ class InferenceEngineV2:
         sm = self.state_manager
         if enabled and sm.recurrent:
             sm.refuse_recurrent("the prefix cache")
+        if enabled and len(sm.groups) > 1:
+            sm.refuse_grouped("the prefix cache")
         self.config.enable_prefix_cache = bool(enabled)
         self.config.prefix_cache_max_blocks = max_blocks
         if enabled:
@@ -776,6 +884,8 @@ class InferenceEngineV2:
             from .kv_quant import validate_kv_quant
 
             validate_kv_quant(dtype, scale_granularity)
+            if len(self.state_manager.groups) > 1:
+                self.state_manager.refuse_grouped("quantized KV pools")
         self.config.kv_quant_enabled = bool(enabled)
         self.config.kv_quant_dtype = dtype
         self.config.kv_quant_scale_granularity = scale_granularity

@@ -307,12 +307,19 @@ class PagedCausalLM:
     def _forward_hybrid(self, params, cache, tokens, start_pos, n_tokens,
                         block_tables, state_slots, verify_width: int = 0):
         """The forward of a hybrid block (``cfg.layer_pattern``,
-        models/hybrid.py): one scan over the periods, the period's layers
-        in order inside the body. ``cache`` holds two kinds of state and
-        both ride in the carry, donated and written in place:
+        models/hybrid.py): the lead layers, then one scan over the
+        periods, the period's layers in order inside the body. ``cache``
+        holds two kinds of state and both ride in the carry, donated and
+        written in place:
 
-        - ``k``/``v`` [L_attn, NB, KH, bs, D]: the paged pool of the
-          attention layers only; ``layer`` counts those.
+        - the paged pools of the attention layers, one a group of layers
+          whose K/V has one lifetime (``cfg.kv_groups()``): ``k``/``v``
+          [L_0, NB_0, KH, bs, D] the first group's, ``k1``/``v1`` the
+          second's — the layers of a window, whose blocks behind it the
+          manager hands back while the sequence lives. ``layer`` counts a
+          group's own layers, and ``block_tables`` is [N, MB] for one
+          group, [G, N, MB] for several: a group's write plan and its
+          kernel's walk read its own table.
         - ``ssm`` [L_lin, slots + 1, HV, DK, DV] float32 and ``conv``
           [L_lin, slots + 1, K-1, CH]: the recurrent layers' state, one
           slot a sequence (``state_slots`` [N]; padded rows point at the
@@ -326,27 +333,38 @@ class PagedCausalLM:
         if verify_width:
             raise hybrid.RecurrentStateUnsupported(
                 "speculative verification rolls rejected tokens back; a "
-                "recurrent state cannot be cut at a token")
+                "hybrid block's recurrent state cannot be cut at a token "
+                "and its window layers' blocks may be gone")
         cfg = self.cfg
         N, C = tokens.shape
         bs = self.block_size
-        NB = cache["k"].shape[1]
         dt = cfg.dtype
         scope = jax.named_scope
-        pattern = cfg.layer_pattern
-        n_full, n_lin = pattern.count("full"), pattern.count("linear")
+        pattern, lead = cfg.layer_pattern, cfg.lead_layers
         kvh, hd = cfg.kv_heads, cfg.head_dim
+        # which group an attention kind's layers write and read: its
+        # window's (``kv_groups``: the whole context is window 0)
+        windows = [w for w, _ in cfg.kv_groups()]
+        group_of = {kind: windows.index(cfg.sliding_window
+                                        if kind == "window" else 0)
+                    for kind in set(pattern + lead) & set(hybrid.ATTN_SCOPE)}
+        tables = [block_tables] if block_tables.ndim == 2 \
+            else list(block_tables)
 
         with scope("embed"):
             x = params["embed"]["wte"][tokens].astype(dt)      # [N, C, H]
+            if cfg.embed_scale != 1.0:
+                x = x * jnp.asarray(cfg.embed_scale, dt)
             positions = start_pos[:, None] + jnp.arange(C)[None, :]
             cos_full, sin_full = rope_table(cfg.max_seq_len, cfg.rot_dim,
                                             cfg.rope_theta)
             cos, sin = cos_full[positions], sin_full[positions]
         quant = "k_scale" in cache
         with scope("kv_write"):
-            kv_plan = touched_block_plan(block_tables, start_pos, n_tokens,
-                                         C, bs, NB)
+            plans = [touched_block_plan(
+                t, start_pos, n_tokens, C, bs,
+                cache["k" + ("" if g == 0 else str(g))].shape[1])
+                for g, t in enumerate(tables)]
 
         def rope(t):
             return apply_rope(t, cos, sin, cfg.rope_interleaved)
@@ -355,35 +373,46 @@ class PagedCausalLM:
         max_rows = min(N * C, self.max_batch_tokens or N * C)
         fresh = start_pos == 0
 
-        def period(carry, xs):
-            x, pools = carry
-            slots, p = xs
-            pools = dict(pools)         # the mixers below write into it
+        def mixers_for(pools, first_layer):
+            """The mixers of one run of layers over ``pools`` (written
+            into); ``first_layer[kind]``: where the run's first layer of
+            a kind sits among its group's (or the recurrent) layers."""
+            def attention_mixer(kind):
+                g = group_of[kind]
+                sfx = str(g) if g else ""
+                turn = rope if hybrid.rotates(cfg, kind) else (lambda t: t)
+                window = cfg.sliding_window if kind == "window" else 0
 
-            def full_mixer(h1, lp, i):
-                layer = p * n_full + i
-                with scope("qkv"):
-                    q, k, v, gate = hybrid.full_qkv(cfg, h1, lp, rope)
-                with scope("kv_write"):
-                    for name, rows in (("k", k), ("v", v)):
-                        rows = rows.reshape(-1, kvh, hd)
-                        if quant:
-                            sname = name + "_scale"
-                            pools[name], pools[sname] = \
-                                quantized_block_write(
-                                    pools[name], pools[sname], rows,
-                                    kv_plan, layer)
-                        else:
-                            pools[name] = block_write(pools[name], rows,
-                                                      kv_plan, layer)
-                with scope("attend"):
-                    attn = self._attend(q, pools, layer, block_tables,
-                                        start_pos, n_tokens, None)
-                with scope("attn_out"):
-                    return hybrid.full_out(cfg, attn, gate, lp)
+                def mixer(h1, lp, i):
+                    layer = first_layer[kind] + i
+                    with scope("qkv"):
+                        q, k, v, gate = hybrid.full_qkv(cfg, h1, lp, turn)
+                    with scope("kv_write"):
+                        for name, rows in (("k" + sfx, k), ("v" + sfx, v)):
+                            rows = rows.reshape(-1, kvh, hd)
+                            if quant:
+                                sname = name + "_scale"
+                                pools[name], pools[sname] = \
+                                    quantized_block_write(
+                                        pools[name], pools[sname], rows,
+                                        plans[g], layer)
+                            else:
+                                pools[name] = block_write(
+                                    pools[name], rows, plans[g], layer)
+                    with scope("attend"):
+                        attn = self._attend(
+                            q, {"k": pools["k" + sfx], "v": pools["v" + sfx],
+                                **({"k_scale": pools["k_scale"],
+                                    "v_scale": pools["v_scale"]}
+                                   if quant else {})},
+                            layer, tables[g], start_pos, n_tokens, None,
+                            window=window)
+                    with scope("attn_out"):
+                        return hybrid.full_out(cfg, attn, gate, lp)
+                return mixer
 
             def linear_mixer(h1, lp, i):
-                layer = p * n_lin + i
+                layer = first_layer["linear"] + i
                 with scope("linear_attn"):
                     tail = pools["conv"][layer, state_slots]
                     state = pools["ssm"][layer, state_slots]
@@ -397,15 +426,30 @@ class PagedCausalLM:
                         layer, state_slots].set(state)
                     return y
 
-            x, _ = hybrid.run_period(cfg, x, slots, full_mixer, linear_mixer,
+            return dict({kind: attention_mixer(kind) for kind in group_of},
+                        linear=linear_mixer)
+
+        def period(carry, xs):
+            x, pools = carry
+            slots, p = xs
+            pools = dict(pools)         # the mixers below write into it
+            first = {kind: lead.count(kind) + p * pattern.count(kind)
+                     for kind in hybrid.KINDS}
+            x, _ = hybrid.run_period(cfg, x, slots, mixers_for(pools, first),
                                      valid=valid, max_rows=max_rows)
             return (x, pools), None
 
         slots = tuple(params["layers"][f"slot{i}"]
                       for i in range(len(pattern)))
         with scope("layers"):
+            pools = dict(cache)
+            if lead:
+                x, _ = hybrid.run_period(
+                    cfg, x, hybrid.lead_slots(cfg, params),
+                    mixers_for(pools, {kind: 0 for kind in hybrid.KINDS}),
+                    kinds=lead, dense=True)
             (x, new_cache), _ = lax.scan(
-                period, (x, dict(cache)),
+                period, (x, pools),
                 (slots, jnp.arange(cfg.num_periods, dtype=jnp.int32)))
         with scope("final_norm"):
             x = hybrid.block_norm(cfg, x, params["final_norm"]["w"])

@@ -8,6 +8,22 @@ device-side paged cache tensors [L, num_blocks, KH, block_size, D] (the
 per-(block, kv-head) slab is the trailing [block_size, D] — the layout the
 Pallas paged-attention index maps depend on, ops/paged_attention.py).
 
+KV by layer group (docs/SERVING.md "The pool contract"): layers whose K/V
+has one lifetime — the whole context, or the last ``window`` positions —
+are a group (``TransformerConfig.kv_groups``) with a pool, an allocator
+and a block table a sequence of their own. The tables are indexed alike,
+by the position's block; a window group's blocks that lie wholly behind
+every position a later query can attend go back to their free list after
+the put that passes them (``release_behind``), their table entries turn
+-1, and neither the write plan nor the kernel's walk, which starts at the
+window's first live block, reads an entry behind it. The first group is
+the one whose K/V lives longest: ``num_blocks`` is its pool's size, and
+``free_blocks`` / ``available_blocks`` / the reservation ledger count its
+blocks; a further group is sized so that it is never the one that runs
+out (``group_blocks``) and is checked all the same. What assumes the
+whole context resident in one pool works on the first group of a model
+that has one group, and raises ``ReleasedKVUnsupported`` otherwise.
+
 Prefix cache (docs/SERVING.md "Prefix caching"): every *full* KV block a
 sequence fills is registered in a hash index keyed by the chain hash of
 its token content — ``h_i = hash((h_{i-1}, tokens_i))`` — so a later
@@ -52,10 +68,40 @@ class DSSequenceDescriptor:
     # a hybrid model's recurrent layers: this sequence's slot in the
     # state tree (-1: the model has none)
     state_slot: int = -1
+    # K/V by layer group: ``kv_blocks`` is the first group's table, these
+    # are the further groups'; every table is indexed by the position's
+    # block and as long as the context, and a block a window group has
+    # handed back is -1 in it. ``released[g]``: group g's leading blocks
+    # handed back so far
+    more_blocks: List[List[int]] = field(default_factory=list)
+    released: List[int] = field(default_factory=lambda: [0])
+    # the tables once more as one int32 array [groups, blocks], what a
+    # forward's staging copies from (``DSStateManager.table_rows`` keeps
+    # it: ``rows_synced[g]`` entries of group g's table are in it,
+    # ``rows_cleared[g]`` leading ones are marked handed back)
+    rows: Optional[np.ndarray] = None
+    rows_synced: List[int] = field(default_factory=list)
+    rows_cleared: List[int] = field(default_factory=list)
 
     @property
     def cur_allocated_blocks(self) -> int:
         return len(self.kv_blocks)
+
+    @property
+    def tables(self) -> List[List[int]]:
+        """The block table of each layer group, the first group's first."""
+        return [self.kv_blocks] + self.more_blocks
+
+
+@dataclass
+class KVGroup:
+    """Layers whose K/V has one lifetime: ``window`` positions (0: the
+    whole context), their pool's leaves ``k<suffix>`` / ``v<suffix>`` and
+    its allocator."""
+    window: int
+    layers: int
+    allocator: BlockedAllocator
+    suffix: str = ""
 
 
 class DSStateManager:
@@ -72,10 +118,20 @@ class DSStateManager:
                  kv_tier_host_bytes: int = 64 * 1024 * 1024,
                  kv_tier_disk_path: Optional[str] = None,
                  kv_tier_disk_bytes: int = 0,
-                 state_slots: int = 0):
+                 state_slots: int = 0,
+                 group_blocks: Optional[Sequence[int]] = None):
         from ..kv_quant import kv_bytes_per_block
 
         self.cfg = model_cfg
+        # (window, layers) of each layer group; ``group_blocks``: the
+        # further groups' pool sizes (None: as large as the first)
+        kv_groups = (model_cfg.kv_groups() if hasattr(model_cfg, "kv_groups")
+                     else ((0, model_cfg.num_layers),))
+        sizes = [num_blocks] + [min(int(n), num_blocks) for n in (
+            group_blocks or [num_blocks] * (len(kv_groups) - 1))]
+        if len(sizes) != len(kv_groups):
+            raise ValueError(f"{len(kv_groups)} layer groups, "
+                             f"{len(sizes)} pool sizes")
         # A hybrid model's recurrent layers keep a fixed-size state a
         # sequence (models/hybrid.state_shapes) beside the paged K/V of
         # its attention layers: ``state_slots`` slots, one a tracked
@@ -97,10 +153,21 @@ class DSStateManager:
         # blocks (inference/v2/kv_quant.py)
         self.kv_quant = bool(kv_quant)
         self.kv_quant_dtype = str(kv_quant_dtype)
-        self.allocator = BlockedAllocator(
-            num_blocks,
-            bytes_per_block=kv_bytes_per_block(model_cfg, block_size,
-                                               self.kv_quant, dtype))
+        per_layer = kv_bytes_per_block(model_cfg, block_size, self.kv_quant,
+                                       dtype) // sum(n for _, n in kv_groups)
+        self.groups = [
+            KVGroup(window, layers,
+                    BlockedAllocator(size, bytes_per_block=per_layer * layers),
+                    "" if g == 0 else str(g))
+            for g, ((window, layers), size) in enumerate(zip(kv_groups,
+                                                             sizes))]
+        self.allocator = self.groups[0].allocator
+        # blocks handed back behind a window since this manager was built
+        self.blocks_released = 0
+        if len(self.groups) > 1 and (enable_prefix_cache or kv_quant
+                                     or kv_tier_enabled):
+            self.refuse_grouped("the prefix cache, the KV tier and "
+                                "quantized pools")
         self._seqs: Dict[int, DSSequenceDescriptor] = {}
         # -- reservation ledger (docs/SERVING.md "Admission and
         # preemption"): per-sequence TOTAL projected block need, recorded
@@ -147,9 +214,11 @@ class DSStateManager:
         # paged-attention index maps (ops/paged_attention.py).
         # ``sharding``: optional NamedSharding placing KH over the tensor
         # axis (TP serving — reference v2 sharding/qkv.py:166 head split).
-        shape = (getattr(model_cfg, "num_attn_layers",
-                         model_cfg.num_layers), num_blocks,
-                 model_cfg.kv_heads, block_size, model_cfg.head_dim)
+        def pool_shape(group):
+            return (group.layers, group.allocator.total_blocks,
+                    model_cfg.kv_heads, block_size, model_cfg.head_dim)
+
+        shape = pool_shape(self.groups[0])
         from ..kv_quant import pool_dtype as _pool_dtype
 
         pool_dt = _pool_dtype(self.kv_quant_dtype) if self.kv_quant else dt
@@ -164,8 +233,9 @@ class DSStateManager:
 
         # one buffer per leaf: the forward donates the cache and writes it
         # in place (paged_model.py), and one buffer cannot be donated twice
-        self.kv_cache = {"k": _alloc(shape, pool_dt, sharding),
-                         "v": _alloc(shape, pool_dt, sharding)}
+        self.kv_cache = {
+            name + group.suffix: _alloc(pool_shape(group), pool_dt, sharding)
+            for group in self.groups for name in ("k", "v")}
         if self.kv_quant:
             # symmetric per-(layer, block, kv-head) scales, indexed by
             # pool block id — a prefix-shared block shares its scale for
@@ -201,6 +271,18 @@ class DSStateManager:
             "token or shared by prefix (snapshots of state are not "
             "built yet)")
 
+    def refuse_grouped(self, what: str) -> None:
+        """The typed refusal of a feature that assumes a sequence's whole
+        context resident in one pool."""
+        from ....models.hybrid import ReleasedKVUnsupported
+
+        raise ReleasedKVUnsupported(
+            f"{what} assume(s) a sequence's whole context resident in one "
+            f"pool; this model keeps K/V in {len(self.groups)} layer "
+            f"group(s) (windows {[g.window for g in self.groups]}; 0 = the "
+            "whole context), and a window group hands the blocks behind "
+            "its window back while the sequence lives")
+
     # -- the forward's cache ------------------------------------------------
     @property
     def forward_cache(self) -> Dict[str, jax.Array]:
@@ -223,7 +305,9 @@ class DSStateManager:
         if uid not in self._seqs:
             if len(self._seqs) >= self.max_tracked_sequences:
                 raise RuntimeError("max tracked sequences exceeded")
-            seq = DSSequenceDescriptor(uid=uid)
+            seq = DSSequenceDescriptor(
+                uid=uid, more_blocks=[[] for _ in self.groups[1:]],
+                released=[0] * len(self.groups))
             if self.recurrent:
                 if not self._free_slots:
                     raise RuntimeError("no free recurrent-state slot")
@@ -241,22 +325,84 @@ class DSStateManager:
         to them."""
         seq = self._seqs.pop(uid, None)
         self._reserved.pop(uid, None)     # reservation dies with the state
-        if seq is not None and seq.kv_blocks:
-            self._release_blocks(seq.kv_blocks)
+        if seq is not None:
+            for g, table in enumerate(seq.tables):
+                live = [b for b in table if b >= 0]
+                if live:
+                    self._release_blocks(live, g)
         if seq is not None and seq.state_slot >= 0:
             self._free_slots.append(seq.state_slot)
 
-    def _release_blocks(self, blocks: List[int]) -> None:
+    def _release_blocks(self, blocks: List[int], group: int = 0) -> None:
         """Drop one reference per block and keep the incremental
         evictable count honest: an indexed block whose only remaining
         reference is the cache's own just became reclaimable. The single
-        home for this transition — flush and trim both go through it."""
-        self.allocator.release(blocks)
-        if self.prefix_cache_enabled:
+        home for this transition — flush, trim and the release behind a
+        window all go through it."""
+        self.groups[group].allocator.release(blocks)
+        if self.prefix_cache_enabled and group == 0:
             for b in blocks:
                 if (b in self._block_hash
                         and self.allocator.ref_count(b) == 1):
                     self._evictable += 1
+
+    def first_live_block(self, window: int, position: int) -> int:
+        """The first block a query at ``position`` (and any later one)
+        can attend under ``window``: the paged kernel's walk starts
+        there, and every block before it is dead."""
+        if not window:
+            return 0
+        return max(position - window + 1, 0) // self.block_size
+
+    def table_rows(self, seq: DSSequenceDescriptor) -> np.ndarray:
+        """The sequence's block tables as one int32 array ``[groups,
+        blocks]``, for the forward's staging: a decode step of a 40k-token
+        context would otherwise turn a list of 700 ints into an array a
+        group a step. The array is kept beside the lists and brought up
+        to date here by what changed since the last call — tables grow at
+        the end (allocation, a matched prefix, an import), lose entries
+        at the end (``trim_sequence``, which lowers the mark) and are
+        handed back from the front (``release_behind``)."""
+        tables = seq.tables
+        n = len(tables[0])
+        if seq.rows is None:
+            width = -(-self.cfg.max_seq_len // self.block_size)
+            seq.rows = np.full((len(tables), max(width, n)), -1, np.int32)
+            seq.rows_synced = [0] * len(tables)
+            seq.rows_cleared = [0] * len(tables)
+        for g, table in enumerate(tables):
+            have = seq.rows_synced[g]
+            if n > have:
+                seq.rows[g, have:n] = table[have:]
+            elif n < have:
+                seq.rows[g, n:have] = -1
+            seq.rows_synced[g] = n
+            gone = seq.released[g]
+            if gone > seq.rows_cleared[g]:
+                seq.rows[g, seq.rows_cleared[g]:gone] = -1
+                seq.rows_cleared[g] = gone
+        return seq.rows[:, :n]
+
+    def release_behind(self, seq: DSSequenceDescriptor) -> int:
+        """Hand back every block of a window group that lies wholly
+        behind the window of the sequence's next query (position
+        ``seen_tokens``): it belongs to no later query. A shared block
+        loses this sequence's reference only. Called after the put that
+        passed them was dispatched and its tokens were recorded. Returns
+        the number of blocks handed back."""
+        handed = 0
+        for g, (group, table) in enumerate(zip(self.groups, seq.tables)):
+            first = min(self.first_live_block(group.window, seq.seen_tokens),
+                        len(table))
+            done = seq.released[g]
+            if first > done:
+                self._release_blocks([b for b in table[done:first] if b >= 0],
+                                     g)
+                table[done:first] = [-1] * (first - done)
+                seq.released[g] = first
+                handed += first - done
+        self.blocks_released += handed
+        return handed
 
     def trim_sequence(self, uid: int, n_tokens: int) -> int:
         """KV rollback: drop the trailing ``n_tokens`` from a sequence —
@@ -287,11 +433,18 @@ class DSStateManager:
             return 0
         if self.recurrent:
             self.refuse_recurrent("trim_sequence (speculative rollback)")
+        if len(self.groups) > 1:
+            self.refuse_grouped("trim_sequence (speculative rollback)")
         if n_tokens > seq.seen_tokens:
             raise ValueError(
                 f"cannot trim {n_tokens} tokens from sequence {uid} "
                 f"({seq.seen_tokens} seen)")
         new_seen = seq.seen_tokens - n_tokens
+        if seq.released[0] > self.first_live_block(self.groups[0].window,
+                                                   new_seen):
+            self.refuse_grouped(
+                f"trim_sequence of {n_tokens} tokens (a rollback past a "
+                f"block sequence {uid} has handed back)")
         if new_seen < seq.hashed_blocks * self.block_size:
             raise ValueError(
                 f"cannot trim sequence {uid} into prefix-indexed blocks "
@@ -309,6 +462,8 @@ class DSStateManager:
                     f"cannot trim block {b} of sequence {uid}: shared "
                     "outside the prefix index (sharing invariant violated)")
         del seq.kv_blocks[keep:]
+        if seq.rows is not None:    # what grows back is other blocks
+            seq.rows_synced[0] = min(seq.rows_synced[0], keep)
         seq.seen_tokens = new_seen
         # chain state: un-blocked pending tokens past the new end are gone
         over = (seq.hashed_blocks * self.block_size
@@ -351,6 +506,9 @@ class DSStateManager:
         seq = self._seqs.get(uid)
         if seq is None or not seq.kv_blocks:
             return None
+        if len(self.groups) > 1 or seq.released[0]:
+            self.refuse_grouped("export_sequence (KV handoff, the "
+                                "preemption stash)")
         meta = {"seen_tokens": seq.seen_tokens,
                 "block_size": self.block_size,
                 "kv_quant": self.kv_quant,
@@ -419,6 +577,8 @@ class DSStateManager:
         :meth:`export_sequence`); chunked payloads scatter one chunk at
         a time, so the first chunks land while later ones are still
         materializing/arriving."""
+        if len(self.groups) > 1:
+            self.refuse_grouped("import_sequence (KV handoff)")
         chunks = payload.get("chunks")
         slabs = (payload["slabs"] if chunks is None
                  else {k: None for k in chunks[0]} if chunks
@@ -531,7 +691,30 @@ class DSStateManager:
             short = need - self.allocator.free_blocks
             if short > 0 and self.prefix_cache_enabled:
                 self._evict(short)           # LRU unreferenced cached blocks
-            seq.kv_blocks.extend(self.allocator.allocate(need))
+            # every group's table grows alike; all or nothing
+            if self.groups_short(need):
+                raise ValueError(f"cannot allocate {need} blocks in every "
+                                 "layer group")
+            for group, table in zip(self.groups, seq.tables):
+                table.extend(group.allocator.allocate(need))
+
+    def groups_short(self, blocks_needed: int) -> bool:
+        """Whether a further group's pool cannot give ``blocks_needed``
+        blocks (the first group's is ``available_blocks``' to say)."""
+        return any(blocks_needed > g.allocator.free_blocks
+                   for g in self.groups[1:])
+
+    def resident_bytes(self) -> Dict[str, int]:
+        """K/V bytes the pools hold for the tracked sequences, beside the
+        bytes the same sequences would hold had no block been handed
+        back (every group's table as long as the context)."""
+        return {
+            "kv_bytes_resident": sum(
+                (g.allocator.total_blocks - g.allocator.free_blocks)
+                * g.allocator.bytes_per_block for g in self.groups),
+            "kv_bytes_unreleased": sum(
+                len(seq.kv_blocks) for seq in self._seqs.values())
+            * sum(g.allocator.bytes_per_block for g in self.groups)}
 
     # -- reservation ledger (docs/SERVING.md "Admission and preemption") ----
     def _unfilled(self, uid: int, total: int) -> int:
@@ -562,6 +745,8 @@ class DSStateManager:
             return 0
         n = 0
         for b in seq.kv_blocks:
+            if b < 0:           # handed back behind the window already
+                continue
             rc = self.allocator.ref_count(b)
             if rc == 1 or (rc == 2 and b in self._block_hash):
                 n += 1
@@ -686,6 +871,12 @@ class DSStateManager:
         serving layer publishes this as ``kv_blocks_in_use`` /
         ``kv_bytes_in_use`` gauges."""
         occ = self.allocator.occupancy()
+        # blocks are the first group's (what ``num_blocks`` sized and
+        # admission counts); bytes are every group's
+        by_group = [dict(g.allocator.occupancy(), window=g.window,
+                         layers=g.layers) for g in self.groups]
+        for key in ("bytes_in_use", "bytes_total"):
+            occ[key] = sum(g[key] for g in by_group)
         occ["evictable_blocks"] = self.evictable_blocks
         occ["available_blocks"] = occ["free_blocks"] + occ["evictable_blocks"]
         # per-tier residency (docs/SERVING.md "KV tiering"): zeros when
@@ -703,6 +894,10 @@ class DSStateManager:
         occ["state_slots_used"] = self.state_slots - len(self._free_slots)
         occ["state_bytes"] = sum(int(leaf.nbytes)
                                  for leaf in self.state_cache.values())
+        # by layer group (the keys above are the first group's): window,
+        # layers, and the allocator's own snapshot
+        occ["groups"] = by_group
+        occ["blocks_released"] = self.blocks_released
         return occ
 
     def prefix_stats(self) -> Dict[str, int]:
@@ -790,7 +985,8 @@ class DSStateManager:
             seq.chain_hash = hash(key)
             block = seq.kv_blocks[seq.hashed_blocks]
             seq.hashed_blocks += 1
-            self._register(key, block)
+            if block >= 0:      # not handed back behind the window since
+                self._register(key, block)
 
     def _register(self, key: tuple, block: int) -> None:
         if key in self._index or block in self._block_hash:

@@ -17,7 +17,10 @@ import numpy as np
 
 class RaggedBatchWrapper:
     def __init__(self, max_seqs: int, max_chunk: int, max_blocks_per_seq: int,
-                 min_chunk: int = 1):
+                 min_chunk: int = 1, groups: int = 1):
+        # K/V by layer group (ragged/manager.py): one block table a group,
+        # ``block_tables`` [groups, S, MB]; one group keeps [S, MB]
+        self.groups = int(groups)
         self.max_seqs = max_seqs
         self.max_chunk = max_chunk
         # the narrowest bucket a chunk wider than one token is padded to
@@ -32,7 +35,9 @@ class RaggedBatchWrapper:
         self.tokens = np.zeros((ms, mc), np.int32)
         self.start_pos = np.zeros((ms,), np.int32)     # tokens already cached
         self.n_tokens = np.zeros((ms,), np.int32)      # new tokens this step
-        self.block_tables = np.full((ms, mb), -1, np.int32)
+        self.block_tables = np.full(
+            (ms, mb) if self.groups == 1 else (self.groups, ms, mb), -1,
+            np.int32)
         self.uids: List[int] = []
 
     @property
@@ -45,19 +50,24 @@ class RaggedBatchWrapper:
 
     def insert_sequence(self, uid: int, tokens: Sequence[int], start_pos: int,
                         kv_blocks: Sequence[int]) -> int:
-        """Add one sequence's chunk; returns its row index."""
+        """Add one sequence's chunk; returns its row index. ``kv_blocks``:
+        its block table, or with several layer groups one table a group
+        (equally long; an int32 array ``[groups, blocks]`` is copied as
+        it is — ``DSStateManager.table_rows``)."""
         i = len(self.uids)
         if i >= self.max_seqs:
             raise ValueError("ragged batch full (max_seqs)")
         n = len(tokens)
         if n > self.max_chunk:
             raise ValueError(f"chunk {n} > max_chunk {self.max_chunk}")
-        if len(kv_blocks) > self.max_blocks_per_seq:
+        tables = np.asarray(kv_blocks, np.int32)
+        if tables.shape[-1] > self.max_blocks_per_seq:
             raise ValueError("sequence exceeds max_blocks_per_seq")
         self.tokens[i, :n] = np.asarray(tokens, np.int32)
         self.start_pos[i] = start_pos
         self.n_tokens[i] = n
-        self.block_tables[i, :len(kv_blocks)] = np.asarray(kv_blocks, np.int32)
+        # [blocks] into row i, or [groups, blocks] into every group's row i
+        self.block_tables[..., i, :tables.shape[-1]] = tables
         self.uids.append(uid)
         return i
 
@@ -107,5 +117,5 @@ class RaggedBatchWrapper:
             "tokens": self.tokens[:S, :C],
             "start_pos": self.start_pos[:S],
             "n_tokens": self.n_tokens[:S],
-            "block_tables": self.block_tables[:S],
+            "block_tables": self.block_tables[..., :S, :],
         }

@@ -31,7 +31,7 @@ import numpy as np
 
 from ...telemetry import NOOP_TRACER
 from ...utils.logging import logger
-from .engine_v2 import InferenceEngineV2
+from .engine_v2 import FORWARD_ONLY, InferenceEngineV2
 from .scheduling_utils import SchedulingResult
 from .spec import DraftProposer, verify_greedy
 
@@ -834,7 +834,7 @@ class ContinuousBatchingScheduler:
                     # agreement test holds them to its own wrapper's
                     sspan.attrs.update(
                         {k: v for k, v in record.items()
-                         if k not in ("kv_blocks_live", "kv_table_slots")})
+                         if not k.startswith(FORWARD_ONLY)})
             with tracer.span("fetch"):
                 logits = np.asarray(logits)
             if traced:

@@ -4,24 +4,36 @@ one period an iteration), each followed by the same sparse FFN.
 
 - ``"full"``: gated softmax attention — a stated head size, per-head
   q/k RMSNorm, an output gate read off a ``wq`` twice as wide
-  (``[q | g]`` within each head), partial rotary.
+  (``[q | g]`` within each head) or off a projection of its own
+  (``attn_gate_proj``: ``wg``), partial rotary or none
+  (``rope_kinds``).
+- ``"window"``: the same layer over the last ``sliding_window``
+  positions; its K/V is a group of its own in serving, with a pool and a
+  block table whose blocks behind the window go back while the sequence
+  lives.
 - ``"linear"``: Gated DeltaNet (``ops/gated_delta.py``) — one projection
   to ``[q | k | v | z]`` and one to ``[b | a]``, a depthwise causal conv
   over ``[q | k | v]``, the gated delta rule over a float32 state, a
   gated RMSNorm and the output projection. Its cache is the state and the
   conv's last inputs, not per-token K/V.
 - the FFN: dropless top-k experts over a held range plus a shared expert
-  under a sigmoid gate (``moe/grouped.py``).
+  (``moe/grouped.py``): softmax or sigmoid scores, a selection bias
+  outside the weights, the shared expert under a sigmoid gate or bare.
+  ``lead_layers`` run before the scanned periods with a dense MLP in its
+  place; ``sandwich_norm`` puts a norm behind the mixer and the FFN too.
 
 ``CausalLM`` (training, the reference path) and ``PagedCausalLM``
 (serving) both call these; only where the mixer's cache lives differs.
 Scopes follow ``docs/OBSERVABILITY.md``: ``linear_attn`` ⊃ ``gdn_proj``,
-``gdn_conv``, ``gdn_scan``, ``gdn_out``; ``router``, ``experts``,
-``shared_expert`` inside ``mlp``.
+``gdn_conv``, ``gdn_scan``, ``gdn_out``; ``full_attn`` / ``window_attn``
+round an attention layer's ``qkv``, ``kv_write``, ``attend`` and
+``attn_out``; ``router``, ``experts``, ``shared_expert`` or
+``dense_mlp`` inside ``mlp``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
@@ -31,13 +43,24 @@ from jax import lax
 from ..ops import gated_delta as gd
 from ..parallel.sharding import spec
 
-KINDS = ("full", "linear")
+KINDS = ("full", "linear", "window")
+#: the kinds that keep per-token K/V, and the scope round each one's layer
+ATTN_SCOPE = {"full": "full_attn", "window": "window_attn"}
 
 
 class RecurrentStateUnsupported(NotImplementedError):
     """Raised where a feature that assumes per-token KV (rollback, prefix
     sharing, the KV tier, head-split TP) meets a model with recurrent
     layers: their state cannot be cut at a token or shared by prefix."""
+
+
+class ReleasedKVUnsupported(NotImplementedError):
+    """Raised where a feature that assumes a sequence's whole context is
+    resident in one pool (the prefix cache and the KV tier over several
+    layer groups, export / import and the preemption stash, a rollback
+    past a released block, quantized pools of several groups) meets K/V
+    kept by layer group, whose window groups hand blocks back while the
+    sequence lives (inference/v2/ragged/manager.py)."""
 
 
 def rms(x, w, eps, zero_centered):
@@ -73,9 +96,10 @@ def state_shapes(cfg, slots: int):
 
 # ------------------------------------------------------------------- init
 
-def init_slot(cfg, kind: str, key, periods: int):
+def init_slot(cfg, kind: str, key, periods: int, dense: bool = False):
     """One position of the period: its mixer's and its FFN's weights,
-    stacked over the periods."""
+    stacked over the periods. ``dense``: a lead layer, whose FFN is the
+    dense MLP."""
     h, hd, nh, kvh = (cfg.hidden_size, cfg.head_dim, cfg.num_heads,
                       cfg.kv_heads)
     P, std = periods, 0.02
@@ -89,10 +113,16 @@ def init_slot(cfg, kind: str, key, periods: int):
     gain = (jnp.zeros if cfg.norm_zero_centered else jnp.ones)
     lp = {"attn_norm_w": gain((P, h), jnp.float32),
           "mlp_norm_w": gain((P, h), jnp.float32)}
-    if kind == "full":
-        q_out = nh * hd * (2 if cfg.attn_output_gate else 1)
+    if cfg.sandwich_norm:
+        lp.update(post_attn_norm_w=gain((P, h), jnp.float32),
+                  post_mlp_norm_w=gain((P, h), jnp.float32))
+    if kind in ATTN_SCOPE:
+        own_gate = cfg.attn_output_gate and cfg.attn_gate_proj
+        q_out = nh * hd * (2 if cfg.attn_output_gate and not own_gate else 1)
         lp.update(wq=w((h, q_out)), wk=w((h, kvh * hd)), wv=w((h, kvh * hd)),
                   wo=w((nh * hd, h), out_std))
+        if own_gate:
+            lp["wg"] = w((h, nh * hd))
         if cfg.qk_norm:
             lp["q_norm_w"] = gain((P, hd), jnp.float32)
             lp["k_norm_w"] = gain((P, hd), jnp.float32)
@@ -107,29 +137,41 @@ def init_slot(cfg, kind: str, key, periods: int):
             dt_bias=jnp.ones((P, hv), jnp.float32),
             gdn_norm_w=jnp.ones((P, dv), jnp.float32),
             w_gdn_out=w((hv * dv, h), out_std))
+    if dense:
+        m = cfg.intermediate_size
+        lp.update(w_in=w((h, m)), w_gate=w((h, m)), w_out=w((m, h), out_std))
+        return lp
     n_held = cfg.moe_held_experts[1] if cfg.moe_held_experts \
         else cfg.moe_num_experts
     m = cfg.moe_intermediate_size or cfg.intermediate_size
     lp.update(router_wg=w((h, cfg.moe_num_experts), 1.0 / math.sqrt(h)),
               w_in=w((n_held, h, m)), w_gate=w((n_held, h, m)),
               w_out=w((n_held, m, h), out_std))
+    if cfg.moe_select_bias:
+        lp["router_b"] = jnp.zeros((P, cfg.moe_num_experts), jnp.float32)
     ms = cfg.moe_shared_intermediate_size
     if ms:
         lp.update(shared_w_in=w((h, ms)), shared_w_gate=w((h, ms)),
-                  shared_w_out=w((ms, h), out_std),
-                  shared_gate_w=w((h, 1), 1.0 / math.sqrt(h)))
+                  shared_w_out=w((ms, h), out_std))
+        if cfg.moe_shared_gate:
+            lp["shared_gate_w"] = w((h, 1), 1.0 / math.sqrt(h))
     return lp
 
 
-def slot_specs(cfg, kind: str):
+def slot_specs(cfg, kind: str, dense: bool = False):
     """Logical sharding axes of ``init_slot``'s tree."""
     lp = {"attn_norm_w": spec("layers", "embed"),
           "mlp_norm_w": spec("layers", "embed")}
-    if kind == "full":
+    if cfg.sandwich_norm:
+        lp.update(post_attn_norm_w=spec("layers", "embed"),
+                  post_mlp_norm_w=spec("layers", "embed"))
+    if kind in ATTN_SCOPE:
         lp.update(wq=spec("layers", "embed", "heads"),
                   wk=spec("layers", "embed", "kv_heads"),
                   wv=spec("layers", "embed", "kv_heads"),
                   wo=spec("layers", "heads", "embed"))
+        if cfg.attn_output_gate and cfg.attn_gate_proj:
+            lp["wg"] = spec("layers", "embed", "heads")
         if cfg.qk_norm:
             lp["q_norm_w"] = spec("layers", None)
             lp["k_norm_w"] = spec("layers", None)
@@ -140,15 +182,23 @@ def slot_specs(cfg, kind: str):
                   A_log=spec("layers", None), dt_bias=spec("layers", None),
                   gdn_norm_w=spec("layers", None),
                   w_gdn_out=spec("layers", None, "embed"))
+    if dense:
+        lp.update(w_in=spec("layers", "embed", "mlp"),
+                  w_gate=spec("layers", "embed", "mlp"),
+                  w_out=spec("layers", "mlp", "embed"))
+        return lp
     lp.update(router_wg=spec("layers", "embed", None),
               w_in=spec("layers", "expert", "embed", "mlp"),
               w_gate=spec("layers", "expert", "embed", "mlp"),
               w_out=spec("layers", "expert", "mlp", "embed"))
+    if cfg.moe_select_bias:
+        lp["router_b"] = spec("layers", None)
     if cfg.moe_shared_intermediate_size:
         lp.update(shared_w_in=spec("layers", "embed", "mlp"),
                   shared_w_gate=spec("layers", "embed", "mlp"),
-                  shared_w_out=spec("layers", "mlp", "embed"),
-                  shared_gate_w=spec("layers", "embed", None))
+                  shared_w_out=spec("layers", "mlp", "embed"))
+        if cfg.moe_shared_gate:
+            lp["shared_gate_w"] = spec("layers", "embed", None)
     return lp
 
 
@@ -157,14 +207,17 @@ def slot_specs(cfg, kind: str):
 def full_qkv(cfg, h1, lp, rope):
     """The gated attention layer's projections on its normed input
     [B, T, H]: (q, k, v, gate) with q/k normed per head and rotated
-    (``rope``: q or k [B, T, heads, D] -> the same, rotated)."""
+    (``rope``: q or k [B, T, heads, D] -> the same, rotated; the
+    identity for a kind that is not rotated, ``rotates``)."""
     from .transformer import _linear
 
     B, T, _ = h1.shape
     nh, kvh, hd, dt = cfg.num_heads, cfg.kv_heads, cfg.head_dim, cfg.dtype
     q = _linear(h1, lp["wq"], None, dt)
     gate = None
-    if cfg.attn_output_gate:
+    if cfg.attn_output_gate and cfg.attn_gate_proj:
+        gate = _linear(h1, lp["wg"], None, dt).reshape(B, T, nh, hd)
+    elif cfg.attn_output_gate:
         q, gate = jnp.split(q.reshape(B, T, nh, 2 * hd), 2, axis=-1)
     q = q.reshape(B, T, nh, hd)
     k = _linear(h1, lp["wk"], None, dt).reshape(B, T, kvh, hd)
@@ -173,6 +226,10 @@ def full_qkv(cfg, h1, lp, rope):
         q = block_norm(cfg, q, lp["q_norm_w"])
         k = block_norm(cfg, k, lp["k_norm_w"])
     return rope(q), rope(k), v, gate
+
+
+def rotates(cfg, kind: str) -> bool:
+    return cfg.rope_kinds is None or kind in cfg.rope_kinds
 
 
 def full_out(cfg, attn, gate, lp):
@@ -232,36 +289,63 @@ def gdn_mixer(cfg, h1, lp, tail, state, n_tokens):
 
 # ----------------------------------------------------------------- period
 
-def run_period(cfg, x, slots, full_mixer, linear_mixer, valid=None,
+def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
                max_rows=None, transform=None):
-    """One period of layers on x [B, T, H]: each position's norm, its
-    mixer, the sparse FFN and the residual adds. ``slots``: the period's
-    weights, one tree a position. ``full_mixer(h1, lp, i)`` /
-    ``linear_mixer(h1, lp, i)`` -> the mixer's output for the i-th layer
-    of its kind in the period: where the cache lives is the caller's
-    (training keeps none, serving a paged pool and state slots).
-    Returns (x, summed aux loss)."""
+    """A run of layers on x [B, T, H] — one period (``kinds`` None: the
+    pattern) or the lead layers (``dense``) — each position's norm, its
+    mixer, the FFN and the residual adds. ``slots``: the weights, one
+    tree a position. ``mixers[kind](h1, lp, i)`` -> the mixer's output
+    for the i-th layer of its kind in the run: where the cache lives is
+    the caller's (training keeps none, serving paged pools and state
+    slots). Returns (x, summed aux loss)."""
     scope = jax.named_scope
     aux = jnp.zeros((), jnp.float32)
+    kinds = cfg.layer_pattern if kinds is None else kinds
     seen = {kind: 0 for kind in KINDS}
-    for kind, lp in zip(cfg.layer_pattern, slots):
+    for kind, lp in zip(kinds, slots):
         if transform is not None:
             lp = transform(lp)
         with scope("attn_norm"):
             h1 = block_norm(cfg, x, lp["attn_norm_w"])
-        mixer = full_mixer if kind == "full" else linear_mixer
-        y = mixer(h1, lp, seen[kind])
+        with scope(ATTN_SCOPE[kind]) if kind in ATTN_SCOPE \
+                else contextlib.nullcontext():
+            y = mixers[kind](h1, lp, seen[kind])
         seen[kind] += 1
-        with scope("mlp"):      # norm, FFN and the residual adds
+        with scope("mlp"):      # norms, FFN and the residual adds
+            if cfg.sandwich_norm:
+                y = block_norm(cfg, y, lp["post_attn_norm_w"])
             x = x + y
             h2 = block_norm(cfg, x, lp["mlp_norm_w"])
-            f, a = moe_ffn(cfg, h2, lp, valid=valid, max_rows=max_rows)
+            if dense:
+                f, a = dense_ffn(cfg, h2, lp), 0.0
+            else:
+                f, a = moe_ffn(cfg, h2, lp, valid=valid, max_rows=max_rows)
+            if cfg.sandwich_norm:
+                f = block_norm(cfg, f, lp["post_mlp_norm_w"])
             x = x + f
         aux = aux + a
     return x, aux
 
 
+def lead_slots(cfg, params):
+    """The lead layers' trees, in order (each is stacked over one
+    "period", like a slot: the leading dim is dropped)."""
+    return tuple(jax.tree.map(lambda a: a[0], params["layers"][f"lead{j}"])
+                 for j in range(len(cfg.lead_layers)))
+
+
 # -------------------------------------------------------------------- FFN
+
+def dense_ffn(cfg, h2, lp):
+    """A lead layer's dense gated MLP on its normed input [B, T, H]."""
+    from .transformer import _linear
+
+    dt = cfg.dtype
+    with jax.named_scope("dense_mlp"):
+        return _linear(jax.nn.silu(_linear(h2, lp["w_gate"], None, dt))
+                       * _linear(h2, lp["w_in"], None, dt),
+                       lp["w_out"], None, dt)
+
 
 def moe_ffn(cfg, h2, lp, valid=None, max_rows=None):
     """The sparse FFN on its normed input [B, T, H]: the held experts'
@@ -283,13 +367,17 @@ def moe_ffn(cfg, h2, lp, valid=None, max_rows=None):
             rows, logits, lp["w_in"], lp["w_out"], lp["w_gate"],
             activation="silu", dtype=dt, top_k=cfg.moe_top_k,
             renormalize=cfg.moe_norm_topk, held=cfg.moe_held_experts,
-            valid=flat_valid, max_rows=max_rows)
+            valid=flat_valid, max_rows=max_rows,
+            score_func=cfg.moe_score_func, select_bias=lp.get("router_b"),
+            route_scale=cfg.moe_route_scale)
     if cfg.moe_shared_intermediate_size:
         with jax.named_scope("shared_expert"):
             s = jax.nn.silu(_linear(rows, lp["shared_w_gate"], None, dt)) \
                 * _linear(rows, lp["shared_w_in"], None, dt)
             s = _linear(s, lp["shared_w_out"], None, dt)
-            gate = jax.nn.sigmoid(_linear(rows, lp["shared_gate_w"], None,
-                                          dt).astype(jnp.float32))
-            y = y + (s * gate.astype(dt))
+            if cfg.moe_shared_gate:
+                gate = jax.nn.sigmoid(_linear(rows, lp["shared_gate_w"],
+                                              None, dt).astype(jnp.float32))
+                s = s * gate.astype(dt)
+            y = y + s
     return y.reshape(B, T, H), l_aux

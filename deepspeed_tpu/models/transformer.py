@@ -103,13 +103,23 @@ class TransformerConfig:
     moe_aux_loss_coef: float = 0.01
     moe_dropless: bool = False   # ragged_dot grouped GEMM (moe/grouped.py)
     # Hybrid blocks (models/hybrid.py): layers of more than one kind.
-    # ``layer_pattern`` is one period of mixer kinds ("full" | "linear"),
-    # repeated num_layers / len(pattern) times and scanned one period an
-    # iteration; None is the uniform attention block above. The fields
-    # below are read by the hybrid block only.
+    # ``layer_pattern`` is one period of mixer kinds ("full" | "linear" |
+    # "window": attention over the last ``sliding_window`` positions),
+    # repeated (num_layers - len(lead_layers)) / len(pattern) times and
+    # scanned one period an iteration; None is the uniform attention
+    # block above. The fields below are read by the hybrid block only.
     layer_pattern: Optional[Tuple[str, ...]] = None
+    # mixer kinds of the layers run before the scan, each followed by a
+    # dense MLP of ``intermediate_size`` instead of the sparse FFN
+    lead_layers: Tuple[str, ...] = ()
     head_size: Optional[int] = None      # stated head size; None → hidden/heads
     attn_output_gate: bool = False       # wq twice as wide: [q | gate] a head
+    attn_gate_proj: bool = False         # ... or a projection of its own (wg)
+    # attention kinds whose q and k are rotated; None: all of them. A
+    # kind left out has no position term at all
+    rope_kinds: Optional[Tuple[str, ...]] = None
+    sandwich_norm: bool = False          # a norm after the mixer and the FFN too
+    embed_scale: float = 1.0             # the embedding's multiplier
     qk_norm: bool = False                # RMSNorm over each head of q and k
     norm_zero_centered: bool = False     # RMSNorm gain is 1 + w
     linear_num_key_heads: int = 0        # Gated DeltaNet layer sizes
@@ -118,6 +128,11 @@ class TransformerConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 4
     moe_norm_topk: bool = False          # top-k weights divided by their sum
+    moe_score_func: str = "softmax"      # | "sigmoid": each expert's own score
+    moe_select_bias: bool = False        # top-k over score + a learned bias
+    #   (``router_b``); the weights stay the unbiased scores
+    moe_route_scale: float = 1.0         # the top-k weights' multiplier
+    moe_shared_gate: bool = True         # shared expert under a sigmoid gate
     moe_held_experts: Optional[Tuple[int, int]] = None  # (lo, n): the share
     #   of the moe_num_experts routed over whose weights this model holds
     moe_intermediate_size: Optional[int] = None   # None → intermediate_size
@@ -125,24 +140,33 @@ class TransformerConfig:
 
     def __post_init__(self):
         # a configuration read from JSON brings lists
-        for name in ("layer_pattern", "moe_held_experts"):
+        for name in ("layer_pattern", "moe_held_experts", "lead_layers",
+                     "rope_kinds"):
             value = getattr(self, name)
             if isinstance(value, list):
                 object.__setattr__(self, name, tuple(value))
         if self.layer_pattern is not None:
             from .hybrid import KINDS
 
-            pattern = self.layer_pattern
-            if not pattern or any(k not in KINDS for k in pattern) \
-                    or self.num_layers % len(pattern):
+            pattern, lead = self.layer_pattern, self.lead_layers
+            if not pattern or any(k not in KINDS for k in pattern + lead) \
+                    or (self.num_layers - len(lead)) % len(pattern) \
+                    or self.num_layers <= len(lead):
                 raise ValueError(
                     f"layer_pattern {pattern!r}: a period of {KINDS} that "
-                    f"divides num_layers ({self.num_layers})")
-            if self.sliding_window or self.moe_num_experts <= 0 \
+                    f"divides num_layers ({self.num_layers}) less the "
+                    f"{len(lead)} lead_layers")
+            windowed = "window" in pattern + lead
+            if windowed != (isinstance(self.sliding_window, int)
+                            and self.sliding_window > 0) \
+                    or self.moe_num_experts <= 0 \
                     or self.norm != "rmsnorm" or self.position != "rope":
                 raise ValueError(
-                    "a hybrid block is RMSNorm, rotary, full-context "
-                    "attention and a sparse FFN (moe_num_experts > 0)")
+                    "a hybrid block is RMSNorm, rotary and a sparse FFN "
+                    "(moe_num_experts > 0); sliding_window is its "
+                    "\"window\" layers' length, and set with them only")
+        elif self.lead_layers:
+            raise ValueError("lead_layers belong to a layer_pattern")
 
     @property
     def head_dim(self) -> int:
@@ -154,21 +178,50 @@ class TransformerConfig:
 
     @property
     def num_periods(self) -> int:
-        return self.num_layers // len(self.layer_pattern)
+        return (self.num_layers - len(self.lead_layers)) \
+            // len(self.layer_pattern)
+
+    def layers_of(self, kind: str) -> int:
+        """A hybrid block's layers of one mixer kind, lead layers and
+        periods together."""
+        return self.lead_layers.count(kind) \
+            + self.num_periods * self.layer_pattern.count(kind)
 
     @property
     def num_attn_layers(self) -> int:
         """Layers that keep per-token K/V (all of them, unless hybrid)."""
         if self.layer_pattern is None:
             return self.num_layers
-        return self.num_periods * self.layer_pattern.count("full")
+        return self.layers_of("full") + self.layers_of("window")
 
     @property
     def num_linear_layers(self) -> int:
         """Layers that keep a recurrent state instead."""
         if self.layer_pattern is None:
             return 0
-        return self.num_periods * self.layer_pattern.count("linear")
+        return self.layers_of("linear")
+
+    @property
+    def num_sparse_layers(self) -> int:
+        """Layers whose FFN is the sparse one."""
+        if self.moe_num_experts <= 0:
+            return 0
+        return self.num_layers - len(self.lead_layers)
+
+    def kv_groups(self) -> Tuple[Tuple[int, int], ...]:
+        """``(window, layers)`` of each group of layers whose K/V has one
+        lifetime, and so a pool and a block table of its own in serving
+        (inference/v2/ragged/manager.py): 0 = the whole context. The
+        group whose K/V lives longest comes first. A uniform model is one
+        group; so is a dense model whose layers differ in window (its
+        layers share one stacked pool: nothing is released there)."""
+        if self.layer_pattern is None:
+            sw = self.sliding_window
+            return ((int(sw) if isinstance(sw, int) else 0,
+                     self.num_layers),)
+        groups = [(0, self.layers_of("full")),
+                  (int(self.sliding_window or 0), self.layers_of("window"))]
+        return tuple(g for g in groups if g[1])
 
     @property
     def kv_heads(self) -> int:
@@ -753,6 +806,10 @@ class CausalLM:
                        for i, kind in enumerate(cfg.layer_pattern)},
             "final_norm": {"w": gain((h,), jnp.float32)},
         }
+        # the lead layers' trees, each stacked over one "period"
+        for j, kind in enumerate(cfg.lead_layers):
+            params["layers"][f"lead{j}"] = hybrid.init_slot(
+                cfg, kind, jax.random.fold_in(rng, j), 1, dense=True)
         if not cfg.tie_embeddings:
             params["lm_head"] = {"w": (0.02 * jax.random.normal(
                 keys[-2], (h, v))).astype(jnp.float32)}
@@ -770,6 +827,9 @@ class CausalLM:
                      "layers": {f"slot{i}": hybrid.slot_specs(cfg, kind)
                                 for i, kind in enumerate(cfg.layer_pattern)},
                      "final_norm": {"w": spec("embed")}}
+            for j, kind in enumerate(cfg.lead_layers):
+                specs["layers"][f"lead{j}"] = hybrid.slot_specs(
+                    cfg, kind, dense=True)
             if not cfg.tie_embeddings:
                 specs["lm_head"] = {"w": spec("embed", "vocab")}
             return specs
@@ -1068,6 +1128,8 @@ class CausalLM:
         scope = jax.named_scope
         with scope("embed"):
             x = params["embed"]["wte"][tokens].astype(cfg.dtype)
+            if cfg.embed_scale != 1.0:
+                x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
             cos, sin = rope_table(cfg.max_seq_len, cfg.rot_dim,
                                   cfg.rope_theta)
             cos, sin = ((cos[positions], sin[positions])
@@ -1081,21 +1143,30 @@ class CausalLM:
                   in hybrid.state_shapes(cfg, B).items()} \
             if cfg.num_linear_layers else None
 
-        def full_mixer(h1, lp, _):
-            with scope("qkv"):
-                q, k, v, gate = hybrid.full_qkv(cfg, h1, lp, rope)
-            with scope("attend"):
-                attn = _attention(q, k, v, cfg, causal=True)
-            with scope("attn_out"):
-                return hybrid.full_out(cfg, attn, gate, lp)
+        def attention_mixer(kind):
+            turn = rope if hybrid.rotates(cfg, kind) else (lambda t: t)
+            window = cfg.sliding_window if kind == "window" else 0
+
+            def mixer(h1, lp, _):
+                with scope("qkv"):
+                    q, k, v, gate = hybrid.full_qkv(cfg, h1, lp, turn)
+                with scope("attend"):
+                    attn = _attention(q, k, v, cfg, causal=True,
+                                      window=window)
+                with scope("attn_out"):
+                    return hybrid.full_out(cfg, attn, gate, lp)
+            return mixer
 
         def linear_mixer(h1, lp, _):
             with scope("linear_attn"):
                 return hybrid.gdn_mixer(cfg, h1, lp, state0["conv"],
                                         state0["ssm"], n_tokens)[0]
 
+        mixers = {"full": attention_mixer("full"),
+                  "window": attention_mixer("window"), "linear": linear_mixer}
+
         def period(x, slots):
-            return hybrid.run_period(cfg, x, slots, full_mixer, linear_mixer,
+            return hybrid.run_period(cfg, x, slots, mixers,
                                      transform=self.layer_transform)
 
         if cfg.remat:
@@ -1103,6 +1174,10 @@ class CausalLM:
         slots = tuple(params["layers"][f"slot{i}"]
                       for i in range(len(cfg.layer_pattern)))
         with scope("layers"):
+            x, _ = hybrid.run_period(
+                cfg, x, hybrid.lead_slots(cfg, params), mixers,
+                kinds=cfg.lead_layers, dense=True,
+                transform=self.layer_transform)
             x, aux = lax.scan(period, x, slots)
         with scope("final_norm"):
             x = hybrid.block_norm(cfg, x, params["final_norm"]["w"])

@@ -51,7 +51,10 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
                      renormalize: bool = False,
                      held: Optional[Tuple[int, int]] = None,
                      valid: Optional[jax.Array] = None,
-                     max_rows: Optional[int] = None
+                     max_rows: Optional[int] = None,
+                     score_func: str = "softmax",
+                     select_bias: Optional[jax.Array] = None,
+                     route_scale: float = 1.0
                      ) -> Tuple[jax.Array, jax.Array]:
     """Top-k dropless MoE FFN over the experts this layer holds.
 
@@ -59,9 +62,12 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
     w_in [n, H, M]; w_out [n, M, H]; w_gate [n, H, M] for SwiGLU, where
     the n experts held are ``[lo, lo + n)`` (``held = (lo, n)``; None:
     all of them). A token's ``top_k`` experts are chosen over all E and
-    weighted by their softmax probabilities (``renormalize``: divided by
-    their sum); only the pairs whose expert is held are computed here —
-    what the others would add is another holder's part of the sum.
+    weighted by their scores — softmax probabilities, or with
+    ``score_func`` "sigmoid" each expert's own sigmoid — (``renormalize``:
+    divided by their sum; all times ``route_scale``). ``select_bias``
+    [E]: the choice is the top k of score + bias, the weights stay the
+    unbiased scores. Only the pairs whose expert is held are computed
+    here — what the others would add is another holder's part of the sum.
     ``valid`` [N] bool: rows that are padding; they reach no expert.
     ``max_rows``: a bound the caller knows on the number of valid rows —
     the grouped GEMMs then run over that many rows and not over N.
@@ -77,13 +83,28 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
     if held is not None and int(held[1]) != n_held:
         raise ValueError(f"held {held} but {n_held} experts' weights")
     dtype = dtype or tokens.dtype
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    gate, expert = lax.top_k(probs, top_k)                # [N, k]
+    if score_func not in ("softmax", "sigmoid"):
+        raise ValueError(f"score_func {score_func!r}")
+    sigmoid = score_func == "sigmoid"
+    logits32 = router_logits.astype(jnp.float32)
+    probs = jax.nn.sigmoid(logits32) if sigmoid \
+        else jax.nn.softmax(logits32, axis=-1)
+    if select_bias is None:
+        gate, expert = lax.top_k(probs, top_k)            # [N, k]
+    else:
+        _, expert = lax.top_k(probs + select_bias.astype(jnp.float32), top_k)
+        gate = jnp.take_along_axis(probs, expert, axis=-1)
     if renormalize:
-        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        # sigmoid scores can all be ~0: the source's modelling code keeps
+        # the quotient finite with 1e-20
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True)
+                       + (1e-20 if sigmoid else 0.0))
+    if route_scale != 1.0:
+        gate = gate * route_scale
 
     # load-balance aux (reference sharded_moe.py top1gating l_aux)
-    me = jnp.mean(probs, axis=0)
+    me = jnp.mean(probs / jnp.sum(probs, -1, keepdims=True) if sigmoid
+                  else probs, axis=0)
     ce = jnp.mean(jnp.sum(jax.nn.one_hot(expert, E, dtype=jnp.float32),
                           axis=1), axis=0) / top_k
     l_aux = jnp.sum(me * ce) * E
@@ -154,8 +175,36 @@ def grouped_matmul(lhs, rhs, group_sizes):
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     out = gmm(lhs, rhs, group_sizes.astype(jnp.int32),
               preferred_element_type=lhs.dtype,
-              tiling=(GMM_ROWS, min(k, 2048), min(n, 2048)))
+              tiling=(GMM_ROWS,) + gmm_tiles(k, n, rhs.dtype.itemsize))
     return out[:m] if pad else out
+
+
+#: bytes of VMEM the two slots of a grid step's weight tile may take, of
+#: the 16 MiB a v5e core grants a kernel (rows, accumulator and output
+#: tiles beside them)
+GMM_WEIGHT_TILE_BYTES = 10 * 2 ** 20
+
+
+def gmm_tiles(k: int, n: int, itemsize: int = 2):
+    """``(tk, tn)``: the weight tile of one grid step of the grouped
+    matmul, ``[k, n]`` a group. Each side is a divisor of its dimension
+    (no masked remainder), a multiple of 128 where the dimension has one
+    and 2048 at most; the larger side steps down while both slots of the
+    tile are over ``GMM_WEIGHT_TILE_BYTES`` (2,048 x 2,048 bf16 is 8 MiB
+    a slot: refused by the chip's compiler; experts 3,072 wide run at
+    1,536 x 1,536)."""
+    def sides(dim):
+        fit = [d for d in range(128, min(dim, 2048) + 1, 128)
+               if dim % d == 0]
+        return fit or [min(dim, 2048)]
+
+    ks, ns = sides(k), sides(n)
+    while 2 * ks[-1] * ns[-1] * itemsize > GMM_WEIGHT_TILE_BYTES \
+            and (len(ks) > 1 or len(ns) > 1):
+        longer = ks if (ks[-1] >= ns[-1] and len(ks) > 1) or len(ns) == 1 \
+            else ns
+        longer.pop()
+    return ks[-1], ns[-1]
 
 
 def _ragged_expert_ffn(st, gs, w_in, w_out, w_gate, activation, dtype,

@@ -418,6 +418,18 @@ def pallas_supported(num_heads: int, kv_heads: int, head_dim: int,
             and (_on_tpu() or force_interpret or _FORCE_INTERPRET))
 
 
+def _chunk_tile(chunk: int, group: int) -> int:
+    """The tokens of one piece of a chunk whose query group (``group``
+    query heads a KV head) is over ``MAX_QUERY_ROWS`` rows: the largest
+    divisor of the chunk that fits, so that the pieces are alike (one
+    kernel, no ragged last piece) whatever the group — 6 heads a KV head
+    cut a 1,024-token chunk into four pieces of 256, as 8 do."""
+    most = max(1, MAX_QUERY_ROWS // group)
+    if chunk <= most:
+        return chunk
+    return max(d for d in range(1, most + 1) if chunk % d == 0)
+
+
 def _pallas_ok(q, k_pool) -> bool:
     N, C, H, D = q.shape
     KH = k_pool.shape[-3]
@@ -457,7 +469,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
                 interpret=_use_interpret())
 
         N, C, H, _ = q.shape
-        tile = max(1, MAX_QUERY_ROWS // (H // k_pool.shape[-3]))
+        tile = _chunk_tile(C, H // k_pool.shape[-3])
         if C <= tile:
             return kernel(q, start_pos, n_tokens)
         # A query group of G·C rows is one VMEM block (with its float32

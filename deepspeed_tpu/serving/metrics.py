@@ -394,6 +394,10 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # and, of those, the pairs whose expert this replica holds
               # (the expectation under even routing: engine._count_routing)
               "moe_rows_routed", "moe_rows_held",
+              # K/V by layer group: blocks a window group handed back
+              # behind its window while their sequence lived
+              # (DSStateManager.release_behind)
+              "kv_blocks_released",
               # fault tolerance (docs/SERVING.md "Fault tolerance"):
               # failover = a dead replica's request re-enqueued (stream
               # resumed elsewhere); restarts = supervisor replaced a DEAD

@@ -585,7 +585,8 @@ class Replica:
                       ("restored", "kv_tier_blocks_restored"),
                       ("dropped", "kv_tier_blocks_dropped"))
     _PUT_COUNTERS = ("forwards", "positions_computed", "tokens_valid",
-                     "puts_split", "moe_rows_routed", "moe_rows_held")
+                     "puts_split", "moe_rows_routed", "moe_rows_held",
+                     "kv_blocks_released")
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),
                          ("resumed", "sequences_resumed"))
 

@@ -5,9 +5,29 @@ Counterpart of the reference's DistributedTest harness
 NCCL/Gloo loopback; the TPU-native equivalent is a single process with
 ``--xla_force_host_platform_device_count=8`` — real XLA collectives over a
 virtual 8-device mesh, exercising the same SPMD programs that run on ICI.
+
+
+Tiny twins for the benchmark's rehearsals. The runner tests
+(``tests/benchmark/test_benchmark_runners.py::checkout``) build a
+temporary checkout from the real manifest and write tiny configurations
+and traffic mixes into it under the real names; that fixture knows the
+configurations it was written with, and ``tests/benchmark/conftest.py``
+adds qwen3-next's. Every configuration or mix the manifest names since is
+filled here, from a twin **found by name**:
+``tests/benchmark/twins/configs/<configuration>.json`` and
+``tests/benchmark/twins/traffic/<mix>.json`` (``pytest_fixture_setup``
+below). So a later configuration adds files only: its twin keeps the
+published file's shape (the same ``block``, every switch of its
+``transformer_config``) with every width shrunk, float32, a window or a
+state a few blocks long and shorter than its prompts, tolerances of 1e-4,
+``compile_ahead`` as the real file; its mix keeps the generator and the
+loop with lengths of a few dozen tokens. A name with no twin is left
+alone (its own conftest may bring it).
 """
 
+import json
 import os
+import shutil
 
 # Must happen before jax is imported: it reads both variables then.
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
@@ -33,6 +53,37 @@ def devices8():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual CPU devices, got {len(devs)}"
     return devs
+
+
+TWINS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmark",
+                     "twins")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_fixture_setup(fixturedef, request):
+    """Once a ``checkout`` fixture has built its temporary checkout, fill
+    what its manifest names and it did not write from the twins (module
+    docstring)."""
+    outcome = yield
+    if fixturedef.argname != "checkout" or outcome.excinfo is not None:
+        return
+    root = outcome.get_result()
+    try:
+        with open(os.path.join(root, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+    except (OSError, TypeError, ValueError):
+        return
+    bench = os.path.join(root, manifest["paths"][0])
+    wanted = [(os.path.join(root, c["file"]),
+               os.path.join(TWINS, "configs", c["name"] + ".json"))
+              for c in manifest["configs"]]
+    wanted += [(os.path.join(bench, "traffic", w["traffic"] + ".json"),
+                os.path.join(TWINS, "traffic", w["traffic"] + ".json"))
+               for w in manifest["workloads"]]
+    for target, twin in wanted:
+        if not os.path.exists(target) and os.path.isfile(twin):
+            os.makedirs(os.path.dirname(target), exist_ok=True)
+            shutil.copyfile(twin, target)
 
 
 def pytest_sessionfinish(session, exitstatus):
