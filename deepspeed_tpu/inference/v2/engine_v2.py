@@ -134,24 +134,48 @@ def _keys_and_pairs(window: int, seen: int, n: int) -> Tuple[int, int]:
             short * seen + short * (short + 1) // 2 + (n - short) * window)
 
 
-class _RowLogits:
-    """The logits of a put that ran as several forwards: the parts stay
-    on the device, and are glued on the host, in the put's row order, when
-    they are asked for as one array (``np.asarray``, the scheduler's
-    ``fetch``). Gluing them on the device would be one more small program
-    for every (one-token rows, chunk rows) count a step can have."""
+#: a one-token row's token when its sequence's next token is still on
+#: the device: the forward takes it from the sequence's slot of
+#: ``InferenceEngineV2.next_ids`` (``PagedCausalLM._forward``)
+DEVICE_TOKEN = -1
 
-    def __init__(self, parts, order):
-        self.parts = parts
-        self.order = np.argsort(order)
-        self.shape = (len(order),) + tuple(parts[0].shape[1:])
-        self.dtype = parts[0].dtype
+
+class PutLogits:
+    """What a put returns: its rows' logits, ``[len(uids), vocab]`` (or
+    ``[len(uids), W, vocab]``), as a handle. The forwards' outputs stay on
+    the device, padded rows and all, and nothing is copied until someone
+    asks (``np.asarray``, an index, ``prefetch``); the rows are then cut
+    and, where the put ran as several forwards, glued in the put's row
+    order on the host -- on the device either would be one more small
+    program for every row count a step can have.
+
+    ``next_tokens()`` is the greedy draw over each row's last logits, read
+    from the engine's next-token buffer as this put left it (one small
+    copy, asked for when the put was dispatched)."""
+
+    def __init__(self, parts, order, next_ids, slots):
+        self.parts = parts              # [(device logits, real rows)]
+        self.order = np.argsort(order) if len(parts) > 1 else None
+        self.shape = (len(order),) + tuple(parts[0][0].shape[1:])
+        self.dtype = parts[0][0].dtype
+        self._ids, self._slots = next_ids, slots
         self._whole = None
+
+    def prefetch(self) -> None:
+        """Start the logits' copy to the host behind the forward, for a
+        caller that will read them: the read then waits once, for the
+        bytes, and not first for the program and then for the copy."""
+        for part, _ in self.parts or ():
+            part.copy_to_host_async()
+
+    def next_tokens(self) -> np.ndarray:
+        return np.asarray(self._ids)[self._slots]
 
     def __array__(self, dtype=None, copy=None):
         if self._whole is None:
-            self._whole = np.concatenate(
-                [np.asarray(p) for p in self.parts])[self.order]
+            rows = [np.asarray(part)[:n] for part, n in self.parts]
+            self._whole = rows[0] if self.order is None \
+                else np.concatenate(rows)[self.order]
             self.parts = None
         whole = self._whole
         return whole if dtype is None else whole.astype(dtype)
@@ -280,6 +304,17 @@ class InferenceEngineV2:
         if any(g.window for g in self.state_manager.groups):
             # blocks handed back behind a window while their sequence lived
             self.put_totals["kv_blocks_released"] = 0
+        # the next token of every tracked sequence, drawn by the forward
+        # that computed its last logits and kept on the device: one slot a
+        # sequence (``DSSequenceDescriptor.id_slot``) and a scratch one
+        # for padded rows, carried from forward to forward like the pool
+        # (but not donated: the buffer a put returned stays readable while
+        # the next put runs). A row of ``[DEVICE_TOKEN]`` reads its slot.
+        self.next_ids = jnp.zeros((self.state_manager.id_slots + 1,),
+                                  jnp.int32)
+        if jmesh is not None:
+            self.next_ids = jax.device_put(
+                self.next_ids, NamedSharding(jmesh, P()))
         self._forward_jit = self.paged.forward
         self._compile_ahead()
 
@@ -312,10 +347,11 @@ class InferenceEngineV2:
         def lowered(shape):
             s = shape[0]
             ints = [shape, (s,), (s,),
-                    (s, width) if groups == 1 else (groups, s, width)] + \
-                ([(s,)] if sm.recurrent else [])
+                    (s, width) if groups == 1 else (groups, s, width),
+                    (s,) if sm.recurrent else None,
+                    self.next_ids.shape, (s,)]
             return jitted.lower(self.params, sm.forward_cache, *(
-                jax.ShapeDtypeStruct(i, jnp.int32) for i in ints))
+                i and jax.ShapeDtypeStruct(i, jnp.int32) for i in ints))
 
         with ThreadPoolExecutor(self.config.compile_ahead) as pool:
             compiling = {shape: pool.submit(lowered(shape).compile)
@@ -411,9 +447,14 @@ class InferenceEngineV2:
     def put(self, uids: Sequence[int],
             tokens_list: Sequence[Sequence[int]], *,
             verify_width: int = 0,
-            defer_commit: bool = False) -> jnp.ndarray:
+            defer_commit: bool = False) -> PutLogits:
         """Run one forward over the ragged batch; returns next-token logits
-        [len(uids), vocab] (reference engine_v2.py:89).
+        [len(uids), vocab] (reference engine_v2.py:89) as a handle that
+        copies nothing from the device until it is read (``PutLogits``:
+        ``np.asarray``, an index; ``next_tokens()`` for the greedy draws
+        alone). A one-token row may be ``[DEVICE_TOKEN]``: its token is
+        then the one the sequence's last forward drew, which is still on
+        the device (``next_ids``) — the same program either way.
 
         Speculative verification (spec/, docs/SERVING.md "Speculative
         decoding") uses two keyword extensions; the default call is
@@ -436,15 +477,21 @@ class InferenceEngineV2:
         if status != SchedulingResult.Success:
             raise SchedulingError(status)
         groups = self._forward_groups(widths, verify_width)
-        if len(groups) == 1:
-            return self._forward_rows(uids, tokens_list, verify_width,
-                                      defer_commit)
+        sm = self.state_manager
         outs, records = [], []
         for rows in groups:
-            outs.append(self._forward_rows(
+            outs.append((self._forward_rows(
                 [uids[i] for i in rows], [tokens_list[i] for i in rows],
-                verify_width, defer_commit))
+                verify_width, defer_commit), len(rows)))
             records.append(self.last_put)
+        # the draws' copy back is asked for now and follows the put's last
+        # forward on the device's own queue
+        self.next_ids.copy_to_host_async()
+        result = PutLogits(
+            outs, [i for rows in groups for i in rows], self.next_ids,
+            [sm.get_sequence(uid).id_slot for uid in uids])
+        if len(groups) == 1:
+            return result
         # the put's record: its last (widest) forward's bucket, the sums
         # of what its forwards counted, and how many they were
         summed = ("rows", "valid_tokens", "kv_read_tokens", "qk_pairs",
@@ -456,7 +503,7 @@ class InferenceEngineV2:
             k: sum(r[k] for r in records) for k in summed
             if k in records[-1]})
         self.put_totals["puts_split"] += 1
-        return _RowLogits(outs, [i for rows in groups for i in rows])
+        return result
 
     def _forward_groups(self, widths: Sequence[int],
                         verify_width: int = 0) -> List[List[int]]:
@@ -467,7 +514,7 @@ class InferenceEngineV2:
         positions the rows wider than one token therefore run each as a
         forward of its own (``[1, C]``) and the one-token rows together
         (``[S, 1]``, first); the parts' logits meet at the scheduler's one
-        fetch (``_RowLogits``). The rule reads the bucket alone, not which
+        fetch (``PutLogits``). The rule reads the bucket alone, not which
         rows fill it: whoever has put every ``[S, C]`` once with one wide
         row has run every program a later put can reach
         (``forward_shapes``).
@@ -550,28 +597,31 @@ class InferenceEngineV2:
         # argument path moves them in a fifth of the time four
         # ``jnp.asarray`` calls take (the wrapper makes new ones a forward,
         # so none is written again behind the transfer)
-        args = (self.params, kv_cache, arrays["tokens"],
-                arrays["start_pos"], arrays["n_tokens"],
-                arrays["block_tables"])
+        slots = None
         if sm.recurrent:
             # each row's slot in the state tree; a padded row's is the
             # scratch slot behind the last
             slots = np.full((bucket_seqs,), sm.state_slots, np.int32)
             slots[:len(staged)] = [seq.state_slot for seq, _ in staged]
-            args += (slots,)
             # a hybrid model's put says its slots in use
             self.last_put["state_slots_used"] = \
                 sm.state_slots - sm.free_state_slots
+        # and its slot in the next-token buffer, likewise
+        id_slots = np.full((bucket_seqs,), sm.id_slots, np.int32)
+        id_slots[:len(staged)] = [seq.id_slot for seq, _ in staged]
+        args = (self.params, kv_cache, arrays["tokens"],
+                arrays["start_pos"], arrays["n_tokens"],
+                arrays["block_tables"], slots, self.next_ids, id_slots)
         if self.model.cfg.is_hybrid:    # and its sparse FFNs' rows
             self._count_routing(valid)
         # the forward consumes ``kv_cache`` (donated, written in place) and
         # hands the same memory back as ``new_cache``
         try:
             if verify_width:
-                logits, new_cache = self.paged.forward_verify(
+                logits, new_cache, next_ids = self.paged.forward_verify(
                     *args, verify_width=int(verify_width))
             else:
-                logits, new_cache = self.paged.forward(*args)
+                logits, new_cache, next_ids = self.paged.forward(*args)
         except Exception as e:
             if any(leaf.is_deleted() for leaf in kv_cache.values()):
                 raise RuntimeError(
@@ -589,24 +639,22 @@ class InferenceEngineV2:
         # the pool at flush. (Assumes each uid appears at most once per
         # batch, which the scheduler guarantees.)
         sm.forward_cache = new_cache
+        self.next_ids = next_ids
         released = 0
         for seq, toks in staged:
             seq.seen_tokens += len(toks)
             if not defer_commit:
-                sm.record_tokens(seq, toks)
+                # a token that is still on the device is recorded when
+                # its id has come back (``commit_tokens``)
+                if toks[0] != DEVICE_TOKEN:
+                    sm.record_tokens(seq, toks)
                 # the blocks now wholly behind a window belong to no later
-                # query (a put that verifies drafts may yet be trimmed: its
-                # release waits for ``commit_tokens``)
+                # query: lengths say so, whatever the tokens are (a put
+                # that verifies drafts may yet be trimmed: its release
+                # waits for ``commit_tokens``)
                 released += sm.release_behind(seq)
         self._record_groups(released, group_read, group_pairs)
-        rows = logits[:len(uids)]
-        # the copy back is asked for now and follows the forward on the
-        # device's own queue: the scheduler's fetch then waits once, for
-        # the bytes, and not first for the program and then for the copy
-        start_copy = getattr(rows, "copy_to_host_async", None)
-        if start_copy is not None:
-            start_copy()
-        return rows
+        return logits
 
     def _record_groups(self, released: int, group_read, group_pairs) -> None:
         """The put's record by layer group, for a model that keeps more
@@ -664,13 +712,18 @@ class InferenceEngineV2:
         interaction contract)."""
         return self.state_manager.trim_sequence(uid, n_tokens)
 
-    def commit_tokens(self, uid: int, tokens: Sequence[int]) -> None:
-        """Advance the prefix-cache hash chain with verified tokens — the
-        second half of a ``put(defer_commit=True)`` step, called after
-        rejected drafts were trimmed. No-op when the cache is disabled."""
+    def commit_tokens(self, uid: int, tokens: Sequence[int],
+                      in_flight: int = 0) -> None:
+        """Advance the prefix-cache hash chain with tokens a put left
+        unrecorded: the second half of a ``put(defer_commit=True)`` step,
+        called after rejected drafts were trimmed, and of a
+        ``DEVICE_TOKEN`` row, called when the id has come back —
+        ``in_flight``: the tokens of this sequence put since, which the
+        chain does not hold yet either. No-op when the cache is
+        disabled."""
         seq = self.state_manager.get_sequence(uid)
         if seq is not None:
-            self.state_manager.record_tokens(seq, tokens)
+            self.state_manager.record_tokens(seq, tokens, in_flight)
             # the release a put that deferred its commit left undone
             self._count_released(self.state_manager.release_behind(seq))
 

@@ -46,6 +46,28 @@ from .kv_quant import quantized_block_write
 from .kv_write import block_write, touched_block_plan
 
 
+def _fed_tokens(tokens, next_ids, id_slots):
+    """``tokens`` with each negative first token replaced by its row's
+    slot of ``next_ids``: the token a forward before this one drew on the
+    device (``PagedCausalLM._forward``)."""
+    if next_ids is None:
+        return tokens
+    with jax.named_scope("embed"):
+        first = tokens[:, 0]
+        return tokens.at[:, 0].set(
+            jnp.where(first < 0, next_ids[id_slots], first))
+
+
+def _with_draw(logits, last_logits, new_cache, next_ids, id_slots):
+    """The forward's results, with the greedy draw over ``last_logits``
+    [N, V] written to the rows' slots of ``next_ids`` when there is such
+    a buffer (called under the ``logits`` scope)."""
+    if next_ids is None:
+        return logits, new_cache
+    drawn = jnp.argmax(last_logits, axis=-1).astype(next_ids.dtype)
+    return logits, new_cache, next_ids.at[id_slots].set(drawn)
+
+
 class PagedCausalLM:
     """Wraps a CausalLM's weights with a paged ragged forward.
 
@@ -147,11 +169,23 @@ class PagedCausalLM:
 
     # ------------------------------------------------------------------
     def _forward(self, params, kv_cache, tokens, start_pos, n_tokens,
-                 block_tables, state_slots=None, verify_width: int = 0):
+                 block_tables, state_slots=None, next_ids=None,
+                 id_slots=None, verify_width: int = 0):
         """tokens [N, C]; start_pos/n_tokens [N]; block_tables [N, MB];
         ``state_slots`` [N]: a hybrid model's rows' slots in the recurrent
         state tree, which then rides in ``kv_cache`` beside the pool
         (``_forward_hybrid``); None otherwise.
+
+        ``next_ids`` [slots + 1] int32 with ``id_slots`` [N] (the engine's;
+        None: a caller that keeps no such buffer): the next token of each
+        sequence, drawn on the device. A row whose first token is negative
+        takes ``next_ids[id_slots[row]]`` in its place -- the id an earlier
+        forward drew for its sequence, which never left the device -- and
+        every row writes the greedy draw over its last-position logits
+        (the first of equal maxima, as ``np.argmax``) to its slot; padded
+        rows point at the scratch slot behind the last. The buffer is
+        returned as a third result, a new array: the one passed in stays
+        readable.
         kv_cache {k,v}: [L, NB, KH, bs, D] — plus {k_scale,v_scale}
         [L, NB, KH] when the pools are int8-quantized (kv_quant.py); the
         pytree structure selects the compiled program, so the
@@ -169,7 +203,8 @@ class PagedCausalLM:
         if cfg.is_hybrid:
             return self._forward_hybrid(params, kv_cache, tokens, start_pos,
                                         n_tokens, block_tables, state_slots,
-                                        verify_width)
+                                        next_ids, id_slots, verify_width)
+        tokens = _fed_tokens(tokens, next_ids, id_slots)
         N, C = tokens.shape
         bs = self.block_size
         NB = kv_cache["k"].shape[1]
@@ -295,17 +330,20 @@ class PagedCausalLM:
                                0, C - 1)                          # [N, W]
                 x_v = jnp.take_along_axis(x, idx[:, :, None],
                                           axis=1)                 # [N,W,H]
-                return self.model._unembed(params, x_v), new_cache
+                logits = self.model._unembed(params, x_v)
+                return _with_draw(logits, logits[:, -1], new_cache,
+                                  next_ids, id_slots)
             # logits_gather: only the last valid token per sequence
             last_idx = jnp.clip(n_tokens - 1, 0, C - 1)
             x_last = jnp.take_along_axis(x, last_idx[:, None, None],
                                          axis=1)[:, 0]
             logits = self.model._unembed(params, x_last[:, None, :])[:, 0]
-            return logits, new_cache
+            return _with_draw(logits, logits, new_cache, next_ids, id_slots)
 
     # ------------------------------------------------------------------
     def _forward_hybrid(self, params, cache, tokens, start_pos, n_tokens,
-                        block_tables, state_slots, verify_width: int = 0):
+                        block_tables, state_slots, next_ids=None,
+                        id_slots=None, verify_width: int = 0):
         """The forward of a hybrid block (``cfg.layer_pattern``,
         models/hybrid.py): the lead layers, then one scan over the
         periods, the period's layers in order inside the body. ``cache``
@@ -327,7 +365,8 @@ class PagedCausalLM:
           from zero whatever its slot holds; any other resumes from its
           slot. Positions at or beyond ``n_tokens`` change no state.
 
-        Returns (last_logits [N, V], new cache)."""
+        Returns (last_logits [N, V], new cache) and, given ``next_ids``,
+        the buffer with this forward's draws (``_forward``)."""
         from ...models import hybrid
 
         if verify_width:
@@ -336,6 +375,7 @@ class PagedCausalLM:
                 "hybrid block's recurrent state cannot be cut at a token "
                 "and its window layers' blocks may be gone")
         cfg = self.cfg
+        tokens = _fed_tokens(tokens, next_ids, id_slots)
         N, C = tokens.shape
         bs = self.block_size
         dt = cfg.dtype
@@ -458,4 +498,4 @@ class PagedCausalLM:
             x_last = jnp.take_along_axis(x, last_idx[:, None, None],
                                          axis=1)[:, 0]
             logits = self.model._unembed(params, x_last[:, None, :])[:, 0]
-            return logits, new_cache
+            return _with_draw(logits, logits, new_cache, next_ids, id_slots)

@@ -68,6 +68,11 @@ class DSSequenceDescriptor:
     # a hybrid model's recurrent layers: this sequence's slot in the
     # state tree (-1: the model has none)
     state_slot: int = -1
+    # this sequence's slot in the engine's next-token buffer, kept while
+    # it is tracked: the forward writes the id it draws for the row there
+    # and a later one-token row may take its token from it
+    # (``InferenceEngineV2.next_ids``)
+    id_slot: int = -1
     # K/V by layer group: ``kv_blocks`` is the first group's table, these
     # are the further groups'; every table is indexed by the position's
     # block and as long as the context, and a block a window group has
@@ -253,6 +258,10 @@ class DSStateManager:
         # starts from zero whatever its slot holds (paged_model.py).
         self.state_cache: Dict[str, jax.Array] = {}
         self._free_slots: List[int] = list(range(self.state_slots))[::-1]
+        # next-token slots, one a tracked sequence (any model); the slot
+        # behind the last is scratch, a padded batch row's
+        self.id_slots = int(max_tracked_sequences)
+        self._free_id_slots: List[int] = list(range(self.id_slots))[::-1]
         if self.recurrent:
             from ....models.hybrid import state_shapes
 
@@ -312,6 +321,7 @@ class DSStateManager:
                 if not self._free_slots:
                     raise RuntimeError("no free recurrent-state slot")
                 seq.state_slot = self._free_slots.pop()
+            seq.id_slot = self._free_id_slots.pop()
             self._seqs[uid] = seq
         return self._seqs[uid]
 
@@ -330,8 +340,14 @@ class DSStateManager:
                 live = [b for b in table if b >= 0]
                 if live:
                     self._release_blocks(live, g)
-        if seq is not None and seq.state_slot >= 0:
+        if seq is not None:
+            self._give_slots(seq)
+
+    def _give_slots(self, seq: DSSequenceDescriptor) -> None:
+        """The slots of a sequence that is no longer tracked."""
+        if seq.state_slot >= 0:
             self._free_slots.append(seq.state_slot)
+        self._free_id_slots.append(seq.id_slot)
 
     def _release_blocks(self, blocks: List[int], group: int = 0) -> None:
         """Drop one reference per block and keep the incremental
@@ -667,8 +683,7 @@ class DSStateManager:
         except Exception:
             self._seqs.pop(uid, None)
             self.allocator.release(blocks)
-            if seq.state_slot >= 0:
-                self._free_slots.append(seq.state_slot)
+            self._give_slots(seq)
             raise
 
     @property
@@ -962,11 +977,13 @@ class DSStateManager:
         return n
 
     def record_tokens(self, seq: DSSequenceDescriptor,
-                      tokens: Sequence[int]) -> None:
+                      tokens: Sequence[int], in_flight: int = 0) -> None:
         """Advance the sequence's hash chain with tokens just written to
         its KV blocks; each block that becomes full is registered in the
         index (prompt and generated tokens alike — a later request whose
-        prompt extends this conversation reuses both)."""
+        prompt extends this conversation reuses both). ``in_flight``: the
+        tokens written behind these whose ids are not known yet (they
+        follow in a call of their own)."""
         if not self.prefix_cache_enabled:
             return
         # chain-state consistency guard: hashing is only valid when the
@@ -975,7 +992,7 @@ class DSStateManager:
         # its content under wrong positions). An inconsistent sequence
         # skips without extending state, so it stays skipped.
         if (seq.hashed_blocks * self.block_size + len(seq.pending_tokens)
-                != seq.seen_tokens - len(tokens)):
+                != seq.seen_tokens - len(tokens) - in_flight):
             return
         seq.pending_tokens.extend(int(t) for t in tokens)
         while len(seq.pending_tokens) >= self.block_size:

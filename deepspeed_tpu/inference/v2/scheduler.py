@@ -18,10 +18,20 @@ argmax agrees with, and rejected tokens are rolled back with
 ``engine.trim_sequence``. The emitted stream is byte-identical to
 speculation off; with no proposer the scheduler is byte-for-byte the
 historical one.
+
+One step in flight (docs/SERVING.md "A step in flight"): the forward draws
+each row's next token on the device and keeps it there
+(``engine.next_ids``); the next step's one-token rows say "my token is my
+slot's id" (``DEVICE_TOKEN``) and are planned from counts alone, so
+``step`` dispatches step n+1 *before* it reads step n's ids, and the host's
+turn — pack, stage, commit — runs while the device works. A scheduler
+given a host ``sample_fn`` or a proposer needs every token on the host
+before it can plan, and runs the same code with nothing left in flight.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import OrderedDict, deque
@@ -31,7 +41,7 @@ import numpy as np
 
 from ...telemetry import NOOP_TRACER
 from ...utils.logging import logger
-from .engine_v2 import FORWARD_ONLY, InferenceEngineV2
+from .engine_v2 import DEVICE_TOKEN, FORWARD_ONLY, InferenceEngineV2
 from .scheduling_utils import SchedulingResult
 from .spec import DraftProposer, verify_greedy
 
@@ -60,7 +70,12 @@ class Request:
     prefix_matched: int = -1     # tokens served from the prefix cache
     #                              (-1 = lookup not yet performed)
     generated: List[int] = dataclasses.field(default_factory=list)
-    last_logits: Optional[np.ndarray] = None
+    # the logits ``generated[-1]`` was (or the first token will be) drawn
+    # from: an array, or a row of a put's handle that is copied from the
+    # device only when read (``np.asarray``)
+    last_logits: Optional[object] = None
+    # rows dispatched for this request whose draw has not been read yet
+    in_flight: int = 0
     done: bool = False
     finish_reason: Optional[str] = None
     # telemetry (docs/OBSERVABILITY.md): set by submit() when the
@@ -72,6 +87,37 @@ class Request:
     @property
     def prompt_remaining(self) -> int:
         return len(self.prompt_tokens) - self.prompt_fed
+
+
+class _LogitsRow:
+    """Row ``i`` of a put's logits, read from the device when asked."""
+
+    __slots__ = ("handle", "i")
+
+    def __init__(self, handle, i: int):
+        self.handle, self.i = handle, i
+
+    def __array__(self, dtype=None, copy=None):
+        row = np.asarray(self.handle)[self.i]
+        return row if dtype is None else row.astype(dtype)
+
+
+class _Flight:
+    """A dispatched step whose tokens have not been read: its plan, the
+    put's handle, which rows draw a token (decode rows and the rows that
+    complete a prompt), the tokens it fed by uid, whether its commit
+    reads the logits (a host sampler's, a verification's: else the ids
+    the forward drew), and its open ``forward`` span."""
+
+    __slots__ = ("plan", "handle", "draws", "fed", "verify_width",
+                 "reads_logits", "fspan")
+
+    def __init__(self, plan, handle, draws, verify_width, reads_logits,
+                 fspan):
+        self.plan, self.handle, self.draws = plan, handle, draws
+        self.fed = {req.uid: len(chunk) for req, chunk, _ in plan}
+        self.verify_width, self.reads_logits = verify_width, reads_logits
+        self.fspan = fspan
 
 
 class ContinuousBatchingScheduler:
@@ -102,7 +148,10 @@ class ContinuousBatchingScheduler:
         self.pending: Deque[Request] = deque()
         self.running: Dict[int, Request] = {}
         self.finished: Dict[int, Request] = {}
-        self.sample_fn = sample_fn or (lambda logits: int(np.argmax(logits)))
+        # a host sampler has to see the logits before the next step can be
+        # planned; without one the forward's own greedy draw is the token
+        self.sample_fn = sample_fn
+        self._draw = sample_fn or (lambda logits: int(np.argmax(logits)))
         self._budget = engine.config.max_ragged_batch_size
         self._max_seqs = engine.config.max_ragged_sequence_count
         self._chunk = engine.config.max_chunk_tokens
@@ -115,6 +164,14 @@ class ContinuousBatchingScheduler:
                 "speculative decoding requires greedy sampling; custom "
                 "sample_fn given — proposer disabled for this scheduler")
             self.proposer = None
+        # whether a step may be left in flight while the next is planned:
+        # not when a token has to be on the host first (a sampler's
+        # logits, a proposer's context)
+        self._run_ahead = sample_fn is None and self.proposer is None
+        self._flight: Optional[_Flight] = None  # dispatched, unread
+        self._stepping = False                  # inside step()
+        self._done_now: List[int] = []          # finished since step() said
+        self._step_stats = {"steps": 0, "steps_overlapped": 0}
         self._spec_stats = {"proposed": 0, "accepted": 0, "emitted": 0,
                             "decode_rows": 0}
         self._proposer_warned = False
@@ -150,6 +207,12 @@ class ContinuousBatchingScheduler:
     @property
     def spec_enabled(self) -> bool:
         return self.proposer is not None
+
+    def step_stats(self) -> Dict[str, int]:
+        """Monotonic counters: ``steps`` dispatched, and of them
+        ``steps_overlapped`` — dispatched while the step before was still
+        unread, so that the host's turn ran behind the device's."""
+        return dict(self._step_stats)
 
     def spec_stats(self) -> Dict[str, int]:
         """Monotonic speculative-decoding counters: ``proposed``/
@@ -194,13 +257,18 @@ class ContinuousBatchingScheduler:
         prefill-role replica (``engine.import_sequence`` must have run
         first): the request enters ``running`` directly with the prompt
         marked fed and the source's final-position logits, so the first
-        decode step samples exactly the token the source would have —
-        byte-lossless under greedy decoding (docs/SERVING.md
-        "Disaggregated serving")."""
+        token is drawn (on the host, at the next step) from exactly the
+        logits the source would have drawn it from — byte-lossless under
+        greedy decoding (docs/SERVING.md "Disaggregated serving"). Where
+        the imported KV is short of ``prompt_tokens`` — a sequence
+        evacuated in mid-decode, whose last delivered token had not been
+        fed yet — the rest is fed as a prompt chunk and ``last_logits``
+        is not read."""
         req = Request(uid, list(prompt_tokens), max_new_tokens,
                       eos_token_id, on_token, on_finish,
                       shed_rank=int(shed_rank))
-        req.prompt_fed = len(req.prompt_tokens)
+        req.prompt_fed = min(len(req.prompt_tokens),
+                             self.engine.query(uid)[0])
         req.prefix_matched = 0       # no lookup: the KV arrived whole
         req.last_logits = np.asarray(last_logits)
         if self.reservation:
@@ -226,6 +294,8 @@ class ContinuousBatchingScheduler:
         (serving's cancel path — the blocks go back to the pool this step,
         not when the sequence would have finished). Returns False for
         unknown/already-finished uids."""
+        if uid in self.running:
+            self._settle()      # its row in flight, if any, is read first
         req = self.running.pop(uid, None)
         if req is None:
             # a preempted (parked) sequence holds no device blocks —
@@ -249,6 +319,7 @@ class ContinuousBatchingScheduler:
         self._end_request_spans(req, "cancelled")
         req.done = True
         req.finish_reason = "cancelled"
+        req.last_logits = None      # not its last put's logits, kept alive
         self.finished[uid] = req
         if req.on_finish is not None:
             req.on_finish(req, "cancelled")
@@ -270,6 +341,8 @@ class ContinuousBatchingScheduler:
         Returns ``None`` also for unknown/finished uids (nothing to
         move)."""
         payload = None
+        if uid in self.running:
+            self._settle()      # its row in flight, if any, is read first
         req = self.running.pop(uid, None)
         if req is not None:
             if (req.prompt_remaining == 0 and not req.done
@@ -282,7 +355,7 @@ class ContinuousBatchingScheduler:
                                    "to re-prefill")
                     payload = None
                 if payload is not None:
-                    payload["last_logits"] = req.last_logits
+                    payload["last_logits"] = np.asarray(req.last_logits)
         else:
             # parked sequence: its device blocks are already free and
             # its payload sits in the preempt stash — drop the stash
@@ -312,15 +385,21 @@ class ContinuousBatchingScheduler:
 
     @property
     def has_work(self) -> bool:
-        return bool(self.pending or self.running or self.preempted)
+        return bool(self.pending or self.running or self.preempted
+                    or self._flight is not None)
 
     def _pack(self):
         """Dynamic SplitFuse packing: decodes first, then prompt chunks.
 
-        Pure planning — no request state is mutated here (so a failed
-        forward can be retried); admission is checked incrementally for
-        decodes AND prompt chunks, deferring what doesn't fit to the next
-        step."""
+        Planning from counts — what a request has been fed and how many
+        tokens it has drawn, the one in flight included — so a step is
+        planned before the step ahead of it has been read: a decode row
+        whose token is still on the device carries ``DEVICE_TOKEN``. No
+        request state moves here (a put that fails before its dispatch can
+        be retried; ``_dispatch`` moves it), but for a sequence handed
+        over with its logits, whose first token is drawn from them here.
+        Admission is checked incrementally for decodes AND prompt chunks,
+        deferring what doesn't fit to the next step."""
         uids: List[int] = []
         chunks: List[List[int]] = []
         plan: List[tuple] = []        # (req, chunk, is_decode)
@@ -372,7 +451,16 @@ class ContinuousBatchingScheduler:
                 break     # prefill-role: decode rows never pack here
             if req.prompt_remaining > 0 or budget <= 0:
                 continue  # still prefilling (below) / out of budget (defer)
-            tok = self.sample_fn(req.last_logits)
+            drawn = len(req.generated) + req.in_flight
+            if not drawn:
+                # handed over with the logits of its prompt's last
+                # position (``submit_prefilled``): the first token is
+                # drawn from them, on the host
+                self._deliver(req, self._draw(np.asarray(req.last_logits)))
+                drawn = 1
+            if req.done or drawn >= req.max_new_tokens:
+                continue  # its last token is drawn: nothing more to feed
+            tok = DEVICE_TOKEN if req.in_flight else req.generated[-1]
             chunk = [tok]
             if self.proposer is not None:
                 # cap drafts so the chunk fits every static budget; the
@@ -381,7 +469,7 @@ class ContinuousBatchingScheduler:
                 k = min(self.max_draft_tokens, budget - 1, self._chunk - 1,
                         req.max_new_tokens - len(req.generated) - 1)
                 if k > 0:
-                    drafts = self._propose(req, tok, k)
+                    drafts = self._propose(req, k)
                     if drafts:
                         chunk = [tok] + [int(d) for d in drafts[:k]]
             if admit(req, chunk):
@@ -573,6 +661,7 @@ class ContinuousBatchingScheduler:
         cap = self.oversubscription_factor * self.engine.config.kv_blocks
         if committed + total > cap:
             return False
+        self._settle()      # victims are chosen among sequences at rest
         victims = self._eligible_victims(min_rank=req.shed_rank)
         have = self.engine.query(req.uid)[1]     # prefix-matched credit
         shortfall = (max(0, total - have)
@@ -597,6 +686,7 @@ class ContinuousBatchingScheduler:
         if not self.preempt_enabled:
             return
         while self.engine.reservation_headroom() < 0:
+            self._settle()  # victims are chosen among sequences at rest
             victims = self._eligible_victims()
             if not victims:
                 return
@@ -608,13 +698,16 @@ class ContinuousBatchingScheduler:
         when a tier is configured — free its device blocks, and park it
         for a later byte-lossless resume. Returns the blocks freed."""
         t0 = time.perf_counter()
+        self._settle()
         uid = req.uid
         payload = self.engine.export_sequence(uid)
         n_blocks = int(payload["n_blocks"]) if payload else 0
         if payload is not None:
             self.engine.preempt_stash(uid, payload)
-        # the tokens the exported KV encodes: fed prompt + committed
-        # generation — what import_sequence replays into the prefix index
+        # everything delivered or fed: the fed prompt and the generation.
+        # The exported KV encodes all of it but a last drawn token, which
+        # had not been fed yet — what import_sequence replays into the
+        # prefix index is cut to the KV's length at resume
         tokens = req.prompt_tokens[:req.prompt_fed] + list(req.generated)
         self.engine.flush(uid)        # frees blocks + releases reservation
         self.running.pop(uid, None)
@@ -623,7 +716,9 @@ class ContinuousBatchingScheduler:
         req.preempt_count += 1
         self.preempted[uid] = {
             "req": req, "tokens": tokens, "stashed": payload is not None,
-            "last_logits": req.last_logits, "fed": req.prompt_fed,
+            # read only by a sequence that has drawn nothing yet
+            "last_logits": None if req.generated else req.last_logits,
+            "fed": req.prompt_fed,
             "n_blocks": n_blocks,
             "total_blocks": req.total_blocks or self._total_blocks(req)}
         self._parked_blocks += n_blocks
@@ -639,7 +734,7 @@ class ContinuousBatchingScheduler:
         full-reservation headroom exist (strict FIFO: resuming younger,
         smaller sequences over the head would starve it). The spilled
         payload imports byte-losslessly — the resumed sequence decodes
-        from the exact logits it was parked with; a payload the tier
+        from the token it was parked with; a payload the tier
         dropped (byte bounds, disk corruption) degrades to a greedy
         re-prefill of prompt + delivered tokens, the failover resume
         semantics."""
@@ -656,8 +751,9 @@ class ContinuousBatchingScheduler:
                        if entry["stashed"] else None)
             if payload is not None:
                 try:
-                    self.engine.import_sequence(uid, payload,
-                                                tokens=entry["tokens"])
+                    self.engine.import_sequence(
+                        uid, payload,
+                        tokens=entry["tokens"][:payload["seen_tokens"]])
                 except Exception as e:
                     logger.warning(
                         f"preemption resume import for sequence {uid} "
@@ -721,7 +817,7 @@ class ContinuousBatchingScheduler:
         out, self._preempt_events = self._preempt_events, []
         return out
 
-    def _propose(self, req: Request, tok: int, k: int) -> List[int]:
+    def _propose(self, req: Request, k: int) -> List[int]:
         """Fetch drafts, isolating the scheduler from proposer faults —
         proposers are advisory, so any exception degrades to "no drafts"
         (warned once) instead of killing the serving step loop. Proposers
@@ -729,16 +825,14 @@ class ContinuousBatchingScheduler:
         saving a full-history list rebuild per decode row per step."""
         win = getattr(self.proposer, "context_window", None)
         if win is None:
-            ctx = req.prompt_tokens + req.generated + [tok]
+            ctx = req.prompt_tokens + req.generated
         else:
-            need = max(win - 1, 0)
             gen = req.generated
-            if len(gen) >= need:
-                ctx = gen[len(gen) - need:] + [tok]
+            if len(gen) >= win:
+                ctx = gen[len(gen) - win:]
             else:
                 ctx = (req.prompt_tokens[max(0, len(req.prompt_tokens)
-                                             - (need - len(gen))):]
-                       + gen + [tok])
+                                             - (win - len(gen))):] + gen)
         try:
             return self.proposer.propose(req.uid, ctx, k)
         except Exception as e:
@@ -774,171 +868,256 @@ class ContinuousBatchingScheduler:
         req.spans = None
 
     def step(self) -> List[int]:
-        """One engine forward; returns uids of requests finished this step.
+        """Dispatch one engine forward and retire the one before it;
+        returns the uids of the requests finished since the last call.
 
         Traced (docs/OBSERVABILITY.md "Trace model"), a step is a ``step``
-        span on the scheduler's trace with one child per phase, each
-        mirrored into an open profiler session as ``ds:<name>``:
+        span on the scheduler's trace (``overlapped``: whether its forward
+        was dispatched while the one before was unread) with one child per
+        phase, each mirrored into an open profiler session as
+        ``ds:<name>`` — of the step it dispatches:
 
-        - ``pack``: :meth:`_pack` — admission, prefix matching, the
-          chunks, and **sampling**: each decode row's next token is drawn
-          from its last logits here (``sample_fn``), drafts included;
+        - ``pack``: :meth:`_pack` — admission, prefix matching and the
+          chunks, planned from counts (no sampling: a decode row's token
+          is its sequence's last draw, on the device or on the host;
+          drafts are proposed here);
         - ``stage``: the host part of ``engine.put`` — scheduling check,
           KV allocation, ``batch.finalize``, uploads and the dispatch;
           attrs are the engine's record of the put (``last_put``);
-        - ``fetch``: ``np.asarray`` of the logits — the wait for the
-          device plus the copy back;
-        - ``commit``: the per-row loop — commit, finish, flush and the
-          ``on_token`` / ``on_finish`` callbacks (inside ``spec_verify``
-          on a speculative step).
 
-        The ``forward`` span (``stage`` + ``fetch`` in one interval) is
-        kept for its readers. One call site each, traced or not: a
+        and then of the step before it, which ran meanwhile:
+
+        - ``fetch``: the wait for that forward and the copy back of what
+          its commit reads — the drawn ids, a few bytes a row (the
+          logits, for a host sampler or a verification);
+        - ``commit``: the per-row loop — append, stream ``on_token``,
+          finish, flush and ``on_finish`` (inside ``spec_verify`` on a
+          speculative step).
+
+        A scheduler that cannot run ahead (``sample_fn``, a proposer)
+        retires the step it has just dispatched: the same four phases,
+        all of one step. The ``forward`` span runs from a step's dispatch
+        to the end of its fetch. One call site each, traced or not: a
         disabled tracer hands out the shared no-op span."""
         tracer = self.tracer
-        traced = tracer.enabled
-        with tracer.span("step", trace_id=self.trace_label):
-            with tracer.span("pack"):
-                uids, chunks, plan = self._pack()
-            if not uids:
-                return []
-            # verification width: the widest speculative decode chunk this
-            # step, bucketed (pow2) to bound compiled-program variants.
-            # Steps with no drafts in flight — pure prefill, draft-less
-            # decode — take the exact historical path.
-            spec_w = max((len(c) for _, c, d in plan if d and len(c) > 1),
-                         default=0)
-            speculative = self.proposer is not None and spec_w > 0
-            # speculative step: right-aligned trailing-position logits for
-            # verification; the prefix-cache hash chain is committed
-            # per-row below, once rejected drafts have been trimmed (the
-            # index must never see tokens a trim can roll back)
-            W = self.engine.batch._bucket(spec_w, self._chunk) \
-                if speculative else 0
-            put_kw = {"verify_width": W, "defer_commit": True} \
-                if speculative else {}
-            fspan = vspan = None
-            if traced:
-                fspan = tracer.begin(
-                    "forward", trace_id=self.trace_label,
-                    attrs={"n_seqs": len(uids),
-                           "n_tokens": int(sum(len(c) for c in chunks))})
-                if speculative:
-                    fspan.set("verify_width", W)
-            with tracer.span("stage") as sspan:
-                logits = self.engine.put(uids, chunks, **put_kw)
-                if traced:
-                    record = self.engine.last_put
-                    fspan.attrs.update(record)
-                    # ``stage`` keeps the keys it had: the benchmark's
-                    # agreement test holds them to its own wrapper's
-                    sspan.attrs.update(
-                        {k: v for k, v in record.items()
-                         if not k.startswith(FORWARD_ONLY)})
-            with tracer.span("fetch"):
-                logits = np.asarray(logits)
-            if traced:
-                fspan.end()
-                if speculative:
-                    # host-side verify/trim/commit of this step, as its
-                    # own span
-                    vspan = tracer.begin("spec_verify",
-                                         trace_id=self.trace_label,
-                                         attrs={"verify_width": W})
-            with tracer.span("commit"):
-                done_now = self._commit(plan, logits, speculative)
-            if vspan is not None:
-                vspan.end()
-            return done_now
+        self._stepping = True
+        try:
+            with tracer.span("step", trace_id=self.trace_label) as span:
+                with tracer.span("pack"):
+                    uids, chunks, plan = self._pack()
+                # (packing may have retired it: a preemption does)
+                ahead, newer = self._flight, None
+                if uids:
+                    newer = self._dispatch(uids, chunks, plan)
+                    self._step_stats["steps"] += 1
+                    self._step_stats["steps_overlapped"] += ahead is not None
+                span.set("overlapped", bool(uids) and ahead is not None)
+                if ahead is not None:
+                    self._retire(ahead, newer)
+                self._flight = newer
+                if not self._run_ahead:
+                    self._settle()
+        finally:
+            self._stepping = False
+        done, self._done_now = self._done_now, []
+        return done
 
-    def _commit(self, plan, logits, speculative: bool) -> List[int]:
-        """Commit one step's rows — only after the forward succeeded."""
-        done_now = []
-        for i, (req, chunk, is_decode) in enumerate(plan):
-            if not speculative:
-                req.last_logits = logits[i]
-                if is_decode:
-                    if not req.generated:
-                        self._note_first_token(req)
-                    req.generated.append(chunk[0])
-                    self._spec_stats["decode_rows"] += 1
-                    self._spec_stats["emitted"] += 1
-                    if req.on_token is not None:
-                        req.on_token(req.uid, chunk[0])
-                else:
+    def _dispatch(self, uids, chunks, plan) -> _Flight:
+        """Put one planned step and move what is planned from counts."""
+        tracer = self.tracer
+        traced = tracer.enabled
+        # verification width: the widest speculative decode chunk this
+        # step, bucketed (pow2) to bound compiled-program variants.
+        # Steps with no drafts in flight — pure prefill, draft-less
+        # decode — take the exact historical path.
+        spec_w = max((len(c) for _, c, d in plan if d and len(c) > 1),
+                     default=0)
+        speculative = self.proposer is not None and spec_w > 0
+        # speculative step: right-aligned trailing-position logits for
+        # verification; the prefix-cache hash chain is committed
+        # per-row at the commit, once rejected drafts have been trimmed
+        # (the index must never see tokens a trim can roll back)
+        W = self.engine.batch._bucket(spec_w, self._chunk) \
+            if speculative else 0
+        put_kw = {"verify_width": W, "defer_commit": True} \
+            if speculative else {}
+        fspan = None
+        if traced:
+            fspan = tracer.begin(
+                "forward", trace_id=self.trace_label,
+                attrs={"n_seqs": len(uids),
+                       "n_tokens": int(sum(len(c) for c in chunks))})
+            if speculative:
+                fspan.set("verify_width", W)
+        with tracer.span("stage") as sspan:
+            handle = self.engine.put(uids, chunks, **put_kw)
+            if traced:
+                record = self.engine.last_put
+                fspan.attrs.update(record)
+                # ``stage`` keeps the keys it had: the benchmark's
+                # agreement test holds them to its own wrapper's
+                sspan.attrs.update(
+                    {k: v for k, v in record.items()
+                     if not k.startswith(FORWARD_ONLY)})
+            reads_logits = speculative or self.sample_fn is not None
+            if reads_logits:
+                handle.prefetch()
+            # the forward is out: the counts the next step is planned
+            # from move now, the values follow when the step is retired
+            draws = []
+            for req, chunk, is_decode in plan:
+                if not is_decode:
                     req.prompt_fed += len(chunk)
                     self.running[req.uid] = req
-            elif is_decode:
+                draws.append(req.prompt_remaining == 0)
+                req.in_flight += draws[-1]
+        return _Flight(plan, handle, draws, W, reads_logits, fspan)
+
+    def _settle(self) -> None:
+        """Retire what is in flight, so that every running request is at
+        rest — its delivered tokens are all it has drawn: what a call
+        that reads or edits a sequence from outside the step does first
+        (``cancel``, ``evacuate``, a preemption). Outside ``step`` the
+        retirement is a ``step`` span of its own."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        with contextlib.nullcontext() if self._stepping else \
+                self.tracer.span("step", trace_id=self.trace_label,
+                                 attrs={"overlapped": False}):
+            self._retire(flight, None)
+
+    def _retire(self, flight: _Flight, newer: Optional[_Flight]) -> None:
+        """Read a dispatched step's tokens and commit its rows; ``newer``:
+        the step dispatched behind it, still in flight."""
+        tracer = self.tracer
+        W = flight.verify_width
+        logits = tokens = None
+        with tracer.span("fetch"):
+            if flight.reads_logits:
+                logits = np.asarray(flight.handle)
+            else:
+                tokens = flight.handle.next_tokens()
+        vspan = None
+        if flight.fspan is not None:
+            flight.fspan.end()
+            if W:
+                # host-side verify/trim/commit of this step, as its
+                # own span
+                vspan = tracer.begin("spec_verify",
+                                     trace_id=self.trace_label,
+                                     attrs={"verify_width": W})
+        with tracer.span("commit"):
+            self._commit(flight, logits, tokens, newer)
+        if vspan is not None:
+            vspan.end()
+
+    def _commit(self, flight: _Flight, logits, tokens,
+                newer: Optional[_Flight]) -> None:
+        """Commit one retired step's rows: ``tokens`` [rows], the
+        forward's own greedy draws, or ``logits`` for a host sampler
+        ([rows, vocab]) and a verification ([rows, W, vocab])."""
+        handle, W = flight.handle, flight.verify_width
+        for i, (req, chunk, is_decode) in enumerate(flight.plan):
+            req.in_flight -= flight.draws[i]
+            if req.done:
+                # ended while this row flew (it ran past an EOS the host
+                # had not seen): nothing of it is delivered, and its
+                # blocks went back with the sequence
+                continue
+            if W and is_decode:
                 # row i's valid positions are right-aligned: the last
                 # len(chunk) slots
                 self._apply_verified(req, chunk,
                                      logits[i, logits.shape[1] - len(chunk):])
-            else:
-                req.last_logits = logits[i, -1]   # slot W-1 = last valid
+                continue
+            if W:
                 self.engine.commit_tokens(req.uid, chunk)
-                req.prompt_fed += len(chunk)
-                self.running[req.uid] = req
-            if req.prompt_remaining > 0:
-                continue  # mid-prefill: sample only once the prompt is done
+            elif chunk[0] == DEVICE_TOKEN:
+                # the token this row was fed on the device is the one
+                # delivered last; the row behind it is unrecorded too
+                self.engine.commit_tokens(
+                    req.uid, req.generated[-1:],
+                    newer.fed.get(req.uid, 0) if newer else 0)
+            # (a verification's slot W-1 is the row's last valid position)
+            row = None if logits is None else \
+                logits[i, -1] if W else logits[i]
+            req.last_logits = _LogitsRow(handle, i) if row is None else row
+            if not flight.draws[i]:
+                continue  # mid-prefill: a token once the prompt is done
             if self.prefill_only:
                 # prompt complete on a prefill-role scheduler: stop here.
                 # The KV is deliberately NOT flushed — the serving layer
                 # exports it for the decode-role handoff and flushes once
                 # the payload is staged (docs/SERVING.md "Disaggregated
                 # serving"); last_logits carries the final-position
-                # logits the destination samples its first token from.
-                req.done = True
-                req.finish_reason = "prefilled"
-                self._end_request_spans(req, "prefilled")
-                self.finished[req.uid] = req
-                self.running.pop(req.uid, None)
-                if self.proposer is not None:
-                    self.proposer.release(req.uid)
-                done_now.append(req.uid)
-                if req.on_finish is not None:
-                    req.on_finish(req, "prefilled")
+                # logits the destination draws its first token from
+                req.last_logits = np.asarray(req.last_logits)
+                self._finish(req, "prefilled")
                 continue
-            ended = (req.eos_token_id is not None and req.generated
-                     and req.generated[-1] == req.eos_token_id)
-            if len(req.generated) >= req.max_new_tokens or ended:
-                req.done = True
-                req.finish_reason = "eos" if ended else "length"
-                self._end_request_spans(req, req.finish_reason)
-                self.finished[req.uid] = req
-                self.running.pop(req.uid, None)
-                self.engine.flush(req.uid)
-                if self.proposer is not None:
-                    self.proposer.release(req.uid)
-                done_now.append(req.uid)
-                if req.on_finish is not None:
-                    req.on_finish(req, req.finish_reason)
-        return done_now
+            if is_decode:
+                self._spec_stats["decode_rows"] += 1
+                self._spec_stats["emitted"] += 1
+            self._deliver(req, int(tokens[i]) if row is None
+                          else self._draw(row))
+
+    def _deliver(self, req: Request, tok: int) -> None:
+        """One drawn token: appended, streamed, and the request finished
+        if it was its last."""
+        if not req.generated:
+            self._note_first_token(req)
+        req.generated.append(tok)
+        if req.on_token is not None:
+            req.on_token(req.uid, tok)
+        ended = req.eos_token_id is not None and tok == req.eos_token_id
+        if ended or len(req.generated) >= req.max_new_tokens:
+            self._finish(req, "eos" if ended else "length")
+
+    def _finish(self, req: Request, reason: str) -> None:
+        req.done = True
+        req.finish_reason = reason
+        self._end_request_spans(req, reason)
+        self.finished[req.uid] = req
+        self.running.pop(req.uid, None)
+        if reason != "prefilled":
+            self.engine.flush(req.uid)
+            # (a finished request would keep its last put's logits on
+            # the device for as long as ``finished`` keeps the request)
+            req.last_logits = None
+        if self.proposer is not None:
+            self.proposer.release(req.uid)
+        self._done_now.append(req.uid)
+        if req.on_finish is not None:
+            req.on_finish(req, reason)
 
     def _apply_verified(self, req: Request, chunk: List[int],
                         rows: np.ndarray) -> None:
         """Verify one speculative decode row and commit the outcome:
         accept the longest target-agreeing draft prefix, trim the rejected
         tail out of the KV cache, advance the prefix-cache chain with the
-        surviving tokens only, and stream the emitted tokens (stopping at
-        EOS — exactly where plain greedy decoding would have stopped)."""
-        emitted, last = verify_greedy(chunk, rows)
+        surviving tokens only, and stream what the row has proven — the
+        accepted drafts and the token after them (``chunk[0]`` was
+        delivered when it was drawn) — stopping at EOS, exactly where
+        plain greedy decoding would have stopped."""
+        kept, last = verify_greedy(chunk, rows)
+        emitted = kept[1:] + [int(np.argmax(rows[last]))]
         if req.eos_token_id is not None and req.eos_token_id in emitted:
             # tokens the target accepted beyond EOS are never delivered —
             # truncate BEFORE trim/commit/stats so the KV state, the
             # prefix chain, and the counters all describe exactly the
             # stream the request receives
-            cut = emitted.index(req.eos_token_id) + 1
-            emitted, last = emitted[:cut], cut - 1
-        rejected = len(chunk) - len(emitted)
+            emitted = emitted[:emitted.index(req.eos_token_id) + 1]
+            kept = kept[:len(emitted) + 1]
+        accepted = len(kept) - 1
+        rejected = len(chunk) - len(kept)
         if rejected:
             self.engine.trim_sequence(req.uid, rejected)
-        self.engine.commit_tokens(req.uid, emitted)
+        self.engine.commit_tokens(req.uid, kept)
         req.last_logits = rows[last]
         self._spec_stats["decode_rows"] += 1
         self._spec_stats["proposed"] += len(chunk) - 1
-        self._spec_stats["accepted"] += len(emitted) - 1
-        if not req.generated and emitted:
-            self._note_first_token(req)
+        self._spec_stats["accepted"] += accepted
+        self._spec_stats["emitted"] += len(emitted)
         if req.spans is not None:
             # accumulate this request's speculation outcome on its decode
             # span — "how many of MY tokens came from accepted drafts"
@@ -946,12 +1125,11 @@ class ContinuousBatchingScheduler:
             if dec is not None:
                 a = dec.attrs
                 a["spec_proposed"] = a.get("spec_proposed", 0) + len(chunk) - 1
-                a["spec_accepted"] = a.get("spec_accepted", 0) + len(emitted) - 1
+                a["spec_accepted"] = a.get("spec_accepted", 0) + accepted
         for t in emitted:
-            req.generated.append(t)
-            self._spec_stats["emitted"] += 1
-            if req.on_token is not None:
-                req.on_token(req.uid, t)
+            if req.done:
+                break
+            self._deliver(req, t)
 
     def run_to_completion(self, max_steps: int = 10000) -> Dict[int, Request]:
         steps = 0

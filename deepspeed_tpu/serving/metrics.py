@@ -390,6 +390,11 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # forwards of their own (engine._forward_groups)
               "forwards", "positions_computed", "tokens_valid",
               "puts_split",
+              # scheduler steps dispatched and, of them, those dispatched
+              # while the step before was still unread, so that the
+              # host's turn ran behind the device's (scheduler.step_stats,
+              # delta-published per Replica)
+              "scheduler_steps", "steps_overlapped",
               # a hybrid model's sparse FFNs: (token, choice) pairs routed
               # and, of those, the pairs whose expert this replica holds
               # (the expectation under even routing: engine._count_routing)

@@ -180,6 +180,7 @@ class Replica:
         self._prefix_last: Dict[str, int] = {}
         self._put_last: Dict[str, int] = {}
         self._spec_last: Dict[str, int] = {}
+        self._step_last: Dict[str, int] = {}
         self._tier_last: Dict[str, int] = {}
         self._preempt_last: Dict[str, int] = {}
         self.thread = threading.Thread(target=self._loop, daemon=True,
@@ -439,8 +440,11 @@ class Replica:
                                 f"KV import of {total} blocks exceeds "
                                 "reservation headroom "
                                 f"({self.engine.reservation_headroom()})")
-                    self.engine.import_sequence(req.uid, payload,
-                                                tokens=resume)
+                    # (an evacuated sequence's KV is one short of what
+                    # was delivered: its last token had not been fed)
+                    self.engine.import_sequence(
+                        req.uid, payload,
+                        tokens=resume[:int(payload["seen_tokens"])])
                 except Exception as e:
                     logger.warning(
                         f"serving replica {self.replica_id}: KV handoff "
@@ -589,6 +593,8 @@ class Replica:
                      "kv_blocks_released")
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),
                          ("resumed", "sequences_resumed"))
+    _STEP_COUNTERS = (("steps", "scheduler_steps"),
+                      ("steps_overlapped", "steps_overlapped"))
 
     def _publish_prefix_stats(self) -> None:
         """Forward the engine's monotonic prefix-cache counters (and the
@@ -626,6 +632,14 @@ class Replica:
             if delta:
                 self.metrics.counter(name).inc(delta)
         self._spec_last = sstats
+        # steps dispatched and, of them, those dispatched while the step
+        # before was still unread (docs/SERVING.md "A step in flight")
+        steps = self.scheduler.step_stats()
+        for key, name in self._STEP_COUNTERS:
+            delta = steps[key] - self._step_last.get(key, 0)
+            if delta:
+                self.metrics.counter(name).inc(delta)
+        self._step_last = steps
         # tiered KV memory (docs/SERVING.md "KV tiering"): spill/restore
         # counters as deltas, per-block restore times into the histogram
         tier_fn = getattr(self.engine, "tier_stats", None)

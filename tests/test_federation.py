@@ -317,7 +317,12 @@ class TestCrossFrontendFailover:
         """The real thing: a peer frontend in its own process, killed
         -9 mid-decode — every in-flight federated stream fails over to
         the adopter's local replica and resumes byte-losslessly."""
-        ps = prompts(4, 33, lo=8, hi=12)
+        # twelve requests over two replicas of four seats: the peer is
+        # still in its burst when the kill lands, however far its tokens
+        # run ahead of their frames (a step in flight made a 96-token
+        # stream a few hundred ms on the CPU; with four requests the
+        # peer's could all be done before the adopter saw two tokens)
+        ps = prompts(12, 33, lo=8, hi=12)
         # 4 concurrent seats x (prompt + 96) stays inside the engine's
         # 64x8-token KV pool — 160 here wedges the reference run dry
         ref = local_reference(ps, 96)

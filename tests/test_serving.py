@@ -427,10 +427,17 @@ class _CompletingFakeEngine(_FakeEngine):
     def put(self, uids, chunks, **kw):
         import numpy as np
 
-        return np.zeros((len(uids), 8), dtype=np.float32)
+        class Logits(np.ndarray):       # what the scheduler asks of a put
+            def next_tokens(self):
+                return np.argmax(self, axis=-1)
+
+        return np.zeros((len(uids), 8), dtype=np.float32).view(Logits)
 
     def match_prefix(self, uid, prompt_tokens):
         return 0
+
+    def commit_tokens(self, uid, tokens, in_flight=0):
+        pass
 
 
 def test_check_health_on_draining_replica():

@@ -641,8 +641,10 @@ def test_greedy_parity_telemetry_on_vs_off():
 
 def test_scheduler_step_phase_spans():
     """One step is a ``step`` span whose children are pack, stage, fetch
-    and commit in that order; ``stage`` (and the kept ``forward``) carry
-    the engine's own record of the put."""
+    and commit in that order — pack and stage of the step it dispatches,
+    fetch and commit of the step before it, which ran meanwhile (docs/
+    SERVING.md "A step in flight"); ``stage`` (and the kept ``forward``)
+    carry the engine's own record of the put."""
     from deepspeed_tpu.inference.v2.scheduler import (
         ContinuousBatchingScheduler)
 
@@ -661,11 +663,26 @@ def test_scheduler_step_phase_spans():
                       # 128 / 8 slots
                       "kv_blocks_live": 2 + 1, "kv_table_slots": 2 * 16,
                       "free_blocks": eng.state_manager.available_blocks}
-    spans = {s["name"]: s for s in tr.export()}
+    # the first step dispatches and leaves its forward in flight
+    first = {s["name"]: s for s in tr.export()}
+    assert set(first) == {"step", "pack", "stage", "forward"}
+    assert first["forward"]["t_end"] is None
+    assert first["step"]["attrs"] == {"overlapped": False}
+    tr.clear()
+    # the second dispatches a decode step and then retires the first
+    sched.step()
+    decode_put = dict(eng.last_put)
+    spans = {s["name"]: s for s in tr.export() if s["t_end"] is not None}
+    # (the first step's forward ends with its fetch, here)
     assert set(spans) == {"step", "pack", "stage", "fetch", "commit",
                           "forward"}
+    assert spans["forward"]["span_id"] == first["forward"]["span_id"]
+    assert spans["forward"]["t_end"] == spans["fetch"]["t_end"] or \
+        spans["fetch"]["t_end"] <= spans["forward"]["t_end"] \
+        <= spans["commit"]["t_start"]
     step = spans["step"]
     assert step["parent_id"] is None and step["trace_id"] == "replica-7"
+    assert step["attrs"] == {"overlapped": True}
     phases = [spans[n] for n in ("pack", "stage", "fetch", "commit")]
     for a, b in zip(phases, phases[1:]):
         assert a["t_end"] <= b["t_start"]
@@ -673,6 +690,7 @@ def test_scheduler_step_phase_spans():
         assert ph["parent_id"] == step["span_id"]
         assert ph["trace_id"] == "replica-7"
         assert step["t_start"] <= ph["t_start"] and ph["t_end"] <= step["t_end"]
+    spans["stage"] = first["stage"]
     # ``stage`` carries the record without the walk's two counts (the
     # benchmark's agreement test pins its keys); ``forward`` carries all
     walk = {"kv_blocks_live", "kv_table_slots"}
@@ -680,7 +698,7 @@ def test_scheduler_step_phase_spans():
                                        if k not in walk}
     assert spans["forward"]["attrs"] == dict(record, n_seqs=2, n_tokens=16)
     # a decode step: one position a row, every key seen so far read
-    sched.step()
+    assert eng.last_put == decode_put
     assert eng.last_put["bucket_chunk"] == 1
     assert eng.last_put["kv_read_tokens"] == 12 + 6
     assert eng.last_put["qk_pairs"] == 12 + 6
@@ -746,7 +764,8 @@ def test_replica_loop_spans_and_put_counters():
         assert names.count("idle_wait") == 2
         snap = fe.metrics_snapshot()
         assert snap["forwards"] == eng.put_totals["forwards"] >= 3
-        assert snap["tokens_valid"] == eng.put_totals["tokens_valid"] == 9 + 3
+        # (the last of the three tokens is drawn and never fed)
+        assert snap["tokens_valid"] == eng.put_totals["tokens_valid"] == 9 + 2
         assert snap["positions_computed"] == \
             eng.put_totals["positions_computed"] >= 16 + 2
     finally:
