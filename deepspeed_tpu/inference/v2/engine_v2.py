@@ -18,6 +18,7 @@ import numpy as np
 
 from ...models.transformer import CausalLM
 from ...ops import gated_delta
+from ...ops import latent_attention as la
 from ...utils.logging import logger
 from .paged_model import PagedCausalLM
 from .ragged import BlockedAllocator, DSStateManager, RaggedBatchWrapper
@@ -134,6 +135,11 @@ def _keys_and_pairs(window: int, seen: int, n: int) -> Tuple[int, int]:
             short * seen + short * (short + 1) // 2 + (n - short) * window)
 
 
+#: a latent model's counters kept in ``put_totals`` (``_count_latent``)
+_LATENT_TOTALS = ("prefill_tokens", "latent_q_absorbed", "latent_q_expanded",
+                  "latent_rows_expanded")
+
+
 #: a one-token row's token when its sequence's next token is still on
 #: the device: the forward takes it from the sequence's slot of
 #: ``InferenceEngineV2.next_ids`` (``PagedCausalLM._forward``)
@@ -224,6 +230,13 @@ class InferenceEngineV2:
             if tp <= 1:
                 jmesh = None
                 tp = 1
+            elif model.cfg.is_latent:
+                from ...models.hybrid import LatentKVUnsupported
+
+                raise LatentKVUnsupported(
+                    "TP serving splits the KV pool and the attention by "
+                    "kv-head; a latent cache has no head to split (every "
+                    "head reads the same row)")
             elif model.cfg.is_hybrid:
                 from ...models.hybrid import RecurrentStateUnsupported
 
@@ -304,6 +317,8 @@ class InferenceEngineV2:
         if any(g.window for g in self.state_manager.groups):
             # blocks handed back behind a window while their sequence lived
             self.put_totals["kv_blocks_released"] = 0
+        if cfg.is_latent:       # which path its queries took (_count_latent)
+            self.put_totals.update(dict.fromkeys(_LATENT_TOTALS, 0))
         # the next token of every tracked sequence, drawn by the forward
         # that computed its last logits and kept on the device: one slot a
         # sequence (``DSSequenceDescriptor.id_slot``) and a scratch one
@@ -496,9 +511,11 @@ class InferenceEngineV2:
         # of what its forwards counted, and how many they were
         summed = ("rows", "valid_tokens", "kv_read_tokens", "qk_pairs",
                   "kv_blocks_live", "kv_table_slots",
-                  "moe_rows_routed", "moe_rows_held", "kv_blocks_released") \
+                  "moe_rows_routed", "moe_rows_held", "kv_blocks_released",
+                  "prefill_tokens") \
             + tuple(k for k in records[-1] if k.endswith(("_read_tokens",
-                                                          "_qk_pairs")))
+                                                          "_qk_pairs"))
+                    or k.startswith("latent_"))
         self.last_put = dict(records[-1], forwards=len(records), **{
             k: sum(r[k] for r in records) for k in summed
             if k in records[-1]})
@@ -614,6 +631,8 @@ class InferenceEngineV2:
                 arrays["block_tables"], slots, self.next_ids, id_slots)
         if self.model.cfg.is_hybrid:    # and its sparse FFNs' rows
             self._count_routing(valid)
+        if self.model.cfg.is_latent:    # and which path its queries take
+            self._count_latent(staged, bucket_chunk)
         # the forward consumes ``kv_cache`` (donated, written in place) and
         # hands the same memory back as ``new_cache``
         try:
@@ -699,6 +718,38 @@ class InferenceEngineV2:
         self.last_put.update(counts)
         for name, n in counts.items():
             self.put_totals[name] += n
+
+    def _count_latent(self, staged, bucket_chunk: int) -> None:
+        """A latent model's forward by path (ops/latent_attention.py): a
+        bucket of at most ``ABSORB_MAX_QUERIES`` positions a row runs
+        absorbed, a wider one expanded. ``latent_q_*``: the query rows
+        either path took, with the keys the absorbed ones read (once a
+        sequence) and their query-key pairs (the expanded ones' are the
+        record's ``kv_read_tokens`` / ``qk_pairs`` less these);
+        ``latent_rows_expanded``: the context positions whose K/V the
+        expanded rows rebuilt, each row's context in whole tiles;
+        ``prefill_tokens``: the positions of rows wider than one token,
+        what a prompt's prefill is made of."""
+        absorbed = bucket_chunk <= la.ABSORB_MAX_QUERIES
+        tile = la.expand_tile(self.batch.max_blocks_per_seq,
+                              self.config.kv_block_size)
+        counts = dict.fromkeys(_LATENT_TOTALS + (
+            "latent_keys_absorbed", "latent_pairs_absorbed"), 0)
+        for seq, toks in staged:
+            n, seen = len(toks), seq.seen_tokens
+            counts["prefill_tokens"] += n if n > 1 else 0
+            if absorbed:
+                keys, pairs = _keys_and_pairs(0, seen, n)
+                counts["latent_q_absorbed"] += n
+                counts["latent_keys_absorbed"] += keys
+                counts["latent_pairs_absorbed"] += pairs
+            else:
+                counts["latent_q_expanded"] += n
+                counts["latent_rows_expanded"] += la.expand_positions(
+                    seen, n, tile)
+        self.last_put.update(counts)
+        for name in _LATENT_TOTALS:
+            self.put_totals[name] += counts[name]
 
     def flush(self, uid: int) -> None:
         self.state_manager.flush_sequence(uid)
@@ -939,6 +990,8 @@ class InferenceEngineV2:
             validate_kv_quant(dtype, scale_granularity)
             if len(self.state_manager.groups) > 1:
                 self.state_manager.refuse_grouped("quantized KV pools")
+            if self.state_manager.headless:
+                self.state_manager.refuse_latent("quantized KV pools")
         self.config.kv_quant_enabled = bool(enabled)
         self.config.kv_quant_dtype = dtype
         self.config.kv_quant_scale_granularity = scale_granularity

@@ -106,6 +106,11 @@ def kv_bytes_per_block(model_cfg, block_size: int, quant: bool,
     the fixed-byte-budget comparison: at equal ``num_blocks *
     kv_bytes_per_block`` an int8 pool holds ~2x the bf16 blocks."""
     layers = getattr(model_cfg, "num_attn_layers", model_cfg.num_layers)
+    if getattr(model_cfg, "is_latent", False):
+        # one leaf, a token's padded latent row: what it occupies on the
+        # chip, not ``latent_dim`` numbers (never quantized: refused)
+        return layers * block_size * model_cfg.latent_width \
+            * jnp.dtype(dtype or model_cfg.dtype).itemsize
     slab = layers * model_cfg.kv_heads * block_size * model_cfg.head_dim
     if quant:
         return 2 * slab * 1 + 2 * layers * model_cfg.kv_heads * 4

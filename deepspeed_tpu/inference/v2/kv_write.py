@@ -28,6 +28,10 @@ and back for the kernel — two copies of the whole pool per forward
 fault in small — placing the rows by scatter made XLA transpose the
 gathered view there and back — hence the select.
 
+A latent pool (``[L, NB, bs, W]``: a token's row has no head axis,
+docs/SERVING.md "The pool contract") runs the same three steps on blocks
+``[bs, W]``: its index dimensions already lead.
+
 int8/fp8 pools run the same three steps with a dequantize before step 2
 and a re-quantize after it (``kv_quant.quantized_block_write``).
 """
@@ -98,7 +102,10 @@ def touched_block_plan(block_tables, start_pos, n_tokens, chunk: int,
 def place_rows(blocks, new_vals, plan):
     """Step 2: ``new_vals`` [N*C, KH, D] (the chunk's rows, sequence-major)
     into the gathered view ``blocks`` [N, TB, KH, bs, D]: each slot takes
-    the row ``plan`` names for it or keeps what it held."""
+    the row ``plan`` names for it or keeps what it held. A headless pool's
+    view is [N, TB, bs, W] and its rows [N*C, W]."""
+    if blocks.ndim == 4:
+        return place_rows(blocks[:, :, None], new_vals[:, None], plan)[:, :, 0]
     N, TB, KH, bs, D = blocks.shape
     rows = new_vals.reshape(N, -1, KH, D)
     if rows.shape[1] == 1:      # a decode token: every slot is offered it
@@ -112,7 +119,8 @@ def place_rows(blocks, new_vals, plan):
 
 def block_write(pool, new_vals, plan, layer):
     """Write new K or V rows into layer ``layer`` of an unquantized pool
-    [L, NB, KH, bs, D] (reference ``linear_blocked_kv_rotary`` kernel):
+    [L, NB, KH, bs, D] — or latent rows [N*C, W] into a headless one
+    [L, NB, bs, W] — (reference ``linear_blocked_kv_rotary`` kernel):
     token t lands at ``pool[layer, block(t), :, slot(t), :]``, every other
     slot keeps its content. Returns the updated pool — the same buffer
     when the caller owns it (the paged forward's scan carry)."""

@@ -42,6 +42,7 @@ from jax import lax
 
 from ...models.transformer import (CausalLM, _linear, _norm, alibi_slopes,
                                    apply_rope, rope_table)
+from ...ops import latent_attention
 from .kv_quant import quantized_block_write
 from .kv_write import block_write, touched_block_plan
 
@@ -358,6 +359,13 @@ class PagedCausalLM:
           group's own layers, and ``block_tables`` is [N, MB] for one
           group, [G, N, MB] for several: a group's write plan and its
           kernel's walk read its own table.
+          A model of latent layers keeps one leaf instead, ``kv``
+          [L, NB, bs, W]: a token's ``(c, k_r)`` row, padded to whole
+          lane tiles, shared by every head (``cfg.kv_layout``). A forward
+          of at most ``ABSORB_MAX_QUERIES`` positions a row reads it
+          absorbed, each position a row of the ``mla_decode`` kernel; a
+          wider chunk rebuilds K/V heads from its row's live context and
+          attends expanded (ops/latent_attention.py).
         - ``ssm`` [L_lin, slots + 1, HV, DK, DV] float32 and ``conv``
           [L_lin, slots + 1, K-1, CH]: the recurrent layers' state, one
           slot a sequence (``state_slots`` [N]; padded rows point at the
@@ -387,7 +395,7 @@ class PagedCausalLM:
         windows = [w for w, _ in cfg.kv_groups()]
         group_of = {kind: windows.index(cfg.sliding_window
                                         if kind == "window" else 0)
-                    for kind in set(pattern + lead) & set(hybrid.ATTN_SCOPE)}
+                    for kind in set(pattern + lead) & {"full", "window"}}
         tables = [block_tables] if block_tables.ndim == 2 \
             else list(block_tables)
 
@@ -400,10 +408,11 @@ class PagedCausalLM:
                                             cfg.rope_theta)
             cos, sin = cos_full[positions], sin_full[positions]
         quant = "k_scale" in cache
+        leaf = cfg.kv_layout(bs)[0][0]
         with scope("kv_write"):
             plans = [touched_block_plan(
                 t, start_pos, n_tokens, C, bs,
-                cache["k" + ("" if g == 0 else str(g))].shape[1])
+                cache[leaf + ("" if g == 0 else str(g))].shape[1])
                 for g, t in enumerate(tables)]
 
         def rope(t):
@@ -451,6 +460,56 @@ class PagedCausalLM:
                         return hybrid.full_out(cfg, attn, gate, lp)
                 return mixer
 
+            def latent_mixer(h1, lp, i):
+                layer = first_layer["latent"] + i
+                rank, H = cfg.kv_lora_rank, cfg.num_heads
+                sm_scale = hybrid.latent_scale(cfg)
+                absorbed = C <= latent_attention.ABSORB_MAX_QUERIES
+                pad = cfg.latent_width - cfg.latent_dim
+                with scope("qkv"):
+                    q_nope, q_rope, c, k_r = hybrid.latent_qkv(cfg, h1, lp,
+                                                               rope)
+                    if absorbed:
+                        # [q~ | q_rope | 0…], laid out as the pool's rows
+                        q = jnp.concatenate(
+                            [hybrid.latent_absorb(cfg, q_nope, lp), q_rope,
+                             jnp.zeros((N, C, H, pad), dt)], axis=-1)
+                with scope("kv_write"):
+                    rows = jnp.concatenate(
+                        [c, k_r, jnp.zeros((N, C, pad), dt)], axis=-1)
+                    pools["kv"] = block_write(
+                        pools["kv"], rows.reshape(N * C, -1), plans[0],
+                        layer)
+                if absorbed:
+                    with scope("attend"):
+                        # each position a row of its own: its context is
+                        # the keys up to itself, none for a padded one
+                        own = jnp.arange(C)[None, :]
+                        ctx = jnp.where(own < n_tokens[:, None],
+                                        start_pos[:, None] + own + 1, 0)
+                        o_lat = latent_attention.latent_decode(
+                            q.reshape(N * C, H, -1), pools["kv"], layer,
+                            jnp.repeat(tables[0], C, axis=0),
+                            ctx.reshape(N * C), rank, sm_scale)
+                    with scope("attn_out"):
+                        attn = hybrid.latent_unabsorb(
+                            cfg, o_lat.reshape(N, C, H, rank), lp)
+                else:
+                    def expand(lat):
+                        k_nope, v = hybrid.latent_expand(cfg, lat, lp)
+                        return (k_nope.transpose(1, 0, 2),
+                                v.transpose(1, 0, 2))
+
+                    # a chunk row at a time: each rebuilds its own context
+                    # (``latent_prefill`` opens ``kv_expand`` and
+                    # ``attend`` itself, a turn of its loop each)
+                    attn = jnp.stack([latent_attention.latent_prefill(
+                        q_nope[n], q_rope[n], pools["kv"], layer,
+                        tables[0][n], start_pos[n], n_tokens[n], expand,
+                        rank, cfg.v_head_dim, sm_scale) for n in range(N)])
+                with scope("attn_out"):
+                    return hybrid.latent_out(cfg, attn, lp)
+
             def linear_mixer(h1, lp, i):
                 layer = first_layer["linear"] + i
                 with scope("linear_attn"):
@@ -467,7 +526,7 @@ class PagedCausalLM:
                     return y
 
             return dict({kind: attention_mixer(kind) for kind in group_of},
-                        linear=linear_mixer)
+                        linear=linear_mixer, latent=latent_mixer)
 
         def period(carry, xs):
             x, pools = carry

@@ -7,6 +7,12 @@ counts and KV block ownership, allocates blocks on demand, and owns the
 device-side paged cache tensors [L, num_blocks, KH, block_size, D] (the
 per-(block, kv-head) slab is the trailing [block_size, D] — the layout the
 Pallas paged-attention index maps depend on, ops/paged_attention.py).
+A group carries its pool's layout (``KVGroup.leaves``, ``block_shape``:
+``TransformerConfig.kv_layout``): latent attention's pool is one leaf
+``kv`` [L, num_blocks, block_size, W], a token's ``(c, k_r)`` row with no
+head axis (ops/latent_attention.py). Whatever moves whole blocks of a
+group — the allocator, the prefix cache, export / import, the preemption
+stash, a trim — reads the leaves' second axis and nothing behind it.
 
 KV by layer group (docs/SERVING.md "The pool contract"): layers whose K/V
 has one lifetime — the whole context, or the last ``window`` positions —
@@ -45,7 +51,7 @@ from __future__ import annotations
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -101,12 +107,22 @@ class DSSequenceDescriptor:
 @dataclass
 class KVGroup:
     """Layers whose K/V has one lifetime: ``window`` positions (0: the
-    whole context), their pool's leaves ``k<suffix>`` / ``v<suffix>`` and
-    its allocator."""
+    whole context), their pool's layout — the leaves ``<leaf><suffix>``,
+    each ``[layers, blocks, *block_shape]`` — and its allocator."""
     window: int
     layers: int
     allocator: BlockedAllocator
     suffix: str = ""
+    leaves: Tuple[str, ...] = ("k", "v")
+    block_shape: Tuple[int, ...] = ()
+
+    @property
+    def names(self) -> List[str]:
+        return [leaf + self.suffix for leaf in self.leaves]
+
+    @property
+    def pool_shape(self) -> Tuple[int, ...]:
+        return (self.layers, self.allocator.total_blocks) + self.block_shape
 
 
 class DSStateManager:
@@ -160,12 +176,22 @@ class DSStateManager:
         self.kv_quant_dtype = str(kv_quant_dtype)
         per_layer = kv_bytes_per_block(model_cfg, block_size, self.kv_quant,
                                        dtype) // sum(n for _, n in kv_groups)
+        leaves, block_shape = (
+            model_cfg.kv_layout(block_size) if hasattr(model_cfg, "kv_layout")
+            else (("k", "v"), (model_cfg.kv_heads, block_size,
+                               model_cfg.head_dim)))
         self.groups = [
             KVGroup(window, layers,
                     BlockedAllocator(size, bytes_per_block=per_layer * layers),
-                    "" if g == 0 else str(g))
+                    "" if g == 0 else str(g), leaves, block_shape)
             for g, ((window, layers), size) in enumerate(zip(kv_groups,
                                                              sizes))]
+        # a pool with no kv-head axis (latent attention's)
+        self.headless = "k" not in leaves
+        if self.headless and (kv_quant or kv_tier_enabled
+                              or sharding is not None):
+            self.refuse_latent("quantized pools, the KV tier and a pool "
+                               "sharded by head")
         self.allocator = self.groups[0].allocator
         # blocks handed back behind a window since this manager was built
         self.blocks_released = 0
@@ -219,11 +245,7 @@ class DSStateManager:
         # paged-attention index maps (ops/paged_attention.py).
         # ``sharding``: optional NamedSharding placing KH over the tensor
         # axis (TP serving — reference v2 sharding/qkv.py:166 head split).
-        def pool_shape(group):
-            return (group.layers, group.allocator.total_blocks,
-                    model_cfg.kv_heads, block_size, model_cfg.head_dim)
-
-        shape = pool_shape(self.groups[0])
+        shape = self.groups[0].pool_shape
         from ..kv_quant import pool_dtype as _pool_dtype
 
         pool_dt = _pool_dtype(self.kv_quant_dtype) if self.kv_quant else dt
@@ -239,8 +261,8 @@ class DSStateManager:
         # one buffer per leaf: the forward donates the cache and writes it
         # in place (paged_model.py), and one buffer cannot be donated twice
         self.kv_cache = {
-            name + group.suffix: _alloc(pool_shape(group), pool_dt, sharding)
-            for group in self.groups for name in ("k", "v")}
+            name: _alloc(group.pool_shape, pool_dt, sharding)
+            for group in self.groups for name in group.names}
         if self.kv_quant:
             # symmetric per-(layer, block, kv-head) scales, indexed by
             # pool block id — a prefix-shared block shares its scale for
@@ -279,6 +301,16 @@ class DSStateManager:
             f"{self.cfg.num_layers} layers, which cannot be cut at a "
             "token or shared by prefix (snapshots of state are not "
             "built yet)")
+
+    def refuse_latent(self, what: str) -> None:
+        """The typed refusal of a feature that assumes K/V by kv-head."""
+        from ....models.hybrid import LatentKVUnsupported
+
+        raise LatentKVUnsupported(
+            f"{what} assume(s) a pool [L, NB, KH, bs, D] with a kv-head "
+            "axis (a scale a head, a split by head); this model's cache "
+            "is one latent row a token, shared by every head "
+            f"({self.groups[0].block_shape} a block)")
 
     def refuse_grouped(self, what: str) -> None:
         """The typed refusal of a feature that assumes a sequence's whole
@@ -1067,6 +1099,8 @@ class DSStateManager:
             return
         if self.recurrent:
             self.refuse_recurrent("the KV tier")
+        if self.headless:
+            self.refuse_latent("the KV tier")
         if not self.prefix_cache_enabled:
             raise ValueError(
                 "kv_tier requires the prefix cache: spill/restore happen "

@@ -11,6 +11,14 @@ one period an iteration), each followed by the same sparse FFN.
   positions; its K/V is a group of its own in serving, with a pool and a
   block table whose blocks behind the window go back while the sequence
   lives.
+- ``"latent"``: latent attention (MLA) — queries through a low-rank
+  bottleneck (``w_qa``, a norm, ``w_qb``), keys and values rebuilt from
+  one normed latent a token (``w_kva`` -> ``[c | k_r]``; ``w_kb`` /
+  ``w_vb``), a head's query and key ``[nope | rope]`` wide with the
+  key's rope part one for all heads. Its cache is ``(c, k_r)``, no head
+  axis; the same numbers come out of the *expanded* form (K/V rebuilt,
+  MHA) and the *absorbed* one (``w_kb`` folded into the query, ``w_vb``
+  applied to the attended latents): ``latent_*`` below.
 - ``"linear"``: Gated DeltaNet (``ops/gated_delta.py``) — one projection
   to ``[q | k | v | z]`` and one to ``[b | a]``, a depthwise causal conv
   over ``[q | k | v]``, the gated delta rule over a float32 state, a
@@ -25,9 +33,10 @@ one period an iteration), each followed by the same sparse FFN.
 ``CausalLM`` (training, the reference path) and ``PagedCausalLM``
 (serving) both call these; only where the mixer's cache lives differs.
 Scopes follow ``docs/OBSERVABILITY.md``: ``linear_attn`` ⊃ ``gdn_proj``,
-``gdn_conv``, ``gdn_scan``, ``gdn_out``; ``full_attn`` / ``window_attn``
-round an attention layer's ``qkv``, ``kv_write``, ``attend`` and
-``attn_out``; ``router``, ``experts``, ``shared_expert`` or
+``gdn_conv``, ``gdn_scan``, ``gdn_out``; ``full_attn`` / ``window_attn`` /
+``latent_attn`` round an attention layer's ``qkv``, ``kv_write``,
+``attend`` and ``attn_out`` (a latent layer's chunk forward adds
+``kv_expand``); ``router``, ``experts``, ``shared_expert`` or
 ``dense_mlp`` inside ``mlp``.
 """
 
@@ -43,9 +52,11 @@ from jax import lax
 from ..ops import gated_delta as gd
 from ..parallel.sharding import spec
 
-KINDS = ("full", "linear", "window")
-#: the kinds that keep per-token K/V, and the scope round each one's layer
-ATTN_SCOPE = {"full": "full_attn", "window": "window_attn"}
+KINDS = ("full", "linear", "window", "latent")
+#: the kinds that keep a per-token cache, and the scope round each one's
+#: layer
+ATTN_SCOPE = {"full": "full_attn", "window": "window_attn",
+              "latent": "latent_attn"}
 
 
 class RecurrentStateUnsupported(NotImplementedError):
@@ -61,6 +72,14 @@ class ReleasedKVUnsupported(NotImplementedError):
     past a released block, quantized pools of several groups) meets K/V
     kept by layer group, whose window groups hand blocks back while the
     sequence lives (inference/v2/ragged/manager.py)."""
+
+
+class LatentKVUnsupported(NotImplementedError):
+    """Raised where a feature that assumes K/V kept by kv-head (quantized
+    pools, whose scales are one a block a kv-head; the KV tier; TP
+    serving, which splits the pool and the attention by head) meets
+    latent attention, whose cache is one row a token shared by every
+    head (inference/v2/ragged/manager.py)."""
 
 
 def rms(x, w, eps, zero_centered):
@@ -116,7 +135,15 @@ def init_slot(cfg, kind: str, key, periods: int, dense: bool = False):
     if cfg.sandwich_norm:
         lp.update(post_attn_norm_w=gain((P, h), jnp.float32),
                   post_mlp_norm_w=gain((P, h), jnp.float32))
-    if kind in ATTN_SCOPE:
+    if kind == "latent":
+        qr, kvr, dr = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+        lp.update(w_qa=w((h, qr)), w_qb=w((qr, nh * (dn + dr))),
+                  w_kva=w((h, kvr + dr)), w_kb=w((kvr, nh * dn)),
+                  w_vb=w((kvr, nh * dv)), wo=w((nh * dv, h), out_std),
+                  q_a_norm_w=gain((P, qr), jnp.float32),
+                  kv_a_norm_w=gain((P, kvr), jnp.float32))
+    elif kind in ATTN_SCOPE:
         own_gate = cfg.attn_output_gate and cfg.attn_gate_proj
         q_out = nh * hd * (2 if cfg.attn_output_gate and not own_gate else 1)
         lp.update(wq=w((h, q_out)), wk=w((h, kvh * hd)), wv=w((h, kvh * hd)),
@@ -165,7 +192,16 @@ def slot_specs(cfg, kind: str, dense: bool = False):
     if cfg.sandwich_norm:
         lp.update(post_attn_norm_w=spec("layers", "embed"),
                   post_mlp_norm_w=spec("layers", "embed"))
-    if kind in ATTN_SCOPE:
+    if kind == "latent":
+        lp.update(w_qa=spec("layers", "embed", None),
+                  w_qb=spec("layers", None, "heads"),
+                  w_kva=spec("layers", "embed", None),
+                  w_kb=spec("layers", None, "heads"),
+                  w_vb=spec("layers", None, "heads"),
+                  wo=spec("layers", "heads", "embed"),
+                  q_a_norm_w=spec("layers", None),
+                  kv_a_norm_w=spec("layers", None))
+    elif kind in ATTN_SCOPE:
         lp.update(wq=spec("layers", "embed", "heads"),
                   wk=spec("layers", "embed", "kv_heads"),
                   wv=spec("layers", "embed", "kv_heads"),
@@ -241,6 +277,85 @@ def full_out(cfg, attn, gate, lp):
     if gate is not None:
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)
                                      ).astype(attn.dtype)
+    return _linear(attn.reshape(B, T, -1), lp["wo"], None, cfg.dtype)
+
+
+def latent_qkv(cfg, h1, lp, rope):
+    """A latent layer's projections on its normed input [B, T, H]:
+    ``(q_nope [B, T, heads, nope], q_rope [B, T, heads, rope], c
+    [B, T, kv_rank], k_r [B, T, rope])`` — ``c`` normed, ``q_rope`` and
+    ``k_r`` rotated (``rope``: [B, T, heads, rope] -> the same, rotated).
+    ``(c, k_r)`` is what the cache holds."""
+    from .transformer import _linear
+
+    B, T, _ = h1.shape
+    nh, dt = cfg.num_heads, cfg.dtype
+    dn, dr, kvr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    c_q = block_norm(cfg, _linear(h1, lp["w_qa"], None, dt),
+                     lp["q_a_norm_w"])
+    q = _linear(c_q, lp["w_qb"], None, dt).reshape(B, T, nh, dn + dr)
+    kva = _linear(h1, lp["w_kva"], None, dt)
+    c = block_norm(cfg, kva[..., :kvr], lp["kv_a_norm_w"])
+    k_r = rope(kva[..., None, kvr:])[:, :, 0]
+    return q[..., :dn], rope(q[..., dn:]), c, k_r
+
+
+def latent_expand(cfg, c, lp):
+    """K/V heads rebuilt from latents ``c`` [..., kv_rank]: ``(k_nope
+    [..., heads, nope], v [..., heads, v])``."""
+    from .transformer import _linear
+
+    nh, dt = cfg.num_heads, cfg.dtype
+    return (_linear(c, lp["w_kb"], None, dt).reshape(
+                c.shape[:-1] + (nh, cfg.qk_nope_head_dim)),
+            _linear(c, lp["w_vb"], None, dt).reshape(
+                c.shape[:-1] + (nh, cfg.v_head_dim)))
+
+
+def latent_absorb(cfg, q_nope, lp):
+    """The absorbed form's query: ``q_nope`` [..., heads, nope] through
+    each head's ``w_kb`` transposed -> [..., heads, kv_rank], to be
+    multiplied with the latents themselves."""
+    w = lp["w_kb"].astype(cfg.dtype).reshape(
+        cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim)
+    return jnp.einsum("...hd,chd->...hc", q_nope, w,
+                      preferred_element_type=jnp.float32).astype(cfg.dtype)
+
+
+def latent_unabsorb(cfg, o_lat, lp):
+    """The absorbed form's output: attended latents ``o_lat`` [...,
+    heads, kv_rank] through each head's ``w_vb`` -> [..., heads, v]."""
+    w = lp["w_vb"].astype(cfg.dtype).reshape(
+        cfg.kv_lora_rank, cfg.num_heads, cfg.v_head_dim)
+    return jnp.einsum("...hc,chd->...hd", o_lat.astype(cfg.dtype), w,
+                      preferred_element_type=jnp.float32).astype(cfg.dtype)
+
+
+def latent_scale(cfg) -> float:
+    return cfg.attn_scale or 1.0 / math.sqrt(cfg.head_dim)
+
+
+def latent_attend_dense(cfg, q_nope, q_rope, k_nope, k_r, v):
+    """Causal attention of the expanded form over one whole sequence,
+    plain XLA (no cache: training and the reference path; the flash
+    kernels take one width for q·k and v): q [B, T, heads, ·], k_nope / v
+    [B, T, heads, ·], k_r [B, T, rope] -> [B, T, heads, v]."""
+    T = q_nope.shape[1]
+    s = (jnp.einsum("bthd,bshd->bhts", q_nope, k_nope,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bthd,bsd->bhts", q_rope, k_r,
+                      preferred_element_type=jnp.float32)) * latent_scale(cfg)
+    causal = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]
+    p = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(v.dtype)
+
+
+def latent_out(cfg, attn, lp):
+    """[B, T, heads, v] attention output -> the layer's, through ``wo``."""
+    from .transformer import _linear
+
+    B, T = attn.shape[:2]
     return _linear(attn.reshape(B, T, -1), lp["wo"], None, cfg.dtype)
 
 

@@ -104,7 +104,8 @@ class TransformerConfig:
     moe_dropless: bool = False   # ragged_dot grouped GEMM (moe/grouped.py)
     # Hybrid blocks (models/hybrid.py): layers of more than one kind.
     # ``layer_pattern`` is one period of mixer kinds ("full" | "linear" |
-    # "window": attention over the last ``sliding_window`` positions),
+    # "window": attention over the last ``sliding_window`` positions |
+    # "latent": attention whose cache is one latent a token, below),
     # repeated (num_layers - len(lead_layers)) / len(pattern) times and
     # scanned one period an iteration; None is the uniform attention
     # block above. The fields below are read by the hybrid block only.
@@ -137,6 +138,18 @@ class TransformerConfig:
     #   of the moe_num_experts routed over whose weights this model holds
     moe_intermediate_size: Optional[int] = None   # None → intermediate_size
     moe_shared_intermediate_size: int = 0         # shared expert; 0 = none
+    # Latent attention (a "latent" layer, models/hybrid.py): queries
+    # through a rank-``q_lora_rank`` bottleneck, keys and values rebuilt
+    # from a rank-``kv_lora_rank`` latent a token; a head's query and key
+    # are ``[nope | rope]`` wide (only the rope part is rotated, and the
+    # key's is one for all heads), its value ``v_head_dim``. The cache is
+    # the latent and the rotated key part: ``latent_dim`` numbers a token
+    # a layer, whatever the head count.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         # a configuration read from JSON brings lists
@@ -156,6 +169,18 @@ class TransformerConfig:
                     f"layer_pattern {pattern!r}: a period of {KINDS} that "
                     f"divides num_layers ({self.num_layers}) less the "
                     f"{len(lead)} lead_layers")
+            kinds = set(pattern + lead)
+            if "latent" in kinds and (
+                    kinds & {"full", "window"} or min(
+                        self.q_lora_rank, self.kv_lora_rank,
+                        self.qk_nope_head_dim, self.v_head_dim) <= 0
+                    or self.qk_rope_head_dim <= 0
+                    or self.qk_rope_head_dim % 2):
+                raise ValueError(
+                    "\"latent\" layers need q_lora_rank, kv_lora_rank, "
+                    "qk_nope_head_dim, v_head_dim > 0 and an even "
+                    "qk_rope_head_dim, and do not mix with \"full\" or "
+                    "\"window\" layers (one K/V layout a model)")
             windowed = "window" in pattern + lead
             if windowed != (isinstance(self.sliding_window, int)
                             and self.sliding_window > 0) \
@@ -170,7 +195,30 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        """A head's query-key width (the softmax scale's default root)."""
+        if self.is_latent:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_size or self.hidden_size // self.num_heads
+
+    @property
+    def is_latent(self) -> bool:
+        """Whether the attention layers are latent ones (their cache has
+        no head axis: ``kv_layout``)."""
+        return self.layer_pattern is not None \
+            and "latent" in self.layer_pattern + self.lead_layers
+
+    @property
+    def latent_dim(self) -> int:
+        """What a latent layer caches a token: the latent and the rotated
+        key part."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """A token's row in the latent pool: ``latent_dim`` padded with
+        zeros to whole 128-lane tiles, which is what the row occupies on
+        the chip whether the pad is stated or not."""
+        return -(-self.latent_dim // 128) * 128
 
     @property
     def is_hybrid(self) -> bool:
@@ -192,7 +240,8 @@ class TransformerConfig:
         """Layers that keep per-token K/V (all of them, unless hybrid)."""
         if self.layer_pattern is None:
             return self.num_layers
-        return self.layers_of("full") + self.layers_of("window")
+        return self.layers_of("full") + self.layers_of("window") \
+            + self.layers_of("latent")
 
     @property
     def num_linear_layers(self) -> int:
@@ -219,9 +268,19 @@ class TransformerConfig:
             sw = self.sliding_window
             return ((int(sw) if isinstance(sw, int) else 0,
                      self.num_layers),)
-        groups = [(0, self.layers_of("full")),
+        groups = [(0, self.layers_of("full") + self.layers_of("latent")),
                   (int(self.sliding_window or 0), self.layers_of("window"))]
         return tuple(g for g in groups if g[1])
+
+    def kv_layout(self, block_size: int) -> Tuple[Tuple[str, ...],
+                                                  Tuple[int, ...]]:
+        """A pool's leaves and one block's shape in each (every group of
+        a model has the same): ``k`` and ``v`` ``[KH, bs, D]``, or for
+        latent layers the one leaf ``kv`` ``[bs, latent_width]`` — a
+        token's ``(c, k_r)`` row, shared by every head."""
+        if self.is_latent:
+            return ("kv",), (block_size, self.latent_width)
+        return ("k", "v"), (self.kv_heads, block_size, self.head_dim)
 
     @property
     def kv_heads(self) -> int:
@@ -235,6 +294,8 @@ class TransformerConfig:
     @property
     def rot_dim(self) -> int:
         """Rotary dims per head (even; < head_dim for partial rotary)."""
+        if self.is_latent:
+            return self.qk_rope_head_dim
         return int(self.head_dim * self.rope_pct) // 2 * 2
 
     def layer_windows(self) -> Tuple[int, ...]:
@@ -1162,8 +1223,20 @@ class CausalLM:
                 return hybrid.gdn_mixer(cfg, h1, lp, state0["conv"],
                                         state0["ssm"], n_tokens)[0]
 
+        def latent_mixer(h1, lp, _):
+            with scope("qkv"):
+                q_nope, q_rope, c, k_r = hybrid.latent_qkv(cfg, h1, lp, rope)
+            with scope("kv_expand"):
+                k_nope, v = hybrid.latent_expand(cfg, c, lp)
+            with scope("attend"):
+                attn = hybrid.latent_attend_dense(cfg, q_nope, q_rope,
+                                                  k_nope, k_r, v)
+            with scope("attn_out"):
+                return hybrid.latent_out(cfg, attn, lp)
+
         mixers = {"full": attention_mixer("full"),
-                  "window": attention_mixer("window"), "linear": linear_mixer}
+                  "window": attention_mixer("window"), "linear": linear_mixer,
+                  "latent": latent_mixer}
 
         def period(x, slots):
             return hybrid.run_period(cfg, x, slots, mixers,

@@ -403,6 +403,12 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # behind its window while their sequence lived
               # (DSStateManager.release_behind)
               "kv_blocks_released",
+              # latent attention: prompt positions prefilled, the query
+              # rows the absorbed and the expanded path took, and the
+              # context positions whose K/V the expanded one rebuilt
+              # (engine._count_latent)
+              "prefill_tokens", "latent_q_absorbed", "latent_q_expanded",
+              "latent_rows_expanded",
               # fault tolerance (docs/SERVING.md "Fault tolerance"):
               # failover = a dead replica's request re-enqueued (stream
               # resumed elsewhere); restarts = supervisor replaced a DEAD

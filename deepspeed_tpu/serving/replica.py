@@ -590,7 +590,9 @@ class Replica:
                       ("dropped", "kv_tier_blocks_dropped"))
     _PUT_COUNTERS = ("forwards", "positions_computed", "tokens_valid",
                      "puts_split", "moe_rows_routed", "moe_rows_held",
-                     "kv_blocks_released")
+                     "kv_blocks_released", "prefill_tokens",
+                     "latent_q_absorbed", "latent_q_expanded",
+                     "latent_rows_expanded")
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),
                          ("resumed", "sequences_resumed"))
     _STEP_COUNTERS = (("steps", "scheduler_steps"),
