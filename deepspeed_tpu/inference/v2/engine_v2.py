@@ -20,7 +20,7 @@ from ...models.transformer import CausalLM
 from ...ops import gated_delta
 from ...ops import latent_attention as la
 from ...utils.logging import logger
-from .paged_model import PagedCausalLM
+from .paged_model import PagedCausalLM, fuse_qkv, split_qkv
 from .ragged import BlockedAllocator, DSStateManager, RaggedBatchWrapper
 from .scheduling_utils import SchedulingError, SchedulingResult
 
@@ -250,6 +250,9 @@ class InferenceEngineV2:
         # weights and then shard with their weight shards (the per-leaf
         # block divides the per-shard width; weight_quant.py).
         self._weight_quant_stats = None
+        if self.config.weight_quant_enabled or jmesh is not None:
+            # a tree another engine fused: neither path below reads it
+            params = split_qkv(model.cfg, params)
         if self.config.weight_quant_enabled:
             from .weight_quant import quantize_weights
 
@@ -257,7 +260,12 @@ class InferenceEngineV2:
                 model.cfg, params, dtype=self.config.weight_quant_dtype,
                 block=self.config.weight_quant_block,
                 skip=self.config.weight_quant_skip, tp=tp)
-        if jmesh is not None:
+        if jmesh is None:
+            # on one device q, k and v are one leaf and one matmul; a
+            # quantized tree stays as it is, and under a tensor axis the
+            # three shard by their own heads (``fuse_qkv``)
+            params = fuse_qkv(params)
+        else:
             from ...parallel.sharding import ZeroShardingPlan
             from .weight_quant import expand_spec_tree
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -314,6 +322,16 @@ class InferenceEngineV2:
             "puts_split": 0}        # puts that ran as several forwards
         if cfg.is_hybrid:       # its sparse FFNs' rows (_count_routing)
             self.put_totals.update(moe_rows_routed=0, moe_rows_held=0)
+        else:
+            # forwards whose q, k and v came out of one stacked weight
+            # (``fuse_qkv``): all of an engine's, or none
+            self.put_totals["forwards_qkv_fused"] = 0
+            # (at debug: the logger writes to stdout, where the serving
+            # scripts' callers read ``*_LISTENING`` as the first line)
+            logger.debug(
+                "InferenceEngineV2: qkv is %s", "one matmul on wqkv"
+                if self.qkv_fused else "three matmuls (quantized weights "
+                "or a tensor axis: wq / wk / wv stay apart)")
         if any(g.window for g in self.state_manager.groups):
             # blocks handed back behind a window while their sequence lived
             self.put_totals["kv_blocks_released"] = 0
@@ -332,6 +350,13 @@ class InferenceEngineV2:
                 self.next_ids, NamedSharding(jmesh, P()))
         self._forward_jit = self.paged.forward
         self._compile_ahead()
+
+    @property
+    def qkv_fused(self) -> bool:
+        """Whether the parameter tree is in the serving layout
+        (``fuse_qkv``): read from the tree, which is what the forward's
+        trace reads."""
+        return "wqkv" in self.params["layers"]
 
     def forward_shapes(self) -> List[Tuple[int, int]]:
         """Every ``[S, C]`` a put's forward can be: ``[1, C]`` and
@@ -607,6 +632,8 @@ class InferenceEngineV2:
             "free_blocks": sm.available_blocks}
         totals = self.put_totals
         totals["forwards"] += 1
+        if self.qkv_fused:
+            totals["forwards_qkv_fused"] += 1
         totals["positions_computed"] += bucket_seqs * bucket_chunk
         totals["tokens_valid"] += valid
         kv_cache = sm.forward_cache
@@ -1037,8 +1064,8 @@ class InferenceEngineV2:
         from .weight_quant import quantize_weights
 
         self.params, self._weight_quant_stats = quantize_weights(
-            self.model.cfg, self.params, dtype=dtype, block=int(block),
-            skip=skip_list, tp=self.paged.tp)
+            self.model.cfg, split_qkv(self.model.cfg, self.params),
+            dtype=dtype, block=int(block), skip=skip_list, tp=self.paged.tp)
         self.config.weight_quant_enabled = True
         self.config.weight_quant_dtype = dtype
         self.config.weight_quant_block = int(block)

@@ -22,6 +22,12 @@ function processes a mixed prefill/decode ragged batch with static shapes:
   returned cache is the same memory (docs/SERVING.md "The pool
   contract");
 - returns logits only at each sequence's last valid token (logits_gather);
+- the serving layout of the parameters (``fuse_qkv``, built by the engine
+  where it takes them): q, k and v are one stacked leaf ``wqkv`` and the
+  ``qkv`` scope one matmul, so that no layer stages a projection outside
+  the fusion that multiplies by it; quantized nodes and TP shards keep
+  three leaves and the same body reads either (docs/SERVING.md "The
+  serving parameter tree");
 - weight serving (``weight_quant.py``): when the param tree holds
   blockwise-quantized ``{"qw", "qs"}`` nodes, every projection/MLP/unembed
   matmul here runs straight from the int8/fp8 representation through
@@ -45,6 +51,59 @@ from ...models.transformer import (CausalLM, _linear, _norm, alibi_slopes,
 from ...ops import latent_attention
 from .kv_quant import quantized_block_write
 from .kv_write import block_write, touched_block_plan
+
+
+#: the three projections a dense layer's ``qkv`` scope multiplies by
+QKV_LEAVES = ("wq", "wk", "wv")
+
+
+def fuse_qkv(params):
+    """The serving layout of a dense model's parameter tree: the stacked
+    ``wq`` / ``wk`` / ``wv`` [L, in, out] become one leaf ``wqkv``
+    [L, in, (nh + 2 * kvh) * hd] -- q's columns, then k's, then v's -- and
+    their biases, where the family has them, one ``wqkv_b``; the three
+    are not kept. ``_forward`` then multiplies once and cuts the result.
+    Sliced apart, the chip's compiler stages each of the three in a buffer
+    of its own and transposes it, every layer of every forward wider than
+    one row (tests/test_tpu_compile.py); one leaf it slices inside the
+    matmul, as it does ``wo`` and the MLP's.
+
+    A tree whose three leaves are not all arrays -- quantized
+    ``{"qw", "qs"}`` nodes (weight_quant.py: a scale block would straddle
+    the seams), a hybrid model's slots, one already fused -- is returned
+    as it is. The layout is the serving program's: checkpoints, the train
+    engine and ``CausalLM`` never see it (``split_qkv`` undoes it)."""
+    layers = params["layers"]
+    if not all(getattr(layers.get(n), "ndim", 0) == 3 for n in QKV_LEAVES):
+        return params
+    layers = dict(layers)
+    layers["wqkv"] = jnp.concatenate(
+        [layers.pop(n) for n in QKV_LEAVES], axis=-1)
+    biases = [layers.pop(n + "_b", None) for n in QKV_LEAVES]
+    if biases[0] is not None:
+        layers["wqkv_b"] = jnp.concatenate(biases, axis=-1)
+    return dict(params, layers=layers)
+
+
+def split_qkv(cfg, params):
+    """``fuse_qkv`` undone: the tree ``CausalLM`` and ``quantize_weights``
+    read (one that is not fused is returned as it is)."""
+    layers = params["layers"]
+    if "wqkv" not in layers:
+        return params
+    layers = dict(layers)
+    cuts = _qkv_cuts(cfg)
+    for suffix in ("", "_b"):
+        if "wqkv" + suffix in layers:
+            parts = jnp.split(layers.pop("wqkv" + suffix), cuts, axis=-1)
+            layers.update(zip((n + suffix for n in QKV_LEAVES), parts))
+    return dict(params, layers=layers)
+
+
+def _qkv_cuts(cfg):
+    """Where q ends and where k ends among ``wqkv``'s columns."""
+    return [cfg.num_heads * cfg.head_dim,
+            (cfg.num_heads + cfg.kv_heads) * cfg.head_dim]
 
 
 def _fed_tokens(tokens, next_ids, id_slots):
@@ -216,8 +275,10 @@ class PagedCausalLM:
         # attn_out, mlp}, final_norm, logits — the vocabulary
         # models/transformer.py shares. What runs under ``layers`` but
         # under none of the block's scopes is the scan's own plumbing:
-        # slices of the stacked weights. (The pools are not in it: they
-        # ride in the carry and are neither sliced nor written back.)
+        # slices of the stacked norm gains and biases (a matrix is sliced
+        # inside its matmul's fusion, under that matmul's scope). The
+        # pools are not in it: they ride in the carry and are neither
+        # sliced nor written back.
         scope = jax.named_scope
 
         with scope("embed"):
@@ -271,12 +332,16 @@ class PagedCausalLM:
                                cfg.norm, cfg.norm_eps)
                 nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
                 with scope("qkv"):
-                    q = rope_q(_linear(h1, lp["wq"], lp.get("wq_b"),
-                                       dt).reshape(N, C, nh, hd))
-                    k = rope_q(_linear(h1, lp["wk"], lp.get("wk_b"),
-                                       dt).reshape(N, C, kvh, hd))
-                    v = _linear(h1, lp["wv"], lp.get("wv_b"),
-                                dt).reshape(N, C, kvh, hd)
+                    if "wqkv" in lp:    # the serving layout (fuse_qkv)
+                        q, k, v = jnp.split(
+                            _linear(h1, lp["wqkv"], lp.get("wqkv_b"), dt),
+                            _qkv_cuts(cfg), axis=-1)
+                    else:   # quantized nodes, or sharded over ``tensor``
+                        q, k, v = (_linear(h1, lp[n], lp.get(n + "_b"), dt)
+                                   for n in QKV_LEAVES)
+                    q = rope_q(q.reshape(N, C, nh, hd))
+                    k = rope_q(k.reshape(N, C, kvh, hd))
+                    v = v.reshape(N, C, kvh, hd)
 
                 # paged KV write (reference linear_blocked_kv_rotary
                 # kernel): token t lands at pool[layer, block(t), :,

@@ -390,6 +390,10 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # forwards of their own (engine._forward_groups)
               "forwards", "positions_computed", "tokens_valid",
               "puts_split",
+              # of ``forwards``, those whose q, k and v were one matmul on
+              # the stacked ``wqkv`` (paged_model.fuse_qkv): all of a
+              # replica's or none, so the ratio reads the fleet's share
+              "forwards_qkv_fused",
               # scheduler steps dispatched and, of them, those dispatched
               # while the step before was still unread, so that the
               # host's turn ran behind the device's (scheduler.step_stats,

@@ -589,7 +589,8 @@ class Replica:
                       ("restored", "kv_tier_blocks_restored"),
                       ("dropped", "kv_tier_blocks_dropped"))
     _PUT_COUNTERS = ("forwards", "positions_computed", "tokens_valid",
-                     "puts_split", "moe_rows_routed", "moe_rows_held",
+                     "puts_split", "forwards_qkv_fused",
+                     "moe_rows_routed", "moe_rows_held",
                      "kv_blocks_released", "prefill_tokens",
                      "latent_q_absorbed", "latent_q_expanded",
                      "latent_rows_expanded")

@@ -313,9 +313,13 @@ class TestFabricTracePropagation:
                     v.get("remote", 0)
                     for v in fe.fleet.sources().values()) >= 2), \
                     "journal never heard from both servers"
+                # a ``server`` span closes with its request, so it rides
+                # the tick after the one that brought the first spans
+                assert _wait(lambda: any(s["name"] == "server"
+                                         for s in fe.tracer.export())), \
+                    "no server-side spans in the merged set"
                 spans = fe.tracer.export()
                 servers = [s for s in spans if s["name"] == "server"]
-                assert servers, "no server-side spans in the merged set"
                 ids = {s["span_id"] for s in spans}
                 for s in servers:
                     assert str(s["trace_id"]).startswith("req-")

@@ -194,7 +194,12 @@ def test_v2_put_matches_dense_alibi(monkeypatch):
     rng = np.random.default_rng(3)
     prompt = rng.integers(0, cfg.vocab_size, size=20).tolist()
     logits = engine.put([1], [prompt])
-    full = model.apply(engine.params, jnp.asarray([prompt], jnp.int32))
+    # the engine's tree is in the serving layout (``wqkv``); the model
+    # reads three leaves
+    from deepspeed_tpu.inference.v2.paged_model import split_qkv
+
+    full = model.apply(split_qkv(cfg, engine.params),
+                       jnp.asarray([prompt], jnp.int32))
     np.testing.assert_allclose(np.asarray(logits)[0],
                                np.asarray(full)[0, -1], atol=2e-3,
                                rtol=2e-3)

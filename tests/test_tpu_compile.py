@@ -165,31 +165,19 @@ def test_paged_attention_at_the_cells_geometries(v5e, geometry):
     _compile(attend, *shapes, sharding=SingleDeviceSharding(v5e[0]))
 
 
-@pytest.mark.parametrize("bucket", [(1, 1), (16, 1), (8, 256)],
-                         ids=lambda b: f"{b[0]}x{b[1]}")
-@pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.int8],
-                         ids=lambda d: jnp.dtype(d).name)
-@pytest.mark.parametrize("entry", ["forward", "forward_verify"])
-def test_paged_forward_keeps_the_pool_in_place(v5e, entry, pool, bucket,
-                                               monkeypatch):
-    """The serving forward at Pythia-1.4B widths (6 of its 24 layers, the
-    benchmark's 336 blocks of 64 tokens): the chip's compiler aliases every
-    pool leaf to the output and keeps its temporaries under ONE layer's
-    slab. This is the reading that decides how the write is formulated:
-    XLA ran a per-token scatter ``pool.at[layer, blk, :, slot, :]`` behind
-    two transposing copies of the whole pool (temporaries of one pool leaf
-    at [16, 1]), which the CPU backend's compile does not show; the
-    whole-block scatter of ``kv_write.py`` is the pool's own layout."""
-    import dataclasses
-
+def _compile_paged_forward(v5e, monkeypatch, cfg, bucket, *,
+                           pool=jnp.bfloat16, entry="forward", fused=True):
+    """``PagedCausalLM.<entry>`` of ``cfg`` compiled for one described
+    chip at the bucket ``[N, C]`` over the benchmark's 336 blocks of 64
+    tokens, on the serving layout of the parameters (``fuse_qkv``) or on
+    the model's three leaves: the executable, the parameters' shapes and
+    the pool's."""
     from deepspeed_tpu.inference.v2 import modules
-    from deepspeed_tpu.inference.v2.paged_model import PagedCausalLM
+    from deepspeed_tpu.inference.v2.paged_model import PagedCausalLM, fuse_qkv
     from deepspeed_tpu.models import transformer as tr
 
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
     monkeypatch.setattr(modules, "on_tpu", lambda: True)
-    cfg = dataclasses.replace(tr.PYTHIA_1B4, num_layers=6,
-                              dtype=jnp.bfloat16)
     model = tr.CausalLM(cfg)
     bs, NB, MB = 64, 336, 32
     paged = PagedCausalLM(model, bs, MB)
@@ -198,9 +186,9 @@ def test_paged_forward_keeps_the_pool_in_place(v5e, entry, pool, bucket,
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    params = jax.tree.map(
-        lambda a: spec(a.shape, jnp.bfloat16),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    init = (lambda key: fuse_qkv(model.init(key))) if fused else model.init
+    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: spec(a.shape, jnp.bfloat16), shapes)
     shape = (cfg.num_layers, NB, cfg.kv_heads, bs, cfg.head_dim)
     cache = {"k": spec(shape, pool), "v": spec(shape, pool)}
     if pool == jnp.int8:
@@ -212,6 +200,28 @@ def test_paged_forward_keeps_the_pool_in_place(v5e, entry, pool, bucket,
         params, cache, spec((N, C), jnp.int32), spec((N,), jnp.int32),
         spec((N,), jnp.int32), spec((N, MB), jnp.int32), **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled, shapes, cache
+
+
+@pytest.mark.parametrize("bucket", [(1, 1), (16, 1), (8, 256)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.int8],
+                         ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("entry", ["forward", "forward_verify"])
+def test_paged_forward_keeps_the_pool_in_place(v5e, entry, pool, bucket,
+                                               monkeypatch):
+    """The serving forward at Pythia-1.4B widths (6 of its 24 layers, the
+    benchmark's 336 blocks of 64 tokens, the parameters in the serving
+    layout): the chip's compiler aliases every pool leaf to the output and
+    keeps its temporaries under ONE layer's slab and the bucket's own q, k
+    and v. This is the reading that decides how the write is formulated:
+    XLA ran a per-token scatter ``pool.at[layer, blk, :, slot, :]`` behind
+    two transposing copies of the whole pool (temporaries of one pool leaf
+    at [16, 1]), which the CPU backend's compile does not show; the
+    whole-block scatter of ``kv_write.py`` is the pool's own layout."""
+    cfg, _ = _dense_widths("pythia")
+    compiled, _, cache = _compile_paged_forward(
+        v5e, monkeypatch, cfg, bucket, pool=pool, entry=entry)
 
     def nbytes(s):
         return math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
@@ -219,9 +229,123 @@ def test_paged_forward_keeps_the_pool_in_place(v5e, entry, pool, bucket,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(nbytes(s) for s in cache.values())
     slab = nbytes(cache["k"]) // cfg.num_layers
-    assert mem.temp_size_in_bytes < slab, (
+    # the one activation that is wider on the serving layout: the fused
+    # projection's result lives beside its three cuts (25 MB at [8, 256],
+    # where an int8 slab is 44 MB)
+    N, C = bucket
+    qkv = N * C * (cfg.num_heads + 2 * cfg.kv_heads) * cfg.head_dim * 2
+    assert mem.temp_size_in_bytes < slab + qkv, (
         f"{mem.temp_size_in_bytes} B of temporaries against a layer's slab "
-        f"of {slab} B: the pool is being copied")
+        f"of {slab} B and {qkv} B of q, k and v: the pool is being copied")
+
+
+def _staged_weights(text, weight_shapes):
+    """The instructions of the layer loop's body (optimized HLO ``text``)
+    whose result is a whole weight matrix -- dims in ``weight_shapes`` --
+    and that are no matmul: a bare ``dynamic-slice``, a fusion that holds
+    no ``convolution`` / ``dot``, a ``copy``."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    bodies = {m.group(1) for lines in comps.values() for line in lines
+              if "layers/while" in line
+              for m in [re.search(r"\bwhile\(.*body=%?([\w.\-]+)", line)]
+              if m}
+    assert bodies, "the program has no layer loop"
+    staged = []
+    for body in bodies:
+        for line in comps[body]:
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\S* "
+                         r"([\w\-]+)\(", line)
+            if not m or tuple(map(int, m.group(2).split(","))) \
+                    not in weight_shapes:
+                continue
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            inner = "\n".join(comps.get(called.group(1), ())) if called \
+                else ""
+            if not re.search(r"\b(convolution|dot)\(", inner):
+                staged.append(f"{m.group(3)} {m.group(1)} [{m.group(2)}]")
+    return staged
+
+
+def _dense_widths(widths):
+    """Pythia-1.4B's widths at 6 layers or Mistral-7B's (4096 / 14336,
+    32 / 8 heads) at 4, in bfloat16, and the function that gives a
+    parameter tree's weight-matrix shapes as one layer's slice has them."""
+    import dataclasses
+
+    from deepspeed_tpu.models import transformer as tr
+
+    base, layers = {"pythia": (tr.PYTHIA_1B4, 6),
+                    "mistral": (tr.MISTRAL_7B, 4)}[widths]
+    return (dataclasses.replace(base, num_layers=layers, dtype=jnp.bfloat16),
+            lambda tree: {(1,) + leaf.shape[1:]
+                          for leaf in jax.tree.leaves(tree["layers"])
+                          if leaf.ndim == 3})
+
+
+@pytest.mark.parametrize("widths,bucket", [
+    ("pythia", (1, 1)), ("pythia", (2, 1)), ("pythia", (16, 1)),
+    ("pythia", (1, 256)), ("mistral", (32, 1)), ("mistral", (1, 256))],
+    ids=lambda v: v if isinstance(v, str) else f"{v[0]}x{v[1]}")
+def test_paged_forward_multiplies_weights_where_they_lie(v5e, widths, bucket,
+                                                         monkeypatch):
+    """The dense serving forward on the serving layout of its parameters
+    (``fuse_qkv``: one ``wqkv`` leaf) at Pythia-1.4B's widths (6 layers)
+    and Mistral-7B's (4096 / 14336, 32 / 8 heads, 4 layers): the layer
+    loop's body holds no ``copy`` and no stand-alone ``dynamic-slice``
+    fusion whose result is a weight matrix ([1, in, out] of any stacked
+    leaf) -- every weight is sliced inside the fusion that multiplies by
+    it, streamed from HBM once.
+
+    On three leaves (the parent of PR 37) this fails at every bucket wider
+    than one row: ``wq`` / ``wk`` / ``wv`` are each sliced into a buffer of
+    their own (``constant_dynamic-slice_fusion``, memory space ``S(1)``)
+    and transposed by a ``copy`` ({2,1,0} -> {1,2,0}) before their matmul --
+    three ``bf16[1,2048,2048]`` at Pythia's widths, ``bf16[1,4096,4096]``
+    and two ``bf16[1,4096,1024]`` at Mistral's, 14% of a decode forward on
+    the chip -- while ``wo`` and the MLP's matrices never were. ``[1, 1]``
+    had no copy on three leaves either: its case holds the one-row
+    program to that."""
+    cfg, matrices = _dense_widths(widths)
+    compiled, served, _ = _compile_paged_forward(v5e, monkeypatch, cfg,
+                                                 bucket)
+    assert "wqkv" in served["layers"] and "wq" not in served["layers"]
+    assert (1, cfg.hidden_size, (cfg.num_heads + 2 * cfg.kv_heads)
+            * cfg.head_dim) in matrices(served)
+    assert _staged_weights(compiled.as_text(), matrices(served)) == []
+
+
+@pytest.mark.parametrize("widths,bucket", [("pythia", (2, 1)),
+                                           ("mistral", (32, 1))],
+                         ids=lambda v: v if isinstance(v, str)
+                         else f"{v[0]}x{v[1]}")
+def test_three_leaves_are_staged_and_transposed(v5e, widths, bucket,
+                                                monkeypatch):
+    """What the serving layout is for, and that ``_staged_weights`` sees
+    it: on the model's three leaves (the body quantized trees and TP
+    shards still run) the loop's body stages ``wq``, ``wk`` and ``wv`` --
+    a stand-alone slice and a ``copy`` each -- and no other matrix. If
+    this fails because nothing is staged, the compiler has learnt to
+    slice them in place and ``fuse_qkv`` has lost its reason."""
+    cfg, matrices = _dense_widths(widths)
+    compiled, shapes, _ = _compile_paged_forward(v5e, monkeypatch, cfg,
+                                                 bucket, fused=False)
+    qkv = sorted(",".join(map(str, (1,) + shapes["layers"][n].shape[1:]))
+                 for n in ("wq", "wk", "wv"))
+    staged = _staged_weights(compiled.as_text(), matrices(shapes))
+    assert sorted(s.split("[")[1][:-1] for s in staged
+                  if s.startswith("copy ")) == qkv
+    assert sorted(s.split("[")[1][:-1] for s in staged
+                  if s.startswith("fusion ")) == qkv
+    assert len(staged) == 6
 
 
 def test_the_kernels_carry_their_names(v5e):

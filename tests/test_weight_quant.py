@@ -119,7 +119,12 @@ def test_disabled_path_byte_identical(model_and_params):
     lb = np.asarray(eng_off.put([1], [prompt]))
     np.testing.assert_array_equal(la, lb)
     # pytree untouched: identical leaves, no {"qw","qs"} nodes anywhere
-    assert eng_off.params is params
+    # (q, k and v excepted, which every unquantized engine on one device
+    # serves as one leaf: paged_model.fuse_qkv)
+    assert eng_off.params["embed"] is params["embed"]
+    assert all(leaf is params["layers"][name]
+               for name, leaf in eng_off.params["layers"].items()
+               if name != "wqkv")
     assert not any(WQ.is_quantized(l) for l in
                    jax.tree.leaves(eng_off.params, is_leaf=WQ.is_quantized)
                    if isinstance(l, dict))
