@@ -1,0 +1,7 @@
+"""Device us a valid token of the traced window's forwards wider than one token, each module matched to its dispatch by order. (the saturated cell's name)"""
+
+from benchmark import dispatch_readers
+
+
+def reduce(ctx):
+    return dispatch_readers.dev_prefill_us_per_token(ctx)

@@ -19,6 +19,7 @@ import numpy as np
 from ...models.transformer import CausalLM
 from ...ops import gated_delta
 from ...ops import latent_attention as la
+from ...telemetry.tracer import NOOP_TRACER
 from ...utils.logging import logger
 from .paged_model import PagedCausalLM, fuse_qkv, split_qkv
 from .ragged import BlockedAllocator, DSStateManager, RaggedBatchWrapper
@@ -159,8 +160,11 @@ class PutLogits:
     from the engine's next-token buffer as this put left it (one small
     copy, asked for when the put was dispatched)."""
 
-    def __init__(self, parts, order, next_ids, slots):
+    def __init__(self, parts, order, next_ids, slots, ran_dry=False):
         self.parts = parts              # [(device logits, real rows)]
+        # whether the device had finished all it had been given when this
+        # put's first forward was handed over (``put`` asks, not waiting)
+        self.ran_dry = ran_dry
         self.order = np.argsort(order) if len(parts) > 1 else None
         self.shape = (len(order),) + tuple(parts[0][0].shape[1:])
         self.dtype = parts[0][0].dtype
@@ -349,6 +353,9 @@ class InferenceEngineV2:
             self.next_ids = jax.device_put(
                 self.next_ids, NamedSharding(jmesh, P()))
         self._forward_jit = self.paged.forward
+        # the scheduler's tracer, handed over when one is built on this
+        # engine: every forward is a ``dispatch`` span (``_forward_rows``)
+        self.tracer = NOOP_TRACER
         self._compile_ahead()
 
     @property
@@ -519,17 +526,23 @@ class InferenceEngineV2:
         groups = self._forward_groups(widths, verify_width)
         sm = self.state_manager
         outs, records = [], []
+        # everything handed over before this put ends in this buffer
+        ahead, ran_dry = self.next_ids, False
         for rows in groups:
             outs.append((self._forward_rows(
                 [uids[i] for i in rows], [tokens_list[i] for i in rows],
                 verify_width, defer_commit), len(rows)))
+            if not records:
+                # asked without waiting, the put's first forward just
+                # handed over: had the device run dry before it got there?
+                ran_dry = ahead.is_ready()
             records.append(self.last_put)
         # the draws' copy back is asked for now and follows the put's last
         # forward on the device's own queue
         self.next_ids.copy_to_host_async()
         result = PutLogits(
             outs, [i for rows in groups for i in rows], self.next_ids,
-            [sm.get_sequence(uid).id_slot for uid in uids])
+            [sm.get_sequence(uid).id_slot for uid in uids], ran_dry)
         if len(groups) == 1:
             return result
         # the put's record: its last (widest) forward's bucket, the sums
@@ -660,14 +673,32 @@ class InferenceEngineV2:
             self._count_routing(valid)
         if self.model.cfg.is_latent:    # and which path its queries take
             self._count_latent(staged, bucket_chunk)
+        # the unit of device work, named where it is handed over: one
+        # ``dispatch`` span a forward, round the call alone, with that
+        # forward's own counts (not the put's sums) and its place in the
+        # engine's order of dispatch, which is the device's order of
+        # execution (docs/OBSERVABILITY.md "XLA alignment"). One call site,
+        # traced or not: a wrapper frame would change a Mosaic kernel's
+        # compile-cache key
+        tracer, attrs = self.tracer, None
+        if tracer.enabled:
+            attrs = {"ordinal": totals["forwards"],
+                     "bucket_seqs": bucket_seqs, "bucket_chunk": bucket_chunk,
+                     "rows": len(staged), "valid_tokens": valid,
+                     # one string: a tuple's commas would end the stat in
+                     # the annotation's ``key=value,`` encoding
+                     "uids": " ".join(str(u) for u in uids)}
+            if verify_width:
+                attrs["verify_width"] = int(verify_width)
         # the forward consumes ``kv_cache`` (donated, written in place) and
         # hands the same memory back as ``new_cache``
         try:
-            if verify_width:
-                logits, new_cache, next_ids = self.paged.forward_verify(
-                    *args, verify_width=int(verify_width))
-            else:
-                logits, new_cache, next_ids = self.paged.forward(*args)
+            with tracer.span("dispatch", attrs=attrs):
+                if verify_width:
+                    logits, new_cache, next_ids = self.paged.forward_verify(
+                        *args, verify_width=int(verify_width))
+                else:
+                    logits, new_cache, next_ids = self.paged.forward(*args)
         except Exception as e:
             if any(leaf.is_deleted() for leaf in kv_cache.values()):
                 raise RuntimeError(

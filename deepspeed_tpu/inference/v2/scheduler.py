@@ -145,6 +145,9 @@ class ContinuousBatchingScheduler:
         # ``enabled`` attribute check per step.
         self.tracer = tracer if tracer is not None else NOOP_TRACER
         self.trace_label = trace_label
+        # the engine opens one ``dispatch`` span a forward on the same
+        # tracer, inside this scheduler's ``stage``
+        engine.tracer = self.tracer
         self.pending: Deque[Request] = deque()
         self.running: Dict[int, Request] = {}
         self.finished: Dict[int, Request] = {}
@@ -171,7 +174,8 @@ class ContinuousBatchingScheduler:
         self._flight: Optional[_Flight] = None  # dispatched, unread
         self._stepping = False                  # inside step()
         self._done_now: List[int] = []          # finished since step() said
-        self._step_stats = {"steps": 0, "steps_overlapped": 0}
+        self._step_stats = {"steps": 0, "steps_overlapped": 0,
+                            "steps_starved": 0}
         self._spec_stats = {"proposed": 0, "accepted": 0, "emitted": 0,
                             "decode_rows": 0}
         self._proposer_warned = False
@@ -211,7 +215,11 @@ class ContinuousBatchingScheduler:
     def step_stats(self) -> Dict[str, int]:
         """Monotonic counters: ``steps`` dispatched, and of them
         ``steps_overlapped`` — dispatched while the step before was still
-        unread, so that the host's turn ran behind the device's."""
+        unread, so that the host's turn ran behind the device's — and
+        ``steps_starved``: of those, the steps whose predecessor had
+        already finished on the device when their first forward was
+        handed over (``PutLogits.ran_dry``), so that the device had run
+        dry and the host set the pace."""
         return dict(self._step_stats)
 
     def spec_stats(self) -> Dict[str, int]:
@@ -242,7 +250,9 @@ class ContinuousBatchingScheduler:
             req.trace_id = trace_id
             req.spans = {"prefill": self.tracer.begin(
                 "prefill", trace_id=trace_id,
-                attrs={"prompt_tokens": len(req.prompt_tokens)})}
+                attrs={"prompt_tokens": len(req.prompt_tokens),
+                       # joins the ``dispatch`` spans that fed it (``uids``)
+                       "uid": uid})}
         self.pending.append(req)
 
     def submit_prefilled(self, uid: int, prompt_tokens: List[int],
@@ -873,7 +883,9 @@ class ContinuousBatchingScheduler:
 
         Traced (docs/OBSERVABILITY.md "Trace model"), a step is a ``step``
         span on the scheduler's trace (``overlapped``: whether its forward
-        was dispatched while the one before was unread) with one child per
+        was dispatched while the one before was unread; ``starved``:
+        whether that one had finished on the device before this one's
+        first forward got there) with one child per
         phase, each mirrored into an open profiler session as
         ``ds:<name>`` — of the step it dispatches:
 
@@ -882,7 +894,8 @@ class ContinuousBatchingScheduler:
           is its sequence's last draw, on the device or on the host;
           drafts are proposed here);
         - ``stage``: the host part of ``engine.put`` — scheduling check,
-          KV allocation, ``batch.finalize``, uploads and the dispatch;
+          KV allocation, ``batch.finalize``, uploads and the dispatch
+          (its child ``dispatch``, one a forward: the engine's);
           attrs are the engine's record of the put (``last_put``);
 
         and then of the step before it, which ran meanwhile:
@@ -907,11 +920,18 @@ class ContinuousBatchingScheduler:
                     uids, chunks, plan = self._pack()
                 # (packing may have retired it: a preemption does)
                 ahead, newer = self._flight, None
+                starved = False
                 if uids:
                     newer = self._dispatch(uids, chunks, plan)
+                    # with a step in flight, had the device finished it
+                    # before this one's first forward got there? (the put
+                    # asked as it handed it over, without waiting)
+                    starved = ahead is not None and newer.handle.ran_dry
                     self._step_stats["steps"] += 1
                     self._step_stats["steps_overlapped"] += ahead is not None
+                    self._step_stats["steps_starved"] += starved
                 span.set("overlapped", bool(uids) and ahead is not None)
+                span.set("starved", starved)
                 if ahead is not None:
                     self._retire(ahead, newer)
                 self._flight = newer
@@ -984,7 +1004,8 @@ class ContinuousBatchingScheduler:
             return
         with contextlib.nullcontext() if self._stepping else \
                 self.tracer.span("step", trace_id=self.trace_label,
-                                 attrs={"overlapped": False}):
+                                 attrs={"overlapped": False,
+                                        "starved": False}):
             self._retire(flight, None)
 
     def _retire(self, flight: _Flight, newer: Optional[_Flight]) -> None:

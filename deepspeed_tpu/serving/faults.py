@@ -172,3 +172,10 @@ class _FaultyEnginePut:
 
     def __getattr__(self, name):
         return getattr(object.__getattribute__(self, "_ft_inner"), name)
+
+    def __setattr__(self, name, value):
+        # writes delegate too (the scheduler hands the engine its tracer)
+        if name.startswith("_ft_"):
+            object.__setattr__(self, name, value)
+        else:
+            setattr(self._ft_inner, name, value)

@@ -397,8 +397,11 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # scheduler steps dispatched and, of them, those dispatched
               # while the step before was still unread, so that the
               # host's turn ran behind the device's (scheduler.step_stats,
-              # delta-published per Replica)
-              "scheduler_steps", "steps_overlapped",
+              # delta-published per Replica); and of those, the steps
+              # whose predecessor had already finished on the device, which
+              # had run dry: steps_starved / scheduler_steps is the share
+              # of steps the host was late for
+              "scheduler_steps", "steps_overlapped", "steps_starved",
               # a hybrid model's sparse FFNs: (token, choice) pairs routed
               # and, of those, the pairs whose expert this replica holds
               # (the expectation under even routing: engine._count_routing)

@@ -597,7 +597,8 @@ class Replica:
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),
                          ("resumed", "sequences_resumed"))
     _STEP_COUNTERS = (("steps", "scheduler_steps"),
-                      ("steps_overlapped", "steps_overlapped"))
+                      ("steps_overlapped", "steps_overlapped"),
+                      ("steps_starved", "steps_starved"))
 
     def _publish_prefix_stats(self) -> None:
         """Forward the engine's monotonic prefix-cache counters (and the
@@ -635,8 +636,9 @@ class Replica:
             if delta:
                 self.metrics.counter(name).inc(delta)
         self._spec_last = sstats
-        # steps dispatched and, of them, those dispatched while the step
-        # before was still unread (docs/SERVING.md "A step in flight")
+        # steps dispatched; of them, those dispatched while the step
+        # before was still unread (docs/SERVING.md "A step in flight");
+        # and of those, the ones the device had run dry before
         steps = self.scheduler.step_stats()
         for key, name in self._STEP_COUNTERS:
             delta = steps[key] - self._step_last.get(key, 0)

@@ -428,6 +428,8 @@ class _CompletingFakeEngine(_FakeEngine):
         import numpy as np
 
         class Logits(np.ndarray):       # what the scheduler asks of a put
+            ran_dry = True
+
             def next_tokens(self):
                 return np.argmax(self, axis=-1)
 

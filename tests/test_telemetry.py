@@ -665,24 +665,26 @@ def test_scheduler_step_phase_spans():
                       "free_blocks": eng.state_manager.available_blocks}
     # the first step dispatches and leaves its forward in flight
     first = {s["name"]: s for s in tr.export()}
-    assert set(first) == {"step", "pack", "stage", "forward"}
+    assert set(first) == {"step", "pack", "stage", "dispatch", "forward"}
     assert first["forward"]["t_end"] is None
-    assert first["step"]["attrs"] == {"overlapped": False}
+    assert first["step"]["attrs"] == {"overlapped": False, "starved": False}
+    assert first["dispatch"]["parent_id"] == first["stage"]["span_id"]
     tr.clear()
     # the second dispatches a decode step and then retires the first
     sched.step()
     decode_put = dict(eng.last_put)
     spans = {s["name"]: s for s in tr.export() if s["t_end"] is not None}
     # (the first step's forward ends with its fetch, here)
-    assert set(spans) == {"step", "pack", "stage", "fetch", "commit",
-                          "forward"}
+    assert set(spans) == {"step", "pack", "stage", "dispatch", "fetch",
+                          "commit", "forward"}
     assert spans["forward"]["span_id"] == first["forward"]["span_id"]
     assert spans["forward"]["t_end"] == spans["fetch"]["t_end"] or \
         spans["fetch"]["t_end"] <= spans["forward"]["t_end"] \
         <= spans["commit"]["t_start"]
     step = spans["step"]
     assert step["parent_id"] is None and step["trace_id"] == "replica-7"
-    assert step["attrs"] == {"overlapped": True}
+    assert step["attrs"]["overlapped"] is True
+    assert set(step["attrs"]) == {"overlapped", "starved"}
     phases = [spans[n] for n in ("pack", "stage", "fetch", "commit")]
     for a, b in zip(phases, phases[1:]):
         assert a["t_end"] <= b["t_start"]
