@@ -107,15 +107,15 @@ class RaggedInferenceEngineConfig:
         self.compile_ahead = int(compile_ahead)
 
 
-#: The most positions ``S * C`` of a dense put's bucket that still run as
-#: one forward. A dense forward streams every weight once, whatever its
-#: shape, and multiplies each with every position: on a v5e (197 TFLOP/s
-#: over 819 GB/s of bf16) the stream is the cost up to about 240 positions
-#: -- one *weight pass* -- and the positions are beyond it. A chunk row
-#: beside one-token rows costs two passes as two forwards and its
-#: ``S * C`` positions as one padded forward: the two meet at two passes'
-#: worth (the chip's readings: ``_forward_groups``).
-_JOINT_POSITIONS = 512
+#: Positions a dense forward computes for nothing. It streams every weight
+#: once, whatever its shape, and multiplies each with every position: on a
+#: v5e (197 TFLOP/s over 819 GB/s of bf16) the stream is the cost up to
+#: about 240 positions -- one *weight pass*. Well inside that, padding is
+#: free: a put whose padded ``[S, C]`` bucket holds no more positions than
+#: this runs whole, whatever rows fill it, and the chunk part of a merged
+#: forward is no narrower (every bucket is a program to compile, a merged
+#: one with two traces of the paged kernel): ``_forward_groups``.
+_FREE_POSITIONS = 128
 
 
 #: keys of ``engine.last_put`` that ride on the scheduler's ``forward``
@@ -165,7 +165,9 @@ class PutLogits:
         # whether the device had finished all it had been given when this
         # put's first forward was handed over (``put`` asks, not waiting)
         self.ran_dry = ran_dry
-        self.order = np.argsort(order) if len(parts) > 1 else None
+        # (a merged forward's rows are its wide row, then the others)
+        self.order = np.argsort(order) \
+            if list(order) != sorted(order) else None
         self.shape = (len(order),) + tuple(parts[0][0].shape[1:])
         self.dtype = parts[0][0].dtype
         self._ids, self._slots = next_ids, slots
@@ -184,8 +186,8 @@ class PutLogits:
     def __array__(self, dtype=None, copy=None):
         if self._whole is None:
             rows = [np.asarray(part)[:n] for part, n in self.parts]
-            self._whole = rows[0] if self.order is None \
-                else np.concatenate(rows)[self.order]
+            whole = rows[0] if len(rows) == 1 else np.concatenate(rows)
+            self._whole = whole if self.order is None else whole[self.order]
             self.parts = None
         whole = self._whole
         return whole if dtype is None else whole.astype(dtype)
@@ -306,9 +308,12 @@ class InferenceEngineV2:
             self.config.max_chunk_tokens, max_blocks_per_seq,
             min_chunk=gated_delta.TILE if cfg.is_hybrid else 1,
             groups=len(self.state_manager.groups))
-        # the most bucket positions a put runs as one forward
-        # (``_forward_groups``); a hybrid model's chunk rows never share one
-        self._joint_positions = 0 if cfg.is_hybrid else _JOINT_POSITIONS
+        # the most bucket positions a put runs as one padded forward, and
+        # past it the merged programs, tokens' shape -> rows
+        # (``_forward_groups``); a hybrid model's chunk rows never share a
+        # forward
+        self._free_positions = 0 if cfg.is_hybrid else _FREE_POSITIONS
+        self._merged_rows = self._merged_programs()
         # what the last put staged, counted where the work happens (plain
         # ints; the scheduler copies them into its span attrs when traced):
         # the bucket [S, C] the forward ran at, its real rows and valid
@@ -330,6 +335,8 @@ class InferenceEngineV2:
             # forwards whose q, k and v came out of one stacked weight
             # (``fuse_qkv``): all of an engine's, or none
             self.put_totals["forwards_qkv_fused"] = 0
+            # forwards that held a chunk row and one-token rows, merged
+            self.put_totals["forwards_merged"] = 0
             # (at debug: the logger writes to stdout, where the serving
             # scripts' callers read ``*_LISTENING`` as the first line)
             logger.debug(
@@ -366,12 +373,50 @@ class InferenceEngineV2:
         return "wqkv" in self.params["layers"]
 
     def forward_shapes(self) -> List[Tuple[int, int]]:
-        """Every ``[S, C]`` a put's forward can be: ``[1, C]`` and
-        ``[S, 1]``, and the joint buckets ``_forward_groups`` leaves whole
-        (a hybrid model has none)."""
+        """The shape of ``tokens`` in every forward a default put can ask
+        for: ``[1, C]``, ``[S, 1]``, and of a dense model the padded
+        ``[S, C]`` of at most ``_FREE_POSITIONS`` and past them the merged
+        ``[1, C + S]`` (``_forward_groups``)."""
         seqs, chunks = self.batch.buckets()
         return [(s, c) for s in seqs for c in chunks
-                if s == 1 or c == 1 or s * c <= self._joint_positions]
+                if s == 1 or c == 1 or s * c <= self._free_positions] \
+            + list(self._merged_rows)
+
+    def _merged_programs(self) -> Dict[Tuple[int, int], int]:
+        """``{(1, C + S): S}`` over the ``[S, C]`` buckets past
+        ``_FREE_POSITIONS``, ``C`` no narrower than that. The tokens' shape
+        names a program (``_compile_ahead``, and whoever reads a trace by
+        bucket), so a ``C + S`` that is a chunk bucket too, or another
+        pair's sum, is left out and its puts run apart; with no more rows
+        a batch than ``_FREE_POSITIONS`` there is none."""
+        seqs, chunks = self.batch.buckets()
+        taken = {(1, c) for c in chunks}
+        rows = {}
+        for s in seqs[1:] if self._free_positions else ():
+            for c in chunks[1:]:
+                shape = (1, self._merged_chunk(c) + s)
+                if s * c > self._free_positions and rows.get(shape) != s \
+                        and shape not in taken:
+                    taken.add(shape)
+                    rows[shape] = s
+        return rows
+
+    def _merged_chunk(self, chunk: int) -> int:
+        """The width of a merged forward's chunk part, for a chunk of the
+        bucket ``chunk``."""
+        return max(chunk, min(self._free_positions, self.batch.max_chunk))
+
+    def _merged_shape(self, rows: int,
+                      width: int) -> Optional[Tuple[int, int]]:
+        """The tokens' shape of the merged forward over ``rows`` rows, one
+        of them ``width`` tokens wide and the others one; None where they
+        run padded or apart."""
+        seqs, chunk = self.batch.bucket(rows, width)
+        shape = (1, self._merged_chunk(chunk) + seqs)
+        if seqs * chunk > self._free_positions \
+                and self._merged_rows.get(shape) == seqs:
+            return shape
+        return None
 
     def _compile_ahead(self) -> None:
         """Lower the forward at every shape of ``forward_shapes``, for the
@@ -392,7 +437,7 @@ class InferenceEngineV2:
         groups = len(sm.groups)
 
         def lowered(shape):
-            s = shape[0]
+            s = self._merged_rows.get(shape, shape[0])
             ints = [shape, (s,), (s,),
                     (s, width) if groups == 1 else (groups, s, width),
                     (s,) if sm.recurrent else None,
@@ -555,7 +600,7 @@ class InferenceEngineV2:
                                                           "_qk_pairs"))
                     or k.startswith("latent_"))
         self.last_put = dict(records[-1], forwards=len(records), **{
-            k: sum(r[k] for r in records) for k in summed
+            k: sum(r.get(k, 0) for r in records) for k in summed
             if k in records[-1]})
         self.put_totals["puts_split"] += 1
         return result
@@ -565,37 +610,44 @@ class InferenceEngineV2:
         """Which rows of a put run together in one forward, from their
         token counts. The forward pads its batch to an ``[S, C]`` bucket,
         so a chunk row beside S - 1 one-token rows costs S times its own
-        work in every mixer and every matmul. Past a limit of ``S * C``
-        positions the rows wider than one token therefore run each as a
-        forward of its own (``[1, C]``) and the one-token rows together
-        (``[S, 1]``, first); the parts' logits meet at the scheduler's one
-        fetch (``PutLogits``). The rule reads the bucket alone, not which
-        rows fill it: whoever has put every ``[S, C]`` once with one wide
-        row has run every program a later put can reach
-        (``forward_shapes``).
+        work in every mixer and every matmul; as forwards of their own
+        (``[S, 1]`` and ``[1, C]``), every weight is streamed twice. So:
 
-        A hybrid model's limit is zero (measured on the chip at
-        Qwen3-Next's widths, 8k of context: ``[8, 1024]`` 116 ms against
-        ``[1, 1024]`` 45 ms + ``[8, 1]`` 4 ms). A dense model's is
-        ``_JOINT_POSITIONS`` (measured on the chip, PR 33: one chunk row
-        beside S - 1 one-token rows at 600 / 1,000 tokens of context, ms
-        as one forward / apart -- Mistral-7B's 11 layers ``[32, 256]``
-        257 / 19.9, ``[4, 256]`` 32.4 / 17.7, ``[32, 32]`` 34.0 / 18.1,
-        ``[2, 256]`` 17.4 / 16.7, ``[32, 16]`` 19.4 / 18.0, ``[4, 64]``
-        10.1 / 16.0; Pythia-1.4B ``[4, 256]`` 25.9 / 14.6, ``[2, 256]``
-        13.6 / 12.5, ``[2, 128]`` 7.7 / 10.3): at the limit the two are
-        within a tenth on the device and the one forward spares the host
-        a stage; at half of it one forward wins by a third, at twice it
-        the parts win by half. A put that verifies drafts stays whole (a
-        one-token group has no ``verify_width`` positions), and so does
-        one wide row alone."""
+        - a bucket of at most ``_FREE_POSITIONS`` runs whole, padded,
+          whatever rows fill it: it costs the weight stream either way;
+        - past it, a dense put's one-token rows and its **first** wide row
+          run as one *merged* forward, first: the ``C + S`` positions laid
+          end to end through everything that works position by position,
+          the K/V write and the attention once a part
+          (``PagedCausalLM._forward``). It is keyed as the padded one
+          would be, by the bucket of the group's row count and of the
+          wide row's width;
+        - every further wide row runs as a ``[1, C]`` forward of its own.
+
+        The parts' logits meet at the scheduler's one fetch
+        (``PutLogits``). The rule reads buckets alone: whoever has put
+        every ``[S, C]`` once with one wide row has run every program a
+        later put can reach (``forward_shapes``).
+
+        A hybrid model's rows never share a forward (measured on the chip
+        at Qwen3-Next's widths, 8k of context: ``[8, 1024]`` 116 ms
+        against ``[1, 1024]`` 45 ms + ``[8, 1]`` 4 ms; its one-token
+        forward is 1.25-4 ms beside chunk forwards of 22-137 ms, and a
+        shared pass would have to carry recurrent state and layer
+        groups). A put that verifies drafts stays whole in its padded
+        ``[S, W]`` bucket (a one-token part has no ``verify_width``
+        positions), and so does one wide row alone. What the chip read,
+        merged against apart: docs/SERVING.md "A put may be several
+        forwards"."""
         everyone = [list(range(len(widths)))]
         wide = [i for i, n in enumerate(widths) if n > 1]
         ones = [i for i, n in enumerate(widths) if n == 1]
         seqs, chunk = self.batch.bucket(len(widths), max(widths))
         if not wide or (len(wide) == 1 and not ones) or verify_width \
-                or seqs * chunk <= self._joint_positions:
+                or seqs * chunk <= self._free_positions:
             return everyone
+        if ones and self._merged_shape(1 + len(ones), widths[wide[0]]):
+            return [[wide[0]] + ones] + [[i] for i in wide[1:]]
         return ([ones] if ones else []) + [[i] for i in wide]
 
     def _forward_rows(self, uids, tokens_list, verify_width: int,
@@ -633,20 +685,30 @@ class InferenceEngineV2:
                 group_read[g] += read
                 group_pairs[g] += pairs
 
-        arrays = self.batch.finalize()
+        # a wide row, first, and one-token rows past ``_FREE_POSITIONS``
+        # are a merged forward (``_forward_groups``): the bucket it states
+        # is its tokens' shape, [1, C + S]
+        merged, width = None, len(tokens_list[0])
+        if not verify_width and 1 < width == valid - len(staged) + 1:
+            merged = self._merged_shape(len(staged), width)
+        arrays = self.batch.finalize_merged(merged[1]) if merged \
+            else self.batch.finalize()
         bucket_seqs, bucket_chunk = arrays["tokens"].shape
+        table_rows = len(arrays["start_pos"])
         self.last_put = {
             "bucket_seqs": bucket_seqs, "bucket_chunk": bucket_chunk,
             "rows": len(staged), "valid_tokens": valid,
             "kv_read_tokens": kv_read, "qk_pairs": qk_pairs,
             "kv_blocks_live": blocks_live,
-            "kv_table_slots": bucket_seqs * len(groups)
+            "kv_table_slots": table_rows * len(groups)
             * arrays["block_tables"].shape[-1],
             "free_blocks": sm.available_blocks}
         totals = self.put_totals
         totals["forwards"] += 1
         if self.qkv_fused:
             totals["forwards_qkv_fused"] += 1
+        if merged:
+            totals["forwards_merged"] += 1
         totals["positions_computed"] += bucket_seqs * bucket_chunk
         totals["tokens_valid"] += valid
         kv_cache = sm.forward_cache
@@ -664,7 +726,7 @@ class InferenceEngineV2:
             self.last_put["state_slots_used"] = \
                 sm.state_slots - sm.free_state_slots
         # and its slot in the next-token buffer, likewise
-        id_slots = np.full((bucket_seqs,), sm.id_slots, np.int32)
+        id_slots = np.full((table_rows,), sm.id_slots, np.int32)
         id_slots[:len(staged)] = [seq.id_slot for seq, _ in staged]
         args = (self.params, kv_cache, arrays["tokens"],
                 arrays["start_pos"], arrays["n_tokens"],
@@ -685,6 +747,8 @@ class InferenceEngineV2:
             attrs = {"ordinal": totals["forwards"],
                      "bucket_seqs": bucket_seqs, "bucket_chunk": bucket_chunk,
                      "rows": len(staged), "valid_tokens": valid,
+                     # the one-token rows a merged forward carried
+                     "merged_ones": len(staged) - 1 if merged else 0,
                      # one string: a tuple's commas would end the stat in
                      # the annotation's ``key=value,`` encoding
                      "uids": " ".join(str(u) for u in uids)}

@@ -34,6 +34,12 @@ docs/SERVING.md "The pool contract") runs the same three steps on blocks
 
 int8/fp8 pools run the same three steps with a dequantize before step 2
 and a re-quantize after it (``kv_quant.quantized_block_write``).
+
+A merged forward (``paged_model._parts``) writes twice a layer, a plan
+each: its chunk row's blocks, then its one-token rows'. The two plans
+share no block -- a sequence is a row of one part, a padded row of the
+other -- so the writes are two in-place scatters on the one carried
+buffer, in either order.
 """
 
 from __future__ import annotations

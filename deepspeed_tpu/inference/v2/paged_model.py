@@ -8,7 +8,10 @@ function processes a mixed prefill/decode ragged batch with static shapes:
 
 - tokens [N, C] padded chunks, per-seq ``start_pos`` (tokens already
   cached) and ``n_tokens`` (valid width) — Dynamic SplitFuse feeds both
-  prompt chunks and single decode tokens through this same path;
+  prompt chunks and single decode tokens through this same path; a dense
+  model's chunk row and its one-token rows may also come laid end to
+  end, tokens [1, C + S]: one pass over every weight, the K/V write and
+  the attention once a part (``_parts``);
 - paged KV cache [L, NB, KH, bs, D] with per-seq block tables. **The pool
   stays where it is**: the jit donates it, the layer scan carries it
   whole, writes are a read-modify-write of the touched blocks of that
@@ -40,7 +43,7 @@ function processes a mixed prefill/decode ragged batch with static shapes:
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -109,13 +112,48 @@ def _qkv_cuts(cfg):
 def _fed_tokens(tokens, next_ids, id_slots):
     """``tokens`` with each negative first token replaced by its row's
     slot of ``next_ids``: the token a forward before this one drew on the
-    device (``PagedCausalLM._forward``)."""
+    device (``PagedCausalLM._forward``). In the merged layout a row's
+    first token is its place among the last ``len(id_slots)`` positions."""
     if next_ids is None:
         return tokens
     with jax.named_scope("embed"):
-        first = tokens[:, 0]
-        return tokens.at[:, 0].set(
+        rows = id_slots.shape[0]
+        at = (slice(None), 0) if tokens.shape[0] == rows \
+            else (0, slice(-rows, None))
+        first = tokens[at]
+        return tokens.at[at].set(
             jnp.where(first < 0, next_ids[id_slots], first))
+
+
+class _Part(NamedTuple):
+    """A run of a forward's positions that ``kv_write`` and ``attend``
+    take as one batch: the positions ``[lo, hi)`` of the tokens' second
+    axis seen as ``shape`` = (rows, chunk), with the rows' metadata."""
+    lo: int
+    hi: int
+    shape: Tuple[int, int]
+    block_tables: jax.Array
+    start_pos: jax.Array
+    n_tokens: jax.Array
+
+
+def _parts(tokens, start_pos, n_tokens, block_tables) -> List[_Part]:
+    """A padded ``[N, C]`` batch is one part. The merged layout --
+    ``tokens`` [1, C + S] beside ``S`` rows of metadata
+    (``RaggedBatchWrapper.finalize_merged``) -- is two: row 0's chunk as
+    ``[1, C]``, then one position a row as ``[S, 1]``, where row 0, whose
+    tokens the first part holds, is a padded row: no token, no context, no
+    block (a quantized write would clear the slots past its context as
+    stale, and they hold what the first part has just written)."""
+    N, P = tokens.shape
+    S = start_pos.shape[0]
+    if S == N:
+        return [_Part(0, P, (N, P), block_tables, start_pos, n_tokens)]
+    C = P - S
+    return [_Part(0, C, (1, C), block_tables[:1], start_pos[:1],
+                  n_tokens[:1]),
+            _Part(C, P, (S, 1), block_tables.at[0].set(-1),
+                  start_pos.at[0].set(0), n_tokens.at[0].set(0))]
 
 
 def _with_draw(logits, last_logits, new_cache, next_ids, id_slots):
@@ -236,6 +274,17 @@ class PagedCausalLM:
         state tree, which then rides in ``kv_cache`` beside the pool
         (``_forward_hybrid``); None otherwise.
 
+        **The merged layout** (a dense model): tokens [1, C + S] beside
+        ``S`` rows of start_pos / n_tokens / block_tables / id_slots -- row
+        0's chunk of ``C`` positions and one position a row, laid end to
+        end (``_parts``). Everything that works position by position
+        (embedding, norms, the projections, the MLP, the unembedding) runs
+        once over the ``C + S`` positions, one pass over each weight;
+        ``kv_write`` and ``attend`` run once a part, with the shapes a
+        ``[1, C]`` and an ``[S, 1]`` forward give them, on the one carried
+        pool. Logits come back ``[S, V]`` in the rows' order, as from a
+        padded ``[S, C]`` batch that computes ``S * C`` positions.
+
         ``next_ids`` [slots + 1] int32 with ``id_slots`` [N] (the engine's;
         None: a caller that keeps no such buffer): the next token of each
         sequence, drawn on the device. A row whose first token is negative
@@ -265,7 +314,8 @@ class PagedCausalLM:
                                         n_tokens, block_tables, state_slots,
                                         next_ids, id_slots, verify_width)
         tokens = _fed_tokens(tokens, next_ids, id_slots)
-        N, C = tokens.shape
+        N, C = tokens.shape         # C: the positions a row of ``tokens``
+        parts = _parts(tokens, start_pos, n_tokens, block_tables)
         bs = self.block_size
         NB = kv_cache["k"].shape[1]
         dt = cfg.dtype
@@ -287,7 +337,9 @@ class PagedCausalLM:
                 x = _norm(x, params["embed"]["ln_w"],
                           params["embed"].get("ln_b"), cfg.norm,
                           cfg.norm_eps)
-            positions = start_pos[:, None] + jnp.arange(C)[None, :]  # [N, C]
+            positions = jnp.concatenate(
+                [(p.start_pos[:, None] + jnp.arange(p.shape[1])[None, :]
+                  ).reshape(N, -1) for p in parts], axis=1)       # [N, C]
             slopes = None
             if cfg.position == "rope":
                 cos_full, sin_full = rope_table(cfg.max_seq_len, cfg.rot_dim,
@@ -310,8 +362,9 @@ class PagedCausalLM:
         # none of their code.
         quant = "k_scale" in kv_cache
         with scope("kv_write"):
-            kv_plan = touched_block_plan(block_tables, start_pos, n_tokens,
-                                         C, bs, NB)
+            kv_plans = [touched_block_plan(p.block_tables, p.start_pos,
+                                           p.n_tokens, p.shape[1], bs, NB)
+                        for p in parts]
 
         def rope_q(q):
             if cfg.position != "rope":
@@ -351,15 +404,17 @@ class PagedCausalLM:
                 with scope("kv_write"):
                     pools = dict(pools)
                     for name, rows in (("k", k), ("v", v)):
-                        rows = rows.reshape(-1, kvh, hd)
-                        if quant:
-                            sname = name + "_scale"
-                            pools[name], pools[sname] = quantized_block_write(
-                                pools[name], pools[sname], rows, kv_plan,
-                                layer)
-                        else:
-                            pools[name] = block_write(pools[name], rows,
-                                                      kv_plan, layer)
+                        for p, kv_plan in zip(parts, kv_plans):
+                            part = rows[:, p.lo:p.hi].reshape(-1, kvh, hd)
+                            if quant:
+                                sname = name + "_scale"
+                                pools[name], pools[sname] = \
+                                    quantized_block_write(
+                                        pools[name], pools[sname], part,
+                                        kv_plan, layer)
+                            else:
+                                pools[name] = block_write(pools[name], part,
+                                                          kv_plan, layer)
 
                 # paged read: Pallas block-table walk over this layer of
                 # the stacked pools (reference blocked_flash; Mistral
@@ -367,12 +422,13 @@ class PagedCausalLM:
                 # TP shard_maps the walk over the tensor axis; int8 pools
                 # dequantize in-kernel via the scale operands)
                 with scope("attend"):
-                    attn = self._attend(q, pools, layer, block_tables,
-                                        start_pos, n_tokens, slopes,
-                                        window=window)
+                    attn = jnp.concatenate([self._attend(
+                        q[:, p.lo:p.hi].reshape(*p.shape, nh, hd), pools,
+                        layer, p.block_tables, p.start_pos, p.n_tokens,
+                        slopes, window=window
+                    ).reshape(N, p.hi - p.lo, nh * hd) for p in parts], axis=1)
                 with scope("attn_out"):
-                    attn_out = _linear(attn.reshape(N, C, nh * hd),
-                                       lp["wo"], lp.get("wo_b"), dt)
+                    attn_out = _linear(attn, lp["wo"], lp.get("wo_b"), dt)
                 with scope("mlp"):      # norm, MLP and the residual adds
                     x = self.model._attn_mlp_merge(x, attn_out, lp, h1)
                 return (x, pools), None
@@ -400,9 +456,16 @@ class PagedCausalLM:
                 return _with_draw(logits, logits[:, -1], new_cache,
                                   next_ids, id_slots)
             # logits_gather: only the last valid token per sequence
-            last_idx = jnp.clip(n_tokens - 1, 0, C - 1)
-            x_last = jnp.take_along_axis(x, last_idx[:, None, None],
-                                         axis=1)[:, 0]
+            if len(parts) == 1:
+                last_idx = jnp.clip(n_tokens - 1, 0, C - 1)
+                x_last = jnp.take_along_axis(x, last_idx[:, None, None],
+                                             axis=1)[:, 0]
+            else:
+                # row 0's is in its chunk, another row's is its own place
+                chunk, rows = parts[0].hi, parts[1].shape[0]
+                own = chunk + jnp.arange(rows)
+                x_last = x[0, own.at[0].set(
+                    jnp.clip(n_tokens[0] - 1, 0, chunk - 1))]
             logits = self.model._unembed(params, x_last[:, None, :])[:, 0]
             return _with_draw(logits, logits, new_cache, next_ids, id_slots)
 

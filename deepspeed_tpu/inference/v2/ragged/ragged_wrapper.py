@@ -119,3 +119,17 @@ class RaggedBatchWrapper:
             "n_tokens": self.n_tokens[:S],
             "block_tables": self.block_tables[..., :S, :],
         }
+
+    def finalize_merged(self, positions: int) -> Dict[str, np.ndarray]:
+        """The same arrays for a batch whose row 0 is its one row wider
+        than one token, with the positions laid end to end
+        (``PagedCausalLM._forward``): ``tokens`` [1, positions] holds row
+        0's chunk padded to ``positions - S`` places, then one place for
+        each of the ``S`` rows the batch is bucketed to -- a one-token
+        row's token; row 0's own place there stays a pad."""
+        arrays = self.finalize()
+        chunk = positions - len(arrays["start_pos"])
+        tokens = np.concatenate([self.tokens[0, :chunk],
+                                 self.tokens[:positions - chunk, 0]])
+        tokens[chunk] = 0
+        return dict(arrays, tokens=tokens[None])

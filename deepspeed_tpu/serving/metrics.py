@@ -394,6 +394,11 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # the stacked ``wqkv`` (paged_model.fuse_qkv): all of a
               # replica's or none, so the ratio reads the fleet's share
               "forwards_qkv_fused",
+              # of ``forwards``, those that held a chunk row and one-token
+              # rows laid end to end, one pass over every weight
+              # (engine._forward_groups): the share of wide forwards that
+              # spared the decoding rows a forward of their own
+              "forwards_merged",
               # scheduler steps dispatched and, of them, those dispatched
               # while the step before was still unread, so that the
               # host's turn ran behind the device's (scheduler.step_stats,

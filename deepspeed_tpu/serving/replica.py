@@ -589,7 +589,7 @@ class Replica:
                       ("restored", "kv_tier_blocks_restored"),
                       ("dropped", "kv_tier_blocks_dropped"))
     _PUT_COUNTERS = ("forwards", "positions_computed", "tokens_valid",
-                     "puts_split", "forwards_qkv_fused",
+                     "puts_split", "forwards_qkv_fused", "forwards_merged",
                      "moe_rows_routed", "moe_rows_held",
                      "kv_blocks_released", "prefill_tokens",
                      "latent_q_absorbed", "latent_q_expanded",
@@ -618,7 +618,9 @@ class Replica:
             self._prefix_last = stats
         # what the forwards computed against what was asked of them: pad
         # ratio over any interval = delta positions / delta valid tokens;
-        # puts_split = the puts that ran as more than one forward
+        # puts_split = the puts that ran as more than one forward;
+        # forwards_merged = the forwards that carried a chunk row and
+        # one-token rows through one weight pass
         totals = getattr(self.engine, "put_totals", None)
         if totals is not None:
             for name in self._PUT_COUNTERS:

@@ -75,7 +75,8 @@ def test_one_dispatch_span_a_forward_with_consecutive_ordinals():
         assert stage["t_start"] <= s["t_start"] <= s["t_end"] <= stage["t_end"]
         assert s["trace_id"] == stage["trace_id"] == "scheduler"
         assert set(s["attrs"]) == {"ordinal", "bucket_seqs", "bucket_chunk",
-                                   "rows", "valid_tokens", "uids"}
+                                   "rows", "valid_tokens", "merged_ones",
+                                   "uids"}
     # a put that ran as one forward: the span says what the record says
     first = spans[0]["attrs"]
     assert (first["bucket_seqs"], first["bucket_chunk"], first["rows"],

@@ -288,7 +288,7 @@ def test_a_dense_model_is_given_no_slots(model_and_params):
     assert "state_slots_used" not in eng.last_put
     assert set(eng.put_totals) == {"forwards", "positions_computed",
                                    "tokens_valid", "puts_split",
-                                   "forwards_qkv_fused"}
+                                   "forwards_qkv_fused", "forwards_merged"}
     occ = eng.occupancy()
     assert occ["state_slots"] == occ["state_slots_used"] == 0
     assert eng.state_manager.get_sequence(1).state_slot == -1

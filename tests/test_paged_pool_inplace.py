@@ -226,32 +226,105 @@ def test_forward_logits_and_pool_match_dense(mode, quant):
         assert (k[:, 1, :, :5] != FILL).all()
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16tree", "int8tree"])
+@pytest.mark.parametrize("position", ["rope", "learned", "alibi"])
+def test_merged_forward_logits_and_pool_match_dense(position, quant):
+    """The merged layout (``tokens`` [1, C + S] beside ``S`` rows of
+    metadata): one sequence prefills in two chunks of a ``[1, 16]`` part
+    while two others decode beside it, a row each of the ``[4, 1]`` part
+    -- last-token logits of every row equal the dense forward's, and what
+    lies in the pool is the dense prefill's K/V: both parts wrote the one
+    carried pool, neither the other's blocks nor a padded row's."""
+    cfg = _tiny(position=position)
+    model = CausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(3))
+    bs, NB, MB = 8, 12, 5
+    paged = PagedCausalLM(model, bs, MB)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 97, size=n) for n in (21, 13, 7)]
+    tables = jnp.asarray([[4, 9, 1, -1, -1], [7, 2, -1, -1, -1],
+                          [3, -1, -1, -1, -1], [-1] * 5], jnp.int32)
+    FILL = 7.0
+    cache = _empty_cache(cfg, NB, bs, quant, fill=FILL)
+    dense_logits, dense_kv = [], []
+    for p in prompts:
+        toks = jnp.asarray(p[None])
+        dense_logits.append(np.asarray(model.apply(params, toks))[0])
+        _, c = model.prefill(params, toks, model.init_cache(1, len(p)))
+        dense_kv.append(c)
+
+    # the two decoding sequences' prompts but their last two tokens, padded
+    fed = [0, 11, 5]
+    toks = np.zeros((2, 16), np.int32)
+    for i in (1, 2):
+        toks[i - 1, :fed[i]] = prompts[i][:fed[i]]
+    _, cache = paged.forward(params, cache, jnp.asarray(toks),
+                             jnp.zeros((2,), jnp.int32),
+                             jnp.asarray(fed[1:], jnp.int32), tables[1:3])
+    C, S = 16, 4
+    for n_chunk in (16, 5):
+        flat = np.zeros((1, C + S), np.int32)
+        flat[0, :n_chunk] = prompts[0][fed[0]:fed[0] + n_chunk]
+        for i in (1, 2):
+            flat[0, C + i] = prompts[i][fed[i]]
+        logits, cache = paged.forward(
+            params, cache, jnp.asarray(flat),
+            jnp.asarray(fed + [0], jnp.int32),
+            jnp.asarray([n_chunk, 1, 1, 0], jnp.int32), tables)
+        fed = [fed[0] + n_chunk, fed[1] + 1, fed[2] + 1]
+        assert logits.shape == (S, 97)
+        for i, f in enumerate(fed):
+            np.testing.assert_allclose(
+                np.asarray(logits)[i], dense_logits[i][f - 1],
+                atol=5e-3 if quant else 1e-5, rtol=0)
+    assert fed == [len(p) for p in prompts]
+    for i, p in enumerate(prompts):
+        for name in ("k", "v"):
+            want = np.asarray(dense_kv[i][name])[:, 0]      # [L, T, KH, D]
+            got = _pool_rows(cache, name, tables[i], len(p))
+            atol = np.abs(want).max() / Q_MAX if quant else 1e-5
+            np.testing.assert_allclose(got, want, atol=atol, rtol=0)
+    if not quant:
+        k = np.asarray(cache["k"])
+        for free in (0, 5, 6, 8, 10, 11):
+            assert (k[:, free] == FILL).all()
+        assert (k[:, 1, :, 5:] == FILL).all()   # past 21 = 2 blocks + 5
+        assert (k[:, 3, :, 7:] == FILL).all()   # past the third's 7 tokens
+
+
 # ------------------------------------------------ the mechanism is on
 
-def _forward_args(cfg, NB, bs, MB, N, C, quant):
+def _forward_args(cfg, NB, bs, MB, N, C, quant, merged=False):
+    """Two rows of four tokens, padded ``[N, C]`` -- or ``merged``, the
+    first row's chunk and a place a row laid end to end, [1, C + N]."""
     model = CausalLM(cfg)
     params = model.init(jax.random.PRNGKey(0))
     paged = PagedCausalLM(model, bs, MB)
     cache = _empty_cache(cfg, NB, bs, quant)
-    args = (params, cache, jnp.zeros((N, C), jnp.int32),
+    args = (params, cache,
+            jnp.zeros((1, C + N) if merged else (N, C), jnp.int32),
             jnp.zeros((N,), jnp.int32), jnp.full((N,), C, jnp.int32),
             jnp.tile(jnp.arange(MB, dtype=jnp.int32)[None], (N, 1)))
     return paged, args, cache
 
 
-@pytest.mark.parametrize("entry", ["forward", "forward_verify"])
+@pytest.mark.parametrize("entry", ["forward", "forward_verify", "merged"])
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16tree", "int8tree"])
 def test_compiled_forward_aliases_the_pool(entry, quant):
     """Static: every pool leaf is an input the compiled program aliases to
     its output (donated and written in place), and the program's
     temporaries are smaller than ONE layer's slab of one leaf — so no
-    slab, let alone a pool, is copied anywhere in it. The pool is sized so
-    that a slab dwarfs the tiny model's activations."""
+    slab, let alone a pool, is copied anywhere in it, not between the
+    merged layout's two writes either. The pool is sized so that a slab
+    dwarfs the tiny model's activations."""
     cfg = _tiny(num_layers=3, hidden_size=32, intermediate_size=64,
                 vocab_size=64)
     NB, bs, MB = 4096, 8, 4
-    paged, args, cache = _forward_args(cfg, NB, bs, MB, 2, 4, quant)
+    paged, args, cache = _forward_args(cfg, NB, bs, MB, 2, 4, quant,
+                                       merged=entry == "merged")
     kw = {"verify_width": 2} if entry == "forward_verify" else {}
+    if entry == "merged":
+        entry = "forward"
     compiled = getattr(paged, entry).lower(*args, **kw).compile()
     pool_bytes = sum(leaf.nbytes for leaf in cache.values())
     slab_bytes = cache["k"].nbytes // cfg.num_layers

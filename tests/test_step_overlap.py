@@ -10,12 +10,14 @@ the registry. A tiny dense model and a tiny hybrid, float32 on the CPU."""
 
 import dataclasses
 import time
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.inference.v2 import engine_v2
 from deepspeed_tpu.inference.v2.engine_v2 import (
     DEVICE_TOKEN, InferenceEngineV2, RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.scheduler import ContinuousBatchingScheduler
@@ -61,12 +63,12 @@ def model_and_params(kind):
 
 def engine(kind, **sizing):
     model, params = model_and_params(kind)
-    eng = InferenceEngineV2(model, params=params,
-                            config=RaggedInferenceEngineConfig(
-                                **dict(SIZING, **sizing)))
-    if kind == "dense":
-        eng._joint_positions = 32       # [4, 16] parts, [2, 16] does not
-    return eng
+    # as if 32 positions were free, not 128: a dense [2, 16] runs padded,
+    # a chunk row beside two or three decodes merged, [1, 16 + 4]
+    with mock.patch.object(engine_v2, "_FREE_POSITIONS", 32):
+        return InferenceEngineV2(model, params=params,
+                                 config=RaggedInferenceEngineConfig(
+                                     **dict(SIZING, **sizing)))
 
 
 def host_argmax(logits):
@@ -143,7 +145,9 @@ def test_an_overlapped_run_is_the_run_at_depth_0_token_for_token(
     stats, stats0 = ahead.step_stats(), host.step_stats()
     assert stats0["steps_overlapped"] == 0 < stats0["steps"]
     assert stats["steps_overlapped"] >= 0.8 * stats["steps"]
-    assert ahead.engine.put_totals["puts_split"] > 0    # a parted put
+    totals = ahead.engine.put_totals
+    assert totals["puts_split"] > 0                     # a parted put
+    assert (totals.get("forwards_merged", 0) > 0) == (kind == "dense")
     for sched in (ahead, host):
         sm = sched.engine.state_manager
         assert sm.tracked_sequences == []

@@ -707,7 +707,8 @@ def test_scheduler_step_phase_spans():
     assert eng.last_put["kv_blocks_live"] == 2 + 1
     assert eng.put_totals == {"forwards": 2, "tokens_valid": 18,
                               "positions_computed": 2 * 16 + 2,
-                              "puts_split": 0, "forwards_qkv_fused": 2}
+                              "puts_split": 0, "forwards_qkv_fused": 2,
+                              "forwards_merged": 0}
     # a step with nothing to run is a step with a pack and no more
     idle = ContinuousBatchingScheduler(tiny_engine(), tracer=Tracer())
     assert idle.step() == []

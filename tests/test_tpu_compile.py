@@ -166,12 +166,14 @@ def test_paged_attention_at_the_cells_geometries(v5e, geometry):
 
 
 def _compile_paged_forward(v5e, monkeypatch, cfg, bucket, *,
-                           pool=jnp.bfloat16, entry="forward", fused=True):
+                           pool=jnp.bfloat16, entry="forward", fused=True,
+                           rows=None):
     """``PagedCausalLM.<entry>`` of ``cfg`` compiled for one described
     chip at the bucket ``[N, C]`` over the benchmark's 336 blocks of 64
-    tokens, on the serving layout of the parameters (``fuse_qkv``) or on
-    the model's three leaves: the executable, the parameters' shapes and
-    the pool's."""
+    tokens -- with ``rows``, the merged layout: ``bucket`` is the tokens'
+    ``[1, C + rows]`` -- on the serving layout of the parameters
+    (``fuse_qkv``) or on the model's three leaves: the executable, the
+    parameters' shapes and the pool's."""
     from deepspeed_tpu.inference.v2 import modules
     from deepspeed_tpu.inference.v2.paged_model import PagedCausalLM, fuse_qkv
     from deepspeed_tpu.models import transformer as tr
@@ -194,10 +196,10 @@ def _compile_paged_forward(v5e, monkeypatch, cfg, bucket, *,
     if pool == jnp.int8:
         cache["k_scale"] = spec(shape[:3], jnp.float32)
         cache["v_scale"] = spec(shape[:3], jnp.float32)
-    N, C = bucket
+    N = rows or bucket[0]
     kw = {"verify_width": 4} if entry == "forward_verify" else {}
     compiled = getattr(paged, entry).lower(
-        params, cache, spec((N, C), jnp.int32), spec((N,), jnp.int32),
+        params, cache, spec(bucket, jnp.int32), spec((N,), jnp.int32),
         spec((N,), jnp.int32), spec((N, MB), jnp.int32), **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled, shapes, cache
@@ -239,11 +241,8 @@ def test_paged_forward_keeps_the_pool_in_place(v5e, entry, pool, bucket,
         f"of {slab} B and {qkv} B of q, k and v: the pool is being copied")
 
 
-def _staged_weights(text, weight_shapes):
-    """The instructions of the layer loop's body (optimized HLO ``text``)
-    whose result is a whole weight matrix -- dims in ``weight_shapes`` --
-    and that are no matmul: a bare ``dynamic-slice``, a fusion that holds
-    no ``convolution`` / ``dot``, a ``copy``."""
+def _computations(text):
+    """The computations of an optimized HLO module, name -> lines."""
     comps, name = {}, None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
@@ -254,13 +253,28 @@ def _staged_weights(text, weight_shapes):
             name = None
         elif name is not None:
             comps[name].append(line)
-    bodies = {m.group(1) for lines in comps.values() for line in lines
-              if "layers/while" in line
+    return comps
+
+
+def _layer_bodies(comps):
+    """The names of the layer loop's body computations, of
+    ``_computations``."""
+    bodies = {m.group(1) for lines in comps.values()
+              for line in lines if "layers/while" in line
               for m in [re.search(r"\bwhile\(.*body=%?([\w.\-]+)", line)]
               if m}
     assert bodies, "the program has no layer loop"
+    return bodies
+
+
+def _staged_weights(text, weight_shapes):
+    """The instructions of the layer loop's body (optimized HLO ``text``)
+    whose result is a whole weight matrix -- dims in ``weight_shapes`` --
+    and that are no matmul: a bare ``dynamic-slice``, a fusion that holds
+    no ``convolution`` / ``dot``, a ``copy``."""
+    comps = _computations(text)
     staged = []
-    for body in bodies:
+    for body in _layer_bodies(comps):
         for line in comps[body]:
             m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]+)\]\S* "
                          r"([\w\-]+)\(", line)
@@ -321,6 +335,56 @@ def test_paged_forward_multiplies_weights_where_they_lie(v5e, widths, bucket,
     assert (1, cfg.hidden_size, (cfg.num_heads + 2 * cfg.kv_heads)
             * cfg.head_dim) in matrices(served)
     assert _staged_weights(compiled.as_text(), matrices(served)) == []
+
+
+@pytest.mark.parametrize("widths,rows", [("mistral", 32), ("pythia", 2)])
+def test_the_merged_forward_streams_each_weight_once(v5e, widths, rows,
+                                                     monkeypatch):
+    """The merged program at Mistral-7B's ``[1, 256 + 32]`` and
+    Pythia-1.4B's ``[1, 256 + 2]``: what it is for, read from the
+    optimized HLO. The layer loop's body multiplies each of ``wqkv``,
+    ``wo`` and the MLP's matrices once (one pass over each weight for both
+    parts), stages none of them, calls ``paged_attention`` twice (the
+    chunk as ``[1, 256]``, the rows as ``[S, 1]``), and every pool leaf is
+    aliased to the output with temporaries under one layer's slab: no copy
+    of the pool between the two parts' writes."""
+    cfg, matrices = _dense_widths(widths)
+    compiled, served, cache = _compile_paged_forward(
+        v5e, monkeypatch, cfg, (1, 256 + rows), rows=rows)
+    text = compiled.as_text()
+    assert _staged_weights(text, matrices(served)) == []
+    comps = _computations(text)
+    (body,) = _layer_bodies(comps)
+    body = comps[body]
+    # the stacked leaves as the body names them, by their dims
+    stacked = {m.group(1): (1,) + tuple(map(int, m.group(2).split(",")))[1:]
+               for line in body for m in [re.match(
+                   r"\s*%?([\w.\-]+) = \w+\[([\d,]+)\]\S* get-tuple-element\(",
+                   line)] if m}
+    per_weight = dict.fromkeys(matrices(served), 0)
+    for line in body:
+        called = re.search(r"fusion\((.*?)\), kind=.*calls=%?([\w.\-]+)", line)
+        if not called or not re.search(
+                r"\b(convolution|dot)\(", "\n".join(comps[called.group(2)])):
+            continue        # no matmul
+        for name in re.findall(r"%([\w.\-]+)", called.group(1)):
+            if stacked.get(name) in per_weight:
+                per_weight[stacked[name]] += 1
+    mlp = (1, cfg.hidden_size, cfg.intermediate_size)
+    gated = 2 if widths == "mistral" else 1         # SwiGLU: gate and up
+    assert per_weight.pop(mlp) == gated
+    assert set(per_weight.values()) == {1}, per_weight
+    calls = [line for line in body if "custom-call(" in line
+             and "paged_attention" in line]
+    assert len(calls) == 2
+    assert sorted(re.search(r"= \w+\[([\d,]+)\]", c).group(1)
+                  for c in calls) == sorted(
+        [f"1,{cfg.kv_heads},{256 * cfg.num_heads // cfg.kv_heads},128",
+         f"{rows},{cfg.kv_heads},{cfg.num_heads // cfg.kv_heads},128"])
+    mem = compiled.memory_analysis()
+    pool = sum(math.prod(s.shape) * 2 for s in cache.values())
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < pool // (2 * cfg.num_layers)
 
 
 @pytest.mark.parametrize("widths,bucket", [("pythia", (2, 1)),
