@@ -139,6 +139,10 @@ def _keys_and_pairs(window: int, seen: int, n: int) -> Tuple[int, int]:
 #: a latent model's counters kept in ``put_totals`` (``_count_latent``)
 _LATENT_TOTALS = ("prefill_tokens", "latent_q_absorbed", "latent_q_expanded",
                   "latent_rows_expanded")
+#: ... of a model with ``"latent_sparse"`` / ``"latent_window"`` layers
+_SPARSE_TOTALS = ("sparse_keys_live", "sparse_keys_selected")
+_WINDOW_TOTALS = ("window_q_absorbed", "window_q_expanded",
+                  "window_rows_expanded")
 
 
 #: a one-token row's token when its sequence's next token is still on
@@ -347,7 +351,11 @@ class InferenceEngineV2:
             # blocks handed back behind a window while their sequence lived
             self.put_totals["kv_blocks_released"] = 0
         if cfg.is_latent:       # which path its queries took (_count_latent)
-            self.put_totals.update(dict.fromkeys(_LATENT_TOTALS, 0))
+            kinds = set(cfg.layer_pattern + cfg.lead_layers)
+            self.put_totals.update(dict.fromkeys(
+                _LATENT_TOTALS
+                + (_SPARSE_TOTALS if "latent_sparse" in kinds else ())
+                + (_WINDOW_TOTALS if "latent_window" in kinds else ()), 0))
         # the next token of every tracked sequence, drawn by the forward
         # that computed its last logits and kept on the device: one slot a
         # sequence (``DSSequenceDescriptor.id_slot``) and a scratch one
@@ -851,12 +859,41 @@ class InferenceEngineV2:
         ``latent_rows_expanded``: the context positions whose K/V the
         expanded rows rebuilt, each row's context in whole tiles;
         ``prefill_tokens``: the positions of rows wider than one token,
-        what a prompt's prefill is made of."""
+        what a prompt's prefill is made of. These are the whole-context
+        kinds'. ``"latent_sparse"`` layers add what their selection keeps:
+        ``sparse_keys_live`` — the keys the rows' query positions may
+        see, summed over the positions — and ``sparse_keys_selected``, of
+        those the ones attended (``min(index_topk, live)`` a position;
+        ``sparse_keys_absorbed``: the absorbed rows' part of them, what
+        ``mla_sparse_decode`` gathers). ``"latent_window"`` layers, whose
+        paths cross elsewhere (``hybrid.absorb_limit``), add
+        ``window_q_absorbed`` / ``window_q_expanded``,
+        ``window_rows_expanded`` — the positions their expanded rows
+        rebuilt: the tiles from the window's first key to the chunk's
+        last — and the absorbed rows' ``window_keys_absorbed`` /
+        ``window_pairs_absorbed`` under the window (the expanded rows'
+        are the window group's ``kv_g<i>_*`` less these)."""
+        from ...models import hybrid
+
+        cfg = self.model.cfg
+        kinds = set(cfg.layer_pattern + cfg.lead_layers)
+        blocks, bs = self.batch.max_blocks_per_seq, self.config.kv_block_size
         absorbed = bucket_chunk <= la.ABSORB_MAX_QUERIES
-        tile = la.expand_tile(self.batch.max_blocks_per_seq,
-                              self.config.kv_block_size)
+        tile = la.expand_tile(blocks, bs)
         counts = dict.fromkeys(_LATENT_TOTALS + (
             "latent_keys_absorbed", "latent_pairs_absorbed"), 0)
+        sparse = cfg.index_topk if "latent_sparse" in kinds else 0
+        if sparse:
+            counts.update(sparse_keys_live=0, sparse_keys_selected=0,
+                          sparse_keys_absorbed=0)
+        window = cfg.sliding_window if "latent_window" in kinds else 0
+        if window:
+            w_absorbed = bucket_chunk <= hybrid.absorb_limit(
+                cfg, "latent_window")
+            w_tile = la.expand_tile(blocks, bs, window)
+            counts.update(window_q_absorbed=0, window_q_expanded=0,
+                          window_rows_expanded=0, window_keys_absorbed=0,
+                          window_pairs_absorbed=0)
         for seq, toks in staged:
             n, seen = len(toks), seq.seen_tokens
             counts["prefill_tokens"] += n if n > 1 else 0
@@ -869,9 +906,29 @@ class InferenceEngineV2:
                 counts["latent_q_expanded"] += n
                 counts["latent_rows_expanded"] += la.expand_positions(
                     seen, n, tile)
+            if sparse:
+                # position i sees seen + i + 1 keys and keeps ``sparse``
+                # of them at most: whole up to the first one that cuts
+                whole = max(0, min(n, sparse - seen))
+                kept = _keys_and_pairs(0, seen, whole)[1] \
+                    + (n - whole) * sparse
+                counts["sparse_keys_live"] += _keys_and_pairs(0, seen, n)[1]
+                counts["sparse_keys_selected"] += kept
+                counts["sparse_keys_absorbed"] += kept if absorbed else 0
+            if window and w_absorbed:
+                keys, pairs = _keys_and_pairs(window, seen, n)
+                counts["window_q_absorbed"] += n
+                counts["window_keys_absorbed"] += keys
+                counts["window_pairs_absorbed"] += pairs
+            elif window:
+                counts["window_q_expanded"] += n
+                first = max(seen - window + 1, 0) // w_tile
+                counts["window_rows_expanded"] += (
+                    -(-(seen + n) // w_tile) - first) * w_tile
         self.last_put.update(counts)
-        for name in _LATENT_TOTALS:
-            self.put_totals[name] += counts[name]
+        for name in counts:
+            if name in self.put_totals:
+                self.put_totals[name] += counts[name]
 
     def flush(self, uid: int) -> None:
         self.state_manager.flush_sequence(uid)

@@ -43,6 +43,7 @@ function processes a mixed prefill/decode ragged batch with static shapes:
 
 from __future__ import annotations
 
+import math
 from typing import Dict, List, NamedTuple, Tuple
 
 import jax
@@ -58,6 +59,13 @@ from .kv_write import block_write, touched_block_plan
 
 #: the three projections a dense layer's ``qkv`` scope multiplies by
 QKV_LEAVES = ("wq", "wk", "wv")
+#: positions of a wide chunk whose selection a sparse layer takes at a
+#: time (``PagedCausalLM._select``), and the widths (keys) its scores are
+#: taken at while the context fits one (a branch a width in every sparse
+#: layer of every program: two widths compile in half the time of four,
+#: and half the requests' contexts stay under the narrow one)
+SELECT_ROWS = 256
+SELECT_WIDTHS = (16384,)
 
 
 def fuse_qkv(params):
@@ -470,6 +478,83 @@ class PagedCausalLM:
             return _with_draw(logits, logits, new_cache, next_ids, id_slots)
 
     # ------------------------------------------------------------------
+    def _select(self, qi, wi, pool, layer, table, ctx, topk: int,
+                absorbed: bool):
+        """A sparse layer's selection for a forward's [N, C] positions
+        (under the ``index`` scope): qi [N, C, heads, D] and wi [N, C,
+        heads] the indexer's queries and head weights, ``pool`` the
+        index-key pool (which holds this forward's keys already), table
+        [N, MB], ctx [N, C]: the keys a position may see (itself + 1; 0:
+        a padded one). ``absorbed``: ``(idx [N·C, K], n [N·C])``, each
+        position's selected keys (``hybrid.index_select``); otherwise
+        the mask ``keep [N, C, keys]`` int8 over the table's keys in
+        whole tiles of ``latent_prefill``. Positions are scored ``Q`` of
+        one sequence a kernel row, and a wide chunk ``SELECT_ROWS``
+        positions at a time: the scores of 2,048 positions over 66,560
+        keys would be 545 MB before the selection's own buffers."""
+        from ...models import hybrid
+
+        N, C, HI, D = qi.shape
+        bs = self.block_size
+        keys = table.shape[1] * bs
+        Q = math.gcd(C, latent_attention.INDEX_QUERIES)
+        # the scores' width follows the context: a table cut to the
+        # narrowest of ``SELECT_WIDTHS`` that holds every row's context
+        # (one branch a width; the top-k and the mask cost by the width)
+        widths = [w for w in SELECT_WIDTHS if topk <= w < keys] + [keys]
+
+        def scored(qb, wb, ctxb, width):
+            """qb [N, c, heads, D] ... -> (scores, live) [N, c, width]."""
+            c = qb.shape[1]
+            with jax.named_scope("index_score"):
+                s = latent_attention.index_score(
+                    qb.reshape(N * c // Q, Q * HI, D),
+                    wb.reshape(N * c // Q, Q * HI), pool, layer,
+                    jnp.repeat(table[:, :width // bs], c // Q, axis=0),
+                    ctxb.reshape(N * c // Q, Q).max(axis=-1), Q)
+            return s.reshape(N, c, width), \
+                jnp.arange(width)[None, None, :] < ctxb[:, :, None]
+
+        def at_width(branch, qb, wb, ctxb):
+            """``branch(width)(qb, wb, ctxb)`` at the context's width."""
+            if len(widths) == 1:
+                return branch(keys)(qb, wb, ctxb)
+            longest = jnp.max(ctxb)
+            return lax.switch(
+                sum((longest > w).astype(jnp.int32) for w in widths[:-1]),
+                [branch(w) for w in widths], qb, wb, ctxb)
+
+        if absorbed:
+            def indices(width):
+                def run(qb, wb, ctxb):
+                    s, live = scored(qb, wb, ctxb, width)
+                    with jax.named_scope("index_select"):
+                        idx, n = hybrid.index_select(s, live, topk)
+                        k = min(topk, keys)
+                        return jnp.pad(idx, ((0, 0), (0, 0),
+                                             (0, k - idx.shape[-1]))), n
+                return run
+
+            idx, n = at_width(indices, qi, wi, ctx)
+            return idx.reshape(N * C, -1), n.reshape(N * C)
+        rows = math.gcd(C, SELECT_ROWS)
+        tile = latent_attention.expand_tile(table.shape[1], bs)
+
+        def mask(width):
+            def run(qb, wb, ctxb):
+                s, live = scored(qb, wb, ctxb, width)
+                with jax.named_scope("index_select"):
+                    keep = hybrid.index_keep(s, live, topk).astype(jnp.int8)
+                    return jnp.pad(keep, ((0, 0), (0, 0),
+                                          (0, keys + -keys % tile - width)))
+            return run
+
+        split = lambda a: jnp.moveaxis(                        # noqa: E731
+            a.reshape((N, C // rows, rows) + a.shape[2:]), 1, 0)
+        keep = lax.map(lambda xs: at_width(mask, *xs),
+                       (split(qi), split(wi), split(ctx)))
+        return jnp.moveaxis(keep, 0, 1).reshape(N, C, -1)
+
     def _forward_hybrid(self, params, cache, tokens, start_pos, n_tokens,
                         block_tables, state_slots, next_ids=None,
                         id_slots=None, verify_width: int = 0):
@@ -487,13 +572,24 @@ class PagedCausalLM:
           group's own layers, and ``block_tables`` is [N, MB] for one
           group, [G, N, MB] for several: a group's write plan and its
           kernel's walk read its own table.
-          A model of latent layers keeps one leaf instead, ``kv``
+          A model of latent layers keeps one leaf a group instead, ``kv``
           [L, NB, bs, W]: a token's ``(c, k_r)`` row, padded to whole
-          lane tiles, shared by every head (``cfg.kv_layout``). A forward
+          lane tiles, shared by every head (``cfg.kv_layouts``). A forward
           of at most ``ABSORB_MAX_QUERIES`` positions a row reads it
           absorbed, each position a row of the ``mla_decode`` kernel; a
           wider chunk rebuilds K/V heads from its row's live context and
-          attends expanded (ops/latent_attention.py).
+          attends expanded (ops/latent_attention.py). ``"latent_window"``
+          layers keep theirs in the window group, ``kv1`` at their own
+          width, and both kernels' walks start at the window
+          (``hybrid.absorb_limit`` says where that kind's paths cross).
+          ``"latent_sparse"`` layers keep a second leaf in the first
+          group, ``ki`` [L, NB, bs, index dim]: the indexer's key of
+          every token, written beside its latent row (the same plan). A
+          layer scores its queries against the live ones
+          (``index_score``), takes the exact top-k, and attends those
+          keys only: a one-position row over its gathered rows
+          (``mla_sparse_decode``), a wide chunk expanded under the
+          selection's mask (``mla_sparse_prefill``).
         - ``ssm`` [L_lin, slots + 1, HV, DK, DV] float32 and ``conv``
           [L_lin, slots + 1, K-1, CH]: the recurrent layers' state, one
           slot a sequence (``state_slots`` [N]; padded rows point at the
@@ -522,8 +618,8 @@ class PagedCausalLM:
         # window's (``kv_groups``: the whole context is window 0)
         windows = [w for w, _ in cfg.kv_groups()]
         group_of = {kind: windows.index(cfg.sliding_window
-                                        if kind == "window" else 0)
-                    for kind in set(pattern + lead) & {"full", "window"}}
+                                        if kind in cfg.group_kinds(1) else 0)
+                    for kind in set(pattern + lead) & set(hybrid.ATTN_SCOPE)}
         tables = [block_tables] if block_tables.ndim == 2 \
             else list(block_tables)
 
@@ -535,6 +631,13 @@ class PagedCausalLM:
             cos_full, sin_full = rope_table(cfg.max_seq_len, cfg.rot_dim,
                                             cfg.rope_theta)
             cos, sin = cos_full[positions], sin_full[positions]
+            # a latent kind rotated at a base of its own
+            own_base = {}
+            for kind in set(pattern + lead) & set(hybrid.LATENT_KINDS):
+                z = cfg.latent_sizes(kind)
+                if z.theta != cfg.rope_theta and z.theta not in own_base:
+                    c_k, s_k = rope_table(cfg.max_seq_len, z.rope, z.theta)
+                    own_base[z.theta] = (c_k[positions], s_k[positions])
         quant = "k_scale" in cache
         leaf = cfg.kv_layout(bs)[0][0]
         with scope("kv_write"):
@@ -545,6 +648,12 @@ class PagedCausalLM:
 
         def rope(t):
             return apply_rope(t, cos, sin, cfg.rope_interleaved)
+
+        def rope_at(theta):
+            if theta not in own_base:
+                return rope
+            return lambda t: apply_rope(t, *own_base[theta],
+                                        cfg.rope_interleaved)
 
         valid = jnp.arange(C)[None, :] < n_tokens[:, None]      # [N, C]
         max_rows = min(N * C, self.max_batch_tokens or N * C)
@@ -588,55 +697,98 @@ class PagedCausalLM:
                         return hybrid.full_out(cfg, attn, gate, lp)
                 return mixer
 
-            def latent_mixer(h1, lp, i):
-                layer = first_layer["latent"] + i
-                rank, H = cfg.kv_lora_rank, cfg.num_heads
-                sm_scale = hybrid.latent_scale(cfg)
-                absorbed = C <= latent_attention.ABSORB_MAX_QUERIES
-                pad = cfg.latent_width - cfg.latent_dim
-                with scope("qkv"):
-                    q_nope, q_rope, c, k_r = hybrid.latent_qkv(cfg, h1, lp,
-                                                               rope)
-                    if absorbed:
-                        # [q~ | q_rope | 0…], laid out as the pool's rows
-                        q = jnp.concatenate(
-                            [hybrid.latent_absorb(cfg, q_nope, lp), q_rope,
-                             jnp.zeros((N, C, H, pad), dt)], axis=-1)
-                with scope("kv_write"):
-                    rows = jnp.concatenate(
-                        [c, k_r, jnp.zeros((N, C, pad), dt)], axis=-1)
-                    pools["kv"] = block_write(
-                        pools["kv"], rows.reshape(N * C, -1), plans[0],
-                        layer)
-                if absorbed:
-                    with scope("attend"):
-                        # each position a row of its own: its context is
-                        # the keys up to itself, none for a padded one
-                        own = jnp.arange(C)[None, :]
-                        ctx = jnp.where(own < n_tokens[:, None],
-                                        start_pos[:, None] + own + 1, 0)
-                        o_lat = latent_attention.latent_decode(
-                            q.reshape(N * C, H, -1), pools["kv"], layer,
-                            jnp.repeat(tables[0], C, axis=0),
-                            ctx.reshape(N * C), rank, sm_scale)
-                    with scope("attn_out"):
-                        attn = hybrid.latent_unabsorb(
-                            cfg, o_lat.reshape(N, C, H, rank), lp)
-                else:
-                    def expand(lat):
-                        k_nope, v = hybrid.latent_expand(cfg, lat, lp)
-                        return (k_nope.transpose(1, 0, 2),
-                                v.transpose(1, 0, 2))
+            def latent_mixer(kind):
+                z = cfg.latent_sizes(kind)
+                g = group_of[kind]
+                name = "kv" + (str(g) if g else "")
+                rank, H = z.kv_rank, z.heads
+                # the model's one set of sizes is ``hybrid.latent_*``'s
+                # default: a kind with sizes of its own names itself
+                own = () if kind == "latent" else (kind,)
+                sm_scale = hybrid.latent_scale(cfg, *own)
+                absorbed = C <= hybrid.absorb_limit(cfg, kind)
+                pad = z.width - z.dim
+                turn = rope_at(z.theta)
+                # what only a window kind's calls are given
+                bound = {"window": z.window} if z.window else {}
 
-                    # a chunk row at a time: each rebuilds its own context
-                    # (``latent_prefill`` opens ``kv_expand`` and
-                    # ``attend`` itself, a turn of its loop each)
-                    attn = jnp.stack([latent_attention.latent_prefill(
-                        q_nope[n], q_rope[n], pools["kv"], layer,
-                        tables[0][n], start_pos[n], n_tokens[n], expand,
-                        rank, cfg.v_head_dim, sm_scale) for n in range(N)])
-                with scope("attn_out"):
-                    return hybrid.latent_out(cfg, attn, lp)
+                def contexts():
+                    """[N, C]: each position a row of its own, its context
+                    the keys up to itself, none for a padded one."""
+                    at = jnp.arange(C)[None, :]
+                    return jnp.where(at < n_tokens[:, None],
+                                     start_pos[:, None] + at + 1, 0)
+
+                def mixer(h1, lp, i):
+                    layer = first_layer[kind] + i
+                    with scope("qkv"):
+                        c_q = hybrid.latent_cq(cfg, h1, lp, kind) \
+                            if z.topk else None
+                        q_nope, q_rope, c, k_r = hybrid.latent_qkv(
+                            cfg, h1, lp, turn, *own,
+                            **({"c_q": c_q} if z.topk else {}))
+                        gate = hybrid.latent_gate(cfg, h1, lp)
+                        if absorbed:
+                            # [q~ | q_rope | 0…], laid out as the pool's rows
+                            q = jnp.concatenate(
+                                [hybrid.latent_absorb(cfg, q_nope, lp, *own),
+                                 q_rope, jnp.zeros((N, C, H, pad), dt)],
+                                axis=-1)
+                    if z.topk:
+                        with scope("index"), scope("index_proj"):
+                            qi, ki, wi = hybrid.index_qk(cfg, h1, c_q, lp,
+                                                         turn)
+                    with scope("kv_write"):
+                        rows = jnp.concatenate(
+                            [c, k_r, jnp.zeros((N, C, pad), dt)], axis=-1)
+                        pools[name] = block_write(
+                            pools[name], rows.reshape(N * C, -1), plans[g],
+                            layer)
+                        if z.topk:
+                            with scope("index_write"):
+                                pools["ki"] = block_write(
+                                    pools["ki"], ki.reshape(N * C, -1),
+                                    plans[g], layer)
+                    if z.topk:
+                        with scope("index"):
+                            picked = self._select(
+                                qi, wi, pools["ki"], layer, tables[g],
+                                contexts(), z.topk, absorbed)
+                    if absorbed:
+                        with scope("attend"):
+                            rows_q = q.reshape(N * C, H, -1)
+                            table = jnp.repeat(tables[g], C, axis=0)
+                            if z.topk:
+                                o_lat = latent_attention.latent_sparse_decode(
+                                    rows_q, pools[name], layer, table,
+                                    *picked, rank, sm_scale)
+                            else:
+                                o_lat = latent_attention.latent_decode(
+                                    rows_q, pools[name], layer, table,
+                                    contexts().reshape(N * C), rank,
+                                    sm_scale, **bound)
+                        with scope("attn_out"):
+                            attn = hybrid.latent_unabsorb(
+                                cfg, o_lat.reshape(N, C, H, rank), lp, *own)
+                    else:
+                        def expand(lat):
+                            k_nope, v = hybrid.latent_expand(cfg, lat, lp,
+                                                             *own)
+                            return (k_nope.transpose(1, 0, 2),
+                                    v.transpose(1, 0, 2))
+
+                        # a chunk row at a time: each rebuilds its own
+                        # context (``latent_prefill`` opens ``kv_expand``
+                        # and ``attend`` itself, a turn of its loop each)
+                        attn = jnp.stack([latent_attention.latent_prefill(
+                            q_nope[n], q_rope[n], pools[name], layer,
+                            tables[g][n], start_pos[n], n_tokens[n], expand,
+                            rank, z.v, sm_scale, **bound,
+                            **({"keep": picked[n]} if z.topk else {}))
+                            for n in range(N)])
+                    with scope("attn_out"):
+                        return hybrid.latent_out(cfg, attn, lp, gate)
+                return mixer
 
             def linear_mixer(h1, lp, i):
                 layer = first_layer["linear"] + i
@@ -653,8 +805,10 @@ class PagedCausalLM:
                         layer, state_slots].set(state)
                     return y
 
-            return dict({kind: attention_mixer(kind) for kind in group_of},
-                        linear=linear_mixer, latent=latent_mixer)
+            return dict({kind: latent_mixer(kind)
+                         if kind in hybrid.LATENT_KINDS
+                         else attention_mixer(kind) for kind in group_of},
+                        linear=linear_mixer)
 
         def period(carry, xs):
             x, pools = carry

@@ -7,12 +7,16 @@ counts and KV block ownership, allocates blocks on demand, and owns the
 device-side paged cache tensors [L, num_blocks, KH, block_size, D] (the
 per-(block, kv-head) slab is the trailing [block_size, D] — the layout the
 Pallas paged-attention index maps depend on, ops/paged_attention.py).
-A group carries its pool's layout (``KVGroup.leaves``, ``block_shape``:
-``TransformerConfig.kv_layout``): latent attention's pool is one leaf
-``kv`` [L, num_blocks, block_size, W], a token's ``(c, k_r)`` row with no
-head axis (ops/latent_attention.py). Whatever moves whole blocks of a
-group — the allocator, the prefix cache, export / import, the preemption
-stash, a trim — reads the leaves' second axis and nothing behind it.
+A group carries its own pool's layout (``KVGroup.leaves``,
+``block_shapes``: ``TransformerConfig.kv_layouts``): latent attention's
+pool is a leaf ``kv`` [L, num_blocks, block_size, W], a token's ``(c,
+k_r)`` row with no head axis (ops/latent_attention.py), at the width of
+the group's own kind; a group with sparse layers has a second leaf of
+another width beside it, ``ki`` [L, num_blocks, block_size, index dim],
+a block of which belongs to whoever holds the same block of ``kv``.
+Whatever moves whole blocks of a group — the allocator, the prefix
+cache, export / import, the preemption stash, a trim, the release behind
+a window — reads the leaves' second axis and nothing behind it.
 
 KV by layer group (docs/SERVING.md "The pool contract"): layers whose K/V
 has one lifetime — the whole context, or the last ``window`` positions —
@@ -108,21 +112,32 @@ class DSSequenceDescriptor:
 class KVGroup:
     """Layers whose K/V has one lifetime: ``window`` positions (0: the
     whole context), their pool's layout — the leaves ``<leaf><suffix>``,
-    each ``[layers, blocks, *block_shape]`` — and its allocator."""
+    each ``[layers, blocks, *its block shape]`` — and its allocator."""
     window: int
     layers: int
     allocator: BlockedAllocator
     suffix: str = ""
     leaves: Tuple[str, ...] = ("k", "v")
-    block_shape: Tuple[int, ...] = ()
+    block_shapes: Tuple[Tuple[int, ...], ...] = ()
 
     @property
     def names(self) -> List[str]:
         return [leaf + self.suffix for leaf in self.leaves]
 
     @property
+    def block_shape(self) -> Tuple[int, ...]:
+        """The first leaf's block (every leaf's, but for an index leaf)."""
+        return self.block_shapes[0]
+
+    @property
     def pool_shape(self) -> Tuple[int, ...]:
-        return (self.layers, self.allocator.total_blocks) + self.block_shape
+        return self.pool_shapes[self.names[0]]
+
+    @property
+    def pool_shapes(self) -> Dict[str, Tuple[int, ...]]:
+        lead = (self.layers, self.allocator.total_blocks)
+        return {name: lead + shape
+                for name, shape in zip(self.names, self.block_shapes)}
 
 
 class DSStateManager:
@@ -176,18 +191,30 @@ class DSStateManager:
         self.kv_quant_dtype = str(kv_quant_dtype)
         per_layer = kv_bytes_per_block(model_cfg, block_size, self.kv_quant,
                                        dtype) // sum(n for _, n in kv_groups)
-        leaves, block_shape = (
-            model_cfg.kv_layout(block_size) if hasattr(model_cfg, "kv_layout")
-            else (("k", "v"), (model_cfg.kv_heads, block_size,
-                               model_cfg.head_dim)))
+        if hasattr(model_cfg, "kv_layouts"):
+            layouts = model_cfg.kv_layouts(block_size)
+        else:
+            block = (model_cfg.kv_heads, block_size, model_cfg.head_dim)
+            layouts = ({"k": block, "v": block},) * len(kv_groups)
+        # a pool with no kv-head axis (latent attention's): each group's
+        # block costs what its own leaves hold (their widths differ)
+        self.headless = "k" not in layouts[0]
+        itemsize = jnp.dtype(dtype or model_cfg.dtype).itemsize
+
+        def block_bytes(layout, layers):
+            if not self.headless:
+                return per_layer * layers
+            return layers * itemsize * sum(
+                int(np.prod(shape)) for shape in layout.values())
+
         self.groups = [
             KVGroup(window, layers,
-                    BlockedAllocator(size, bytes_per_block=per_layer * layers),
-                    "" if g == 0 else str(g), leaves, block_shape)
-            for g, ((window, layers), size) in enumerate(zip(kv_groups,
-                                                             sizes))]
-        # a pool with no kv-head axis (latent attention's)
-        self.headless = "k" not in leaves
+                    BlockedAllocator(size, bytes_per_block=block_bytes(
+                        layout, layers)),
+                    "" if g == 0 else str(g), tuple(layout),
+                    tuple(layout.values()))
+            for g, ((window, layers), size, layout) in enumerate(zip(
+                kv_groups, sizes, layouts))]
         if self.headless and (kv_quant or kv_tier_enabled
                               or sharding is not None):
             self.refuse_latent("quantized pools, the KV tier and a pool "
@@ -261,8 +288,8 @@ class DSStateManager:
         # one buffer per leaf: the forward donates the cache and writes it
         # in place (paged_model.py), and one buffer cannot be donated twice
         self.kv_cache = {
-            name: _alloc(group.pool_shape, pool_dt, sharding)
-            for group in self.groups for name in group.names}
+            name: _alloc(shape, pool_dt, sharding) for group in self.groups
+            for name, shape in group.pool_shapes.items()}
         if self.kv_quant:
             # symmetric per-(layer, block, kv-head) scales, indexed by
             # pool block id — a prefix-shared block shares its scale for
@@ -310,7 +337,7 @@ class DSStateManager:
             f"{what} assume(s) a pool [L, NB, KH, bs, D] with a kv-head "
             "axis (a scale a head, a split by head); this model's cache "
             "is one latent row a token, shared by every head "
-            f"({self.groups[0].block_shape} a block)")
+            f"({[g.block_shapes for g in self.groups]} a block)")
 
     def refuse_grouped(self, what: str) -> None:
         """The typed refusal of a feature that assumes a sequence's whole

@@ -82,3 +82,18 @@ def spec_summary(stats: Dict[str, int]) -> Dict[str, float]:
         "tokens_per_forward": (stats.get("emitted", 0) / rows
                                if rows else 0.0),
     }
+
+
+def share_forward(engine, cache: dict, key):
+    """Give ``engine`` the jitted forward of the first engine that was
+    registered in ``cache`` under ``key``, with what it has compiled, and
+    return the engine. For suites that build many engines of one model at
+    one sizing: each would otherwise trace and compile the same buckets
+    again (a jit belongs to its ``PagedCausalLM``). ``key`` has to name
+    everything a trace reads — the model, the sizing, any module switch a
+    test has moved. An engine that compiled ahead keeps its own."""
+    if not engine.config.compile_ahead:
+        engine.paged.forward = engine._forward_jit = cache.setdefault(
+            key, engine.paged.forward)
+    return engine
+

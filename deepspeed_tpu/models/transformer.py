@@ -28,13 +28,37 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import spec
+
+
+class LatentSizes(NamedTuple):
+    """One latent kind's sizes (``TransformerConfig.latent_sizes``)."""
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    window: int         # 0: the whole context
+    topk: int           # 0: no selection
+
+    @property
+    def dim(self) -> int:
+        """What a layer caches a token: the latent and the rotated key
+        part."""
+        return self.kv_rank + self.rope
+
+    @property
+    def width(self) -> int:
+        """A token's row in the pool: ``dim`` in whole 128-lane tiles."""
+        return -(-self.dim // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,6 +174,29 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # A "latent_sparse" layer is that layer behind a learned selection:
+    # an indexer of ``index_n_heads`` heads ``index_head_dim`` wide scores
+    # every earlier position, and the layer attends the ``index_topk`` of
+    # largest score only (all of them while the context is shorter). Its
+    # cache is the latent row and, beside it, the indexer's key.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # A "latent_window" layer is latent attention over the last
+    # ``sliding_window`` positions, at sizes of its own (``swa_*``: heads,
+    # ranks, head widths, rope base); its cache is a layer group of its
+    # own, whose blocks behind the window go back.
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    attn_gate_headwise: bool = False     # latent kinds: one sigmoid gate a
+    #   head on the attention output, from ``w_g`` (hidden -> heads)
+    latent_rescale: bool = False         # c_q and c times sqrt(hidden /
+    #   rank) behind their norms
 
     def __post_init__(self):
         # a configuration read from JSON brings lists
@@ -170,18 +217,27 @@ class TransformerConfig:
                     f"divides num_layers ({self.num_layers}) less the "
                     f"{len(lead)} lead_layers")
             kinds = set(pattern + lead)
-            if "latent" in kinds and (
-                    kinds & {"full", "window"} or min(
-                        self.q_lora_rank, self.kv_lora_rank,
-                        self.qk_nope_head_dim, self.v_head_dim) <= 0
-                    or self.qk_rope_head_dim <= 0
-                    or self.qk_rope_head_dim % 2):
+            from .hybrid import LATENT_KINDS
+
+            for kind in sorted(kinds & set(LATENT_KINDS)):
+                z = self.latent_sizes(kind)
+                if kinds & {"full", "window"} or min(
+                        z.heads, z.q_rank, z.kv_rank, z.nope, z.v) <= 0 \
+                        or z.rope <= 0 or z.rope % 2:
+                    raise ValueError(
+                        f"{kind!r} layers need heads, q and kv ranks, "
+                        "nope and v head widths > 0 and an even rope "
+                        "width, and do not mix with \"full\" or "
+                        "\"window\" layers (K/V by head and headless "
+                        "latents are not laid out in one model)")
+            if "latent_sparse" in kinds and (
+                    min(self.index_n_heads, self.index_topk) <= 0
+                    or self.index_head_dim < self.qk_rope_head_dim):
                 raise ValueError(
-                    "\"latent\" layers need q_lora_rank, kv_lora_rank, "
-                    "qk_nope_head_dim, v_head_dim > 0 and an even "
-                    "qk_rope_head_dim, and do not mix with \"full\" or "
-                    "\"window\" layers (one K/V layout a model)")
-            windowed = "window" in pattern + lead
+                    "\"latent_sparse\" layers need index_n_heads, "
+                    "index_topk > 0 and an index_head_dim that holds the "
+                    "rotated part (qk_rope_head_dim)")
+            windowed = bool(kinds & {"window", "latent_window"})
             if windowed != (isinstance(self.sliding_window, int)
                             and self.sliding_window > 0) \
                     or self.moe_num_experts <= 0 \
@@ -203,22 +259,42 @@ class TransformerConfig:
     @property
     def is_latent(self) -> bool:
         """Whether the attention layers are latent ones (their cache has
-        no head axis: ``kv_layout``)."""
-        return self.layer_pattern is not None \
-            and "latent" in self.layer_pattern + self.lead_layers
+        no head axis: ``kv_layouts``)."""
+        from .hybrid import LATENT_KINDS
+
+        return self.layer_pattern is not None and bool(
+            set(LATENT_KINDS) & set(self.layer_pattern + self.lead_layers))
+
+    def latent_sizes(self, kind: str = "latent") -> "LatentSizes":
+        """The sizes of one latent kind's layers: the model's one set for
+        ``"latent"`` and ``"latent_sparse"`` (the second with the
+        selection's ``topk``), the ``swa_*`` set and the window for
+        ``"latent_window"``."""
+        if kind == "latent_window":
+            return LatentSizes(
+                self.swa_num_heads, self.swa_q_lora_rank,
+                self.swa_kv_lora_rank, self.swa_qk_nope_head_dim,
+                self.swa_qk_rope_head_dim, self.swa_v_head_dim,
+                self.swa_rope_theta or self.rope_theta,
+                int(self.sliding_window or 0), 0)
+        return LatentSizes(
+            self.num_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+            self.rope_theta, 0,
+            self.index_topk if kind == "latent_sparse" else 0)
 
     @property
     def latent_dim(self) -> int:
-        """What a latent layer caches a token: the latent and the rotated
-        key part."""
-        return self.kv_lora_rank + self.qk_rope_head_dim
+        """What a whole-context latent layer caches a token: the latent
+        and the rotated key part (``latent_sizes(kind).dim`` of any)."""
+        return self.latent_sizes().dim
 
     @property
     def latent_width(self) -> int:
-        """A token's row in the latent pool: ``latent_dim`` padded with
-        zeros to whole 128-lane tiles, which is what the row occupies on
-        the chip whether the pad is stated or not."""
-        return -(-self.latent_dim // 128) * 128
+        """A token's row in the whole-context latent pool: ``latent_dim``
+        padded with zeros to whole 128-lane tiles, which is what the row
+        occupies on the chip whether the pad is stated or not."""
+        return self.latent_sizes().width
 
     @property
     def is_hybrid(self) -> bool:
@@ -240,8 +316,7 @@ class TransformerConfig:
         """Layers that keep per-token K/V (all of them, unless hybrid)."""
         if self.layer_pattern is None:
             return self.num_layers
-        return self.layers_of("full") + self.layers_of("window") \
-            + self.layers_of("latent")
+        return self.num_layers - self.layers_of("linear")
 
     @property
     def num_linear_layers(self) -> int:
@@ -268,19 +343,48 @@ class TransformerConfig:
             sw = self.sliding_window
             return ((int(sw) if isinstance(sw, int) else 0,
                      self.num_layers),)
-        groups = [(0, self.layers_of("full") + self.layers_of("latent")),
-                  (int(self.sliding_window or 0), self.layers_of("window"))]
+        groups = [(0, sum(self.layers_of(k) for k in self.group_kinds(0))),
+                  (int(self.sliding_window or 0),
+                   sum(self.layers_of(k) for k in self.group_kinds(1)))]
         return tuple(g for g in groups if g[1])
+
+    @staticmethod
+    def group_kinds(windowed: int) -> Tuple[str, ...]:
+        """The attention kinds whose K/V lives the whole context (0) or
+        a window (1)."""
+        return ("window", "latent_window") if windowed \
+            else ("full", "latent", "latent_sparse")
+
+    def kv_layouts(self, block_size: int) -> Tuple[Dict[str, Tuple[int,
+                                                                    ...]],
+                                                   ...]:
+        """Each layer group's pool, in ``kv_groups``' order: its leaves
+        and one block's shape in each. ``k`` and ``v`` ``[KH, bs, D]``;
+        for latent layers the leaf ``kv`` ``[bs, width]`` — a token's
+        ``(c, k_r)`` row at the group's kind's own width, shared by every
+        head — and, where the group has ``"latent_sparse"`` layers, the
+        indexer's key beside it, ``ki`` ``[bs, index_head_dim]``: a
+        second row a token, in the same blocks of the same table."""
+        if not self.is_latent:
+            block = (self.kv_heads, block_size, self.head_dim)
+            return ({"k": block, "v": block},) * len(self.kv_groups())
+        kinds = set(self.layer_pattern + self.lead_layers)
+        layouts = []
+        for window, _ in self.kv_groups():
+            kind = "latent_window" if window else "latent"
+            leaves = {"kv": (block_size, self.latent_sizes(kind).width)}
+            if not window and "latent_sparse" in kinds:
+                leaves["ki"] = (block_size, self.index_head_dim)
+            layouts.append(leaves)
+        return tuple(layouts)
 
     def kv_layout(self, block_size: int) -> Tuple[Tuple[str, ...],
                                                   Tuple[int, ...]]:
-        """A pool's leaves and one block's shape in each (every group of
-        a model has the same): ``k`` and ``v`` ``[KH, bs, D]``, or for
-        latent layers the one leaf ``kv`` ``[bs, latent_width]`` — a
-        token's ``(c, k_r)`` row, shared by every head."""
-        if self.is_latent:
-            return ("kv",), (block_size, self.latent_width)
-        return ("k", "v"), (self.kv_heads, block_size, self.head_dim)
+        """The first group's leaves and its first leaf's block: the
+        whole of it for a model whose groups share one layout and whose
+        leaves share one block shape (``kv_layouts`` says the rest)."""
+        first = self.kv_layouts(block_size)[0]
+        return tuple(first), next(iter(first.values()))
 
     @property
     def kv_heads(self) -> int:
@@ -1223,20 +1327,49 @@ class CausalLM:
                 return hybrid.gdn_mixer(cfg, h1, lp, state0["conv"],
                                         state0["ssm"], n_tokens)[0]
 
-        def latent_mixer(h1, lp, _):
-            with scope("qkv"):
-                q_nope, q_rope, c, k_r = hybrid.latent_qkv(cfg, h1, lp, rope)
-            with scope("kv_expand"):
-                k_nope, v = hybrid.latent_expand(cfg, c, lp)
-            with scope("attend"):
-                attn = hybrid.latent_attend_dense(cfg, q_nope, q_rope,
-                                                  k_nope, k_r, v)
-            with scope("attn_out"):
-                return hybrid.latent_out(cfg, attn, lp)
+        def latent_mixer(kind):
+            z = cfg.latent_sizes(kind)
+            turn = rope
+            if z.theta != cfg.rope_theta:       # the kind's own base
+                cos_k, sin_k = rope_table(cfg.max_seq_len, z.rope, z.theta)
+                cos_k, sin_k = ((cos_k[positions], sin_k[positions])
+                                if positions is not None
+                                else (cos_k[:T], sin_k[:T]))
+                turn = lambda t: apply_rope(                # noqa: E731
+                    t, cos_k, sin_k, cfg.rope_interleaved)
+
+            def mixer(h1, lp, _):
+                keep = None
+                with scope("qkv"):
+                    c_q = hybrid.latent_cq(cfg, h1, lp, kind)
+                    q_nope, q_rope, c, k_r = hybrid.latent_qkv(
+                        cfg, h1, lp, turn, kind, c_q)
+                    gate = hybrid.latent_gate(cfg, h1, lp)
+                if z.topk:
+                    with scope("index"):
+                        with scope("index_proj"):
+                            qi, ki, wi = hybrid.index_qk(cfg, h1, c_q, lp,
+                                                         turn)
+                        with scope("index_score"):
+                            scores = hybrid.index_scores(qi, ki, wi)
+                        with scope("index_select"):
+                            live = jnp.arange(T)[:, None] \
+                                >= jnp.arange(T)[None, :]
+                            keep = hybrid.index_keep(scores, live[None],
+                                                     z.topk)
+                with scope("kv_expand"):
+                    k_nope, v = hybrid.latent_expand(cfg, c, lp, kind)
+                with scope("attend"):
+                    attn = hybrid.latent_attend_dense(
+                        cfg, q_nope, q_rope, k_nope, k_r, v, kind, keep)
+                with scope("attn_out"):
+                    return hybrid.latent_out(cfg, attn, lp, gate)
+            return mixer
 
         mixers = {"full": attention_mixer("full"),
                   "window": attention_mixer("window"), "linear": linear_mixer,
-                  "latent": latent_mixer}
+                  **{kind: latent_mixer(kind)
+                     for kind in hybrid.LATENT_KINDS}}
 
         def period(x, slots):
             return hybrid.run_period(cfg, x, slots, mixers,

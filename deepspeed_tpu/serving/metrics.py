@@ -421,6 +421,12 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # (engine._count_latent)
               "prefill_tokens", "latent_q_absorbed", "latent_q_expanded",
               "latent_rows_expanded",
+              # ... of sparse layers, the keys their query positions may
+              # see and the ones the selection kept; of window latent
+              # layers, the query rows by path and the positions rebuilt
+              "sparse_keys_live", "sparse_keys_selected",
+              "window_q_absorbed", "window_q_expanded",
+              "window_rows_expanded",
               # fault tolerance (docs/SERVING.md "Fault tolerance"):
               # failover = a dead replica's request re-enqueued (stream
               # resumed elsewhere); restarts = supervisor replaced a DEAD

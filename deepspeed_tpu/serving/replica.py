@@ -593,7 +593,9 @@ class Replica:
                      "moe_rows_routed", "moe_rows_held",
                      "kv_blocks_released", "prefill_tokens",
                      "latent_q_absorbed", "latent_q_expanded",
-                     "latent_rows_expanded")
+                     "latent_rows_expanded", "sparse_keys_live",
+                     "sparse_keys_selected", "window_q_absorbed",
+                     "window_q_expanded", "window_rows_expanded")
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),
                          ("resumed", "sequences_resumed"))
     _STEP_COUNTERS = (("steps", "scheduler_steps"),

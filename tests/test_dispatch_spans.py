@@ -17,6 +17,7 @@ import pytest
 
 from deepspeed_tpu.inference.v2.engine_v2 import (
     InferenceEngineV2, RaggedInferenceEngineConfig)
+from deepspeed_tpu.inference.v2.testing import share_forward
 from deepspeed_tpu.inference.v2.scheduler import ContinuousBatchingScheduler
 from deepspeed_tpu.models.transformer import (
     TINY_TEST, CausalLM, TransformerConfig)
@@ -37,7 +38,7 @@ HYBRID = TransformerConfig(
     moe_shared_intermediate_size=16)
 SIZING = dict(max_ragged_sequence_count=4, max_chunk_tokens=16,
               max_ragged_batch_size=48, kv_blocks=96, kv_block_size=8)
-MODELS = {}
+MODELS, FORWARDS = {}, {}
 
 
 def engine(kind="dense"):
@@ -45,8 +46,11 @@ def engine(kind="dense"):
         model = CausalLM(DENSE if kind == "dense" else HYBRID)
         MODELS[kind] = model, model.init(jax.random.PRNGKey(0))
     model, params = MODELS[kind]
-    return InferenceEngineV2(model, params=params,
-                             config=RaggedInferenceEngineConfig(**SIZING))
+    # engines of a kind share one jitted forward (``testing.share_forward``)
+    return share_forward(
+        InferenceEngineV2(model, params=params,
+                          config=RaggedInferenceEngineConfig(**SIZING)),
+        FORWARDS, kind)
 
 
 def named(tracer, name):

@@ -17,6 +17,7 @@ import pytest
 from deepspeed_tpu.inference.v2.engine_v2 import (
     InferenceEngineV2, RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.scheduling_utils import SchedulingResult
+from deepspeed_tpu.inference.v2.testing import share_forward
 from deepspeed_tpu.models.hybrid import RecurrentStateUnsupported
 from deepspeed_tpu.models.transformer import CausalLM, TransformerConfig
 
@@ -50,11 +51,21 @@ def model_and_params():
     return model, jax.tree_util.tree_unflatten(tree, flat)
 
 
-def engine(model_and_params, **sizing):
+#: the engines of one sizing serve one model at one configuration: they
+#: share one jitted forward (``testing.share_forward``), so a case
+#: compiles only the buckets no earlier case ran
+_FORWARDS = {}
+
+
+def engine(model_and_params, own_forward=False, **sizing):
     model, params = model_and_params
-    return InferenceEngineV2(model, params=params,
-                             config=RaggedInferenceEngineConfig(
-                                 **dict(SIZING, **sizing)))
+    eng = InferenceEngineV2(model, params=params,
+                            config=RaggedInferenceEngineConfig(
+                                **dict(SIZING, **sizing)))
+    if own_forward:
+        return eng
+    return share_forward(eng, _FORWARDS,
+                         (id(model), tuple(sorted(sizing.items()))))
 
 
 def prompt(seed, n):
@@ -322,8 +333,8 @@ def test_compiled_ahead_the_engine_gives_the_same_logits_and_compiles_no_more(
     """``compile_ahead``: every shape's executable is there when the
     engine is built, a put runs it (nothing is compiled at first use), and
     the logits are those of the engine that compiles at first use."""
-    lazy, ahead = engine(model_and_params), engine(model_and_params,
-                                                   compile_ahead=2)
+    lazy, ahead = engine(model_and_params, own_forward=True), engine(
+        model_and_params, compile_ahead=2)
     p, q = prompt(3, 21), prompt(4, 5)
     puts = [([1], [p[:16]]), ([1, 2], [p[16:], q]), ([1, 2], [[7], [9]])]
     for uids, tokens in puts:

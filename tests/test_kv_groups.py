@@ -66,11 +66,21 @@ def dense():
     return model, _perturbed(model)
 
 
+#: engines of one model at one sizing share one jitted forward
+#: (``testing.share_forward``; release on or off is the manager's, not the
+#: program's)
+_FORWARDS = {}
+
+
 def engine(model_and_params, **sizing):
+    from deepspeed_tpu.inference.v2.testing import share_forward
+
     model, params = model_and_params
-    return InferenceEngineV2(model, params=params,
-                             config=RaggedInferenceEngineConfig(
-                                 **dict(SIZING, **sizing)))
+    eng = InferenceEngineV2(model, params=params,
+                            config=RaggedInferenceEngineConfig(
+                                **dict(SIZING, **sizing)))
+    return share_forward(eng, _FORWARDS,
+                         (id(model), tuple(sorted(sizing.items()))))
 
 
 def prompt(seed, n):

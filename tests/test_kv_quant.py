@@ -14,6 +14,7 @@ import pytest
 
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   RaggedInferenceEngineConfig)
+from deepspeed_tpu.inference.v2.testing import share_forward
 from deepspeed_tpu.inference.v2.kv_quant import (kv_bytes_per_block,
                                                  validate_kv_quant)
 from deepspeed_tpu.inference.v2.ragged import BlockedAllocator, DSStateManager
@@ -37,6 +38,9 @@ def model_and_params():
     return model, model.init(jax.random.PRNGKey(0))
 
 
+_FORWARDS = {}
+
+
 def make_engine(model, params, quant=True, kv_blocks=64, max_seqs=8,
                 qdtype="int8", **cfg_kw):
     vcfg = RaggedInferenceEngineConfig(
@@ -44,7 +48,11 @@ def make_engine(model, params, quant=True, kv_blocks=64, max_seqs=8,
         max_chunk_tokens=32, kv_blocks=kv_blocks, kv_block_size=BS,
         max_tracked_sequences=64, kv_quant_enabled=quant,
         kv_quant_dtype=qdtype, **cfg_kw)
-    return InferenceEngineV2(model, params=params, config=vcfg)
+    # one block size and one step budget: what a trace reads beside its
+    # arguments (pool dtype, blocks and scales are arguments) is the
+    # model's, so a model's engines share one jitted forward
+    return share_forward(InferenceEngineV2(model, params=params, config=vcfg),
+                         _FORWARDS, id(model))
 
 
 # the representation axis (ISSUE 13): the PR 6 suite runs for both the

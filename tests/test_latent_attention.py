@@ -125,3 +125,159 @@ def test_off_the_tpu_the_twins_run_and_misaligned_widths_fall_to_them(
     assert not calls
     la.latent_decode(q, rows, 0, tables[:1], ctx, R, SCALE)
     assert len(calls) == 1
+
+
+# ------------------------------------------------ a window and a selection
+
+def _dense(q_rows, kv, keep, rank=R):
+    """Softmax of ``q_rows`` [H, W] over the rows ``kv`` [S, W] that
+    ``keep`` [S] names, times their first ``rank`` lanes."""
+    s = np.einsum("hw,sw->hs", np.asarray(q_rows), kv) * SCALE
+    s = np.where(keep[None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return (p / p.sum(-1, keepdims=True)) @ kv[:, :rank]
+
+
+@pytest.mark.parametrize("window", [5, 16, 40, 300])
+def test_absorbed_kernel_under_a_window_walks_from_its_first_block(
+        pool, interpret, window):
+    rows, tables = pool
+    rng = np.random.default_rng(3)
+    q = np.zeros((3, H, W), np.float32)
+    q[..., :R + ROPE] = rng.normal(size=(3, H, R + ROPE))
+    q = jnp.asarray(q)
+    ctx = jnp.asarray([37, 0, 128], jnp.int32)
+    # what lies wholly behind the window is gone from the table
+    gone = np.asarray(tables).copy()
+    for n, c in enumerate([37, 0, 128]):
+        gone[n, :max(c - window, 0) // BS] = -1
+    got = la.latent_decode(q, rows, 1, jnp.asarray(gone), ctx, R, SCALE,
+                           window=window)
+    twin = la.latent_decode_xla(q, rows, 1, tables, ctx, R, SCALE, window)
+    np.testing.assert_allclose(got, twin, atol=2e-6)
+    assert not np.asarray(got[1]).any()
+    for n, c in ((0, 37), (2, 128)):
+        kv = np.asarray(rows[1, tables[n]]).reshape(-1, W)
+        at = np.arange(kv.shape[0])
+        np.testing.assert_allclose(
+            got[n], _dense(q[n], kv, (at < c) & (at >= c - window)),
+            atol=2e-5)
+
+
+@pytest.mark.parametrize("start, n", [(0, 32), (50, 20), (96, 32)],
+                         ids=["fresh", "inside", "to-the-end"])
+@pytest.mark.parametrize("mode", ["window", "selected", "joined"])
+def test_expanded_kernel_under_a_window_a_mask_and_a_joined_rope(
+        pool, monkeypatch, start, n, mode):
+    """``window``: keys within 24 of the query, the loop starting at the
+    window's tile; ``selected``: each query's own random subset; ``joined``:
+    a nope width of 96 with the 32-wide rope behind it, one dot."""
+    rows, tables = pool
+    monkeypatch.setattr(la, "EXPAND_TILE", 64)
+    monkeypatch.setattr(la, "BLOCK_Q", 16)
+    monkeypatch.setattr(la, "BLOCK_K", 32)
+    rng = np.random.default_rng(4)
+    C, dn = 32, 96 if mode == "joined" else DN
+    window = 24 if mode != "selected" else 0
+    wkb = jnp.asarray(rng.normal(size=(R, H, dn)) * 0.1, jnp.float32)
+    wvb = jnp.asarray(rng.normal(size=(R, H, DV)) * 0.1, jnp.float32)
+    qn = jnp.asarray(rng.normal(size=(C, H, dn)), jnp.float32)
+    qr = jnp.asarray(rng.normal(size=(C, H, ROPE)), jnp.float32)
+    expand = _expand(wkb, wvb)
+    keys = MB * BS
+    picked = jnp.asarray(rng.random((C, keys)) < 0.4) \
+        if mode == "selected" else None
+    options = {"window": window} if window else {}
+    if picked is not None:
+        options["keep"] = picked.astype(jnp.int8)
+
+    def run(force):
+        monkeypatch.setattr(la, "_FORCE_INTERPRET", force)
+        return jax.jit(lambda: la.latent_prefill(
+            qn, qr, rows, 1, tables[0], jnp.asarray(start), jnp.asarray(n),
+            expand, R, DV, SCALE, **options))()
+
+    kernel, twin = run(True), run(False)
+    lat = rows[1, tables[0]].reshape(-1, W)
+    kn, v = expand(lat[:, :R])
+    s = (jnp.einsum("chd,htd->hct", qn, kn)
+         + jnp.einsum("chd,td->hct", qr, lat[:, R:R + ROPE])) * SCALE
+    qpos = start + jnp.arange(C)[:, None]
+    kpos = jnp.arange(keys)[None]
+    keep = (kpos <= qpos) & (kpos < start + n)
+    if window:
+        keep &= kpos > qpos - window
+    if picked is not None:
+        # a query with no selected key at all has no answer to hold
+        keep &= picked
+        rows_ok = np.asarray(keep.any(-1))[:n]
+    else:
+        rows_ok = np.ones((n,), bool)
+    want = jnp.einsum("hct,htd->chd",
+                      jax.nn.softmax(jnp.where(keep[None], s, -1e30), -1), v)
+    np.testing.assert_allclose(kernel[:n][rows_ok], want[:n][rows_ok],
+                               atol=5e-6)
+    np.testing.assert_allclose(twin[:n][rows_ok], want[:n][rows_ok],
+                               atol=5e-6)
+
+
+@pytest.mark.parametrize("queries", [1, 8])
+def test_index_score_kernel_against_its_twin_and_the_definition(
+        interpret, monkeypatch, queries):
+    monkeypatch.setattr(la, "KEY_TILE", 32)
+    rng = np.random.default_rng(5)
+    hi, D, N = 8, 128, 3
+    pool = jnp.asarray(rng.normal(size=(L, NB, BS, D)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(NB)[:N * MB].reshape(N, MB),
+                         jnp.int32)
+    q = jnp.asarray(rng.normal(size=(N, queries * hi, D)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(N, queries * hi)), jnp.float32)
+    ctx = jnp.asarray([37, 0, 128], jnp.int32)
+    got = la.index_score(q, w, pool, 1, tables, ctx, queries)
+    twin = la.index_score_xla(q, w, pool, 1, tables, ctx, queries)
+    assert got.shape == twin.shape == (N, queries, MB * BS)
+    for n, c in ((0, 37), (2, 128)):
+        np.testing.assert_allclose(got[n, :, :c], twin[n, :, :c], atol=2e-4)
+        k = np.asarray(pool[1, tables[n]]).reshape(-1, D)[:c]
+        qh = np.asarray(q[n]).reshape(queries, hi, D)
+        want = np.einsum("qhs,qh->qs",
+                         np.maximum(np.einsum("qhd,sd->qhs", qh, k), 0),
+                         np.asarray(w[n]).reshape(queries, hi))
+        np.testing.assert_allclose(got[n, :, :c], want, atol=2e-4)
+
+
+def test_sparse_absorbed_kernel_attends_the_gathered_rows_and_no_other(
+        pool, interpret):
+    rows, tables = pool
+    rng = np.random.default_rng(6)
+    K = 32
+    q = np.zeros((3, H, W), np.float32)
+    q[..., :R + ROPE] = rng.normal(size=(3, H, R + ROPE))
+    q = jnp.asarray(q)
+    ctx = [37, 0, 128]
+    idx = np.stack([np.r_[rng.permutation(max(c, 1))[:K],
+                          np.zeros(max(K - c, 0), int)][:K] for c in ctx])
+    n_sel = jnp.asarray([min(c, K) for c in ctx], jnp.int32)
+    got = la.latent_sparse_decode(q, rows, 1, tables, jnp.asarray(idx),
+                                  n_sel, R, SCALE)
+    kv = rows[1, jnp.take_along_axis(tables, jnp.asarray(idx) // BS, 1),
+              jnp.asarray(idx) % BS]
+    np.testing.assert_allclose(
+        got, la.sparse_decode_xla(q, kv, n_sel, R, SCALE), atol=2e-6)
+    assert not np.asarray(got[1]).any()
+    for n in (0, 2):
+        lat = np.asarray(rows[1, tables[n]]).reshape(-1, W)
+        keep = np.zeros(lat.shape[0], bool)
+        keep[idx[n, :int(n_sel[n])]] = True
+        np.testing.assert_allclose(got[n], _dense(q[n], lat, keep), atol=2e-5)
+
+
+def test_where_a_kinds_paths_cross_is_a_function_of_its_widths():
+    # R 512, nope 128, v 128: 33.6 M a key over 196.6 k a pair = 171 rows
+    assert la.absorb_max_queries(512, 128, 64, 128) == la.ABSORB_MAX_QUERIES
+    # R 1024, nope 192, v 128 under a window of 513: 300 rows
+    assert la.absorb_max_queries(1024, 192, 64, 128, 513) == 256
+    # a window whose own rebuild costs more than absorbing it: never
+    assert la.absorb_max_queries(1024, 192, 64, 128, 64) >= 1 << 20
+    assert la.expand_tile(1040, 64, 513) == 1024
+    assert la.expand_tile(1040, 64) == 4096

@@ -48,11 +48,21 @@ def expanded(monkeypatch):
     monkeypatch.setattr(la, "EXPAND_TILE", 16)
 
 
+#: engines of one sizing and one setting of the paths' switch (both are
+#: read when a forward is traced) share one jitted forward
+#: (``testing.share_forward``)
+_FORWARDS = {}
+
+
 def engine(pangu, **sizing):
+    from deepspeed_tpu.inference.v2.testing import share_forward
+
     model, params, base = pangu
-    return InferenceEngineV2(model, params=params,
-                             config=RaggedInferenceEngineConfig(
-                                 **dict(base, **sizing)))
+    eng = InferenceEngineV2(model, params=params,
+                            config=RaggedInferenceEngineConfig(
+                                **dict(base, **sizing)))
+    return share_forward(eng, _FORWARDS, (
+        tuple(sorted(sizing.items())), la.ABSORB_MAX_QUERIES, la.EXPAND_TILE))
 
 
 def prompt(seed, n):

@@ -21,6 +21,7 @@ from deepspeed_tpu.inference.v2 import engine_v2
 from deepspeed_tpu.inference.v2.engine_v2 import (
     DEVICE_TOKEN, InferenceEngineV2, RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.scheduler import ContinuousBatchingScheduler
+from deepspeed_tpu.inference.v2.testing import share_forward
 from deepspeed_tpu.inference.v2.spec import NGramProposer
 from deepspeed_tpu.models.transformer import (
     TINY_TEST, CausalLM, TransformerConfig)
@@ -61,14 +62,21 @@ def model_and_params(kind):
     return MODELS[kind]
 
 
+#: engines of one kind at one sizing share one jitted forward
+#: (``testing.share_forward``)
+_FORWARDS = {}
+
+
 def engine(kind, **sizing):
     model, params = model_and_params(kind)
     # as if 32 positions were free, not 128: a dense [2, 16] runs padded,
     # a chunk row beside two or three decodes merged, [1, 16 + 4]
     with mock.patch.object(engine_v2, "_FREE_POSITIONS", 32):
-        return InferenceEngineV2(model, params=params,
-                                 config=RaggedInferenceEngineConfig(
-                                     **dict(SIZING, **sizing)))
+        eng = InferenceEngineV2(model, params=params,
+                                config=RaggedInferenceEngineConfig(
+                                    **dict(SIZING, **sizing)))
+    return share_forward(eng, _FORWARDS,
+                         (kind, tuple(sorted(sizing.items()))))
 
 
 def host_argmax(logits):
