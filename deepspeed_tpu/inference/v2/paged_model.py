@@ -500,7 +500,7 @@ class PagedCausalLM:
         Q = math.gcd(C, latent_attention.INDEX_QUERIES)
         # the scores' width follows the context: a table cut to the
         # narrowest of ``SELECT_WIDTHS`` that holds every row's context
-        # (one branch a width; the top-k and the mask cost by the width)
+        # (one branch a width; the selection's passes cost by the width)
         widths = [w for w in SELECT_WIDTHS if topk <= w < keys] + [keys]
 
         def scored(qb, wb, ctxb, width):

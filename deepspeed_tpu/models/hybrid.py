@@ -506,16 +506,111 @@ def index_scores(q, k, w):
                       precision=lax.Precision.HIGHEST)
 
 
+SELECT_BLOCK = 256      # positions a block of ``index_select``'s compaction
+
+
+def _order_keys(scores, live):
+    """Each score's bits in an order-preserving unsigned form [..., S]
+    uint32: a larger score is a larger key, and a key that is not
+    ``live`` is 0, below every score's."""
+    bits = lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    return jnp.where(live, jnp.where(bits >= top, ~bits, bits | top),
+                     jnp.uint32(0))
+
+
+def _kth_largest(key, k: int, axes: int = 1):
+    """The ``k``-th largest of the keys over the last ``axes`` axes of
+    ``key`` (uint32) -> [...], found bit by bit from the top: 32 passes
+    that each count the keys at or above a candidate (a compare and a
+    sum, fused) and keep the bit while ``k`` of them are. Exact, and no
+    sort. (Four bits a pass, 15 candidates at once, is no faster for one
+    row: a pass is its compares, 66,560 of them a candidate.)"""
+    over = tuple(range(-axes, 0))
+    top = jnp.uint32(1 << 31)
+
+    def refine(i, kth):
+        cand = kth | (top >> i.astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[(...,) + (None,) * axes], axis=over,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, kth)
+
+    return lax.fori_loop(0, 32, refine,
+                         jnp.zeros(key.shape[:key.ndim - axes], jnp.uint32))
+
+
+def _running_count(mask):
+    """mask [..., blocks, B] -> the count of set positions up to and
+    including each, inside its block: one product with a triangle of
+    ones on the matrix unit (0/1 in bfloat16, summed in float32: exact)."""
+    B = mask.shape[-1]
+    tri = (jnp.arange(B)[:, None] <= jnp.arange(B)[None, :])
+    return jnp.einsum("...b,bc->...c", mask.astype(jnp.bfloat16),
+                      tri.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+
+
 def index_select(scores, live, topk: int):
     """The selection, exact: ``scores`` [..., S] float32 with ``live``
     [..., S] saying which keys a query may see -> ``(idx [..., k], n
-    [...])``: the ``k = min(topk, S)`` positions of largest score in
-    descending order (of equal scores the earlier position first), of
-    which the first ``n = min(live keys, topk)`` are selected — the rest
-    point at dead keys."""
-    k = min(int(topk), scores.shape[-1])
-    _, idx = lax.top_k(jnp.where(live, scores, -jnp.inf), k)
-    n = jnp.minimum(jnp.sum(live, axis=-1), k).astype(jnp.int32)
+    [...])``, ``k = min(topk, S)``: the ``n = min(live keys, k)`` live
+    positions of largest score — of equal scores at the edge the earlier
+    positions, ``lax.top_k``'s own rule, so the set is ``lax.top_k``'s —
+    in ascending order of position (the gather behind it walks a table's
+    blocks in order; no caller reads an order by score). ``idx[..., n:]``
+    is 0 and is not to be read.
+
+    No sort: ``lax.top_k`` at k = 2,048 sorts a row whole on the TPU,
+    and a sort of one row costs what its dependent stages cost, whatever
+    the rows (v5e: 0.64 ms for one row of 66,560 scores and 9.4 ms for
+    128, where this takes 0.09 and 1.2). Instead (1) the ``k``-th
+    largest live score is counted out (``_kth_largest``, shared with
+    ``index_keep``); (2) every key above it is in, ``g < k`` of them,
+    and of the keys equal to it the first ``k − g`` by position (a
+    running count over the equal ones); (3) the kept positions are
+    compacted in two levels over blocks of ``SELECT_BLOCK``: an output
+    slot finds its block in the blocks' running totals ([k x blocks]
+    compares), then its place in the block's running count ([k x
+    block]), which a one-hot product brings to the slot — no scatter
+    and no gather over the width."""
+    S = scores.shape[-1]
+    k, B = min(int(topk), S), SELECT_BLOCK
+    lead, nb = scores.shape[:-1], -(-S // B)
+    key = jnp.pad(_order_keys(scores, live),
+                  [(0, 0)] * len(lead) + [(0, nb * B - S)]
+                  ).reshape(lead + (nb, B))
+    kth = _kth_largest(key, k, axes=2)[..., None, None]
+    above, equal = key > kth, key == kth
+    earlier = jnp.arange(nb)[None, :] < jnp.arange(nb)[:, None]
+
+    def totals(mask):
+        """-> (running count in the block, the block's, the blocks
+        before it's)."""
+        run = _running_count(mask)
+        each = run[..., B - 1].astype(jnp.int32)              # [..., nb]
+        return run, each, jnp.sum(jnp.where(earlier, each[..., None, :], 0),
+                                  axis=-1)
+
+    # the edge: of the equal keys, the first (k - above) by position
+    room = k - jnp.sum(above, axis=(-2, -1), dtype=jnp.int32)
+    run, _, before = totals(equal)
+    kept = (above | (equal & (run + before[..., None].astype(jnp.float32)
+                              <= room[..., None, None].astype(jnp.float32)))
+            ) & (key > 0)
+    run, each, before = totals(kept)
+    until = before + each                                     # [..., nb]
+    n = until[..., -1]
+    slot = jnp.arange(k, dtype=jnp.int32)
+    past = until[..., None, :] <= slot[:, None]               # [..., k, nb]
+    block = jnp.sum(past, axis=-1, dtype=jnp.int32)           # [..., k]
+    rank = slot - jnp.max(jnp.where(past, until[..., None, :], 0), axis=-1)
+    mine = block[..., None] == jnp.arange(nb, dtype=jnp.int32)
+    run = jnp.einsum("...kn,...nb->...kb", mine.astype(jnp.bfloat16),
+                     run.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)      # [..., k, B]
+    place = jnp.sum(run <= rank[..., None].astype(jnp.float32), axis=-1,
+                    dtype=jnp.int32)
+    idx = jnp.where(slot < n[..., None], block * B + place, 0)
     return idx, n
 
 
@@ -526,23 +621,10 @@ def index_keep(scores, live, topk: int):
     more than ``topk``. Exact, and without a sort — ``lax.top_k`` at
     k = 2,048 sorts each row whole on the TPU, 0.08 ms a row of 66,560
     scores, 79% of a chunk forward: the ``topk``-th largest score is
-    found bit by bit instead, 32 passes that each count the keys at or
-    above a candidate (a compare and a sum, fused; the float's bits in
-    an order-preserving unsigned form)."""
-    k = min(int(topk), scores.shape[-1])
-    bits = lax.bitcast_convert_type(
-        jnp.where(live, scores, -jnp.inf).astype(jnp.float32), jnp.uint32)
-    top = jnp.uint32(1 << 31)
-    key = jnp.where(bits >= top, ~bits, bits | top)
-
-    def refine(i, kth):
-        cand = kth | (top >> i.astype(jnp.uint32))
-        enough = jnp.sum(key >= cand[..., None], axis=-1,
-                         dtype=jnp.int32) >= k
-        return jnp.where(enough, cand, kth)
-
-    kth = lax.fori_loop(0, 32, refine,
-                        jnp.zeros(scores.shape[:-1], jnp.uint32))
+    counted out instead (``_kth_largest``). Keys equal to it are all
+    kept (``index_select`` keeps the earlier ones, to ``topk`` exactly)."""
+    key = _order_keys(scores, live)
+    kth = _kth_largest(key, min(int(topk), scores.shape[-1]))
     return live & (key >= kth[..., None])
 
 

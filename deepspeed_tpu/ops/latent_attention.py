@@ -39,9 +39,6 @@ expanded, plus the rebuild a key. At the published widths (R 512, rope
 64, nope 128, v 128, 128 heads) that is 278.5 k against 81.9 k a pair
 and 33.6 M a key: equal at 33.6 M / (278.5 k − 81.9 k) = 171 query rows.
 ``ABSORB_MAX_QUERIES`` is the widest chunk that stays absorbed (a chunk
-is bucketed to a power of two: 128 absorbed, 256 expanded).
-
-``ABSORB_MAX_QUERIES`` is the widest chunk that stays absorbed (a chunk
 is bucketed to a power of two: 128 absorbed, 256 expanded); with a window
 the rebuild is of ``window + chunk`` keys for ``window`` pairs a query and
 the crossing moves (``absorb_max_queries``: 300 rows, so 256, at R 1024,
@@ -56,8 +53,11 @@ blocks behind it are gone from the table (-1) and are never read.
 index-key pool ``[L, NB, bs, D]`` of a row's live table and writes ``Σ_h
 w_h · relu(q_h · k_s)`` for each key, ``INDEX_QUERIES`` query positions
 of one sequence a grid step (they share the walk). The top-k over those
-scores is ``lax.top_k``, exact. A one-position row then attends its
-selected rows only: ``latent_sparse_decode`` gathers the selected
+scores is exact and no sort (``hybrid.index_select`` / ``index_keep``:
+the k-th largest score counted out bit by bit, then a compaction of the
+kept positions or the mask; ``lax.top_k`` sorts the row whole on the
+TPU). A one-position row then attends its selected rows only, in
+ascending order of position: ``latent_sparse_decode`` gathers the selected
 ``[c | k_r]`` rows through the table (an XLA gather, under ``attend``)
 and the kernel ``mla_sparse_decode`` runs the absorbed product over
 them, one grid step a row. A chunk attends expanded under the

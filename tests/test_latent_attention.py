@@ -5,10 +5,13 @@ inside a block, an empty row, several loop turns), the expanded one over
 chunks that start at 0, inside and at the end of earlier context (one
 tile and several, blocks the diagonal crosses and blocks it does not)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from deepspeed_tpu.ops import latent_attention as la
 
@@ -244,6 +247,103 @@ def test_index_score_kernel_against_its_twin_and_the_definition(
                          np.maximum(np.einsum("qhd,sd->qhs", qh, k), 0),
                          np.asarray(w[n]).reshape(queries, hi))
         np.testing.assert_allclose(got[n, :, :c], want, atol=2e-4)
+
+
+def _selection_case(name):
+    """-> (scores, live, topk) of one named case of the selection."""
+    rng = np.random.default_rng(len(name))
+
+    def prefix(ctx, S):
+        return np.arange(S) < np.asarray(ctx)[..., None]
+
+    if name == "fewer_live_than_k":
+        return rng.normal(size=(3, 700)), prefix([1, 40, 63], 700), 64
+    if name == "exactly_k_live":
+        return rng.normal(size=(2, 700)), prefix([64, 64], 700), 64
+    if name == "width_under_k":
+        return rng.normal(size=(2, 300)), prefix([300, 17], 300), 2048
+    if name == "all_equal":
+        return np.full((2, 600), 0.25), prefix([600, 90], 600), 64
+    if name == "ties_straddle_the_edge":
+        # a handful of levels: the 64th largest is one of many equal keys
+        return (np.round(rng.normal(size=(4, 900)) * 2) / 2,
+                prefix([900, 500, 70, 257], 900), 64)
+    if name == "inf_negative_denormal":
+        s = -np.abs(rng.normal(size=(3, 520)))
+        s[0, ::3] = -np.inf
+        s[1] = rng.integers(-40, 40, size=520) * 1e-42   # denormals
+        s[2, :100] = np.inf
+        return s, prefix([520, 400, 300], 520), 200
+    if name == "live_is_no_prefix":
+        return (np.round(rng.normal(size=(3, 800)), 1),
+                rng.random(size=(3, 800)) < [[0.5], [0.05], [0.9]], 64)
+    if name == "leading_axes":
+        return (rng.normal(size=(2, 3, 2, 400)),
+                prefix(rng.integers(1, 401, size=(2, 3, 2)), 400), 50)
+    if name == "width_16384":
+        return (np.round(rng.normal(size=(2, 16384)), 2),
+                prefix([16384, 9000], 16384), 2048)
+    if name == "width_no_multiple_of_the_block":
+        return (rng.normal(size=(2, 2 * 256 + 77)),
+                prefix([2 * 256 + 77, 300], 2 * 256 + 77), 100)
+    raise KeyError(name)
+
+
+def _parents_index_keep(scores, live, topk):
+    """``hybrid.index_keep`` as PR 43 wrote it, before it shared the
+    threshold with ``index_select``."""
+    k = min(int(topk), scores.shape[-1])
+    bits = lax.bitcast_convert_type(
+        jnp.where(live, scores, -jnp.inf).astype(jnp.float32), jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    key = jnp.where(bits >= top, ~bits, bits | top)
+
+    def refine(i, kth):
+        cand = kth | (top >> i.astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, kth)
+
+    kth = lax.fori_loop(0, 32, refine,
+                        jnp.zeros(scores.shape[:-1], jnp.uint32))
+    return live & (key >= kth[..., None])
+
+
+@pytest.mark.parametrize("case", [
+    "fewer_live_than_k", "exactly_k_live", "width_under_k", "all_equal",
+    "ties_straddle_the_edge", "inf_negative_denormal", "live_is_no_prefix",
+    "leading_axes", "width_16384", "width_no_multiple_of_the_block"])
+def test_the_selection_is_lax_top_ks_set_and_holds_no_sort(case):
+    """``hybrid.index_select`` against ``lax.top_k`` over the same masked
+    scores: the same ``n``, the same set in its first ``n`` entries (of
+    equal scores at the edge the earlier positions), ascending, the rest
+    0 — and the mask ``index_keep`` makes of the same threshold is the
+    parent's, bit for bit."""
+    from deepspeed_tpu.models import hybrid
+
+    scores, live, topk = _selection_case(case)
+    scores, live = jnp.asarray(scores, jnp.float32), jnp.asarray(live)
+    k = min(topk, scores.shape[-1])
+    select = jax.jit(hybrid.index_select,
+                     static_argnums=2).lower(scores, live, topk)
+    assert not re.search(r"\bsort\b|top_k|\bscatter\b", select.as_text())
+    idx, n = map(np.asarray, select.compile()(scores, live))
+    assert idx.shape == scores.shape[:-1] + (k,) and n.shape == idx.shape[:-1]
+    live_n = np.asarray(live).sum(-1)
+    np.testing.assert_array_equal(n, np.minimum(live_n, k))
+    # a dead key sorts behind every live one (-inf, and a later position
+    # than the live -inf ones where the case has them: live is a prefix)
+    _, want = lax.top_k(jnp.where(live, scores, -jnp.inf), k)
+    want = np.asarray(want).reshape(-1, k)
+    flat_live = np.asarray(live).reshape(-1, live.shape[-1])
+    for row, (got, ref, m) in enumerate(zip(idx.reshape(-1, k), want,
+                                            n.reshape(-1))):
+        assert set(got[:m]) == set(ref[:m]), row
+        assert (np.diff(got[:m]) > 0).all() and not got[m:].any(), row
+        assert flat_live[row, got[:m]].all(), row
+    np.testing.assert_array_equal(
+        np.asarray(hybrid.index_keep(scores, live, topk)),
+        np.asarray(_parents_index_keep(scores, live, topk)))
 
 
 def test_sparse_absorbed_kernel_attends_the_gathered_rows_and_no_other(

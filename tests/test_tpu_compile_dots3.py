@@ -62,7 +62,7 @@ def _nbytes(s):
     return math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
 
 
-@pytest.mark.parametrize("bucket", [(1, 256), (1, 2048)],
+@pytest.mark.parametrize("bucket", [(2, 1), (1, 128), (1, 256), (1, 2048)],
                          ids=lambda b: f"{b[0]}x{b[1]}")
 def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
     from deepspeed_tpu.inference.v2 import modules
@@ -127,6 +127,12 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
                                                    else 3)
     assert not {"mla_decode", "mla_prefill", "paged_attention"} & set(kernels)
     assert kernels.count("gmm") == 12
+    # the selection holds no sort (one-token rows and narrow chunks count
+    # the threshold out and compact, as the wide chunks' mask does): what
+    # sorts is the experts' routing, over 256 experts and a row's pairs
+    sorts = re.findall(r' sort\([^\n]*op_name="([^"]*)"', text)
+    assert sorts and all("/mlp/experts/" in s for s in sorts), sorts
+    assert not re.search(r"topk|TopK|top-k", text)
     scoped = re.findall(r'%index_score[.\d]* = [^\n]*op_name="([^"]*)"', text)
     assert scoped and all("latent_attn/index/" in s and "/index_score/" in s
                           for s in scoped)
