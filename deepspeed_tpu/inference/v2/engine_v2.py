@@ -143,6 +143,14 @@ _LATENT_TOTALS = ("prefill_tokens", "latent_q_absorbed", "latent_q_expanded",
 _SPARSE_TOTALS = ("sparse_keys_live", "sparse_keys_selected")
 _WINDOW_TOTALS = ("window_q_absorbed", "window_q_expanded",
                   "window_rows_expanded")
+#: ... of a model with ``"block_sparse"`` layers (``_count_blocks``)
+_BLOCK_TOTALS = ("sparse_rows_dense", "sparse_rows_selected",
+                 "sparse_blocks_live", "sparse_blocks_selected")
+#: the prefixes of such a model's counts in ``last_put``, which a put of
+#: several forwards sums
+_BLOCK_RECORD = ("sparse_rows_", "sparse_blocks_", "sparse_ones",
+                 "sparse_q_chunk", "sparse_pairs_chunk", "sparse_keys_chunk",
+                 "lightning_rows")
 
 
 #: a one-token row's token when its sequence's next token is still on
@@ -333,8 +341,13 @@ class InferenceEngineV2:
         self.put_totals: Dict[str, int] = {
             "forwards": 0, "positions_computed": 0, "tokens_valid": 0,
             "puts_split": 0}        # puts that ran as several forwards
-        if cfg.is_hybrid:       # its sparse FFNs' rows (_count_routing)
-            self.put_totals.update(moe_rows_routed=0, moe_rows_held=0)
+        if cfg.is_hybrid:
+            if cfg.moe_num_experts:     # its sparse FFNs' rows
+                self.put_totals.update(moe_rows_routed=0, moe_rows_held=0)
+            if cfg.has_kind("block_sparse"):    # what its selection kept
+                self.put_totals.update(dict.fromkeys(_BLOCK_TOTALS, 0))
+            if cfg.has_kind("lightning"):   # positions through the scan
+                self.put_totals["lightning_rows"] = 0
         else:
             # forwards whose q, k and v came out of one stacked weight
             # (``fuse_qkv``): all of an engine's, or none
@@ -606,7 +619,8 @@ class InferenceEngineV2:
                   "prefill_tokens") \
             + tuple(k for k in records[-1] if k.endswith(("_read_tokens",
                                                           "_qk_pairs"))
-                    or k.startswith("latent_"))
+                    or k.startswith("latent_")
+                    or k.startswith(_BLOCK_RECORD))
         self.last_put = dict(records[-1], forwards=len(records), **{
             k: sum(r.get(k, 0) for r in records) for k in summed
             if k in records[-1]})
@@ -739,8 +753,13 @@ class InferenceEngineV2:
         args = (self.params, kv_cache, arrays["tokens"],
                 arrays["start_pos"], arrays["n_tokens"],
                 arrays["block_tables"], slots, self.next_ids, id_slots)
-        if self.model.cfg.is_hybrid:    # and its sparse FFNs' rows
+        if "moe_rows_routed" in self.put_totals:    # its sparse FFNs' rows
             self._count_routing(valid)
+        if "sparse_blocks_live" in self.put_totals:
+            self._count_blocks(staged)
+        if "lightning_rows" in self.put_totals:
+            self.last_put["lightning_rows"] = valid
+            self.put_totals["lightning_rows"] += valid
         if self.model.cfg.is_latent:    # and which path its queries take
             self._count_latent(staged, bucket_chunk)
         # the unit of device work, named where it is handed over: one
@@ -848,6 +867,57 @@ class InferenceEngineV2:
         self.last_put.update(counts)
         for name, n in counts.items():
             self.put_totals[name] += n
+
+    def _count_blocks(self, staged) -> None:
+        """What a block-sparse layer's selection keeps of this put, a
+        layer and a K/V head (every one's is the same count: it follows
+        from the positions alone). ``sparse_rows_dense`` /
+        ``sparse_rows_selected``: the query positions short of
+        ``block_dense_len``, which attend every block of their past, and
+        the ones that select; ``sparse_blocks_live``: the blocks the
+        positions could see, summed over them; ``sparse_blocks_selected``:
+        of those the ones attended — a selecting position's initial
+        blocks, its ``block_topk`` (fewer while fewer lie before its
+        window) and the blocks its window reaches. ``last_put`` alone
+        also splits them by kernel: the one-token rows' (``sparse_ones``
+        and ``sparse_blocks_ones``, what ``paged_attention_select``
+        reads) and the chunk rows' (``sparse_q_chunk``, the selected
+        query-key pairs ``sparse_pairs_chunk`` and
+        ``sparse_keys_chunk``, the keys every position of a row reads
+        whatever it selects: its initial blocks, its window, itself)."""
+        from ...models import hybrid
+
+        z = hybrid.block_sizes(self.model.cfg)
+        counts = dict.fromkeys(_BLOCK_TOTALS + (
+            "sparse_ones", "sparse_blocks_ones", "sparse_q_chunk",
+            "sparse_pairs_chunk", "sparse_keys_chunk"), 0)
+        for seq, toks in staged:
+            n, seen = len(toks), seq.seen_tokens
+            t = seen + np.arange(n, dtype=np.int64)
+            live = t // z.block + 1
+            first_w = np.maximum(t - z.window + 1, 0) // z.block
+            chosen = np.clip(first_w - z.init, 0, z.topk)
+            picked = np.where(t < z.dense_len, live,
+                              z.init + chosen + t // z.block - first_w + 1)
+            dense = int((t < z.dense_len).sum())
+            counts["sparse_rows_dense"] += dense
+            counts["sparse_rows_selected"] += n - dense
+            counts["sparse_blocks_live"] += int(live.sum())
+            counts["sparse_blocks_selected"] += int(picked.sum())
+            if n == 1:
+                counts["sparse_ones"] += 1
+                counts["sparse_blocks_ones"] += int(picked[0])
+            else:
+                # the keys a position attends: its whole blocks but its
+                # own, and its own up to itself
+                counts["sparse_q_chunk"] += n
+                counts["sparse_pairs_chunk"] += int(
+                    ((picked - 1) * z.block + t % z.block + 1).sum())
+                counts["sparse_keys_chunk"] += int(min(
+                    seen + n, z.init * z.block + z.window + n - 1))
+        self.last_put.update(counts)
+        for name in _BLOCK_TOTALS:
+            self.put_totals[name] += counts[name]
 
     def _count_latent(self, staged, bucket_chunk: int) -> None:
         """A latent model's forward by path (ops/latent_attention.py): a

@@ -52,7 +52,7 @@ from jax import lax
 
 from ...models.transformer import (CausalLM, _linear, _norm, alibi_slopes,
                                    apply_rope, rope_table)
-from ...ops import latent_attention
+from ...ops import latent_attention, paged_attention
 from .kv_quant import quantized_block_write
 from .kv_write import block_write, touched_block_plan
 
@@ -66,6 +66,11 @@ QKV_LEAVES = ("wq", "wk", "wv")
 #: and half the requests' contexts stay under the narrow one)
 SELECT_ROWS = 256
 SELECT_WIDTHS = (16384,)
+#: positions of a chunk whose block scores a block-sparse layer takes at a
+#: time (``PagedCausalLM._block_selection``): every head's softmax over
+#: the table's compressed keys is 101 MB of float32 at 128 positions, 32
+#: heads and 6,208 kernels
+BLOCK_SCORE_ROWS = 128
 
 
 def fuse_qkv(params):
@@ -555,6 +560,83 @@ class PagedCausalLM:
                        (split(qi), split(wi), split(ctx)))
         return jnp.moveaxis(keep, 0, 1).reshape(N, C, -1)
 
+    def _block_compress(self, k_pool, kc_pool, layer, table, start_pos,
+                        n_tokens, chunk: int):
+        """The compressed keys this forward completes, written into the
+        ``kc`` leaf (under ``block_compress``): kernel j — the mean of
+        keys ``stride·j … stride·j + kernel − 1`` — belongs to the
+        forward that brings its last key, which may be a block after the
+        one it began in, and goes to row ``j % per`` of table block
+        ``j // per``. ``k_pool`` holds this forward's keys already: a
+        row's run of keys, from the first kernel it completes to its last
+        position, is read from whole blocks of its table and every
+        stride's mean is taken once. A kernel that ends at or past a
+        row's valid tokens is dropped."""
+        from ...models import hybrid
+
+        z = hybrid.block_sizes(self.cfg)
+        bs, MB, NB = self.block_size, table.shape[1], kc_pool.shape[1]
+        N = table.shape[0]
+        nk = -(-chunk // z.stride)
+        width = z.stride * (nk + z.ratio - 1)
+        j0 = jnp.maximum((start_pos - z.kernel + z.stride) // z.stride, 0)
+        j = j0[:, None] + jnp.arange(nk)[None, :]              # [N, nk]
+        ends = j * z.stride + z.kernel - 1
+        valid = (ends >= start_pos[:, None]) \
+            & (ends < (start_pos + n_tokens)[:, None])
+        lo = j0 * z.stride
+        n_blocks = (width + 2 * bs - z.stride - 1) // bs
+        ids = jnp.take_along_axis(
+            table, jnp.clip((lo // bs)[:, None] + jnp.arange(n_blocks),
+                            0, MB - 1), axis=1)
+        run = k_pool[layer, jnp.maximum(ids, 0)]       # [N, nb, KH, bs, D]
+        run = run.transpose(0, 1, 3, 2, 4).reshape(
+            (N, n_blocks * bs) + run.shape[2:3] + run.shape[4:])
+        run = jax.vmap(lambda r, at: lax.dynamic_slice_in_dim(
+            r, at, width, axis=0))(run, lo % bs)
+        rows = hybrid.block_compress(z, run)                   # [N, nk, KH, D]
+        block = jnp.take_along_axis(table, jnp.clip(j // z.per, 0, MB - 1),
+                                    axis=1)
+        # the sentinel NB: a positive out-of-range id, really dropped
+        block = jnp.where(valid & (block >= 0), block, NB)
+        return kc_pool.at[layer, block, j % z.per].set(rows, mode="drop")
+
+    def _block_selection(self, q, kc_pool, layer, table, positions):
+        """A block-sparse layer's selection for a forward's [N, C]
+        queries q [N, C, H, D] at ``positions`` [N, C] (a padded one's is
+        whatever): the table's compressed keys are scored
+        (``block_score``) and the blocks chosen (``block_select``). One
+        position a row -> ``(tables [N, KH, W] of pool block ids, n
+        [N])``, what ``paged_attention_select`` walks; a chunk -> the
+        int8 mask [N, C, KH, MB] over the table's blocks,
+        ``BLOCK_SCORE_ROWS`` positions at a time."""
+        from ...models import hybrid
+
+        cfg = self.cfg
+        N, C = positions.shape
+        ctx = kc_pool[layer, jnp.maximum(table, 0)]    # [N, MB, per, KH, D]
+        ctx = ctx.reshape((N, -1) + ctx.shape[3:])             # [N, J, KH, D]
+        if C == 1:
+            with jax.named_scope("block_score"):
+                scores = hybrid.block_scores(cfg, q, ctx, positions)[:, 0]
+            with jax.named_scope("block_select"):
+                picked, n = hybrid.block_select(cfg, scores, positions[:, 0])
+                return jnp.take_along_axis(
+                    jnp.maximum(table, 0)[:, None, :], picked, axis=-1), n
+        rows = math.gcd(C, BLOCK_SCORE_ROWS)
+
+        def some(xs):
+            qb, at = xs
+            with jax.named_scope("block_score"):
+                scores = hybrid.block_scores(cfg, qb, ctx, at)
+            with jax.named_scope("block_select"):
+                return hybrid.block_keep(cfg, scores, at).astype(jnp.int8)
+
+        split = lambda a: jnp.moveaxis(                        # noqa: E731
+            a.reshape((N, C // rows, rows) + a.shape[2:]), 1, 0)
+        keep = lax.map(some, (split(q), split(positions)))
+        return jnp.moveaxis(keep, 0, 1).reshape((N, C) + keep.shape[3:])
+
     def _forward_hybrid(self, params, cache, tokens, start_pos, n_tokens,
                         block_tables, state_slots, next_ids=None,
                         id_slots=None, verify_width: int = 0):
@@ -590,12 +672,24 @@ class PagedCausalLM:
           keys only: a one-position row over its gathered rows
           (``mla_sparse_decode``), a wide chunk expanded under the
           selection's mask (``mla_sparse_prefill``).
+          ``"block_sparse"`` layers keep ``k`` / ``v`` and, beside them,
+          ``kc`` [L, NB, per, KH, D]: the compressed keys, a kernel a
+          stride, written by the forward that brings a kernel's last key
+          (``_block_compress``). A layer scores its queries against the
+          table's (``_block_selection``) and attends the chosen blocks: a
+          one-token row through a table a K/V head
+          (``paged_attention_select``: those blocks are read and no
+          other), a chunk row under a block mask a query a K/V head
+          (``paged_attention_masked``: every live block walked). A query
+          short of ``block_dense_len`` selects every block of its past.
         - ``ssm`` [L_lin, slots + 1, HV, DK, DV] float32 and ``conv``
           [L_lin, slots + 1, K-1, CH]: the recurrent layers' state, one
           slot a sequence (``state_slots`` [N]; padded rows point at the
           scratch slot behind the last). A row with ``start_pos`` 0 starts
           from zero whatever its slot holds; any other resumes from its
           slot. Positions at or beyond ``n_tokens`` change no state.
+          ``"lightning"`` layers keep ``lightning`` [L_lgt, slots + 1,
+          heads, D, D] float32 by the same rules.
 
         Returns (last_logits [N, V], new cache) and, given ``next_ids``,
         the buffer with this forward's draws (``_forward``)."""
@@ -669,6 +763,28 @@ class PagedCausalLM:
                 turn = rope if hybrid.rotates(cfg, kind) else (lambda t: t)
                 window = cfg.sliding_window if kind == "window" else 0
 
+                def sparse_attend(q, layer):
+                    """A ``"block_sparse"`` layer behind its ``kv_write``:
+                    the kernels this forward ends, the selection, and
+                    the attention over what it chose."""
+                    with scope("block_compress"):
+                        pools["kc"] = self._block_compress(
+                            pools["k"], pools["kc"], layer, tables[g],
+                            start_pos, n_tokens, C)
+                    picked = self._block_selection(q, pools["kc"], layer,
+                                                   tables[g], positions)
+                    with scope("attend"):
+                        if C == 1:
+                            blocks, n = picked
+                            return paged_attention.paged_attention_select(
+                                q, pools["k"], pools["v"], blocks,
+                                jnp.where(n_tokens > 0, n, 0), start_pos,
+                                sm_scale=cfg.attn_scale, layer=layer)
+                        return paged_attention.paged_attention_masked(
+                            q, pools["k"], pools["v"], tables[g], start_pos,
+                            n_tokens, picked, sm_scale=cfg.attn_scale,
+                            layer=layer)
+
                 def mixer(h1, lp, i):
                     layer = first_layer[kind] + i
                     with scope("qkv"):
@@ -685,14 +801,18 @@ class PagedCausalLM:
                             else:
                                 pools[name] = block_write(
                                     pools[name], rows, plans[g], layer)
-                    with scope("attend"):
-                        attn = self._attend(
-                            q, {"k": pools["k" + sfx], "v": pools["v" + sfx],
-                                **({"k_scale": pools["k_scale"],
-                                    "v_scale": pools["v_scale"]}
-                                   if quant else {})},
-                            layer, tables[g], start_pos, n_tokens, None,
-                            window=window)
+                    if kind == "block_sparse":
+                        attn = sparse_attend(q, layer)
+                    else:
+                        with scope("attend"):
+                            attn = self._attend(
+                                q, {"k": pools["k" + sfx],
+                                    "v": pools["v" + sfx],
+                                    **({"k_scale": pools["k_scale"],
+                                        "v_scale": pools["v_scale"]}
+                                       if quant else {})},
+                                layer, tables[g], start_pos, n_tokens, None,
+                                window=window)
                     with scope("attn_out"):
                         return hybrid.full_out(cfg, attn, gate, lp)
                 return mixer
@@ -790,6 +910,19 @@ class PagedCausalLM:
                         return hybrid.latent_out(cfg, attn, lp, gate)
                 return mixer
 
+            def lightning_mixer(h1, lp, i):
+                layer = first_layer["lightning"] + i
+                turn = rope if hybrid.rotates(cfg, "lightning") \
+                    else (lambda t: t)
+                with scope("lightning_attn"):
+                    state = pools["lightning"][layer, state_slots]
+                    state = jnp.where(fresh[:, None, None, None], 0, state)
+                    y, state = hybrid.lightning_mixer(cfg, h1, lp, turn,
+                                                      state, n_tokens)
+                    pools["lightning"] = pools["lightning"].at[
+                        layer, state_slots].set(state)
+                    return y
+
             def linear_mixer(h1, lp, i):
                 layer = first_layer["linear"] + i
                 with scope("linear_attn"):
@@ -808,7 +941,7 @@ class PagedCausalLM:
             return dict({kind: latent_mixer(kind)
                          if kind in hybrid.LATENT_KINDS
                          else attention_mixer(kind) for kind in group_of},
-                        linear=linear_mixer)
+                        linear=linear_mixer, lightning=lightning_mixer)
 
         def period(carry, xs):
             x, pools = carry
@@ -839,4 +972,6 @@ class PagedCausalLM:
             x_last = jnp.take_along_axis(x, last_idx[:, None, None],
                                          axis=1)[:, 0]
             logits = self.model._unembed(params, x_last[:, None, :])[:, 0]
+            if cfg.logit_scale != 1.0:
+                logits = logits * jnp.asarray(cfg.logit_scale, logits.dtype)
             return _with_draw(logits, logits, new_cache, next_ids, id_slots)

@@ -203,7 +203,11 @@ class DSStateManager:
 
         def block_bytes(layout, layers):
             if not self.headless:
-                return per_layer * layers
+                # k and v, and a leaf beside them (a block-sparse
+                # layer's compressed keys) at its own block shape
+                return per_layer * layers + layers * itemsize * sum(
+                    int(np.prod(shape)) for name, shape in layout.items()
+                    if name not in ("k", "v"))
             return layers * itemsize * sum(
                 int(np.prod(shape)) for shape in layout.values())
 
@@ -219,6 +223,14 @@ class DSStateManager:
                               or sharding is not None):
             self.refuse_latent("quantized pools, the KV tier and a pool "
                                "sharded by head")
+        # compressed keys beside k / v (``"kc"``): a block's last row is a
+        # kernel that ends in the next block, so a block is its own
+        # sequence's; scales and splits by head know no such leaf
+        self.compressed = "kc" in layouts[0]
+        if self.compressed and (enable_prefix_cache or kv_quant
+                                or kv_tier_enabled or sharding is not None):
+            self.refuse_compressed("the prefix cache, quantized pools, "
+                                   "the KV tier and a pool sharded by head")
         self.allocator = self.groups[0].allocator
         # blocks handed back behind a window since this manager was built
         self.blocks_released = 0
@@ -328,6 +340,17 @@ class DSStateManager:
             f"{self.cfg.num_layers} layers, which cannot be cut at a "
             "token or shared by prefix (snapshots of state are not "
             "built yet)")
+
+    def refuse_compressed(self, what: str) -> None:
+        """The typed refusal of a feature that assumes a block holds its
+        own tokens' K/V and nothing else."""
+        from ....models.hybrid import CompressedKeysUnsupported
+
+        raise CompressedKeysUnsupported(
+            f"{what} assume(s) that a pool block holds its own tokens' "
+            "K/V only; a block-sparse layer keeps compressed keys beside "
+            "them (leaf kc), the last of which a block's successor "
+            "completes: a block is not shared, scaled or split by head")
 
     def refuse_latent(self, what: str) -> None:
         """The typed refusal of a feature that assumes K/V by kv-head."""
@@ -968,6 +991,10 @@ class DSStateManager:
         occ["state_slots_used"] = self.state_slots - len(self._free_slots)
         occ["state_bytes"] = sum(int(leaf.nbytes)
                                  for leaf in self.state_cache.values())
+        # the pools' bytes by leaf (k, v, a latent row, an index or a
+        # compressed-key leaf beside them) and the state's
+        occ["leaf_bytes"] = {name: int(leaf.nbytes) for name, leaf in
+                             {**self.kv_cache, **self.state_cache}.items()}
         # by layer group (the keys above are the first group's): window,
         # layers, and the allocator's own snapshot
         occ["groups"] = by_group
@@ -992,6 +1019,8 @@ class DSStateManager:
             return 0
         if self.recurrent:      # enabled on a built engine: refuse here
             self.refuse_recurrent("the prefix cache (match_prefix)")
+        if self.compressed:
+            self.refuse_compressed("the prefix cache (match_prefix)")
         seq = self.get_or_create_sequence(uid)
         if seq.seen_tokens > 0 or seq.kv_blocks:
             return seq.seen_tokens
@@ -1128,6 +1157,8 @@ class DSStateManager:
             self.refuse_recurrent("the KV tier")
         if self.headless:
             self.refuse_latent("the KV tier")
+        if self.compressed:
+            self.refuse_compressed("the KV tier")
         if not self.prefix_cache_enabled:
             raise ValueError(
                 "kv_tier requires the prefix cache: spill/restore happen "

@@ -30,6 +30,18 @@ one period an iteration), each followed by the same sparse FFN.
   positions at sizes of its own (``swa_*``: ``cfg.latent_sizes(kind)``
   gives every latent kind's); its latents are a layer group of their
   own, as a ``"window"`` layer's K/V.
+- ``"lightning"``: linear attention under one scalar decay a head
+  (ops/lightning_attention.py): q/k RMSNorm a head and rotary, a float32
+  state ``[heads, D, D]`` a sequence in place of any per-token cache, an
+  RMSNorm over the joined heads' output and a sigmoid gate of the
+  layer's input in front of ``wo`` (``lightning_mixer``).
+- ``"block_sparse"``: a ``"full"`` layer (its projections, norms and
+  gate) whose query, from position ``block_dense_len`` on, attends whole
+  blocks of keys only — the first, those its window reaches, and the
+  ``block_topk`` others of largest score, chosen a K/V head with **no
+  weights**: the head's queries against the means of overlapping runs of
+  its keys (``block_*`` below). Its cache is ``k`` / ``v`` and the
+  compressed keys ``kc`` beside them.
 - ``"linear"``: Gated DeltaNet (``ops/gated_delta.py``) — one projection
   to ``[q | k | v | z]`` and one to ``[b | a]``, a depthwise causal conv
   over ``[q | k | v]``, the gated delta rule over a float32 state, a
@@ -39,7 +51,9 @@ one period an iteration), each followed by the same sparse FFN.
   (``moe/grouped.py``): softmax or sigmoid scores, a selection bias
   outside the weights, the shared expert under a sigmoid gate or bare.
   ``lead_layers`` run before the scanned periods with a dense MLP in its
-  place; ``sandwich_norm`` puts a norm behind the mixer and the FFN too.
+  place, and so does every layer of a model without experts
+  (``moe_num_experts`` 0); ``sandwich_norm`` puts a norm behind the mixer
+  and the FFN too; ``residual_scale`` multiplies both before their adds.
 
 ``CausalLM`` (training, the reference path) and ``PagedCausalLM``
 (serving) both call these; only where the mixer's cache lives differs.
@@ -50,7 +64,10 @@ Scopes follow ``docs/OBSERVABILITY.md``: ``linear_attn`` ⊃ ``gdn_proj``,
 chunk forward adds ``kv_expand``; a sparse one ``index`` ⊃
 ``index_proj``, ``index_score``, ``index_select``, and ``index_write``
 inside ``kv_write``); ``router``, ``experts``, ``shared_expert`` or
-``dense_mlp`` inside ``mlp``.
+``dense_mlp`` inside ``mlp``; ``lightning_attn`` ⊃ ``lightning_proj``,
+``lightning_scan``, ``lightning_out``; ``sparse_attn`` rounds a
+block-sparse layer's and adds ``block_compress``, ``block_score`` and
+``block_select``.
 """
 
 from __future__ import annotations
@@ -58,23 +75,26 @@ from __future__ import annotations
 import contextlib
 import itertools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..ops import gated_delta as gd
+from ..ops import lightning_attention as la
 from ..parallel.sharding import spec
 
 KINDS = ("full", "linear", "window", "latent", "latent_sparse",
-         "latent_window")
+         "latent_window", "lightning", "block_sparse")
 #: the kinds whose cache is a latent row a token, no head axis
 LATENT_KINDS = ("latent", "latent_sparse", "latent_window")
 #: the kinds that keep a per-token cache, and the scope round each one's
 #: layer
 ATTN_SCOPE = {"full": "full_attn", "window": "window_attn",
               "latent": "latent_attn", "latent_sparse": "latent_attn",
-              "latent_window": "window_latent_attn"}
+              "latent_window": "window_latent_attn",
+              "block_sparse": "sparse_attn"}
 
 
 class RecurrentStateUnsupported(NotImplementedError):
@@ -100,6 +120,14 @@ class LatentKVUnsupported(NotImplementedError):
     head (inference/v2/ragged/manager.py)."""
 
 
+class CompressedKeysUnsupported(NotImplementedError):
+    """Raised where a feature that assumes a pool block holds its own
+    tokens' K/V and nothing else (the prefix cache, which shares blocks;
+    quantized pools and TP serving, which scale and split by kv-head; the
+    KV tier) meets a block-sparse layer's compressed keys, kept beside
+    k / v in the same blocks (inference/v2/ragged/manager.py)."""
+
+
 def rms(x, w, eps, zero_centered):
     """RMSNorm over the last dim in float32; ``zero_centered``: the gain
     is ``1 + w``."""
@@ -123,12 +151,22 @@ def gdn_dims(cfg):
 
 
 def state_shapes(cfg, slots: int):
-    """The recurrent cache for ``slots`` sequences: the delta rule's
-    state (float32 whatever the served type) and the conv's tail."""
-    hk, hv, dk, dv, ch = gdn_dims(cfg)
-    L = cfg.num_linear_layers
-    return {"ssm": ((L, slots, hv, dk, dv), jnp.float32),
-            "conv": ((L, slots, cfg.linear_conv_kernel - 1, ch), cfg.dtype)}
+    """The recurrent cache for ``slots`` sequences, by the kinds the
+    model has: the delta rule's state (float32 whatever the served type)
+    and the conv's tail; a lightning layer's state, as wide as its head
+    on both sides."""
+    shapes = {}
+    if cfg.layers_of("linear"):
+        hk, hv, dk, dv, ch = gdn_dims(cfg)
+        L = cfg.layers_of("linear")
+        shapes.update(
+            ssm=((L, slots, hv, dk, dv), jnp.float32),
+            conv=((L, slots, cfg.linear_conv_kernel - 1, ch), cfg.dtype))
+    if cfg.layers_of("lightning"):
+        nh, hd = cfg.lightning_num_heads, cfg.lightning_head_dim
+        shapes["lightning"] = ((cfg.layers_of("lightning"), slots, nh, hd,
+                                hd), jnp.float32)
+    return shapes
 
 
 # ------------------------------------------------------------------- init
@@ -183,6 +221,14 @@ def init_slot(cfg, kind: str, key, periods: int, dense: bool = False):
                       w_iw=w((h, hi)),
                       ik_norm_w=jnp.ones((P, di), jnp.float32),
                       ik_norm_b=jnp.zeros((P, di), jnp.float32))
+    elif kind == "lightning":
+        width = cfg.lightning_num_heads * cfg.lightning_head_dim
+        lp.update(wq=w((h, width)), wk=w((h, width)), wv=w((h, width)),
+                  wg=w((h, width)), wo=w((width, h), out_std),
+                  out_norm_w=jnp.ones((P, width), jnp.float32))
+        if cfg.qk_norm:
+            lp["q_norm_w"] = gain((P, cfg.lightning_head_dim), jnp.float32)
+            lp["k_norm_w"] = gain((P, cfg.lightning_head_dim), jnp.float32)
     elif kind in ATTN_SCOPE:
         own_gate = cfg.attn_output_gate and cfg.attn_gate_proj
         q_out = nh * hd * (2 if cfg.attn_output_gate and not own_gate else 1)
@@ -204,7 +250,7 @@ def init_slot(cfg, kind: str, key, periods: int, dense: bool = False):
             dt_bias=jnp.ones((P, hv), jnp.float32),
             gdn_norm_w=jnp.ones((P, dv), jnp.float32),
             w_gdn_out=w((hv * dv, h), out_std))
-    if dense:
+    if dense or not cfg.moe_num_experts:
         m = cfg.intermediate_size
         lp.update(w_in=w((h, m)), w_gate=w((h, m)), w_out=w((m, h), out_std))
         return lp
@@ -249,6 +295,14 @@ def slot_specs(cfg, kind: str, dense: bool = False):
                       w_iw=spec("layers", "embed", None),
                       ik_norm_w=spec("layers", None),
                       ik_norm_b=spec("layers", None))
+    elif kind == "lightning":
+        lp.update({name: spec("layers", "embed", "heads")
+                   for name in ("wq", "wk", "wv", "wg")},
+                  wo=spec("layers", "heads", "embed"),
+                  out_norm_w=spec("layers", None))
+        if cfg.qk_norm:
+            lp["q_norm_w"] = spec("layers", None)
+            lp["k_norm_w"] = spec("layers", None)
     elif kind in ATTN_SCOPE:
         lp.update(wq=spec("layers", "embed", "heads"),
                   wk=spec("layers", "embed", "kv_heads"),
@@ -266,7 +320,7 @@ def slot_specs(cfg, kind: str, dense: bool = False):
                   A_log=spec("layers", None), dt_bias=spec("layers", None),
                   gdn_norm_w=spec("layers", None),
                   w_gdn_out=spec("layers", None, "embed"))
-    if dense:
+    if dense or not cfg.moe_num_experts:
         lp.update(w_in=spec("layers", "embed", "mlp"),
                   w_gate=spec("layers", "embed", "mlp"),
                   w_out=spec("layers", "mlp", "embed"))
@@ -575,29 +629,9 @@ def index_select(scores, live, topk: int):
     and no gather over the width."""
     S = scores.shape[-1]
     k, B = min(int(topk), S), SELECT_BLOCK
-    lead, nb = scores.shape[:-1], -(-S // B)
-    key = jnp.pad(_order_keys(scores, live),
-                  [(0, 0)] * len(lead) + [(0, nb * B - S)]
-                  ).reshape(lead + (nb, B))
-    kth = _kth_largest(key, k, axes=2)[..., None, None]
-    above, equal = key > kth, key == kth
-    earlier = jnp.arange(nb)[None, :] < jnp.arange(nb)[:, None]
-
-    def totals(mask):
-        """-> (running count in the block, the block's, the blocks
-        before it's)."""
-        run = _running_count(mask)
-        each = run[..., B - 1].astype(jnp.int32)              # [..., nb]
-        return run, each, jnp.sum(jnp.where(earlier, each[..., None, :], 0),
-                                  axis=-1)
-
-    # the edge: of the equal keys, the first (k - above) by position
-    room = k - jnp.sum(above, axis=(-2, -1), dtype=jnp.int32)
-    run, _, before = totals(equal)
-    kept = (above | (equal & (run + before[..., None].astype(jnp.float32)
-                              <= room[..., None, None].astype(jnp.float32)))
-            ) & (key > 0)
-    run, each, before = totals(kept)
+    kept = _kept_blocks(scores, live, k)
+    nb = kept.shape[-2]
+    run, each, before = _block_totals(kept)
     until = before + each                                     # [..., nb]
     n = until[..., -1]
     slot = jnp.arange(k, dtype=jnp.int32)
@@ -612,6 +646,46 @@ def index_select(scores, live, topk: int):
                     dtype=jnp.int32)
     idx = jnp.where(slot < n[..., None], block * B + place, 0)
     return idx, n
+
+
+def _block_totals(mask):
+    """mask [..., nb, B] -> (the running count inside each block, each
+    block's count, the count of the blocks before it)."""
+    nb = mask.shape[-2]
+    earlier = jnp.arange(nb)[None, :] < jnp.arange(nb)[:, None]
+    run = _running_count(mask)
+    each = run[..., -1].astype(jnp.int32)                     # [..., nb]
+    return run, each, jnp.sum(jnp.where(earlier, each[..., None, :], 0),
+                              axis=-1)
+
+
+def _kept_blocks(scores, live, k: int):
+    """``index_select``'s set as a mask over the keys in blocks of
+    ``SELECT_BLOCK`` [..., nb, B]: every live key above the ``k``-th
+    largest live score and, of the keys equal to it, the first by
+    position, to ``k`` exactly."""
+    S, B = scores.shape[-1], SELECT_BLOCK
+    lead, nb = scores.shape[:-1], -(-S // B)
+    key = jnp.pad(_order_keys(scores, live),
+                  [(0, 0)] * len(lead) + [(0, nb * B - S)]
+                  ).reshape(lead + (nb, B))
+    kth = _kth_largest(key, k, axes=2)[..., None, None]
+    above, equal = key > kth, key == kth
+    # the edge: of the equal keys, the first (k - above) by position
+    room = k - jnp.sum(above, axis=(-2, -1), dtype=jnp.int32)
+    run, _, before = _block_totals(equal)
+    return (above | (equal & (run + before[..., None].astype(jnp.float32)
+                              <= room[..., None, None].astype(jnp.float32)))
+            ) & (key > 0)
+
+
+def index_kept(scores, live, topk: int):
+    """``index_select``'s set as a mask [..., S] over the keys: ties at
+    the edge go to the earlier positions, to ``topk`` exactly (where
+    ``index_keep`` keeps every key equal to the edge)."""
+    S = scores.shape[-1]
+    kept = _kept_blocks(scores, live, min(int(topk), S))
+    return kept.reshape(kept.shape[:-2] + (-1,))[..., :S]
 
 
 def index_keep(scores, live, topk: int):
@@ -671,6 +745,221 @@ def gdn_mixer(cfg, h1, lp, tail, state, n_tokens):
     return y, tail, state
 
 
+def lightning_mixer(cfg, h1, lp, rope, state, n_tokens):
+    """The lightning layer on its normed input [B, T, H], resumed from
+    ``state`` [B, heads, D, D] (float32). Positions at or beyond a row's
+    ``n_tokens`` leave it as it was. ``rope``: q or k [B, T, heads, D] ->
+    the same, rotated (the identity where the kind is not rotated).
+    Returns (y [B, T, H], new state)."""
+    from .transformer import _linear
+
+    B, T, _ = h1.shape
+    nh, hd, dt = cfg.lightning_num_heads, cfg.lightning_head_dim, cfg.dtype
+    with jax.named_scope("lightning_proj"):
+        q, k, v, gate = (_linear(h1, lp[name], None, dt)
+                         for name in ("wq", "wk", "wv", "wg"))
+        q, k, v = (a.reshape(B, T, nh, hd) for a in (q, k, v))
+        if cfg.qk_norm:
+            q = block_norm(cfg, q, lp["q_norm_w"])
+            k = block_norm(cfg, k, lp["k_norm_w"])
+        q, k = rope(q), rope(k)
+    with jax.named_scope("lightning_scan"):
+        slope = la.slopes(nh)
+        if T == 1:
+            o, new = la.lightning_step(q[:, 0], k[:, 0], v[:, 0], slope,
+                                       state)
+            o = o[:, None]
+            state = jnp.where((n_tokens > 0)[:, None, None, None], new,
+                              state)
+        else:
+            o, state = la.lightning_chunked(q, k, v, slope, state, n_tokens)
+        o = o * (cfg.attn_scale or hd ** -0.5)
+    with jax.named_scope("lightning_out"):
+        o = rms(o.reshape(B, T, nh * hd), lp["out_norm_w"], cfg.norm_eps,
+                cfg.norm_zero_centered)
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
+        y = _linear(o.astype(dt), lp["wo"], None, dt)
+    return y, state
+
+
+# ---------------------------------------------------------- block selection
+
+class BlockSizes(NamedTuple):
+    """A block-sparse layer's sizes: a compressed key is the mean of
+    ``kernel`` keys, one every ``stride``; a ``block`` of keys is
+    attended whole; ``per`` kernels begin in a block and each spans
+    ``ratio`` strides."""
+    kernel: int
+    stride: int
+    block: int
+    topk: int
+    init: int
+    window: int
+    dense_len: int
+
+    @property
+    def per(self) -> int:
+        return self.block // self.stride
+
+    @property
+    def ratio(self) -> int:
+        return self.kernel // self.stride
+
+    @property
+    def table_width(self) -> int:
+        """The most blocks a one-token row attends: a selecting query's
+        initial blocks, its ``topk`` and the blocks its window reaches,
+        or every block of a context short of ``dense_len``."""
+        return max(self.init + self.topk + self.window // self.block + 1,
+                   -(-self.dense_len // self.block))
+
+
+def block_sizes(cfg) -> BlockSizes:
+    return BlockSizes(cfg.block_kernel_size, cfg.block_kernel_stride,
+                      cfg.block_select_size, cfg.block_topk,
+                      cfg.block_init_blocks, cfg.block_window,
+                      cfg.block_dense_len)
+
+
+def block_compress(z: BlockSizes, k):
+    """Compressed keys of a run of keys ``k`` [..., W, KH, D] that begins
+    at a whole stride, ``W = stride · (n + ratio − 1)``: the ``n`` means
+    [..., n, KH, D] of ``kernel`` consecutive keys, one a stride — each
+    the mean of its ``ratio`` strides' means, in float32, returned in
+    ``k``'s type."""
+    lead, (W, KH, D) = k.shape[:-3], k.shape[-3:]
+    n = W // z.stride - (z.ratio - 1)
+    strides = jnp.mean(k.astype(jnp.float32).reshape(
+        lead + (W // z.stride, z.stride, KH, D)), axis=-3)
+    return (sum(strides[..., i:i + n, :, :] for i in range(z.ratio))
+            / z.ratio).astype(k.dtype)
+
+
+def block_visible(z: BlockSizes, t):
+    """The kernels wholly in the causal past of position ``t``: those j
+    with ``stride · j + kernel <= t + 1``."""
+    return jnp.maximum((t + 1 - z.kernel) // z.stride + 1, 0)
+
+
+def block_scores(cfg, q, kc, t):
+    """Each block's score for the queries ``q`` [..., C, H, D] at
+    positions ``t`` [..., C], against the compressed keys ``kc``
+    [..., J, KH, D] (J a whole number of blocks' kernels, kernel j at
+    row j): a head's softmax over the kernels it may see, summed over
+    the K/V head's queries, and for a block the largest over the kernels
+    that overlap it -> [..., C, KH, J / per] float32."""
+    z = block_sizes(cfg)
+    lead, (C, H, D) = q.shape[:-3], q.shape[-3:]
+    J, KH = kc.shape[-3], kc.shape[-2]
+    s = jnp.einsum("...ckgd,...jkd->...ckgj",
+                   q.reshape(lead + (C, KH, H // KH, D)), kc,
+                   preferred_element_type=jnp.float32) \
+        * (cfg.attn_scale or 1.0 / math.sqrt(D))
+    live = (jnp.arange(J) < block_visible(z, t)[..., None]
+            )[..., None, None, :]
+    s = jnp.where(live, s, -1e30)
+    p = jnp.where(live, jnp.exp(s - jnp.max(s, -1, keepdims=True)), 0.0)
+    p = jnp.sum(p / jnp.maximum(jnp.sum(p, -1, keepdims=True), 1e-30),
+                axis=-2)                                  # [..., C, KH, J]
+    blocks = p.shape[:-1] + (J // z.per, z.per)
+    score = jnp.max(p.reshape(blocks), axis=-1)
+    for i in range(1, z.ratio):     # the kernels that began a block before
+        before = jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(i, 0)])[..., :J]
+        score = jnp.maximum(score, before.reshape(blocks)[..., 0])
+    return score
+
+
+def _block_parts(z: BlockSizes, t, blocks: int):
+    """For queries at positions ``t`` [...] over ``blocks`` blocks:
+    ``(forced, candidates, first_w, cur)`` — the blocks a selecting
+    query attends whatever their score (the initial ones and those that
+    hold one of its last ``window`` positions), those it chooses among
+    (the rest of its past), its window's first block and its own."""
+    b = jnp.arange(blocks)
+    first_w = (jnp.maximum(t - z.window + 1, 0) // z.block)[..., None]
+    cur = (t // z.block)[..., None]
+    forced = ((b >= first_w) | (b < z.init)) & (b <= cur)
+    return forced, (b >= z.init) & (b < first_w), first_w[..., 0], \
+        cur[..., 0]
+
+
+def block_keep(cfg, scores, t):
+    """The blocks each query attends, as a mask: ``scores``
+    [..., C, KH, blocks] (``block_scores``), ``t`` [..., C] ->
+    [..., C, KH, blocks] bool. A query short of ``dense_len`` attends
+    every block of its past; another its forced blocks and the ``topk``
+    candidates of largest score, ties to the lower block."""
+    z = block_sizes(cfg)
+    forced, cand, _, cur = _block_parts(z, t, scores.shape[-1])
+    kept = index_kept(scores, jnp.broadcast_to(
+        cand[..., None, :], scores.shape), z.topk)
+    causal = jnp.arange(scores.shape[-1]) <= cur[..., None]
+    return jnp.where((t < z.dense_len)[..., None, None],
+                     causal[..., None, :], forced[..., None, :] | kept)
+
+
+def block_select(cfg, scores, t):
+    """The same selection as a table, for one-token rows: ``scores``
+    [N, KH, blocks], ``t`` [N] -> ``(table [N, KH, width] int32, n
+    [N])``: the ``n`` blocks each row attends in ascending order, its
+    own block last (``width``: ``BlockSizes.table_width``; entries past
+    ``n`` are 0 and not to be read). The count is one for a row's K/V
+    heads: it follows from the position alone."""
+    z = block_sizes(cfg)
+    _, cand, first_w, cur = _block_parts(z, t, scores.shape[-1])
+    idx, n = index_select(scores, jnp.broadcast_to(
+        cand[:, None, :], scores.shape), z.topk)        # [N, KH, k]
+    n = n[:, :1]                                         # [N, 1]
+    slot = jnp.arange(z.table_width, dtype=jnp.int32)
+    chosen = jnp.take_along_axis(
+        idx, jnp.broadcast_to(jnp.clip(slot - z.init, 0, idx.shape[-1] - 1),
+                              idx.shape[:-1] + slot.shape), axis=-1)
+    window = first_w[:, None] + slot - z.init - n        # [N, width]
+    picked = jnp.where(slot < z.init, slot,
+                       jnp.where(slot < z.init + n[..., None], chosen,
+                                 window[:, None, :]))
+    dense = t < z.dense_len
+    count = jnp.where(dense, cur + 1, z.init + n[:, 0] + cur - first_w + 1)
+    table = jnp.where(dense[:, None, None], slot, picked)
+    return jnp.where(slot < count[:, None, None], table, 0).astype(
+        jnp.int32), count.astype(jnp.int32)
+
+
+def block_keep_dense(cfg, q, k):
+    """A whole sequence's selection, plain XLA (no cache: training and
+    the reference path): q [B, T, H, D], k [B, T, KH, D] -> the keys each
+    query attends, [B, T, KH, T] bool (causal)."""
+    z = block_sizes(cfg)
+    T = q.shape[1]
+    blocks = -(-T // z.block)
+    at = jnp.arange(T)
+    with jax.named_scope("block_compress"):
+        width = z.stride * (blocks * z.per + z.ratio - 1)
+        kc = block_compress(z, jnp.pad(
+            k, ((0, 0), (0, width - T), (0, 0), (0, 0))))
+    with jax.named_scope("block_score"):
+        scores = block_scores(cfg, q, kc, at[None, :])
+    with jax.named_scope("block_select"):
+        keep = jnp.repeat(block_keep(cfg, scores, at[None, :]), z.block,
+                          axis=-1)[..., :T]
+    return keep & (at[None, :] <= at[:, None])[None, :, None, :]
+
+
+def block_attend_dense(cfg, q, k, v, keep):
+    """Attention of q [B, T, H, D] over k, v [B, T, KH, D] under ``keep``
+    [B, T, KH, T], plain XLA -> [B, T, H, D]."""
+    B, T, H, D = q.shape
+    KH = k.shape[2]
+    s = jnp.einsum("btkgd,bskd->bkgts", q.reshape(B, T, KH, H // KH, D), k,
+                   preferred_element_type=jnp.float32) \
+        * (cfg.attn_scale or 1.0 / math.sqrt(D))
+    p = jax.nn.softmax(jnp.where(keep.transpose(0, 2, 1, 3)[:, :, None], s,
+                                 -1e30), axis=-1)
+    return jnp.einsum("bkgts,bskd->btkgd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32
+                      ).astype(v.dtype).reshape(B, T, H, D)
+
+
 # ----------------------------------------------------------------- period
 
 def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
@@ -686,6 +975,8 @@ def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
     aux = jnp.zeros((), jnp.float32)
     kinds = cfg.layer_pattern if kinds is None else kinds
     seen = {kind: 0 for kind in KINDS}
+    scaled = (lambda y: y) if cfg.residual_scale == 1.0 else (
+        lambda y: y * jnp.asarray(cfg.residual_scale, y.dtype))
     for kind, lp in zip(kinds, slots):
         if transform is not None:
             lp = transform(lp)
@@ -698,15 +989,15 @@ def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
         with scope("mlp"):      # norms, FFN and the residual adds
             if cfg.sandwich_norm:
                 y = block_norm(cfg, y, lp["post_attn_norm_w"])
-            x = x + y
+            x = x + scaled(y)
             h2 = block_norm(cfg, x, lp["mlp_norm_w"])
-            if dense:
+            if dense or not cfg.moe_num_experts:
                 f, a = dense_ffn(cfg, h2, lp), 0.0
             else:
                 f, a = moe_ffn(cfg, h2, lp, valid=valid, max_rows=max_rows)
             if cfg.sandwich_norm:
                 f = block_norm(cfg, f, lp["post_mlp_norm_w"])
-            x = x + f
+            x = x + scaled(f)
         aux = aux + a
     return x, aux
 
@@ -721,7 +1012,8 @@ def lead_slots(cfg, params):
 # -------------------------------------------------------------------- FFN
 
 def dense_ffn(cfg, h2, lp):
-    """A lead layer's dense gated MLP on its normed input [B, T, H]."""
+    """The dense gated MLP on its normed input [B, T, H]: a lead layer's,
+    and every layer's of a model without experts."""
     from .transformer import _linear
 
     dt = cfg.dtype

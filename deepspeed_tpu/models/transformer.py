@@ -197,6 +197,32 @@ class TransformerConfig:
     #   head on the attention output, from ``w_g`` (hidden -> heads)
     latent_rescale: bool = False         # c_q and c times sqrt(hidden /
     #   rank) behind their norms
+    # A "lightning" layer (ops/lightning_attention.py): linear attention
+    # under one scalar decay a head, ``lightning_num_heads`` heads of
+    # ``lightning_head_dim`` on both sides of a float32 state a sequence;
+    # q/k RMSNorm a head (``qk_norm``), an RMSNorm over the joined heads'
+    # output and a sigmoid gate of the layer's input in front of ``wo``.
+    lightning_num_heads: int = 0
+    lightning_head_dim: int = 0
+    # A "block_sparse" layer (InfLLM-V2): a "full" layer whose query, from
+    # position ``block_dense_len`` on, attends whole blocks of
+    # ``block_select_size`` keys only: the first ``block_init_blocks``,
+    # those that reach into its last ``block_window`` positions, and the
+    # ``block_topk`` others of largest score — a K/V head's queries
+    # scored together against the means of ``block_kernel_size`` keys
+    # taken every ``block_kernel_stride`` (no weights: models/hybrid.py
+    # ``block_*``). In serving the block is the pool's.
+    block_kernel_size: int = 0
+    block_kernel_stride: int = 0
+    block_select_size: int = 0
+    block_topk: int = 0
+    block_init_blocks: int = 1
+    block_window: int = 0
+    block_dense_len: int = 0
+    # muP's multipliers (MiniCPM): each mixer's and each FFN's output
+    # before its residual add, and the logits. 1.0 is no multiply at all.
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         # a configuration read from JSON brings lists
@@ -237,15 +263,39 @@ class TransformerConfig:
                     "\"latent_sparse\" layers need index_n_heads, "
                     "index_topk > 0 and an index_head_dim that holds the "
                     "rotated part (qk_rope_head_dim)")
+            if "lightning" in kinds and (
+                    self.lightning_num_heads <= 0
+                    or self.lightning_head_dim != self.head_dim):
+                raise ValueError(
+                    "\"lightning\" layers need lightning_num_heads > 0 "
+                    "and the model's head size (one rotary table, one "
+                    "q/k norm width)")
+            if "block_sparse" in kinds:
+                stride, size = self.block_kernel_stride, self.block_select_size
+                if min(stride, size, self.block_topk, self.block_window,
+                       self.block_init_blocks) <= 0 \
+                        or self.block_kernel_size % stride \
+                        or size % stride or self.block_window % size \
+                        or self.block_dense_len < (
+                            self.block_window
+                            + (self.block_init_blocks + 1) * size):
+                    raise ValueError(
+                        "\"block_sparse\" layers need a kernel and a "
+                        "block that are whole strides, a window of whole "
+                        "blocks, topk and init_blocks > 0, and a "
+                        "block_dense_len past the window and the initial "
+                        "blocks (a selecting query's window never holds "
+                        "them)")
             windowed = bool(kinds & {"window", "latent_window"})
             if windowed != (isinstance(self.sliding_window, int)
                             and self.sliding_window > 0) \
-                    or self.moe_num_experts <= 0 \
+                    or self.moe_num_experts < 0 \
                     or self.norm != "rmsnorm" or self.position != "rope":
                 raise ValueError(
-                    "a hybrid block is RMSNorm, rotary and a sparse FFN "
-                    "(moe_num_experts > 0); sliding_window is its "
-                    "\"window\" layers' length, and set with them only")
+                    "a hybrid block is RMSNorm and rotary, its FFN the "
+                    "sparse one (moe_num_experts > 0) or the dense MLP "
+                    "(0); sliding_window is its \"window\" layers' "
+                    "length, and set with them only")
         elif self.lead_layers:
             raise ValueError("lead_layers belong to a layer_pattern")
 
@@ -300,6 +350,11 @@ class TransformerConfig:
     def is_hybrid(self) -> bool:
         return self.layer_pattern is not None
 
+    def has_kind(self, kind: str) -> bool:
+        """Whether a hybrid block has layers of ``kind``."""
+        return self.layer_pattern is not None \
+            and kind in self.layer_pattern + self.lead_layers
+
     @property
     def num_periods(self) -> int:
         return (self.num_layers - len(self.lead_layers)) \
@@ -316,14 +371,14 @@ class TransformerConfig:
         """Layers that keep per-token K/V (all of them, unless hybrid)."""
         if self.layer_pattern is None:
             return self.num_layers
-        return self.num_layers - self.layers_of("linear")
+        return self.num_layers - self.num_linear_layers
 
     @property
     def num_linear_layers(self) -> int:
         """Layers that keep a recurrent state instead."""
         if self.layer_pattern is None:
             return 0
-        return self.layers_of("linear")
+        return self.layers_of("linear") + self.layers_of("lightning")
 
     @property
     def num_sparse_layers(self) -> int:
@@ -353,7 +408,7 @@ class TransformerConfig:
         """The attention kinds whose K/V lives the whole context (0) or
         a window (1)."""
         return ("window", "latent_window") if windowed \
-            else ("full", "latent", "latent_sparse")
+            else ("full", "latent", "latent_sparse", "block_sparse")
 
     def kv_layouts(self, block_size: int) -> Tuple[Dict[str, Tuple[int,
                                                                     ...]],
@@ -367,7 +422,20 @@ class TransformerConfig:
         second row a token, in the same blocks of the same table."""
         if not self.is_latent:
             block = (self.kv_heads, block_size, self.head_dim)
-            return ({"k": block, "v": block},) * len(self.kv_groups())
+            layouts = [{"k": block, "v": block} for _ in self.kv_groups()]
+            if self.has_kind("block_sparse"):
+                # the compressed keys a block's kernels end in, beside
+                # k / v in the same blocks of the same table: a kernel a
+                # stride, [rows, KH, D] (the index dimensions of its
+                # write lead: inference/v2/kv_write.py says why)
+                if block_size != self.block_select_size:
+                    raise ValueError(
+                        f"kv_block_size {block_size}: a \"block_sparse\" "
+                        "layer selects pool blocks, so the pool's block "
+                        f"is block_select_size ({self.block_select_size})")
+                layouts[0]["kc"] = (block_size // self.block_kernel_stride,
+                                    self.kv_heads, self.head_dim)
+            return tuple(layouts)
         kinds = set(self.layer_pattern + self.lead_layers)
         layouts = []
         for window, _ in self.kv_groups():
@@ -1327,6 +1395,24 @@ class CausalLM:
                 return hybrid.gdn_mixer(cfg, h1, lp, state0["conv"],
                                         state0["ssm"], n_tokens)[0]
 
+        def lightning_mixer(h1, lp, _):
+            turn = rope if hybrid.rotates(cfg, "lightning") \
+                else (lambda t: t)
+            with scope("lightning_attn"):
+                return hybrid.lightning_mixer(
+                    cfg, h1, lp, turn, state0["lightning"], n_tokens)[0]
+
+        def block_sparse_mixer(h1, lp, _):
+            turn = rope if hybrid.rotates(cfg, "block_sparse") \
+                else (lambda t: t)
+            with scope("qkv"):
+                q, k, v, gate = hybrid.full_qkv(cfg, h1, lp, turn)
+            keep = hybrid.block_keep_dense(cfg, q, k)
+            with scope("attend"):
+                attn = hybrid.block_attend_dense(cfg, q, k, v, keep)
+            with scope("attn_out"):
+                return hybrid.full_out(cfg, attn, gate, lp)
+
         def latent_mixer(kind):
             z = cfg.latent_sizes(kind)
             turn = rope
@@ -1368,6 +1454,8 @@ class CausalLM:
 
         mixers = {"full": attention_mixer("full"),
                   "window": attention_mixer("window"), "linear": linear_mixer,
+                  "lightning": lightning_mixer,
+                  "block_sparse": block_sparse_mixer,
                   **{kind: latent_mixer(kind)
                      for kind in hybrid.LATENT_KINDS}}
 
@@ -1389,6 +1477,8 @@ class CausalLM:
             x = hybrid.block_norm(cfg, x, params["final_norm"]["w"])
         with scope("logits"):
             logits = self._unembed(params, x)
+            if cfg.logit_scale != 1.0:
+                logits = logits * jnp.asarray(cfg.logit_scale, logits.dtype)
         if return_aux:
             return logits, jnp.sum(aux)
         return logits

@@ -129,7 +129,7 @@ def _tiles(rows: int, head_dim: int, kv_heads: int, block_size: int,
 def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
                   q_ref, k_hbm, v_hbm, *refs, chunk: int, groups: int,
                   kv_heads: int, sm_scale: float, alibi: bool, window: int,
-                  quant: bool):
+                  quant: bool, by_head: bool = False, masked: bool = False):
     """One (n, head group) grid step: sequence n's [KHt, G·C, D] query
     rows against its live table blocks, ``T`` blocks a loop turn. The
     pools stay in HBM; a turn's blocks are copied through the table into
@@ -140,15 +140,29 @@ def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
     carry sequence n's per-(table slot, kv-head) dequantization scales,
     flat [MB·KH] (docs/SERVING.md "KV quantization"): the payload goes
     into the dots as it is (converted to the query's dtype, which holds it
-    exactly) and the scales multiply the scores and the probabilities."""
+    exactly) and the scales multiply the scores and the probabilities.
+
+    The two variants of a block-sparse layer (a step holds one K/V head
+    in both). ``by_head``: the table is a K/V head's own, row ``n ·
+    kv_heads + head`` of [N·KH, W] — the blocks that head's query
+    selected, in order, the query's own block last, so that the walk,
+    the context's length and the causal mask read it as a context of its
+    own (``paged_attention_select``). ``masked``: one more operand, the
+    step's [C, MB'] int8 mask of the table blocks each query position
+    attends; a turn's T columns are spread over its keys by a product
+    with a 0/1 matrix on the matrix unit and join the causal mask
+    (``paged_attention_masked``)."""
     if quant:
         ks_ref, vs_ref, *refs = refs
+    if masked:
+        mask_ref, *refs = refs
     o_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref = refs
     _, kh_t, T, bs, D = k_buf.shape
     rows, keys = q_ref.shape[2], T * bs
     last_slot = tables_ref.shape[1] - 1
     n = pl.program_id(0)
     kh0 = pl.program_id(1) * kh_t
+    trow = n * kv_heads + pl.program_id(1) if by_head else n
     layer = layer_ref[0]
     startp = startp_ref[n]
     ctx_len = startp + ntok_ref[n]
@@ -161,7 +175,7 @@ def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
     def copies(b, slot):
         """Table block b's K and V copies into their place in ``slot``."""
         return [pltpu.make_async_copy(
-            hbm.at[layer, tables_ref[n, b], pl.ds(kh0, kh_t)],
+            hbm.at[layer, tables_ref[trow, b], pl.ds(kh0, kh_t)],
             buf.at[slot, :, b % T], sem.at[i, slot])
             for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
 
@@ -243,6 +257,22 @@ def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
         keep = (kvpos <= qpos) & (kvpos < ctx_len)
         if window:
             keep = keep & (kvpos > qpos - window)
+        if masked:
+            # the turn's T mask columns lie inside one 128-lane tile (T
+            # is a power of two): the tile times E[l, key] = (l is the
+            # key's block) gives each key its block's bit
+            lane0 = pl.multiple_of((turn * T) // LANES * LANES, LANES)
+            tile = mask_ref[0, 0, :, pl.ds(lane0, LANES)]     # [C, 128]
+            spread = (lax.broadcasted_iota(jnp.int32, (LANES, keys), 0)
+                      == turn * T - lane0
+                      + lax.broadcasted_iota(jnp.int32, (LANES, keys), 1)
+                      // bs)
+            bit = jnp.dot(tile.astype(jnp.float32).astype(jnp.bfloat16),
+                          spread.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)  # [C, T·bs]
+            if groups > 1:
+                bit = jnp.concatenate([bit] * groups, axis=0)
+            keep = keep & (bit[None] > 0.5)
         s = jnp.where(keep, s, NEG_INF)                       # [KHt, G·C, T·bs]
         m_prev, l_prev = m_ref[...], l_ref[...]               # [KHt, G·C, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -281,7 +311,11 @@ def _stacked(k_pool, v_pool, k_scale, v_scale, layer):
 
 def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
                   alibi_slopes=None, window: int = 0, sm_scale=None,
-                  k_scale=None, v_scale=None, layer=None, interpret: bool):
+                  k_scale=None, v_scale=None, layer=None, interpret: bool,
+                  by_head: bool = False, block_mask=None):
+    """``by_head``: ``block_tables`` is [N·KH, W], a K/V head's own row
+    (``_paged_kernel``). ``block_mask`` [N, KH, C, MB'] int8, MB' whole
+    lane tiles: the table blocks each query position attends."""
     k_pool, v_pool, k_scale, v_scale, layer = _stacked(
         k_pool, v_pool, k_scale, v_scale, layer)
     N, C, H, D = q.shape
@@ -289,8 +323,13 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
     G = H // KH
     MB = block_tables.shape[1]
     quant = k_scale is not None
+    masked = block_mask is not None
     sm_scale = 1.0 / math.sqrt(D) if sm_scale is None else float(sm_scale)
     kh_t, T = _tiles(G * C, D, KH, bs, MB, q.dtype, k_pool.dtype)
+    if by_head or masked:
+        # one K/V head a step (its own table, its own mask), and a turn
+        # of 2^i blocks: its mask columns never straddle a lane tile
+        kh_t, T = 1, 1 << (T.bit_length() - 1)
 
     # [N, C, H, D] -> [N, KH, G*C, D]: row r = g*C + ci
     qh = q.transpose(0, 2, 1, 3).reshape(N, KH, G * C, D)
@@ -305,7 +344,7 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
 
     kernel = functools.partial(_paged_kernel, chunk=C, groups=G, kv_heads=KH,
                                sm_scale=sm_scale, alibi=alibi, window=window,
-                               quant=quant)
+                               quant=quant, by_head=by_head, masked=masked)
     q_spec = pl.BlockSpec((1, kh_t, G * C, D), lambda n, h, *_: (n, h, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [q_spec, pool_spec, pool_spec]
@@ -323,6 +362,10 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
         operands += [
             jnp.asarray(s, jnp.float32)[layer, tables].reshape(N, 1, MB * KH)
             for s in (k_scale, v_scale)]
+    if masked:
+        in_specs.append(pl.BlockSpec((1, 1) + block_mask.shape[2:],
+                                     lambda n, h, *_: (n, h, 0, 0)))
+        operands.append(block_mask)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(N, KH // kh_t),
@@ -339,7 +382,8 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
     )
     o = pl.pallas_call(
         kernel,
-        name="paged_attention",
+        name="paged_attention_select" if by_head
+        else "paged_attention_mask" if masked else "paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, KH, G * C, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -356,7 +400,8 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
 
 def paged_attention_xla(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
                         alibi_slopes=None, window: int = 0, sm_scale=None,
-                        k_scale=None, v_scale=None, layer=None):
+                        k_scale=None, v_scale=None, layer=None,
+                        block_mask=None):
     """Dense-gather formulation (the pre-Pallas path): gather the table into
     [N, MB*bs, KH, D] and mask. Numerically the kernel's reference, with
     the kernel's arguments: stacked pools read at ``layer`` (only the
@@ -364,7 +409,8 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
     ``k_scale``/``v_scale`` [L, NB, KH]: per-(block, kv-head)
     dequantization scales for int8 pools (docs/SERVING.md "KV
     quantization") — gathered through the same block table and applied to
-    the gathered context."""
+    the gathered context. ``block_mask`` [N, C, KH, MB]: the table blocks
+    each query position attends (``paged_attention_masked``)."""
     k_pool, v_pool, k_scale, v_scale, layer = _stacked(
         k_pool, v_pool, k_scale, v_scale, layer)
     N, C, H, D = q.shape
@@ -400,6 +446,9 @@ def paged_attention_xla(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
     if window:
         keep = keep & (qpos[:, None, None, :, None]
                        - ctx_positions[None, None, None, None, :] < window)
+    if block_mask is not None:
+        keep = keep & (jnp.repeat(block_mask, bs, axis=-1) > 0
+                       ).transpose(0, 2, 1, 3)[:, :, None]
     s = jnp.where(keep, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     o = jnp.einsum("nkgcs,nksd->nckgd", p, v_ctx)
@@ -428,6 +477,17 @@ def _chunk_tile(chunk: int, group: int) -> int:
     if chunk <= most:
         return chunk
     return max(d for d in range(1, most + 1) if chunk % d == 0)
+
+
+def _pieces(chunk: int, tile: int, start_pos, n_tokens):
+    """A chunk cut along C into pieces of ``tile`` positions: ``(c0,
+    start_pos, n_tokens)`` of each. The pool already holds the whole
+    chunk's K/V and the mask goes by position, so a piece is the same
+    call at a later start. A piece past a row's valid tokens is given a
+    context of 0: every block of its walk is dead."""
+    for c0 in range(0, chunk, tile):
+        n_sub = jnp.clip(n_tokens - c0, 0, tile)
+        yield c0, jnp.where(n_sub > 0, start_pos + c0, 0), n_sub
 
 
 def _pallas_ok(q, k_pool) -> bool:
@@ -474,19 +534,85 @@ def paged_attention(q, k_pool, v_pool, block_tables, start_pos, n_tokens,
             return kernel(q, start_pos, n_tokens)
         # A query group of G·C rows is one VMEM block (with its float32
         # accumulator and softmax statistics): a long chunk of a wide
-        # group is cut along C and each piece walks the table on its own.
-        # The pool already holds the whole chunk's K/V and the mask goes
-        # by position, so a piece is the same call at a later start. A
-        # piece past a row's valid tokens is given a context of 0: every
-        # block of its walk is dead.
-        outs = []
-        for c0 in range(0, C, tile):
-            n_sub = jnp.clip(n_tokens - c0, 0, tile)
-            outs.append(kernel(
-                q[:, c0:c0 + tile],
-                jnp.where(n_sub > 0, start_pos + c0, 0), n_sub))
-        return jnp.concatenate(outs, axis=1)
+        # group is cut along C and each piece walks the table on its own
+        # (``_pieces``).
+        return jnp.concatenate(
+            [kernel(q[:, c0:c0 + tile], start, n_sub) for c0, start, n_sub
+             in _pieces(C, tile, start_pos, n_tokens)], axis=1)
     return paged_attention_xla(q, k_pool, v_pool, block_tables, start_pos,
                                n_tokens, alibi_slopes=alibi_slopes,
                                window=window, sm_scale=sm_scale,
                                k_scale=k_scale, v_scale=v_scale, layer=layer)
+
+
+# ------------------------------------------------ block-sparse (InfLLM-V2)
+
+def _select_context(n_blocks, positions, block_size: int):
+    """A one-token row's selected table read as a context of its own:
+    ``(start_pos, n_tokens)`` — the row's own block is the table's last,
+    so its keys run to ``(n_blocks − 1) · bs + position % bs``; a padded
+    row (no blocks) has no token."""
+    real = n_blocks > 0
+    return (jnp.where(real, (n_blocks - 1) * block_size
+                      + positions % block_size, 0).astype(jnp.int32),
+            real.astype(jnp.int32))
+
+
+def paged_attention_select(q, k_pool, v_pool, tables, n_blocks, positions,
+                           sm_scale=None, layer=None):
+    """One-token rows over the blocks each K/V head selected — **the
+    kernel reads those blocks and no other**. q [N, 1, H, D]; pools
+    [L, NB, KH, bs, D] read at ``layer``; ``tables`` [N, KH, W]: pool
+    block ids in the order of their positions, the row's own block last
+    among its ``n_blocks`` [N] (0: a padded row); ``positions`` [N]: the
+    query's position, which says how far into its own block it sees.
+    Every other selected block lies wholly in its past, so the table is
+    walked as a context of ``(n_blocks − 1) · bs + position % bs + 1``
+    keys (kernel ``paged_attention_select``; the XLA gather off the
+    chip)."""
+    N, _, H, D = q.shape
+    KH, bs = k_pool.shape[-3], k_pool.shape[-2]
+    start, ntok = _select_context(n_blocks, positions, bs)
+    if _pallas_ok(q, k_pool):
+        return _paged_pallas(
+            q, k_pool, v_pool, tables.reshape(N * KH, -1), start, ntok,
+            sm_scale=sm_scale, layer=layer, interpret=_use_interpret(),
+            by_head=True)
+    k_pool, v_pool, _, _, layer = _stacked(k_pool, v_pool, None, None, layer)
+    sm_scale = 1.0 / math.sqrt(D) if sm_scale is None else float(sm_scale)
+    W = tables.shape[-1]
+    heads = jnp.arange(KH)[None, :, None]
+    tbl = jnp.maximum(tables, 0)
+    k_ctx = k_pool[layer, tbl, heads].reshape(N, KH, W * bs, D)
+    v_ctx = v_pool[layer, tbl, heads].reshape(N, KH, W * bs, D)
+    s = jnp.einsum("nkgd,nksd->nkgs", q.reshape(N, KH, H // KH, D), k_ctx
+                   ).astype(jnp.float32) * sm_scale
+    keep = jnp.arange(W * bs)[None, :] < (start + ntok)[:, None]
+    s = jnp.where(keep[:, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("nkgs,nksd->nkgd", p, v_ctx).reshape(N, 1, H, D)
+
+
+def paged_attention_masked(q, k_pool, v_pool, block_tables, start_pos,
+                           n_tokens, block_mask, sm_scale=None, layer=None):
+    """Chunk rows under a block mask: ``paged_attention`` in which query
+    position c of row n attends table block b of K/V head h only where
+    ``block_mask[n, c, h, b]`` is set (int8 [N, C, KH, MB]), causal
+    inside it. Every live block is walked, the mask decides what enters
+    the softmax (kernel ``paged_attention_mask``)."""
+    if not _pallas_ok(q, k_pool):
+        return paged_attention_xla(q, k_pool, v_pool, block_tables,
+                                   start_pos, n_tokens, sm_scale=sm_scale,
+                                   layer=layer, block_mask=block_mask)
+    N, C, H, _ = q.shape
+    MB = block_mask.shape[-1]
+    # [N, KH, C, MB'] with the blocks on whole lane tiles
+    mask = jnp.pad(block_mask.transpose(0, 2, 1, 3).astype(jnp.int8),
+                   ((0, 0), (0, 0), (0, 0), (0, -MB % LANES)))
+    tile = _chunk_tile(C, H // k_pool.shape[-3])
+    outs = [_paged_pallas(
+        q[:, c0:c0 + tile], k_pool, v_pool, block_tables, start, n_sub,
+        sm_scale=sm_scale, layer=layer, interpret=_use_interpret(),
+        block_mask=mask[:, :, c0:c0 + tile])
+        for c0, start, n_sub in _pieces(C, tile, start_pos, n_tokens)]
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)

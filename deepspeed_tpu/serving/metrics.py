@@ -427,6 +427,13 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               "sparse_keys_live", "sparse_keys_selected",
               "window_q_absorbed", "window_q_expanded",
               "window_rows_expanded",
+              # block-sparse layers: query positions by mode (every block
+              # of their past / a selection), the blocks they could see
+              # and the ones attended; lightning layers: positions through
+              # the recurrence (engine._count_blocks)
+              "sparse_rows_dense", "sparse_rows_selected",
+              "sparse_blocks_live", "sparse_blocks_selected",
+              "lightning_rows",
               # fault tolerance (docs/SERVING.md "Fault tolerance"):
               # failover = a dead replica's request re-enqueued (stream
               # resumed elsewhere); restarts = supervisor replaced a DEAD

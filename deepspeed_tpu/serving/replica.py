@@ -595,7 +595,10 @@ class Replica:
                      "latent_q_absorbed", "latent_q_expanded",
                      "latent_rows_expanded", "sparse_keys_live",
                      "sparse_keys_selected", "window_q_absorbed",
-                     "window_q_expanded", "window_rows_expanded")
+                     "window_q_expanded", "window_rows_expanded",
+                     "sparse_rows_dense", "sparse_rows_selected",
+                     "sparse_blocks_live", "sparse_blocks_selected",
+                     "lightning_rows")
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),
                          ("resumed", "sequences_resumed"))
     _STEP_COUNTERS = (("steps", "scheduler_steps"),

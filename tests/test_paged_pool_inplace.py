@@ -18,6 +18,8 @@ for the write than the Mosaic call wants for its operand — is compiled for
 a described v5e in ``tests/test_tpu_compile.py``.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -153,6 +155,26 @@ def _pool_rows(cache, name, table, ctx):
     return blocks.transpose(0, 1, 3, 2, 4).reshape(L, nb * bs, KH, D)[:, :ctx]
 
 
+@functools.lru_cache(maxsize=None)
+def _dense_answers(lengths, window=0, scan_mode="auto", position="rope"):
+    """The tiny model, its weights, seeded prompts of ``lengths`` and the
+    dense forward's answers to them — logits at every position, K/V of
+    every layer — built once a shape: the bf16 and the int8 case of a
+    test compare against the same ones."""
+    model = CausalLM(_tiny(window, position=position))
+    model._scan_mode = scan_mode
+    params = model.init(jax.random.PRNGKey(3))
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 97, size=n) for n in lengths]
+    dense_logits, dense_kv = [], []
+    for p in prompts:
+        toks = jnp.asarray(p[None])
+        dense_logits.append(np.asarray(model.apply(params, toks))[0])
+        _, c = model.prefill(params, toks, model.init_cache(1, len(p)))
+        dense_kv.append(c)
+    return model, params, prompts, dense_logits, dense_kv
+
+
 SCAN_MODES = {
     # name -> (sliding_window schedule, forced _scan_mode)
     "uniform": (0, "auto"),
@@ -172,25 +194,15 @@ def test_forward_logits_and_pool_match_dense(mode, quant):
     pool — every layer, through the block tables — is the dense prefill's
     K/V. Slots nobody wrote keep what they held."""
     window, scan_mode = mode
-    cfg = _tiny(window)
-    model = CausalLM(cfg)
-    model._scan_mode = scan_mode
-    params = model.init(jax.random.PRNGKey(3))
+    # dense reference: logits at every position, K/V of every layer
+    model, params, prompts, dense_logits, dense_kv = _dense_answers(
+        (21, 13), window, scan_mode)
+    cfg = model.cfg
     bs, NB, MB = 8, 12, 5
     paged = PagedCausalLM(model, bs, MB)
-    rng = np.random.default_rng(5)
-    prompts = [rng.integers(0, 97, size=n) for n in (21, 13)]
     tables = jnp.asarray([[4, 9, 1, -1, -1], [7, 2, -1, -1, -1]], jnp.int32)
     FILL = 7.0
     cache = _empty_cache(cfg, NB, bs, quant, fill=FILL)
-
-    # dense reference: logits at every position, K/V of every layer
-    dense_logits, dense_kv = [], []
-    for p in prompts:
-        toks = jnp.asarray(p[None])
-        dense_logits.append(np.asarray(model.apply(params, toks))[0])
-        _, c = model.prefill(params, toks, model.init_cache(1, len(p)))
-        dense_kv.append(c)
 
     fed = [0, 0]
     # per step, the tokens each sequence feeds: a chunk, the chunk's
@@ -235,23 +247,15 @@ def test_merged_forward_logits_and_pool_match_dense(position, quant):
     -- last-token logits of every row equal the dense forward's, and what
     lies in the pool is the dense prefill's K/V: both parts wrote the one
     carried pool, neither the other's blocks nor a padded row's."""
-    cfg = _tiny(position=position)
-    model = CausalLM(cfg)
-    params = model.init(jax.random.PRNGKey(3))
+    model, params, prompts, dense_logits, dense_kv = _dense_answers(
+        (21, 13, 7), position=position)
+    cfg = model.cfg
     bs, NB, MB = 8, 12, 5
     paged = PagedCausalLM(model, bs, MB)
-    rng = np.random.default_rng(5)
-    prompts = [rng.integers(0, 97, size=n) for n in (21, 13, 7)]
     tables = jnp.asarray([[4, 9, 1, -1, -1], [7, 2, -1, -1, -1],
                           [3, -1, -1, -1, -1], [-1] * 5], jnp.int32)
     FILL = 7.0
     cache = _empty_cache(cfg, NB, bs, quant, fill=FILL)
-    dense_logits, dense_kv = [], []
-    for p in prompts:
-        toks = jnp.asarray(p[None])
-        dense_logits.append(np.asarray(model.apply(params, toks))[0])
-        _, c = model.prefill(params, toks, model.init_cache(1, len(p)))
-        dense_kv.append(c)
 
     # the two decoding sequences' prompts but their last two tokens, padded
     fed = [0, 11, 5]

@@ -363,10 +363,13 @@ def test_quantized_pools_quantized_weights_and_a_tensor_axis_merge_too(
         topo.reset_topology()
         mesh = topo.MeshTopology.build(data=4, tensor=2)
     model, params = dense
-    engines = [InferenceEngineV2(
+    # the two engines are one model at one sizing: one jitted forward, so
+    # that the buckets both reach (the single rows' chunks) compile once
+    shared = {}
+    engines = [share_forward(InferenceEngineV2(
         model, params=params, mesh=mesh,
-        config=RaggedInferenceEngineConfig(**SMALL, **served))
-        for _ in range(2)]
+        config=RaggedInferenceEngineConfig(**SMALL, **served)),
+        shared, "served") for _ in range(2)]
     merged, parted = engines[0], apart(engines[1])
     assert merged.qkv_fused == (not mesh and "weight_quant_enabled"
                                 not in served)
