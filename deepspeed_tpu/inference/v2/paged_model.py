@@ -49,6 +49,7 @@ from typing import Dict, List, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ...models.transformer import (CausalLM, _linear, _norm, alibi_slopes,
                                    apply_rope, rope_table)
@@ -120,6 +121,36 @@ def _qkv_cuts(cfg):
     """Where q ends and where k ends among ``wqkv``'s columns."""
     return [cfg.num_heads * cfg.head_dim,
             (cfg.num_heads + cfg.kv_heads) * cfg.head_dim]
+
+
+def _rows_major(y):
+    """A projection's output [N, C, out] held to the layout the matmul
+    writes, a position's row behind a position's row. Left free, a
+    consumer that batches by head (a lightning layer's recurrence, a
+    block-sparse layer's scores) has the compiler lay the output out by
+    head through the matmul, and then it is the *weight* that is
+    transposed to fit — taken out of its stack into a buffer and copied
+    across, 32 MiB twice in front of every such matmul of every forward
+    — where a step's rows are a few KiB (``_held``)."""
+    return with_layout_constraint(
+        y, Layout(major_to_minor=tuple(range(y.ndim))))
+
+
+def _held(cfg, kind: str, rows: int):
+    """``hybrid.full_qkv``'s and ``lightning_mixer``'s ``hold`` in a
+    forward of ``rows`` bucket positions: which of a ``kind`` layer's
+    projections (``"q"``, ``"k"``, ``"v"``, the gate's ``"g"``) are held
+    to rows (``_rows_major``). Whichever side is laid out anew is
+    copied, and what that costs was measured on the chip at 4,096 wide
+    (PERF.md section 6, PR 46): a lightning layer's q, k and v at every
+    width (0.9 ms off a 2,048-row chunk's 101, 0.4 off a two-row step's
+    9.8); its gate and a block-sparse layer's four while the rows are at
+    most a quarter of the weight's (another 0.3 ms off the step, 0.1 off
+    a 512-row chunk; 1 ms *onto* the 2,048-row chunk each: their rows
+    are relaid in float32, behind a norm or a sigmoid, more than once)."""
+    names = "qkvg" if 4 * rows <= cfg.hidden_size \
+        else "qkv" if kind == "lightning" else ""
+    return lambda name, y: _rows_major(y) if name in names else y
 
 
 def _fed_tokens(tokens, next_ids, id_slots):
@@ -762,6 +793,10 @@ class PagedCausalLM:
                 sfx = str(g) if g else ""
                 turn = rope if hybrid.rotates(cfg, kind) else (lambda t: t)
                 window = cfg.sliding_window if kind == "window" else 0
+                # the other kinds' queries go to a kernel, whose operands
+                # are laid out as they are given
+                hold = _held(cfg, kind, N * C) \
+                    if kind == "block_sparse" else None
 
                 def sparse_attend(q, layer):
                     """A ``"block_sparse"`` layer behind its ``kv_write``:
@@ -788,7 +823,8 @@ class PagedCausalLM:
                 def mixer(h1, lp, i):
                     layer = first_layer[kind] + i
                     with scope("qkv"):
-                        q, k, v, gate = hybrid.full_qkv(cfg, h1, lp, turn)
+                        q, k, v, gate = hybrid.full_qkv(cfg, h1, lp, turn,
+                                                        hold)
                     with scope("kv_write"):
                         for name, rows in (("k" + sfx, k), ("v" + sfx, v)):
                             rows = rows.reshape(-1, kvh, hd)
@@ -917,8 +953,9 @@ class PagedCausalLM:
                 with scope("lightning_attn"):
                     state = pools["lightning"][layer, state_slots]
                     state = jnp.where(fresh[:, None, None, None], 0, state)
-                    y, state = hybrid.lightning_mixer(cfg, h1, lp, turn,
-                                                      state, n_tokens)
+                    y, state = hybrid.lightning_mixer(
+                        cfg, h1, lp, turn, state, n_tokens,
+                        hold=_held(cfg, "lightning", N * C))
                     pools["lightning"] = pools["lightning"].at[
                         layer, state_slots].set(state)
                     return y

@@ -1,6 +1,9 @@
 """The parts of a hybrid block: layers of more than one kind in one model
 (``TransformerConfig.layer_pattern``: one period of mixer kinds, scanned
-one period an iteration), each followed by the same sparse FFN.
+one period an iteration — in serving too, however few the periods: two
+periods laid out inline ran slower than the loop in every program
+measured, PERF.md section 6, PR 46), each followed by the same sparse
+FFN.
 
 - ``"full"``: gated softmax attention — a stated head size, per-head
   q/k RMSNorm, an output gate read off a ``wq`` twice as wide
@@ -56,7 +59,10 @@ one period an iteration), each followed by the same sparse FFN.
   and the FFN too; ``residual_scale`` multiplies both before their adds.
 
 ``CausalLM`` (training, the reference path) and ``PagedCausalLM``
-(serving) both call these; only where the mixer's cache lives differs.
+(serving) both call these; only where the mixer's cache lives differs —
+and that serving holds some projections' outputs to the layout their
+matmul writes (``full_qkv``'s and ``lightning_mixer``'s ``hold``), so
+that the weight is multiplied where it lies in its stack.
 Scopes follow ``docs/OBSERVABILITY.md``: ``linear_attn`` ⊃ ``gdn_proj``,
 ``gdn_conv``, ``gdn_scan``, ``gdn_out``; ``full_attn`` / ``window_attn`` /
 ``latent_attn`` / ``window_latent_attn`` round an attention layer's
@@ -342,24 +348,29 @@ def slot_specs(cfg, kind: str, dense: bool = False):
 
 # ----------------------------------------------------------------- mixers
 
-def full_qkv(cfg, h1, lp, rope):
+def full_qkv(cfg, h1, lp, rope, hold=None):
     """The gated attention layer's projections on its normed input
     [B, T, H]: (q, k, v, gate) with q/k normed per head and rotated
     (``rope``: q or k [B, T, heads, D] -> the same, rotated; the
-    identity for a kind that is not rotated, ``rotates``)."""
+    identity for a kind that is not rotated, ``rotates``). ``hold``:
+    ``(name, y) -> y``, what the output [B, T, out] of the projection
+    ``"q"``, ``"k"``, ``"v"`` or ``"g"`` goes through before it is cut
+    into heads (serving's hold on its layout; None: nothing)."""
     from .transformer import _linear
 
     B, T, _ = h1.shape
     nh, kvh, hd, dt = cfg.num_heads, cfg.kv_heads, cfg.head_dim, cfg.dtype
-    q = _linear(h1, lp["wq"], None, dt)
+    hold = hold or (lambda name, y: y)
+    q = hold("q", _linear(h1, lp["wq"], None, dt))
     gate = None
     if cfg.attn_output_gate and cfg.attn_gate_proj:
-        gate = _linear(h1, lp["wg"], None, dt).reshape(B, T, nh, hd)
+        gate = hold("g", _linear(h1, lp["wg"], None, dt)
+                    ).reshape(B, T, nh, hd)
     elif cfg.attn_output_gate:
         q, gate = jnp.split(q.reshape(B, T, nh, 2 * hd), 2, axis=-1)
     q = q.reshape(B, T, nh, hd)
-    k = _linear(h1, lp["wk"], None, dt).reshape(B, T, kvh, hd)
-    v = _linear(h1, lp["wv"], None, dt).reshape(B, T, kvh, hd)
+    k = hold("k", _linear(h1, lp["wk"], None, dt)).reshape(B, T, kvh, hd)
+    v = hold("v", _linear(h1, lp["wv"], None, dt)).reshape(B, T, kvh, hd)
     if cfg.qk_norm:
         q = block_norm(cfg, q, lp["q_norm_w"])
         k = block_norm(cfg, k, lp["k_norm_w"])
@@ -745,19 +756,20 @@ def gdn_mixer(cfg, h1, lp, tail, state, n_tokens):
     return y, tail, state
 
 
-def lightning_mixer(cfg, h1, lp, rope, state, n_tokens):
+def lightning_mixer(cfg, h1, lp, rope, state, n_tokens, hold=None):
     """The lightning layer on its normed input [B, T, H], resumed from
     ``state`` [B, heads, D, D] (float32). Positions at or beyond a row's
     ``n_tokens`` leave it as it was. ``rope``: q or k [B, T, heads, D] ->
     the same, rotated (the identity where the kind is not rotated).
-    Returns (y [B, T, H], new state)."""
+    ``hold``: as ``full_qkv``'s. Returns (y [B, T, H], new state)."""
     from .transformer import _linear
 
     B, T, _ = h1.shape
     nh, hd, dt = cfg.lightning_num_heads, cfg.lightning_head_dim, cfg.dtype
+    hold = hold or (lambda name, y: y)
     with jax.named_scope("lightning_proj"):
-        q, k, v, gate = (_linear(h1, lp[name], None, dt)
-                         for name in ("wq", "wk", "wv", "wg"))
+        q, k, v, gate = (hold(name, _linear(h1, lp["w" + name], None, dt))
+                         for name in "qkvg")
         q, k, v = (a.reshape(B, T, nh, hd) for a in (q, k, v))
         if cfg.qk_norm:
             q = block_norm(cfg, q, lp["q_norm_w"])

@@ -7,7 +7,9 @@ one-token rows' kernel alone at a decode step of every row: what the
 chip's compiler refuses
 (the select kernel's table a K/V head, the mask kernel's spread of a
 block's bit over its keys), and what does not fit beside the weights,
-shows here and not on the chip. Nothing runs. And the other way round:
+shows here and not on the chip; and that a step multiplies by its
+weights where they lie (``paged_model._held``). Nothing runs. And the
+other way round:
 ``mistral-7b``'s and ``qwen3-next-80b-a3b``'s ``[S, 1]`` and ``[1, C]``
 programs hold the kernel names and operand counts they held before the
 block-sparse variants were added to ``ops/paged_attention.py`` (read off
@@ -45,7 +47,7 @@ def v5e():
         pytest.skip(f"cannot describe a v5e topology here: {e}")
 
 
-@pytest.fixture(autouse=True)
+@pytest.fixture(scope="module", autouse=True)
 def _no_persistent_cache():
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -55,6 +57,17 @@ def _no_persistent_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", old)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def cell_chunk(v5e, _no_persistent_cache):
+    """The cell's widest chunk forward, ``[1, 2048]``, lowered and
+    compiled once for the tests that read it: ``(lowered, compiled,
+    params, cache, cfg)``."""
+    with pytest.MonkeyPatch.context() as patch:
+        lowered, params, cache, cfg = _lowered("minicpm-sala", v5e[0],
+                                               (1, 2048), patch)
+        return lowered, lowered.compile(), params, cache, cfg
 
 
 def _nbytes(s):
@@ -144,10 +157,8 @@ def test_the_select_kernel_at_the_files_sizes(v5e, monkeypatch):
 
 @pytest.mark.parametrize("bucket", [(1, 2048)],
                          ids=lambda b: f"{b[0]}x{b[1]}")
-def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
-    lowered, params, cache, cfg = _lowered("minicpm-sala", v5e[0], bucket,
-                                           monkeypatch)
-    compiled = lowered.compile()
+def test_the_cells_forwards_at_the_files_sizes(cell_chunk, bucket):
+    _, compiled, params, cache, cfg = cell_chunk
     assert cfg.kv_groups() == ((0, 2),)
     assert cfg.kv_layouts(64) == ({"k": (2, 64, 128), "v": (2, 64, 128),
                                    "kc": (4, 2, 128)},)
@@ -185,6 +196,33 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
           f"{mem.temp_size_in_bytes / 2**20:.1f} MiB")
 
 
+#: a buffer as large as a projection's slice of its stack that is a
+#: copy's or a slicing fusion's result: a weight staged in front of its
+#: matmul (asynchronous slices into fast memory are prefetches)
+STAGED = (r"%((?:copy|[\w\-]*slice[\w\-]*fusion)[.\d]*) = "
+          r"bf16\[(?:1,)?(?:4096,4096|4096,256|256,4096)\]")
+
+
+def test_a_step_multiplies_by_its_weights_where_they_lie(
+        v5e, cell_chunk, monkeypatch):
+    """A two-row step holds the outputs that a consumer batches by head
+    to rows (``paged_model._held``: q, k, v and the gate of a period's
+    three lightning layers and of its block-sparse one), so no
+    projection's weight is taken out of its stack and transposed in
+    front of its matmul. The widest chunk holds the lightning layers'
+    q, k and v alone, and there the compiler stages the sparse layer's
+    four as it did — a slicing fusion and a copy each."""
+    lowered, *_ = _lowered("minicpm-sala", v5e[0], (2, 1), monkeypatch)
+    assert lowered.as_text().count("@LayoutConstraint") == 16
+    assert not re.findall(STAGED, lowered.compile().as_text())
+    wide, compiled, *_ = cell_chunk
+    assert wide.as_text().count("@LayoutConstraint") == 9
+    assert len(re.findall(STAGED, compiled.as_text())) == 2 * 4
+    # all sixteen again up to a quarter of the hidden size in rows
+    narrow, *_ = _lowered("minicpm-sala", v5e[0], (1, 1024), monkeypatch)
+    assert narrow.as_text().count("@LayoutConstraint") == 16
+
+
 @pytest.mark.parametrize("name,bucket,layers,operands", [
     ("mistral-7b", (16, 1), 2, 8), ("mistral-7b", (1, 256), 2, 8),
     ("qwen3-next-80b-a3b", (16, 1), None, 8),
@@ -195,8 +233,10 @@ def test_models_without_block_sparse_layers_lower_as_they_did(
     """The paged kernel's call as it was before this file's cell: its
     name, one call a program (the scan's body holds the layer), eight
     operands (five scalar-prefetched: layer, tables, start, lengths,
-    slopes; q, k, v) — no table a head, no mask."""
+    slopes; q, k, v) — no table a head, no mask; and no output held to
+    a layout (``paged_model._held``: their q goes to the kernel)."""
     lowered, *_ = _lowered(name, v5e[0], bucket, monkeypatch, layers)
+    assert "@LayoutConstraint" not in lowered.as_text()
     calls = re.findall(r"stablehlo\.custom_call @tpu_custom_call\(([^)]*)\)"
                        r'[^\n]*?kernel_name = \\?"([a-z_]+)', lowered.as_text())
     assert [name for _, name in calls].count("paged_attention") == 1
