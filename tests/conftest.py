@@ -28,11 +28,25 @@ alone (its own conftest may bring it).
 import json
 import os
 import shutil
+import tempfile
 
-# Must happen before jax is imported: it reads both variables then.
+# Must happen before jax is imported: it reads these variables then.
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if "PYTEST_XDIST_WORKER" not in os.environ:
+    # One persistent compile cache a run: most of the suite's seconds are
+    # XLA compiling tiny programs for the CPU, test after test building
+    # the same tiny engine anew. The controller (or a run without xdist)
+    # makes the directory fresh, its workers and the processes the tests
+    # start inherit it, and ``pytest_sessionfinish`` removes it: nothing
+    # survives a run, so a run proves what it proved. The files that
+    # compile for a described chip switch the cache off for themselves
+    # (tests/tpu_compile_harness.py).
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
+        prefix="dstpu-tests-jax-cache-")
+    os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
@@ -145,13 +159,50 @@ def _manifest_as_the_pinning_test_knew_it(request, monkeypatch):
     yield
 
 
+def pytest_configure(config):
+    """The order files are handed out in is ``pytest_collection_modifyitems``'
+    below, not pytest-xdist's own (most tests first): it is blind to a
+    file of seven tests that is among the suite's heaviest."""
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_collection_modifyitems(config, items):
+    """The heavy files start first. The driver runs ``--dist loadfile``:
+    a file is one worker's, files are handed out in the order of their
+    first items, and the run is as long as its unluckiest worker. So the
+    files that compile for a described chip (the ones that take ``v5e``
+    from tests/tpu_compile_harness.py -- a few tests each, a minute and a
+    half the slowest) go out first, then the cells' rehearsals
+    (tests/benchmark: a whole engine a test), and the rest as
+    pytest-xdist would hand them out, the file with the most tests first:
+    what is left to even the tail out is then the suite's smallest files,
+    not its slowest tests. Each file keeps its own order."""
+    import collections
+
+    import tpu_compile_harness
+
+    rehearsals = os.path.dirname(TWINS)
+    tests_of = collections.Counter(item.path for item in items)
+    items.sort(key=lambda item: (
+        getattr(item.module, "v5e", None) is not tpu_compile_harness.v5e,
+        not item.path.is_relative_to(rehearsals),
+        -tests_of[item.path]))
+
+
 def pytest_sessionfinish(session, exitstatus):
-    """Teardown-hygiene tripwire (VERDICT r3 weak #7: the interpreter
+    """The run's compile cache goes with the run (the controller's, or
+    the only process's: a worker leaves it to them). And a
+    teardown-hygiene tripwire (VERDICT r3 weak #7: the interpreter
     lingered ~10 min after [100%]): name any non-daemon thread still alive
     so a slow exit is attributable instead of mysterious."""
     import sys
     import threading
 
+    if "PYTEST_XDIST_WORKER" not in os.environ:
+        shutil.rmtree(os.environ["JAX_COMPILATION_CACHE_DIR"],
+                      ignore_errors=True)
     stragglers = [t for t in threading.enumerate()
                   if t is not threading.main_thread() and not t.daemon]
     if stragglers:

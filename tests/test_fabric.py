@@ -18,6 +18,7 @@ through ``scripts/serve_replica.py``.
 
 import json
 import os
+import select
 import socket
 import struct
 import subprocess
@@ -88,6 +89,14 @@ def local_reference(ps, max_new, n_replicas=1, **scfg_extra):
         return run_fleet(fe, ps, max_new)
     finally:
         fe.shutdown(drain=False, timeout=5)
+
+
+def first_line(proc, timeout=180):
+    """A child's first line of output -- it blocks until jax is up --
+    or "" after ``timeout`` seconds: a child that hangs before it
+    listens fails its test, not the run at its limit."""
+    ready, _, _ = select.select([proc.stdout], [], [], timeout)
+    return proc.stdout.readline() if ready else ""
 
 
 class _Servers:
@@ -587,17 +596,24 @@ class TestRemoteParity:
             try:
                 bh = [fe.submit(p, max_new_tokens=24,
                                 request_class="batch") for p in ps_batch]
-                time.sleep(0.6)
+                # the interactive wave goes out when the first batch
+                # token has streamed back: the remote pool then holds
+                # that sequence, 23 tokens from its end -- a pause the
+                # batch wave can outlast (a loaded machine) or finish
+                # inside (a warm one) leaves nothing to preempt
+                first = next(bh[0].stream(timeout=120))
                 ih = [fe.submit(p, max_new_tokens=4,
                                 request_class="interactive")
                       for p in ps_int]
                 assert fe.wait_all(bh + ih, timeout=300), \
                     [h.state for h in bh + ih]
                 got_batch = [[ev.token for ev in h.drain()] for h in bh]
+                got_batch[0].insert(0, first.token)
                 got_int = [[ev.token for ev in h.drain()] for h in ih]
                 deadline = time.monotonic() + 10
                 snap = fe.metrics_snapshot()
-                while snap["sequences_preempted"] == 0 \
+                while not (snap["sequences_preempted"]
+                           and snap["sequences_resumed"]) \
                         and time.monotonic() < deadline:
                     time.sleep(0.1)
                     snap = fe.metrics_snapshot()
@@ -853,7 +869,7 @@ class TestSubprocessReplica:
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
             text=True, env=env)
         try:
-            line = proc.stdout.readline()       # blocks until jax is up
+            line = first_line(proc)
             assert line.startswith("FABRIC_LISTENING "), line
             addr = line.split()[1]
             ps = prompts(4, 23)
@@ -904,7 +920,7 @@ class TestMixedModelFleet:
 
     @staticmethod
     def _addr(proc):
-        line = proc.stdout.readline()
+        line = first_line(proc)
         assert line.startswith("FABRIC_LISTENING "), line
         return line.split()[1]
 
@@ -1070,7 +1086,7 @@ class TestFabricPrefixDigest:
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
             text=True, env=env)
         try:
-            line = proc.stdout.readline()
+            line = first_line(proc)
             assert line.startswith("FABRIC_LISTENING "), line
             addr = line.split()[1]
             sys_prompt = prompts(1, 33, lo=40, hi=41)[0]
@@ -1279,7 +1295,7 @@ class TestSubprocessMesh:
         greedy streams to the unsharded in-process fleet."""
         proc = self._spawn(tmp_path, {"tensor": 2, "data": 1}, devices=2)
         try:
-            line = proc.stdout.readline()       # blocks until jax is up
+            line = first_line(proc)
             assert line.startswith("FABRIC_LISTENING "), \
                 (line, proc.stderr.read() if proc.poll() is not None
                  else "")

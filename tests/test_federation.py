@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 import pytest
+from test_fabric import first_line
 
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   RaggedInferenceEngineConfig)
@@ -303,13 +304,17 @@ class TestCrossFrontendFailover:
         spec_path = tmp_path / "frontend.json"
         spec_path.write_text(json.dumps(spec))
         env = dict(os.environ, JAX_PLATFORMS="cpu")
+        # the peer compiles its own programs: reading them from the run's
+        # compile cache (tests/conftest.py) it is through its burst in a
+        # few hundred ms, before the kill of the test below can land
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
         proc = subprocess.Popen(
             [sys.executable,
              os.path.join(os.path.dirname(__file__), "..", "scripts",
                           "serve_frontend.py"), "--spec", str(spec_path)],
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
             text=True, env=env)
-        line = proc.stdout.readline()           # blocks until jax is up
+        line = first_line(proc)
         assert line.startswith("FEDERATION_LISTENING "), line
         return proc, line.split()[1]
 

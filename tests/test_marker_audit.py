@@ -7,6 +7,11 @@
    ~80 SPMD tests the shim un-gated.
 2. The ``slow`` marker the tier-1 budget depends on (``-m 'not slow'``)
    must stay registered in pyproject.toml.
+3. Every ``tests/test_tpu_compile*.py`` takes the described chip and the
+   switched-off persistent cache from ``tests/tpu_compile_harness.py``
+   and defines neither: the next configuration's file adds buckets and
+   assertions, not a seventh copy (and ``tests/conftest.py`` starts the
+   files that take ``v5e`` from there first).
 """
 
 import pathlib
@@ -45,3 +50,22 @@ def test_slow_marker_registered():
     assert markers and "slow" in markers.group(1), (
         "the 'slow' pytest marker must stay registered in pyproject.toml "
         "(the tier-1 suite runs -m 'not slow')")
+
+
+def test_compile_files_share_one_harness():
+    import ast
+
+    files = sorted((REPO / "tests").glob("test_tpu_compile*.py"))
+    assert len(files) >= 6
+    for path in files:
+        body = ast.parse(path.read_text()).body
+        taken = {alias.name for node in body
+                 if isinstance(node, ast.ImportFrom)
+                 and node.module == "tpu_compile_harness"
+                 for alias in node.names}
+        assert {"v5e", "_no_persistent_cache"} <= taken, (
+            f"{path.name} must import v5e and _no_persistent_cache from "
+            f"tests/tpu_compile_harness.py")
+        own = {node.name for node in body if isinstance(node, ast.FunctionDef)} \
+            & {"v5e", "_no_persistent_cache", "_nbytes", "_kernels", "_lowered"}
+        assert not own, f"{path.name} defines its own {own}: use the harness"

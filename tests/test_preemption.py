@@ -527,7 +527,11 @@ def test_frontend_publishes_preempt_metrics_and_journal(model_and_params):
     try:
         hb = [fe.submit(rand_prompt(rng, 60), max_new_tokens=24,
                         request_class="batch") for _ in range(4)]
-        time.sleep(0.6)
+        # the interactive wave goes out when the pool holds a batch
+        # sequence 23 tokens from its end, not after a pause the batch
+        # wave can finish inside (tests/test_fabric.py's
+        # test_preempt_resume_parity waits the same way)
+        next(hb[0].stream(timeout=120))
         hi = [fe.submit(rand_prompt(rng, 60), max_new_tokens=4,
                         request_class="interactive") for _ in range(8)]
         assert fe.wait_all(hb + hi, timeout=240)

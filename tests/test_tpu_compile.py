@@ -15,47 +15,22 @@ compile cache is off around them: an entry written for an unattached chip
 cannot be read back and warns on every later run.
 """
 
-import math
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+from tpu_compile_harness import (_no_persistent_cache, nbytes,  # noqa: F401
+                                 spec_on, v5e)
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-from jax.sharding import NamedSharding, SingleDeviceSharding  # noqa: E402
-from jax.sharding import PartitionSpec as P  # noqa: E402
-
-from deepspeed_tpu.ops import flash_attention as fa  # noqa: E402
-from deepspeed_tpu.ops import paged_attention as pa  # noqa: E402
-from deepspeed_tpu.ops import pallas_utils  # noqa: E402
-from deepspeed_tpu.ops import quantizer as qz  # noqa: E402
+from deepspeed_tpu.ops import flash_attention as fa
+from deepspeed_tpu.ops import paged_attention as pa
+from deepspeed_tpu.ops import pallas_utils
+from deepspeed_tpu.ops import quantizer as qz
 
 FP8 = jnp.float8_e4m3fn
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2").devices
-    except Exception as e:  # no libtpu / unknown topology on this host
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
-
-
-@pytest.fixture(autouse=True)
-def _no_persistent_cache():
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    old = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", old)
-    cc.reset_cache()
 
 
 def _compile(fn, *shapes, sharding):
@@ -165,44 +140,57 @@ def test_paged_attention_at_the_cells_geometries(v5e, geometry):
     _compile(attend, *shapes, sharding=SingleDeviceSharding(v5e[0]))
 
 
-def _compile_paged_forward(v5e, monkeypatch, cfg, bucket, *,
-                           pool=jnp.bfloat16, entry="forward", fused=True,
-                           rows=None):
-    """``PagedCausalLM.<entry>`` of ``cfg`` compiled for one described
-    chip at the bucket ``[N, C]`` over the benchmark's 336 blocks of 64
-    tokens -- with ``rows``, the merged layout: ``bucket`` is the tokens'
-    ``[1, C + rows]`` -- on the serving layout of the parameters
-    (``fuse_qkv``) or on the model's three leaves: the executable, the
-    parameters' shapes and the pool's."""
+@pytest.fixture(scope="module")
+def paged_forward(v5e, _no_persistent_cache):
+    """``PagedCausalLM.<entry>`` at ``widths`` (``_dense_widths``)
+    compiled for one described chip at the bucket ``[N, C]`` over the
+    benchmark's 336 blocks of 64 tokens -- with ``rows``, the merged
+    layout: ``bucket`` is the tokens' ``[1, C + rows]`` -- on the serving
+    layout of the parameters (``fuse_qkv``) or on the model's three
+    leaves: the executable, the parameters' shapes and the pool's. A
+    program that several tests read is compiled once."""
     from deepspeed_tpu.inference.v2 import modules
     from deepspeed_tpu.inference.v2.paged_model import PagedCausalLM, fuse_qkv
     from deepspeed_tpu.models import transformer as tr
 
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
-    monkeypatch.setattr(modules, "on_tpu", lambda: True)
-    model = tr.CausalLM(cfg)
-    bs, NB, MB = 64, 336, 32
-    paged = PagedCausalLM(model, bs, MB)
-    one = SingleDeviceSharding(v5e[0])
+    spec = spec_on(v5e[0])
+    programs = {}
 
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    def build(widths, bucket, pool, entry, fused, rows):
+        cfg, _ = _dense_widths(widths)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(pa, "_on_tpu", lambda: True)
+            patch.setattr(modules, "on_tpu", lambda: True)
+            model = tr.CausalLM(cfg)
+            bs, NB, MB = 64, 336, 32
+            paged = PagedCausalLM(model, bs, MB)
+            init = (lambda key: fuse_qkv(model.init(key))) if fused \
+                else model.init
+            shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
+            params = jax.tree.map(lambda a: spec(a.shape, jnp.bfloat16),
+                                  shapes)
+            shape = (cfg.num_layers, NB, cfg.kv_heads, bs, cfg.head_dim)
+            cache = {"k": spec(shape, pool), "v": spec(shape, pool)}
+            if pool == jnp.int8:
+                cache["k_scale"] = spec(shape[:3], jnp.float32)
+                cache["v_scale"] = spec(shape[:3], jnp.float32)
+            N = rows or bucket[0]
+            kw = {"verify_width": 4} if entry == "forward_verify" else {}
+            compiled = getattr(paged, entry).lower(
+                params, cache, spec(bucket, jnp.int32), spec((N,), jnp.int32),
+                spec((N,), jnp.int32), spec((N, MB), jnp.int32),
+                **kw).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        return compiled, shapes, cache
 
-    init = (lambda key: fuse_qkv(model.init(key))) if fused else model.init
-    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
-    params = jax.tree.map(lambda a: spec(a.shape, jnp.bfloat16), shapes)
-    shape = (cfg.num_layers, NB, cfg.kv_heads, bs, cfg.head_dim)
-    cache = {"k": spec(shape, pool), "v": spec(shape, pool)}
-    if pool == jnp.int8:
-        cache["k_scale"] = spec(shape[:3], jnp.float32)
-        cache["v_scale"] = spec(shape[:3], jnp.float32)
-    N = rows or bucket[0]
-    kw = {"verify_width": 4} if entry == "forward_verify" else {}
-    compiled = getattr(paged, entry).lower(
-        params, cache, spec(bucket, jnp.int32), spec((N,), jnp.int32),
-        spec((N,), jnp.int32), spec((N, MB), jnp.int32), **kw).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    return compiled, shapes, cache
+    def compiled(widths, bucket, *, pool=jnp.bfloat16, entry="forward",
+                 fused=True, rows=None):
+        key = (widths, bucket, jnp.dtype(pool).name, entry, fused, rows)
+        if key not in programs:
+            programs[key] = build(widths, bucket, pool, entry, fused, rows)
+        return programs[key]
+
+    return compiled
 
 
 @pytest.mark.parametrize("bucket", [(1, 1), (16, 1), (8, 256)],
@@ -210,8 +198,8 @@ def _compile_paged_forward(v5e, monkeypatch, cfg, bucket, *,
 @pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.int8],
                          ids=lambda d: jnp.dtype(d).name)
 @pytest.mark.parametrize("entry", ["forward", "forward_verify"])
-def test_paged_forward_keeps_the_pool_in_place(v5e, entry, pool, bucket,
-                                               monkeypatch):
+def test_paged_forward_keeps_the_pool_in_place(paged_forward, entry, pool,
+                                               bucket):
     """The serving forward at Pythia-1.4B widths (6 of its 24 layers, the
     benchmark's 336 blocks of 64 tokens, the parameters in the serving
     layout): the chip's compiler aliases every pool leaf to the output and
@@ -222,12 +210,8 @@ def test_paged_forward_keeps_the_pool_in_place(v5e, entry, pool, bucket,
     at [16, 1]), which the CPU backend's compile does not show; the
     whole-block scatter of ``kv_write.py`` is the pool's own layout."""
     cfg, _ = _dense_widths("pythia")
-    compiled, _, cache = _compile_paged_forward(
-        v5e, monkeypatch, cfg, bucket, pool=pool, entry=entry)
-
-    def nbytes(s):
-        return math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
-
+    compiled, _, cache = paged_forward("pythia", bucket, pool=pool,
+                                       entry=entry)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(nbytes(s) for s in cache.values())
     slab = nbytes(cache["k"]) // cfg.num_layers
@@ -309,8 +293,8 @@ def _dense_widths(widths):
     ("pythia", (1, 1)), ("pythia", (2, 1)), ("pythia", (16, 1)),
     ("pythia", (1, 256)), ("mistral", (32, 1)), ("mistral", (1, 256))],
     ids=lambda v: v if isinstance(v, str) else f"{v[0]}x{v[1]}")
-def test_paged_forward_multiplies_weights_where_they_lie(v5e, widths, bucket,
-                                                         monkeypatch):
+def test_paged_forward_multiplies_weights_where_they_lie(paged_forward,
+                                                         widths, bucket):
     """The dense serving forward on the serving layout of its parameters
     (``fuse_qkv``: one ``wqkv`` leaf) at Pythia-1.4B's widths (6 layers)
     and Mistral-7B's (4096 / 14336, 32 / 8 heads, 4 layers): the layer
@@ -329,8 +313,7 @@ def test_paged_forward_multiplies_weights_where_they_lie(v5e, widths, bucket,
     had no copy on three leaves either: its case holds the one-row
     program to that."""
     cfg, matrices = _dense_widths(widths)
-    compiled, served, _ = _compile_paged_forward(v5e, monkeypatch, cfg,
-                                                 bucket)
+    compiled, served, _ = paged_forward(widths, bucket)
     assert "wqkv" in served["layers"] and "wq" not in served["layers"]
     assert (1, cfg.hidden_size, (cfg.num_heads + 2 * cfg.kv_heads)
             * cfg.head_dim) in matrices(served)
@@ -338,8 +321,8 @@ def test_paged_forward_multiplies_weights_where_they_lie(v5e, widths, bucket,
 
 
 @pytest.mark.parametrize("widths,rows", [("mistral", 32), ("pythia", 2)])
-def test_the_merged_forward_streams_each_weight_once(v5e, widths, rows,
-                                                     monkeypatch):
+def test_the_merged_forward_streams_each_weight_once(paged_forward, widths,
+                                                     rows):
     """The merged program at Mistral-7B's ``[1, 256 + 32]`` and
     Pythia-1.4B's ``[1, 256 + 2]``: what it is for, read from the
     optimized HLO. The layer loop's body multiplies each of ``wqkv``,
@@ -349,8 +332,8 @@ def test_the_merged_forward_streams_each_weight_once(v5e, widths, rows,
     aliased to the output with temporaries under one layer's slab: no copy
     of the pool between the two parts' writes."""
     cfg, matrices = _dense_widths(widths)
-    compiled, served, cache = _compile_paged_forward(
-        v5e, monkeypatch, cfg, (1, 256 + rows), rows=rows)
+    compiled, served, cache = paged_forward(widths, (1, 256 + rows),
+                                            rows=rows)
     text = compiled.as_text()
     assert _staged_weights(text, matrices(served)) == []
     comps = _computations(text)
@@ -382,7 +365,7 @@ def test_the_merged_forward_streams_each_weight_once(v5e, widths, rows,
         [f"1,{cfg.kv_heads},{256 * cfg.num_heads // cfg.kv_heads},128",
          f"{rows},{cfg.kv_heads},{cfg.num_heads // cfg.kv_heads},128"])
     mem = compiled.memory_analysis()
-    pool = sum(math.prod(s.shape) * 2 for s in cache.values())
+    pool = sum(nbytes(s) for s in cache.values())
     assert mem.alias_size_in_bytes >= pool
     assert mem.temp_size_in_bytes < pool // (2 * cfg.num_layers)
 
@@ -391,8 +374,8 @@ def test_the_merged_forward_streams_each_weight_once(v5e, widths, rows,
                                            ("mistral", (32, 1))],
                          ids=lambda v: v if isinstance(v, str)
                          else f"{v[0]}x{v[1]}")
-def test_three_leaves_are_staged_and_transposed(v5e, widths, bucket,
-                                                monkeypatch):
+def test_three_leaves_are_staged_and_transposed(paged_forward, widths,
+                                                bucket):
     """What the serving layout is for, and that ``_staged_weights`` sees
     it: on the model's three leaves (the body quantized trees and TP
     shards still run) the loop's body stages ``wq``, ``wk`` and ``wv`` --
@@ -400,8 +383,7 @@ def test_three_leaves_are_staged_and_transposed(v5e, widths, bucket,
     this fails because nothing is staged, the compiler has learnt to
     slice them in place and ``fuse_qkv`` has lost its reason."""
     cfg, matrices = _dense_widths(widths)
-    compiled, shapes, _ = _compile_paged_forward(v5e, monkeypatch, cfg,
-                                                 bucket, fused=False)
+    compiled, shapes, _ = paged_forward(widths, bucket, fused=False)
     qkv = sorted(",".join(map(str, (1,) + shapes["layers"][n].shape[1:]))
                  for n in ("wq", "wk", "wv"))
     staged = _staged_weights(compiled.as_text(), matrices(shapes))

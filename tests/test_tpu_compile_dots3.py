@@ -13,120 +13,76 @@ rows or ``mla_sparse_prefill`` under the selection's mask; the window
 layers' ``mla_window_decode`` at 64 heads x 1,152 lanes or
 ``mla_window_prefill`` with the rope joined to a 192-wide nope; three
 leaves, each aliased to the output. See tests/test_tpu_compile.py for
-the method."""
+the method and tests/tpu_compile_harness.py for what is shared; which
+kernels every bucket takes is also held without compiling."""
 
-import json
-import math
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import pytest
+from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
+                                 configuration, fits_beside, kernels, lowered,
+                                 v5e)
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from deepspeed_tpu.models import hybrid
+from deepspeed_tpu.ops import latent_attention as la
 
-from deepspeed_tpu.ops import latent_attention as la  # noqa: E402
-from deepspeed_tpu.ops import paged_attention as pa  # noqa: E402
-from deepspeed_tpu.ops import pallas_utils  # noqa: E402
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-HBM = 15.75 * 2 ** 30
+NAME = "dots3-note-prev"
+BUCKETS = [(2, 1), (1, 128), (1, 256), (1, 2048)]
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2").devices
-    except Exception as e:  # no libtpu / unknown topology on this host
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
+def _absorbed(cfg, C):
+    """Whether a row of ``C`` positions runs absorbed: in the sparse
+    layers, in the window layers."""
+    return (C <= la.ABSORB_MAX_QUERIES,
+            C <= hybrid.absorb_limit(cfg, "latent_window"))
 
 
-@pytest.fixture(autouse=True)
-def _no_persistent_cache():
-    from jax.experimental.compilation_cache import compilation_cache as cc
+@pytest.mark.parametrize("bucket,sparse,window", [
+    ((2, 1), "mla_sparse_decode", "mla_window_decode"),
+    ((1, 128), "mla_sparse_decode", "mla_window_decode"),
+    ((1, 256), "mla_sparse_prefill", "mla_window_decode"),
+    ((1, 2048), "mla_sparse_prefill", "mla_window_prefill")], ids=bucket_id)
+def test_the_kernels_each_bucket_takes(bucket, sparse, window):
+    """Without compiling: the side of each kind's crossing every one of
+    the file's buckets lies on, by name (the four differ in kind: all
+    four compile in tier-1)."""
+    cfg, sizes = configuration(NAME)
+    assert bucket[1] <= sizes["max_chunk_tokens"]
+    took = _absorbed(cfg, bucket[1])
+    assert ("mla_sparse_decode" if took[0] else "mla_sparse_prefill",
+            "mla_window_decode" if took[1] else "mla_window_prefill") == (
+        sparse, window)
 
-    old = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", old)
-    cc.reset_cache()
 
-
-def _nbytes(s):
-    return math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
-
-
-@pytest.mark.parametrize("bucket", [(2, 1), (1, 128), (1, 256), (1, 2048)],
-                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("bucket", BUCKETS, ids=bucket_id)
 def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
-    from deepspeed_tpu.inference.v2 import modules
-    from deepspeed_tpu.inference.v2.engine_v2 import \
-        RaggedInferenceEngineConfig
     from deepspeed_tpu.inference.v2 import paged_model
-    from deepspeed_tpu.inference.v2.paged_model import PagedCausalLM
-    from deepspeed_tpu.models import hybrid
-    from deepspeed_tpu.models import transformer as tr
 
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
-    monkeypatch.setattr(la, "_on_tpu", lambda: True)
-    monkeypatch.setattr(modules, "on_tpu", lambda: True)
-    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
-    with open(os.path.join(REPO, "benchmark", "configs",
-                           "dots3-note-prev.json")) as f:
-        body = json.load(f)
-    cfg = tr.TransformerConfig(**dict(body["transformer_config"],
-                                      dtype=jnp.bfloat16))
-    sizing = RaggedInferenceEngineConfig(**{
-        k: v for k, v in body["engine"].items() if not k.startswith("_")})
-    model = tr.CausalLM(cfg)
-    bs = sizing.kv_block_size
-    MB = -(-cfg.max_seq_len // bs)
-    paged = PagedCausalLM(model, bs, MB,
-                          max_batch_tokens=sizing.max_ragged_batch_size)
-    one = SingleDeviceSharding(v5e[0])
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    params = jax.tree.map(
-        lambda a: spec(a.shape, jnp.bfloat16),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    low, params, cache, cfg = lowered(NAME, v5e[0], bucket, monkeypatch)
     # the pools in the layouts the model states: two groups, three leaves
     assert cfg.kv_groups() == ((0, 2), (513, 3))
-    assert cfg.kv_layouts(bs) == ({"kv": (64, 640), "ki": (64, 128)},
+    assert cfg.kv_layouts(64) == ({"kv": (64, 640), "ki": (64, 128)},
                                   {"kv": (64, 1152)})
-    window_blocks = 32 * (-(-513 // bs) + 2) + 4096 // bs
-    cache = {"kv": spec((2, sizing.kv_blocks, 64, 640), jnp.bfloat16),
-             "ki": spec((2, sizing.kv_blocks, 64, 128), jnp.bfloat16),
-             "kv1": spec((3, window_blocks, 64, 1152), jnp.bfloat16)}
-    N, C = bucket
-    compiled = paged.forward.lower(
-        params, cache, spec((N, C), jnp.int32), spec((N,), jnp.int32),
-        spec((N,), jnp.int32), spec((2, N, MB), jnp.int32)).compile()
+    window_blocks = 32 * (-(-513 // 64) + 2) + 4096 // 64
+    assert {n: s.shape for n, s in cache.items()} == {
+        "kv": (2, 16384, 64, 640), "ki": (2, 16384, 64, 128),
+        "kv1": (3, window_blocks, 64, 1152)}
+    compiled = low.compile()
     text = compiled.as_text()
-    kernels = re.findall(r"%([a-z_\-]+)[.\d]* = [^\n]*tpu_custom_call", text)
+    found = kernels(text)
     # two whole-context layers (the leading one and the period's), three
     # window layers: one attention kernel call each, by the kind's path
-    sparse_absorbed = C <= la.ABSORB_MAX_QUERIES
-    window_absorbed = C <= hybrid.absorb_limit(cfg, "latent_window")
+    sparse_absorbed, window_absorbed = _absorbed(cfg, bucket[1])
     assert hybrid.absorb_limit(cfg, "latent_window") == 256
     # (the scores are taken at one of two widths: a branch each)
-    assert kernels.count("index_score") == 2 * (len(paged_model.SELECT_WIDTHS)
-                                                + 1)
-    assert kernels.count("mla_sparse_decode") == (2 if sparse_absorbed else 0)
-    assert kernels.count("mla_sparse_prefill") == (0 if sparse_absorbed
-                                                   else 2)
-    assert kernels.count("mla_window_decode") == (3 if window_absorbed else 0)
-    assert kernels.count("mla_window_prefill") == (0 if window_absorbed
-                                                   else 3)
-    assert not {"mla_decode", "mla_prefill", "paged_attention"} & set(kernels)
-    assert kernels.count("gmm") == 12
+    assert found.count("index_score") == 2 * (len(paged_model.SELECT_WIDTHS)
+                                              + 1)
+    assert found.count("mla_sparse_decode") == (2 if sparse_absorbed else 0)
+    assert found.count("mla_sparse_prefill") == (0 if sparse_absorbed else 2)
+    assert found.count("mla_window_decode") == (3 if window_absorbed else 0)
+    assert found.count("mla_window_prefill") == (0 if window_absorbed else 3)
+    assert not {"mla_decode", "mla_prefill", "paged_attention"} & set(found)
+    assert found.count("gmm") == 12
     # the selection holds no sort (one-token rows and narrow chunks count
     # the threshold out and compact, as the wide chunks' mask does): what
     # sorts is the experts' routing, over 256 experts and a row's pairs
@@ -140,13 +96,4 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
                         text)
     assert scoped and all("window_latent_attn" in s and "/attend/" in s
                           for s in scoped)
-
-    mem = compiled.memory_analysis()
-    pool = sum(_nbytes(s) for s in cache.values())
-    weights = sum(_nbytes(s) for s in jax.tree.leaves(params))
-    assert mem.alias_size_in_bytes >= pool
-    assert weights + pool + mem.temp_size_in_bytes < HBM - 2 ** 30, (
-        weights / 2 ** 30, pool / 2 ** 30, mem.temp_size_in_bytes / 2 ** 30)
-    print(f"[{N}x{C}] weights {weights / 2**30:.2f} GiB pool "
-          f"{pool / 2**30:.2f} GiB temporaries "
-          f"{mem.temp_size_in_bytes / 2**20:.1f} MiB")
+    fits_beside(compiled, params, cache, bucket, headroom=2 ** 30)

@@ -6,107 +6,59 @@ paged kernel at head size 256 with 8 query heads a KV head, a 1,024-token
 chunk cut into pieces of ``MAX_QUERY_ROWS`` rows; the grouped matmul as
 the Pallas ``gmm`` (XLA's own ``ragged-dot`` custom calls carry no
 ``op_name``); every cache leaf — pool and recurrent state — aliased to
-the output. See tests/test_tpu_compile.py for the method."""
+the output. See tests/test_tpu_compile.py for the method and
+tests/tpu_compile_harness.py for what is shared; how many pieces either
+bucket is cut in is also held without compiling."""
 
-import math
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import pytest
+from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
+                                 configuration, kernels, lowered, nbytes, v5e)
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from deepspeed_tpu.ops import paged_attention as pa
 
-from deepspeed_tpu.ops import paged_attention as pa  # noqa: E402
-from deepspeed_tpu.ops import pallas_utils  # noqa: E402
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2").devices
-    except Exception as e:  # no libtpu / unknown topology on this host
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
+NAME = "qwen3-next-80b-a3b"
+BUCKETS = [(1, 1024), (8, 1)]
 
 
-@pytest.fixture(autouse=True)
-def _no_persistent_cache():
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    old = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", old)
-    cc.reset_cache()
+def _pieces(cfg, C):
+    return C // pa._chunk_tile(C, cfg.num_heads // cfg.kv_heads)
 
 
-@pytest.mark.parametrize("bucket", [(1, 1024), (8, 1)],
-                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("bucket,pieces", [((1, 1024), 4), ((8, 1), 1)],
+                         ids=bucket_id)
+def test_the_pieces_each_bucket_is_cut_in(bucket, pieces):
+    """Without compiling: 8 query heads a KV head put 256 tokens in a
+    piece of ``MAX_QUERY_ROWS`` rows."""
+    cfg, sizes = configuration(NAME)
+    assert cfg.num_heads // cfg.kv_heads == 8 and cfg.head_dim == 256
+    assert bucket[1] <= sizes["max_chunk_tokens"]
+    assert _pieces(cfg, bucket[1]) == pieces
+
+
+@pytest.mark.parametrize("bucket", BUCKETS, ids=bucket_id)
 def test_hybrid_forward_at_published_widths(v5e, bucket, monkeypatch):
-    from deepspeed_tpu.inference.v2 import modules
-    from deepspeed_tpu.inference.v2.paged_model import PagedCausalLM
-    from deepspeed_tpu.models import hybrid
-    from deepspeed_tpu.models import transformer as tr
-
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
-    monkeypatch.setattr(modules, "on_tpu", lambda: True)
-    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
-    cfg = tr.TransformerConfig(
-        vocab_size=75968, hidden_size=2048, intermediate_size=5120,
-        num_layers=4, num_heads=16, num_kv_heads=2, head_size=256,
-        max_seq_len=33280, norm="rmsnorm", norm_eps=1e-6,
-        norm_zero_centered=True, activation="silu", position="rope",
-        rope_pct=0.25, rope_theta=1e7, tie_embeddings=False,
-        dtype=jnp.bfloat16,
-        layer_pattern=("linear", "linear", "linear", "full"),
-        attn_output_gate=True, qk_norm=True, linear_num_key_heads=16,
-        linear_num_value_heads=32, linear_key_head_dim=128,
-        linear_value_head_dim=128, linear_conv_kernel=4,
-        moe_num_experts=512, moe_top_k=10, moe_dropless=True,
-        moe_norm_topk=True, moe_held_experts=(0, 256),
-        moe_intermediate_size=512, moe_shared_intermediate_size=512)
-    model = tr.CausalLM(cfg)
-    bs, NB, MB = 64, 1024, 520
-    paged = PagedCausalLM(model, bs, MB, max_batch_tokens=1056)
-    one = SingleDeviceSharding(v5e[0])
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    params = jax.tree.map(
-        lambda a: spec(a.shape, jnp.bfloat16),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    cache = {name: spec((1, NB, 2, bs, 256), jnp.bfloat16)
-             for name in ("k", "v")}
-    cache.update({name: spec(shape, dt) for name, (shape, dt)
-                  in hybrid.state_shapes(cfg, 5).items()})
-    N, C = bucket
-    compiled = paged.forward.lower(
-        params, cache, spec((N, C), jnp.int32), spec((N,), jnp.int32),
-        spec((N,), jnp.int32), spec((N, MB), jnp.int32),
-        spec((N,), jnp.int32)).compile()
+    # the configuration's one period at its widths; 1,024 blocks, a
+    # budget of 1,056 tokens and five slots
+    low, _, cache, cfg = lowered(
+        NAME, v5e[0], bucket, monkeypatch, kv_blocks=1024,
+        max_ragged_batch_size=1056, max_ragged_sequence_count=8)
+    assert cfg.num_layers == 4
+    assert cache["k"].shape == cache["v"].shape == (1, 1024, 2, 64, 256)
+    compiled = low.compile()
     text = compiled.as_text()
-    kernels = re.findall(r"%([a-z_\-]+)[.\d]* = [^\n]*tpu_custom_call", text)
+    found = kernels(text)
     # one attention layer: the chunk's 8 x 1024 query rows in four pieces
-    assert kernels.count("paged_attention") == (4 if C == 1024 else 1)
+    assert found.count("paged_attention") == _pieces(cfg, bucket[1])
     # gate, up, down in each of the four layers, and nothing of XLA's own
-    assert kernels.count("gmm") == 12
-    assert not any(k.startswith("ragged") for k in kernels)
+    assert found.count("gmm") == 12
+    assert not any(k.startswith("ragged") for k in found)
     scoped = re.findall(r'%gmm[.\d]* = [^\n]*op_name="([^"]*)"', text)
     assert scoped and all("/mlp/experts/" in s for s in scoped)
-
-    def nbytes(s):
-        return math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
-
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(nbytes(s) for s in cache.values())
-    # no copy of the state tree (5 slots x 3 layers x 2 MiB) in the
-    # temporaries of a decode step
-    if C == 1:
+    # no copy of the state tree (a slot a sequence and one x 3 layers x
+    # 2 MiB) in the temporaries of a decode step
+    if bucket[1] == 1:
         assert mem.temp_size_in_bytes < nbytes(cache["ssm"])

@@ -14,49 +14,22 @@ other way round:
 programs hold the kernel names and operand counts they held before the
 block-sparse variants were added to ``ops/paged_attention.py`` (read off
 the lowered program: what the compiler is handed). See
-tests/test_tpu_compile.py for the method."""
+tests/test_tpu_compile.py for the method and tests/tpu_compile_harness.py
+for what is shared."""
 
-import json
-import math
-import os
 import re
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import jax
+import jax.numpy as jnp
+import pytest
+from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
+                                 configuration, fits_beside, kernels, lowered,
+                                 spec_on, v5e)
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import pytest  # noqa: E402
-from jax.sharding import SingleDeviceSharding  # noqa: E402
+from deepspeed_tpu.ops import paged_attention as pa
+from deepspeed_tpu.ops import pallas_utils
 
-from deepspeed_tpu.ops import latent_attention as la  # noqa: E402
-from deepspeed_tpu.ops import paged_attention as pa  # noqa: E402
-from deepspeed_tpu.ops import pallas_utils  # noqa: E402
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-HBM = 15.75 * 2 ** 30
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2").devices
-    except Exception as e:  # no libtpu / unknown topology on this host
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _no_persistent_cache():
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    old = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", old)
-    cc.reset_cache()
+NAME = "minicpm-sala"
 
 
 @pytest.fixture(scope="module")
@@ -65,74 +38,31 @@ def cell_chunk(v5e, _no_persistent_cache):
     compiled once for the tests that read it: ``(lowered, compiled,
     params, cache, cfg)``."""
     with pytest.MonkeyPatch.context() as patch:
-        lowered, params, cache, cfg = _lowered("minicpm-sala", v5e[0],
-                                               (1, 2048), patch)
-        return lowered, lowered.compile(), params, cache, cfg
+        low, params, cache, cfg = lowered(NAME, v5e[0], (1, 2048), patch)
+        return low, low.compile(), params, cache, cfg
 
 
-def _nbytes(s):
-    return math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+def _sparse_calls(cfg, C):
+    """The block-sparse layers' kernel at a row of ``C`` positions, and
+    its calls a layer: a piece of ``_chunk_tile`` each."""
+    if C == 1:
+        return "paged_attention_select", 1
+    return "paged_attention_mask", C // pa._chunk_tile(
+        C, cfg.num_heads // cfg.kv_heads)
 
 
-def _lowered(name, device, bucket, monkeypatch, layers=None):
-    """The configuration's paged forward at ``bucket``, lowered for the
-    described device: ``(lowered, params, cache, cfg)``."""
-    from deepspeed_tpu.inference.v2 import modules
-    from deepspeed_tpu.inference.v2.engine_v2 import \
-        RaggedInferenceEngineConfig
-    from deepspeed_tpu.inference.v2.paged_model import (PagedCausalLM,
-                                                        fuse_qkv)
-    from deepspeed_tpu.models import hybrid
-    from deepspeed_tpu.models import transformer as tr
-
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
-    monkeypatch.setattr(la, "_on_tpu", lambda: True)
-    monkeypatch.setattr(modules, "on_tpu", lambda: True)
-    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
-    with open(os.path.join(REPO, "benchmark", "configs",
-                           name + ".json")) as f:
-        body = json.load(f)
-    arch = dict(body["transformer_config"], dtype=jnp.bfloat16)
-    if layers:
-        arch["num_layers"] = layers
-    cfg = tr.TransformerConfig(**arch)
-    sizing = RaggedInferenceEngineConfig(**{
-        k: v for k, v in body["engine"].items() if not k.startswith("_")})
-    model = tr.CausalLM(cfg)
-    bs = sizing.kv_block_size
-    MB = -(-cfg.max_seq_len // bs)
-    paged = PagedCausalLM(model, bs, MB,
-                          max_batch_tokens=sizing.max_ragged_batch_size)
-    one = SingleDeviceSharding(device)
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    params = jax.tree.map(
-        lambda a: spec(a.shape, jnp.bfloat16),
-        jax.eval_shape(lambda k: fuse_qkv(model.init(k)),
-                       jax.random.PRNGKey(0)))
-    groups = cfg.kv_groups()
-    cache = {}
-    for g, ((_, n), layout) in enumerate(zip(groups, cfg.kv_layouts(bs))):
-        for leaf, block in layout.items():
-            cache[leaf + (str(g) if g else "")] = spec(
-                (n, sizing.kv_blocks) + block, jnp.bfloat16)
-    N, C = bucket
-    args = [params, cache, spec((N, C), jnp.int32), spec((N,), jnp.int32),
-            spec((N,), jnp.int32),
-            spec((N, MB) if len(groups) == 1 else (len(groups), N, MB),
-                 jnp.int32)]
-    if cfg.is_hybrid and cfg.num_linear_layers:
-        slots = sizing.max_ragged_sequence_count + 1
-        for leaf, (shape, dt) in hybrid.state_shapes(cfg, slots).items():
-            cache[leaf] = spec(shape, dt)
-        args.append(spec((N,), jnp.int32))
-    return paged.forward.lower(*args), params, cache, cfg
-
-
-def _kernels(text):
-    return re.findall(r"%([a-z_\-]+)[.\d]* = [^\n]*tpu_custom_call", text)
+@pytest.mark.parametrize("bucket,kernel,calls", [
+    ((1, 2048), "paged_attention_mask", 16),
+    ((1, 1024), "paged_attention_mask", 8),
+    ((2, 1), "paged_attention_select", 1)], ids=bucket_id)
+def test_the_kernel_each_bucket_takes(bucket, kernel, calls):
+    """Without compiling: a one-token row walks its selected table, a
+    chunk every live block under the mask, 128 positions a call (16
+    query heads a KV head) -- for the buckets this file lowers."""
+    cfg, sizes = configuration(NAME)
+    assert bucket[1] <= sizes["max_chunk_tokens"]
+    assert cfg.num_heads // cfg.kv_heads == 16
+    assert _sparse_calls(cfg, bucket[1]) == (kernel, calls)
 
 
 def test_the_select_kernel_at_the_files_sizes(v5e, monkeypatch):
@@ -143,20 +73,17 @@ def test_the_select_kernel_at_the_files_sizes(v5e, monkeypatch):
     on the chip in the cell: PERF.md section 4.)"""
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
     monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
-    one = SingleDeviceSharding(v5e[0])
-    spec = lambda shape, dt: jax.ShapeDtypeStruct(      # noqa: E731
-        shape, dt, sharding=one)
+    spec = spec_on(v5e[0])
     pool = spec((2, 16384, 2, 64, 128), jnp.bfloat16)
     text = jax.jit(lambda q, k, v, t, n, p, layer: pa.paged_attention_select(
         q, k, v, t, n, p, layer=layer)).lower(
             spec((32, 1, 32, 128), jnp.bfloat16), pool, pool,
             spec((32, 2, 128), jnp.int32), spec((32,), jnp.int32),
             spec((32,), jnp.int32), spec((), jnp.int32)).compile().as_text()
-    assert _kernels(text).count("paged_attention_select") == 1
+    assert kernels(text).count("paged_attention_select") == 1
 
 
-@pytest.mark.parametrize("bucket", [(1, 2048)],
-                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("bucket", [(1, 2048)], ids=bucket_id)
 def test_the_cells_forwards_at_the_files_sizes(cell_chunk, bucket):
     _, compiled, params, cache, cfg = cell_chunk
     assert cfg.kv_groups() == ((0, 2),)
@@ -164,18 +91,14 @@ def test_the_cells_forwards_at_the_files_sizes(cell_chunk, bucket):
                                    "kc": (4, 2, 128)},)
     assert cache["lightning"].shape == (6, 33, 32, 128, 128)
     text = compiled.as_text()
-    kernels = _kernels(text)
-    N, C = bucket
+    found = kernels(text)
     # two sparse layers, one period each (the scan's body holds one): a
     # one-token row walks its selected table, a chunk every live block
     # under the mask, 128 positions a call
-    if C == 1:
-        assert kernels.count("paged_attention_select") == 1
-        assert "paged_attention_mask" not in kernels
-    else:
-        assert kernels.count("paged_attention_mask") == max(1, C // 128)
-        assert "paged_attention_select" not in kernels
-    assert "paged_attention" not in kernels
+    kernel, calls = _sparse_calls(cfg, bucket[1])
+    assert found.count(kernel) == calls
+    assert not {"paged_attention_select", "paged_attention_mask",
+                "paged_attention"} - {kernel} & set(found)
     # the selection holds no sort
     assert not re.search(r" sort\(|topk|TopK|top-k", text)
     scoped = re.findall(
@@ -185,15 +108,7 @@ def test_the_cells_forwards_at_the_files_sizes(cell_chunk, bucket):
                  "lightning_attn/lightning_scan",
                  "lightning_attn/lightning_proj"):
         assert name + "/" in text, name
-    mem = compiled.memory_analysis()
-    pool = sum(_nbytes(s) for s in cache.values())
-    weights = sum(_nbytes(s) for s in jax.tree.leaves(params))
-    assert mem.alias_size_in_bytes >= pool
-    assert weights + pool + mem.temp_size_in_bytes < HBM - 4 * 2 ** 30, (
-        weights / 2 ** 30, pool / 2 ** 30, mem.temp_size_in_bytes / 2 ** 30)
-    print(f"[{N}x{C}] weights {weights / 2**30:.2f} GiB pool+state "
-          f"{pool / 2**30:.2f} GiB temporaries "
-          f"{mem.temp_size_in_bytes / 2**20:.1f} MiB")
+    fits_beside(compiled, params, cache, bucket, headroom=4 * 2 ** 30)
 
 
 #: a buffer as large as a projection's slice of its stack that is a
@@ -212,14 +127,14 @@ def test_a_step_multiplies_by_its_weights_where_they_lie(
     front of its matmul. The widest chunk holds the lightning layers'
     q, k and v alone, and there the compiler stages the sparse layer's
     four as it did — a slicing fusion and a copy each."""
-    lowered, *_ = _lowered("minicpm-sala", v5e[0], (2, 1), monkeypatch)
-    assert lowered.as_text().count("@LayoutConstraint") == 16
-    assert not re.findall(STAGED, lowered.compile().as_text())
+    step, *_ = lowered(NAME, v5e[0], (2, 1), monkeypatch)
+    assert step.as_text().count("@LayoutConstraint") == 16
+    assert not re.findall(STAGED, step.compile().as_text())
     wide, compiled, *_ = cell_chunk
     assert wide.as_text().count("@LayoutConstraint") == 9
     assert len(re.findall(STAGED, compiled.as_text())) == 2 * 4
     # all sixteen again up to a quarter of the hidden size in rows
-    narrow, *_ = _lowered("minicpm-sala", v5e[0], (1, 1024), monkeypatch)
+    narrow, *_ = lowered(NAME, v5e[0], (1, 1024), monkeypatch)
     assert narrow.as_text().count("@LayoutConstraint") == 16
 
 
@@ -235,10 +150,10 @@ def test_models_without_block_sparse_layers_lower_as_they_did(
     operands (five scalar-prefetched: layer, tables, start, lengths,
     slopes; q, k, v) — no table a head, no mask; and no output held to
     a layout (``paged_model._held``: their q goes to the kernel)."""
-    lowered, *_ = _lowered(name, v5e[0], bucket, monkeypatch, layers)
-    assert "@LayoutConstraint" not in lowered.as_text()
+    text = lowered(name, v5e[0], bucket, monkeypatch, layers)[0].as_text()
+    assert "@LayoutConstraint" not in text
     calls = re.findall(r"stablehlo\.custom_call @tpu_custom_call\(([^)]*)\)"
-                       r'[^\n]*?kernel_name = \\?"([a-z_]+)', lowered.as_text())
+                       r'[^\n]*?kernel_name = \\?"([a-z_]+)', text)
     assert [name for _, name in calls].count("paged_attention") == 1
     assert not {"paged_attention_select", "paged_attention_mask"} \
         & {name for _, name in calls}
