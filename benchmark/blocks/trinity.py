@@ -288,12 +288,11 @@ def logits(params, tokens, arch, q_block=Q_BLOCK):
     model's, a float32 reference knows one of them, and which one a
     bfloat16 program meets is rounding's to say. Whether a position is
     so is decided here, from the float32 margins alone, before anything
-    of the program is seen. ``serve_runner.check_logits`` folds its
-    comparisons with ``max(worst, err)``, which keeps ``worst`` when
-    ``err`` is NaN: such a position is not compared (a harness that
-    folded otherwise would print ``correct: false`` for every run that
-    holds one — loudly; tests/benchmark/test_trinity_block.py holds both
-    ends)."""
+    of the program is seen. ``serve_runner.check_logits`` takes a
+    reference row that is NaN throughout for this mask: the position is
+    not compared and is counted (``logits_check.unanswered``), and any
+    other value that is not a number fails the check
+    (tests/benchmark/test_trinity_block.py holds both ends)."""
     with jax.default_matmul_precision("highest"):
         lg, least = _logits_one(params, tokens, arch, q_block)
     return jnp.where((least < TIE_MARGIN)[:, None], jnp.nan, lg)

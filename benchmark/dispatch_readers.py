@@ -456,6 +456,25 @@ def dev_prefill_us_per_token(ctx):
     return 1e6 * sum(f["dur"] for f in fw) / tokens if tokens else None
 
 
+def forward_device_ms(ctx, mixed: bool):
+    """Median device ms of the window's forwards at their most frequent
+    bucket: among the one-token forwards (``bucket_chunk`` = 1), or —
+    ``mixed`` — among those of the widest chunk the window ran. None
+    where the trace cannot be paired or the window holds no such
+    forward."""
+    r = _reduced(ctx)
+    fw = _window_forwards(r, wide=mixed) if r else []
+    if mixed and fw:
+        widest = max(f["attrs"]["bucket_chunk"] for f in fw)
+        fw = [f for f in fw if f["attrs"]["bucket_chunk"] == widest]
+    by_bucket = defaultdict(list)
+    for f in fw:
+        by_bucket[bucket_of(f["attrs"])].append(f["dur"])
+    if not by_bucket:
+        return None
+    return 1e3 * ar.median(max(by_bucket.values(), key=len))
+
+
 def idle_share(ctx, cause: str):
     """Idle seconds of one cause ÷ the traced window, in percent."""
     r = _reduced(ctx)

@@ -1,4 +1,4 @@
-"""Median device time of the forward program at the most frequent [S, 256] bucket."""
+"""Median device time of the forward program at the most frequent bucket of the widest chunk the traced window ran, each execution matched to its dispatch by order."""
 
 from benchmark import readers
 

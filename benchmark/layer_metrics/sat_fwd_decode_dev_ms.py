@@ -1,4 +1,4 @@
-"""Median device time of the forward program at the most frequent [S, 1] bucket."""
+"""Median device time of the forward program at the traced window's most frequent [S, 1] bucket, each execution matched to its dispatch by order."""
 
 from benchmark import readers
 

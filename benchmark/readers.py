@@ -11,7 +11,7 @@ from __future__ import annotations
 from collections import Counter, defaultdict
 
 from . import arithmetic as ar
-from . import peaks, trace
+from . import dispatch_readers, peaks, trace
 
 #: the flash-attention forward and its two backward kernels, as named in
 #: ``ops/flash_attention.py``
@@ -131,9 +131,20 @@ def kv_blocks_peak_share(ctx):
 def forward_device_ms(ctx, mixed: bool):
     """Median device time of the paged forward's program at the most
     frequent [S, 1] (decode) or [S, max chunk] (mixed) bucket of the
-    traced window, from the trace's XLA Modules line."""
+    traced window. Each program execution of the trace's XLA Modules line
+    is matched to its ``ds:dispatch`` by order
+    (``dispatch_readers.forward_device_ms``): with a step in flight a
+    forward starts on the device about a step after its dispatch, so the
+    annotation that began nearest to a module's start is its neighbour's
+    wherever the bucket changes, and a window whose widest chunk forwards
+    each stand between two one-token steps had nothing to read. Only a
+    trace that cannot be paired by order (no ``ds:dispatch``: a program
+    from before the span, a recorded trace) is still read by nearness."""
     if ctx.trace is None:
         return None
+    by_order = dispatch_readers.forward_device_ms(ctx, mixed)
+    if by_order is not None:
+        return by_order
     t0, t1 = ctx.trace["window"]
     tags = Counter()
     for e in ctx.trace["host"]:

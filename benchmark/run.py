@@ -8,8 +8,10 @@ every shape, measures for ``--seconds``, checks the outputs, and prints as
 the last line of stdout one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced). With
 ``--trace 0`` the metrics are the cell's end-to-end metrics, with
-``--trace 1`` its per-layer metrics. What else a run learned (why it is
-not correct, compile seconds and cache hits, counters) is printed as an
+``--trace 1`` its per-layer metrics. Each number ``correct`` compared
+stands beside its limit under ``checks``, the line's last key, and in the
+last lines of stderr. What else a run learned (why it is not correct,
+compile seconds and cache hits, counters) is printed as an
 ``{"extra": …}`` line before it.
 """
 
@@ -124,8 +126,12 @@ def main(argv=None, root: str = mf.CHECKOUT, platform: str = "tpu") -> int:
              "compilations": compiles["count"],
              "compile_cache_hits": compiles["hits"],
              "counters": result["counters"]}
+    line["checks"] = {name: {"value": value, "limit": limit}
+                      for name, (value, limit) in result["checks"].items()}
     print(json.dumps({"extra": extra}), flush=True)
     print(json.dumps(line), flush=True)
+    for name, (value, limit) in result["checks"].items():
+        print(f"check {name}: {value} (limit {limit})", file=sys.stderr)
     return 0
 
 

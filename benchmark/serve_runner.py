@@ -62,11 +62,11 @@ def bucket_grid(engine):
             for c in pow2(engine.config.max_chunk_tokens)]
 
 
-def warm_up(engine) -> int:
+def warm_up(engine, block=None) -> int:
     """Run every shape the traffic can reach through ``engine.put``, so
-    that nothing compiles in the window: each [S, C] bucket once, and each
-    sequence count 1..max once (the slice of the logits to the real rows
-    is a small program of its own per count). KV blocks go back at once."""
+    that nothing compiles in the window: each [S, C] bucket once, each
+    sequence count 1..max once, then what the cell's block type adds
+    (below). KV blocks go back at once. Returns the ``put`` calls made."""
     uid = itertools.count(_OWN_UID)
     calls = 0
 
@@ -82,7 +82,15 @@ def warm_up(engine) -> int:
         put(s, c)
     for n in range(1, engine.config.max_ragged_sequence_count + 1):
         put(n, 1)
-    return calls
+    # a block type whose served decode reaches programs the grid does not
+    # (a forward that reads several rows, ``verify_width``, is a program
+    # of its own a width) runs them here: ``warm_up(engine, uids)`` of
+    # ``blocks/<block>.py``, fresh uids in, its ``put`` calls out. It is
+    # called from this frame, behind the grid, so the grid's programs keep
+    # the Python stack the compile cache keyed them under; the
+    # ``CompileWatch`` judges a hook that forgets a program as any run
+    own = getattr(block, "warm_up", None)
+    return calls + (int(own(engine, uid)) if own is not None else 0)
 
 
 # ---------------------------------------------------------------- traffic
@@ -165,47 +173,124 @@ def drain(fe, records, drain_s: float):
 
 # ------------------------------------------------------------ correctness
 
+def causal_replay(engine, uid, prompt, decode_steps: int):
+    """How a causal model generates, and the replay of a block type that
+    names none of its own: the prompt in chunks of ``max_chunk_tokens``,
+    then ``decode_steps`` steps, a step being one greedy token put back
+    alone, each reading the logits of the sequence's last position. One
+    view: the tokens as they stand at the end (a causal row sees nothing
+    behind it, so every earlier forward saw them so), and the rows
+    ``len(prompt) - 1 ... + decode_steps``."""
+    chunk = engine.config.max_chunk_tokens
+    got, tokens = [], list(prompt)
+    for at in range(0, len(prompt), chunk):
+        lg = engine.put([uid], [prompt[at:at + chunk]])
+    got.append(np.asarray(lg[0], np.float32))
+    for _ in range(decode_steps):
+        tokens.append(int(np.argmax(got[-1])))
+        got.append(np.asarray(engine.put([uid], [[tokens[-1]]])[0],
+                              np.float32))
+    first = len(prompt) - 1
+    return [(tokens, range(first, first + len(got)), got)]
+
+
 def check_logits(engine, params, info, sample, decode_steps: int,
                  tolerance: float, rms_tolerance: float) -> dict:
-    """A seeded sample of requests, prefill in chunks and then decode
-    through the cache, the engine's logits at every step against the full
-    forward of the configuration's block reference (``info["block"]``)
-    over the same tokens: the largest disagreement relative to the range,
-    and the RMS relative to the RMS (a lower precision shows there first)."""
+    """A seeded sample of requests, each run through the engine the way
+    its block type generates (``replay`` of ``blocks/<block>.py``, else
+    ``causal_replay``), and every logits row the engine gave held against
+    the configuration's block reference (``info["block"].logits``).
+
+    A replay returns **views**, one a forward whose logits it read:
+    ``(tokens, rows, got)`` — the whole token list as the model saw it at
+    that forward (placeholders such as mask tokens included), the
+    positions whose logits were read, and the engine's float32 logits for
+    them, ``[len(rows), vocab]``. The harness pads each view's tokens on
+    the right with zeros to one width, runs the reference once over them,
+    and compares ``want[rows]`` with ``got`` row by row: the largest
+    disagreement relative to the range, and the RMS relative to the RMS (a
+    lower precision shows there first); the worst of either is held to the
+    configuration's tolerances. The uid is the harness's and so is the
+    ``flush``; what a replay leaves behind fails ``blocks_back``.
+
+    What is not a number is never folded: a non-finite value in the
+    engine's logits fails the check. A reference row that is NaN
+    throughout is the block saying that it gives no answer there (a
+    routing decision within rounding of its edge: ``blocks/trinity.py``):
+    the position is masked out and counted (``unanswered``); any other
+    non-finite value of the reference fails. A check whose every row went
+    unanswered passes, as it always did, and says ``compared: 0``: with
+    12 rows a run and up to four in ten unanswered that is one run in some
+    tens of thousands, and a later PR whose change is sound should not be
+    refused for it; that a block's reference answers at all is held by
+    its rehearsal (``0 < max_rel_err``)."""
     import jax
 
     block, arch = info["block"], info["config"]["transformer_config"]
-    chunk = engine.config.max_chunk_tokens
-    width = -(-max(len(p) + decode_steps for p in sample) // 256) * 256
-    ref_fn = jax.jit(lambda p, t: block.logits(p, t, arch))
-    worst = worst_rms = 0.0
+    replay = getattr(block, "replay", causal_replay)
+    views = []
     for i, prompt in enumerate(sample):
         uid = _OWN_UID + (1 << 20) + i
-        got, tokens = [], list(prompt)
-        for at in range(0, len(prompt), chunk):
-            lg = engine.put([uid], [prompt[at:at + chunk]])
-        got.append(np.asarray(lg[0], np.float32))
-        for _ in range(decode_steps):
-            tokens.append(int(np.argmax(got[-1])))
-            got.append(np.asarray(engine.put([uid], [[tokens[-1]]])[0],
-                                  np.float32))
+        views += [(i, *view) for view in
+                  replay(engine, uid, list(prompt), decode_steps)]
         engine.flush(uid)
+    record = {"tolerance": tolerance, "rms_tolerance": rms_tolerance,
+              "sampled": len(sample), "steps_each": decode_steps + 1,
+              "views": len(views)}
+    if not views:
+        return dict(record, ok=False, why="the replay read no logits")
+    width = -(-max(len(tokens) for _, tokens, _, _ in views) // 256) * 256
+    ref_fn = jax.jit(lambda p, t: block.logits(p, t, arch))
+    worst = worst_rms = 0.0
+    compared = unanswered = 0
+    for i, tokens, rows, got in views:
         padded = np.zeros((width,), np.int32)
         padded[:len(tokens)] = tokens
         want = np.asarray(ref_fn(params, padded))
-        for step, g in enumerate(got):
-            w = want[len(prompt) - 1 + step]
+        for row, g in zip(rows, got, strict=True):
+            w = want[row]
             if not np.isfinite(g).all():
-                return {"ok": False, "why": f"sample {i}: logits not finite"}
+                return dict(record, ok=False,
+                            why=f"sample {i}: logits not finite")
+            if np.isnan(w).all():
+                unanswered += 1
+                continue
+            if not np.isfinite(w).all():
+                return dict(record, ok=False, why=f"sample {i}: the "
+                            f"reference's logits at {row} are not finite")
             worst = max(worst, ar.max_rel_err(g, w))
             worst_rms = max(worst_rms, ar.rms_rel_err(g, w))
+            compared += 1
     ok = worst <= tolerance and worst_rms <= rms_tolerance
-    return {"ok": ok, "max_rel_err": worst, "tolerance": tolerance,
-            "rms_rel_err": worst_rms, "rms_tolerance": rms_tolerance,
-            "sampled": len(sample), "steps_each": decode_steps + 1,
-            "why": None if ok else
-            f"engine vs reference logits: max {worst:.4f} of range "
-            f"(<= {tolerance}), rms {worst_rms:.4f} (<= {rms_tolerance})"}
+    return dict(
+        record, ok=ok, max_rel_err=worst, rms_rel_err=worst_rms,
+        compared=compared, unanswered=unanswered, why=None if ok else
+        f"engine vs reference logits: max {worst:.4f} of range "
+        f"(<= {tolerance}), rms {worst_rms:.4f} (<= {rms_tolerance})")
+
+
+def checked_sample(records, check: dict, seed: int) -> list:
+    """The prompts the check replays: ``check["requests"]`` of the requests
+    that finished as asked, drawn from the seed among those whose prompt
+    has at least ``min_prompt_tokens`` tokens (0 where the configuration
+    names none) and at most ``max_prompt_tokens``."""
+    least = check.get("min_prompt_tokens", 0)
+    ok_records = [r for r in records if r.ok
+                  and least <= len(r.req.prompt) <= check["max_prompt_tokens"]]
+    if not ok_records:
+        return []
+    picks = np.random.default_rng([seed, 0x636b]).choice(
+        len(ok_records), size=min(check["requests"], len(ok_records)),
+        replace=False)
+    return [ok_records[i].req.prompt for i in picks]
+
+
+def causal_qk_pairs(new, seen) -> int:
+    """Query-key pairs of a put's rows under a causal mask: each of a
+    row's ``new`` positions sees the ``seen`` before the put and those of
+    the put up to itself. A block type whose mask is another gives its own
+    ``qk_pairs(new, seen)`` (int64 arrays in, an integer out)."""
+    return int((new * seen + new * (new + 1) // 2).sum())
 
 
 # -------------------------------------------------------------------- run
@@ -214,15 +299,16 @@ def run(info: dict, args, watch, process_t0: float) -> dict:
     mix, wl = info["traffic"], info["workload"]
     traced = bool(args.trace)
     cfg, params, engine = build(info, args.seed)
-    kv_blocks = engine.config.kv_blocks
+    kv_blocks, block = engine.config.kv_blocks, info["block"]
     probe = Probe()
     # warm up first, instrument after: a wrapper is one more frame on the
     # Python stack, the stack is in the locations a Mosaic kernel carries
     # into the compile cache's key, and a traced run would compile every
     # program again
-    warm_calls = warm_up(engine)
+    warm_calls = warm_up(engine, block)
     if traced:
-        _instrument_engine(probe, engine)
+        _instrument_engine(probe, engine,
+                           getattr(block, "qk_pairs", causal_qk_pairs))
 
     from deepspeed_tpu.serving import ServingConfig, ServingFrontend
 
@@ -267,23 +353,22 @@ def run(info: dict, args, watch, process_t0: float) -> dict:
     finally:
         fe.shutdown(drain=False, timeout=30)
 
-    rng = np.random.default_rng([args.seed, 0x636b])
     check = info["config"]["check"]
-    ok_records = [r for r in records if r.ok
-                  and len(r.req.prompt) <= check["max_prompt_tokens"]]
-    picks = rng.choice(len(ok_records),
-                       size=min(check["requests"], len(ok_records)),
-                       replace=False) if ok_records else []
-    logits = check_logits(engine, params, info,
-                          [ok_records[i].req.prompt for i in picks],
+    sample = checked_sample(records, check, args.seed)
+    logits = check_logits(engine, params, info, sample,
                           check["decode_steps"], check["tolerance"],
                           check["rms_tolerance"]) \
-        if len(picks) else {"ok": False, "why": "no finished request"}
+        if sample else {"ok": False, "why": "no finished request"}
     blocks_back = blocks_back and \
         engine.state_manager.available_blocks == kv_blocks
+    # a recurrent layer's state slots (none without one): every sequence
+    # of the window and of the check has been flushed by now
+    slots_held = int(engine.occupancy()["state_slots_used"])
     why = [w for w in (
         logits.get("why"),
         None if blocks_back else "KV blocks were not all returned",
+        None if slots_held == 0 else
+        f"{slots_held} state slots were not returned",
         None if compiles_in_window == 0 else
         f"{compiles_in_window} compilations inside the window",
         None if in_window else "no request was due in the window") if w]
@@ -294,9 +379,20 @@ def run(info: dict, args, watch, process_t0: float) -> dict:
         "window": (w0, w1), "records": records, "xplane": xplane,
         "trace_marks": trace.marks if trace is not None else None,
         "probe": probe, "program_spans": program_spans,
+        # each number ``correct`` compared, beside its limit
+        "checks": {
+            "logits_max_rel_err": (logits.get("max_rel_err"),
+                                   check["tolerance"]),
+            "logits_rms_rel_err": (logits.get("rms_rel_err"),
+                                   check["rms_tolerance"]),
+            "kv_blocks_missing": (
+                kv_blocks - engine.state_manager.available_blocks, 0),
+            "state_slots_held": (slots_held, 0),
+            "compiles_in_window": (compiles_in_window, 0)},
         "counters": {"kv_blocks": kv_blocks,
                      "compiles_in_window": compiles_in_window,
                      "unfinished_at_drain": unfinished,
+                     "state_slots_held": slots_held,
                      "warm_up_calls": warm_calls,
                      "requests_sent": len(records),
                      "longest_silence_ms": 1e3 * ar.longest_silence(
@@ -322,10 +418,11 @@ def _latency_table(records) -> dict:
     return table
 
 
-def _instrument_engine(probe: Probe, engine) -> None:
+def _instrument_engine(probe: Probe, engine, qk_pairs=causal_qk_pairs) -> None:
     """Spans round ``engine.put`` and the paged forward (with its bucket
-    shape and valid tokens), and the pool's free blocks sampled after
-    every put."""
+    shape, its valid tokens and its query-key pairs as the block type's
+    mask counts them), and the pool's free blocks sampled after every
+    put."""
     def forward_attrs(params, kv, tokens, *rest):
         s, c = tokens.shape
         batch = engine.batch           # the host-side arrays of this put
@@ -336,7 +433,7 @@ def _instrument_engine(probe: Probe, engine) -> None:
                 "valid_tokens": int(new.sum()),
                 # keys a row's queries may see, and query-key pairs
                 "kv_read_tokens": int((seen + new).sum()),
-                "qk_pairs": int((new * seen + new * (new + 1) // 2).sum())}
+                "qk_pairs": int(qk_pairs(new, seen))}
 
     probe.wrap(engine.paged, "forward", "forward", attrs=forward_attrs)
     probe.wrap(engine, "put", "put", after=lambda: probe.sample(

@@ -57,7 +57,7 @@ def main(argv=None) -> int:
     from deepspeed_tpu.serving import ServingConfig, ServingFrontend
 
     cfg, params, engine = sr.build(info, args.seed)
-    sr.warm_up(engine)
+    sr.warm_up(engine, info["block"])
     fe = ServingFrontend([engine],
                          ServingConfig(**info["workload"].get("serving", {})))
     try:

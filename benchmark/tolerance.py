@@ -2,11 +2,14 @@
 from, measured and not asserted:
 
     python3 -m benchmark.tolerance --config <name> [--seed 1] [--prompt-tokens 200]
+                                   [--variants served,fp8_weights]
 
 runs the cell's own correctness check (``serve_runner.check_logits``: the
-engine through ``engine.put``, prefill then decode through the cache,
-against the reference of the block the configuration names,
-``blocks/<block>.py``) on one seeded prompt, three times:
+engine through ``engine.put`` the way the configuration's block type
+generates, its ``replay``, against the reference of the same block,
+``blocks/<block>.py``) on one seeded prompt, three times, or as many as
+``--variants`` names (a build that does not fit the device is left out:
+the float32 one holds 4 bytes a parameter), in this order:
 
 ``float32``       engine and weights in float32. What is left is what the
                   two implementations do differently — it must be tiny,
@@ -38,8 +41,12 @@ from . import manifest as mf
 from . import serve_runner as sr
 
 
-def variants(info: dict, seed: int, prompt, kv_blocks: int):
-    """(name, check_logits record) for the three builds."""
+VARIANTS = ("float32", "served", "fp8_weights")
+
+
+def variants(info: dict, seed: int, prompt, kv_blocks: int,
+             wanted=VARIANTS):
+    """(name, check_logits record) for the builds named in ``wanted``."""
     import jax
     import jax.numpy as jnp
 
@@ -55,11 +62,17 @@ def variants(info: dict, seed: int, prompt, kv_blocks: int):
                                check["decode_steps"], check["tolerance"],
                                check["rms_tolerance"])
 
-    cfg, params, engine = sr.build(info, seed, {"dtype": jnp.float32})
-    yield "float32", measure(engine, params)
-    del params, engine
+    if "float32" in wanted:
+        cfg, params, engine = sr.build(info, seed, {"dtype": jnp.float32})
+        yield "float32", measure(engine, params)
+        del params, engine
+    if set(wanted) <= {"float32"}:
+        return
     cfg, params, engine = sr.build(info, seed)
-    yield "served", measure(engine, params)
+    if "served" in wanted:
+        yield "served", measure(engine, params)
+    if "fp8_weights" not in wanted:
+        return
 
     def through_fp8(a):
         wide = a.astype(jnp.float32)
@@ -78,7 +91,12 @@ def main(argv=None, root: str = mf.CHECKOUT) -> int:
     ap.add_argument("--config", required=True)
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--prompt-tokens", type=int, default=200)
+    ap.add_argument("--variants", default=",".join(VARIANTS),
+                    help="which builds to measure, of " + ", ".join(VARIANTS))
     args = ap.parse_args(argv)
+    wanted = args.variants.split(",")
+    if not wanted or set(wanted) - set(VARIANTS):
+        ap.error(f"--variants: a list of {', '.join(VARIANTS)}")
     manifest = mf.load(root)
     cell = next(w["name"] for w in manifest["workloads"]
                 if w["config"] == args.config)
@@ -92,7 +110,7 @@ def main(argv=None, root: str = mf.CHECKOUT) -> int:
     prompt = np.random.default_rng([args.seed, 0x746f]).integers(
         0, vocab, size=args.prompt_tokens).tolist()
     kv_blocks = -(-(args.prompt_tokens + steps) // block) + 1
-    for name, record in variants(info, args.seed, prompt, kv_blocks):
+    for name, record in variants(info, args.seed, prompt, kv_blocks, wanted):
         print(json.dumps({
             "config": args.config, "variant": name,
             "platform": jax.devices()[0].platform,

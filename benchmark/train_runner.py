@@ -138,6 +138,9 @@ def run(info: dict, args, watch, process_t0: float) -> dict:
         "tokens_per_step": tokens_per_step, "losses": losses,
         "xplane": xplane, "probe": probe, "program_spans": [],
         "trace_marks": trace.marks if trace is not None else None,
+        # each number ``correct`` compared, beside its limit
+        "checks": {"loss_rel_err": (check["rel_err"], check["tolerance"]),
+                   "compiles_in_window": (compiles_in_window, 0)},
         "counters": {"compiles_in_window": compiles_in_window,
                      "loss_check": check, "steps": len(ends),
                      "first_losses": losses[:3], "last_losses": losses[-3:]},

@@ -119,7 +119,10 @@ def rehearse(root, capsys, cell, trace, seconds="1.5", seed="5"):
 
 def check_line(line, manifest, cell, group):
     assert set(line) <= {"correct", "attempted", "failed", "metrics",
-                         "device", "breakdown"}
+                         "device", "breakdown", "checks"}
+    # each number compared beside its limit, under the line's last key
+    assert list(line)[-1] == "checks" and "compiles_in_window" in line["checks"]
+    assert all(set(c) == {"value", "limit"} for c in line["checks"].values())
     assert line["device"]["platform"] == "cpu"      # says where it ran
     wanted = {m["name"]: m for m in mf.metrics_for(manifest, group, cell)}
     assert set(line["metrics"]) <= set(wanted)

@@ -9,7 +9,7 @@ import pytest
 
 from benchmark import dispatch_readers as dr
 from benchmark import manifest as mf
-from benchmark import scopes
+from benchmark import readers, scopes, trace
 
 DEV, HOST = "/device:TPU:0", "/host:CPU"
 NEW_METRICS = (
@@ -459,6 +459,66 @@ def test_steps_share_counts_the_steps_that_dispatched(monkeypatch):
     assert dr.steps_share(ctx, "starved") is None
 
 
+# ------------------------------------------ the two fwd_*_dev_ms readers
+
+def _summary_of(events):
+    """What ``trace.summarize`` keeps for ``readers.forward_device_ms``:
+    the probe's ``bench:forward[SxC]`` annotation beside each dispatch,
+    and the forward modules."""
+    host = [{"name": "bench:forward[{bucket_seqs}x{bucket_chunk}]".format(
+        **d["stats"]), "start": d["start"], "dur": d["dur"]}
+            for d in dr.dispatches(events)]
+    modules = [dict(m, device=DEV) for m in dr.forward_modules(events)]
+    return {"window": scopes._window(events), "host": host,
+            "modules": modules}
+
+
+def test_fwd_dev_ms_pairs_by_order_where_the_nearest_annotation_is_the_neighbours(
+        monkeypatch):
+    """``fwd_mixed_dev_ms`` / ``fwd_decode_dev_ms`` (PR 49, the check's
+    refusal: ``trinity-large-preview.mixedctx``, seed 1940209189, printed
+    no ``fwd_mixed_dev_ms``). With a step in flight each chunk forward
+    starts on the device while the host is already dispatching the next
+    one-token step, so by nearness the ``[1, 64]`` tag finds no module at
+    all and the 9 ms chunks are booked under ``[4, 1]``."""
+    events = simulate(SCHEDULE)
+    summary = _summary_of(events)
+    assert trace.module_seconds(summary, "forward", tag="1x64") == []
+    assert sorted(trace.module_seconds(summary, "forward", tag="4x1"))[-2:] \
+        == [9e-3, 9e-3]
+    ctx = Ctx(events, [], summary["window"], monkeypatch=monkeypatch)
+    ctx.trace = summary
+    assert readers.forward_device_ms(ctx, mixed=True) == pytest.approx(9.0)
+    assert readers.forward_device_ms(ctx, mixed=False) == pytest.approx(4.0)
+    assert dr.forward_device_ms(ctx, mixed=True) == pytest.approx(9.0)
+    # the decode bucket is the most frequent one: [4, 1] eight times of
+    # 4 ms against [8, 1] once of 5 ms
+    slower = simulate([[(8, 1, 5e-3)]] * 3 + [DECODE])
+    ctx = Ctx(slower, [], scopes._window(slower), monkeypatch=monkeypatch)
+    assert dr.forward_device_ms(ctx, mixed=False) == pytest.approx(5.0)
+    assert dr.forward_device_ms(ctx, mixed=True) is None
+    # a trace without ds:dispatch cannot be paired by order: read by
+    # nearness as before, whatever that finds
+    bare = [e for e in events if e["name"] != "ds:dispatch"]
+    ctx = Ctx(bare, [], summary["window"], monkeypatch=monkeypatch)
+    ctx.trace = summary
+    assert dr.forward_device_ms(ctx, mixed=True) is None
+    assert readers.forward_device_ms(ctx, mixed=True) is None
+    assert readers.forward_device_ms(ctx, mixed=False) == pytest.approx(4.0)
+
+
+def test_fwd_dev_ms_on_the_recorded_chip_trace(monkeypatch):
+    """The recorded stretch of ``qwen3-next-80b-a3b.longdoc``: four
+    ``[1, 1024]`` chunk forwards (the ``[1, 512]`` one is narrower and
+    not the mixed bucket), five ``[2, 1]`` steps against two ``[4, 1]``."""
+    events = scopes.load_recorded(RECORDED)
+    ctx = Ctx(events, [], scopes._window(events), monkeypatch=monkeypatch)
+    assert dr.forward_device_ms(ctx, mixed=True) == \
+        pytest.approx((29.831 + 30.260) / 2, abs=1e-3)
+    assert dr.forward_device_ms(ctx, mixed=False) == \
+        pytest.approx(1.703, abs=1e-3)
+
+
 # --------------------------------------------------------- nothing to read
 
 @pytest.mark.parametrize("name", NEW_METRICS)
@@ -491,8 +551,11 @@ def test_the_manifest_lists_the_new_metrics_behind_the_old():
     manifest = mf.load()
     mf.validate(manifest)
     names = [m["name"] for m in manifest["per_layer"]]
-    assert tuple(names[-len(NEW_METRICS):]) == NEW_METRICS
-    assert names[-len(NEW_METRICS) - 1] == "mla_prefill_roofline"
+    # appended behind what PR 36 left last, in one piece; what later PRs
+    # append stands behind them
+    first = names.index(NEW_METRICS[0])
+    assert tuple(names[first:first + len(NEW_METRICS)]) == NEW_METRICS
+    assert names[first - 1] == "mla_prefill_roofline"
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     for name in NEW_METRICS:
         m = by_name[name]

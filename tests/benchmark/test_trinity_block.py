@@ -62,8 +62,10 @@ def twin():
 def test_the_manifest_validates_with_the_new_entries():
     manifest, info = real()
     mf.validate(manifest)
-    assert len(manifest["workloads"]) == 6
-    assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
+    # (no pin on the totals: a later PR appends, and may not edit this file)
+    assert len(manifest["workloads"]) >= 6
+    assert sum(w["chips"] == 4 for w in manifest["workloads"]) \
+        <= max(1, len(manifest["workloads"]) // 4)
     assert info["block"].__name__.endswith("trinity")
     assert info["traffic"]["loop"] == "open"
     assert info["traffic"]["generator"] == "stratified"
@@ -83,9 +85,11 @@ def test_the_manifest_validates_with_the_new_entries():
     assert doc - {"paged_attn_roofline"} <= mine
     assert {m["name"] for m in mf.metrics_for(manifest, "end_to_end", CELL)} \
         == {"ttft_p90_ms", "tpot_p90_ms", "setup_s"}
+    # listed for this cell first; a later cell that reads the same names
+    # is appended behind it
     for m in manifest["per_layer"]:
         if m["name"] in NEW_READERS:
-            assert m["workloads"] == [CELL]
+            assert m["workloads"][0] == CELL
 
 
 def test_the_configuration_is_the_catalog_rows_but_for_what_it_reduces():
@@ -535,12 +539,12 @@ def test_logits_answer_exactly_where_every_decision_is_well_conditioned(
 
 def test_an_unanswered_position_is_not_compared_and_an_answered_one_is(
         monkeypatch):
-    """Through the harness's own ``check_logits``: it folds its comparisons
-    with ``max``, which keeps the running worst when a comparison is NaN.
-    An engine that is wrong at every position (its selection bias dropped)
-    is caught as long as one compared position is answered — and a limit
-    so wide that none is would pass anything, which is why ``TIE_MARGIN``
-    is a few rounding errors and not more."""
+    """Through the harness's own ``check_logits``: a reference row that is
+    NaN throughout is masked out and counted. An engine that is wrong at
+    every position (its selection bias dropped) is caught as long as one
+    compared position is answered — and a limit so wide that none is
+    would pass anything (``compared: 0``), which is why ``TIE_MARGIN`` is
+    a few rounding errors and not more."""
     import jax
 
     from benchmark import serve_runner as sr
@@ -578,6 +582,10 @@ def test_an_unanswered_position_is_not_compared_and_an_answered_one_is(
         params, np.asarray(tokens + [0] * 10, np.int32), arch)[1])[69:72]
         .tolist())
     # one position unanswered, two compared: still caught
-    assert not check((seen[0] + seen[1]) / 2)["ok"]
+    record = check((seen[0] + seen[1]) / 2)
+    assert not record["ok"]
+    assert (record["compared"], record["unanswered"]) == (2, 1)
     # none answered: nothing is compared, and that passes
-    assert check(seen[-1] * 2)["ok"]
+    record = check(seen[-1] * 2)
+    assert record["ok"]
+    assert (record["compared"], record["unanswered"]) == (0, 3)
