@@ -2,8 +2,11 @@
 (``TransformerConfig.layer_pattern``: one period of mixer kinds, scanned
 one period an iteration — in serving too, however few the periods: two
 periods laid out inline ran slower than the loop in every program
-measured, PERF.md section 6, PR 46), each followed by the same sparse
-FFN.
+measured, PERF.md section 6, PR 46). A position of the period is a mixer
+followed by the FFN, a mixer alone (``layer_ffn`` False there) or an FFN
+alone (None in the pattern): one norm and one residual add for each part
+it has, so a model that counts its mixers and its FFNs as layers of
+their own is a period of such positions.
 
 - the mixers: one module a family under ``models/mixers/``, each kind
   described there (its weights, its cache, its two forwards, its
@@ -11,7 +14,10 @@ FFN.
   model's kinds and names none.
 - the FFN: dropless top-k experts over a held range plus a shared expert
   (``moe/grouped.py``): softmax or sigmoid scores, a selection bias
-  outside the weights, the shared expert under a sigmoid gate or bare.
+  outside the weights, the shared expert under a sigmoid gate or bare;
+  gated SiLU experts or the ungated ``relu2`` (``moe_activation``); the
+  routed experts on the model's width or in a latent between two
+  projections (``moe_latent_size``).
   ``lead_layers`` run before the scanned periods with a dense MLP in its
   place, and so does every layer of a model without experts
   (``moe_num_experts`` 0); ``sandwich_norm`` puts a norm behind the mixer
@@ -21,7 +27,7 @@ FFN.
 (serving) both run their layers through ``run_period``; only where the
 mixer's cache lives differs (a kind's ``reference`` and ``paged``).
 Scopes follow ``docs/OBSERVABILITY.md``: ``attn_norm``, the kind's own
-(its module says), and ``mlp`` ⊃ ``router``, ``experts``,
+(its module says), and ``mlp`` ⊃ ``router``, ``latent_proj``, ``experts``,
 ``shared_expert`` or ``dense_mlp``.
 """
 
@@ -114,10 +120,20 @@ class _Draw:
                 ).astype(jnp.float32)
 
 
-def init_slot(cfg, kind: str, key, periods: int, dense: bool = False):
-    """One position of the period: its mixer's and its FFN's weights,
-    stacked over the periods. ``dense``: a lead layer, whose FFN is the
-    dense MLP."""
+def _norm_names(cfg, kind, ffn: bool):
+    """A position's norm gains: one (two under ``sandwich_norm``) for
+    each part it has."""
+    parts = (("attn",) if kind is not None else ()) + (("mlp",) if ffn
+                                                       else ())
+    return [f"{pre}{part}_norm_w" for part in parts
+            for pre in (("", "post_") if cfg.sandwich_norm else ("",))]
+
+
+def init_slot(cfg, kind, key, periods: int, dense: bool = False,
+              ffn: bool = True):
+    """One position of the period: its mixer's (``kind`` None: it has
+    none) and, with ``ffn``, its FFN's weights, stacked over the
+    periods. ``dense``: a lead layer, whose FFN is the dense MLP."""
     h, P = cfg.hidden_size, periods
     w = _Draw(cfg, key, periods)
     out_std = w.out_std
@@ -126,10 +142,11 @@ def init_slot(cfg, kind: str, key, periods: int, dense: bool = False):
     def gain(*shape):
         return fill((P,) + shape, jnp.float32)
 
-    lp = {"attn_norm_w": gain(h), "mlp_norm_w": gain(h)}
-    if cfg.sandwich_norm:
-        lp.update(post_attn_norm_w=gain(h), post_mlp_norm_w=gain(h))
-    lp.update(KINDS[kind].init(cfg, w, gain))
+    lp = {name: gain(h) for name in _norm_names(cfg, kind, ffn)}
+    if kind is not None:
+        lp.update(KINDS[kind].init(cfg, w, gain))
+    if not ffn:
+        return lp
     if dense or not cfg.moe_num_experts:
         m = cfg.intermediate_size
         lp.update(w_in=w((h, m)), w_gate=w((h, m)), w_out=w((m, h), out_std))
@@ -137,45 +154,62 @@ def init_slot(cfg, kind: str, key, periods: int, dense: bool = False):
     n_held = cfg.moe_held_experts[1] if cfg.moe_held_experts \
         else cfg.moe_num_experts
     m = cfg.moe_intermediate_size or cfg.intermediate_size
-    lp.update(router_wg=w((h, cfg.moe_num_experts), 1.0 / math.sqrt(h)),
-              w_in=w((n_held, h, m)), w_gate=w((n_held, h, m)),
-              w_out=w((n_held, m, h), out_std))
+    gated = cfg.moe_activation == "silu"
+    # the experts' width: the model's, or the latent's
+    he = cfg.moe_latent_size or h
+    lp["router_wg"] = w((h, cfg.moe_num_experts), 1.0 / math.sqrt(h))
+    lp["w_in"] = w((n_held, he, m))
+    if gated:
+        lp["w_gate"] = w((n_held, he, m))
+    lp["w_out"] = w((n_held, m, he), out_std)
     if cfg.moe_select_bias:
         lp["router_b"] = jnp.zeros((P, cfg.moe_num_experts), jnp.float32)
     ms = cfg.moe_shared_intermediate_size
     if ms:
-        lp.update(shared_w_in=w((h, ms)), shared_w_gate=w((h, ms)),
-                  shared_w_out=w((ms, h), out_std))
+        lp["shared_w_in"] = w((h, ms))
+        if gated:
+            lp["shared_w_gate"] = w((h, ms))
+        lp["shared_w_out"] = w((ms, h), out_std)
         if cfg.moe_shared_gate:
             lp["shared_gate_w"] = w((h, 1), 1.0 / math.sqrt(h))
+    if cfg.moe_latent_size:
+        lp.update(latent_w_in=w((h, he)), latent_w_out=w((he, h), out_std))
     return lp
 
 
-def slot_specs(cfg, kind: str, dense: bool = False):
+def slot_specs(cfg, kind, dense: bool = False, ffn: bool = True):
     """Logical sharding axes of ``init_slot``'s tree."""
-    lp = {"attn_norm_w": spec("layers", "embed"),
-          "mlp_norm_w": spec("layers", "embed")}
-    if cfg.sandwich_norm:
-        lp.update(post_attn_norm_w=spec("layers", "embed"),
-                  post_mlp_norm_w=spec("layers", "embed"))
-    lp.update(KINDS[kind].specs(cfg))
+    lp = {name: spec("layers", "embed")
+          for name in _norm_names(cfg, kind, ffn)}
+    if kind is not None:
+        lp.update(KINDS[kind].specs(cfg))
+    if not ffn:
+        return lp
     if dense or not cfg.moe_num_experts:
         lp.update(w_in=spec("layers", "embed", "mlp"),
                   w_gate=spec("layers", "embed", "mlp"),
                   w_out=spec("layers", "mlp", "embed"))
         return lp
+    gated = cfg.moe_activation == "silu"
+    # (experts in a latent: its width is no axis of the mesh)
+    wide = None if cfg.moe_latent_size else "embed"
     lp.update(router_wg=spec("layers", "embed", None),
-              w_in=spec("layers", "expert", "embed", "mlp"),
-              w_gate=spec("layers", "expert", "embed", "mlp"),
-              w_out=spec("layers", "expert", "mlp", "embed"))
+              w_in=spec("layers", "expert", wide, "mlp"),
+              w_out=spec("layers", "expert", "mlp", wide))
+    if gated:
+        lp["w_gate"] = spec("layers", "expert", wide, "mlp")
     if cfg.moe_select_bias:
         lp["router_b"] = spec("layers", None)
     if cfg.moe_shared_intermediate_size:
         lp.update(shared_w_in=spec("layers", "embed", "mlp"),
-                  shared_w_gate=spec("layers", "embed", "mlp"),
                   shared_w_out=spec("layers", "mlp", "embed"))
+        if gated:
+            lp["shared_w_gate"] = spec("layers", "embed", "mlp")
         if cfg.moe_shared_gate:
             lp["shared_gate_w"] = spec("layers", "embed", None)
+    if cfg.moe_latent_size:
+        lp.update(latent_w_in=spec("layers", "embed", None),
+                  latent_w_out=spec("layers", None, "embed"))
     return lp
 
 
@@ -185,30 +219,38 @@ def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
                max_rows=None, transform=None):
     """A run of layers on x [B, T, H] — one period (``kinds`` None: the
     pattern) or the lead layers (``dense``) — each position's norm, its
-    mixer, the FFN and the residual adds. ``slots``: the weights, one
-    tree a position. ``mixers[kind](h1, lp, i)`` -> the mixer's output
-    for the i-th layer of its kind in the run: where the cache lives is
-    the caller's (training keeps none, serving paged pools and state
-    slots). Returns (x, summed aux loss)."""
+    mixer, the FFN and the residual adds (a position of the pattern may
+    lack its mixer or its FFN: ``cfg.layer_ffn``). ``slots``: the
+    weights, one tree a position. ``mixers[kind](h1, lp, i)`` -> the
+    mixer's output for the i-th layer of its kind in the run: where the
+    cache lives is the caller's (training keeps none, serving paged pools
+    and state slots). Returns (x, summed aux loss)."""
     scope = jax.named_scope
     aux = jnp.zeros((), jnp.float32)
+    # the pattern's positions say which carry an FFN; a lead layer does
+    ffns = cfg.layer_ffn if kinds is None else None
     kinds = cfg.layer_pattern if kinds is None else kinds
+    ffns = ffns or (True,) * len(kinds)
     seen = {kind: 0 for kind in KINDS}
     scaled = (lambda y: y) if cfg.residual_scale == 1.0 else (
         lambda y: y * jnp.asarray(cfg.residual_scale, y.dtype))
-    for kind, lp in zip(kinds, slots):
+    for kind, ffn, lp in zip(kinds, ffns, slots):
         if transform is not None:
             lp = transform(lp)
-        with scope("attn_norm"):
-            h1 = block_norm(cfg, x, lp["attn_norm_w"])
-        with scope(KINDS[kind].scope) if KINDS[kind].scope \
-                else contextlib.nullcontext():
-            y = mixers[kind](h1, lp, seen[kind])
-        seen[kind] += 1
+        if kind is not None:
+            with scope("attn_norm"):
+                h1 = block_norm(cfg, x, lp["attn_norm_w"])
+            with scope(KINDS[kind].scope) if KINDS[kind].scope \
+                    else contextlib.nullcontext():
+                y = mixers[kind](h1, lp, seen[kind])
+            seen[kind] += 1
         with scope("mlp"):      # norms, FFN and the residual adds
-            if cfg.sandwich_norm:
-                y = block_norm(cfg, y, lp["post_attn_norm_w"])
-            x = x + scaled(y)
+            if kind is not None:
+                if cfg.sandwich_norm:
+                    y = block_norm(cfg, y, lp["post_attn_norm_w"])
+                x = x + scaled(y)
+            if not ffn:
+                continue
             h2 = block_norm(cfg, x, lp["mlp_norm_w"])
             if dense or not cfg.moe_num_experts:
                 f, a = dense_ffn(cfg, h2, lp), 0.0
@@ -242,9 +284,13 @@ def dense_ffn(cfg, h2, lp):
 
 def moe_ffn(cfg, h2, lp, valid=None, max_rows=None):
     """The sparse FFN on its normed input [B, T, H]: the held experts'
-    part of the top-k sum plus the shared expert. ``valid`` [B, T]:
-    padding reaches no expert; ``max_rows`` bounds the valid rows.
-    Returns (y, aux_loss)."""
+    part of the top-k sum plus the shared expert. The experts are gated
+    SiLU ones or, with ``moe_activation`` "relu2", ``down(relu(up)²)``,
+    and so is the shared expert; with ``moe_latent_size`` the routed
+    ones run between ``latent_w_in`` and ``latent_w_out`` (the second
+    on the held experts' partial sum), the router and the shared expert
+    on the full width. ``valid`` [B, T]: padding reaches no expert;
+    ``max_rows`` bounds the valid rows. Returns (y, aux_loss)."""
     from ..moe.grouped import dropless_moe_mlp
     B, T, H = h2.shape
     dt = cfg.dtype
@@ -253,18 +299,30 @@ def moe_ffn(cfg, h2, lp, valid=None, max_rows=None):
     with jax.named_scope("router"):
         logits = rows.astype(jnp.float32) \
             @ lp["router_wg"].astype(jnp.float32)
+    routed = rows
+    if cfg.moe_latent_size:
+        with jax.named_scope("latent_proj"):
+            routed = _linear(rows, lp["latent_w_in"], None, dt)
     with jax.named_scope("experts"):
         y, l_aux = dropless_moe_mlp(
-            rows, logits, lp["w_in"], lp["w_out"], lp["w_gate"],
-            activation="silu", dtype=dt, top_k=cfg.moe_top_k,
+            routed, logits, lp["w_in"], lp["w_out"], lp.get("w_gate"),
+            activation=cfg.moe_activation, dtype=dt, top_k=cfg.moe_top_k,
             renormalize=cfg.moe_norm_topk, held=cfg.moe_held_experts,
             valid=flat_valid, max_rows=max_rows,
             score_func=cfg.moe_score_func, select_bias=lp.get("router_b"),
             route_scale=cfg.moe_route_scale)
+    if cfg.moe_latent_size:
+        with jax.named_scope("latent_proj"):
+            y = _linear(y, lp["latent_w_out"], None, dt)
     if cfg.moe_shared_intermediate_size:
         with jax.named_scope("shared_expert"):
-            s = jax.nn.silu(_linear(rows, lp["shared_w_gate"], None, dt)) \
-                * _linear(rows, lp["shared_w_in"], None, dt)
+            if cfg.moe_activation == "relu2":
+                s = jnp.square(jax.nn.relu(
+                    _linear(rows, lp["shared_w_in"], None, dt)))
+            else:
+                s = jax.nn.silu(_linear(rows, lp["shared_w_gate"], None,
+                                        dt)) \
+                    * _linear(rows, lp["shared_w_in"], None, dt)
             s = _linear(s, lp["shared_w_out"], None, dt)
             if cfg.moe_shared_gate:
                 gate = jax.nn.sigmoid(_linear(rows, lp["shared_gate_w"],

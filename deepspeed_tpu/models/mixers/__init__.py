@@ -12,7 +12,7 @@ from __future__ import annotations
 from types import MappingProxyType
 from typing import Dict, Mapping, Tuple
 
-from . import attention, block_sparse, gdn, latent, lightning
+from . import attention, block_sparse, gdn, latent, lightning, mamba2
 from .base import Fwd, Mixer  # noqa: F401
 
 #: read-only: a kind is added here, in the source, and nowhere else
@@ -25,12 +25,14 @@ KINDS: Mapping[str, Mixer] = MappingProxyType({
     "latent_window": latent.LATENT_WINDOW,
     "lightning": lightning.LIGHTNING,
     "block_sparse": block_sparse.BLOCK_SPARSE,
+    "mamba2": mamba2.MAMBA2,
 })
 
 
 def kinds_of(cfg) -> Tuple[str, ...]:
     """The kinds a model has layers of, in the registry's order (none
-    for a model that is not a hybrid block)."""
+    for a model that is not a hybrid block; a position of the pattern
+    that is None is an FFN alone and has none)."""
     if cfg.layer_pattern is None:
         return ()
     have = set(cfg.layer_pattern + cfg.lead_layers)

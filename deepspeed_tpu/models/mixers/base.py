@@ -17,7 +17,11 @@ from jax.experimental.layout import Layout, with_layout_constraint
 @dataclasses.dataclass(frozen=True)
 class Mixer:
     """One mixer kind, as plain functions. ``cfg`` is the model's
-    ``TransformerConfig`` everywhere.
+    ``TransformerConfig`` everywhere. A kind is a position's mixer and
+    knows nothing of what follows it: the position may carry the FFN
+    behind it or be the mixer alone (``cfg.layer_ffn``), and a position
+    that is an FFN alone (None in ``cfg.layer_pattern``) has no kind —
+    ``i`` below counts the layers of this kind, not the positions.
 
     - ``init(cfg, w, gain)`` -> the mixer's leaves, stacked over the
       periods: ``w(shape, scale=w.std)`` draws one at the slot's next key

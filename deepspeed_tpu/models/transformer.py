@@ -109,11 +109,17 @@ class TransformerConfig:
     # "latent": attention whose cache is one latent a token, below),
     # repeated (num_layers - len(lead_layers)) / len(pattern) times and
     # scanned one period an iteration; None is the uniform attention
-    # block above. The fields below are read by the hybrid block only.
-    layer_pattern: Optional[Tuple[str, ...]] = None
+    # block above. A position that is None has no mixer: an FFN alone
+    # (``layer_ffn``). The fields below are read by the hybrid block only.
+    layer_pattern: Optional[Tuple[Optional[str], ...]] = None
     # mixer kinds of the layers run before the scan, each followed by a
     # dense MLP of ``intermediate_size`` instead of the sparse FFN
     lead_layers: Tuple[str, ...] = ()
+    # which positions of the period carry an FFN (None: every one). A
+    # position may be a mixer alone (False here) or an FFN alone (None in
+    # ``layer_pattern``): one norm and one residual add, a layer of a
+    # model that counts its mixers and its FFNs as layers of their own
+    layer_ffn: Optional[Tuple[bool, ...]] = None
     head_size: Optional[int] = None      # stated head size; None → hidden/heads
     attn_output_gate: bool = False       # wq twice as wide: [q | gate] a head
     attn_gate_proj: bool = False         # ... or a projection of its own (wg)
@@ -139,6 +145,15 @@ class TransformerConfig:
     #   of the moe_num_experts routed over whose weights this model holds
     moe_intermediate_size: Optional[int] = None   # None → intermediate_size
     moe_shared_intermediate_size: int = 0         # shared expert; 0 = none
+    # the experts' (and the shared expert's) form: "silu" is the gated one
+    # (``down(silu(gate(x)) * up(x))``, three matrices an expert), "relu2"
+    # the ungated ``down(relu(up(x))²)``, two
+    moe_activation: str = "silu"
+    # > 0: the routed experts run in a latent this wide, between two
+    # projections every token passes once (``latent_w_in`` before the
+    # dispatch, ``latent_w_out`` behind the combine); the router and the
+    # shared expert stay on the full width
+    moe_latent_size: int = 0
     # Latent attention (a "latent" layer, models/mixers/latent.py): queries
     # through a rank-``q_lora_rank`` bottleneck, keys and values rebuilt
     # from a rank-``kv_lora_rank`` latent a token; a head's query and key
@@ -181,6 +196,20 @@ class TransformerConfig:
     # output and a sigmoid gate of the layer's input in front of ``wo``.
     lightning_num_heads: int = 0
     lightning_head_dim: int = 0
+    # A "mamba2" layer (ops/mamba2_ssd.py): the Mamba-2 state-space layer,
+    # ``mamba_num_heads`` heads of ``mamba_head_dim`` channels over a
+    # float32 state ``[heads, head_dim, mamba_state_size]`` a sequence
+    # under a decay that depends on the token, B and C shared by the
+    # heads of one of ``mamba_n_groups`` groups, a depthwise causal conv
+    # of ``mamba_conv_kernel`` taps (with bias) in front and a gated
+    # RMSNorm by group behind; chunks of ``mamba_chunk_size`` tokens in
+    # the chunked form.
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state_size: int = 0
+    mamba_n_groups: int = 1
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 128
     # A "block_sparse" layer (InfLLM-V2): a "full" layer whose query, from
     # position ``block_dense_len`` on, attends whole blocks of
     # ``block_select_size`` keys only: the first ``block_init_blocks``,
@@ -205,7 +234,7 @@ class TransformerConfig:
     def __post_init__(self):
         # a configuration read from JSON brings lists
         for name in ("layer_pattern", "moe_held_experts", "lead_layers",
-                     "rope_kinds"):
+                     "rope_kinds", "layer_ffn"):
             value = getattr(self, name)
             if isinstance(value, list):
                 object.__setattr__(self, name, tuple(value))
@@ -213,13 +242,28 @@ class TransformerConfig:
             from .mixers import KINDS, kinds_of
 
             pattern, lead = self.layer_pattern, self.lead_layers
-            if not pattern or any(k not in KINDS for k in pattern + lead) \
+            ffn = self.layer_ffn or (True,) * len(pattern or ())
+            if not pattern or any(k not in KINDS for k in lead) \
+                    or any(k is not None and k not in KINDS for k in pattern) \
                     or (self.num_layers - len(lead)) % len(pattern) \
                     or self.num_layers <= len(lead):
                 raise ValueError(
                     f"layer_pattern {pattern!r}: a period of "
                     f"{tuple(KINDS)} that divides num_layers "
                     f"({self.num_layers}) less the {len(lead)} lead_layers")
+            if len(ffn) != len(pattern) or any(
+                    k is None and not f for k, f in zip(pattern, ffn)):
+                raise ValueError(
+                    f"layer_ffn {self.layer_ffn!r}: one entry a position "
+                    f"of layer_pattern {pattern!r}, and every position a "
+                    "mixer, an FFN or both")
+            if self.moe_activation not in ("silu", "relu2") or (
+                    (self.moe_activation != "silu" or self.moe_latent_size)
+                    and (lead or self.moe_num_experts <= 0)):
+                raise ValueError(
+                    "moe_activation is \"silu\" (gated) or \"relu2\"; it "
+                    "and moe_latent_size are the sparse FFN's (the dense "
+                    "MLP of lead_layers or of moe_num_experts 0 is SwiGLU)")
             kinds = kinds_of(self)
             for kind in kinds:
                 KINDS[kind].check(self)
@@ -286,12 +330,19 @@ class TransformerConfig:
         return self.lead_layers.count(kind) \
             + self.num_periods * self.layer_pattern.count(kind)
 
+    def ffn_at(self, i: int) -> bool:
+        """Whether position ``i`` of the period carries an FFN."""
+        return self.layer_ffn is None or bool(self.layer_ffn[i])
+
     @property
     def num_attn_layers(self) -> int:
         """Layers that keep per-token K/V (all of them, unless hybrid)."""
         if self.layer_pattern is None:
             return self.num_layers
-        return self.num_layers - self.num_linear_layers
+        from .mixers import KINDS, kinds_of
+
+        return sum(self.layers_of(kind) for kind in kinds_of(self)
+                   if KINDS[kind].pool is not None)
 
     @property
     def num_linear_layers(self) -> int:
@@ -306,6 +357,8 @@ class TransformerConfig:
         """Layers whose FFN is the sparse one."""
         if self.moe_num_experts <= 0:
             return 0
+        if self.layer_ffn is not None:
+            return self.num_periods * sum(map(bool, self.layer_ffn))
         return self.num_layers - len(self.lead_layers)
 
     def kv_groups(self) -> Tuple[Tuple[int, int], ...]:
@@ -940,7 +993,8 @@ class CausalLM:
             "embed": {"wte": (0.02 * jax.random.normal(keys[-1], (v, h))
                               ).astype(jnp.float32)},
             "layers": {f"slot{i}": hybrid.init_slot(cfg, kind, keys[i],
-                                                    cfg.num_periods)
+                                                    cfg.num_periods,
+                                                    ffn=cfg.ffn_at(i))
                        for i, kind in enumerate(cfg.layer_pattern)},
             "final_norm": {"w": gain((h,), jnp.float32)},
         }
@@ -962,7 +1016,8 @@ class CausalLM:
             from . import hybrid
 
             specs = {"embed": {"wte": spec("vocab", "embed")},
-                     "layers": {f"slot{i}": hybrid.slot_specs(cfg, kind)
+                     "layers": {f"slot{i}": hybrid.slot_specs(
+                                    cfg, kind, ffn=cfg.ffn_at(i))
                                 for i, kind in enumerate(cfg.layer_pattern)},
                      "final_norm": {"w": spec("embed")}}
             for j, kind in enumerate(cfg.lead_layers):

@@ -59,7 +59,9 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
     """Top-k dropless MoE FFN over the experts this layer holds.
 
     tokens [N, H]; router_logits [N, E] (fp32) over ALL E experts;
-    w_in [n, H, M]; w_out [n, M, H]; w_gate [n, H, M] for SwiGLU, where
+    w_in [n, H, M]; w_out [n, M, H]; w_gate [n, H, M] for SwiGLU
+    (``activation`` "silu"; without it "relu", "relu2" — the square of
+    the relu — or a gelu), where
     the n experts held are ``[lo, lo + n)`` (``held = (lo, n)``; None:
     all of them). A token's ``top_k`` experts are chosen over all E and
     weighted by their scores — softmax probabilities, or with
@@ -218,6 +220,8 @@ def _ragged_expert_ffn(st, gs, w_in, w_out, w_gate, activation, dtype,
         h = jax.nn.silu(g) * h
     elif activation == "relu":
         h = jax.nn.relu(h)
+    elif activation == "relu2":
+        h = jnp.square(jax.nn.relu(h))
     else:
         h = jax.nn.gelu(h, approximate=activation != "gelu_exact")
     return matmul(h, w_out.astype(dtype), gs)

@@ -41,6 +41,8 @@ FIELDS = {
     "block_sparse": dict(block_kernel_size=4, block_kernel_stride=2,
                          block_select_size=8, block_topk=2,
                          block_window=16, block_dense_len=32),
+    "mamba2": dict(mamba_num_heads=4, mamba_head_dim=8, mamba_state_size=16,
+                   mamba_n_groups=2, mamba_chunk_size=16),
 }
 BS, SLOTS = 8, 3
 
@@ -101,7 +103,7 @@ def test_the_fleets_counters_are_the_engines_own_and_the_registrys():
 
 
 UNMISTAKABLE = ("latent_sparse", "latent_window", "block_sparse",
-                "lightning", "linear_attn")
+                "lightning", "linear_attn", "mamba2")
 
 
 def _docstrings(tree):
