@@ -13,12 +13,17 @@ Gated DeltaNet layer's rules (``gdn.py``; the leaves are named apart from
 its, so that a model may hold both): a row at ``start_pos`` 0 starts from
 zero, positions at or beyond ``n_tokens`` change neither. Rows of one
 token go through the step, wider ones through the chunked form at
-``mamba_chunk_size``.
+``mamba_chunk_size``. In serving a forward of one-token rows steps the
+state where it lies in its slots (``ssd.ssd_step_slots``: the leaf goes
+to the kernel and comes back from it, a padded row moves none of it);
+the chunked form's one row a forward is gathered and scattered.
 
 Scopes (docs/OBSERVABILITY.md): ``mamba`` ⊃ ``mamba_proj``,
-``mamba_conv``, ``mamba_scan`` (the recurrence alone), ``mamba_out`` and,
-in serving, ``mamba_state_io``: the gather of the rows' state and conv
-tail out of the slots and the scatter back."""
+``mamba_conv``, ``mamba_scan`` (the recurrence alone: in a served
+one-token forward the kernel ``mamba2_step`` and what feeds it),
+``mamba_out`` and, in serving, ``mamba_state_io``: the gather of the
+rows' conv tail out of the slots and the scatter back -- and, round the
+chunked form only, the state's."""
 
 from __future__ import annotations
 
@@ -114,11 +119,13 @@ def state_bytes(cfg) -> int:
     return nh * hd * ns * 4
 
 
-def mamba2_mixer(cfg, h1, lp, tail, state, n_tokens):
+def mamba2_mixer(cfg, h1, lp, tail, state, n_tokens, in_slots=None):
     """The Mamba-2 layer on its normed input [B, T, H], resumed from
     ``tail`` [B, K-1, CH] and ``state`` [B, heads, P, S] (float32).
     Positions at or beyond a row's ``n_tokens`` change neither. Returns
-    (y [B, T, H], new tail, new state)."""
+    (y [B, T, H], new tail, new state). ``in_slots``: one-token rows
+    whose state stays where the caller keeps it -- ``(x, dt, A, B, C, D)
+    -> y`` steps it there, and ``state`` is None in and out."""
     B, T, _ = h1.shape
     nh, hd, ns, g, inner, ch = dims(cfg)
     dt_, f32 = cfg.dtype, jnp.float32
@@ -138,7 +145,10 @@ def mamba2_mixer(cfg, h1, lp, tail, state, n_tokens):
         Bm = xbc[..., inner:inner + g * ns].reshape(B, T, g, ns)
         Cm = xbc[..., inner + g * ns:].reshape(B, T, g, ns)
     with scope("mamba_scan"):
-        if T == 1:
+        if in_slots is not None:
+            y = in_slots(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                         lp["mamba_D"])[:, None]
+        elif T == 1:
             y, state = ssd.ssd_step(x[:, 0], dt[:, 0], A, Bm[:, 0],
                                     Cm[:, 0], lp["mamba_D"], state)
             y = y[:, None]
@@ -170,22 +180,32 @@ def reference(cfg, fwd):
 
 def paged(cfg, fwd):
     pools, slots, fresh = fwd.pools, fwd.state_slots, fwd.fresh
+    stepped = fwd.shape[1] == 1
 
     def mixer(h1, lp, i):
         layer = fwd.layer(KIND, i)
+
+        def in_slots(*step):
+            y, pools["mamba_ssm"] = ssd.ssd_step_slots(
+                pools["mamba_ssm"], layer, slots, fwd.n_tokens, fresh, *step)
+            return y
+
         with scope("mamba"):
             with scope("mamba_state_io"):
                 tail = pools["mamba_conv"][layer, slots]
-                state = pools["mamba_ssm"][layer, slots]
                 tail = jnp.where(fresh[:, None, None], 0, tail)
-                state = jnp.where(fresh[:, None, None, None], 0, state)
-            y, tail, state = mamba2_mixer(cfg, h1, lp, tail, state,
-                                          fwd.n_tokens)
+                state = None if stepped else jnp.where(
+                    fresh[:, None, None, None], 0,
+                    pools["mamba_ssm"][layer, slots])
+            y, tail, state = mamba2_mixer(
+                cfg, h1, lp, tail, state, fwd.n_tokens,
+                in_slots if stepped else None)
             with scope("mamba_state_io"):
                 pools["mamba_conv"] = pools["mamba_conv"].at[
                     layer, slots].set(tail)
-                pools["mamba_ssm"] = pools["mamba_ssm"].at[
-                    layer, slots].set(state)
+                if not stepped:
+                    pools["mamba_ssm"] = pools["mamba_ssm"].at[
+                        layer, slots].set(state)
             return y
     return mixer
 

@@ -3,8 +3,11 @@ and a period whose positions are a mixer alone or an FFN alone
 (models/hybrid.run_period): the chunked form against the one-token step
 folded and against a sequential reference written here, across tile and
 chunk boundaries, with rows that are padding and a row that starts fresh
-on a slot that holds another sequence's state; the engine over such a
-model, its counters, and every block and state slot given back."""
+on a slot that holds another sequence's state; the one-token step's
+kernel (``mamba2_step``, interpreted) against gather, step and scatter
+over slots in any order with padding anywhere, and the lowered ``[S, 1]``
+program free of state-sized copies; the engine over such a model, its
+counters, and every block and state slot given back."""
 
 import jax
 import jax.numpy as jnp
@@ -226,6 +229,134 @@ def test_the_paged_layer_reads_and_writes_its_rows_slots(layer):
         assert (after[name][0] == before[name][0]).all()        # layer 0
         assert (after[name][1, [0, 2, 4]]
                 == before[name][1, [0, 2, 4]]).all()
+
+
+# --------------------------------------------- the step where the state lies
+
+def _slot_case(case, L=3, NS=7, N=6, H=4, P=8, G=2, S=16):
+    """A pool of ``NS`` slots (the last the scratch slot) a layer and a
+    bucket of ``N`` rows: (pool, slots, n_tokens, fresh, step inputs)."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    pool = jax.random.normal(ks[0], (L, NS, H, P, S))
+    step = dict(x=jax.random.normal(ks[1], (N, H, P)),
+                dt=jax.nn.softplus(jax.random.normal(ks[2], (N, H))),
+                A=-jnp.arange(1, H + 1, dtype=jnp.float32),
+                B=jax.random.normal(ks[3], (N, G, S)),
+                C=jax.random.normal(ks[4], (N, G, S)),
+                D=1.0 + 0.1 * jax.random.normal(ks[5], (H,)))
+    scratch = NS - 1
+    n = [1] * N
+    slots = [4, 0, 5, 2, 1, 3]          # out of order
+    fresh = [False] * N
+    if case == "padding_among_live":    # no prefix: padding first and between
+        n = [0, 1, 0, 0, 1, 1]
+    elif case == "padding_last":
+        n = [1, 1, 1, 0, 0, 0]
+    elif case == "fresh_over_garbage":
+        fresh = [False, True, False, True, False, False]
+        pool = pool.at[:, 0].set(jnp.nan).at[:, 2].set(1e30)
+    elif case == "one_live_row":
+        n = [0, 0, 0, 0, 1, 0]
+        fresh = [True] * N              # what start_pos 0 makes of padding
+    elif case == "no_live_row":
+        n = [0] * N
+        fresh = [True] * N
+    elif case == "step_of_zero":
+        step["dt"] = step["dt"].at[jnp.asarray([1, 4])].set(0.0)
+    slots = [s if live else scratch for s, live in zip(slots, n)]
+    return (pool, jnp.asarray(slots, jnp.int32), jnp.asarray(n, jnp.int32),
+            jnp.asarray(fresh), step)
+
+
+def _gather_step_scatter(pool, layer, slots, n, fresh, step):
+    """The oracle: the plain step on a gathered copy, scattered back."""
+    live = n > 0
+    state = jnp.where((fresh & live)[:, None, None, None], 0,
+                      pool[layer, slots])
+    y, state = ssd.ssd_step(step["x"], jnp.where(live[:, None], step["dt"], 0),
+                            step["A"], step["B"], step["C"], step["D"], state)
+    return y, pool.at[layer, slots].set(state)
+
+
+SLOT_CASES = ("all_live", "padding_among_live", "padding_last",
+              "fresh_over_garbage", "one_live_row", "no_live_row",
+              "step_of_zero")
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("case", SLOT_CASES)
+def test_the_kernel_steps_the_state_where_it_lies(case, layer, monkeypatch):
+    """``mamba2_step`` interpreted against gather, ``ssd_step``, scatter:
+    ``y`` of the live rows and their slots to float32 round-off; the
+    scratch slot, every slot no live row names and every other layer bit
+    for bit what they were; the layer index traced."""
+    monkeypatch.setattr(ssd, "_FORCE_INTERPRET", True)
+    pool, slots, n, fresh, step = _slot_case(case)
+    before = np.asarray(pool)
+    want_y, want_pool = _gather_step_scatter(pool, layer, slots, n, fresh,
+                                             step)
+    got_y, got_pool = jax.jit(
+        lambda pool, layer: ssd.ssd_step_slots(
+            pool, layer, slots, n, fresh, **step))(pool, jnp.int32(layer))
+    got_pool, live = np.asarray(got_pool), np.asarray(n) > 0
+    named = np.asarray(slots)[live]
+    if live.any():
+        assert close(got_y[live], want_y[live])
+        assert close(got_pool[layer, named], np.asarray(want_pool)[layer,
+                                                                   named])
+    assert (np.asarray(got_y)[~live] == 0).all()
+    others = np.setdiff1d(np.arange(pool.shape[1]), named)
+    same = lambda a, b: np.array_equal(a, b, equal_nan=True)    # noqa: E731
+    assert same(got_pool[layer, others], before[layer, others])
+    for other in set(range(pool.shape[0])) - {layer}:
+        assert same(got_pool[other], before[other])
+    if case == "step_of_zero":
+        kept = np.asarray(slots)[[1, 4]]
+        assert same(got_pool[layer, kept], before[layer, kept])
+
+
+def test_off_the_chip_the_plain_form_gives_the_same(monkeypatch):
+    pool, slots, n, fresh, step = _slot_case("padding_among_live")
+    plain = ssd.ssd_step_slots(pool, 1, slots, n, fresh, **step)
+    monkeypatch.setattr(ssd, "_FORCE_INTERPRET", True)
+    kernel = ssd.ssd_step_slots(pool, 1, slots, n, fresh, **step)
+    assert close(kernel[0], plain[0]) and close(kernel[1], plain[1])
+
+
+def test_a_one_token_forward_moves_no_state_sized_copy(layer, monkeypatch):
+    """The ``[S, 1]`` program as it is lowered for the chip: the state
+    leaf goes to ``mamba2_step`` as it lies and comes back from it -- no
+    gather, scatter or dynamic-update-slice has a state-sized operand
+    (the chunked form still gathers and scatters its one row's)."""
+    import re
+
+    from deepspeed_tpu.ops import pallas_utils
+
+    cfg, lp, h1 = layer
+    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    shapes = mamba2.state(cfg, 5)
+    state = "x".join(map(str, shapes["mamba_ssm"][0][2:])) + "xf32"
+
+    def forward(T):
+        def run(pools, h1, n, slots, first):
+            fwd = Fwd(shape=(3, T), n_tokens=n, ropes={}, pools=pools,
+                      first_layer={"mamba2": first}, state_slots=slots,
+                      fresh=n > 1)
+            return mamba2.paged(cfg, fwd)(h1, lp, 0), pools
+        pools = {name: jnp.zeros(shape, dt)
+                 for name, (shape, dt) in shapes.items()}
+        text = jax.jit(run).trace(
+            pools, h1[:, :T], jnp.ones((3,), jnp.int32),
+            jnp.arange(3, dtype=jnp.int32), jnp.int32(1)
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        return [m.group(0) for m in re.finditer(
+            r"stablehlo\.(gather|scatter|dynamic_update_slice)\b.*?"
+            r"-> tensor<[^>]*>", text, re.S) if state in m.group(0)], text
+
+    moved, text = forward(1)
+    assert not moved, moved
+    assert text.count("tpu_custom_call") == 1 and "mamba2_step" in text
+    assert forward(40)[0]       # what the assertion would catch
 
 
 # ---------------------------------------- positions of the period, the engine

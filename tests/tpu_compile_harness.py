@@ -66,7 +66,8 @@ def nbytes(s):
 def kernels(text):
     """The Pallas kernels of an optimized HLO module, by name, one a
     call."""
-    return re.findall(r"%([a-z_\-]+)[.\d]* = [^\n]*tpu_custom_call", text)
+    return re.findall(
+        r"%([a-z_\-][a-z\d_\-]*?)[.\d]* = [^\n]*tpu_custom_call", text)
 
 
 def spec_on(device):
