@@ -20,6 +20,7 @@ from ...models.mixers import KINDS, kinds_of
 from ...models.mixers.base import keys_and_pairs as _keys_and_pairs
 from ...models.transformer import CausalLM
 from ...ops import gated_delta
+from ...ops import paged_attention as pa
 from ...telemetry.tracer import NOOP_TRACER
 from ...utils.logging import logger
 from .paged_model import PagedCausalLM, fuse_qkv, split_qkv
@@ -122,7 +123,7 @@ _FREE_POSITIONS = 128
 #: keys of ``engine.last_put`` that ride on the scheduler's ``forward``
 #: span only (by prefix): ``stage`` keeps the keys the benchmark's wrapper has
 FORWARD_ONLY = ("kv_blocks_live", "kv_table_slots", "kv_blocks_released",
-                "kv_bytes_", "kv_g")
+                "kv_bytes_", "kv_g", "attn_steps")
 
 
 #: a one-token row's token when its sequence's next token is still on
@@ -322,6 +323,9 @@ class InferenceEngineV2:
         # that a put of several forwards sums
         self._record = tuple(name for mixer in mixers
                              for name in mixer.record)
+        # the kinds of layer that attend through the paged kernel, as the
+        # shapes its grid follows from beside a forward's rows
+        self._walks = self._paged_walks()
         if cfg.is_hybrid:
             if cfg.moe_num_experts:     # its sparse FFNs' rows
                 self.put_totals.update(moe_rows_routed=0, moe_rows_held=0)
@@ -588,6 +592,7 @@ class InferenceEngineV2:
         # of what its forwards counted, and how many they were
         summed = ("rows", "valid_tokens", "kv_read_tokens", "qk_pairs",
                   "kv_blocks_live", "kv_table_slots",
+                  "attn_steps", "attn_steps_primed",
                   "moe_rows_routed", "moe_rows_held", "kv_blocks_released") \
             + tuple(k for k in records[-1] if k.endswith(("_read_tokens",
                                                           "_qk_pairs"))
@@ -696,6 +701,7 @@ class InferenceEngineV2:
             "kv_table_slots": table_rows * len(groups)
             * arrays["block_tables"].shape[-1],
             "free_blocks": sm.available_blocks}
+        self._count_attn_steps(arrays, bool(merged))
         totals = self.put_totals
         totals["forwards"] += 1
         if self.qkv_fused:
@@ -793,6 +799,67 @@ class InferenceEngineV2:
                 released += sm.release_behind(seq)
         self._record_groups(released, group_read, group_pairs)
         return logits
+
+    def _paged_walks(self) -> List[Tuple[str, Dict[str, int]]]:
+        """One entry a kind of layer whose attention is
+        ``ops.paged_attention.paged_attention`` — a dense model's layers,
+        a hybrid block's kinds that say so (``Mixer.paged_walk``) — with
+        what that call's grid follows from beside its rows
+        (``grid_steps``): its K pool's leaf (whose dtype the tiles
+        follow), a TP shard's heads, the head size, the kind's window.
+        No entry for a latent cache or a selection of blocks (other
+        kernels), none at all where the call is the XLA gather (off the
+        chip, or by the registry)."""
+        cfg, tp = self.model.cfg, self.paged.tp
+        shape = {"heads": cfg.num_heads // tp, "kv_heads": cfg.kv_heads // tp,
+                 "head_dim": cfg.head_dim,
+                 "block_size": self.config.kv_block_size}
+        if self.paged._attn_raw is pa.paged_attention_xla \
+                or not pa.pallas_supported(shape["heads"], shape["kv_heads"],
+                                           cfg.head_dim):
+            return []
+        windows = [group.window for group in self.state_manager.groups]
+        if cfg.layer_pattern is None:
+            groups = [0]
+        else:       # a kind's layer group, as ``_forward_hybrid`` finds it
+            groups = [windows.index(int(cfg.sliding_window)
+                                    if KINDS[kind].windowed else 0)
+                      for kind in kinds_of(cfg)
+                      if KINDS[kind].paged_walk]
+        return [(f"k{g or ''}", dict(shape, window=windows[g]))
+                for g in groups]
+
+    def _count_attn_steps(self, arrays, merged: bool) -> None:
+        """``attn_steps`` / ``attn_steps_primed`` of ``last_put``: the
+        live grid steps of this forward's paged-attention calls, one call
+        a kind of layer (not a layer: the layers of a kind repeat it),
+        and those whose first turn the step before had fetched — the
+        kernel's rule on the rows the forward is handed
+        (``ops.paged_attention.grid_steps``). A merged forward's calls
+        are ``paged_model._parts``': row 0's chunk alone, then one
+        position a row with row 0 a padded row. Two dozen array
+        operations a call: a traced forward's alone, as its ``dispatch``
+        attrs are."""
+        if not (self._walks and self.tracer.enabled):
+            return
+        start, n_tokens = arrays["start_pos"], arrays["n_tokens"]
+        width = arrays["tokens"].shape[1]
+        calls = [(width, start, n_tokens)]
+        if merged:
+            dead = np.arange(len(start)) > 0
+            calls = [(width - len(start), start[:1], n_tokens[:1]),
+                     (1, start * dead, n_tokens * dead)]
+        cache = self.state_manager.forward_cache
+        steps = primed = 0
+        for leaf, shape in self._walks:
+            for chunk, s, n in calls:
+                a, b = pa.grid_steps(
+                    s, n, chunk=chunk, q_dtype=self.model.cfg.dtype,
+                    pool_dtype=cache[leaf].dtype,
+                    table_blocks=arrays["block_tables"].shape[-1], **shape)
+                steps, primed = steps + a, primed + b
+        self.last_put["attn_steps"] = steps
+        self.last_put["attn_steps_primed"] = primed
 
     def _record_groups(self, released: int, group_read, group_pairs) -> None:
         """The put's record by layer group, for a model that keeps more

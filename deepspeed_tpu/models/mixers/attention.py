@@ -147,7 +147,7 @@ def _describe(kind: str, name: str, windowed: bool) -> Mixer:
         return mixer
 
     return Mixer(init=init, specs=specs, reference=reference, paged=paged,
-                 scope=name, pool=pool, windowed=windowed)
+                 scope=name, pool=pool, windowed=windowed, paged_walk=True)
 
 
 FULL = _describe("full", "full_attn", False)

@@ -36,6 +36,10 @@ class Mixer:
     - ``scope``: the named scope round the layer, for a kind that keeps a
       per-token cache (another opens its own inside).
     - ``check(cfg)`` raises ``ValueError`` on sizes the kind cannot run.
+    - ``paged_walk``: its attention is ``ops.paged_attention
+      .paged_attention`` over its group's table at the model's heads, so
+      the engine can count the kernel's grid steps for it
+      (``attn_steps``).
     - the cache: ``pool(cfg, block_size)`` -> its pool leaves and one
       block's shape in each (None: no per-token cache), in the layer
       group of the whole context or, ``windowed``, of the window;
@@ -57,6 +61,7 @@ class Mixer:
     check: Callable = lambda cfg: None
     pool: Optional[Callable] = None
     windowed: bool = False
+    paged_walk: bool = False
     headless: bool = False
     state: Optional[Callable] = None
     rope_base: Optional[Callable] = None

@@ -17,7 +17,12 @@ follows the live KV bytes and the query-key pairs, not the table's width.
   [KH, bs, D] when the step holds every head: contiguous) is copied by
   ``make_async_copy`` at ``[layer, table[n, b], heads]`` — layer, table and
   lengths are scalar-prefetched — into one of two buffer slots, while the
-  other slot's T blocks are folded. A [NB, KH, bs, D] pool with no
+  other slot's T blocks are folded; the copy in flight is the walk's next
+  turn or, under a walk's last turn, **the first turn of the grid step
+  after it**: the grid runs in order and the buffers outlive a step, so a
+  batch of short contexts reads its K/V back to back instead of exposing
+  one copy a sequence (``_paged_kernel`` has the pairing of starts and
+  waits). A [NB, KH, bs, D] pool with no
   ``layer`` is the one-layer case. No [N, max_ctx, H, D] gather is ever
   materialized in HBM and GQA needs no ``jnp.repeat`` — each turn's two
   dots are batched over the heads: q [KHt, G·C, D] · k [KHt, T·bs, D].
@@ -26,9 +31,9 @@ follows the live KV bytes and the query-key pairs, not the table's width.
   n_tokens``: a slot past the context (or of a padded row with no tokens)
   costs no grid step, no copy and no arithmetic, and its table entry is
   never dereferenced. Inside a turn, a block place that is not live is
-  not copied and its scores are masked; the V buffer starts each grid step
-  as zeros, so such a place holds zeros or an earlier turn's rows, never
-  a NaN for 0 · NaN to carry into the sum.
+  not copied and its scores are masked; the V buffer starts the call as
+  zeros, so such a place holds zeros or an earlier turn's rows (this
+  walk's or one before it), never a NaN for 0 · NaN to carry into the sum.
 - **Tiles**: ``KHt`` and ``T`` come from the shapes the call sees (G·C, D,
   the local KH, bs, the dtypes) against ``VMEM_BUDGET`` (``_tiles``):
   blocks first, up to ``KEY_TILE`` keys a turn, then every head that
@@ -52,9 +57,11 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .pallas_utils import on_tpu as _on_tpu
@@ -124,7 +131,43 @@ def _tiles(rows: int, head_dim: int, kv_heads: int, block_size: int,
     return kh_t, blocks
 
 
+#: ``(min, max, div)`` for the kernel's int32 scalars, none negative —
+#: ``lax`` and not ``jnp``: the kernel is traced and lowered once a
+#: forward program, and a ``jnp`` ``//``, ``%`` or ``where`` is a nested
+#: function of a dozen equations where these are one — and for the host's
+#: arrays
+_SCALARS = lax.min, lax.max, lax.div
+_ARRAYS = np.minimum, np.maximum, np.floor_divide
+
+
+def _live_blocks(start_pos, n_tokens, block_size: int, window: int,
+                 slots: int, ops=_SCALARS):
+    """The table blocks [first, last) a walk covers: up to the context's
+    end (and the table's) and, with a sliding window, from the earliest
+    position any query row of the chunk attends, start_pos − window + 1.
+    One rule for the kernel's scalars and for the host's count of its
+    grid steps (``grid_steps``, ``ops=_ARRAYS``)."""
+    least, most, div = ops
+    last = least(div(start_pos + n_tokens + (block_size - 1), block_size),
+                 slots)
+    first = div(most(start_pos - (window - 1), 0), block_size) \
+        if window else 0
+    return first, last
+
+
 # ------------------------------------------------------------------- kernel
+
+class _Walk(NamedTuple):
+    """A grid step's walk, as scalars: its row of the table, the first
+    K/V head it holds, its live blocks [first, last) and the turns
+    [lo, hi) that hold them."""
+    row: Any
+    head0: Any
+    first: Any
+    last: Any
+    lo: Any
+    hi: Any
+
 
 def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
                   q_ref, k_hbm, v_hbm, *refs, chunk: int, groups: int,
@@ -142,6 +185,31 @@ def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
     into the dots as it is (converted to the query's dtype, which holds it
     exactly) and the scales multiply the scores and the probabilities.
 
+    **A copy stays in flight across grid steps.** The grid runs in order
+    (``"arbitrary"``), and the buffers, the semaphores and two SMEM
+    scalars outlive a step. Every fold of a turn first starts the copies
+    of *the turn after it*: the walk's next turn or, from the walk's last
+    turn, the first turn of the grid step after this one — (n, h + 1),
+    else (n + 1, 0), read from the same scalars that step will read —
+    into the slot the fold is not reading. ``state[1]`` tells that step
+    its first turn is on its way, ``state[0]`` the slot it lands in (a
+    running count of turns, not ``turn % 2`` of each walk: a walk's last
+    turn and the next walk's first must not meet in one slot). The
+    pairing of starts and waits, per semaphore ``sem[K/V, slot]`` and per
+    block copy of [KHt, bs, D]:
+
+    - the copies of a walk's first turn are started once — by the grid
+      step before it, from its last fold, if that step walks at least one
+      turn and this one does too; else by this step, before its loop —
+      and waited once, by the fold of that turn;
+    - the copies of a later turn are started by the fold of the turn
+      before it and waited by its own fold;
+    - both sides take a turn's live blocks from the same rule
+      (``span``), so a wait names as many block copies as were started;
+      a step that walks nothing (a padded row, a piece past a row's
+      tokens) starts nothing, waits for nothing and is fetched nothing,
+      and the last grid step fetches nothing: no copy outlives the call.
+
     The two variants of a block-sparse layer (a step holds one K/V head
     in both). ``by_head``: the table is a K/V head's own, row ``n ·
     kv_heads + head`` of [N·KH, W] — the blocks that head's query
@@ -156,60 +224,76 @@ def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
         ks_ref, vs_ref, *refs = refs
     if masked:
         mask_ref, *refs = refs
-    o_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref = refs
+    o_ref, k_buf, v_buf, sem, state, acc_ref, m_ref, l_ref = refs
     _, kh_t, T, bs, D = k_buf.shape
     rows, keys = q_ref.shape[2], T * bs
     last_slot = tables_ref.shape[1] - 1
-    n = pl.program_id(0)
-    kh0 = pl.program_id(1) * kh_t
-    trow = n * kv_heads + pl.program_id(1) if by_head else n
+    n, h = pl.program_id(0), pl.program_id(1)
+    n_seqs = startp_ref.shape[0]
     layer = layer_ref[0]
+
+    def walk(n, h):
+        """Grid step (n, h)'s walk (``_live_blocks``); the step after
+        the grid's last walks nothing."""
+        row = lax.min(n, n_seqs - 1)
+        first, last = _live_blocks(startp_ref[row], ntok_ref[row], bs,
+                                   window, last_slot + 1)
+        last = lax.select(n < n_seqs, last, jnp.int32(0))
+        return _Walk(row * kv_heads + h if by_head else row, h * kh_t,
+                     first, last, lax.div(first, T) if window else 0,
+                     pl.cdiv(last, T))
+
+    mine = walk(n, h)
+    kh0, lo, hi = mine.head0, mine.lo, mine.hi
     startp = startp_ref[n]
     ctx_len = startp + ntok_ref[n]
-    # live blocks [first, last): up to the context's end and, with a
-    # sliding window, from the earliest position any query row of this
-    # chunk attends, startp − window + 1
-    last = jnp.minimum(pl.cdiv(ctx_len, bs), last_slot + 1)
-    first = jnp.maximum(startp - window + 1, 0) // bs if window else 0
+    # the walk of the grid step after this one
+    wrap = h + 1 == kv_heads // kh_t
+    nxt = walk(n + wrap.astype(jnp.int32),
+               lax.select(wrap, jnp.int32(0), h + 1))
 
-    def copies(b, slot):
-        """Table block b's K and V copies into their place in ``slot``."""
-        return [pltpu.make_async_copy(
-            hbm.at[layer, tables_ref[trow, b], pl.ds(kh0, kh_t)],
-            buf.at[slot, :, b % T], sem.at[i, slot])
-            for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
-
-    def live_span(turn):
-        """The live blocks of a turn, [b0, b1): none past the last turn."""
-        return (jnp.maximum(first, turn * T),
-                jnp.minimum(last, turn * T + T))
+    def span(w, turn):
+        """The live blocks of walk ``w``'s turn, [b0, b1): none past the
+        walk's last turn, none of a walk with no live block."""
+        return lax.max(w.first, turn * T), lax.min(w.last, turn * T + T)
 
     # a loop over a turn's live blocks, not unrolled and with no branch a
     # block: the kernel is traced once for every forward program (54 a
     # dense engine), and each cond or loop it holds is host time at set-up
-    def each_live(turn, slot, act):
+    def each_live(w, b0, b1, slot, act):
         def one(b, _):
-            for dma in copies(b, slot):
-                act(dma)
+            for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(
+                    hbm.at[layer, tables_ref[w.row, b], pl.ds(w.head0, kh_t)],
+                    buf.at[slot, :, lax.rem(b, T)], sem.at[i, slot]))
 
-        lax.fori_loop(*live_span(turn), one, None)
+        lax.fori_loop(b0, b1, one, None)
 
-    def start(turn, slot):
-        each_live(turn, slot, lambda dma: dma.start())
-
-    def wait(turn, slot):
-        each_live(turn, slot, lambda dma: dma.wait())
+    def start(w, b0, b1, slot):
+        each_live(w, b0, b1, slot, lambda dma: dma.start())
 
     # a place of a turn that no block is copied into keeps what it held:
-    # an earlier turn's rows, or these zeros — never the NaN that would
-    # reach the sum through 0 · NaN (its scores are masked)
-    v_buf[...] = jnp.zeros_like(v_buf)
+    # an earlier turn's rows — this walk's or a walk's before it, finite —
+    # or the zeros of the call's first step, never the NaN that would
+    # reach the sum through 0 · NaN (its scores are masked). Once a call:
+    # a later step's zeroing would wipe the turn fetched for it
+    @pl.when((n == 0) & (h == 0))
+    def _():
+        v_buf[...] = jnp.zeros_like(v_buf)
+        state[0] = 0
+        state[1] = 0
+
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-    lo, hi = first // T, pl.cdiv(last, T)
-    start(lo, lo % 2)
+    # the slot of this walk's first turn, and whether the step before
+    # this one has started its copies: else they start here
+    slot0, primed = state[0], state[1]
+    b0, b1 = span(mine, lo)
+    start(mine, b0, lax.select(primed == 1, b0, b1), slot0)
+    state[0] = (slot0 + lax.max(hi - lo, 0)) & 1
+    state[1] = ((hi > lo) & (nxt.hi > nxt.lo)).astype(jnp.int32)
 
     # q row r = g·C + ci sits at global position startp + ci; ALiBi's
     # slope belongs to head (kh0 + k)·G + g. Neither moves with the turn.
@@ -238,9 +322,14 @@ def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
             for k in range(kh_t)])
 
     def fold(turn, _):
-        slot = turn % 2
-        start(turn + 1, 1 - slot)
-        wait(turn, slot)
+        slot = (slot0 + turn - lo) & 1
+        # the turn after this one: the walk's, or the next grid step's
+        # first, by scalar selects — one loop of starts either way
+        more = turn + 1 < hi
+        ahead = _Walk(*(lax.select(more, a, b) for a, b in zip(mine, nxt)))
+        start(ahead, *span(ahead, lax.select(more, turn + 1, nxt.lo)),
+              1 - slot)
+        each_live(mine, *span(mine, turn), slot, lambda dma: dma.wait())
         q = q_ref[0]                                          # [KHt, G·C, D]
         k = k_buf[slot].reshape(kh_t, keys, D)
         v = v_buf[slot].reshape(kh_t, keys, D)
@@ -375,6 +464,7 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
             pltpu.VMEM((2, kh_t, T, bs, D), k_pool.dtype),
             pltpu.VMEM((2, kh_t, T, bs, D), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),                 # [K/V, slot]
+            pltpu.SMEM((2,), jnp.int32),     # first turn's slot, fetched?
             pltpu.VMEM((kh_t, G * C, D), jnp.float32),
             pltpu.VMEM((kh_t, G * C, LANES), jnp.float32),
             pltpu.VMEM((kh_t, G * C, LANES), jnp.float32),
@@ -387,7 +477,8 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, KH, G * C, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            # in order: a step's last turn fetches for the step after it
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(layer.reshape(1), tables, start_pos.astype(jnp.int32),
       n_tokens.astype(jnp.int32), slopes, *operands)
@@ -479,15 +570,54 @@ def _chunk_tile(chunk: int, group: int) -> int:
     return max(d for d in range(1, most + 1) if chunk % d == 0)
 
 
-def _pieces(chunk: int, tile: int, start_pos, n_tokens):
+def _pieces(chunk: int, tile: int, start_pos, n_tokens, xp=jnp):
     """A chunk cut along C into pieces of ``tile`` positions: ``(c0,
     start_pos, n_tokens)`` of each. The pool already holds the whole
     chunk's K/V and the mask goes by position, so a piece is the same
     call at a later start. A piece past a row's valid tokens is given a
     context of 0: every block of its walk is dead."""
     for c0 in range(0, chunk, tile):
-        n_sub = jnp.clip(n_tokens - c0, 0, tile)
-        yield c0, jnp.where(n_sub > 0, start_pos + c0, 0), n_sub
+        n_sub = xp.clip(n_tokens - c0, 0, tile)
+        yield c0, xp.where(n_sub > 0, start_pos + c0, 0), n_sub
+
+
+@functools.cache
+def _grid_shape(chunk, group, head_dim, kv_heads, block_size, table_blocks,
+                q_dtype, pool_dtype):
+    """``(tile, head groups)``: the positions of one piece of a call's
+    chunk and the grid steps a row of a piece takes (a forward asks this
+    of the same few shapes, put after put)."""
+    tile = _chunk_tile(chunk, group)
+    kh_t, _ = _tiles(group * tile, head_dim, kv_heads, block_size,
+                     table_blocks, q_dtype, pool_dtype)
+    return tile, kv_heads // kh_t
+
+
+def grid_steps(start_pos, n_tokens, *, chunk: int, heads: int, kv_heads: int,
+               head_dim: int, block_size: int, table_blocks: int,
+               window: int = 0, q_dtype=jnp.bfloat16, pool_dtype=jnp.bfloat16):
+    """``(steps, primed)`` of one ``paged_attention`` call, on the host
+    from its rows' ``start_pos`` / ``n_tokens`` (numpy) and its shapes:
+    the (row, head group) grid steps that walk at least one turn, over
+    the call's pieces, and those of them whose first turn the grid step
+    before them had fetched — a live step behind a live step of the same
+    piece (``_paged_kernel``: the kernel's own rules, ``_chunk_tile``,
+    ``_pieces``, ``_tiles`` and ``_live_blocks``, on numbers)."""
+    tile, head_groups = _grid_shape(chunk, heads // kv_heads, head_dim,
+                                    kv_heads, block_size, table_blocks,
+                                    jnp.dtype(q_dtype), jnp.dtype(pool_dtype))
+    steps = primed = 0
+    for _, start, n_sub in _pieces(chunk, tile, np.asarray(start_pos),
+                                   np.asarray(n_tokens), xp=np):
+        first, last = _live_blocks(start, n_sub, block_size, window,
+                                   table_blocks, ops=_ARRAYS)
+        live = last > first
+        rows = int(live.sum())
+        steps += head_groups * rows
+        # a row's later head groups follow its own; its first follows the
+        # last of the row before it
+        primed += (head_groups - 1) * rows + int((live[1:] & live[:-1]).sum())
+    return steps, primed
 
 
 def _pallas_ok(q, k_pool) -> bool:

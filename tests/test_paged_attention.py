@@ -4,6 +4,9 @@ Mirrors the reference's ragged-ops kernel tests
 (tests/unit/inference/kernels/ragged_ops/test_blocked_flash.py pattern:
 build a paged cache + block tables, compare against a dense reference)."""
 
+import functools
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -421,3 +424,265 @@ def test_put_record_counts_the_walk():
     assert put["kv_blocks_live"] == 3 + 2 + 1        # 21, 10 and 8 tokens
     assert put["kv_table_slots"] == put["bucket_seqs"] * slots
     assert put["kv_blocks_live"] * 8 >= put["kv_read_tokens"]
+
+
+# ------------------------------------------ a copy in flight across steps
+
+# a walk's last turn starts the first turn of the grid step after it, so
+# what a row reads depends on the rows beside it: 0 (a padded row), 1, 2
+# and 7 turns of 8 blocks, in every order
+_TURN_CTX = {0: 0, 1: 300, 2: 600, 7: 3500}
+_EDGE = dict(N=4, bs=64, MB=56, NB=80)
+ORDERS = list(itertools.permutations(_TURN_CTX))
+
+
+def _tpu_interpreter(monkeypatch, dma="on_wait"):
+    """The kernel under Pallas's TPU interpreter, not the plain one: a
+    buffer starts as NaN, not zeros, and with ``on_wait`` a copy moves
+    its bytes when it is waited for and not before — a start with no
+    wait of its own leaves the NaN where the fold reads."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    params = pltpu.InterpretParams(dma_execution_mode=dma,
+                                   uninitialized_memory="nan")
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: params)
+
+
+def _edge_case(order, C=1, H=4, KH=2, D=64, seed=9, **kw):
+    ctx = [_TURN_CTX[t] for t in order]
+    return _walk_case(np.random.default_rng(seed), C=C, H=H, KH=KH, D=D,
+                      ctx_lens=ctx, n_tokens=[min(C, c) for c in ctx],
+                      **_EDGE, **kw)
+
+
+def _assert_rows(out, ref, n_tokens, atol=2e-5):
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    for i, n in enumerate(np.asarray(n_tokens)):
+        np.testing.assert_allclose(out[i, :n], ref[i, :n], atol=atol,
+                                   rtol=atol, err_msg=f"row {i}")
+        if n == 0:      # a padded row reads as zeros, whoever is beside it
+            assert not out[i].any()
+
+
+@pytest.mark.parametrize("order", ORDERS, ids=lambda o: "".join(map(str, o)))
+def test_step_edge_in_every_order_of_turns(order, monkeypatch):
+    """Copies made at their start (the plain interpreter): a zeroing or a
+    copy that lands on a slot still to be folded shows here."""
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    args, _ = _edge_case(order)
+    assert pa._tiles(1, 64, 2, 64, 56, jnp.float32, jnp.float32) == (2, 8)
+    _assert_rows(pa.paged_attention(*args), pa.paged_attention_xla(*args),
+                 args[5])
+
+
+def _plain(args, scales, **kw):
+    return (pa.paged_attention(*args, **scales, **kw),
+            pa.paged_attention_xla(*args, **scales, **kw), args[5])
+
+
+def _head_groups(monkeypatch, **kw):
+    # one K/V head a step of the two: the step after (n, 0) is (n, 1)
+    monkeypatch.setattr(pa, "VMEM_BUDGET", 700_000)
+    assert pa._tiles(2, 64, 2, 64, 56, jnp.float32, jnp.float32) == (1, 8)
+    return _plain(*_edge_case(**kw))
+
+
+def _pieces_cut(monkeypatch, order, **kw):
+    # three pieces of 8 positions: a piece past a row's tokens is a dead
+    # step between live ones
+    monkeypatch.setattr(pa, "MAX_QUERY_ROWS", 16)
+    ctx = [_TURN_CTX[t] for t in order]
+    args, _ = _walk_case(
+        np.random.default_rng(4), C=24, H=4, KH=2, D=64, ctx_lens=ctx,
+        n_tokens=[min(c, n) for c, n in zip(ctx, (24, 5, 9, 17))], **_EDGE)
+    return _plain(args, {})
+
+
+def _by_head(monkeypatch, order, **kw):
+    rng = np.random.default_rng(8)
+    (q, kp, vp, *_), _ = _edge_case(order)
+    blocks = jnp.asarray([-(-_TURN_CTX[t] // 64) for t in order])
+    tables = jnp.asarray(rng.integers(1, _EDGE["NB"], (4, 2, 56)), jnp.int32)
+    positions = jnp.asarray(rng.integers(0, 4000, 4), jnp.int32)
+    call = [q, kp[None], vp[None], tables, blocks, positions]
+    out = pa.paged_attention_select(*call, layer=0)
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", False)
+    return (out, pa.paged_attention_select(*call, layer=0),
+            (blocks > 0).astype(jnp.int32))
+
+
+def _masked(monkeypatch, order, **kw):
+    (q, kp, vp, tbl, sp, nt), _ = _edge_case(order, C=4)
+    rng = np.random.default_rng(12)
+    own = np.arange(56)[None, None, None, :] == (
+        (np.asarray(sp)[:, None] + np.arange(4)[None]) // 64)[:, :, None, None]
+    mask = jnp.asarray((rng.random((4, 4, 2, 56)) > 0.5) | own, jnp.int8)
+    return (pa.paged_attention_masked(q, kp, vp, tbl, sp, nt, mask),
+            pa.paged_attention_xla(q, kp, vp, tbl, sp, nt, block_mask=mask),
+            nt)
+
+
+STEP_EDGE_VARIANTS = {
+    "head_groups": _head_groups,
+    "head_groups_chunk": functools.partial(_head_groups, C=4),
+    # the first live block of the longest row is block 43, of the next 0
+    "window": lambda m, **kw: _plain(*_edge_case(**kw), window=700),
+    "window_chunk": lambda m, **kw: _plain(*_edge_case(C=4, **kw),
+                                           window=700),
+    "alibi": lambda m, **kw: _plain(
+        *_edge_case(**kw),
+        alibi_slopes=jnp.asarray([0.5, 0.25, 0.125, 0.0625]) / 64),
+    "int8_pool": lambda m, **kw: _plain(*_edge_case(quant=jnp.int8, **kw)),
+    "pieces": _pieces_cut,
+    "by_head": _by_head,
+    "masked": _masked,
+}
+
+
+@pytest.mark.parametrize("order", [(0, 7, 1, 2), (2, 0, 7, 1), (1, 2, 7, 0)],
+                         ids=lambda o: "".join(map(str, o)))
+@pytest.mark.parametrize("variant", STEP_EDGE_VARIANTS)
+def test_step_edge_variants_pair_their_waits(variant, order, monkeypatch):
+    """The body's variants across the step edge (a padded row first,
+    between two live ones, last), copies made when they are waited for."""
+    _tpu_interpreter(monkeypatch)
+    out, ref, n_tokens = STEP_EDGE_VARIANTS[variant](monkeypatch, order=order)
+    _assert_rows(out, ref, n_tokens,
+                 atol=2e-4 if variant == "int8_pool" else 2e-5)
+
+
+@pytest.mark.parametrize("dma", ["eager", "on_wait"])
+def test_buffers_that_start_as_nan_are_never_read_unfilled(dma, monkeypatch):
+    """Both slots of ``k_buf`` / ``v_buf`` start as NaN. Copies made at
+    once (``eager``): the one zeroing of ``v_buf`` must not fall on a
+    turn already fetched. Copies made at their wait (``on_wait``): every
+    turn folded was waited for. Rows of one block each leave most of a
+    turn's places unfilled, beside rows that fill every place."""
+    _tpu_interpreter(monkeypatch, dma)
+    ctx = [3500, 30, 600, 0, 64, 512, 1]
+    args, _ = _walk_case(np.random.default_rng(2), C=1, H=4, KH=2, D=64,
+                         ctx_lens=ctx, n_tokens=[1, 1, 1, 0, 1, 1, 1],
+                         **dict(_EDGE, N=7))
+    out = pa.paged_attention(*args)
+    assert np.isfinite(np.asarray(out)).all()
+    _assert_rows(out, pa.paged_attention_xla(*args), args[5])
+
+
+# ---------------------------------------------- the count of the grid steps
+
+def _grid_by_hand(rows, head_groups, bs, slots, window=0):
+    """``(steps, primed)`` of one kernel call, step by step as the grid
+    runs: (n, h) is live if its walk holds a block, primed if the step
+    before it was live too."""
+    steps = primed = 0
+    before = False
+    for start, n in rows:
+        for _ in range(head_groups):
+            last = min(-(-(start + n) // bs), slots)
+            first = max(start - window + 1, 0) // bs if window else 0
+            live = last > first
+            steps += live
+            primed += live and before
+            before = live
+    return steps, primed
+
+
+@pytest.mark.parametrize("case", [
+    # rows' (start_pos, n_tokens), chunk, window, head groups a row
+    dict(rows=[(599, 1)] * 5 + [(0, 0)] * 3, chunk=1, hg=1),
+    dict(rows=[(0, 0), (40, 1), (0, 0), (7, 1), (900, 1)], chunk=1, hg=1),
+    dict(rows=[(5000, 1), (100, 1), (0, 0)], chunk=1, hg=1, window=4096),
+    # 8 heads over 2: G·C = 1,024 rows a K/V head, one head a step
+    dict(rows=[(256, 256), (0, 17), (0, 0)], chunk=256, hg=2),
+], ids=["padded_tail", "padded_between", "window", "head_groups"])
+def test_grid_steps_is_the_kernels_grid_walked_by_hand(case):
+    rows, chunk, hg = case["rows"], case["chunk"], case["hg"]
+    window = case.get("window", 0)
+    shape = dict(chunk=chunk, heads=8, kv_heads=2, head_dim=128,
+                 block_size=64, table_blocks=64, window=window)
+    assert pa._tiles(4 * chunk, 128, 2, 64, 64, jnp.bfloat16,
+                     jnp.bfloat16)[0] == 2 // hg
+    start, n = map(np.asarray, zip(*rows))
+    assert pa.grid_steps(start, n, **shape) \
+        == _grid_by_hand(rows, hg, 64, 64, window)
+
+
+def test_grid_steps_of_a_chunk_cut_in_pieces(monkeypatch):
+    """Three pieces of 8 positions, each a call of its own: a piece past
+    a row's tokens is a dead step, and no step is fetched for across two
+    calls."""
+    monkeypatch.setattr(pa, "MAX_QUERY_ROWS", 16)
+    pa._grid_shape.cache_clear()
+    rows = [(100, 24), (0, 5), (30, 9), (0, 0), (64, 17)]
+    start, n = map(np.asarray, zip(*rows))
+    got = pa.grid_steps(start, n, chunk=24, heads=4, kv_heads=2, head_dim=64,
+                        block_size=8, table_blocks=16,
+                        q_dtype=jnp.float32, pool_dtype=jnp.float32)
+    pieces = [[(s + c0, min(max(k - c0, 0), 8)) if k > c0 else (0, 0)
+               for s, k in rows] for c0 in (0, 8, 16)]
+    want = [_grid_by_hand(p, 1, 8, 16) for p in pieces]
+    assert want == [(4, 2), (3, 0), (2, 0)]
+    assert got == tuple(map(sum, zip(*want)))
+    pa._grid_shape.cache_clear()
+
+
+def test_put_record_counts_the_grid_steps(monkeypatch):
+    """``attn_steps`` / ``attn_steps_primed`` of ``engine.last_put``: the
+    kernel's rule on the rows a forward is handed — padded rows, a merged
+    forward's two calls, a put of two forwards summed — and on the
+    ``forward`` span alone, not on ``stage``."""
+    from deepspeed_tpu.inference.v2.engine_v2 import (
+        FORWARD_ONLY, InferenceEngineV2, RaggedInferenceEngineConfig)
+    from deepspeed_tpu.models.transformer import CausalLM, TINY_TEST
+
+    vcfg = RaggedInferenceEngineConfig(
+        max_ragged_batch_size=512, max_ragged_sequence_count=8,
+        max_chunk_tokens=64, kv_blocks=64, kv_block_size=8,
+        max_tracked_sequences=16)
+    # off the chip the registry takes the XLA gather: no grid, no count
+    assert "attn_steps" not in _put(InferenceEngineV2(
+        CausalLM(TINY_TEST), config=vcfg), [1], [[5, 6, 7]])
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    engine = InferenceEngineV2(CausalLM(TINY_TEST), config=vcfg)
+    assert "attn_steps".startswith(FORWARD_ONLY)
+    # ... and an untraced forward does not pay for the count
+    assert "attn_steps" not in _put(engine, [9], [[5, 6, 7]])
+    from deepspeed_tpu.telemetry import Tracer
+
+    engine.tracer = Tracer()
+    # three rows in a bucket of four: the fourth is a padded row
+    put = _put(engine, [1, 2, 3], [list(range(1, 21)), [4] * 9, [7] * 3])
+    assert (put["bucket_seqs"], put["attn_steps"],
+            put["attn_steps_primed"]) == (4, 3, 2)
+    # a row of 40, four one-token rows and a row of two: past 128
+    # positions the first chunk and the ones are one merged forward of two
+    # calls — the chunk's one step, then a padded row 0 before four live
+    # rows (and three padded) — and the second chunk a forward of its own
+    put = _put(engine, [4, 1, 2, 3, 5, 6],
+               [list(range(1, 41)), [9], [9], [9], [8] * 2, [8]])
+    assert engine.put_totals["forwards_merged"] == 1 and put["forwards"] == 2
+    assert (put["attn_steps"], put["attn_steps_primed"]) \
+        == (1 + 4 + 1, 0 + 3 + 0)
+    # the scheduler's spans: on ``forward``, not on ``stage``
+    from deepspeed_tpu.inference.v2.scheduler import \
+        ContinuousBatchingScheduler
+
+    tracer = Tracer()
+    sched = ContinuousBatchingScheduler(engine, tracer=tracer)
+    for uid in (11, 12):
+        sched.submit(uid, [3] * 12, max_new_tokens=2)
+    while sched.has_work:
+        sched.step()
+    spans = tracer.export()
+    counts = [s["attrs"] for s in spans if s["name"] == "forward"]
+    assert counts and all(a["attn_steps"] == a["rows"] for a in counts)
+    assert [a["attn_steps_primed"] for a in counts] \
+        == [a["rows"] - 1 for a in counts]
+    assert not any(k.startswith("attn_steps") for s in spans
+                   if s["name"] == "stage" for k in s["attrs"])
+
+
+def _put(engine, uids, tokens):
+    engine.put(uids, tokens)
+    return dict(engine.last_put)

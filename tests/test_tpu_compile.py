@@ -15,6 +15,7 @@ compile cache is off around them: an entry written for an unattached chip
 cannot be read back and warns on every later run.
 """
 
+import functools
 import re
 
 import jax
@@ -122,13 +123,21 @@ def test_paged_attention(v5e, pool, chunk):
     (1, 256, 32, 8, 128, 64, 1152, 4096),
 ], ids=["mistral_decode", "mistral_chunk", "qwen3_next_decode",
         "qwen3_next_piece", "mistral_decode_tp4", "mistral_chunk_alone"])
-def test_paged_attention_at_the_cells_geometries(v5e, geometry):
+def test_paged_attention_at_the_cells_geometries(v5e, geometry, monkeypatch):
     """The tiles ``_tiles`` picks for each (heads a step, blocks a turn)
     fit the chip's scoped VMEM: the estimate in ``_step_bytes`` is held to
-    what the compiler allocates."""
+    what the compiler allocates — the kernel is compiled with that
+    estimate as its whole allowance (``vmem_limit_bytes``, set here and
+    not in the program), so buffers that grew past it are refused."""
     N, C, H, KH, D, MB, NB, window = geometry
-    assert (H // KH) * C <= pa.MAX_QUERY_ROWS
+    rows = (H // KH) * C
+    assert rows <= pa.MAX_QUERY_ROWS
     bf16 = jnp.bfloat16
+    kh_t, T = pa._tiles(rows, D, KH, 64, MB, bf16, bf16)
+    estimate = pa._step_bytes(kh_t, T, rows, D, 64, bf16, bf16)
+    assert estimate <= pa.VMEM_BUDGET
+    monkeypatch.setattr(pa.pltpu, "CompilerParams", functools.partial(
+        pa.pltpu.CompilerParams, vmem_limit_bytes=estimate))
     shapes = [((N, C, H, D), bf16), ((2, NB, KH, 64, D), bf16),
               ((2, NB, KH, 64, D), bf16), ((), jnp.int32),
               ((N, MB), jnp.int32), ((N,), jnp.int32), ((N,), jnp.int32)]
