@@ -1,0 +1,7 @@
+"""Device self time under the full attention layers' scope (full_attn: their qkv, kv_write, attend and attn_out), share of busy in percent. (the saturated cell's name)"""
+
+from benchmark import kv_group_readers
+
+
+def reduce(ctx):
+    return kv_group_readers.path_share(ctx, "full")

@@ -15,9 +15,11 @@ their own is a period of such positions.
 - the FFN: dropless top-k experts over a held range plus a shared expert
   (``moe/grouped.py``): softmax or sigmoid scores, a selection bias
   outside the weights, the shared expert under a sigmoid gate or bare;
-  gated SiLU experts or the ungated ``relu2`` (``moe_activation``); the
-  routed experts on the model's width or in a latent between two
-  projections (``moe_latent_size``).
+  gated experts (SiLU, or ReLU: ``reglu``) or the ungated ``relu2``
+  (``moe_activation``); the routed experts on the model's width or in a
+  latent between two projections (``moe_latent_size``); the router on
+  the FFN's normed input or on the layer's own input, ahead of the
+  mixer (``moe_router_input``).
   ``lead_layers`` run before the scanned periods with a dense MLP in its
   place, and so does every layer of a model without experts
   (``moe_num_experts`` 0); ``sandwich_norm`` puts a norm behind the mixer
@@ -28,7 +30,8 @@ their own is a period of such positions.
 mixer's cache lives differs (a kind's ``reference`` and ``paged``).
 Scopes follow ``docs/OBSERVABILITY.md``: ``attn_norm``, the kind's own
 (its module says), and ``mlp`` ⊃ ``router``, ``latent_proj``, ``experts``,
-``shared_expert`` or ``dense_mlp``.
+``shared_expert`` or ``dense_mlp`` (``router`` stands ahead of
+``attn_norm``, beside it, where ``moe_router_input`` is "layer").
 """
 
 from __future__ import annotations
@@ -40,6 +43,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..moe.grouped import GATED, dropless_moe_mlp
 from ..parallel.sharding import spec
 from .mixers import KINDS, kinds_of
 # (the kinds' functions that the benchmark's block tests reach under this
@@ -154,7 +158,7 @@ def init_slot(cfg, kind, key, periods: int, dense: bool = False,
     n_held = cfg.moe_held_experts[1] if cfg.moe_held_experts \
         else cfg.moe_num_experts
     m = cfg.moe_intermediate_size or cfg.intermediate_size
-    gated = cfg.moe_activation == "silu"
+    gated = cfg.moe_activation in GATED
     # the experts' width: the model's, or the latent's
     he = cfg.moe_latent_size or h
     lp["router_wg"] = w((h, cfg.moe_num_experts), 1.0 / math.sqrt(h))
@@ -190,7 +194,7 @@ def slot_specs(cfg, kind, dense: bool = False, ffn: bool = True):
                   w_gate=spec("layers", "embed", "mlp"),
                   w_out=spec("layers", "mlp", "embed"))
         return lp
-    gated = cfg.moe_activation == "silu"
+    gated = cfg.moe_activation in GATED
     # (experts in a latent: its width is no axis of the mesh)
     wide = None if cfg.moe_latent_size else "embed"
     lp.update(router_wg=spec("layers", "embed", None),
@@ -234,9 +238,15 @@ def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
     seen = {kind: 0 for kind in KINDS}
     scaled = (lambda y: y) if cfg.residual_scale == 1.0 else (
         lambda y: y * jnp.asarray(cfg.residual_scale, y.dtype))
+    early_router = cfg.moe_router_input == "layer" and not dense
     for kind, ffn, lp in zip(kinds, ffns, slots):
         if transform is not None:
             lp = transform(lp)
+        router_logits = None
+        if early_router:        # from the layer's input, as it comes in
+            with scope("router"):
+                router_logits = _router_logits(
+                    x.reshape(-1, x.shape[-1]), lp)
         if kind is not None:
             with scope("attn_norm"):
                 h1 = block_norm(cfg, x, lp["attn_norm_w"])
@@ -255,7 +265,8 @@ def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
             if dense or not cfg.moe_num_experts:
                 f, a = dense_ffn(cfg, h2, lp), 0.0
             else:
-                f, a = moe_ffn(cfg, h2, lp, valid=valid, max_rows=max_rows)
+                f, a = moe_ffn(cfg, h2, lp, valid=valid, max_rows=max_rows,
+                               router_logits=router_logits)
             if cfg.sandwich_norm:
                 f = block_norm(cfg, f, lp["post_mlp_norm_w"])
             x = x + scaled(f)
@@ -282,23 +293,31 @@ def dense_ffn(cfg, h2, lp):
                        lp["w_out"], None, dt)
 
 
-def moe_ffn(cfg, h2, lp, valid=None, max_rows=None):
+def _router_logits(rows, lp):
+    """The router's float32 logits [N, experts] of ``rows`` [N, H]."""
+    return rows.astype(jnp.float32) @ lp["router_wg"].astype(jnp.float32)
+
+
+def moe_ffn(cfg, h2, lp, valid=None, max_rows=None, router_logits=None):
     """The sparse FFN on its normed input [B, T, H]: the held experts'
     part of the top-k sum plus the shared expert. The experts are gated
-    SiLU ones or, with ``moe_activation`` "relu2", ``down(relu(up)²)``,
-    and so is the shared expert; with ``moe_latent_size`` the routed
-    ones run between ``latent_w_in`` and ``latent_w_out`` (the second
-    on the held experts' partial sum), the router and the shared expert
-    on the full width. ``valid`` [B, T]: padding reaches no expert;
-    ``max_rows`` bounds the valid rows. Returns (y, aux_loss)."""
-    from ..moe.grouped import dropless_moe_mlp
+    ones (``moe_activation`` "silu", "reglu") or, with "relu2",
+    ``down(relu(up)²)``, and so is the shared expert; with
+    ``moe_latent_size`` the routed ones run between ``latent_w_in`` and
+    ``latent_w_out`` (the second on the held experts' partial sum), the
+    router and the shared expert on the full width. ``valid`` [B, T]:
+    padding reaches no expert; ``max_rows`` bounds the valid rows.
+    ``router_logits`` [B * T, experts]: the router has read elsewhere
+    (``moe_router_input`` "layer": ``run_period``) and its matmul is not
+    made here. Returns (y, aux_loss)."""
     B, T, H = h2.shape
     dt = cfg.dtype
     rows = h2.reshape(B * T, H)
     flat_valid = None if valid is None else valid.reshape(B * T)
-    with jax.named_scope("router"):
-        logits = rows.astype(jnp.float32) \
-            @ lp["router_wg"].astype(jnp.float32)
+    logits = router_logits
+    if logits is None:
+        with jax.named_scope("router"):
+            logits = _router_logits(rows, lp)
     routed = rows
     if cfg.moe_latent_size:
         with jax.named_scope("latent_proj"):
@@ -320,8 +339,8 @@ def moe_ffn(cfg, h2, lp, valid=None, max_rows=None):
                 s = jnp.square(jax.nn.relu(
                     _linear(rows, lp["shared_w_in"], None, dt)))
             else:
-                s = jax.nn.silu(_linear(rows, lp["shared_w_gate"], None,
-                                        dt)) \
+                s = GATED[cfg.moe_activation](
+                    _linear(rows, lp["shared_w_gate"], None, dt)) \
                     * _linear(rows, lp["shared_w_in"], None, dt)
             s = _linear(s, lp["shared_w_out"], None, dt)
             if cfg.moe_shared_gate:

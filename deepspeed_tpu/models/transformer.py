@@ -145,10 +145,18 @@ class TransformerConfig:
     #   of the moe_num_experts routed over whose weights this model holds
     moe_intermediate_size: Optional[int] = None   # None → intermediate_size
     moe_shared_intermediate_size: int = 0         # shared expert; 0 = none
-    # the experts' (and the shared expert's) form: "silu" is the gated one
-    # (``down(silu(gate(x)) * up(x))``, three matrices an expert), "relu2"
-    # the ungated ``down(relu(up(x))²)``, two
+    # the experts' (and the shared expert's) form: "silu" and "reglu" are
+    # the gated ones (``down(act(gate(x)) * up(x))``, three matrices an
+    # expert: act is SiLU, or ReLU for "reglu"), "relu2" the ungated
+    # ``down(relu(up(x))²)``, two. (``activation`` "relu" above, a dense
+    # model's ungated MLP, is another thing.)
     moe_activation: str = "silu"
+    # where the sparse FFN's router reads: "ffn" — the FFN's own normed
+    # input, behind the mixer's residual add — or "layer": the layer's
+    # input as it comes in, ahead of the mixer's norm (the logits exist
+    # before attention and are handed to the FFN two sub-layers later).
+    # "layer" asks a mixer and an FFN of every position of the period.
+    moe_router_input: str = "ffn"
     # > 0: the routed experts run in a latent this wide, between two
     # projections every token passes once (``latent_w_in`` before the
     # dispatch, ``latent_w_out`` behind the combine); the router and the
@@ -257,13 +265,24 @@ class TransformerConfig:
                     f"layer_ffn {self.layer_ffn!r}: one entry a position "
                     f"of layer_pattern {pattern!r}, and every position a "
                     "mixer, an FFN or both")
-            if self.moe_activation not in ("silu", "relu2") or (
+            if self.moe_activation not in ("silu", "reglu", "relu2") or (
                     (self.moe_activation != "silu" or self.moe_latent_size)
                     and (lead or self.moe_num_experts <= 0)):
                 raise ValueError(
-                    "moe_activation is \"silu\" (gated) or \"relu2\"; it "
-                    "and moe_latent_size are the sparse FFN's (the dense "
-                    "MLP of lead_layers or of moe_num_experts 0 is SwiGLU)")
+                    "moe_activation is \"silu\" or \"reglu\" (gated: SiLU "
+                    "or ReLU of the gate) or \"relu2\" (ungated); it and "
+                    "moe_latent_size are the sparse FFN's (the dense MLP "
+                    "of lead_layers or of moe_num_experts 0 is SwiGLU)")
+            if self.moe_router_input not in ("ffn", "layer") or (
+                    self.moe_router_input == "layer"
+                    and (self.moe_num_experts <= 0 or None in pattern
+                         or not all(ffn))):
+                raise ValueError(
+                    "moe_router_input is \"ffn\" or \"layer\"; \"layer\" "
+                    "takes the router's logits from the input of a layer "
+                    "that has both a mixer and a sparse FFN, so every "
+                    "position of layer_pattern is a mixer (none is null), "
+                    "layer_ffn leaves none out and moe_num_experts > 0")
             kinds = kinds_of(self)
             for kind in kinds:
                 KINDS[kind].check(self)
@@ -277,8 +296,9 @@ class TransformerConfig:
                     "sparse one (moe_num_experts > 0) or the dense MLP "
                     "(0); sliding_window is its \"window\" layers' "
                     "length, and set with them only")
-        elif self.lead_layers:
-            raise ValueError("lead_layers belong to a layer_pattern")
+        elif self.lead_layers or self.moe_router_input != "ffn":
+            raise ValueError("lead_layers and moe_router_input belong to a "
+                             "layer_pattern")
 
     @property
     def head_dim(self) -> int:

@@ -59,9 +59,10 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
     """Top-k dropless MoE FFN over the experts this layer holds.
 
     tokens [N, H]; router_logits [N, E] (fp32) over ALL E experts;
-    w_in [n, H, M]; w_out [n, M, H]; w_gate [n, H, M] for SwiGLU
-    (``activation`` "silu"; without it "relu", "relu2" — the square of
-    the relu — or a gelu), where
+    w_in [n, H, M]; w_out [n, M, H]; w_gate [n, H, M] for a gated form
+    (``activation`` "silu": SwiGLU, "reglu": the gate through a relu;
+    without it "relu", "relu2" — the square of the relu — or a gelu),
+    where
     the n experts held are ``[lo, lo + n)`` (``held = (lo, n)``; None:
     all of them). A token's ``top_k`` experts are chosen over all E and
     weighted by their scores — softmax probabilities, or with
@@ -209,15 +210,28 @@ def gmm_tiles(k: int, n: int, itemsize: int = 2):
     return ks[-1], ns[-1]
 
 
+#: the gated forms of an expert, ``down(act(gate(x)) * up(x))`` (three
+#: matrices, ``w_gate`` among them): the activation's name -> act
+GATED = {"silu": jax.nn.silu, "reglu": jax.nn.relu}
+
+
 def _ragged_expert_ffn(st, gs, w_in, w_out, w_gate, activation, dtype,
                        matmul=lax.ragged_dot):
     """Grouped FFN over expert-sorted tokens ``st`` with group sizes
     ``gs`` (one trailing dummy group allowed when the weights carry an
-    extra zero expert)."""
+    extra zero expert). ``w_gate`` goes with the gated forms (``GATED``)
+    and with them only: a gate that no form would use is refused, not
+    dropped."""
+    if (w_gate is not None) != (activation in GATED):
+        raise ValueError(
+            f"activation {activation!r} "
+            + ("is gated and needs w_gate" if w_gate is None else
+               f"is ungated and would drop w_gate; the gated forms are "
+               f"{sorted(GATED)}"))
     h = matmul(st, w_in.astype(dtype), gs)
-    if w_gate is not None and activation == "silu":
+    if w_gate is not None:
         g = matmul(st, w_gate.astype(dtype), gs)
-        h = jax.nn.silu(g) * h
+        h = GATED[activation](g) * h
     elif activation == "relu":
         h = jax.nn.relu(h)
     elif activation == "relu2":

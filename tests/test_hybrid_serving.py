@@ -349,3 +349,65 @@ def test_compiled_ahead_the_engine_gives_the_same_logits_and_compiles_no_more(
         for uid in (1, 2):
             eng.flush(uid)
         assert eng.occupancy()["state_slots_used"] == 0
+
+
+# ---------------------------------------------- a router that reads early
+
+#: a block whose router reads the layer's own input, ahead of the mixer
+#: (``moe_router_input`` "layer"), over gated-ReLU experts all held here:
+#: one unrotated whole-context layer and three rotated window layers a
+#: period, 7 query heads a K/V head
+EARLY = TransformerConfig(
+    vocab_size=128, hidden_size=32, intermediate_size=24, num_layers=8,
+    num_heads=14, num_kv_heads=2, head_size=8, max_seq_len=128,
+    norm="rmsnorm", norm_eps=1e-6, activation="silu", position="rope",
+    rope_theta=1.5e6, tie_embeddings=False, dtype=jnp.float32,
+    layer_pattern=("full", "window", "window", "window"), sliding_window=16,
+    rope_kinds=("window",), moe_num_experts=8, moe_top_k=3,
+    moe_dropless=True, moe_norm_topk=True, moe_held_experts=(0, 8),
+    moe_intermediate_size=24, moe_activation="reglu",
+    moe_router_input="layer")
+
+
+@pytest.fixture(scope="module")
+def early_model_and_params():
+    model = CausalLM(EARLY)
+    params = model.init(jax.random.PRNGKey(2))
+    # projections loud enough that where the router reads shows
+    layers = {slot: {name: a if name.endswith("norm_w") else 4.0 * a
+                     for name, a in lp.items()}
+              for slot, lp in params["layers"].items()}
+    return model, dict(params, layers=layers)
+
+
+@pytest.mark.parametrize("n,chunk", [(45, 16), (37, 8)])
+def test_an_early_router_through_the_engine_agrees_with_apply(
+        early_model_and_params, n, chunk):
+    """Prefill in chunks across the window's edge, then decode through
+    both layer groups' pools: the logits ``CausalLM.apply`` gives, whose
+    ``run_period`` takes the router's logits from the layer's input too."""
+    model, params = early_model_and_params
+    eng = InferenceEngineV2(model, params=params,
+                            config=RaggedInferenceEngineConfig(**SIZING))
+    got, tokens = decode(eng, 7, prompt(n, n), steps=3, chunk=chunk)
+    want = np.asarray(model.apply(params, jnp.asarray(tokens)[None]))[0]
+    for step, g in enumerate(got):
+        w = want[n - 1 + step]
+        assert np.abs(g - w).max() < 1e-4 * np.abs(w).max()
+    assert eng.last_put["moe_rows_held"] == eng.last_put["moe_rows_routed"] \
+        == 3 * 8
+    eng.flush(7)
+    assert all(g.allocator.free_blocks == g.allocator.total_blocks
+               for g in eng.state_manager.groups)
+
+
+def test_where_the_router_reads_is_the_models(early_model_and_params):
+    """The same weights under the default — the router on the FFN's own
+    normed input — are another model; the field is no leaf of the tree."""
+    model, params = early_model_and_params
+    late = CausalLM(dataclasses.replace(EARLY, moe_router_input="ffn"))
+    assert jax.tree.structure(late.init(jax.random.PRNGKey(2))) \
+        == jax.tree.structure(params)
+    tokens = jnp.asarray(prompt(5, 40))[None]
+    a, b = (np.asarray(m.apply(params, tokens))[0] for m in (model, late))
+    assert np.abs(a - b).max() > 1e-3 * np.abs(a).max()

@@ -25,7 +25,12 @@ def weights(seed=0):
             "w_out": 0.3 * jax.random.normal(ks[4], (E, M, H))}
 
 
-def by_hand(w, top_k, renormalize, lo=0, n=E, valid=None):
+#: the gated forms: what the gate goes through, in float64
+GATES = {"silu": lambda a: a / (1 + np.exp(-a)),
+         "reglu": lambda a: np.maximum(a, 0.0)}
+
+
+def by_hand(w, top_k, renormalize, lo=0, n=E, valid=None, activation="silu"):
     """Every (token, choice) pair in a Python loop."""
     probs = np.asarray(jax.nn.softmax(w["logits"], -1), np.float64)
     out = np.zeros((N, H))
@@ -38,16 +43,16 @@ def by_hand(w, top_k, renormalize, lo=0, n=E, valid=None):
         for e, g in zip(top, gate):
             if lo <= e < lo + n:
                 a = x @ np.asarray(w["w_gate"][e], np.float64)
-                h = a / (1 + np.exp(-a)) * (x @ np.asarray(w["w_in"][e],
+                h = GATES[activation](a) * (x @ np.asarray(w["w_in"][e],
                                                            np.float64))
                 out[t] += g * (h @ np.asarray(w["w_out"][e], np.float64))
     return out
 
 
-def share(w, lo, n, **kw):
+def share(w, lo, n, activation="silu", **kw):
     out, _ = dropless_moe_mlp(
         w["tokens"], w["logits"], w["w_in"][lo:lo + n], w["w_out"][lo:lo + n],
-        w["w_gate"][lo:lo + n], activation="silu", held=(lo, n), **kw)
+        w["w_gate"][lo:lo + n], activation=activation, held=(lo, n), **kw)
     return np.asarray(out)
 
 
@@ -63,21 +68,43 @@ def test_top_k_against_a_loop_over_the_pairs(top_k, renormalize):
     assert np.isfinite(float(aux))
 
 
+@pytest.mark.parametrize("activation", ["silu", "reglu"])
 @pytest.mark.parametrize("parts", [1, 2, 4, 8])
-def test_the_shares_add_up_to_the_uncut_layer(parts):
+def test_the_shares_add_up_to_the_uncut_layer(parts, activation):
     """The experts split in ``parts`` holders: each routes over all of
-    them and computes its own; the parts summed are the whole layer."""
+    them and computes its own; the parts summed are the whole layer —
+    for either gated form of an expert."""
     w = weights(parts)
-    whole = share(w, 0, E, top_k=K, renormalize=True)
+    whole = share(w, 0, E, activation, top_k=K, renormalize=True)
     n = E // parts
-    held = [share(w, lo, n, top_k=K, renormalize=True)
+    held = [share(w, lo, n, activation, top_k=K, renormalize=True)
             for lo in range(0, E, n)]
+    by = lambda *a: by_hand(w, K, True, *a, activation=activation)  # noqa
     np.testing.assert_allclose(sum(held), whole, atol=2e-5)
-    np.testing.assert_allclose(whole, by_hand(w, K, True), atol=2e-5)
+    np.testing.assert_allclose(whole, by(), atol=2e-5)
     if parts > 1:       # and no part is the whole
         assert np.abs(held[0] - whole).max() > 1e-3
-    np.testing.assert_allclose(held[-1], by_hand(w, K, True, E - n, n),
-                               atol=2e-5)
+    np.testing.assert_allclose(held[-1], by(E - n, n), atol=2e-5)
+    if activation != "silu":        # the two gated forms are two layers
+        silu = share(w, 0, E, top_k=K, renormalize=True)
+        assert np.abs(whole - silu).max() > 1e-3
+
+
+@pytest.mark.parametrize("activation", ["relu", "relu2", "gelu"])
+def test_a_gate_that_no_form_would_use_is_refused(activation):
+    """An ungated activation handed ``w_gate`` used to drop it without a
+    word; a gated one without it ran a gelu. Both are refused."""
+    w = weights()
+    with pytest.raises(ValueError, match="ungated and would drop w_gate"):
+        dropless_moe_mlp(w["tokens"], w["logits"], w["w_in"], w["w_out"],
+                         w["w_gate"], activation=activation)
+    got, _ = dropless_moe_mlp(w["tokens"], w["logits"], w["w_in"],
+                              w["w_out"], None, activation=activation)
+    assert np.isfinite(np.asarray(got)).all()
+    for gated in ("silu", "reglu"):
+        with pytest.raises(ValueError, match="gated and needs w_gate"):
+            dropless_moe_mlp(w["tokens"], w["logits"], w["w_in"],
+                             w["w_out"], None, activation=gated)
 
 
 def test_renormalised_weights_sum_to_one():
@@ -160,6 +187,66 @@ def test_the_shared_expert_is_counted_once(parts):
         y, _ = hybrid.moe_ffn(cfg, x, part)
         total = total + (y - shared)
     np.testing.assert_allclose(total + shared, whole, atol=2e-5)
+
+
+@pytest.mark.parametrize("activation,gated", [("silu", True),
+                                              ("reglu", True),
+                                              ("relu2", False)])
+def test_every_gated_form_draws_and_shards_a_gate(activation, gated):
+    cfg = dataclasses.replace(CFG, moe_activation=activation)
+    lp = hybrid.init_slot(cfg, "full", jax.random.PRNGKey(0), 2)
+    assert ("w_gate" in lp) == ("shared_w_gate" in lp) == gated
+    assert set(hybrid.slot_specs(cfg, "full")) == set(lp)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 5, H))
+    y, _ = hybrid.moe_ffn(cfg, x, {k: v[0] for k, v in lp.items()})
+    assert y.shape == x.shape and np.isfinite(np.asarray(y)).all()
+
+
+@pytest.mark.parametrize("wrong", [
+    dict(moe_activation="relu"), dict(moe_activation="gelu"),
+    dict(moe_router_input="attention"),
+    dict(moe_router_input="layer", layer_pattern=("linear", None),
+         layer_ffn=(False, True)),
+    dict(moe_router_input="layer", layer_ffn=(True, False)),
+    dict(moe_router_input="layer", moe_num_experts=0,
+         moe_shared_intermediate_size=0)],
+    ids=["dense-relu", "gelu", "unknown-input", "a-position-without-mixer",
+         "a-position-without-ffn", "no-experts"])
+def test_what_the_sparse_ffn_cannot_be_is_refused_with_a_sentence(wrong):
+    with pytest.raises(ValueError, match="moe_activation is|"
+                                         "moe_router_input is"):
+        dataclasses.replace(CFG, **wrong)
+
+
+def test_the_router_may_read_the_layers_input_only_in_a_hybrid_block():
+    with pytest.raises(ValueError, match="belong to a layer_pattern"):
+        TransformerConfig(moe_router_input="layer")
+    assert dataclasses.replace(CFG, moe_router_input="layer",
+                               moe_activation="reglu").moe_router_input \
+        == "layer"
+
+
+def test_early_logits_replace_the_ffns_own_router():
+    """``moe_ffn`` given ``router_logits`` makes no router matmul of its
+    own: the logits of its normed input, handed in, give its own answer;
+    other logits give another."""
+    cfg = dataclasses.replace(CFG, moe_shared_intermediate_size=0)
+    lp = layer_params(cfg, jax.random.PRNGKey(3))
+    lp["router_wg"] = 3.0 * lp["router_wg"]
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 9, H))
+    own, _ = hybrid.moe_ffn(cfg, x, lp)
+    logits = x.reshape(-1, H) @ lp["router_wg"]
+    same, _ = hybrid.moe_ffn(cfg, x, lp, router_logits=logits)
+    np.testing.assert_array_equal(np.asarray(own), np.asarray(same))
+    other, _ = hybrid.moe_ffn(cfg, x, lp, router_logits=logits[::-1])
+    assert np.abs(np.asarray(other) - np.asarray(own)).max() > 1e-4
+    def lowered(early):
+        return jax.jit(lambda x, lg: hybrid.moe_ffn(
+            cfg, x, lp, router_logits=lg if early else None)[0]
+        ).lower(x, logits).as_text(debug_info=True)
+
+    assert "/router/" in lowered(False)
+    assert "/router/" not in lowered(True)
 
 
 def test_init_holds_the_share_and_routes_over_all():
