@@ -580,18 +580,28 @@ class PagedCausalLM:
             run = fwd._replace(pools=pools, first_layer=first_layer)
             return {kind: KINDS[kind].paged(cfg, run) for kind in kinds}
 
+        slots = tuple(params["layers"][f"slot{i}"]
+                      for i in range(len(pattern)))
+        # where the scan is a loop, the routed experts stay off its xs:
+        # the body indexes them in their stacks (``hybrid.expert_stacks``).
+        # A scan of one period is unrolled and its slices are bitcasts
+        stacks = None
+        if cfg.num_periods > 1:
+            slots, stacks = hybrid.expert_stacks(cfg, slots)
+
         def period(carry, xs):
             x, pools = carry
             slots, p = xs
+            if stacks is not None:
+                slots = tuple({**lp, **st} for lp, st in zip(slots, stacks))
             pools = dict(pools)         # the mixers below write into it
             first = {kind: lead.count(kind) + p * pattern.count(kind)
                      for kind in kinds}
             x, _ = hybrid.run_period(cfg, x, slots, mixers_for(pools, first),
-                                     valid=valid, max_rows=max_rows)
+                                     valid=valid, max_rows=max_rows,
+                                     period=None if stacks is None else p)
             return (x, pools), None
 
-        slots = tuple(params["layers"][f"slot{i}"]
-                      for i in range(len(pattern)))
         with scope("layers"):
             pools = dict(cache)
             if lead:

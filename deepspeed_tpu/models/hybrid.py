@@ -2,7 +2,9 @@
 (``TransformerConfig.layer_pattern``: one period of mixer kinds, scanned
 one period an iteration — in serving too, however few the periods: two
 periods laid out inline ran slower than the loop in every program
-measured, PERF.md section 6, PR 46). A position of the period is a mixer
+measured, PERF.md section 6, PR 46; where that loop runs more than once
+the serving forward keeps the routed experts off its ``xs``:
+``expert_stacks``). A position of the period is a mixer
 followed by the FFN, a mixer alone (``layer_ffn`` False there) or an FFN
 alone (None in the pattern): one norm and one residual add for each part
 it has, so a model that counts its mixers and its FFNs as layers of
@@ -220,7 +222,7 @@ def slot_specs(cfg, kind, dense: bool = False, ffn: bool = True):
 # ----------------------------------------------------------------- period
 
 def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
-               max_rows=None, transform=None):
+               max_rows=None, transform=None, period=None):
     """A run of layers on x [B, T, H] — one period (``kinds`` None: the
     pattern) or the lead layers (``dense``) — each position's norm, its
     mixer, the FFN and the residual adds (a position of the pattern may
@@ -228,7 +230,9 @@ def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
     weights, one tree a position. ``mixers[kind](h1, lp, i)`` -> the
     mixer's output for the i-th layer of its kind in the run: where the
     cache lives is the caller's (training keeps none, serving paged pools
-    and state slots). Returns (x, summed aux loss)."""
+    and state slots). ``period``: the slots' routed experts are whole
+    stacks over the periods (``expert_stacks``) and this is the period to
+    run. Returns (x, summed aux loss)."""
     scope = jax.named_scope
     aux = jnp.zeros((), jnp.float32)
     # the pattern's positions say which carry an FFN; a lead layer does
@@ -266,12 +270,41 @@ def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
                 f, a = dense_ffn(cfg, h2, lp), 0.0
             else:
                 f, a = moe_ffn(cfg, h2, lp, valid=valid, max_rows=max_rows,
-                               router_logits=router_logits)
+                               router_logits=router_logits, period=period)
             if cfg.sandwich_norm:
                 f = block_norm(cfg, f, lp["post_mlp_norm_w"])
             x = x + scaled(f)
         aux = aux + a
     return x, aux
+
+
+#: the routed experts' leaves of a sparse position
+EXPERT_LEAVES = ("w_in", "w_gate", "w_out")
+
+
+def expert_stacks(cfg, slots):
+    """The pattern's ``slots`` (every leaf stacked over the P periods) in
+    two, for a scan over the periods that calls a kernel: ``(scanned,
+    stacks)``, one tree a position each. ``stacks`` holds a sparse
+    position's routed experts (``EXPERT_LEAVES``), each [P, n, k, m]
+    stack as [P · n, k, m] (a bitcast: the two minor dimensions carry the
+    tiling) for the scan to close over -- its body merges them back into
+    the period's tree and runs it with ``run_period(period=p)``, and the
+    grouped matmul indexes period p's experts where they lie.
+    ``scanned`` is everything else, the scan's ``xs``: norms, the router,
+    the mixer's and the shared expert's weights feed XLA dots, which
+    absorb the scan's ``dynamic-slice``; a Mosaic kernel's operand cannot,
+    so a scanned expert leaf is copied out of its stack, whole, in front
+    of every ``gmm`` (PERF.md section 6, PR 56)."""
+    sparse = [bool(cfg.moe_num_experts) and ffn
+              for ffn in cfg.layer_ffn or (True,) * len(slots)]
+    stacks = tuple(
+        {name: lp[name].reshape((-1,) + lp[name].shape[2:])
+         for name in EXPERT_LEAVES if here and name in lp}
+        for lp, here in zip(slots, sparse))
+    scanned = tuple({name: a for name, a in lp.items() if name not in st}
+                    for lp, st in zip(slots, stacks))
+    return scanned, stacks
 
 
 def lead_slots(cfg, params):
@@ -298,7 +331,8 @@ def _router_logits(rows, lp):
     return rows.astype(jnp.float32) @ lp["router_wg"].astype(jnp.float32)
 
 
-def moe_ffn(cfg, h2, lp, valid=None, max_rows=None, router_logits=None):
+def moe_ffn(cfg, h2, lp, valid=None, max_rows=None, router_logits=None,
+            period=None):
     """The sparse FFN on its normed input [B, T, H]: the held experts'
     part of the top-k sum plus the shared expert. The experts are gated
     ones (``moe_activation`` "silu", "reglu") or, with "relu2",
@@ -309,7 +343,9 @@ def moe_ffn(cfg, h2, lp, valid=None, max_rows=None, router_logits=None):
     padding reaches no expert; ``max_rows`` bounds the valid rows.
     ``router_logits`` [B * T, experts]: the router has read elsewhere
     (``moe_router_input`` "layer": ``run_period``) and its matmul is not
-    made here. Returns (y, aux_loss)."""
+    made here. ``period``: ``lp``'s routed experts are the stacks of all
+    periods and the grouped matmul indexes this one's
+    (``expert_stacks``). Returns (y, aux_loss)."""
     B, T, H = h2.shape
     dt = cfg.dtype
     rows = h2.reshape(B * T, H)
@@ -329,7 +365,7 @@ def moe_ffn(cfg, h2, lp, valid=None, max_rows=None, router_logits=None):
             renormalize=cfg.moe_norm_topk, held=cfg.moe_held_experts,
             valid=flat_valid, max_rows=max_rows,
             score_func=cfg.moe_score_func, select_bias=lp.get("router_b"),
-            route_scale=cfg.moe_route_scale)
+            route_scale=cfg.moe_route_scale, period=period)
     if cfg.moe_latent_size:
         with jax.named_scope("latent_proj"):
             y = _linear(y, lp["latent_w_out"], None, dt)

@@ -54,7 +54,8 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
                      max_rows: Optional[int] = None,
                      score_func: str = "softmax",
                      select_bias: Optional[jax.Array] = None,
-                     route_scale: float = 1.0
+                     route_scale: float = 1.0,
+                     period: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """Top-k dropless MoE FFN over the experts this layer holds.
 
@@ -74,6 +75,14 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
     ``valid`` [N] bool: rows that are padding; they reach no expert.
     ``max_rows``: a bound the caller knows on the number of valid rows —
     the grouped GEMMs then run over that many rows and not over N.
+    ``period`` (a scalar, traced in a scan): the weights are not this
+    layer's own but the whole stacks of a scan's P periods,
+    [P · n, ...] with period p's experts at ``[p · n, (p + 1) · n)``
+    (n from ``held``, or E), and this layer is period ``period``'s. Its
+    group sizes go into a vector over all P · n groups at ``period`` · n
+    and the grouped matmul indexes the stack: the other periods' groups
+    are empty and cost nothing, and no copy of the layer's experts is
+    made in front of a kernel that cannot absorb a slice (``gmm``).
 
     Returns (out [N, H], aux_loss) — aux is the GShard load-balancing
     loss (E · Σ_e fraction_tokens_e · fraction_probs_e), same as
@@ -81,7 +90,13 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
     """
     N, H = tokens.shape
     E = router_logits.shape[-1]
-    n_held = w_in.shape[0]
+    if period is None:
+        n_held = w_in.shape[0]
+    else:
+        n_held = E if held is None else int(held[1])
+        if w_in.shape[0] % n_held:
+            raise ValueError(f"stacks of {w_in.shape[0]} experts are no "
+                             f"whole periods of {n_held}")
     lo = 0 if held is None else int(held[0])
     if held is not None and int(held[1]) != n_held:
         raise ValueError(f"held {held} but {n_held} experts' weights")
@@ -129,6 +144,10 @@ def dropless_moe_mlp(tokens: jax.Array, router_logits: jax.Array,
     order = jnp.argsort(key)                              # stable
     src = order // top_k                                  # pair -> row
     group_sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:-1]
+    if period is not None:
+        group_sizes = lax.dynamic_update_slice(
+            jnp.zeros((w_in.shape[0],), jnp.int32), group_sizes,
+            (period * n_held,))
     out_sorted = _ragged_expert_ffn(tokens[src].astype(dtype), group_sizes,
                                     w_in, w_out, w_gate, activation, dtype,
                                     matmul=grouped_matmul)

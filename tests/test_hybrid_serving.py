@@ -411,3 +411,72 @@ def test_where_the_router_reads_is_the_models(early_model_and_params):
     tokens = jnp.asarray(prompt(5, 40))[None]
     a, b = (np.asarray(m.apply(params, tokens))[0] for m in (model, late))
     assert np.abs(a - b).max() > 1e-3 * np.abs(a).max()
+
+
+# ------------------------------------ experts indexed in their stacks
+
+def _sliced_by_hand(real):
+    """``dropless_moe_mlp`` as a scan that carried the expert leaves in
+    its ``xs`` would call it: the period's experts cut out of their
+    stacks (``stack[p]``) in front of the call, the call on them alone."""
+    def mlp(tokens, logits, w_in, w_out, w_gate=None, *, period, held, **kw):
+        n = logits.shape[-1] if held is None else held[1]
+
+        def cut(w):
+            return None if w is None else jax.lax.dynamic_slice_in_dim(
+                w, period * n, n)
+        return real(tokens, logits, cut(w_in), cut(w_out), cut(w_gate),
+                    held=held, **kw)
+    return mlp
+
+
+@pytest.mark.parametrize("cfg", [
+    pytest.param(CFG, id="a_held_share_gated"),
+    pytest.param(dataclasses.replace(CFG, moe_activation="relu2"),
+                 id="a_held_share_ungated"),
+    pytest.param(EARLY, id="an_early_router_reglu"),
+    pytest.param(dataclasses.replace(EARLY, moe_held_experts=None),
+                 id="every_expert_no_held")])
+def test_experts_indexed_in_their_stacks_are_the_periods_own(cfg,
+                                                             monkeypatch):
+    """Two periods deep the scan closes over the expert stacks and the
+    grouped matmul indexes the period's experts in them
+    (``hybrid.expert_stacks``): chunk ``[1, C]`` and decode ``[S, 1]``
+    forwards, one row of the step padding, give bit for bit the logits and
+    the cache of the same forwards with each period's leaves sliced out
+    by hand."""
+    from deepspeed_tpu.models import hybrid
+
+    assert cfg.num_periods == 2
+    model = CausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(3))
+    p, q, r = prompt(11, 27), prompt(12, 5), prompt(13, 16)
+    puts = [([1], [p[:16]]), ([1, 2, 3], [p[16:], q, r]),
+            ([1, 2, 3], [[7], [9], [11]]), ([1, 3], [[5], [6]])]
+
+    def run():
+        eng = InferenceEngineV2(model, params=params,
+                                config=RaggedInferenceEngineConfig(**SIZING))
+        logits, buckets = [], []
+        for uids, tokens in puts:
+            logits.append(np.asarray(eng.put(uids, tokens)))
+            buckets.append((eng.last_put["bucket_seqs"],
+                            eng.last_put["bucket_chunk"]))
+        # chunk rows short of their bucket, three rows in a step of four
+        assert buckets == [(1, 16), (1, 16), (4, 1), (2, 1)]
+        return logits, {k: np.asarray(v) for k, v in
+                        eng.state_manager.forward_cache.items()}
+
+    stacked = run()
+    seen = []
+    by_hand = _sliced_by_hand(hybrid.dropless_moe_mlp)
+    monkeypatch.setattr(
+        hybrid, "dropless_moe_mlp",
+        lambda *a, **kw: seen.append(kw["period"]) or by_hand(*a, **kw))
+    sliced = run()
+    assert seen and all(s is not None for s in seen)
+    for a, b in zip(stacked[0], sliced[0]):
+        np.testing.assert_array_equal(a, b)
+    assert stacked[1].keys() == sliced[1].keys()
+    for leaf in stacked[1]:
+        np.testing.assert_array_equal(stacked[1][leaf], sliced[1][leaf])

@@ -301,3 +301,127 @@ def test_registry_picks_dropless_under_ep():
         assert isinstance(fn, partial) and fn.func is dropless_moe_mlp_ep
     finally:
         topo.reset_topology()
+
+
+# ----------------------------------- a period's experts in their stack
+
+def _stack(rng, periods, n, k, m):
+    return jnp.asarray(rng.normal(size=(periods, n, k, m)).astype(np.float32))
+
+
+def _sizes_at(sizes, p, periods):
+    """``sizes`` [n] as period p's share of the group sizes of a stack of
+    ``periods`` x n groups: every other period's groups are empty."""
+    n = sizes.shape[0]
+    return jnp.zeros((periods * n,), jnp.int32).at[p * n:(p + 1) * n].set(
+        sizes)
+
+
+@pytest.mark.parametrize("p", range(3))
+@pytest.mark.parametrize("sizes", [
+    pytest.param([5, 3, 8, 4], id="every_row"),
+    pytest.param([2, 0, 7, 0], id="empty_groups_and_rows_behind"),
+    pytest.param([0, 0, 0, 20], id="one_group"),
+    pytest.param([0, 0, 0, 0], id="no_rows")])
+def test_grouped_matmul_indexes_a_period_in_its_stack(p, sizes):
+    """``grouped_matmul`` over the whole stack [P · n, k, m] with the
+    period's group sizes at ``p · n`` is, bit for bit, ``grouped_matmul``
+    over ``stack[p]``: the rows of the period's groups (what lies behind
+    the last group is undefined either way)."""
+    from deepspeed_tpu.moe.grouped import grouped_matmul
+
+    rng = np.random.default_rng(7)
+    P, n, k, m = 3, 4, 16, 24
+    stack = _stack(rng, P, n, k, m)
+    lhs = jnp.asarray(rng.normal(size=(20, k)).astype(np.float32))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    whole = grouped_matmul(lhs, stack.reshape(P * n, k, m),
+                           _sizes_at(sizes, p, P))
+    own = grouped_matmul(lhs, stack[p], sizes)
+    rows = int(sizes.sum())
+    np.testing.assert_array_equal(np.asarray(whole)[:rows],
+                                  np.asarray(own)[:rows])
+    assert whole.shape == own.shape == (20, m)
+
+
+@pytest.mark.parametrize("p", range(2))
+@pytest.mark.parametrize("held,activation,padded", [
+    (None, "silu", False), ((2, 4), "silu", True), ((0, 8), "reglu", True),
+    ((4, 4), "relu2", False), (None, "relu2", True)])
+def test_dropless_indexes_a_period_in_its_stacks(p, held, activation, padded):
+    """``dropless_moe_mlp(period=p)`` on the stacks of two periods'
+    experts, traced as a scan's body would call it, is bit for bit the
+    call on the period's own leaves — all experts or a held share, gated
+    or not, with padding rows under a bound on the valid ones."""
+    from deepspeed_tpu.moe.grouped import GATED, dropless_moe_mlp
+
+    rng = np.random.default_rng(11)
+    N, H, M, E, P = 24, 8, 16, 8, 2
+    n = E if held is None else held[1]
+    tokens = jnp.asarray(rng.normal(size=(N, H)).astype(np.float32))
+    logits = jnp.asarray(rng.normal(size=(N, E)).astype(np.float32))
+    w_in, w_out = _stack(rng, P, n, H, M), _stack(rng, P, n, M, H)
+    w_gate = _stack(rng, P, n, H, M) if activation in GATED else None
+    kw = dict(activation=activation, top_k=3, renormalize=True, held=held)
+    if padded:
+        kw.update(valid=jnp.arange(N) % 3 != 0, max_rows=16)
+
+    def flat(w):
+        return None if w is None else w.reshape((P * n,) + w.shape[2:])
+
+    whole, aux = jax.jit(lambda period: dropless_moe_mlp(
+        tokens, logits, flat(w_in), flat(w_out), flat(w_gate),
+        period=period, **kw))(jnp.int32(p))
+    own, aux_own = jax.jit(lambda: dropless_moe_mlp(
+        tokens, logits, w_in[p], w_out[p],
+        None if w_gate is None else w_gate[p], **kw))()
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(own))
+    assert float(aux) == float(aux_own)
+    assert np.abs(np.asarray(own)).max() > 0
+
+
+def test_dropless_refuses_stacks_that_are_no_whole_periods():
+    from deepspeed_tpu.moe.grouped import dropless_moe_mlp
+
+    w = jnp.zeros((6, 8, 16), jnp.float32)
+    with pytest.raises(ValueError, match="no whole periods of 4"):
+        dropless_moe_mlp(jnp.zeros((4, 8)), jnp.zeros((4, 8)), w,
+                         jnp.zeros((6, 16, 8)), held=(0, 4), period=0)
+
+
+@pytest.mark.parametrize("p", range(2))
+def test_the_pallas_gmm_gives_other_periods_groups_no_tile(p):
+    """What ``grouped_matmul`` counts on of the kernel JAX ships, held
+    here in interpret mode: with the period's group sizes in a vector
+    over the whole stack's groups, the grid has as many active tiles as
+    over the period's own experts (an empty group gets none, in front of
+    the period's or behind), each reads the stack at the period's index,
+    and the rows are the same."""
+    import importlib
+
+    # (the package's ``gmm`` is its differentiable wrapper, not the module)
+    megablox = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    rng = np.random.default_rng(5)
+    P, n, k, m, rows = 2, 4, 128, 128, 256
+    stack = _stack(rng, P, n, k, m)
+    lhs = jnp.asarray(rng.normal(size=(rows, k)).astype(np.float32))
+    sizes = jnp.asarray([5, 0, 130, 20], jnp.int32)
+
+    def tiles(group_sizes):
+        (_, group_ids, _), active = megablox.make_group_metadata(
+            group_sizes=group_sizes, m=rows, tm=128, start_group=jnp.int32(0),
+            num_nonzero_groups=group_sizes.shape[0],
+            visit_empty_groups=False)
+        return np.asarray(group_ids)[:int(active)]
+
+    np.testing.assert_array_equal(tiles(_sizes_at(sizes, p, P)),
+                                  tiles(sizes) + p * n)
+    whole, own = (megablox.gmm(lhs, rhs, gs,
+                               preferred_element_type=jnp.float32,
+                               tiling=(128, 128, 128), interpret=True)
+                  for rhs, gs in ((stack.reshape(P * n, k, m),
+                                   _sizes_at(sizes, p, P)),
+                                  (stack[p], sizes)))
+    np.testing.assert_array_equal(np.asarray(whole)[:155],
+                                  np.asarray(own)[:155])

@@ -14,7 +14,8 @@ import re
 
 import pytest
 from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
-                                 configuration, kernels, lowered, nbytes, v5e)
+                                 configuration, kernels, lowered, nbytes,
+                                 stacked_group_sizes, v5e)
 
 from deepspeed_tpu.ops import paged_attention as pa
 
@@ -56,6 +57,10 @@ def test_hybrid_forward_at_published_widths(v5e, bucket, monkeypatch):
     assert not any(k.startswith("ragged") for k in found)
     scoped = re.findall(r'%gmm[.\d]* = [^\n]*op_name="([^"]*)"', text)
     assert scoped and all("/mlp/experts/" in s for s in scoped)
+    # one period deep: the scan is unrolled, its slice of an expert leaf
+    # is a bitcast, and the experts are the period's own as they always
+    # were -- no group sizes at an offset into a stack
+    assert cfg.num_periods == 1 and not stacked_group_sizes(text)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(nbytes(s) for s in cache.values())
     # no copy of the state tree (a slot a sequence and one x 3 layers x

@@ -10,16 +10,19 @@ the first odd group among the cells: a one-token step's query tile is 7
 rows a K/V head, a 2,048-token chunk is cut in pieces of 256 tokens
 (1,792 query rows) — one call a layer over that layer's group; the
 grouped matmul as the Pallas ``gmm`` at the benchmark's smallest expert
-(768 wide, 64 groups); the router's matmul ahead of the attention norm;
-every pool leaf aliased to the output. See tests/test_tpu_compile.py for
-the method and tests/tpu_compile_harness.py for what is shared."""
+(768 wide), each call on the whole stack of both periods' experts, 128
+groups, with the period's group sizes at its offset -- no copy of an
+expert leaf out of its stack in front of a call; the router's matmul
+ahead of the attention norm; every pool leaf aliased to the output. See
+tests/test_tpu_compile.py for the method and
+tests/tpu_compile_harness.py for what is shared."""
 
 import re
 
 import pytest
 from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
                                  configuration, fits_beside, kernels, lowered,
-                                 v5e)
+                                 stacked_group_sizes, v5e)
 
 from deepspeed_tpu.moe.grouped import gmm_tiles
 from deepspeed_tpu.ops import paged_attention as pa
@@ -62,6 +65,21 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
     # gate, up, down in each of the period's four layers, nothing of XLA's
     assert found.count("gmm") == 12
     assert not any(k.startswith("ragged") for k in found)
+    # each reads its experts where they lie: the operand is the stack of
+    # both periods (a bitcast of the parameter, carried by the loop), the
+    # period's group sizes sit at its offset among 128, and nothing in the
+    # module is one period's expert leaf (a leaf that rides the scan's xs
+    # is written out of its stack, 240 MiB, in front of its call)
+    stacks = re.findall(
+        r"%gmm[.\d]* = [^\n]*?(%[\w.\-]+)\), custom_call", text)
+    assert len(stacks) == 12
+    for operand in set(stacks):
+        made = re.search(re.escape(operand) + r" = (\S+) (\S+?)\(", text)
+        assert made.group(1).startswith(("bf16[128,2560,768]",
+                                         "bf16[128,768,2560]"))
+        assert made.group(2) == "get-tuple-element"
+    assert not re.search(r"bf16\[64,(2560,768|768,2560)\]", text)
+    assert stacked_group_sizes(text)
     scoped = re.findall(r'%paged_attention[.\d]* = [^\n]*op_name="([^"]*)"',
                         text)
     assert scoped and all("/attend/" in s and ("window_attn" in s
@@ -77,3 +95,6 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
     # room for the check's float32 reference when nothing runs (2.46 GiB
     # of logits at 4,352 positions and what they are made from)
     fits_beside(compiled, params, cache, bucket, headroom=4 * 2 ** 30)
+    # a decode step's temporaries hold no expert leaf (240 MiB)
+    if C == 1:
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20

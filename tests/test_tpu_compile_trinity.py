@@ -19,7 +19,7 @@ import re
 import pytest
 from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
                                  configuration, fits_beside, kernels, lowered,
-                                 v5e)
+                                 stacked_group_sizes, v5e)
 
 from deepspeed_tpu.ops import paged_attention as pa
 
@@ -68,6 +68,8 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
     # gate, up, down in each of the four sparse layers, nothing of XLA's own
     assert found.count("gmm") == 12
     assert not any(k.startswith("ragged") for k in found)
+    # one period deep: the experts are the period's own leaves, as ever
+    assert cfg.num_periods == 1 and not stacked_group_sizes(text)
     scoped = re.findall(r'%paged_attention[.\d]* = [^\n]*op_name="([^"]*)"',
                         text)
     assert scoped and all("/attend/" in s and ("window_attn" in s

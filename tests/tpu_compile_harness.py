@@ -70,6 +70,16 @@ def kernels(text):
         r"%([a-z_\-][a-z\d_\-]*?)[.\d]* = [^\n]*tpu_custom_call", text)
 
 
+def stacked_group_sizes(text):
+    """The ``dynamic_update_slice``s under ``mlp/experts`` in an optimized
+    HLO module, by ``op_name``: a period's group sizes written into a
+    vector over the groups of a whole stack of periods
+    (``moe/grouped.dropless_moe_mlp(period=)``). A forward one period deep
+    takes the path it always took and holds none."""
+    return re.findall(
+        r'op_name="([^"]*/mlp/experts/dynamic_update_slice)"', text)
+
+
 def spec_on(device):
     one = SingleDeviceSharding(device)
     return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
