@@ -34,7 +34,6 @@ config on the CPU mesh; the script itself has no such option.
 
 import argparse
 import dataclasses
-import functools
 import gc
 import json
 import sys
@@ -52,31 +51,12 @@ KERNEL_CALL = "tpu_custom_call"
 
 # ----------------------------------------------------------------- plumbing
 
-class _CompileWatch:
-    """Seconds JAX spent in backend compiles (cache reads included), their
-    count, and persistent-cache hits — from JAX's own monitoring events."""
+def _programs_built():
+    """Backend compiles so far (cache reads included), as the program's
+    recorder of JAX's build events counts them."""
+    from deepspeed_tpu.telemetry.builds import RECORDER
 
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.seconds, self.count, self.hits = 0.0, 0, 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-            self.count += 1
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-
-@functools.cache
-def _compiles():
-    """The process's one watch (JAX's listeners cannot be taken back)."""
-    return _CompileWatch()
+    return RECORDER.counters()["program_builds"]
 
 
 def check(ok, why):
@@ -107,17 +87,22 @@ def memory_record():
 
 def run_phase(name, fn, **kwargs):
     """Run one phase and print its JSON line. A raise propagates."""
-    watch = _compiles()
-    s0, c0, h0 = watch.seconds, watch.count, watch.hits
-    t0 = time.perf_counter()
+    # the program's own record of what JAX built (backend compiles, cache
+    # reads included, and the persistent cache's hits among them)
+    from deepspeed_tpu.telemetry.builds import RECORDER
+
+    t0 = time.monotonic()
+    before = RECORDER.snapshot()["announced"]["compile"]
     checked = fn(**kwargs)
+    built = RECORDER.snapshot(since=t0)
+    after = built["announced"]["compile"]
     dev = device_record()
     line = {"phase": name, "platform": dev["platform"],
             "device_kind": dev["kind"], "devices": dev["count"],
-            "seconds": round(time.perf_counter() - t0, 2),
-            "compile_seconds": round(watch.seconds - s0, 2),
-            "compilations": watch.count - c0,
-            "compile_cache_hits": watch.hits - h0,
+            "seconds": round(time.monotonic() - t0, 2),
+            "compile_seconds": round(after["seconds"] - before["seconds"], 2),
+            "compilations": after["count"] - before["count"],
+            "compile_cache_hits": built["cache_hits"],
             "checked": checked, "memory": memory_record()}
     print(json.dumps(line), flush=True)
     return checked
@@ -377,9 +362,9 @@ def serve_phase(cfg, seed=0, prompt_lens=(), max_new=32, engine_cfg=None):
     fe = ServingFrontend([engine], ServingConfig())
     try:
         streams = serve_all(fe)
-        warm = _compiles().count
+        warm = _programs_built()
         serve_all(fe)                # again: every bucket is compiled now
-        after_warmup = _compiles().count - warm
+        after_warmup = _programs_built() - warm
         free = engine.state_manager.available_blocks
         check(free == kv_blocks, f"{kv_blocks - free} KV blocks not returned")
     finally:

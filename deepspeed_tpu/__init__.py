@@ -120,3 +120,11 @@ def init_distributed(dist_backend="xla", **kwargs):
     from .comm import init_distributed as _init
 
     return _init(dist_backend=dist_backend, **kwargs)
+
+
+# the process's recorder of program builds and full collections listens
+# from here on (telemetry/builds.py): before anything the package builds.
+# At the end of the file, so that no line above it moves.
+from .telemetry.builds import RECORDER as _BUILDS  # noqa: E402
+
+_BUILDS.start()

@@ -371,6 +371,7 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
     # (here, not at import: this module is read by processes that never
     # load a model)
     from ..models.mixers import PUT_TOTALS
+    from ..telemetry.builds import COUNTER_NAMES as BUILD_COUNTERS
 
     reg = MetricsRegistry("serving")
     all_classes = list(dict.fromkeys(list(STOCK_CLASSES) + list(classes)))
@@ -411,6 +412,14 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # had run dry: steps_starved / scheduler_steps is the share
               # of steps the host was late for
               "scheduler_steps", "steps_overlapped", "steps_starved",
+              # what the process built and collected, as JAX and the
+              # collector report it themselves (telemetry/builds.py,
+              # delta-published per Replica, once a process): backend
+              # compiles (cache reads among them), the thread seconds of
+              # trace + lower + compile, persistent-cache misses; full
+              # (generation-2) collections and their seconds. On a warm
+              # replica a program_builds that rises is a recompile
+              *BUILD_COUNTERS,
               # a hybrid model's sparse FFNs: (token, choice) pairs routed
               # and, of those, the pairs whose expert this replica holds
               # (the expectation under even routing: engine._count_routing)

@@ -34,6 +34,7 @@ from typing import Callable, Dict, Optional
 
 from ..inference.v2.scheduler import ContinuousBatchingScheduler
 from ..models.mixers import PUT_TOTALS
+from ..telemetry.builds import RECORDER as BUILDS
 from ..utils.locks import RankedLock
 from ..utils.logging import logger
 from .metrics import MetricsRegistry
@@ -648,6 +649,12 @@ class Replica:
             if delta:
                 self.metrics.counter(name).inc(delta)
         self._step_last = steps
+        # programs JAX built and full collections the collector ran, as
+        # the process's recorder heard them (telemetry/builds.py): on a
+        # warm replica a program_builds that still rises is a recompile
+        for name, delta in BUILDS.unpublished(self.metrics).items():
+            if delta:
+                self.metrics.counter(name).inc(delta)
         # tiered KV memory (docs/SERVING.md "KV tiering"): spill/restore
         # counters as deltas, per-block restore times into the histogram
         tier_fn = getattr(self.engine, "tier_stats", None)

@@ -31,12 +31,17 @@ class TelemetryConfig(DSConfigModel):
 
     def build_tracer(self):
         """The configured tracer — the shared NOOP singleton when
-        disabled, so call sites hold one object either way."""
+        disabled, so call sites hold one object either way. An enabled
+        one is fed the process's program builds and full collections
+        (telemetry/builds.py), those from before it was built too."""
+        from .builds import RECORDER
         from .tracer import NOOP_TRACER, Tracer
 
         if not self.enabled:
             return NOOP_TRACER
-        return Tracer(enabled=True, max_spans=self.max_spans)
+        tracer = Tracer(enabled=True, max_spans=self.max_spans)
+        RECORDER.feed(tracer)
+        return tracer
 
     def build_recorder(self, tracer, metrics=None, role="frontend"):
         """Flight recorder over ``tracer``; ``metrics`` (an object with

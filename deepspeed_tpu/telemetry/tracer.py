@@ -398,6 +398,16 @@ def validate_chrome_trace(obj: Any) -> List[str]:
     return problems
 
 
+def union_seconds(intervals: Iterable[Tuple[float, float]]) -> float:
+    """Length of the union of ``(start, end)`` intervals."""
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
 def trace_coverage(spans: Iterable[Dict[str, Any]], t0: float,
                    t1: float) -> float:
     """Fraction of the window ``[t0, t1]`` covered by the union of the
@@ -406,22 +416,7 @@ def trace_coverage(spans: Iterable[Dict[str, Any]], t0: float,
     chain accounts for ≥95% of its measured TTFT."""
     if t1 <= t0:
         return 1.0
-    ivals: List[Tuple[float, float]] = []
-    for s in spans:
-        a = max(float(s["t_start"]), t0)
-        b = min(float(s["t_end"]) if s.get("t_end") is not None else t1, t1)
-        if b > a:
-            ivals.append((a, b))
-    if not ivals:
-        return 0.0
-    ivals.sort()
-    covered = 0.0
-    cur_a, cur_b = ivals[0]
-    for a, b in ivals[1:]:
-        if a > cur_b:
-            covered += cur_b - cur_a
-            cur_a, cur_b = a, b
-        else:
-            cur_b = max(cur_b, b)
-    covered += cur_b - cur_a
-    return covered / (t1 - t0)
+    ivals = ((max(float(s["t_start"]), t0),
+              min(float(s["t_end"]) if s.get("t_end") is not None else t1,
+                  t1)) for s in spans)
+    return union_seconds((a, b) for a, b in ivals if b > a) / (t1 - t0)

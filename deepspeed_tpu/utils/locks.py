@@ -68,6 +68,7 @@ LOCK_RANKS = {
     "telemetry.fleet": 135,        # fleet journal per-source rings
     "telemetry.journal": 140,      # ops event ring + sink
     "telemetry.recorder": 150,     # flight-recorder snapshots
+    "telemetry.builds": 155,       # program-build / full-collection records
     "telemetry.tracer": 160,       # span rings
     # leaves: metric series (plain locks, ranked via _LOCK_RANKS hints)
     "serving.metrics.registry": 170,

@@ -108,7 +108,10 @@ def pytest_fixture_setup(fixturedef, request):
 #: cell (below): every other assertion of it runs as written. Both hold
 #: lists of the saturated cell's metrics to ``mistral-7b.batch`` alone —
 #: one of them also holds its metric to be the last of ``per_layer`` — and
-#: PR 55's cell is a second cell judged on ``serve_tok_s``. A ``benchmark``
+#: PR 55's cell is a second cell judged on ``serve_tok_s``; PR 58's six
+#: start-up metrics are appended behind it and list the older cells too,
+#: so the view also ends ``per_layer`` where it ended then (the pin's
+#: second part). A ``benchmark``
 #: PR that turns a pin into ``>=`` deletes its line here (PERF.md section
 #: 7). (The fixture's name is its own: ``tests/benchmark/conftest.py``
 #: overrides an older one, ``_manifest_as_the_pinning_test_knew_it``, whose
@@ -118,12 +121,30 @@ PINNED_SATURATED_LISTS = dict.fromkeys((
     "test_the_manifest_names_the_metric_for_the_batch_cell",
     "tests/benchmark/test_dispatch_readers.py::"
     "test_the_manifest_lists_the_new_metrics_behind_the_old",
-), "nemotron-3-super-120b-a12b.reason")
+), ("nemotron-3-super-120b-a12b.reason", "paged_primed_share"))
+#: Two more hold every per-layer metric of their cell to move the one
+#: end-to-end metric the cell is judged on, and the cell to be the last
+#: of each metric's ``workloads``; PR 58's start-up metrics move
+#: ``setup_s`` in every cell. They are shown every cell, and
+#: ``per_layer`` as far as it went before those six.
+PINNED_SATURATED_LISTS.update(dict.fromkeys((
+    "tests/benchmark/test_pangu_ultra_moe_block.py::"
+    "test_the_manifest_validates_with_the_new_entries",
+    "tests/benchmark/test_smallthinker_block.py::"
+    "test_the_manifest_validates_with_the_new_entries",
+), (None, "sat_paged_attn_window_roofline")))
 
 
-def manifest_up_to(manifest: dict, cell: str) -> dict:
-    """``manifest`` without the cells appended after ``cell``, their
-    configurations and the metrics only they report."""
+def manifest_up_to(manifest: dict, cell, last_metric: str) -> dict:
+    """``manifest`` without the per-layer metrics appended after
+    ``last_metric`` and, where ``cell`` is given, without the cells
+    appended after it, their configurations and the metrics only they
+    report."""
+    layer_names = [m["name"] for m in manifest["per_layer"]]
+    manifest = dict(manifest, per_layer=manifest["per_layer"][
+        :layer_names.index(last_metric) + 1])
+    if cell is None:
+        return manifest
     names = [w["name"] for w in manifest["workloads"]]
     kept = manifest["workloads"][:names.index(cell) + 1]
     cells = {w["name"] for w in kept}
@@ -141,13 +162,13 @@ def manifest_up_to(manifest: dict, cell: str) -> dict:
 
 @pytest.fixture(autouse=True)
 def _manifest_as_the_saturated_pins_knew_it(request, monkeypatch):
-    cell = PINNED_SATURATED_LISTS.get(request.node.nodeid)
-    if cell is not None:
+    pin = PINNED_SATURATED_LISTS.get(request.node.nodeid)
+    if pin is not None:
         from benchmark import manifest as mf
 
         load = mf.load
         monkeypatch.setattr(mf, "load", lambda *a, **k: manifest_up_to(
-            load(*a, **k), cell))
+            load(*a, **k), *pin))
     yield
 
 
