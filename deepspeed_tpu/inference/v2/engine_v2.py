@@ -123,7 +123,10 @@ _FREE_POSITIONS = 128
 #: keys of ``engine.last_put`` that ride on the scheduler's ``forward``
 #: span only (by prefix): ``stage`` keeps the keys the benchmark's wrapper has
 FORWARD_ONLY = ("kv_blocks_live", "kv_table_slots", "kv_blocks_released",
-                "kv_bytes_", "kv_g", "attn_steps")
+                "kv_bytes_", "kv_g", "attn_steps", "attn_turns")
+#: ``ops.paged_attention.grid_steps``' four counts, as ``last_put`` names them
+_ATTN_COUNTS = ("attn_steps", "attn_steps_primed", "attn_turns",
+                "attn_turns_unmasked")
 
 
 #: a one-token row's token when its sequence's next token is still on
@@ -592,7 +595,7 @@ class InferenceEngineV2:
         # of what its forwards counted, and how many they were
         summed = ("rows", "valid_tokens", "kv_read_tokens", "qk_pairs",
                   "kv_blocks_live", "kv_table_slots",
-                  "attn_steps", "attn_steps_primed",
+                  *_ATTN_COUNTS,
                   "moe_rows_routed", "moe_rows_held", "kv_blocks_released") \
             + tuple(k for k in records[-1] if k.endswith(("_read_tokens",
                                                           "_qk_pairs"))
@@ -830,11 +833,13 @@ class InferenceEngineV2:
                 for g in groups]
 
     def _count_attn_steps(self, arrays, merged: bool) -> None:
-        """``attn_steps`` / ``attn_steps_primed`` of ``last_put``: the
-        live grid steps of this forward's paged-attention calls, one call
-        a kind of layer (not a layer: the layers of a kind repeat it),
-        and those whose first turn the step before had fetched — the
-        kernel's rule on the rows the forward is handed
+        """``attn_steps`` / ``attn_steps_primed`` / ``attn_turns`` /
+        ``attn_turns_unmasked`` of ``last_put``: the live grid steps of
+        this forward's paged-attention calls, one call a kind of layer
+        (not a layer: the layers of a kind repeat it), those whose first
+        turn the step before had fetched, the turns the steps fold and
+        those of them folded without the mask — the kernel's rules on
+        the rows the forward is handed
         (``ops.paged_attention.grid_steps``). A merged forward's calls
         are ``paged_model._parts``': row 0's chunk alone, then one
         position a row with row 0 a padded row. Two dozen array
@@ -850,16 +855,14 @@ class InferenceEngineV2:
             calls = [(width - len(start), start[:1], n_tokens[:1]),
                      (1, start * dead, n_tokens * dead)]
         cache = self.state_manager.forward_cache
-        steps = primed = 0
+        counts = np.zeros(len(_ATTN_COUNTS), np.int64)
         for leaf, shape in self._walks:
             for chunk, s, n in calls:
-                a, b = pa.grid_steps(
+                counts += pa.grid_steps(
                     s, n, chunk=chunk, q_dtype=self.model.cfg.dtype,
                     pool_dtype=cache[leaf].dtype,
                     table_blocks=arrays["block_tables"].shape[-1], **shape)
-                steps, primed = steps + a, primed + b
-        self.last_put["attn_steps"] = steps
-        self.last_put["attn_steps_primed"] = primed
+        self.last_put.update(zip(_ATTN_COUNTS, map(int, counts)))
 
     def _record_groups(self, released: int, group_read, group_pairs) -> None:
         """The put's record by layer group, for a model that keeps more

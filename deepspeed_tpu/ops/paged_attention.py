@@ -47,7 +47,25 @@ follows the live KV bytes and the query-key pairs, not the table's width.
   the scores (K) and the probabilities (V) instead of the tile.
 - Masking: query row r (= g·C + ci) has global position start_pos + ci;
   KV slot s in table block b has position b·bs + s; attend iff
-  kv_pos <= q_pos (causal over the shared pool) and kv_pos < ctx_len.
+  kv_pos <= q_pos (causal over the shared pool) and kv_pos < ctx_len
+  (and, under a window, kv_pos > q_pos − window). **A chunk step folds
+  the turns that lie wholly inside every row's view without the mask**:
+  a step of a lane tile of query rows or more a K/V head
+  (``_stages_scores``) keeps a turn's scores in a VMEM scratch of its
+  own, and rewrites them there with the mask only where a pair can be
+  masked. A turn is inside every row's view when each key of it lies at
+  or before the step's first query position (so inside the context too)
+  and inside the window of its last — decided from the step's scalars
+  before the tile is touched (``_unmasked_span``). The mask would have
+  kept each of its scores, so no bit of the output moves; of a long
+  chunk's turns only those on the diagonal and at the window's tail
+  still pay the mask's compares and select. Such a turn never holds a
+  place no block was copied into: its first key lies at or after the
+  window's first live block and its last before the context's end. A
+  one-token step masks every turn (a few registers a head: the branch
+  would cost more than the mask), and so does
+  ``paged_attention_masked``, whose block mask is per query and per
+  block.
 
 The XLA gather formulation (``paged_attention_xla``) remains as the
 off-TPU fallback and the numeric reference for the kernel tests.
@@ -85,6 +103,9 @@ KEY_TILE = 512
 # Test hook: force the Pallas path in interpreter mode off-TPU (same pattern
 # as ops/flash_attention.py).
 _FORCE_INTERPRET = False
+# Test hook: no step stages its scores, so every turn is masked — the
+# reference the staged steps' outputs are held to, bit for bit.
+_MASK_EVERY_TURN = False
 
 
 def _use_interpret() -> bool:
@@ -155,6 +176,31 @@ def _live_blocks(start_pos, n_tokens, block_size: int, window: int,
     return first, last
 
 
+def _stages_scores(rows: int) -> bool:
+    """Whether a step of ``rows`` (G·C) query rows a K/V head keeps a
+    turn's float32 scores in a VMEM scratch of its own and masks there
+    only the turns that hold a masked pair (``_paged_kernel``): a chunk
+    step's — a lane tile of rows or more. A step of fewer rows, a
+    one-token step above all, holds its scores in a few registers a
+    head and its mask is a few dozen vector operations: the branch round
+    it costs more than it saves there (measured: PERF.md section 6,
+    PR 59), and it masks every turn."""
+    return rows >= LANES
+
+
+def _unmasked_span(start_pos, ctx_len, chunk: int, window: int,
+                   ops=_SCALARS):
+    """The key positions [lo, hi) that every query row of a step attends:
+    at or before the step's *first* query position and inside the context
+    and, with a sliding window, inside the window of its *last* query
+    position, start_pos + chunk − 1 (``lo`` may be negative: no key is).
+    A turn that lies wholly inside the span holds no pair the mask would
+    drop. One rule for the kernel's scalars and for the host's count
+    (``grid_steps``, ``ops=_ARRAYS``), as ``_live_blocks`` is."""
+    return (start_pos + (chunk - window) if window else 0,
+            ops[0](start_pos + 1, ctx_len))
+
+
 # ------------------------------------------------------------------- kernel
 
 class _Walk(NamedTuple):
@@ -219,12 +265,29 @@ def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
     step's [C, MB'] int8 mask of the table blocks each query position
     attends; a turn's T columns are spread over its keys by a product
     with a 0/1 matrix on the matrix unit and join the causal mask
-    (``paged_attention_masked``)."""
+    (``paged_attention_masked``).
+
+    **The mask, where a pair can be masked.** A step of many query
+    rows (``_stages_scores``: a chunk step) is handed one scratch more,
+    [KHt, G·C, T·bs] float32: a turn's scores go there from the first
+    dot, and one ``pl.when`` rewrites them in place with the mask if the
+    turn holds a pair the mask would drop. A turn whose keys all lie inside ``_unmasked_span`` — at or before the
+    step's first query position and inside the context; under a window,
+    inside the window of the step's last query position — holds none,
+    and is folded as it is. Not a second fold: the kernel is traced and
+    lowered once a forward program, and the dots, the scales, ALiBi's
+    bias and the online softmax are the one copy every turn runs. Every
+    place of such a turn was copied (the walk's ``first`` lies at or
+    before it, the context's end after it), so no stale row of ``k_buf``
+    meets an unmasked score. A step with no such scratch — a one-token
+    step, every step of ``masked``, every step under
+    ``_MASK_EVERY_TURN`` (the tests' reference) — masks every turn."""
     if quant:
         ks_ref, vs_ref, *refs = refs
     if masked:
         mask_ref, *refs = refs
-    o_ref, k_buf, v_buf, sem, state, acc_ref, m_ref, l_ref = refs
+    o_ref, k_buf, v_buf, sem, state, acc_ref, m_ref, l_ref, *rest = refs
+    s_ref = rest[0] if rest else None       # a chunk step's scores
     _, kh_t, T, bs, D = k_buf.shape
     rows, keys = q_ref.shape[2], T * bs
     last_slot = tables_ref.shape[1] - 1
@@ -308,6 +371,10 @@ def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
                 range(groups), jnp.zeros((rows, 1), jnp.float32))
             for k in range(kh_t)])                            # [KHt, G·C, 1]
 
+    if s_ref is not None:
+        # the key positions every row of the step attends
+        seen_lo, seen_hi = _unmasked_span(startp, ctx_len, chunk, window)
+
     key_blk = lax.broadcasted_iota(jnp.int32, (1, keys), 1) // bs
 
     def key_scales(ref, turn):
@@ -339,30 +406,55 @@ def _paged_kernel(layer_ref, tables_ref, startp_ref, ntok_ref, slopes_ref,
                             preferred_element_type=jnp.float32) * sm_scale
         if quant:
             s = s * key_scales(ks_ref, turn)
-        kvpos = turn * keys + lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2)
+        key0 = turn * keys
+        kvpos = key0 + lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2)
         if alibi:
             s = s + slope * kvpos.astype(jnp.float32)
-        # causal over the shared pool, and inside the context
-        keep = (kvpos <= qpos) & (kvpos < ctx_len)
-        if window:
-            keep = keep & (kvpos > qpos - window)
-        if masked:
-            # the turn's T mask columns lie inside one 128-lane tile (T
-            # is a power of two): the tile times E[l, key] = (l is the
-            # key's block) gives each key its block's bit
-            lane0 = pl.multiple_of((turn * T) // LANES * LANES, LANES)
-            tile = mask_ref[0, 0, :, pl.ds(lane0, LANES)]     # [C, 128]
-            spread = (lax.broadcasted_iota(jnp.int32, (LANES, keys), 0)
-                      == turn * T - lane0
-                      + lax.broadcasted_iota(jnp.int32, (LANES, keys), 1)
-                      // bs)
-            bit = jnp.dot(tile.astype(jnp.float32).astype(jnp.bfloat16),
-                          spread.astype(jnp.bfloat16),
-                          preferred_element_type=jnp.float32)  # [C, T·bs]
-            if groups > 1:
-                bit = jnp.concatenate([bit] * groups, axis=0)
-            keep = keep & (bit[None] > 0.5)
-        s = jnp.where(keep, s, NEG_INF)                       # [KHt, G·C, T·bs]
+
+        def mask(s):
+            # causal over the shared pool, and inside the context
+            keep = (kvpos <= qpos) & (kvpos < ctx_len)
+            if window:
+                keep = keep & (kvpos > qpos - window)
+            if masked:
+                # the turn's T mask columns lie inside one 128-lane tile
+                # (T is a power of two): the tile times E[l, key] = (l is
+                # the key's block) gives each key its block's bit
+                lane0 = pl.multiple_of((turn * T) // LANES * LANES, LANES)
+                tile = mask_ref[0, 0, :, pl.ds(lane0, LANES)]     # [C, 128]
+                spread = (lax.broadcasted_iota(jnp.int32, (LANES, keys), 0)
+                          == turn * T - lane0
+                          + lax.broadcasted_iota(jnp.int32, (LANES, keys), 1)
+                          // bs)
+                bit = jnp.dot(tile.astype(jnp.float32).astype(jnp.bfloat16),
+                              spread.astype(jnp.bfloat16),
+                              preferred_element_type=jnp.float32)  # [C, T·bs]
+                if groups > 1:
+                    bit = jnp.concatenate([bit] * groups, axis=0)
+                keep = keep & (bit[None] > 0.5)
+            return jnp.where(keep, s, NEG_INF)
+
+        if s_ref is not None:
+            # the scores wait in the step's scratch, and a turn inside
+            # every row's view (``_unmasked_span``) is folded as it is:
+            # the mask would keep each of its scores. One guard round
+            # the mask's few equations, not a second fold — and the
+            # guard stays where it is: most of what a chunk call gains
+            # comes with the boundary it puts between the first dot and
+            # the softmax, not with the arithmetic it skips (PERF.md
+            # section 7, "What PR 59 leaves open")
+            s_ref[...] = s
+            edge = key0 + keys > seen_hi
+            if window:
+                edge = edge | (key0 < seen_lo)
+
+            @pl.when(edge)
+            def _():
+                s_ref[...] = mask(s_ref[...])
+
+            s = s_ref[...]                             # [KHt, G·C, T·bs]
+        else:
+            s = mask(s)
         m_prev, l_prev = m_ref[...], l_ref[...]               # [KHt, G·C, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -419,6 +511,8 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
         # one K/V head a step (its own table, its own mask), and a turn
         # of 2^i blocks: its mask columns never straddle a lane tile
         kh_t, T = 1, 1 << (T.bit_length() - 1)
+    # a block mask is per query and per block: every turn is masked
+    staged = not (masked or _MASK_EVERY_TURN) and _stages_scores(G * C)
 
     # [N, C, H, D] -> [N, KH, G*C, D]: row r = g*C + ci
     qh = q.transpose(0, 2, 1, 3).reshape(N, KH, G * C, D)
@@ -468,6 +562,9 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, start_pos, n_tokens, *,
             pltpu.VMEM((kh_t, G * C, D), jnp.float32),
             pltpu.VMEM((kh_t, G * C, LANES), jnp.float32),
             pltpu.VMEM((kh_t, G * C, LANES), jnp.float32),
+            # a chunk step's scores of a turn (``_step_bytes`` counts them)
+            *([pltpu.VMEM((kh_t, G * C, T * bs), jnp.float32)]
+              if staged else []),
         ],
     )
     o = pl.pallas_call(
@@ -584,29 +681,36 @@ def _pieces(chunk: int, tile: int, start_pos, n_tokens, xp=jnp):
 @functools.cache
 def _grid_shape(chunk, group, head_dim, kv_heads, block_size, table_blocks,
                 q_dtype, pool_dtype):
-    """``(tile, head groups)``: the positions of one piece of a call's
-    chunk and the grid steps a row of a piece takes (a forward asks this
-    of the same few shapes, put after put)."""
+    """``(tile, head groups, T, staged)``: the positions of one piece of
+    a call's chunk, the grid steps a row of a piece takes, the table
+    blocks one of its turns folds and whether a step's scores wait in a
+    scratch (``_stages_scores``) — a forward asks this of the same few
+    shapes, put after put."""
     tile = _chunk_tile(chunk, group)
-    kh_t, _ = _tiles(group * tile, head_dim, kv_heads, block_size,
-                     table_blocks, q_dtype, pool_dtype)
-    return tile, kv_heads // kh_t
+    kh_t, blocks = _tiles(group * tile, head_dim, kv_heads, block_size,
+                          table_blocks, q_dtype, pool_dtype)
+    return tile, kv_heads // kh_t, blocks, _stages_scores(group * tile)
 
 
 def grid_steps(start_pos, n_tokens, *, chunk: int, heads: int, kv_heads: int,
                head_dim: int, block_size: int, table_blocks: int,
                window: int = 0, q_dtype=jnp.bfloat16, pool_dtype=jnp.bfloat16):
-    """``(steps, primed)`` of one ``paged_attention`` call, on the host
-    from its rows' ``start_pos`` / ``n_tokens`` (numpy) and its shapes:
-    the (row, head group) grid steps that walk at least one turn, over
-    the call's pieces, and those of them whose first turn the grid step
-    before them had fetched — a live step behind a live step of the same
-    piece (``_paged_kernel``: the kernel's own rules, ``_chunk_tile``,
-    ``_pieces``, ``_tiles`` and ``_live_blocks``, on numbers)."""
-    tile, head_groups = _grid_shape(chunk, heads // kv_heads, head_dim,
-                                    kv_heads, block_size, table_blocks,
-                                    jnp.dtype(q_dtype), jnp.dtype(pool_dtype))
-    steps = primed = 0
+    """``(steps, primed, turns, unmasked)`` of one ``paged_attention``
+    call, on the host from its rows' ``start_pos`` / ``n_tokens`` (numpy)
+    and its shapes: the (row, head group) grid steps that walk at least
+    one turn, over the call's pieces; those of them whose first turn the
+    grid step before them had fetched — a live step behind a live step of
+    the same piece; the turns those steps fold, and the turns of them
+    that a step with its scores in a scratch folds without the mask,
+    those wholly inside every row's view (``_paged_kernel``: the
+    kernel's own rules, ``_chunk_tile``, ``_pieces``, ``_tiles``,
+    ``_live_blocks``, ``_stages_scores`` and ``_unmasked_span``, on
+    numbers)."""
+    tile, head_groups, T, staged = _grid_shape(
+        chunk, heads // kv_heads, head_dim, kv_heads, block_size,
+        table_blocks, jnp.dtype(q_dtype), jnp.dtype(pool_dtype))
+    keys = T * block_size
+    steps = primed = turns = unmasked = 0
     for _, start, n_sub in _pieces(chunk, tile, np.asarray(start_pos),
                                    np.asarray(n_tokens), xp=np):
         first, last = _live_blocks(start, n_sub, block_size, window,
@@ -617,7 +721,17 @@ def grid_steps(start_pos, n_tokens, *, chunk: int, heads: int, kv_heads: int,
         # a row's later head groups follow its own; its first follows the
         # last of the row before it
         primed += (head_groups - 1) * rows + int((live[1:] & live[:-1]).sum())
-    return steps, primed
+        # the walk's turns [lo, hi), and those of a staged step inside
+        # the span every row of the piece attends
+        lo, hi = first // T, -(-last // T)
+        turns += head_groups * int(((hi - lo) * live).sum())
+        if staged:
+            seen_lo, seen_hi = _unmasked_span(start, start + n_sub, tile,
+                                              window, ops=_ARRAYS)
+            inside = np.minimum(hi, seen_hi // keys) \
+                - np.maximum(lo, -(-np.maximum(seen_lo, 0) // keys))
+            unmasked += head_groups * int((np.maximum(inside, 0) * live).sum())
+    return steps, primed, turns, unmasked
 
 
 def _pallas_ok(q, k_pool) -> bool:

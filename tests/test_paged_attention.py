@@ -569,6 +569,246 @@ def test_buffers_that_start_as_nan_are_never_read_unfilled(dma, monkeypatch):
     _assert_rows(out, pa.paged_attention_xla(*args), args[5])
 
 
+# ------------------------------------ turns inside every row's view: no mask
+
+# 16-token blocks and ``KEY_TILE`` patched to 128: 8 blocks, 128 keys a turn
+_SEEN = dict(N=4, bs=16, MB=48, NB=160, H=4, KH=2, D=64)
+
+
+def _seen_plain(monkeypatch, ctx_lens, n_tokens, C=1, max_rows=None,
+                alibi=False, **kw):
+    case = dict(_SEEN, C=C, ctx_lens=ctx_lens, n_tokens=n_tokens)
+    quant = kw.pop("quant", None)
+    if max_rows:
+        monkeypatch.setattr(pa, "MAX_QUERY_ROWS", max_rows)
+    if alibi:
+        kw["alibi_slopes"] = jnp.asarray([0.5, 0.25, 0.125, 0.0625]) / 64
+    args, scales = _walk_case(np.random.default_rng(3), quant=quant, **case)
+    tile = pa._chunk_tile(C, 2)
+    assert pa._tiles(2 * tile, 64, 2, 16, 48, args[0].dtype,
+                     args[1].dtype)[1] == 8
+    counts = pa.grid_steps(
+        np.asarray(args[4]), np.asarray(args[5]), chunk=C, heads=4,
+        kv_heads=2, head_dim=64, block_size=16, table_blocks=48,
+        window=kw.get("window", 0), q_dtype=args[0].dtype,
+        pool_dtype=args[1].dtype)
+    return (lambda: pa.paged_attention(*args, **scales, **kw)), counts[2:]
+
+
+def _seen_by_head(monkeypatch):
+    rng = np.random.default_rng(8)
+    (q, kp, vp, *_), _ = _walk_case(rng, **dict(
+        _SEEN, C=1, ctx_lens=[0, 40, 330, 130], n_tokens=[0, 1, 1, 1]))
+    blocks = jnp.asarray([0, 3, 20, 9])
+    tables = jnp.asarray(rng.integers(1, 160, (4, 2, 48)), jnp.int32)
+    positions = jnp.asarray([0, 37, 4000, 143], jnp.int32)
+    # a selected table is a context of its own: rows of 3, 20 and 9
+    # blocks walk 1, 3 and 2 turns a head, the last of each on the edge
+    return (lambda: pa.paged_attention_select(
+        q, kp[None], vp[None], tables, blocks, positions, layer=0),
+        (2 * (1 + 3 + 2), 2 * (0 + 2 + 1)))
+
+
+def _seen_masked(monkeypatch):
+    (q, kp, vp, tbl, sp, nt), _ = _walk_case(
+        np.random.default_rng(3), **dict(_SEEN, C=4, ctx_lens=[0, 40, 330, 600],
+                                         n_tokens=[0, 4, 4, 4]))
+    rng = np.random.default_rng(12)
+    own = np.arange(48)[None, None, None, :] == (
+        (np.asarray(sp)[:, None] + np.arange(4)[None]) // 16)[:, :, None, None]
+    mask = jnp.asarray((rng.random((4, 4, 2, 48)) > 0.5) | own, jnp.int8)
+    # a block mask is per query and per block: no turn goes unmasked
+    return (lambda: pa.paged_attention_masked(q, kp, vp, tbl, sp, nt, mask),
+            (None, 0))
+
+
+#: name -> (builder, the turns folded without the mask: some or none)
+SEEN_CASES = {
+    # rows 230..277 cross key 256: the turns [128, 256) and [256, 384) are
+    # on the diagonal, [0, 128) is inside every row's view
+    "diagonal_straddles_two_turns": (functools.partial(
+        _seen_plain, C=48, ctx_lens=[0, 278, 600, 130],
+        n_tokens=[0, 48, 48, 48]), True),
+    # rows 400..407 under a window of 300: the first live block is 6, a
+    # place of turn [0, 128) behind six never copied; [128, 384) is inside
+    # every row's window; [384, 512) is the diagonal's
+    "window_first_block_mid_turn": (functools.partial(
+        _seen_plain, C=8, ctx_lens=[0, 408, 700, 90], n_tokens=[0, 8, 8, 8],
+        window=300), True),
+    "window_decode": (functools.partial(
+        _seen_plain, ctx_lens=[0, 409, 700, 90], n_tokens=[0, 1, 1, 1],
+        window=300), True),
+    # G·C over MAX_QUERY_ROWS (16): three pieces of 8 positions, the
+    # later ones past a row's tokens
+    "piece_past_a_rows_tokens": (functools.partial(
+        _seen_plain, C=24, ctx_lens=[0, 300, 505, 700],
+        n_tokens=[0, 24, 5, 9], max_rows=16), True),
+    "pieces": (functools.partial(
+        _seen_plain, C=32, ctx_lens=[400, 300, 512, 700],
+        n_tokens=[32, 32, 32, 32], max_rows=16), True),
+    "padded_rows_between": (functools.partial(
+        _seen_plain, ctx_lens=[300, 0, 600, 0], n_tokens=[1, 0, 1, 0]), True),
+    # contexts of 128 (its one turn whole and seen: the row sits on key
+    # 127), 129 (one key into the second turn) and 200
+    "context_ends_mid_turn": (functools.partial(
+        _seen_plain, ctx_lens=[200, 129, 128, 640], n_tokens=[1, 1, 1, 1]),
+        True),
+    "short_chunks_see_no_whole_turn": (functools.partial(
+        _seen_plain, C=8, ctx_lens=[0, 100, 8, 60], n_tokens=[0, 8, 8, 3]),
+        False),
+    "alibi": (functools.partial(
+        _seen_plain, C=4, ctx_lens=[0, 278, 600, 130], n_tokens=[0, 4, 2, 4],
+        alibi=True), True),
+    "int8_pool": (functools.partial(
+        _seen_plain, C=4, ctx_lens=[0, 278, 600, 130], n_tokens=[0, 4, 4, 2],
+        quant=jnp.int8), True),
+    "by_head": (_seen_by_head, True),
+    "masked": (_seen_masked, False),
+}
+
+
+@pytest.mark.parametrize("name", SEEN_CASES)
+def test_unmasked_turns_change_no_bit(name, monkeypatch):
+    """A step with its scores in a scratch folds a turn that lies wholly
+    inside every row's view without the mask (``_unmasked_span``): the
+    outputs — the rows past a row's
+    tokens and the padded rows too — are bit for bit those of the same
+    kernel masking every turn, under the TPU interpreter (a place no
+    block was copied into holds NaN: an unmasked turn never holds one)."""
+    _tpu_interpreter(monkeypatch)
+    monkeypatch.setattr(pa, "KEY_TILE", 128)
+    monkeypatch.setattr(pa, "_stages_scores", lambda rows: True)
+    pa._grid_shape.cache_clear()
+    build, some = SEEN_CASES[name]
+    call, (turns, unmasked) = build(monkeypatch)
+    assert (unmasked > 0) == some and (turns is None or unmasked < turns)
+    out = np.asarray(call())
+    monkeypatch.setattr(pa, "_MASK_EVERY_TURN", True)
+    ref = np.asarray(call())
+    assert np.isfinite(out.astype(np.float32)).all()
+    np.testing.assert_array_equal(out.view(np.uint32), ref.view(np.uint32))
+    pa._grid_shape.cache_clear()
+
+
+def _kernel_jaxpr(C=256, **kw):
+    q = jnp.zeros((4, C, 4, 64), jnp.float32)
+    kp = jnp.zeros((2, 32, 2, 16, 64), jnp.float32)
+    tbl, sp = jnp.zeros((4, 16), jnp.int32), jnp.zeros((4,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda q, kp, tbl, sp: pa._paged_pallas(
+        q, kp, kp, tbl, sp, sp, layer=0, interpret=False, **kw))(
+            q, kp, tbl, sp)
+    call, = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return call.params["jaxpr"]
+
+
+def _primitives(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+
+
+def _count(eqns, name):
+    return sum(e.primitive.name == name for e in eqns)
+
+
+@pytest.mark.parametrize("kw", [{}, {"window": 40}],
+                         ids=["plain", "window"])
+def test_a_chunk_step_holds_one_guard_and_one_fold(kw):
+    """The kernel is traced and lowered once a forward program: the rule
+    costs a chunk step (512 rows a K/V head) one conditional round the
+    mask and no second copy of the fold's two dots; the other
+    conditional is the call's one zeroing of ``v_buf``."""
+    assert pa._stages_scores(512) and not pa._stages_scores(2 * 48)
+    eqns = list(_primitives(_kernel_jaxpr(**kw)))
+    assert (_count(eqns, "cond"), _count(eqns, "dot_general")) == (2, 2)
+
+
+@pytest.mark.parametrize("case", ["one_token", "by_head", "masked", "hook"])
+def test_a_step_with_no_scratch_masks_every_turn(case, monkeypatch):
+    """... and a one-token step, a block-sparse layer's two variants and
+    the tests' reference run the body they ran: no guard."""
+    kw = {"one_token": dict(C=1), "by_head": dict(C=1, by_head=True),
+          "masked": dict(block_mask=jnp.ones((4, 2, 256, 128), jnp.int8)),
+          "hook": {}}[case]
+    if case == "hook":
+        monkeypatch.setattr(pa, "_MASK_EVERY_TURN", True)
+    eqns = list(_primitives(_kernel_jaxpr(**kw)))
+    assert _count(eqns, "cond") == 1
+    assert _count(eqns, "dot_general") == (3 if case == "masked" else 2)
+
+
+def _turns_by_hand(rows, chunk, tile, head_groups, bs, T, slots, window=0):
+    """``(turns, unmasked)`` of one call from the ``keep`` matrix
+    ``paged_attention_xla`` builds, piece by piece: a turn of a live walk
+    is unmasked when ``keep`` holds every (row of the piece, key of the
+    turn) pair."""
+    keys = T * bs
+    kv = np.arange((slots // T + 1) * keys)
+    turns = unmasked = 0
+    for c0 in range(0, chunk, tile):
+        for start, n in rows:
+            n_sub = min(max(n - c0, 0), tile)
+            start = start + c0 if n_sub else 0
+            last = min(-(-(start + n_sub) // bs), slots)
+            first = max(start - window + 1, 0) // bs if window else 0
+            if last <= first:
+                continue
+            qpos = start + np.arange(tile)[:, None]
+            keep = (qpos >= kv[None]) & (kv[None] < start + n_sub)
+            if window:
+                keep &= qpos - kv[None] < window
+            for turn in range(first // T, -(-last // T)):
+                turns += head_groups
+                unmasked += head_groups * bool(
+                    keep[:, turn * keys:(turn + 1) * keys].all())
+    return turns, unmasked
+
+
+@pytest.mark.parametrize("shape", [
+    # chunk, window, heads, kv_heads, head_dim, block size, table blocks
+    (1, 0, 8, 2, 128, 64, 64), (1, 700, 8, 2, 128, 64, 64),
+    (64, 0, 8, 2, 128, 64, 64), (64, 1200, 8, 2, 128, 16, 128),
+    (256, 0, 8, 2, 128, 64, 64), (256, 1000, 8, 2, 128, 64, 64),
+    # 6 heads a K/V head: a 512-token chunk is cut into pieces of 256
+    (512, 1000, 48, 8, 128, 64, 64),
+], ids=["decode", "decode_window", "chunk", "chunk_window_small_blocks",
+        "one_head_a_step", "one_head_a_step_window", "pieces"])
+def test_grid_steps_counts_the_unmasked_turns_of_the_keep_matrix(
+        shape, monkeypatch):
+    """The host's twin of the kernel's rule against brute force, on
+    random rows (padded ones and rows short of the chunk among them);
+    a one-token step masks every turn (``_stages_scores``), so it is
+    counted with that rule taken away."""
+    chunk, window, heads, kv_heads, head_dim, bs, slots = shape
+    if chunk == 1:
+        assert pa.grid_steps(np.asarray([4000]), np.asarray([1]), chunk=1,
+                             heads=heads, kv_heads=kv_heads,
+                             head_dim=head_dim, block_size=bs,
+                             table_blocks=slots)[2:] == (8, 0)
+        monkeypatch.setattr(pa, "_stages_scores", lambda rows: True)
+    pa._grid_shape.cache_clear()
+    rng = np.random.default_rng(chunk + window)
+    n = rng.integers(0, chunk + 1, 24)
+    n[::5] = 0
+    n[1::5] = chunk
+    start = np.where(n > 0, rng.integers(0, slots * bs - chunk + 1, 24), 0)
+    start[2::7] //= 8                       # short contexts too
+    tile = pa._chunk_tile(chunk, heads // kv_heads)
+    kh_t, T = pa._tiles(heads // kv_heads * tile, head_dim, kv_heads, bs,
+                        slots, jnp.bfloat16, jnp.bfloat16)
+    got = pa.grid_steps(start, n, chunk=chunk, heads=heads,
+                        kv_heads=kv_heads, head_dim=head_dim, block_size=bs,
+                        table_blocks=slots, window=window)
+    want = _turns_by_hand(list(zip(start, n)), chunk, tile, kv_heads // kh_t,
+                          bs, T, slots, window)
+    assert got[2:] == want and 0 < want[1] < want[0]
+    pa._grid_shape.cache_clear()
+
+
 # ---------------------------------------------- the count of the grid steps
 
 def _grid_by_hand(rows, head_groups, bs, slots, window=0):
@@ -604,7 +844,7 @@ def test_grid_steps_is_the_kernels_grid_walked_by_hand(case):
     assert pa._tiles(4 * chunk, 128, 2, 64, 64, jnp.bfloat16,
                      jnp.bfloat16)[0] == 2 // hg
     start, n = map(np.asarray, zip(*rows))
-    assert pa.grid_steps(start, n, **shape) \
+    assert pa.grid_steps(start, n, **shape)[:2] \
         == _grid_by_hand(rows, hg, 64, 64, window)
 
 
@@ -623,7 +863,7 @@ def test_grid_steps_of_a_chunk_cut_in_pieces(monkeypatch):
                for s, k in rows] for c0 in (0, 8, 16)]
     want = [_grid_by_hand(p, 1, 8, 16) for p in pieces]
     assert want == [(4, 2), (3, 0), (2, 0)]
-    assert got == tuple(map(sum, zip(*want)))
+    assert got[:2] == tuple(map(sum, zip(*want)))
     pa._grid_shape.cache_clear()
 
 
@@ -681,6 +921,58 @@ def test_put_record_counts_the_grid_steps(monkeypatch):
         == [a["rows"] - 1 for a in counts]
     assert not any(k.startswith("attn_steps") for s in spans
                    if s["name"] == "stage" for k in s["attrs"])
+
+
+def test_put_record_counts_the_turns_folded_unmasked(monkeypatch):
+    """``attn_turns`` / ``attn_turns_unmasked`` ride beside ``attn_steps``:
+    on a traced forward's record and its ``forward`` span, summed over a
+    put's forwards, and an untraced forward pays nothing for them."""
+    from deepspeed_tpu.inference.v2.engine_v2 import (
+        FORWARD_ONLY, InferenceEngineV2, RaggedInferenceEngineConfig)
+    from deepspeed_tpu.models.transformer import CausalLM, TINY_TEST
+    from deepspeed_tpu.telemetry import Tracer
+
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    monkeypatch.setattr(pa, "KEY_TILE", 16)        # two blocks of 8 a turn
+    monkeypatch.setattr(pa, "_stages_scores", lambda rows: True)
+    pa._grid_shape.cache_clear()
+    engine = InferenceEngineV2(CausalLM(TINY_TEST), config=(
+        RaggedInferenceEngineConfig(
+            max_ragged_batch_size=512, max_ragged_sequence_count=8,
+            max_chunk_tokens=64, kv_blocks=64, kv_block_size=8,
+            max_tracked_sequences=16)))
+    assert "attn_turns".startswith(FORWARD_ONLY)
+    calls = []
+    grid_steps = pa.grid_steps
+    monkeypatch.setattr(pa, "grid_steps", lambda *a, **k: (
+        calls.append(1), grid_steps(*a, **k))[1])
+    assert "attn_turns" not in _put(engine, [1], [list(range(1, 41))])
+    assert not calls                    # untraced: the count is never made
+    engine.tracer = Tracer()
+    # a prompt of 40 from position 0: its three turns all hold the diagonal
+    put = _put(engine, [2], [list(range(1, 41))])
+    assert (put["attn_turns"], put["attn_turns_unmasked"]) == (3, 0)
+    # one token each at positions 40: [0, 16) and [16, 32) are wholly seen,
+    # [32, 48) holds the row's own key and the context's end; two padded rows
+    put = _put(engine, [1, 2], [[7], [7]])
+    assert (put["bucket_seqs"], put["attn_steps"], put["attn_turns"],
+            put["attn_turns_unmasked"]) == (2, 2, 6, 4)
+    tracer = Tracer()
+    from deepspeed_tpu.inference.v2.scheduler import \
+        ContinuousBatchingScheduler
+
+    sched = ContinuousBatchingScheduler(engine, tracer=tracer)
+    sched.submit(11, [3] * 20, max_new_tokens=3)
+    while sched.has_work:
+        sched.step()
+    spans = tracer.export()
+    counts = [s["attrs"] for s in spans if s["name"] == "forward"]
+    # the prompt's forward, then one-token forwards at 20, 21: [0, 16) seen
+    assert [(a["attn_turns"], a["attn_turns_unmasked"]) for a in counts] \
+        == [(2, 0), (2, 1), (2, 1)]
+    assert not any(k.startswith("attn_turns") for s in spans
+                   if s["name"] == "stage" for k in s["attrs"])
+    pa._grid_shape.cache_clear()
 
 
 def _put(engine, uids, tokens):
