@@ -329,11 +329,24 @@ class InferenceEngineV2:
         # the kinds of layer that attend through the paged kernel, as the
         # shapes its grid follows from beside a forward's rows
         self._walks = self._paged_walks()
+        self._walks_behind = self._walks_behind_exit()
+        self._groups_behind = self._groups_behind_exit()
+        # ... the whole-context group among them: ``qk_pairs`` is its count
+        self._full_behind = any(
+            b and not group.window for b, group in zip(
+                self._groups_behind, self.state_manager.groups))
         if cfg.is_hybrid:
             if cfg.moe_num_experts:     # its sparse FFNs' rows
                 self.put_totals.update(moe_rows_routed=0, moe_rows_held=0)
             for mixer in mixers:
                 self.put_totals.update(dict.fromkeys(mixer.totals, 0))
+            if cfg.layer_runs is not None:
+                # the positions that ran the layers behind the model's
+                # last layer that writes a cache: a row's last alone where
+                # the forward has an exit (``PagedCausalLM._forward_runs``)
+                self.put_totals["xdec_rows"] = 0
+                self._record += ("xdec_rows",)
+                self._exits = cfg.exit_at() is not None
         else:
             # forwards whose q, k and v came out of one stacked weight
             # (``fuse_qkv``): all of an engine's, or none
@@ -674,7 +687,10 @@ class InferenceEngineV2:
             staged.append((seq, toks))
             n, seen = len(toks), seq.seen_tokens
             valid += n
-            read, pairs = _keys_and_pairs(0, seen, n)
+            # behind the forward's exit a row's last position attends
+            # alone: that one query's keys and pairs, whatever the width
+            queries = ((seen, n), (seen + n - 1, min(n, 1)))
+            read, pairs = _keys_and_pairs(0, *queries[self._full_behind])
             kv_read += read
             qk_pairs += pairs
             last = -(-(seen + n) // block_size)
@@ -682,7 +698,8 @@ class InferenceEngineV2:
                 # the blocks the kernel's walk covers: from the window's
                 # first live block to the context's last
                 blocks_live += last - sm.first_live_block(group.window, seen)
-                read, pairs = _keys_and_pairs(group.window, seen, n)
+                read, pairs = _keys_and_pairs(
+                    group.window, *queries[self._groups_behind[g]])
                 group_read[g] += read
                 group_pairs[g] += pairs
 
@@ -735,6 +752,10 @@ class InferenceEngineV2:
                 arrays["block_tables"], slots, self.next_ids, id_slots)
         if "moe_rows_routed" in self.put_totals:    # its sparse FFNs' rows
             self._count_routing(valid)
+        if "xdec_rows" in totals:
+            self.last_put["xdec_rows"] = len(staged) if self._exits \
+                else valid
+            totals["xdec_rows"] += self.last_put["xdec_rows"]
         for count in self._counts:      # and what its kinds count
             counts = count(self.model.cfg, staged, bucket_chunk, block_size)
             self.last_put.update(counts)
@@ -760,6 +781,7 @@ class InferenceEngineV2:
                      "uids": " ".join(str(u) for u in uids)}
             if verify_width:
                 attrs["verify_width"] = int(verify_width)
+            attrs.update(self._own_counts())    # this forward's, no sums
         # the forward consumes ``kv_cache`` (donated, written in place) and
         # hands the same memory back as ``new_cache``
         try:
@@ -814,23 +836,64 @@ class InferenceEngineV2:
         kernels), none at all where the call is the XLA gather (off the
         chip, or by the registry)."""
         cfg, tp = self.model.cfg, self.paged.tp
-        shape = {"heads": cfg.num_heads // tp, "kv_heads": cfg.kv_heads // tp,
-                 "head_dim": cfg.head_dim,
+        heads, kv_heads, head_dim = cfg.paged_heads()
+        shape = {"heads": heads // tp, "kv_heads": kv_heads // tp,
+                 "head_dim": head_dim,
                  "block_size": self.config.kv_block_size}
         if self.paged._attn_raw is pa.paged_attention_xla \
                 or not pa.pallas_supported(shape["heads"], shape["kv_heads"],
-                                           cfg.head_dim):
+                                           head_dim):
             return []
         windows = [group.window for group in self.state_manager.groups]
         if cfg.layer_pattern is None:
-            groups = [0]
-        else:       # a kind's layer group, as ``_forward_hybrid`` finds it
-            groups = [windows.index(int(cfg.sliding_window)
-                                    if KINDS[kind].windowed else 0)
-                      for kind in kinds_of(cfg)
-                      if KINDS[kind].paged_walk]
-        return [(f"k{g or ''}", dict(shape, window=windows[g]))
-                for g in groups]
+            return [("k", dict(shape, window=windows[0]))]
+        # a kind's layer group, as ``_forward_hybrid`` finds it
+        walks = []
+        for kind in kinds_of(cfg):
+            if KINDS[kind].paged_walk:
+                g = windows.index(int(cfg.sliding_window)
+                                  if KINDS[kind].windowed else 0)
+                walks.append((f"k{g or ''}", dict(shape, window=windows[g])))
+        return walks
+
+    def _own_counts(self) -> Dict[str, int]:
+        """What a forward's ``dispatch`` span carries of ``last_put``
+        beside the engine's own attrs: ``xdec_rows``, where the model
+        counts it (the ``forward`` span holds a put's sums)."""
+        return {"xdec_rows": self.last_put["xdec_rows"]} \
+            if "xdec_rows" in self.last_put else {}
+
+    def _walks_behind_exit(self) -> List[bool]:
+        """For each of ``_paged_walks``' entries, whether the kind's
+        calls come behind the forward's exit — every layer of the kind
+        lies at ``cfg.exit_at()`` or behind it — where a chunk forward's
+        call is one position a row (``PagedCausalLM._forward_runs``)."""
+        cfg = self.model.cfg
+        exit_at = cfg.exit_at() if cfg.is_hybrid else None
+        if exit_at is None:
+            return [False] * len(self._walks)
+        return [exit_at <= min(
+            (r, i) for r, (pattern, _) in enumerate(cfg.layer_runs)
+            for i, k in enumerate(pattern) if k == kind)
+            for kind in kinds_of(cfg) if KINDS[kind].paged_walk]
+
+    def _groups_behind_exit(self) -> List[bool]:
+        """For each layer group, whether every kind that walks its pool
+        rows lies behind the forward's exit: a chunk row's keys and pairs
+        in that group are then its last position's (``_forward_rows``)."""
+        cfg = self.model.cfg
+        windows = [group.window for group in self.state_manager.groups]
+        exit_at = cfg.exit_at() if cfg.is_hybrid else None
+        if exit_at is None:
+            return [False] * len(windows)
+        behind: Dict[int, bool] = {}
+        for r, (pattern, _) in enumerate(cfg.layer_runs):
+            for i, kind in enumerate(pattern):
+                if KINDS[kind].paged_walk:
+                    g = windows.index(int(cfg.sliding_window)
+                                      if KINDS[kind].windowed else 0)
+                    behind[g] = behind.get(g, True) and exit_at <= (r, i)
+        return [behind.get(g, False) for g in range(len(windows))]
 
     def _count_attn_steps(self, arrays, merged: bool) -> None:
         """``attn_steps`` / ``attn_steps_primed`` / ``attn_turns`` /
@@ -856,8 +919,11 @@ class InferenceEngineV2:
                      (1, start * dead, n_tokens * dead)]
         cache = self.state_manager.forward_cache
         counts = np.zeros(len(_ATTN_COUNTS), np.int64)
-        for leaf, shape in self._walks:
-            for chunk, s, n in calls:
+        # behind the exit a row's last valid position attends alone
+        last = [(1, start + np.maximum(n_tokens - 1, 0),
+                 np.minimum(n_tokens, 1))]
+        for (leaf, shape), behind in zip(self._walks, self._walks_behind):
+            for chunk, s, n in (last if behind and width > 1 else calls):
                 counts += pa.grid_steps(
                     s, n, chunk=chunk, q_dtype=self.model.cfg.dtype,
                     pool_dtype=cache[leaf].dtype,

@@ -541,6 +541,9 @@ class PagedCausalLM:
         group_of = {kind: windows.index(cfg.sliding_window
                                         if KINDS[kind].windowed else 0)
                     for kind in kinds if KINDS[kind].pool is not None}
+        # a kind that reads another's pool rows reads them where they lie
+        group_of.update({kind: group_of[KINDS[kind].shares]
+                         for kind in kinds if KINDS[kind].shares})
         tables = [block_tables] if block_tables.ndim == 2 \
             else list(block_tables)
 
@@ -573,13 +576,19 @@ class PagedCausalLM:
                   quant="k_scale" in cache, write_rows=_write_rows,
                   attend=self._attend, select_widths=SELECT_WIDTHS)
 
+        def mixers_of(run):
+            return {kind: KINDS[kind].paged(cfg, run) for kind in kinds}
+
         def mixers_for(pools, first_layer):
             """The mixers of one run of layers over ``pools`` (written
             into); ``first_layer[kind]``: where the run's first layer of
             a kind sits among its group's (or the recurrent) layers."""
-            run = fwd._replace(pools=pools, first_layer=first_layer)
-            return {kind: KINDS[kind].paged(cfg, run) for kind in kinds}
+            return mixers_of(fwd._replace(pools=pools,
+                                          first_layer=first_layer))
 
+        if cfg.layer_runs is not None:
+            return self._forward_runs(params, cache, fwd, mixers_of, x,
+                                      next_ids, id_slots)
         slots = tuple(params["layers"][f"slot{i}"]
                       for i in range(len(pattern)))
         # where the scan is a loop, the routed experts stay off its xs:
@@ -613,12 +622,62 @@ class PagedCausalLM:
                 period, (x, pools),
                 (slots, jnp.arange(cfg.num_periods, dtype=jnp.int32)))
         with scope("final_norm"):
-            x = hybrid.block_norm(cfg, x, params["final_norm"]["w"])
+            x = hybrid.final_norm(cfg, x, params["final_norm"])
         with scope("logits"):
             last_idx = jnp.clip(n_tokens - 1, 0, C - 1)
             x_last = jnp.take_along_axis(x, last_idx[:, None, None],
                                          axis=1)[:, 0]
             logits = self.model._unembed(params, x_last[:, None, :])[:, 0]
+            if cfg.logit_scale != 1.0:
+                logits = logits * jnp.asarray(cfg.logit_scale, logits.dtype)
+            return _with_draw(logits, logits, new_cache, next_ids, id_slots)
+
+    def _forward_runs(self, params, cache, fwd, mixers_of, x, next_ids,
+                      id_slots):
+        """``_forward_hybrid``'s layers and logits for a model of several
+        runs of layers (``cfg.layer_runs``, ``hybrid.run_stack``), and
+        **the exit**: the engine reads a row's logits at its last valid
+        position alone, and behind the last layer that writes a cache or
+        a state (``cfg.exit_at()``) a position's value depends on that
+        position and on caches only. So a forward of ``C`` positions a
+        row runs the layers in front on all ``C``, has that layer write
+        its K/V for all ``C`` and attend from the row's last, and runs
+        what lies behind on that one position — the value the whole
+        forward would give there, no term left out; whatever was not the
+        row's last position is not computed (a chunk that is not a
+        prompt's last pays the tail on its one row all the same: one
+        program a chunk width). A forward of one position a row runs
+        every layer as it is."""
+        cfg = self.cfg
+        N, C = fwd.shape
+        scope = jax.named_scope
+        tail = None
+        if cfg.exit_at() is not None:
+            last_idx = jnp.clip(fwd.n_tokens - 1, 0, C - 1)
+
+            def narrow(a):      # [N, C, ...] -> [N, 1, ...]: the last valid
+                if C == 1:
+                    return a
+                return jnp.take_along_axis(
+                    a, last_idx.reshape((N, 1) + (1,) * (a.ndim - 2)),
+                    axis=1)
+
+            tail = fwd._replace(
+                shape=(N, 1), start_pos=fwd.start_pos + last_idx,
+                n_tokens=jnp.minimum(fwd.n_tokens, 1),
+                positions=narrow(fwd.positions), narrow=narrow)
+        with scope("layers"):
+            x, new_cache = hybrid.run_stack(
+                cfg, x, params["layers"], fwd, mixers_of, pools=cache,
+                tail=tail)
+        with scope("final_norm"):
+            x = hybrid.final_norm(cfg, x, params["final_norm"])
+        with scope("logits"):
+            if tail is None:
+                x = jnp.take_along_axis(
+                    x, jnp.clip(fwd.n_tokens - 1, 0, C - 1)[:, None, None],
+                    axis=1)
+            logits = self.model._unembed(params, x)[:, 0]
             if cfg.logit_scale != 1.0:
                 logits = logits * jnp.asarray(cfg.logit_scale, logits.dtype)
             return _with_draw(logits, logits, new_cache, next_ids, id_slots)

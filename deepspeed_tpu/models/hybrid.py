@@ -26,6 +26,13 @@ their own is a period of such positions.
   place, and so does every layer of a model without experts
   (``moe_num_experts`` 0); ``sandwich_norm`` puts a norm behind the mixer
   and the FFN too; ``residual_scale`` multiplies both before their adds.
+- the norm: RMSNorm, or LayerNorm with gain and bias (``cfg.norm``:
+  ``norm_of``).
+- a model of several runs of layers (``cfg.layer_runs``: ``run_stack``):
+  run after run, each a pattern with periods of its own and a scan of its
+  own, with values that ride beside ``x`` from a run to the runs behind
+  it (a state-space layer's memory, a whole-context layer's K/V) and, in
+  serving, an exit behind the last layer that writes a cache.
 
 ``CausalLM`` (training, the reference path) and ``PagedCausalLM``
 (serving) both run their layers through ``run_period``; only where the
@@ -57,7 +64,7 @@ from .mixers.block_sparse import (block_compress, block_keep,  # noqa: F401
 from .mixers.latent import (index_qk, index_scores, latent_cq,  # noqa: F401
                             latent_qkv, latent_scale)
 from .mixers.select import index_keep, index_select  # noqa: F401
-from .transformer import _linear
+from .transformer import _linear, _norm
 
 
 class RecurrentStateUnsupported(NotImplementedError):
@@ -126,13 +133,28 @@ class _Draw:
                 ).astype(jnp.float32)
 
 
-def _norm_names(cfg, kind, ffn: bool):
-    """A position's norm gains: one (two under ``sandwich_norm``) for
-    each part it has."""
+def _norm_names(cfg, kind, ffn: bool, leaf: str = "w"):
+    """A position's norm gains (``leaf`` "b": a LayerNorm's biases): one
+    (two under ``sandwich_norm``) for each part it has."""
     parts = (("attn",) if kind is not None else ()) + (("mlp",) if ffn
                                                        else ())
-    return [f"{pre}{part}_norm_w" for part in parts
+    return [f"{pre}{part}_norm_{leaf}" for part in parts
             for pre in (("", "post_") if cfg.sandwich_norm else ("",))]
+
+
+def norm_of(cfg):
+    """The block's norm, ``(x, lp, name) -> x normed`` by the leaves
+    ``lp[name + "_w"]`` and, for a LayerNorm, ``lp[name + "_b"]``."""
+    if cfg.norm == "rmsnorm":
+        return lambda x, lp, name: block_norm(cfg, x, lp[name + "_w"])
+    return lambda x, lp, name: _norm(x, lp[name + "_w"], lp[name + "_b"],
+                                     cfg.norm, cfg.norm_eps)
+
+
+def final_norm(cfg, x, leaves):
+    """The norm in front of the logits, by ``params["final_norm"]``."""
+    return norm_of(cfg)(x, {f"final_{k}": a for k, a in leaves.items()},
+                        "final")
 
 
 def init_slot(cfg, kind, key, periods: int, dense: bool = False,
@@ -149,6 +171,9 @@ def init_slot(cfg, kind, key, periods: int, dense: bool = False,
         return fill((P,) + shape, jnp.float32)
 
     lp = {name: gain(h) for name in _norm_names(cfg, kind, ffn)}
+    if cfg.norm == "layernorm":
+        lp.update({name: jnp.zeros((P, h), jnp.float32)
+                   for name in _norm_names(cfg, kind, ffn, "b")})
     if kind is not None:
         lp.update(KINDS[kind].init(cfg, w, gain))
     if not ffn:
@@ -186,7 +211,8 @@ def init_slot(cfg, kind, key, periods: int, dense: bool = False,
 def slot_specs(cfg, kind, dense: bool = False, ffn: bool = True):
     """Logical sharding axes of ``init_slot``'s tree."""
     lp = {name: spec("layers", "embed")
-          for name in _norm_names(cfg, kind, ffn)}
+          for leaf in (("w", "b") if cfg.norm == "layernorm" else ("w",))
+          for name in _norm_names(cfg, kind, ffn, leaf)}
     if kind is not None:
         lp.update(KINDS[kind].specs(cfg))
     if not ffn:
@@ -222,7 +248,7 @@ def slot_specs(cfg, kind, dense: bool = False, ffn: bool = True):
 # ----------------------------------------------------------------- period
 
 def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
-               max_rows=None, transform=None, period=None):
+               max_rows=None, transform=None, period=None, narrow=None):
     """A run of layers on x [B, T, H] — one period (``kinds`` None: the
     pattern) or the lead layers (``dense``) — each position's norm, its
     mixer, the FFN and the residual adds (a position of the pattern may
@@ -232,8 +258,12 @@ def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
     cache lives is the caller's (training keeps none, serving paged pools
     and state slots). ``period``: the slots' routed experts are whole
     stacks over the periods (``expert_stacks``) and this is the period to
-    run. Returns (x, summed aux loss)."""
+    run. ``narrow``: the run is the one layer behind whose mixer a serving
+    forward's rows leave (``run_stack``): ``x`` [B, T, H] -> [B, 1, H],
+    taken where the mixer's output, one position a row, is added. Returns
+    (x, summed aux loss)."""
     scope = jax.named_scope
+    norm = norm_of(cfg)
     aux = jnp.zeros((), jnp.float32)
     # the pattern's positions say which carry an FFN; a lead layer does
     ffns = cfg.layer_ffn if kinds is None else None
@@ -253,29 +283,133 @@ def run_period(cfg, x, slots, mixers, kinds=None, dense=False, valid=None,
                     x.reshape(-1, x.shape[-1]), lp)
         if kind is not None:
             with scope("attn_norm"):
-                h1 = block_norm(cfg, x, lp["attn_norm_w"])
+                h1 = norm(x, lp, "attn_norm")
             with scope(KINDS[kind].scope) if KINDS[kind].scope \
                     else contextlib.nullcontext():
                 y = mixers[kind](h1, lp, seen[kind])
             seen[kind] += 1
+            if narrow is not None:
+                x = narrow(x)
         with scope("mlp"):      # norms, FFN and the residual adds
             if kind is not None:
                 if cfg.sandwich_norm:
-                    y = block_norm(cfg, y, lp["post_attn_norm_w"])
+                    y = norm(y, lp, "post_attn_norm")
                 x = x + scaled(y)
             if not ffn:
                 continue
-            h2 = block_norm(cfg, x, lp["mlp_norm_w"])
+            h2 = norm(x, lp, "mlp_norm")
             if dense or not cfg.moe_num_experts:
                 f, a = dense_ffn(cfg, h2, lp), 0.0
             else:
                 f, a = moe_ffn(cfg, h2, lp, valid=valid, max_rows=max_rows,
                                router_logits=router_logits, period=period)
             if cfg.sandwich_norm:
-                f = block_norm(cfg, f, lp["post_mlp_norm_w"])
+                f = norm(f, lp, "post_mlp_norm")
             x = x + scaled(f)
         aux = aux + a
     return x, aux
+
+
+def run_slots(cfg, layers):
+    """A model of ``layer_runs``' weights, one tuple of trees a run (a
+    tree a position of the run's pattern, stacked over its periods):
+    ``layers["run<r>_slot<i>"]``."""
+    return tuple(tuple(layers[f"run{r}_slot{i}"]
+                       for i in range(len(pattern)))
+                 for r, (pattern, _) in enumerate(cfg.layer_runs))
+
+
+def run_stack(cfg, x, layers, fwd, mixers_of, pools=None, tail=None,
+              transform=None):
+    """The layers of a model that is several runs (``cfg.layer_runs``) on
+    x [B, T, H]: run after run, a run of more than one period a
+    ``lax.scan`` over its periods (PERF.md section 6, PR 46: a period
+    inline ran slower than the loop), a run of one laid out inline.
+
+    ``fwd``: the forward's ``mixers.Fwd``; ``mixers_of(fwd) -> {kind:
+    mixer}`` builds a run's layers over it (a kind's ``reference`` or
+    ``paged``) once the run's own fields are filled in here: ``pools``
+    (serving: the cache tree, written into and returned), where the run's
+    first layer of a kind sits (``first_layer``), its layers' depth in
+    the model (``depth_of``), and the **carry** — the values that ride
+    beside ``x`` from the run that hands them on to the runs that take
+    them (``cfg.run_feeds``): written by the handing run's layers, read
+    by the later ones.
+
+    ``tail`` (serving): this forward as the rows behind the exit see it —
+    one position a row, the row's last valid one — with ``tail.narrow``
+    [B, T, ...] -> [B, 1, ...]. The layer at ``cfg.exit_at()`` writes its
+    cache from every position and attends from that one
+    (``Fwd.exit``); behind it ``x``, the carry and every layer run on one
+    position a row, the runs behind its own under the scope ``xdec``.
+    None: every position runs every layer. Returns (x, pools)."""
+    scope = jax.named_scope
+    kinds = kinds_of(cfg)
+    feeds = cfg.run_feeds(cached=pools is not None)
+    exit_at = cfg.exit_at() if tail is not None else None
+    carry, first, depth = {}, dict.fromkeys(kinds, 0), 0
+    pools = None if pools is None else dict(pools)
+
+    def run_of(view, pattern, base, pools, first, hand=(), period=0):
+        """``view`` filled in for one period of a run."""
+        places = {kind: [i for i, k in enumerate(pattern) if k == kind]
+                  for kind in dict.fromkeys(pattern)}
+        return view._replace(
+            pools=pools, carry=carry, hand=hand,
+            first_layer={kind: first[kind] + period * pattern.count(kind)
+                         for kind in kinds},
+            depth_of=lambda kind, i: base + period * len(pattern)
+            + places[kind][i])
+
+    for r, ((pattern, periods), slots) in enumerate(zip(
+            cfg.layer_runs, run_slots(cfg, layers))):
+        behind = exit_at is not None and r > exit_at[0]
+        view = tail if behind else fwd
+        with scope("xdec") if behind else contextlib.nullcontext():
+            if periods > 1:
+                def period(c, xs, view=view, pattern=pattern, base=depth,
+                           first=dict(first)):
+                    x, pools = c
+                    slots, p = xs
+                    pools = None if pools is None else dict(pools)
+                    x, _ = run_period(
+                        cfg, x, slots, mixers_of(run_of(
+                            view, pattern, base, pools, first, period=p)),
+                        kinds=pattern, transform=transform)
+                    return (x, pools), None
+
+                (x, pools), _ = jax.lax.scan(
+                    period, (x, pools),
+                    (slots, jnp.arange(periods, dtype=jnp.int32)))
+            else:
+                # inline, in up to three parts: the layers in front of the
+                # exit, the one at it, the ones behind
+                slots = tuple(jax.tree.map(lambda a: a[0], lp)
+                              for lp in slots)
+                e = exit_at[1] if exit_at and exit_at[0] == r else None
+                cuts = [(0, len(pattern), view, None)] if e is None else [
+                    (0, e, fwd, None),
+                    (e, e + 1, fwd._replace(exit=tail), tail.narrow),
+                    (e + 1, len(pattern), tail, None)]
+                at = dict(first)
+                for lo, hi, part, narrow in cuts:
+                    if lo == hi:
+                        continue
+                    x, _ = run_period(
+                        cfg, x, slots[lo:hi], mixers_of(run_of(
+                            part, pattern[lo:hi], depth + lo, pools, at,
+                            hand=feeds[r])),
+                        kinds=pattern[lo:hi], transform=transform,
+                        narrow=narrow)
+                    if narrow is not None:      # what rides on leaves too
+                        carry = {name: jax.tree.map(narrow, value)
+                                 for name, value in carry.items()}
+                    for kind in pattern[lo:hi]:
+                        at[kind] += 1
+        for kind in kinds:
+            first[kind] += periods * pattern.count(kind)
+        depth += periods * len(pattern)
+    return x, pools
 
 
 #: the routed experts' leaves of a sparse position

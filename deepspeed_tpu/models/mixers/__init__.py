@@ -12,7 +12,8 @@ from __future__ import annotations
 from types import MappingProxyType
 from typing import Dict, Mapping, Tuple
 
-from . import attention, block_sparse, gdn, latent, lightning, mamba2
+from . import (attention, block_sparse, cross, gdn, gmu, latent, lightning,
+               mamba1, mamba2)
 from .base import Fwd, Mixer  # noqa: F401
 
 #: read-only: a kind is added here, in the source, and nowhere else
@@ -26,6 +27,9 @@ KINDS: Mapping[str, Mixer] = MappingProxyType({
     "lightning": lightning.LIGHTNING,
     "block_sparse": block_sparse.BLOCK_SPARSE,
     "mamba2": mamba2.MAMBA2,
+    "mamba1": mamba1.MAMBA1,
+    "gmu": gmu.GMU,
+    "cross": cross.CROSS,
 })
 
 

@@ -52,7 +52,17 @@ class Mixer:
       and prefixes of its counts in ``last_put`` that a put of several
       forwards sums; ``count(cfg, staged, bucket_chunk, block_size)`` ->
       this forward's counts, ``staged`` its ``(sequence, tokens)`` rows
-      before they are committed."""
+      before they are committed.
+    - what it reads of other layers (a model of several runs of layers,
+      ``cfg.layer_runs``): ``hands``, the values it can hand on beside
+      ``x`` to the runs behind its own, and ``takes``, the ones it reads
+      (``Fwd.carry``; ``cfg.run_feeds`` says which run hands what).
+      ``shares``: a kind whose latest layer's pool rows this kind reads
+      in serving, writing none: it has no ``pool`` of its own, its group
+      and its table are that kind's, and what it ``takes`` there comes
+      out of the pool. ``exits``: its serving layer can write its cache
+      from every position and attend from a row's last alone
+      (``Fwd.exit``)."""
     init: Callable
     specs: Callable
     reference: Callable
@@ -68,6 +78,10 @@ class Mixer:
     totals: Tuple[str, ...] = ()
     record: Tuple[str, ...] = ()
     count: Optional[Callable] = None
+    hands: Tuple[str, ...] = ()
+    takes: Tuple[str, ...] = ()
+    shares: Optional[str] = None
+    exits: bool = False
 
 
 class Fwd(NamedTuple):
@@ -101,6 +115,22 @@ class Fwd(NamedTuple):
     #: the widths a learned selection's scores are taken at
     #: (``paged_model.SELECT_WIDTHS``)
     select_widths: Sequence[int] = ()
+    # --- a model of several runs of layers (``cfg.layer_runs``)
+    #: the values that ride beside ``x`` from run to run, by name: read by
+    #: the kinds that ``takes`` them, written by those that ``hands``
+    #: them, in the run ``hand`` names them for
+    carry: Optional[Dict[str, Any]] = None
+    hand: Sequence[str] = ()
+    #: ``(kind, i)`` -> the index in the model of the run's ``i``-th layer
+    #: of a kind (an int, or a traced one inside a scan)
+    depth_of: Optional[Callable] = None
+    #: set for the one layer in which a serving forward's rows leave but
+    #: for their last valid position: this forward as the rows behind see
+    #: it (one position a row, ``start_pos`` the last position's) ...
+    exit: Any = None
+    #: ... and on that view, [N, C, ...] -> [N, 1, ...]: the row's last
+    #: valid position
+    narrow: Optional[Callable] = None
 
     def rope(self, cfg, kind: str):
         """``kind``'s rotation at the model's base; the identity for a
@@ -111,6 +141,11 @@ class Fwd(NamedTuple):
 
     def layer(self, kind: str, i):
         return self.first_layer[kind] + i
+
+    def depth(self, kind: str, i):
+        """The layer's index in the model (a model of runs; None in any
+        other, where no kind asks)."""
+        return None if self.depth_of is None else self.depth_of(kind, i)
 
     def write(self, kind: str, name: str, rows, layer) -> None:
         """``rows`` [n, ...] into layer ``layer`` of ``kind``'s group's

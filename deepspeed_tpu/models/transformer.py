@@ -120,6 +120,21 @@ class TransformerConfig:
     # ``layer_pattern``): one norm and one residual add, a layer of a
     # model that counts its mixers and its FFNs as layers of their own
     layer_ffn: Optional[Tuple[bool, ...]] = None
+    # A model that is several runs of layers, each with a pattern and a
+    # period count of its own: ``((pattern, periods), ...)``, run after
+    # run, each a scan of its own (a decoder-hybrid-decoder is
+    # ``(("mamba1", "window"), 8), (("mamba1", "full"), 1), (("gmu",
+    # "cross"), 7)``). ``layer_pattern`` is then the runs' patterns laid
+    # end to end (set from this; given, it has to agree), every position
+    # is a mixer with the dense MLP behind it, and there are no
+    # ``lead_layers``. Values may ride beside the residual stream from a
+    # run to the runs behind it (``mixers.base.Mixer.hands`` / ``takes``:
+    # ``run_feeds``), and a serving forward's rows may leave behind the
+    # last layer that writes a cache (``exit_at``).
+    layer_runs: Optional[Tuple[Tuple[Tuple[str, ...], int], ...]] = None
+    # the differential form of the "full" / "window" / "cross" kinds
+    # (models/mixers/attention.py): heads in pairs, two softmaxes a pair
+    diff_attn: bool = False
     head_size: Optional[int] = None      # stated head size; None → hidden/heads
     attn_output_gate: bool = False       # wq twice as wide: [q | gate] a head
     attn_gate_proj: bool = False         # ... or a projection of its own (wg)
@@ -218,6 +233,17 @@ class TransformerConfig:
     mamba_n_groups: int = 1
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 128
+    # A "mamba1" layer (ops/selective_scan.py): the Mamba (S6)
+    # state-space layer, ``mamba1_inner_size`` channels over a float32
+    # state ``[mamba1_state_size, channels]`` a sequence under a decay of
+    # its own a channel and a state index, its step from a rank-
+    # ``mamba1_dt_rank`` projection, a depthwise causal conv of
+    # ``mamba1_conv_kernel`` taps (with bias) in front and a gate behind. A
+    # "gmu" layer gates the memory such a layer hands on, as wide.
+    mamba1_inner_size: int = 0
+    mamba1_state_size: int = 0
+    mamba1_dt_rank: int = 0
+    mamba1_conv_kernel: int = 4
     # A "block_sparse" layer (InfLLM-V2): a "full" layer whose query, from
     # position ``block_dense_len`` on, attends whole blocks of
     # ``block_select_size`` keys only: the first ``block_init_blocks``,
@@ -246,6 +272,8 @@ class TransformerConfig:
             value = getattr(self, name)
             if isinstance(value, list):
                 object.__setattr__(self, name, tuple(value))
+        if self.layer_runs is not None:
+            self._check_runs()
         if self.layer_pattern is not None:
             from .mixers import KINDS, kinds_of
 
@@ -253,8 +281,9 @@ class TransformerConfig:
             ffn = self.layer_ffn or (True,) * len(pattern or ())
             if not pattern or any(k not in KINDS for k in lead) \
                     or any(k is not None and k not in KINDS for k in pattern) \
-                    or (self.num_layers - len(lead)) % len(pattern) \
-                    or self.num_layers <= len(lead):
+                    or (self.layer_runs is None and (
+                        (self.num_layers - len(lead)) % len(pattern)
+                        or self.num_layers <= len(lead))):
                 raise ValueError(
                     f"layer_pattern {pattern!r}: a period of "
                     f"{tuple(KINDS)} that divides num_layers "
@@ -290,15 +319,115 @@ class TransformerConfig:
             if windowed != (isinstance(self.sliding_window, int)
                             and self.sliding_window > 0) \
                     or self.moe_num_experts < 0 \
-                    or self.norm != "rmsnorm" or self.position != "rope":
+                    or self.norm not in ("rmsnorm", "layernorm") \
+                    or self.position != "rope":
                 raise ValueError(
-                    "a hybrid block is RMSNorm and rotary, its FFN the "
+                    "a hybrid block is RMSNorm or LayerNorm and rotary "
+                    "(rope_kinds names the kinds that are), its FFN the "
                     "sparse one (moe_num_experts > 0) or the dense MLP "
                     "(0); sliding_window is its \"window\" layers' "
                     "length, and set with them only")
+            if self.layer_runs is not None:
+                self.run_feeds()        # raises on a layer nothing feeds
+            elif any(KINDS[kind].takes for kind in kinds):
+                raise ValueError(
+                    f"the kinds {[k for k in kinds if KINDS[k].takes]} read "
+                    "what an earlier run of layers hands on: they belong "
+                    "to layer_runs")
         elif self.lead_layers or self.moe_router_input != "ffn":
             raise ValueError("lead_layers and moe_router_input belong to a "
                              "layer_pattern")
+
+    def _check_runs(self):
+        """``layer_runs`` made tuples and held to its rules; sets
+        ``layer_pattern`` to the runs' patterns end to end."""
+        try:
+            runs = tuple((tuple(pattern), int(n))
+                         for pattern, n in self.layer_runs)
+        except (TypeError, ValueError):
+            runs = ()
+        flat = tuple(kind for pattern, _ in runs for kind in pattern)
+        if not runs or any(not pattern or n < 1 for pattern, n in runs) \
+                or None in flat \
+                or sum(len(pattern) * n for pattern, n in runs) \
+                != self.num_layers \
+                or self.lead_layers or self.layer_ffn is not None \
+                or self.moe_num_experts \
+                or self.layer_pattern not in (None, flat):
+            raise ValueError(
+                f"layer_runs {self.layer_runs!r}: ((pattern, periods), "
+                f"...), every position a mixer kind, whose layers add up "
+                f"to num_layers ({self.num_layers}); no lead_layers, no "
+                "layer_ffn, no experts, and a layer_pattern (if any) that "
+                "is the runs' patterns end to end")
+        object.__setattr__(self, "layer_runs", runs)
+        object.__setattr__(self, "layer_pattern", flat)
+
+    @property
+    def runs(self) -> Tuple[Tuple[Tuple[Optional[str], ...], int], ...]:
+        """A hybrid block's runs of layers behind its lead layers:
+        ``layer_runs``, or the one pattern with its periods."""
+        return self.layer_runs or ((self.layer_pattern, self.num_periods),)
+
+    def run_feeds(self, cached: bool = False) -> Tuple[Tuple[str, ...], ...]:
+        """For each run of ``layer_runs``, the values its kinds hand on
+        beside the residual stream (``Mixer.hands``) that a later run's
+        kinds take (``Mixer.takes``): a value comes from the latest
+        earlier run that can hand it, and that run is one period (its
+        layers are laid out inline, and what the last of them hands is
+        what rides on). ``cached``: serving, where a kind that ``shares``
+        another's pool rows takes nothing through the carry. Raises
+        ``ValueError`` on a layer that no earlier run feeds."""
+        from .mixers import KINDS
+
+        feeds = [set() for _ in self.layer_runs]
+        for t, (pattern, _) in enumerate(self.layer_runs):
+            for kind in dict.fromkeys(pattern):
+                for name in KINDS[kind].takes:
+                    source = max((s for s in range(t) if any(
+                        name in KINDS[k].hands
+                        for k in self.layer_runs[s][0])), default=None)
+                    if source is None or self.layer_runs[source][1] != 1:
+                        raise ValueError(
+                            f"a {kind!r} layer of run {t} reads {name!r}, "
+                            "which no earlier run of one period hands on "
+                            f"(layer_runs {self.layer_runs!r})")
+                    if not (cached and KINDS[kind].shares):
+                        feeds[source].add(name)
+        return tuple(tuple(sorted(names)) for names in feeds)
+
+    def exit_at(self) -> Optional[Tuple[int, int]]:
+        """``(run, position)`` of the last layer that writes a cache or a
+        state, where a serving forward's rows may leave but for their
+        last valid position — behind it a position's value depends on
+        that position's own ``x`` and on caches alone, and the engine
+        reads a row's last. None where there is no such exit: not a model
+        of ``layer_runs``, the layer in a scanned run, of a kind that
+        cannot (``Mixer.exits``) or one that is rotated."""
+        if self.layer_runs is None:
+            return None
+        from .mixers import KINDS
+
+        writers = [(r, i) for r, (pattern, _) in enumerate(self.layer_runs)
+                   for i, kind in enumerate(pattern)
+                   if KINDS[kind].pool is not None
+                   or KINDS[kind].state is not None]
+        if not writers:
+            return None
+        r, i = writers[-1]
+        pattern, periods = self.layer_runs[r]
+        rotated = self.rope_kinds is None or pattern[i] in self.rope_kinds
+        if periods != 1 or not KINDS[pattern[i]].exits or rotated:
+            return None
+        return r, i
+
+    def paged_heads(self) -> Tuple[int, int, int]:
+        """(heads, K/V heads, head size) as the paged attention and its
+        pool see a hybrid block's attention layers: the model's, or under
+        ``diff_attn`` the joined pairs' (models/mixers/attention.py)."""
+        if self.diff_attn:
+            return self.num_heads, self.kv_heads // 2, 2 * self.head_dim
+        return self.num_heads, self.kv_heads, self.head_dim
 
     @property
     def head_dim(self) -> int:
@@ -341,14 +470,19 @@ class TransformerConfig:
 
     @property
     def num_periods(self) -> int:
+        """The periods of the one pattern (a model of ``layer_runs`` has
+        a count a run: ``runs``)."""
+        if self.layer_runs is not None:
+            raise AttributeError("a model of layer_runs has no one "
+                                 "num_periods: read cfg.runs")
         return (self.num_layers - len(self.lead_layers)) \
             // len(self.layer_pattern)
 
     def layers_of(self, kind: str) -> int:
         """A hybrid block's layers of one mixer kind, lead layers and
         periods together."""
-        return self.lead_layers.count(kind) \
-            + self.num_periods * self.layer_pattern.count(kind)
+        return self.lead_layers.count(kind) + sum(
+            n * pattern.count(kind) for pattern, n in self.runs)
 
     def ffn_at(self, i: int) -> bool:
         """Whether position ``i`` of the period carries an FFN."""
@@ -1009,15 +1143,28 @@ class CausalLM:
         h, v = cfg.hidden_size, cfg.vocab_size
         keys = jax.random.split(rng, len(cfg.layer_pattern) + 2)
         gain = jnp.zeros if cfg.norm_zero_centered else jnp.ones
+        if cfg.layer_runs is not None:
+            # a tree a position of each run (``run<r>_slot<i>``), stacked
+            # over the run's periods; ``keys`` follow the positions as
+            # ``layer_pattern`` lays them end to end
+            at = iter(keys)
+            layers = {f"run{r}_slot{i}": hybrid.init_slot(cfg, kind,
+                                                          next(at), periods)
+                      for r, (pattern, periods) in enumerate(cfg.layer_runs)
+                      for i, kind in enumerate(pattern)}
+        else:
+            layers = {f"slot{i}": hybrid.init_slot(cfg, kind, keys[i],
+                                                   cfg.num_periods,
+                                                   ffn=cfg.ffn_at(i))
+                      for i, kind in enumerate(cfg.layer_pattern)}
         params = {
             "embed": {"wte": (0.02 * jax.random.normal(keys[-1], (v, h))
                               ).astype(jnp.float32)},
-            "layers": {f"slot{i}": hybrid.init_slot(cfg, kind, keys[i],
-                                                    cfg.num_periods,
-                                                    ffn=cfg.ffn_at(i))
-                       for i, kind in enumerate(cfg.layer_pattern)},
+            "layers": layers,
             "final_norm": {"w": gain((h,), jnp.float32)},
         }
+        if cfg.norm == "layernorm":
+            params["final_norm"]["b"] = jnp.zeros((h,), jnp.float32)
         # the lead layers' trees, each stacked over one "period"
         for j, kind in enumerate(cfg.lead_layers):
             params["layers"][f"lead{j}"] = hybrid.init_slot(
@@ -1035,11 +1182,19 @@ class CausalLM:
         if cfg.is_hybrid:
             from . import hybrid
 
+            if cfg.layer_runs is not None:
+                layers = {f"run{r}_slot{i}": hybrid.slot_specs(cfg, kind)
+                          for r, (pattern, _) in enumerate(cfg.layer_runs)
+                          for i, kind in enumerate(pattern)}
+            else:
+                layers = {f"slot{i}": hybrid.slot_specs(
+                              cfg, kind, ffn=cfg.ffn_at(i))
+                          for i, kind in enumerate(cfg.layer_pattern)}
             specs = {"embed": {"wte": spec("vocab", "embed")},
-                     "layers": {f"slot{i}": hybrid.slot_specs(
-                                    cfg, kind, ffn=cfg.ffn_at(i))
-                                for i, kind in enumerate(cfg.layer_pattern)},
+                     "layers": layers,
                      "final_norm": {"w": spec("embed")}}
+            if cfg.norm == "layernorm":
+                specs["final_norm"]["b"] = spec("embed")
             for j, kind in enumerate(cfg.lead_layers):
                 specs["layers"][f"lead{j}"] = hybrid.slot_specs(
                     cfg, kind, dense=True)
@@ -1357,25 +1512,36 @@ class CausalLM:
         for theta, width in own_rope_bases(cfg).items():
             ropes[theta] = rope_at(width, theta)
         fwd = Fwd(shape=(B, T), n_tokens=n_tokens, ropes=ropes)
-        mixers = {kind: KINDS[kind].reference(cfg, fwd)
-                  for kind in kinds_of(cfg)}
 
-        def period(x, slots):
-            return hybrid.run_period(cfg, x, slots, mixers,
-                                     transform=self.layer_transform)
+        def mixers_of(fwd):
+            return {kind: KINDS[kind].reference(cfg, fwd)
+                    for kind in kinds_of(cfg)}
 
-        if cfg.remat:
-            period = jax.checkpoint(period)
-        slots = tuple(params["layers"][f"slot{i}"]
-                      for i in range(len(cfg.layer_pattern)))
-        with scope("layers"):
-            x, _ = hybrid.run_period(
-                cfg, x, hybrid.lead_slots(cfg, params), mixers,
-                kinds=cfg.lead_layers, dense=True,
-                transform=self.layer_transform)
-            x, aux = lax.scan(period, x, slots)
+        if cfg.layer_runs is not None:
+            with scope("layers"):
+                x, _ = hybrid.run_stack(cfg, x, params["layers"], fwd,
+                                        mixers_of,
+                                        transform=self.layer_transform)
+            aux = jnp.zeros((), jnp.float32)
+        else:
+            mixers = mixers_of(fwd)
+
+            def period(x, slots):
+                return hybrid.run_period(cfg, x, slots, mixers,
+                                         transform=self.layer_transform)
+
+            if cfg.remat:
+                period = jax.checkpoint(period)
+            slots = tuple(params["layers"][f"slot{i}"]
+                          for i in range(len(cfg.layer_pattern)))
+            with scope("layers"):
+                x, _ = hybrid.run_period(
+                    cfg, x, hybrid.lead_slots(cfg, params), mixers,
+                    kinds=cfg.lead_layers, dense=True,
+                    transform=self.layer_transform)
+                x, aux = lax.scan(period, x, slots)
         with scope("final_norm"):
-            x = hybrid.block_norm(cfg, x, params["final_norm"]["w"])
+            x = hybrid.final_norm(cfg, x, params["final_norm"])
         with scope("logits"):
             logits = self._unembed(params, x)
             if cfg.logit_scale != 1.0:

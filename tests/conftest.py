@@ -142,6 +142,17 @@ PINNED_SATURATED_LISTS[
         None, "setup_cache_hit_share")
 
 
+#: Two hold metrics to list their own cells and no other — PR 59's to its
+#: two chunked cells, PR 52's state-space readers to its one cell; PR 60's
+#: cell reads all of them too. They are shown the cells as far as PR 55's.
+PINNED_SATURATED_LISTS.update(dict.fromkeys((
+    "tests/benchmark/test_paged_unmasked_turn_share.py::"
+    "test_the_manifest_names_the_metric_for_the_chunked_cells",
+    "tests/benchmark/test_nemotron_h_block.py::"
+    "test_the_manifest_validates_with_the_new_entries",
+), ("smallthinker-21b-a3b.bulkgen", "paged_unmasked_turn_share")))
+
+
 def manifest_up_to(manifest: dict, cell, last_metric: str) -> dict:
     """``manifest`` without the per-layer metrics appended after
     ``last_metric`` and, where ``cell`` is given, without the cells

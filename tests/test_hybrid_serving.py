@@ -480,3 +480,289 @@ def test_experts_indexed_in_their_stacks_are_the_periods_own(cfg,
     assert stacked[1].keys() == sliced[1].keys()
     for leaf in stacked[1]:
         np.testing.assert_array_equal(stacked[1][leaf], sliced[1][leaf])
+
+
+# ------------------------------------------- several runs of layers (SambaY)
+#
+# A decoder-hybrid-decoder at a tiny size: (mamba1, window) x 3, (mamba1,
+# full) x 1, (gmu, cross) x 2 — LayerNorm with bias, differential attention
+# with biases, no position term, a window of 16 (two blocks of 8).
+
+RUNS_CFG = TransformerConfig(
+    vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=12,
+    num_heads=8, num_kv_heads=4, head_size=8, max_seq_len=256,
+    sliding_window=16, norm="layernorm", norm_eps=1e-5, activation="silu",
+    position="rope", rope_kinds=(), tie_embeddings=True, qkv_bias=True,
+    o_bias=True, attn_scale=8 ** -0.5, diff_attn=True, dtype=jnp.float32,
+    layer_runs=((("mamba1", "window"), 3), (("mamba1", "full"), 1),
+                (("gmu", "cross"), 2)),
+    mamba1_inner_size=64, mamba1_state_size=4, mamba1_dt_rank=4,
+    mamba1_conv_kernel=4)
+RUNS_ARCH = dict(
+    vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=12,
+    num_heads=8, num_kv_heads=4, head_size=8, sliding_window=16,
+    norm_eps=1e-5, layer_runs=[[["mamba1", "window"], 3],
+                               [["mamba1", "full"], 1],
+                               [["gmu", "cross"], 2]],
+    mamba1_inner_size=64, mamba1_state_size=4, mamba1_dt_rank=4,
+    mamba1_conv_kernel=4)
+RUNS_PROMPT, RUNS_STEPS = 70, 4
+_RUNS_FORWARDS = {}
+
+
+def runs_block():
+    reference_block()       # puts the checkout on the path
+    from benchmark import manifest as mf
+
+    return mf.find_module(mf.HERE, "blocks", "phi4flash")
+
+
+@pytest.fixture(scope="module")
+def runs_model():
+    """The model, weights with every gain and bias off its initial value
+    (a dropped one would show), a prompt longer than window + block, and
+    the reference's logits: every layer on every position."""
+    model = CausalLM(RUNS_CFG)
+    params = model.init(jax.random.PRNGKey(2))
+    flat, tree = jax.tree_util.tree_flatten_with_path(params)
+    keys = jax.random.split(jax.random.PRNGKey(3), len(flat))
+    flat = [leaf + 0.05 * jax.random.normal(k, leaf.shape)
+            if "norm" in jax.tree_util.keystr(path)
+            or jax.tree_util.keystr(path).endswith("_b']") else leaf
+            for (path, leaf), k in zip(flat, keys)]
+    params = jax.tree_util.tree_unflatten(tree, flat)
+    tokens = prompt(11, RUNS_PROMPT + RUNS_STEPS)
+    want = np.asarray(runs_block().logits(
+        params, np.asarray(tokens, np.int32), RUNS_ARCH, 16))[:len(tokens)]
+    return model, params, tokens, want
+
+
+def runs_engine(runs_model, params=None, dtype=None, **sizing):
+    model, own = runs_model[:2]
+    if dtype is not None:
+        model = CausalLM(dataclasses.replace(RUNS_CFG, dtype=dtype))
+    eng = InferenceEngineV2(
+        model, params=own if params is None else params,
+        config=RaggedInferenceEngineConfig(**dict(SIZING, **sizing)))
+    if dtype is not None:
+        return eng
+    return share_forward(eng, _RUNS_FORWARDS,
+                         tuple(sorted(sizing.items())))
+
+
+def runs_served(eng, tokens, uid=7, chunk=16):
+    """Prefill in chunks then feed the given tokens: the logits at the
+    prompt's last position and at each later one."""
+    got = [feed(eng, uid, tokens[:RUNS_PROMPT], chunk)]
+    for t in tokens[RUNS_PROMPT:]:
+        got.append(np.asarray(eng.put([uid], [[t]])[0]))
+    return np.stack(got)
+
+
+def runs_worst(got, want):
+    return np.abs(got - want[RUNS_PROMPT - 1:]).max() \
+        / (want.max() - want.min())
+
+
+def test_runs_apply_agrees_with_the_reference_at_every_position(runs_model):
+    model, params, tokens, want = runs_model
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(jax.jit(model.apply)(
+            params, jnp.asarray(tokens)[None]))[0]
+    assert np.abs(got - want).max() < 2e-6 * (want.max() - want.min())
+
+
+@pytest.mark.parametrize("chunk", [16, 12])
+def test_runs_chunks_then_decode_agree_with_the_whole_forward(runs_model,
+                                                              chunk):
+    """Prefill in several chunks, then decode through the pools and the
+    slots, against the reference's forward of every layer on every
+    position: a prompt of 70 crosses the window of 16 + a block of 8, so
+    window blocks are handed back on the way. Float32 agrees to 2e-6 of
+    range; the same engine in bfloat16 is a thousand times off."""
+    _, _, tokens, want = runs_model
+    eng = runs_engine(runs_model)
+    assert runs_worst(runs_served(eng, tokens, chunk=chunk), want) < 5e-6
+    totals = eng.put_totals
+    assert totals["kv_blocks_released"] > 0
+    assert totals["ssm_chunk_tokens"] == RUNS_PROMPT
+    assert totals["ssm_rows_stepped"] == RUNS_STEPS
+    sm = eng.state_manager
+    # the pools hold what is WRITTEN: one whole-context layer, three
+    # window layers, K/V heads joined in pairs; the cross layers none
+    shapes = {k: v.shape for k, v in sm.forward_cache.items()}
+    assert shapes["k"][0] == 1 and shapes["k1"][0] == 3
+    assert shapes["k"][2:] == (2, 8, 16) == shapes["v1"][2:]
+    assert shapes["mamba1_ssm"] == (4, 5, 4, 64)
+    assert shapes["mamba1_conv"] == (4, 5, 3, 64)
+    eng.flush(7)
+    assert sm.allocator.free_blocks == sm.allocator.total_blocks
+    assert sm.free_state_slots == sm.state_slots
+
+
+def test_runs_in_bfloat16_fail_the_float32_limit(runs_model):
+    _, params, tokens, want = runs_model
+    low = runs_engine(runs_model, dtype=jnp.bfloat16, params=jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16), params))
+    got = runs_served(low, tokens).astype(np.float32)
+    assert runs_worst(got, want) > 1e-3
+
+
+def test_the_exit_gives_the_whole_forwards_value_on_one_row(runs_model):
+    """A chunk forward's logits at its last row equal the reference's of
+    all layers on all positions there — every chunk's, not the last
+    one's alone — and one position a row entered the layers behind the
+    exit, not the chunk's width."""
+    _, _, tokens, want = runs_model
+    assert RUNS_CFG.exit_at() == (1, 1)
+    eng = runs_engine(runs_model)
+    span = want.max() - want.min()
+    for at in range(0, 64, 16):
+        out = np.asarray(eng.put([3], [tokens[at:at + 16]])[0])
+        assert np.abs(out - want[at + 15]).max() < 5e-6 * span
+        assert eng.last_put["xdec_rows"] == 1
+        assert eng.last_put["valid_tokens"] == 16
+        # seven... here two cross layers' walks of the whole context
+        assert eng.last_put["shared_kv_read_tokens"] == 2 * (at + 16)
+        # the whole-context group's keys and pairs are that one query's,
+        # not a causal chunk's; the window group's stay every position's
+        assert eng.last_put["kv_g0_qk_pairs"] == at + 16 \
+            == eng.last_put["qk_pairs"] == eng.last_put["kv_g0_read_tokens"]
+        assert eng.last_put["kv_g1_qk_pairs"] == sum(
+            min(at + i + 1, 16) for i in range(16))
+    out = np.asarray(eng.put([3, 4], [[tokens[64]], tokens[:5]])[0])
+    assert np.abs(out - want[64]).max() < 5e-6 * span
+    assert eng.put_totals["xdec_rows"] == 4 + 2
+    eng.flush(3)
+    eng.flush(4)
+
+
+def test_differential_attention_on_joined_pairs_is_its_definition():
+    """The padded form the paged path runs — queries (q1 | 0) and
+    (0 | q2) against K/V heads joined in pairs, then the pairs' rows
+    combined — against two softmaxes over the heads themselves, with λ
+    away from λ_init."""
+    from deepspeed_tpu.models.mixers import attention
+    from deepspeed_tpu.models.transformer import attention_reference
+
+    T, nh, kvh, hd = 23, 8, 4, 8
+    k = jax.random.split(jax.random.PRNGKey(5), 8)
+    q = jax.random.normal(k[0], (1, T, nh, hd))
+    kk = jax.random.normal(k[1], (1, T, kvh, hd))
+    v = jax.random.normal(k[2], (1, T, kvh, hd))
+    lp = {name: 0.4 * jax.random.normal(k[3 + i], (hd,))
+          for i, name in enumerate(attention.DIFF_LAMBDAS)}
+    lp["subln_w"] = 1.0 + 0.1 * jax.random.normal(k[7], (2 * hd,))
+    lam = float(jnp.exp(jnp.sum(lp["lambda_q1"] * lp["lambda_k1"]))
+                - jnp.exp(jnp.sum(lp["lambda_q2"] * lp["lambda_k2"])))
+    assert abs(lam) > 0.05
+    for window, depth in ((0, 3), (6, 9)):
+        with jax.default_matmul_precision("highest"):
+            rows = attention_reference(
+                attention.diff_queries(q), attention.diff_keys(kk),
+                attention.diff_keys(v), causal=True, window=window,
+                scale=hd ** -0.5)
+            got = attention.diff_combine(RUNS_CFG, rows, lp, depth)
+            want = runs_block().diff_attention(
+                q[0], kk[0], v[0], lp, depth, RUNS_ARCH, window, 8)
+        np.testing.assert_allclose(np.asarray(got).reshape(T, -1),
+                                   np.asarray(want), rtol=2e-5, atol=2e-6)
+
+
+def test_the_memory_is_the_same_tokens_y_before_the_gate(runs_model):
+    """What the gated memory units read: the output of layer 6's
+    recurrence (the layer in front of the whole-context one), skip added,
+    gate not applied — the reference's, position by position."""
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.models.mixers import mamba1
+
+    model, params, tokens, _ = runs_model
+    b = runs_block()
+    ids = jnp.asarray(tokens, jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        x, _ = b.hidden(params, ids, RUNS_ARCH, 16, upto=6)
+        _, fed = b.hidden(params, ids, RUNS_ARCH, 16, upto=7)
+        lp = jax.tree.map(lambda a: a[0], params["layers"]["run1_slot0"])
+        h1 = hybrid.norm_of(RUNS_CFG)(x[None], lp, "attn_norm")
+        T = len(tokens)
+        out, _, _, memory = mamba1.mamba1_mixer(
+            RUNS_CFG, h1, lp, jnp.zeros((1, 3, 64)), jnp.zeros((1, 4, 64)),
+            jnp.full((1,), T))
+    top = float(jnp.abs(fed["memory"]).max())
+    assert float(jnp.abs(memory[0] - fed["memory"]).max()) < 2e-6 * top
+    # and not the gated value the layer's own output projection reads
+    z = (h1 @ lp["mamba1_w_in"])[0, :, 64:]
+    assert float(jnp.abs(memory[0] * jax.nn.silu(z)
+                         - fed["memory"]).max()) > 0.1 * top
+
+
+@pytest.mark.parametrize("reader", range(3))
+def test_a_lost_whole_context_block_reaches_every_reader(runs_model, reader):
+    """The whole-context group's one layer is read by the layer that
+    writes it and by every cross layer. With all but one reader's output
+    projection silenced, a block of the sequence's table pointed at its
+    neighbour's still moves the next logits: each of them reads the
+    pool."""
+    _, params, tokens, _ = runs_model
+    readers = [("run1_slot1", 0), ("run2_slot1", 0), ("run2_slot1", 1)]
+    layers = {k: dict(v) for k, v in params["layers"].items()}
+    for i, (slot, period) in enumerate(readers):
+        if i != reader:
+            for name in ("wo", "wo_b"):
+                layers[slot][name] = layers[slot][name].at[period].set(0.0)
+    eng = runs_engine(runs_model, params=dict(params, layers=layers))
+    got = []
+    for uid, lose in ((1, False), (2, True)):
+        feed(eng, uid, tokens[:48])
+        if lose:
+            seq = eng.state_manager.get_sequence(uid)
+            seq.rows[0, 2] = seq.rows[0, 3]
+        got.append(np.asarray(eng.put([uid], [[tokens[48]]])[0]))
+        eng.flush(uid)
+    assert np.abs(got[0] - got[1]).max() > 1e-4 * np.abs(got[0]).max()
+
+
+def test_runs_refuse_what_assumes_one_resident_per_token_cache(runs_model,
+                                                               devices8):
+    from deepspeed_tpu.models.hybrid import ReleasedKVUnsupported
+
+    model, params, tokens, _ = runs_model
+    with pytest.raises(RecurrentStateUnsupported, match="prefix cache"):
+        runs_engine(runs_model, enable_prefix_cache=True)
+    with pytest.raises((RecurrentStateUnsupported, ReleasedKVUnsupported)):
+        runs_engine(runs_model, kv_quant_enabled=True)
+    eng = runs_engine(runs_model)
+    feed(eng, 1, tokens[:40])
+    with pytest.raises(RecurrentStateUnsupported, match="KV tier"):
+        eng.configure_kv_tier(True)
+    with pytest.raises(RecurrentStateUnsupported, match="trim_sequence"):
+        eng.trim_sequence(1, 2)
+    with pytest.raises(RecurrentStateUnsupported, match="verif"):
+        eng.put([1], [[3, 4]], verify_width=2)
+    with pytest.raises(ReleasedKVUnsupported):
+        eng.export_sequence(1)
+    eng.flush(1)
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(devices8[:2]), ("tensor",))
+    with pytest.raises(RecurrentStateUnsupported, match="TP serving"):
+        InferenceEngineV2(model, params=params, mesh=mesh,
+                          config=RaggedInferenceEngineConfig(**SIZING))
+
+
+@pytest.mark.parametrize("runs,what", [
+    (((("gmu", "cross"), 2), (("mamba1", "window"), 4)), "no earlier run"),
+    (((("mamba1", "window"), 4), (("gmu", "full"), 2)), "no earlier run"),
+    (((("mamba1", "window"), 2), (("mamba1", "full"), 2),
+      (("gmu", "cross"), 2)), "one period"),
+    (((("mamba1", "window"), 2), (("mamba1", "full"), 1),
+      (("gmu", "cross"), 2)), "num_layers"),
+], ids=["nothing-in-front", "no-writer", "a-scanned-feeder", "depth"])
+def test_a_layer_that_nothing_feeds_is_refused(runs, what):
+    with pytest.raises(ValueError, match=what):
+        dataclasses.replace(RUNS_CFG, layer_runs=runs, layer_pattern=None)
+    # ... and outside layer_runs the kinds have nothing to read at all
+    with pytest.raises(ValueError, match="layer_runs"):
+        dataclasses.replace(RUNS_CFG, layer_runs=None, diff_attn=False,
+                            sliding_window=None,
+                            layer_pattern=("mamba1", "gmu"), num_layers=12)

@@ -43,12 +43,19 @@ FIELDS = {
                          block_window=16, block_dense_len=32),
     "mamba2": dict(mamba_num_heads=4, mamba_head_dim=8, mamba_state_size=16,
                    mamba_n_groups=2, mamba_chunk_size=16),
+    "mamba1": dict(mamba1_inner_size=64, mamba1_state_size=4,
+                   mamba1_dt_rank=2),
 }
+#: the kinds that read what an earlier run of layers hands on: no model
+#: is made of one of them alone (``test_the_fed_kinds_...`` below)
+FED = ("gmu", "cross")
 BS, SLOTS = 8, 3
 
 
 def test_the_cases_below_cover_the_registry():
-    assert list(FIELDS) == list(KINDS)
+    assert list(FIELDS) + list(FED) == list(KINDS)
+    assert [kind for kind, mixer in KINDS.items() if mixer.takes] \
+        == list(FED)
 
 
 @pytest.mark.parametrize("kind", list(FIELDS))
@@ -83,6 +90,43 @@ def test_what_a_kind_states_is_what_the_facades_return(kind):
     assert (mixer.scope is not None) == bool(pool)
 
 
+def test_the_fed_kinds_keep_no_cache_and_say_what_they_read():
+    """A model of runs: a state-space and a whole-context layer in a run
+    of one period, the kinds that read them behind. Their leaves are what
+    ``specs`` names; neither has a pool or a state of its own, and the
+    pools count what is written."""
+    runs = ((("mamba1", "full"), 1), (("gmu", "cross"), 2))
+    cfg = TransformerConfig(**dict(
+        BASE, num_layers=6, qk_norm=False, attn_output_gate=False,
+        attn_gate_proj=False, rope_kinds=(), layer_runs=runs,
+        **FIELDS["mamba1"]))
+    assert cfg.layer_pattern == ("mamba1", "full", "gmu", "cross")
+    assert cfg.runs == runs and cfg.run_feeds() == (("kv", "memory"), ())
+    assert cfg.run_feeds(cached=True) == (("memory",), ())
+    model = CausalLM(cfg)
+    layers = jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"]
+    specs = model.param_specs()["layers"]
+    assert set(layers) == set(specs) == {
+        "run0_slot0", "run0_slot1", "run1_slot0", "run1_slot1"}
+    for kind, slot in (("gmu", "run1_slot0"), ("cross", "run1_slot1")):
+        mixer = KINDS[kind]
+        assert set(layers[slot]) == set(specs[slot])
+        assert set(layers[slot]) - set(mixer.specs(cfg)) == {
+            "attn_norm_w", "mlp_norm_w", "w_in", "w_gate", "w_out"}
+        assert all(leaf.shape[0] == 2 for leaf in layers[slot].values())
+        assert mixer.pool is None and mixer.state is None
+        assert mixer.takes and mixer.scope
+    assert KINDS["cross"].shares == "full" and KINDS["cross"].paged_walk
+    assert set(KINDS["cross"].takes) <= set(KINDS["full"].hands)
+    assert set(KINDS["gmu"].takes) <= set(KINDS["mamba1"].hands)
+    # one whole-context layer is written, whoever reads it
+    assert cfg.kv_groups() == ((0, 1),) and cfg.num_attn_layers == 1
+    assert cfg.layers_of("cross") == 2 and cfg.num_linear_layers == 1
+    assert cfg.exit_at() == (0, 1)
+    with pytest.raises(AttributeError, match="runs"):
+        cfg.num_periods
+
+
 def test_the_fleets_counters_are_the_engines_own_and_the_registrys():
     from deepspeed_tpu.serving.metrics import serving_metrics
     from deepspeed_tpu.serving.replica import Replica
@@ -103,7 +147,7 @@ def test_the_fleets_counters_are_the_engines_own_and_the_registrys():
 
 
 UNMISTAKABLE = ("latent_sparse", "latent_window", "block_sparse",
-                "lightning", "linear_attn", "mamba2")
+                "lightning", "linear_attn", "mamba2", "mamba1", "\"gmu\"")
 
 
 def _docstrings(tree):

@@ -403,6 +403,12 @@ class TestQuarantine:
                     time.sleep(0.05)
                 assert h.state == ReplicaState.HEALTHY, \
                     "probe never re-admitted a healthy peer"
+                # (the handle flips its state under its lock and writes
+                # the journal after it: the probe's thread may be between)
+                deadline = time.monotonic() + 5
+                while not fe.journal.count("replica_readmitted") \
+                        and time.monotonic() < deadline:
+                    time.sleep(0.01)
                 assert fe.journal.count("replica_readmitted") == 1
                 ev = fe.journal.events(kinds=("replica_readmitted",))[0]
                 assert ev["detail"]["quarantined_s"] >= 0.0

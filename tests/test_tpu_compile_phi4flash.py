@@ -1,0 +1,67 @@
+"""The cell ``phi-4-mini-flash-reasoning.deepthink``'s forwards compiled
+for a *described* TPU v5e at the sizes its configuration's file states —
+all 32 layers at the published widths, the whole 200,064-row tied
+embedding, both layer groups' pools at the file's sizes, 33 state slots,
+the table of 36,864 positions — for the decode step ``[32, 1]`` and the
+widest chunk ``[1, 2048]``: what the chip's compiler refuses, and what
+does not fit beside the weights, shows here and not on the chip. Nothing
+runs. Differential attention on the paged kernel that is there, at 128
+wide over 10 joined K/V pairs (4 query rows a pair); the cross layers'
+calls read the whole-context group's one layer; in the chunk forward
+every call behind the exit is one position a row. See
+tests/test_tpu_compile.py for the method and tests/tpu_compile_harness.py
+for what is shared."""
+
+import re
+
+import pytest
+from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
+                                 configuration, fits_beside, kernels, lowered,
+                                 v5e)
+
+from deepspeed_tpu.ops import paged_attention as pa
+
+NAME = "phi-4-mini-flash-reasoning"
+BUCKETS = [(32, 1), (1, 2048)]
+
+
+@pytest.mark.parametrize("bucket", BUCKETS, ids=bucket_id)
+def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
+    low, params, cache, cfg = lowered(NAME, v5e[0], bucket, monkeypatch)
+    # pools by what is written: one whole-context layer, eight window
+    # layers, K/V heads joined in pairs; nine S6 layers' state
+    assert cfg.kv_groups() == ((0, 1), (512, 8))
+    assert cache["k"].shape == cache["v"].shape == (1, 5120, 10, 64, 128)
+    assert cache["k1"].shape == cache["v1"].shape == (8, 384, 10, 64, 128)
+    assert cache["mamba1_ssm"].shape == (9, 33, 16, 5120)
+    assert cache["mamba1_conv"].shape == (9, 33, 3, 5120)
+    compiled = low.compile()
+    text = compiled.as_text()
+    found = kernels(text)
+    # one call site in each scan's body and in each inline layer, whatever
+    # the depth: the window layers' (a scan of eight; a chunk over
+    # MAX_QUERY_ROWS // 4 tokens cut in pieces), layer 17's and the cross
+    # layers' (a scan of seven) -- both one position a row in a chunk
+    # forward too, behind the exit; no other kernel
+    pieces = bucket[1] // pa._chunk_tile(bucket[1], 4)
+    assert found.count("paged_attention") == pieces + 2, found
+    assert set(found) == {"paged_attention"}
+    scoped = re.findall(r'%paged_attention[.\d]* = [^\n]*op_name="([^"]*)"',
+                        text)
+    assert all("/attend/" in s for s in scoped)
+    kinds = [s.split("/attend/")[0].rsplit("/", 1)[-1] for s in scoped]
+    assert sorted(kinds) == ["cross_attn", "full_attn"] \
+        + ["window_attn"] * pieces
+    assert ["/xdec/" in s for s in scoped] \
+        == [kind == "cross_attn" for kind in kinds]
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("mamba/mamba_scan", "mamba/mamba_state_io", "xdec/",
+                  "gmu/", "mlp/dense_mlp", "logits"):
+        assert any(scope in n for n in names), scope
+    # weights + pools + state + this forward's temporaries fit the chip
+    # with room for the check's float32 reference when nothing runs
+    # (3.05 GiB of logits at 4,096 positions and about 1 GiB they are
+    # made from)
+    fits_beside(compiled, params, cache, bucket, headroom=4 * 2 ** 30)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (64 if bucket[1] == 1 else 768) * 2 ** 20, temp / 2 ** 20
