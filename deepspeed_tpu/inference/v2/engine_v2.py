@@ -18,6 +18,7 @@ import numpy as np
 
 from ...models.mixers import KINDS, kinds_of
 from ...models.mixers.base import keys_and_pairs as _keys_and_pairs
+from ...models.mixers.base import narrow
 from ...models.transformer import CausalLM
 from ...ops import gated_delta
 from ...ops import paged_attention as pa
@@ -340,6 +341,10 @@ class InferenceEngineV2:
                 self.put_totals.update(moe_rows_routed=0, moe_rows_held=0)
             for mixer in mixers:
                 self.put_totals.update(dict.fromkeys(mixer.totals, 0))
+            if any(mixer.holds for mixer in mixers):
+                # forwards whose bucket was narrow enough for its kinds to
+                # hold their projections to rows (``mixers.base.held``)
+                self.put_totals["forwards_held"] = 0
             if cfg.layer_runs is not None:
                 # the positions that ran the layers behind the model's
                 # last layer that writes a cache: a row's last alone where
@@ -728,6 +733,9 @@ class InferenceEngineV2:
             totals["forwards_qkv_fused"] += 1
         if merged:
             totals["forwards_merged"] += 1
+        if "forwards_held" in totals and narrow(
+                self.model.cfg, bucket_seqs * bucket_chunk):
+            totals["forwards_held"] += 1
         totals["positions_computed"] += bucket_seqs * bucket_chunk
         totals["tokens_valid"] += valid
         kv_cache = sm.forward_cache

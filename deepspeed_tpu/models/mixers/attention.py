@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from ...parallel.sharding import spec
 from ..transformer import _attention, _linear
-from .base import Mixer, block_norm, rms
+from .base import Mixer, block_norm, held, rms
 
 scope = jax.named_scope
 
@@ -267,10 +267,10 @@ def _describe(kind: str, name: str, windowed: bool) -> Mixer:
 
         def mixer(h1, lp, i):
             layer = fwd.layer(kind, i)
-            # no hold: the queries go to a kernel, whose operands are
-            # laid out as they are given
+            # held to rows in the narrow buckets (``base.held``): left
+            # free, a one-token step copies each weight transposed first
             with scope("qkv"):
-                q, k, v, gate = full_qkv(cfg, h1, lp, turn, q_of=q_of)
+                q, k, v, gate = full_qkv(cfg, h1, lp, turn, held(cfg), q_of)
             with scope("kv_write"):
                 write_kv(cfg, fwd, kind, k, v, layer)
             with scope("attend"):
@@ -288,7 +288,7 @@ def _describe(kind: str, name: str, windowed: bool) -> Mixer:
     return Mixer(init=init, specs=specs, reference=reference, paged=paged,
                  scope=name, check=check, pool=pool, windowed=windowed,
                  paged_walk=True, hands=() if windowed else ("kv",),
-                 exits=True)
+                 exits=True, holds=True)
 
 
 FULL = _describe("full", "full_attn", False)

@@ -62,7 +62,10 @@ class Mixer:
       and its table are that kind's, and what it ``takes`` there comes
       out of the pool. ``exits``: its serving layer can write its cache
       from every position and attend from a row's last alone
-      (``Fwd.exit``)."""
+      (``Fwd.exit``).
+    - ``holds``: its serving layer holds its projections' outputs to rows
+      in the narrow buckets (``held``); an engine of a model with such a
+      kind counts those forwards (``put_totals["forwards_held"]``)."""
     init: Callable
     specs: Callable
     reference: Callable
@@ -82,6 +85,7 @@ class Mixer:
     takes: Tuple[str, ...] = ()
     shares: Optional[str] = None
     exits: bool = False
+    holds: bool = False
 
 
 class Fwd(NamedTuple):
@@ -186,21 +190,37 @@ def rows_major(y):
         y, Layout(major_to_minor=tuple(range(y.ndim))))
 
 
-def held(cfg, rows: int, always: str = ""):
+def narrow(cfg, rows: int) -> bool:
+    """``held``'s rule for a serving forward of ``rows`` bucket positions:
+    its rows are at most a quarter of a projection's weight's, so every
+    projection is held to rows. It reads the bucket alone, so the engine
+    counts the forwards it holds on the host (``forwards_held``)."""
+    return 4 * rows <= cfg.hidden_size
+
+
+def held(cfg, always: str = ""):
     """``full_qkv``'s and ``lightning_mixer``'s ``hold`` in a serving
-    forward of ``rows`` bucket positions: which of a layer's projections
-    (``"q"``, ``"k"``, ``"v"``, the gate's ``"g"``) are held to rows
-    (``rows_major``) — those in ``always`` at every width, all four
-    while the rows are at most a quarter of the weight's. Whichever side
+    forward: which of a layer's projections (``"q"``, ``"k"``, ``"v"``,
+    the gate's ``"g"``) are held to rows (``rows_major``) — those in
+    ``always`` at every width, each of the four while the rows it is
+    taken at are narrow (``narrow``; the output's own [N, C]: an exit's
+    queries are taken at a row's last position alone). Whichever side
     is laid out anew is copied, and what that costs was measured on the
     chip at 4,096 wide (PERF.md section 6, PR 46): a lightning layer's
     q, k and v at every width (0.9 ms off a 2,048-row chunk's 101, 0.4
     off a two-row step's 9.8); its gate and a block-sparse layer's four
     while the rows are few (another 0.3 ms off the step, 0.1 off a
     512-row chunk; 1 ms *onto* the 2,048-row chunk each: their rows are
-    relaid in float32, behind a norm or a sigmoid, more than once)."""
-    names = "qkvg" if 4 * rows <= cfg.hidden_size else always
-    return lambda name, y: rows_major(y) if name in names else y
+    relaid in float32, behind a norm or a sigmoid, more than once); and
+    at five configurations' widths (PR 61): a softmax slot's four off
+    every narrow bucket, 0.36 ms of a four-row step's 3.08 at 3,072 wide
+    (441 MB of weights no longer copied) down to 0.03 of a 512-row
+    chunk's 14.5 at 2,048 wide — no narrow bucket lost."""
+    def hold(name, y):
+        if name in always or narrow(cfg, y.shape[0] * y.shape[1]):
+            return rows_major(y)
+        return y
+    return hold
 
 
 def keys_and_pairs(window: int, seen: int, n: int) -> Tuple[int, int]:

@@ -345,7 +345,7 @@ def paged(cfg, fwd):
     pools, table = fwd.pools, fwd.tables[fwd.group_of[KIND]]
     start_pos, n_tokens = fwd.start_pos, fwd.n_tokens
     turn = fwd.rope(cfg, KIND)
-    hold = held(cfg, N * C)
+    hold = held(cfg)
 
     def attend(q, layer):
         """The layer behind its ``kv_write``: the kernels this forward
@@ -437,4 +437,4 @@ BLOCK_SPARSE = Mixer(
     paged=paged, scope="sparse_attn", check=check, pool=pool, totals=TOTALS,
     record=("sparse_rows_", "sparse_blocks_", "sparse_ones",
             "sparse_q_chunk", "sparse_pairs_chunk", "sparse_keys_chunk"),
-    count=count)
+    count=count, holds=True)

@@ -22,7 +22,7 @@ import jax
 from ...parallel.sharding import spec
 from ..transformer import _attention, _linear
 from . import attention
-from .base import Mixer
+from .base import Mixer, held
 
 KIND = "cross"
 #: the kind whose K/V the layer reads
@@ -52,11 +52,11 @@ def specs(cfg):
                 **attention.spec_extras(cfg, ("wq", "wo")))
 
 
-def queries(cfg, h1, lp):
+def queries(cfg, h1, lp, hold=lambda name, y: y):
     """The layer's queries [B, T, heads, D] (under ``diff_attn`` as the
-    attention over joined pairs takes them)."""
+    attention takes joined pairs). ``hold``: ``attention.full_qkv``'s."""
     B, T, _ = h1.shape
-    q = _linear(h1, lp["wq"], lp.get("wq_b"), cfg.dtype).reshape(
+    q = hold("q", _linear(h1, lp["wq"], lp.get("wq_b"), cfg.dtype)).reshape(
         B, T, cfg.num_heads, cfg.head_dim)
     return attention.diff_queries(q) if cfg.diff_attn else q
 
@@ -83,7 +83,7 @@ def paged(cfg, fwd):
 
     def mixer(h1, lp, i):
         with scope("qkv"):
-            q = queries(cfg, h1, lp)
+            q = queries(cfg, h1, lp, held(cfg))
         with scope("attend"):
             attn = fwd.attend(q, {"k": pools[k_name], "v": pools[v_name]},
                               layer, table, fwd.start_pos, fwd.n_tokens,
@@ -106,4 +106,4 @@ CROSS = Mixer(init=init, specs=specs, reference=reference, paged=paged,
               scope="cross_attn", check=check, paged_walk=True,
               totals=("shared_kv_read_tokens",),
               record=("shared_kv_read_tokens",), count=count,
-              takes=("kv",), shares=SHARES)
+              takes=("kv",), shares=SHARES, holds=True)

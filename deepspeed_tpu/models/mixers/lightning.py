@@ -117,7 +117,6 @@ def reference(cfg, fwd):
 
 def paged(cfg, fwd):
     pools, slots, fresh = fwd.pools, fwd.state_slots, fwd.fresh
-    N, C = fwd.shape
     turn = fwd.rope(cfg, KIND)
 
     def mixer(h1, lp, i):
@@ -127,7 +126,7 @@ def paged(cfg, fwd):
             state = jnp.where(fresh[:, None, None, None], 0, state)
             y, state = lightning_mixer(
                 cfg, h1, lp, turn, state, fwd.n_tokens,
-                hold=held(cfg, N * C, always="qkv"))
+                hold=held(cfg, always="qkv"))
             pools[KIND] = pools[KIND].at[layer, slots].set(state)
             return y
     return mixer
@@ -140,4 +139,4 @@ def count(cfg, staged, bucket_chunk: int, block_size: int):
 
 LIGHTNING = Mixer(init=init, specs=specs, reference=reference, paged=paged,
                   check=check, state=state, totals=("lightning_rows",),
-                  record=("lightning_rows",), count=count)
+                  record=("lightning_rows",), count=count, holds=True)

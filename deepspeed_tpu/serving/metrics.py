@@ -404,6 +404,11 @@ def serving_metrics(classes: Sequence[str] = STOCK_CLASSES,
               # (engine._forward_groups): the share of wide forwards that
               # spared the decoding rows a forward of their own
               "forwards_merged",
+              # of ``forwards``, those whose bucket was narrow enough for
+              # a hybrid model's attention slots to hold their
+              # projections' outputs to rows (mixers/base.held): the
+              # share of forwards that copied no projection weight
+              "forwards_held",
               # scheduler steps dispatched and, of them, those dispatched
               # while the step before was still unread, so that the
               # host's turn ran behind the device's (scheduler.step_stats,

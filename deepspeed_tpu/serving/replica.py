@@ -594,6 +594,7 @@ class Replica:
     # kinds add (``models.mixers.PUT_TOTALS``)
     _PUT_COUNTERS = ("forwards", "positions_computed", "tokens_valid",
                      "puts_split", "forwards_qkv_fused", "forwards_merged",
+                     "forwards_held",
                      "moe_rows_routed", "moe_rows_held",
                      "kv_blocks_released") + PUT_TOTALS
     _PREEMPT_COUNTERS = (("preempted", "sequences_preempted"),

@@ -132,8 +132,8 @@ def test_the_fleets_counters_are_the_engines_own_and_the_registrys():
     from deepspeed_tpu.serving.replica import Replica
 
     own = ("forwards", "positions_computed", "tokens_valid", "puts_split",
-           "forwards_qkv_fused", "forwards_merged", "moe_rows_routed",
-           "moe_rows_held", "kv_blocks_released")
+           "forwards_qkv_fused", "forwards_merged", "forwards_held",
+           "moe_rows_routed", "moe_rows_held", "kv_blocks_released")
     assert Replica._PUT_COUNTERS == own + PUT_TOTALS
     assert len(set(Replica._PUT_COUNTERS)) == len(Replica._PUT_COUNTERS)
     # every name a kind counts under is one of its totals or only in

@@ -94,8 +94,10 @@ def test_what_is_held_by_kind_and_rows(twin, monkeypatch):
     monkeypatch.setattr(base, "rows_major", lambda y: "held")
 
     def held(always, rows):
-        hold = base.held(cfg, rows, always)
-        return "".join(n for n in "qkvg" if hold(n, None) == "held")
+        # a projection's output is asked its own rows: [1, rows, out]
+        out = jax.ShapeDtypeStruct((1, rows, 8), jnp.float32)
+        hold = base.held(cfg, always)
+        return "".join(n for n in "qkvg" if hold(n, out) == "held")
 
     # a lightning layer's q, k and v always; a block-sparse layer's none
     assert [held("qkv", rows) for rows in (1, 16, 17, 64)] \

@@ -15,7 +15,7 @@ import re
 import pytest
 from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
                                  configuration, kernels, lowered, nbytes,
-                                 stacked_group_sizes, v5e)
+                                 stacked_group_sizes, staged_projections, v5e)
 
 from deepspeed_tpu.ops import paged_attention as pa
 
@@ -42,7 +42,7 @@ def test_the_pieces_each_bucket_is_cut_in(bucket, pieces):
 def test_hybrid_forward_at_published_widths(v5e, bucket, monkeypatch):
     # the configuration's one period at its widths; 1,024 blocks, a
     # budget of 1,056 tokens and five slots
-    low, _, cache, cfg = lowered(
+    low, params, cache, cfg = lowered(
         NAME, v5e[0], bucket, monkeypatch, kv_blocks=1024,
         max_ragged_batch_size=1056, max_ragged_sequence_count=8)
     assert cfg.num_layers == 4
@@ -64,6 +64,14 @@ def test_hybrid_forward_at_published_widths(v5e, bucket, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(nbytes(s) for s in cache.values())
     # no copy of the state tree (a slot a sequence and one x 3 layers x
-    # 2 MiB) in the temporaries of a decode step
+    # 2 MiB) in the temporaries of a decode step; its attention layer's
+    # q (with its gate), k and v are held to rows and none of their
+    # weights is copied in front of its dot (``mixers.base.held``; left
+    # free: ``bf16[8192,2048]`` and two ``bf16[512,2048]``). A chunk wider
+    # than a quarter of the hidden size holds nothing
+    held = low.as_text().count("@LayoutConstraint")
     if bucket[1] == 1:
         assert mem.temp_size_in_bytes < nbytes(cache["ssm"])
+        assert held == 3 and staged_projections(text, params) == []
+    else:
+        assert held == 0

@@ -15,7 +15,8 @@ import re
 import jax.numpy as jnp
 import pytest
 from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
-                                 fits_beside, kernels, lowered, nbytes, v5e)
+                                 fits_beside, kernels, lowered, nbytes,
+                                 staged_projections, v5e)
 
 from deepspeed_tpu.models.mixers import mamba2
 from deepspeed_tpu.ops import mamba2_ssd as ssd
@@ -43,6 +44,11 @@ def test_a_decode_step_at_the_files_sizes(v5e, bucket, monkeypatch):
     assert len(scoped) == layers
     assert all("/mamba/mamba_scan/" in s for s in scoped), scoped
     fits_beside(compiled, params, cache, bucket, headroom=2 ** 30)
+    # the attention layer's q, k and v held to rows: none of their weights
+    # copied, transposed, in front of its dot (``mixers.base.held``; left
+    # free: ``bf16[4096,4096]`` and two ``bf16[256,4096]``)
+    assert low.as_text().count("@LayoutConstraint") == 3
+    assert staged_projections(text, params) == []
     # no copy of the bucket's rows' state ([N, 128, 64, 128] float32, one
     # layer's) among the temporaries: the parent's gather held one, and
     # the fresh rows' zeros and the scatter's operand beside it

@@ -17,7 +17,7 @@ import re
 import pytest
 from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
                                  configuration, fits_beside, kernels, lowered,
-                                 v5e)
+                                 staged_projections, v5e)
 
 from deepspeed_tpu.ops import paged_attention as pa
 
@@ -63,5 +63,21 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
     # (3.05 GiB of logits at 4,096 positions and about 1 GiB they are
     # made from)
     fits_beside(compiled, params, cache, bucket, headroom=4 * 2 ** 30)
+    # held to rows (``mixers.base.held``): a step's q, k and v of the
+    # window run's layer and of layer 17 and the cross run's q, and no
+    # projection's weight is copied, transposed, in front of its dot (left
+    # free: three ``bf16[1,2560,2560]``, four ``bf16[1,2560,1280]``). In
+    # a chunk only what lies behind the exit is narrow, one position a
+    # row -- layer 17's q and the cross run's; the 2,048 rows in front
+    # stage the window run's q, k and v (a slice and a copy each) and
+    # layer 17's k and v as they did
+    staged = staged_projections(text, params)
+    if bucket[1] == 1:
+        assert low.as_text().count("@LayoutConstraint") == 7
+        assert staged == []
+    else:
+        assert low.as_text().count("@LayoutConstraint") == 2
+        assert sorted(dims for _, dims, _ in staged) == \
+            ["1,2560,1280"] * 6 + ["1,2560,2560"] * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (64 if bucket[1] == 1 else 768) * 2 ** 20, temp / 2 ** 20

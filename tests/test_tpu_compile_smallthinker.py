@@ -22,7 +22,7 @@ import re
 import pytest
 from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
                                  configuration, fits_beside, kernels, lowered,
-                                 stacked_group_sizes, v5e)
+                                 stacked_group_sizes, staged_projections, v5e)
 
 from deepspeed_tpu.moe.grouped import gmm_tiles
 from deepspeed_tpu.ops import paged_attention as pa
@@ -95,6 +95,14 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
     # room for the check's float32 reference when nothing runs (2.46 GiB
     # of logits at 4,352 positions and what they are made from)
     fits_beside(compiled, params, cache, bucket, headroom=4 * 2 ** 30)
-    # a decode step's temporaries hold no expert leaf (240 MiB)
+    # a decode step's temporaries hold no expert leaf (240 MiB), and it
+    # holds a period's four slots' q, k and v to rows: no projection's
+    # weight is sliced out of its stack and copied, transposed, in front
+    # of its dot (``mixers.base.held``; left free: 95.6 MB a period). The
+    # widest chunk holds nothing and is the program it was
+    held = low.as_text().count("@LayoutConstraint")
     if C == 1:
         assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+        assert held == 12 and staged_projections(text, params) == []
+    else:
+        assert held == 0

@@ -19,8 +19,9 @@ import re
 import pytest
 from tpu_compile_harness import (_no_persistent_cache, bucket_id,  # noqa: F401
                                  configuration, fits_beside, kernels, lowered,
-                                 stacked_group_sizes, v5e)
+                                 stacked_group_sizes, staged_projections, v5e)
 
+from deepspeed_tpu.models.mixers import attention, base
 from deepspeed_tpu.ops import paged_attention as pa
 
 NAME = "trinity-large-preview"
@@ -76,6 +77,37 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
                                                or "full_attn" in s)
                           for s in scoped)
     assert sum("full_attn" in s for s in scoped) == C // tile
+    # a bucket whose rows are few against the weights holds its five
+    # attention layers' q, gate, k and v to rows (the lead layer's and the
+    # scanned period's four slots' in the program's text) and stages none
+    # of their weights in front of its dot; a wider one is the program it
+    # was, with nothing held
+    narrow = base.narrow(cfg, bucket[0] * bucket[1])
+    assert narrow == (bucket[0] * C <= 768)
+    assert low.as_text().count("@LayoutConstraint") == (20 if narrow else 0)
+    if narrow:
+        assert staged_projections(text, params) == []
     # weights + pools + this forward's temporaries fit the chip, with
     # room for the check's float32 reference (~2.5 GiB) when nothing runs
     fits_beside(compiled, params, cache, bucket, headroom=2 * 2 ** 30)
+
+
+def test_left_free_a_step_copies_its_projections_weights(v5e, monkeypatch):
+    """What the hold is for, and that ``staged_projections`` sees it: the
+    cell's decode bucket ``[4, 1]`` with nothing held (the parent of
+    PR 61: ``held`` made the identity) copies ``wq`` and ``wg`` of each of
+    the five attention layers (37.7 MB each) and their ``wk`` and ``wv``
+    (6.3 MB each), transposed, into fast memory in front of their dots --
+    440 MB a forward of 4 ms. If this fails because nothing is staged, the
+    compiler has learnt to leave them where they lie and the hold has
+    lost its reason."""
+    monkeypatch.setattr(attention, "held",
+                        lambda cfg, always="": lambda name, y: y)
+    low, params, *_ = lowered(NAME, v5e[0], (4, 1), monkeypatch)
+    assert "@LayoutConstraint" not in low.as_text()
+    staged = staged_projections(low.compile().as_text(), params)
+    assert all(name.startswith("copy") for name, _, _ in staged)
+    assert sorted(dims.removeprefix("1,") for _, dims, _ in staged) == \
+        ["1024,3072"] * 10 + ["3072,6144"] * 5 + ["6144,3072"] * 5
+    assert sum(size for _, _, size in staged) == 5 * 2 * (
+        2 * 3072 * 6144 + 2 * 3072 * 1024)

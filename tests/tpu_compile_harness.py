@@ -80,6 +80,29 @@ def stacked_group_sizes(text):
         r'op_name="([^"]*/mlp/experts/dynamic_update_slice)"', text)
 
 
+def staged_projections(text, params):
+    """The weights of an attention slot's projections (``wq``, its gate's
+    ``wg``, ``wk``, ``wv`` of any slot of ``params``) that an optimized
+    HLO module stages in front of their dots: every ``copy`` and every
+    stand-alone slicing fusion whose result is as large as one of them
+    out of its stack, either way round -- ``(instruction, dims, bytes)``
+    each. Left free, a consumer that cuts a projection's output into
+    heads has the compiler lay the *weight* out to fit, transposed, into
+    fast memory (``mixers.base.held``); an asynchronous slice is a
+    prefetch and is not among them."""
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if jax.tree_util.keystr(path).endswith(
+                ("['wq']", "['wg']", "['wk']", "['wv']")):
+            rows, cols = leaf.shape[-2:]
+            shapes |= {f"{rows},{cols}", f"{cols},{rows}"}
+    found = re.findall(
+        r"%((?:copy|[\w\-]*slice[\w\-]*fusion)[.\d]*) = "
+        r"bf16\[((?:1,)?(?:" + "|".join(sorted(shapes)) + r"))\]", text)
+    return [(name, dims, 2 * math.prod(map(int, dims.split(","))))
+            for name, dims in found]
+
+
 def spec_on(device):
     one = SingleDeviceSharding(device)
     return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
