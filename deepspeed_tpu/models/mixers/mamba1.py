@@ -3,7 +3,12 @@
 SiLU; ``[δ | B | C] = x W_x`` (``dt_rank | S | S``); ``dt = softplus(δ W_dt
 + b_dt)`` a channel; the recurrence over a float32 state under ``exp(dt ⊗
 A)``, ``A = −exp(A_log)`` a channel **and** a state index; the skip ``D
-x``; the gate ``y · silu(z)`` and the output projection. No norm inside.
+x``; the gate ``y · silu(z)`` and the output projection. No norm inside
+— unless ``cfg.mamba1_inner_norm`` (Jamba's form of the layer): then ``δ``,
+``B`` and ``C`` each go through an RMSNorm with a gain of its own, over
+``dt_rank``, ``S`` and ``S``, between ``W_x`` and ``W_dt``
+(``mamba1_dt_norm``, ``mamba1_b_norm``, ``mamba1_c_norm``; in float32,
+the result in the served type, as the block's norm).
 
 Its cache is the state and the conv's last inputs, not per-token K/V:
 ``mamba1_ssm`` [L_m, slots + 1, S, CH] float32 (the state index in front
@@ -23,9 +28,10 @@ the served type).
 
 Scopes (docs/OBSERVABILITY.md), the names a Mamba-2 layer uses: ``mamba``
 ⊃ ``mamba_proj`` (all three projections), ``mamba_conv``, ``mamba_scan``
-(the recurrence alone), ``mamba_out`` and, in serving,
-``mamba_state_io``: the gather of the rows' state and conv tail out of
-the slots and the scatter back."""
+(the recurrence alone), ``mamba_out``, ``mamba_norm`` (the three inner
+norms, where the model has them) and, in serving, ``mamba_state_io``: the
+gather of the rows' state and conv tail out of the slots and the scatter
+back."""
 
 from __future__ import annotations
 
@@ -38,7 +44,7 @@ from ...ops import gated_delta as gd
 from ...ops import selective_scan as s6
 from ...parallel.sharding import spec
 from ..transformer import _linear
-from .base import Mixer
+from .base import Mixer, rms
 
 KIND = "mamba1"
 scope = jax.named_scope
@@ -52,6 +58,17 @@ def dims(cfg):
     """(inner channels, state size, dt rank, conv taps)."""
     return (cfg.mamba1_inner_size, cfg.mamba1_state_size,
             cfg.mamba1_dt_rank, cfg.mamba1_conv_kernel)
+
+
+def inner_norms(cfg):
+    """The inner norms' gains (``cfg.mamba1_inner_norm``; none without
+    it) and what each norms: name -> width, in the order ``W_x``'s output
+    is cut (δ | B | C)."""
+    if not cfg.mamba1_inner_norm:
+        return {}
+    _, ns, rank, _ = dims(cfg)
+    return {"mamba1_dt_norm": rank, "mamba1_b_norm": ns,
+            "mamba1_c_norm": ns}
 
 
 def check(cfg):
@@ -90,7 +107,8 @@ def init(cfg, w, gain):
             jnp.log(jnp.arange(1, ns + 1, dtype=jnp.float32))[:, None],
             (P, ns, ch)),
         mamba1_D=jnp.ones((P, ch), jnp.float32),
-        mamba1_w_out=w((ch, h), w.out_std))
+        mamba1_w_out=w((ch, h), w.out_std),
+        **{name: gain(width) for name, width in inner_norms(cfg).items()})
 
 
 def specs(cfg):
@@ -102,7 +120,8 @@ def specs(cfg):
                 mamba1_dt_b=spec("layers", None),
                 mamba1_A_log=spec("layers", None, None),
                 mamba1_D=spec("layers", None),
-                mamba1_w_out=spec("layers", None, "embed"))
+                mamba1_w_out=spec("layers", None, "embed"),
+                **{name: spec("layers", None) for name in inner_norms(cfg)})
 
 
 def state(cfg, slots: int):
@@ -137,10 +156,19 @@ def mamba1_mixer(cfg, h1, lp, tail, state, n_tokens):
     with scope("mamba_proj"):
         dbc = _linear(x, lp["mamba1_w_x"], None, dt_)
         Bm, Cm = dbc[..., rank:rank + ns], dbc[..., rank + ns:]
+    delta = None
+    if cfg.mamba1_inner_norm:
+        with scope("mamba_norm"):
+            delta, Bm, Cm = (
+                rms(a, lp[name], cfg.norm_eps, False)
+                for a, name in zip((dbc[..., :rank], Bm, Cm),
+                                   inner_norms(cfg)))
+    with scope("mamba_proj"):
         keep = (jnp.arange(T)[None, :] < n_tokens[:, None])[..., None]
         # a masked position's step is 0: decay 1, nothing added
         dt = jnp.where(keep, jax.nn.softplus(
-            _linear(dbc[..., :rank], lp["mamba1_w_dt"], None, dt_
+            _linear(dbc[..., :rank] if delta is None else delta,
+                    lp["mamba1_w_dt"], None, dt_
                     ).astype(f32) + lp["mamba1_dt_b"].astype(f32)), 0.0)
         A = -jnp.exp(lp["mamba1_A_log"].astype(f32))
     with scope("mamba_scan"):

@@ -240,10 +240,13 @@ class TransformerConfig:
     # ``mamba1_dt_rank`` projection, a depthwise causal conv of
     # ``mamba1_conv_kernel`` taps (with bias) in front and a gate behind. A
     # "gmu" layer gates the memory such a layer hands on, as wide.
+    # ``mamba1_inner_norm`` (Jamba): an RMSNorm with a gain over each of
+    # the step's projection, B and C, between ``W_x`` and ``W_dt``.
     mamba1_inner_size: int = 0
     mamba1_state_size: int = 0
     mamba1_dt_rank: int = 0
     mamba1_conv_kernel: int = 4
+    mamba1_inner_norm: bool = False
     # A "block_sparse" layer (InfLLM-V2): a "full" layer whose query, from
     # position ``block_dense_len`` on, attends whole blocks of
     # ``block_select_size`` keys only: the first ``block_init_blocks``,

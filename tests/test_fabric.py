@@ -418,7 +418,9 @@ class TestChunkedExport:
 # ============================================================ wire refusal
 class TestWireRefusal:
     def test_hello_version_mismatch_is_typed_and_non_fatal(self):
-        with _Servers(1) as srv:
+        # 128 seats: four times what any engine of the benchmark ran
+        # before AI21-Jamba2-3B's; ``max_seats`` is the engine's own
+        with _Servers(1, max_ragged_sequence_count=128) as srv:
             conn = ftransport.dial(srv.peers[0], heartbeat_s=0.0)
             try:
                 with pytest.raises(ftransport.FabricError,
@@ -430,8 +432,8 @@ class TestWireRefusal:
                 info = conn.call("hello",
                                  {"codec_version": fcodec.CODEC_VERSION,
                                   "role": "mixed"}, timeout_s=120)
-                assert info["max_seats"] \
-                    == ENGINE_KW["max_ragged_sequence_count"]
+                assert info["max_seats"] == 128 \
+                    != ENGINE_KW["max_ragged_sequence_count"]
             finally:
                 conn.close()
 

@@ -766,3 +766,108 @@ def test_a_layer_that_nothing_feeds_is_refused(runs, what):
         dataclasses.replace(RUNS_CFG, layer_runs=None, diff_attn=False,
                             sliding_window=None,
                             layer_pattern=("mamba1", "gmu"), num_layers=12)
+
+
+# ---------------- a tiny Jamba: S6 layers with norms inside, 40 seats ------
+# (AI21-Jamba2-3B's block: ``mamba1_inner_norm``, attention of four heads
+# over ONE K/V head with no position term, a dense MLP behind every mixer;
+# the published order of the kinds in little: S6 x 2, attention, S6 x 3,
+# attention, S6 x 1)
+
+JAMBA_CFG = TransformerConfig(
+    vocab_size=128, hidden_size=32, intermediate_size=64, num_layers=8,
+    num_heads=4, num_kv_heads=1, head_size=8, max_seq_len=128,
+    norm="rmsnorm", norm_eps=1e-6, activation="silu", position="rope",
+    rope_kinds=(), tie_embeddings=True, dtype=jnp.float32,
+    layer_runs=((("mamba1",), 2), (("full",), 1), (("mamba1",), 3),
+                (("full",), 1), (("mamba1",), 1)),
+    mamba1_inner_size=64, mamba1_state_size=4, mamba1_dt_rank=4,
+    mamba1_conv_kernel=4, mamba1_inner_norm=True)
+SEATS = 40
+
+
+def test_a_tiny_jamba_with_forty_sequences_at_once_agrees_with_apply():
+    """More rows a step than any engine ran before (the cap was 32
+    everywhere): 40 sequences of different lengths prefilled in chunks of
+    16, then stepped together through the pool and the slots, ``[40, 1]``
+    a forward; ten finish, ten more take their seats — a reused slot
+    starts from zero — and every sequence's logits are ``CausalLM.apply``'s
+    on its own tokens."""
+    model = CausalLM(JAMBA_CFG)
+    params = model.init(jax.random.PRNGKey(4))
+    flat, tree = jax.tree_util.tree_flatten_with_path(params)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(flat))
+    params = jax.tree_util.tree_unflatten(tree, [
+        leaf + 0.05 * jax.random.normal(k, leaf.shape)
+        if "norm" in jax.tree_util.keystr(path) else leaf
+        for (path, leaf), k in zip(flat, keys)])
+    eng = InferenceEngineV2(model, params=params,
+                            config=RaggedInferenceEngineConfig(
+                                max_ragged_batch_size=SEATS * 16,
+                                max_ragged_sequence_count=SEATS,
+                                max_chunk_tokens=16, kv_blocks=SEATS * 16,
+                                kv_block_size=8, max_tracked_sequences=64))
+    sm = eng.state_manager
+    assert sm.state_slots == SEATS
+    assert sm.forward_cache["mamba1_ssm"].shape == (6, SEATS + 1, 4, 64)
+    assert sm.forward_cache["k"].shape == (2, SEATS * 16, 1, 8, 8)
+    steps = 3
+    seqs = {uid: prompt(100 + uid, 5 + uid) for uid in range(SEATS)}
+    apply = jax.jit(model.apply)
+
+    def want(uid):
+        """The whole forward's logits at the sequence's last position."""
+        tokens = np.zeros((1, 64), np.int32)
+        tokens[0, :len(seqs[uid])] = seqs[uid]
+        with jax.default_matmul_precision("highest"):
+            return np.asarray(apply(params, jnp.asarray(tokens))
+                              )[0, len(seqs[uid]) - 1]
+
+    def agree(got, uid):
+        w = want(uid)
+        assert np.abs(got - w).max() < 1e-5 * (w.max() - w.min()), uid
+
+    def prefill(uids):
+        """Every sequence's prompt, all rows of a put together, a chunk
+        of 16 a row a put."""
+        last = {}
+        for at in range(0, max(len(seqs[u]) for u in uids), 16):
+            rows = [u for u in uids if len(seqs[u]) > at]
+            out = eng.put(rows, [seqs[u][at:at + 16] for u in rows])
+            for i, u in enumerate(rows):
+                if len(seqs[u]) <= at + 16:
+                    last[u] = np.asarray(out[i])
+        return last
+
+    def step(uids, last):
+        for u in uids:
+            seqs[u] = seqs[u] + [int(np.argmax(last[u]))]
+        out = eng.put(uids, [[seqs[u][-1]] for u in uids])
+        assert eng.last_put["bucket_seqs"] == SEATS
+        assert eng.last_put["ssm_rows_stepped"] == len(uids)
+        return {u: np.asarray(out[i]) for i, u in enumerate(uids)}
+
+    everyone = list(seqs)
+    last = prefill(everyone)
+    assert eng.occupancy()["state_slots_used"] == SEATS
+    assert eng.can_schedule([SEATS], [1]) \
+        == SchedulingResult.KVCacheLimitExceeded        # no seat left
+    for _ in range(steps):
+        last = step(everyone, last)
+    for uid in everyone:
+        agree(last[uid], uid)
+    # ten finish; ten more take the seats they left
+    for uid in everyone[:10]:
+        eng.flush(uid)
+    assert sm.free_state_slots == 10
+    late = list(range(SEATS, SEATS + 10))
+    seqs.update({uid: prompt(300 + uid, 70 - uid) for uid in late})
+    last.update(prefill(late))
+    running = everyone[10:] + late
+    last = step(running, {u: last[u] for u in running})
+    for uid in running[::7] + late:
+        agree(last[uid], uid)
+    for uid in running:
+        eng.flush(uid)
+    assert sm.allocator.free_blocks == sm.allocator.total_blocks
+    assert sm.free_state_slots == sm.state_slots == SEATS

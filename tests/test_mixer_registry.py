@@ -90,6 +90,27 @@ def test_what_a_kind_states_is_what_the_facades_return(kind):
     assert (mixer.scope is not None) == bool(pool)
 
 
+def test_mamba1s_inner_norms_are_three_gains_and_one_field():
+    """``mamba1_inner_norm`` (Jamba's form of the S6 layer): three gains
+    over the step's projection, B and C beside the leaves the kind always
+    had, named by ``specs``; off, the leaves are today's."""
+    def slot(**more):
+        cfg = TransformerConfig(**BASE, layer_pattern=("mamba1",),
+                                **FIELDS["mamba1"], **more)
+        model = CausalLM(cfg)
+        leaves = jax.eval_shape(model.init,
+                                jax.random.PRNGKey(0))["layers"]["slot0"]
+        assert set(leaves) == set(model.param_specs()["layers"]["slot0"])
+        return {name: leaf.shape for name, leaf in leaves.items()}
+
+    off, on = slot(), slot(mamba1_inner_norm=True)
+    assert off == slot(mamba1_inner_norm=False)
+    assert {name: on[name] for name in set(on) - set(off)} == {
+        "mamba1_dt_norm": (2, 2), "mamba1_b_norm": (2, 4),
+        "mamba1_c_norm": (2, 4)}
+    assert all(on[name] == off[name] for name in off)
+
+
 def test_the_fed_kinds_keep_no_cache_and_say_what_they_read():
     """A model of runs: a state-space and a whole-context layer in a run
     of one period, the kinds that read them behind. Their leaves are what

@@ -10,7 +10,16 @@ wide over 10 joined K/V pairs (4 query rows a pair); the cross layers'
 calls read the whole-context group's one layer; in the chunk forward
 every call behind the exit is one position a row. See
 tests/test_tpu_compile.py for the method and tests/tpu_compile_harness.py
-for what is shared."""
+for what is shared.
+
+The cell ``ai21-jamba2-3b.chatrate``'s forwards join them as cases of
+their own (the S6 layer at the same widths, with its norms inside): all
+28 layers, the 65,536-row tied embedding, the pool of 36,864 blocks, 129
+state slots, the table of 18,432 positions — the one-token step over all
+128 seats, ``[128, 1]``, and the chunk ``[1, 512]`` (the mix's mean
+prompt is 399 tokens: the cell's commonest chunk program, and a quarter of
+the widest one's kernel call sites to compile); the paged kernel walks a
+query group of 20 heads over the ONE K/V head."""
 
 import re
 
@@ -23,6 +32,8 @@ from deepspeed_tpu.ops import paged_attention as pa
 
 NAME = "phi-4-mini-flash-reasoning"
 BUCKETS = [(32, 1), (1, 2048)]
+JAMBA = "ai21-jamba2-3b"
+JAMBA_BUCKETS = [(128, 1), (1, 512)]
 
 
 @pytest.mark.parametrize("bucket", BUCKETS, ids=bucket_id)
@@ -81,3 +92,39 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
             ["1,2560,1280"] * 6 + ["1,2560,2560"] * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (64 if bucket[1] == 1 else 768) * 2 ** 20, temp / 2 ** 20
+
+
+@pytest.mark.parametrize("bucket", JAMBA_BUCKETS,
+                         ids=lambda b: "jamba-" + bucket_id(b))
+def test_jambas_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
+    low, params, cache, cfg = lowered(JAMBA, v5e[0], bucket, monkeypatch)
+    # one layer group of two layers over one K/V head; 26 S6 layers' state
+    # a slot a seat and the scratch one
+    assert cfg.kv_groups() == ((0, 2),)
+    assert cache["k"].shape == cache["v"].shape == (2, 36864, 1, 64, 128)
+    assert cache["mamba1_ssm"].shape == (26, 129, 16, 5120)
+    assert cache["mamba1_conv"].shape == (26, 129, 3, 5120)
+    compiled = low.compile()
+    text = compiled.as_text()
+    found = kernels(text)
+    # a call site in each of the two inline attention layers; a chunk over
+    # MAX_QUERY_ROWS // 20 tokens is cut in pieces (the group of 20 heads
+    # is rows of one K/V head's query block); no other kernel
+    pieces = bucket[1] // pa._chunk_tile(bucket[1], 20)
+    assert found.count("paged_attention") == 2 * pieces, found
+    assert set(found) == {"paged_attention"}
+    scoped = re.findall(r'%paged_attention[.\d]* = [^\n]*op_name="([^"]*)"',
+                        text)
+    assert scoped and all("/full_attn/attend/" in s for s in scoped)
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("mamba/mamba_norm", "mamba/mamba_scan",
+                  "mamba/mamba_state_io", "mamba/mamba_proj",
+                  "mlp/dense_mlp", "logits"):
+        assert any(scope in n for n in names), scope
+    # weights 5.64 GiB + pool 2.25 + state 1.10 + this forward's
+    # temporaries fit the chip with room for the check's float32 reference
+    # when nothing runs (1.1 GiB of logits and K/V rows at 4,096 positions
+    # and about 1 GiB they are made from)
+    fits_beside(compiled, params, cache, bucket, headroom=3 * 2 ** 30)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (192 if bucket[1] == 1 else 384) * 2 ** 20, temp / 2 ** 20
