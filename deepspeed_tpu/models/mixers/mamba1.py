@@ -18,8 +18,12 @@ Mamba-2 layer's rules (``mamba2.py``; the leaves are named apart from
 its): a row at ``start_pos`` 0 starts from zero, positions at or beyond
 ``n_tokens`` change neither. Rows of one token go through the step, wider
 ones through the chunked form (``selective_scan.SUB`` tokens a
-sub-chunk); either way a forward's rows are gathered out of their slots
-and scattered back.
+sub-chunk). A forward of one token a row steps the state where it lies in
+its slots (``s6.s6_step_slots``: the leaf goes through it as it is, on
+the chip one kernel, ``s6_step``, that reads and writes each live row's
+state once and no padded row's); a chunk forward's rows (one a forward)
+are gathered out of their slots and scattered back, and so is the conv's
+tail either way.
 
 In a model of several runs of layers the layer hands on its **memory**:
 ``y`` as the recurrence gives it, the skip added, *before* the gate — what
@@ -28,10 +32,11 @@ the served type).
 
 Scopes (docs/OBSERVABILITY.md), the names a Mamba-2 layer uses: ``mamba``
 ⊃ ``mamba_proj`` (all three projections), ``mamba_conv``, ``mamba_scan``
-(the recurrence alone), ``mamba_out``, ``mamba_norm`` (the three inner
-norms, where the model has them) and, in serving, ``mamba_state_io``: the
-gather of the rows' state and conv tail out of the slots and the scatter
-back."""
+(the recurrence alone: in a one-token serving forward the step over the
+slots, state traffic and all), ``mamba_out``, ``mamba_norm`` (the three
+inner norms, where the model has them) and, in serving,
+``mamba_state_io``: the gather of the rows' conv tail (and, in a chunk
+forward, state) out of the slots and the scatter back."""
 
 from __future__ import annotations
 
@@ -139,11 +144,14 @@ def state_bytes(cfg) -> int:
     return ch * ns * 4
 
 
-def mamba1_mixer(cfg, h1, lp, tail, state, n_tokens):
+def mamba1_mixer(cfg, h1, lp, tail, state, n_tokens, in_slots=None):
     """The S6 layer on its normed input [B, T, H], resumed from ``tail``
     [B, K-1, CH] and ``state`` [B, S, CH] (float32). Positions at or
     beyond a row's ``n_tokens`` change neither. Returns (out [B, T, H],
-    new tail, new state, the memory: y [B, T, CH] before the gate)."""
+    new tail, new state, the memory: y [B, T, CH] before the gate).
+    ``in_slots``: one-token rows whose state stays where the caller keeps
+    it -- ``(x, dt, A, B, C, D) -> y`` steps it there, and ``state`` is
+    None in and out."""
     B, T, _ = h1.shape
     ch, ns, rank, _ = dims(cfg)
     dt_, f32 = cfg.dtype, jnp.float32
@@ -172,7 +180,10 @@ def mamba1_mixer(cfg, h1, lp, tail, state, n_tokens):
                     ).astype(f32) + lp["mamba1_dt_b"].astype(f32)), 0.0)
         A = -jnp.exp(lp["mamba1_A_log"].astype(f32))
     with scope("mamba_scan"):
-        if T == 1:
+        if in_slots is not None:
+            y = in_slots(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                         lp["mamba1_D"])[:, None]
+        elif T == 1:
             y, state = s6.s6_step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
                                   lp["mamba1_D"], state)
             y = y[:, None]
@@ -204,22 +215,33 @@ def reference(cfg, fwd):
 
 def paged(cfg, fwd):
     pools, slots, fresh = fwd.pools, fwd.state_slots, fwd.fresh
+    stepped = fwd.shape[1] == 1
 
     def mixer(h1, lp, i):
         layer = fwd.layer(KIND, i)
+
+        def in_slots(*step):
+            y, pools["mamba1_ssm"] = s6.s6_step_slots(
+                pools["mamba1_ssm"], layer, slots, fwd.n_tokens, fresh,
+                *step)
+            return y
+
         with scope("mamba"):
             with scope("mamba_state_io"):
                 tail = jnp.where(fresh[:, None, None], 0,
                                  pools["mamba1_conv"][layer, slots])
-                state = jnp.where(fresh[:, None, None], 0,
-                                  pools["mamba1_ssm"][layer, slots])
+                state = None if stepped else jnp.where(
+                    fresh[:, None, None], 0,
+                    pools["mamba1_ssm"][layer, slots])
             out, tail, state, memory = mamba1_mixer(
-                cfg, h1, lp, tail, state, fwd.n_tokens)
+                cfg, h1, lp, tail, state, fwd.n_tokens,
+                in_slots if stepped else None)
             with scope("mamba_state_io"):
                 pools["mamba1_conv"] = pools["mamba1_conv"].at[
                     layer, slots].set(tail)
-                pools["mamba1_ssm"] = pools["mamba1_ssm"].at[
-                    layer, slots].set(state)
+                if not stepped:
+                    pools["mamba1_ssm"] = pools["mamba1_ssm"].at[
+                        layer, slots].set(state)
             if "memory" in fwd.hand:
                 fwd.carry["memory"] = memory
             return out

@@ -871,3 +871,73 @@ def test_a_tiny_jamba_with_forty_sequences_at_once_agrees_with_apply():
         eng.flush(uid)
     assert sm.allocator.free_blocks == sm.allocator.total_blocks
     assert sm.free_state_slots == sm.state_slots == SEATS
+
+
+# -------------- the one-token step where the state lies (``s6_step``) ------
+
+def _serve_three(model, params, kernel, monkeypatch):
+    """Three sequences prefilled in chunks and stepped together, greedily,
+    through a bucket of four rows (one is padding every step), in an
+    engine of its own: the forward is traced under the hook as it stands.
+    Returns (tokens by sequence, the last step's logits, the S6 leaf's
+    rows a live sequence named, its scratch slot, the totals, the bucket
+    rows of every trace of the kernel's function)."""
+    from deepspeed_tpu.ops import selective_scan as s6
+
+    monkeypatch.setattr(s6, "_FORCE_INTERPRET", kernel)
+    traced = []
+    inner = getattr(s6._step_in_kernel, "inner", s6._step_in_kernel)
+
+    def counting(pool, layer, slots, *rest, **kw):
+        traced.append(int(slots.shape[0]))
+        return inner(pool, layer, slots, *rest, **kw)
+
+    counting.inner = inner      # a later engine's wrapper goes round this
+    monkeypatch.setattr(s6, "_step_in_kernel", counting)
+    eng = InferenceEngineV2(model, params=params,
+                            config=RaggedInferenceEngineConfig(
+                                **dict(SIZING, compile_ahead=0)))
+    seqs = {u: prompt(40 + u, n) for u, n in ((1, 21), (2, 5), (3, 37))}
+    last = {u: feed(eng, u, list(t)) for u, t in seqs.items()}
+    for _ in range(3):
+        for u in seqs:
+            seqs[u].append(int(np.argmax(last[u])))
+        out = eng.put(list(seqs), [[t[-1]] for t in seqs.values()])
+        assert eng.last_put["bucket_seqs"] == 4
+        assert eng.last_put["ssm_rows_stepped"] == 3
+        last = {u: np.asarray(out[i]) for i, u in enumerate(seqs)}
+    sm = eng.state_manager
+    leaf = np.asarray(sm.forward_cache["mamba1_ssm"])
+    named = [sm.get_sequence(u).state_slot for u in seqs]
+    return (seqs, last, leaf[:, named], leaf[:, sm.state_slots],
+            dict(eng.put_totals), traced)
+
+
+@pytest.mark.parametrize("which", ["runs", "jamba"])
+def test_a_sequence_stepped_through_the_kernel_is_the_plain_forms(
+        which, monkeypatch, request):
+    """``s6_step`` interpreted against gather, ``s6_step``, scatter, in the
+    engine: the same tokens, the logits and the live slots' state to
+    float32 round-off; the scratch slot, which the plain form writes back
+    as it was and the kernel never names, bit for bit the same; and
+    ``ssm_rows_stepped`` is what went through the kernel -- every
+    one-token forward's S6 layers trace it at the bucket's rows, no chunk
+    forward's do, and the plain engine's none."""
+    if which == "runs":
+        model, params = request.getfixturevalue("runs_model")[:2]
+    else:
+        model = CausalLM(JAMBA_CFG)
+        params = model.init(jax.random.PRNGKey(4))
+    plain = _serve_three(model, params, False, monkeypatch)
+    kernel = _serve_three(model, params, True, monkeypatch)
+    assert kernel[0] == plain[0]                        # the tokens
+    for u in plain[1]:
+        span = plain[1][u].max() - plain[1][u].min()
+        assert np.abs(kernel[1][u] - plain[1][u]).max() < 5e-6 * span
+    np.testing.assert_allclose(kernel[2], plain[2], rtol=2e-5, atol=2e-6)
+    np.testing.assert_array_equal(kernel[3], plain[3])
+    assert kernel[4]["ssm_rows_stepped"] == 9 == plain[4]["ssm_rows_stepped"]
+    assert kernel[4]["ssm_chunk_tokens"] == 21 + 5 + 37
+    assert plain[5] == []
+    # a trace a body of S6 layers of the one [4, 1] program
+    assert kernel[5] and set(kernel[5]) == {4}

@@ -218,3 +218,182 @@ def test_the_norms_change_the_layer_and_their_gains_are_read():
     for name in ("mamba1_dt_norm", "mamba1_b_norm", "mamba1_c_norm"):
         assert far(with_norms(dict(lp, **{name: 2.0 * lp[name]}))) > 0.01, \
             name
+
+
+# --------------------------------------------- the step where the state lies
+
+SLOT_CASES = {
+    # name: (bucket rows, rows with a token, fresh rows)
+    "all_live": (6, [1] * 6, []),
+    "padding_first": (6, [0, 0, 1, 1, 1, 1], []),
+    "padding_last": (6, [1, 1, 1, 0, 0, 0], []),
+    "padding_between": (6, [1, 0, 0, 1, 0, 1], []),
+    "padding_first_and_between": (6, [0, 1, 0, 0, 1, 1], [1]),
+    "fresh_over_a_dirty_slot": (6, [1] * 6, [1, 3]),
+    "one_live_row": (6, [0, 0, 0, 0, 1, 0], range(6)),
+    "no_live_row": (6, [0] * 6, range(6)),
+    "bucket_of_1": (1, [1], []),
+    "bucket_of_1_fresh": (1, [1], [0]),
+    "bucket_of_1_padding": (1, [0], [0]),
+    "bucket_of_128": (128, [1] * 81 + [0] * 47, range(0, 128, 9)),
+}
+
+
+def _slot_case(case, L=2, S=4, CH=256, served=jnp.float32):
+    """A pool of a slot a bucket row and the scratch slot behind them,
+    and a bucket: (pool, slots, n_tokens, fresh, step inputs). A padded
+    row names the scratch slot; a dirty slot holds what no step
+    survives."""
+    N, n, fresh = SLOT_CASES[case]
+    NS = N + 2                      # one slot no row names, and the scratch
+    ks = jax.random.split(jax.random.PRNGKey(11), 7)
+    pool = jax.random.normal(ks[0], (L, NS, S, CH))
+    step = dict(x=jax.random.normal(ks[1], (N, CH)).astype(served),
+                dt=jax.nn.softplus(jax.random.normal(ks[2], (N, CH)) - 1.0),
+                A=-jnp.exp(0.5 * jax.random.normal(ks[3], (S, CH))),
+                B=jax.random.normal(ks[4], (N, S)).astype(served),
+                C=jax.random.normal(ks[5], (N, S)).astype(served),
+                D=jax.random.normal(ks[6], (CH,)))
+    own = np.random.default_rng(3).permutation(N)       # out of order
+    is_fresh = np.isin(np.arange(N), list(fresh))
+    for row in np.flatnonzero(is_fresh & (np.asarray(n) > 0)):
+        pool = pool.at[:, own[row]].set(jnp.nan if row % 2 else 1e30)
+    slots = np.where(np.asarray(n) > 0, own, NS - 1)
+    return (pool, jnp.asarray(slots, jnp.int32), jnp.asarray(n, jnp.int32),
+            jnp.asarray(is_fresh), step)
+
+
+def _gather_step_scatter(pool, layer, slots, n, fresh, step):
+    """The oracle: ``s6_step`` on a gathered copy, scattered back."""
+    live = n > 0
+    state = jnp.where((fresh & live)[:, None, None], 0, pool[layer, slots])
+    y, state = s6.s6_step(step["x"], jnp.where(live[:, None], step["dt"], 0),
+                          step["A"], step["B"], step["C"], step["D"], state)
+    return y, pool.at[layer, slots].set(state)
+
+
+def _same(a, b):
+    return np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)
+
+
+@pytest.mark.parametrize("form", ["kernel", "plain"])
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("case", list(SLOT_CASES))
+def test_the_step_over_the_slots_is_the_step_round_a_gather_and_a_scatter(
+        case, layer, form, monkeypatch):
+    """``s6_step_slots`` -- the kernel ``s6_step`` interpreted, and the
+    plain form -- against gather, ``s6_step``, scatter: ``y`` of the live
+    rows and their slots to float32 round-off, a padded row's ``y`` 0;
+    the scratch slot, every slot no live row names and the other layer of
+    the pool bit for bit what they were; the layer index traced."""
+    monkeypatch.setattr(s6, "_FORCE_INTERPRET", form == "kernel")
+    pool, slots, n, fresh, step = _slot_case(case)
+    before = np.asarray(pool)
+    want_y, want_pool = _gather_step_scatter(pool, layer, slots, n, fresh,
+                                             step)
+    got_y, got_pool = jax.jit(
+        lambda pool, layer: s6.s6_step_slots(
+            pool, layer, slots, n, fresh, **step))(pool, jnp.int32(layer))
+    assert got_y.dtype == got_pool.dtype == jnp.float32
+    got_pool, live = np.asarray(got_pool), np.asarray(n) > 0
+    named = np.asarray(slots)[live]
+    if live.any():
+        close(got_y[live], want_y[live])
+        close(got_pool[layer, named], np.asarray(want_pool)[layer, named])
+        assert np.isfinite(got_pool[layer, named]).all()
+    assert (np.asarray(got_y)[~live] == 0).all()
+    others = np.setdiff1d(np.arange(pool.shape[1]), named)
+    assert pool.shape[1] - 1 in others                  # the scratch slot
+    assert _same(got_pool[layer, others], before[layer, others])
+    assert _same(got_pool[1 - layer], before[1 - layer])
+
+
+@pytest.mark.parametrize("form", ["kernel", "plain"])
+def test_two_layers_of_one_pool_are_stepped_one_after_the_other(form,
+                                                                monkeypatch):
+    """A forward's use: the leaf handed from layer to layer, each stepping
+    its own rows of it -- what the second layer's call returns holds both
+    layers' new states and nothing else changed."""
+    monkeypatch.setattr(s6, "_FORCE_INTERPRET", form == "kernel")
+    pool, slots, n, fresh, step = _slot_case("padding_first_and_between")
+    want = pool
+    for layer in (0, 1):
+        _, want = _gather_step_scatter(want, layer, slots, n, fresh, step)
+
+    @jax.jit
+    def forward(pool):
+        for layer in (0, 1):
+            _, pool = s6.s6_step_slots(pool, layer, slots, n, fresh, **step)
+        return pool
+
+    got = np.asarray(forward(pool))
+    named = np.asarray(slots)[np.asarray(n) > 0]
+    close(got[:, named], np.asarray(want)[:, named])
+    others = np.setdiff1d(np.arange(pool.shape[1]), named)
+    assert _same(got[:, others], np.asarray(pool)[:, others])
+
+
+def test_served_types_go_into_the_step_over_the_slots_float32_comes_out(
+        monkeypatch):
+    pool, slots, n, fresh, step = _slot_case("padding_last",
+                                             served=jnp.bfloat16)
+    assert step["x"].dtype == step["B"].dtype == jnp.bfloat16
+    plain = s6.s6_step_slots(pool, 1, slots, n, fresh, **step)
+    monkeypatch.setattr(s6, "_FORCE_INTERPRET", True)
+    kernel = s6.s6_step_slots(pool, 1, slots, n, fresh, **step)
+    for got in (plain, kernel):
+        assert got[0].dtype == got[1].dtype == jnp.float32
+    close(kernel[0], plain[0])
+    close(kernel[1], plain[1])
+    wide = {k: v.astype(jnp.float32) for k, v in step.items()}
+    close(kernel[0], s6.s6_step_slots(pool, 1, slots, n, fresh, **wide)[0])
+
+
+def test_a_one_token_forward_moves_no_state_sized_copy(monkeypatch):
+    """The ``[S, 1]`` program as it is lowered for the chip: the state
+    leaf goes to ``s6_step`` as it lies and comes back from it -- no
+    gather, scatter or dynamic-update-slice has a state-sized operand,
+    and no ``[N, S, CH]`` decay is made outside the kernel (the chunked
+    form still gathers and scatters its one row's)."""
+    import re
+
+    from deepspeed_tpu.models.mixers import mamba1
+    from deepspeed_tpu.models.mixers.base import Fwd
+    from deepspeed_tpu.ops import pallas_utils
+
+    import dataclasses
+
+    # a state size that no other width of the layer shares
+    cfg = dataclasses.replace(_layer_cfg(True), mamba1_state_size=8)
+    lp = _layer_weights(cfg)
+    monkeypatch.setattr(pallas_utils, "on_tpu", lambda: True)
+    shapes = mamba1.state(cfg, 5)
+    state = "x".join(map(str, shapes["mamba1_ssm"][0][2:])) + "xf32"
+    h1 = jax.random.normal(jax.random.PRNGKey(8), (3, 40, 16))
+
+    def forward(T):
+        def run(pools, h1, n, slots, first):
+            fwd = Fwd(shape=(3, T), n_tokens=n, ropes={}, pools=pools,
+                      first_layer={"mamba1": first}, state_slots=slots,
+                      fresh=n > 1, hand=("memory",), carry={})
+            return (mamba1.paged(cfg, fwd)(h1, lp, 0), fwd.carry["memory"],
+                    pools)
+        pools = {name: jnp.zeros(shape, dt)
+                 for name, (shape, dt) in shapes.items()}
+        text = jax.jit(run).trace(
+            pools, h1[:, :T], jnp.ones((3,), jnp.int32),
+            jnp.arange(3, dtype=jnp.int32), jnp.int32(0)
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        moved = [m.group(0) for m in re.finditer(
+            r"stablehlo\.(gather|scatter|dynamic_update_slice)\b.*?"
+            r"-> tensor<[^>]*>", text, re.S) if state in m.group(0)]
+        decays = re.findall(r"stablehlo\.exponential [^\n]*tensor<3x(?:\d+x)?"
+                            + state + ">", text)
+        return moved, decays, text
+
+    moved, decays, text = forward(1)
+    assert not moved, moved
+    assert not decays, decays
+    assert text.count("tpu_custom_call") == 1 and "s6_step" in text
+    moved, decays, _ = forward(40)      # what the assertions would catch
+    assert moved and decays

@@ -56,7 +56,13 @@ def test_the_cells_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
     # forward too, behind the exit; no other kernel
     pieces = bucket[1] // pa._chunk_tile(bucket[1], 4)
     assert found.count("paged_attention") == pieces + 2, found
-    assert set(found) == {"paged_attention"}
+    # a one-token forward steps the S6 state where it lies in its slots:
+    # ``s6_step`` in the window run's scanned body and in layer 16; a
+    # chunk forward holds none
+    stepped = 2 if bucket[1] == 1 else 0
+    assert found.count("s6_step") == stepped, found
+    assert set(found) == {"paged_attention"} | (
+        {"s6_step"} if stepped else set()), found
     scoped = re.findall(r'%paged_attention[.\d]* = [^\n]*op_name="([^"]*)"',
                         text)
     assert all("/attend/" in s for s in scoped)
@@ -112,7 +118,16 @@ def test_jambas_forwards_at_the_files_sizes(v5e, bucket, monkeypatch):
     # is rows of one K/V head's query block); no other kernel
     pieces = bucket[1] // pa._chunk_tile(bucket[1], 20)
     assert found.count("paged_attention") == 2 * pieces, found
-    assert set(found) == {"paged_attention"}
+    # a one-token forward steps the S6 state where it lies in its slots:
+    # ``s6_step`` once in each of the three runs of S6 layers (a scanned
+    # body each); a chunk forward holds none
+    stepped = 3 if bucket[1] == 1 else 0
+    assert found.count("s6_step") == stepped, found
+    assert set(found) == {"paged_attention"} | (
+        {"s6_step"} if stepped else set()), found
+    if stepped:
+        under = re.findall(r'%s6_step[.\d]* = [^\n]*op_name="([^"]*)"', text)
+        assert under and all("/mamba/mamba_scan/" in s for s in under), under
     scoped = re.findall(r'%paged_attention[.\d]* = [^\n]*op_name="([^"]*)"',
                         text)
     assert scoped and all("/full_attn/attend/" in s for s in scoped)
